@@ -16,25 +16,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# bf16 peak FLOPs by TPU device kind (public spec sheets); CPU nominal.
-PEAK_FLOPS_BY_KIND = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,  # v5e
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,  # Trillium
-    "TPU v6e": 918e12,
-}
+def peak_flops() -> float:
+    """Published bf16 peak of this process's device, from the package's one
+    table (accelerator/device.py). Raises on a device the table does not
+    know — a CPU included: a CPU run has no MFU."""
+    from deepspeed_tpu.accelerator.device import device_peaks
+
+    return device_peaks().bf16_flops
 
 
-def peak_flops(platform: str) -> float:
-    if platform == "tpu":
-        kind = jax.devices()[0].device_kind
-        for prefix, peak in PEAK_FLOPS_BY_KIND.items():
-            if kind.startswith(prefix):
-                return peak
-        return 197e12  # unknown TPU: assume v5e class
-    return 1e12  # CPU / non-TPU: nominal figure, MFU not meaningful
+def mfu_pct(achieved_flops: float, ndev: int = 1):
+    """Percent of the published bf16 peak, or None on a CPU run."""
+    from deepspeed_tpu.accelerator.device import on_tpu
+
+    if not on_tpu():
+        return None
+    return round(achieved_flops / (peak_flops() * ndev) * 100, 2)
 
 
 def _bench_7b_streamed_at(peak: float, bsz: int):
@@ -262,10 +259,8 @@ def bench_long_context_cp(steps=3, warmup=1):
         arm = {"s_per_step": round(dt, 4), "loss": round(loss, 4)}
         if label == "ring":
             tok_s = seq / dt
-            mfu = tok_s * flops_per_token(cfg, seq) / (
-                peak_flops(jax.default_backend()) * ndev)
             arm["tok_s"] = round(tok_s, 1)
-            arm["mfu_pct"] = round(mfu * 100, 2)
+            arm["mfu_pct"] = mfu_pct(tok_s * flops_per_token(cfg, seq), ndev)
         out[label] = arm
         del engine, params
     if "dense" in out:
@@ -353,7 +348,7 @@ def bench_splash_ab(steps=5, warmup=2):
     flops = 3.5 * 2.0 * h * ls * ls * d
     out["dense_16k"] = {
         "seq": ls, "s_per_step": round(dt, 4),
-        "mfu_pct": round(100 * flops / dt / peak_flops("tpu"), 2),
+        "mfu_pct": mfu_pct(flops / dt),
     }
     return out
 
@@ -390,6 +385,7 @@ def v5e64_projection():
 
 def main():
     import deepspeed_tpu
+    from deepspeed_tpu.accelerator.device import setup_compile_cache
     from deepspeed_tpu.models import (
         TransformerConfig,
         flops_per_token,
@@ -397,6 +393,7 @@ def main():
         make_loss_fn,
     )
 
+    setup_compile_cache()
     platform = jax.default_backend()
     on_tpu = platform == "tpu"
 
@@ -408,7 +405,7 @@ def main():
         from deepspeed_tpu.parallel.topology import reset_topology
 
         try:
-            streamed_7b = bench_7b_streamed(peak_flops(platform))
+            streamed_7b = bench_7b_streamed(peak_flops())
         except Exception as e:  # the headline metric must survive
             streamed_7b = {"error": f"{type(e).__name__}: {e}"[:200]}
         import gc
@@ -469,16 +466,14 @@ def main():
 
     tokens_per_step = bsz * seq
     tok_s = tokens_per_step * steps / dt
-    achieved = tok_s * flops_per_token(cfg, seq)
-    peak = peak_flops(platform)
-    mfu = achieved / peak
+    mfu = mfu_pct(tok_s * flops_per_token(cfg, seq))  # None on a CPU run
 
     size = "767M" if on_tpu else "tiny"
     out = {
         "metric": f"llama-{size} zero3 train MFU ({platform}, {tok_s:.0f} tok/s, loss={loss:.3f})",
-        "value": round(mfu * 100, 2),
+        "value": mfu,
         "unit": "% MFU",
-        "vs_baseline": round(mfu / 0.40, 3),
+        "vs_baseline": None if mfu is None else round(mfu / 40.0, 3),
     }
     if streamed_7b is not None:
         out["streamed_7b"] = streamed_7b
@@ -510,6 +505,9 @@ def main():
         except Exception as e:  # the headline metric must survive
             out["serving_v2"] = {"error": f"{type(e).__name__}: {e}"[:200]}
     print(json.dumps(out))
+    failed = [k for k, v in out.items() if isinstance(v, dict) and "error" in v]
+    if failed:
+        raise SystemExit(f"bench.py: phases failed: {failed}")
 
 
 def bench_serving(train_cfg):

@@ -9,11 +9,11 @@ Public API mirrors the reference ``deepspeed/__init__.py``:
 
 from typing import Any, Callable, Optional, Union
 
-from deepspeed_tpu import _jax_compat  # noqa: F401  — must run before jax users below
 from deepspeed_tpu.version import __version__
 from deepspeed_tpu import comm
 from deepspeed_tpu.runtime import zero
 from deepspeed_tpu.accelerator import get_accelerator
+from deepspeed_tpu.accelerator.device import setup_compile_cache
 from deepspeed_tpu.comm.comm import init_distributed
 from deepspeed_tpu.parallel.topology import Topology, get_topology, set_topology
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
@@ -50,6 +50,7 @@ def initialize(
     ``mesh`` section sizes the parallelism grid.
     """
     log_dist(f"DeepSpeedTPU info: version={__version__}", ranks=[0])
+    setup_compile_cache()
     if model is None:
         raise ValueError("deepspeed_tpu.initialize: model (loss function) is required")
     if model_parameters is None:

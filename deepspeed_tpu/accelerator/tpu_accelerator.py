@@ -12,10 +12,9 @@ from deepspeed_tpu.accelerator.abstract_accelerator import DeepSpeedAccelerator
 
 
 class TPU_Accelerator(DeepSpeedAccelerator):
-    def __init__(self, platform="tpu"):
+    def __init__(self):
         super().__init__()
         self._name = "tpu"
-        self._platform = platform
         self._communication_backend_name = "xla"
 
     def _devices(self):
@@ -87,9 +86,8 @@ class CPU_Accelerator(TPU_Accelerator):
     ``accelerator/cpu_accelerator.py`` + the gloo path in tests."""
 
     def __init__(self):
-        super().__init__(platform="cpu")
+        super().__init__()
         self._name = "cpu"
-        self._communication_backend_name = "xla"
 
     def _devices(self):
         import jax

@@ -42,11 +42,14 @@ def get_fp32_state_dict_from_zero_checkpoint(checkpoint_dir, tag=None):
     """Reference-parity function name. Returns {dotted_name: fp32 ndarray}."""
     import numpy as np
 
-    # force CPU so this runs on any login/CPU node (reference script likewise
-    # runs without GPUs)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import orbax.checkpoint as ocp
+
+    # A host-side converter: it runs on a login node, or beside a live trainer
+    # that owns the chip, so it pins itself to the CPU before any backend
+    # comes up (in a process that already has one, the pin is a no-op and
+    # the CPU device below is still the one used).
+    jax.config.update("jax_platforms", "cpu")
 
     if tag is None:
         latest = os.path.join(checkpoint_dir, "latest")
@@ -64,7 +67,7 @@ def get_fp32_state_dict_from_zero_checkpoint(checkpoint_dir, tag=None):
         meta = ckptr.metadata(state_path)
         # orbax wraps the item pytree in StepMetadata on recent versions
         meta = getattr(meta, "item_metadata", meta)
-        sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        sharding = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
 
         def abstr(m):
             shape = getattr(m, "shape", None)

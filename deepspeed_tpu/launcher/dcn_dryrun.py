@@ -221,22 +221,10 @@ def run_two_process_dryrun(n_devices: int, log_prefix="dcn-dryrun", timeout_s=42
 # --------------------------------------------------------------------------
 
 def _setup_jax(n_local: int):
-    import os
-
-    if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        # pre-0.5 jax has no jax_num_cpu_devices; the flag must precede
-        # backend init, so set it before the import below
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={n_local}"
-        ).strip()
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_local)
-    except AttributeError:
-        pass  # XLA_FLAGS fallback above
+    jax.config.update("jax_num_cpu_devices", n_local)
     return jax
 
 

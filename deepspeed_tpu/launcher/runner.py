@@ -122,10 +122,14 @@ def run_autotuning(args) -> int:
         if s["hidden_size"] == hidden and new_heads == heads:
             continue
         shapes.append(s)
-    import jax
+    # every experiment is a child that needs the chip, so this parent must
+    # never initialise a backend: a child that exits answers instead
+    from deepspeed_tpu.accelerator.device import device_peaks, probe_device
 
-    on_tpu = jax.default_backend() == "tpu"
-    hbm = 16e9 if on_tpu else 64e9  # CPU smoke runs are unconstrained
+    platform, kind = probe_device()
+    on_tpu = platform == "tpu"
+    # CPU smoke runs are unconstrained
+    hbm = device_peaks(kind).hbm_bytes if on_tpu else 64e9
     mi = ModelInfo(
         num_params=estimate_params(base),
         hidden_size=hidden,

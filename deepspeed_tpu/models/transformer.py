@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.accelerator.device import on_tpu
 from deepspeed_tpu.ops.attention import attention as attention_op
 from deepspeed_tpu.parallel.topology import (
     BATCH_AXES,
@@ -649,7 +650,7 @@ _stage_to_device.defvjp(_stage_fwd, _stage_bwd)
 
 
 def _stream_active(c: TransformerConfig) -> bool:
-    return c.weight_stream and jax.default_backend() == "tpu"
+    return c.weight_stream and on_tpu()
 
 
 # ---------------------------------------------------------------------------
@@ -1469,7 +1470,7 @@ def make_loss_fn(config: TransformerConfig):
             config.fused_ce
             and not config.lm_head_bias
             and not config.mlm_head  # the fused kernel has no MLM transform
-            and jax.default_backend() == "tpu"
+            and on_tpu()
             and get_topology().world_size == 1
         ):
             # Pallas fused head+CE: logits never materialize in HBM

@@ -15,6 +15,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.accelerator.device import on_tpu
+
 
 class AdamParams(NamedTuple):
     lr: float = 1e-3
@@ -194,7 +196,7 @@ def fused_adam_transform(
     import optax
 
     if use_pallas is None:
-        use_pallas = interpret or jax.default_backend() == "tpu"
+        use_pallas = interpret or on_tpu()
     single_device = True
     if mesh is not None:
         single_device = mesh.size == 1

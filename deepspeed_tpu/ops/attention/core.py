@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.accelerator.device import on_tpu
+
 
 def window_too_far(q_pos, k_pos, window: int, window_flag=None):
     """THE sliding-window band convention, shared by every implementation
@@ -172,26 +174,14 @@ def _splash_dispatch(q, k, v, causal, segment_ids, bias, scale, window,
 
         out = head_sharded_splash(q, k, v, schedule, segment_ids=segment_ids,
                                   scale=scale,
-                                  interpret=not _flash_available())
+                                  interpret=not on_tpu())
         if out is not None:
             return out
         # shapes don't divide the mesh: run the kernel unsharded (GSPMD
         # replicates the pallas_call) — scheduling still prunes, only the
         # head parallelism is lost
     return splash_attention(q, k, v, schedule, segment_ids=segment_ids,
-                            scale=scale, interpret=not _flash_available())
-
-
-@functools.lru_cache(maxsize=1)
-def _flash_available() -> bool:
-    if jax.default_backend() != "tpu":
-        return False
-    try:
-        from deepspeed_tpu.ops.attention import flash_pallas  # noqa: F401
-
-        return True
-    except Exception:
-        return False
+                            scale=scale, interpret=not on_tpu())
 
 
 def _flash_sharded(q, k, v, causal, segment_ids, scale, alibi_slopes=None,
@@ -235,8 +225,6 @@ def _ring_eligible(q, k, bias, causal, window):
     b, h, s, d = q.shape
     h_kv, sk = k.shape[1], k.shape[2]
     if s != sk or d not in (64, 128, 256) or s % n or (s // n) % 128:
-        return False
-    if not (_flash_available() or jax.default_backend() == "cpu"):
         return False
     from deepspeed_tpu.ops.attention.sharded import _divisible
 
@@ -321,13 +309,13 @@ def attention(
             return sharded.ring_flash_attention(
                 q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
                 alibi_slopes=alibi_slopes, window=window,
-                interpret=not _flash_available(),
+                interpret=not on_tpu(),
             )
         out = sharded.head_sharded_flash(
             q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
             alibi_slopes=alibi_slopes, alibi_positions=alibi_positions,
             window=window, window_flag=window_flag,
-            interpret=not _flash_available(),
+            interpret=not on_tpu(),
         )
         if out is None:
             raise ValueError(
@@ -340,11 +328,11 @@ def attention(
 
         return sharded.ring_flash_attention(
             q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
-            alibi_slopes=alibi_slopes, interpret=not _flash_available(),
+            alibi_slopes=alibi_slopes, interpret=not on_tpu(),
         )
     use_flash = impl == "flash" or (
         impl in (None, "auto")
-        and _flash_available()
+        and on_tpu()
         and bias is None
         and d in (64, 128, 256)
         and sq % 128 == 0

@@ -47,9 +47,9 @@ LANES = 128
 def _alibi_term(alibi_ref, kpos_ref):
     """ALiBi additive logits term for one block: ``slope_h * key_position``
     (HF bloom's absolute-position convention — softmax-equivalent to the
-    relative form under causal masking). alibi_ref: [1, LANES] slope plane
-    for this head; kpos_ref: [bk] int32 key positions."""
-    return alibi_ref[0, 0] * kpos_ref[:].astype(jnp.float32)[None, :]
+    relative form under causal masking). alibi_ref: [1, 1, LANES] slope plane
+    for this head; kpos_ref: [1, bk] int32 key positions."""
+    return alibi_ref[0, 0, 0] * kpos_ref[:].astype(jnp.float32)
 
 
 def _apply_window(logits, window, wflag_ref, q_pos, k_pos):
@@ -117,9 +117,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
             if window:
                 logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
         if seg_q_ref is not None:
-            logits = jnp.where(
-                seg_q_ref[:][:, None] == seg_k_ref[:][None, :], logits, NEG_INF
-            )
+            logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
         m = m_ref[:, 0]
         l = l_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
@@ -196,9 +194,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
             if window:
                 logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
         if seg_q_ref is not None:
-            logits = jnp.where(
-                seg_q_ref[:][:, None] == seg_k_ref[:][None, :], logits, NEG_INF
-            )
+            logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
         p = jnp.exp(logits - lse[:, None])  # [bq, bk]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -268,9 +264,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
             if window:
                 logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
         if seg_q_ref is not None:
-            logits = jnp.where(
-                seg_q_ref[:][:, None] == seg_k_ref[:][None, :], logits, NEG_INF
-            )
+            logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
         p = jnp.exp(logits - lse[:, None])
         dv_acc_ref[:] = dv_acc_ref[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -409,23 +403,32 @@ def _seg_specs(segment_ids, q_block, q_map, k_block, k_map):
         seg_q, seg_k = segment_ids
     else:
         seg_q = seg_k = segment_ids
-    seg_q = seg_q.astype(jnp.int32)
-    seg_k = seg_k.astype(jnp.int32)
+    # Mosaic wants a block's last two dims to be (8, 128)-aligned or whole:
+    # a (1, block) window of a [b, s] plane is neither once b > 1, and a
+    # [b, sq, 1] column cannot be sliced ("must be aligned to tiling (128)").
+    # So the q ids ride lane-broadcast as [b, sq, LANES], like the LSE, and
+    # the k ids as a [b, 1, sk] row: the kernel compares column 0 of the one
+    # with the row of the other, [bq, 1] == [1, bk], by broadcast.
+    seg_q = jnp.broadcast_to(
+        seg_q.astype(jnp.int32)[:, :, None], seg_q.shape + (LANES,))
+    seg_k = seg_k.astype(jnp.int32)[:, None, :]
     return [seg_q, seg_k], [
-        pl.BlockSpec((1, q_block), lambda b_, h_, i, j: (b_, q_map(i, j))),
-        pl.BlockSpec((1, k_block), lambda b_, h_, i, j: (b_, k_map(i, j))),
+        pl.BlockSpec((1, q_block, LANES), lambda b_, h_, i, j: (b_, q_map(i, j), 0)),
+        pl.BlockSpec((1, 1, k_block), lambda b_, h_, i, j: (b_, 0, k_map(i, j))),
     ]
 
 
 def _alibi_specs(alibi, k_block, k_map):
     """(extra operands, extra in_specs) for ALiBi: the per-head slope plane
-    [h, LANES] plus the [b, s] key-position plane (k-side blocks only)."""
+    [h, LANES] plus the [b, s] key-position plane (k-side blocks only). Both
+    gain a unit middle dim for the same reason as the segment ids: a one-row
+    window of a 2-D plane is not a legal Mosaic block."""
     if alibi is None:
         return [], []
     slopes_lane, kpos = alibi
-    return [slopes_lane, kpos], [
-        pl.BlockSpec((1, LANES), lambda b_, h_, i, j: (h_, 0)),
-        pl.BlockSpec((1, k_block), lambda b_, h_, i, j: (b_, k_map(i, j))),
+    return [slopes_lane[:, None, :], kpos[:, None, :]], [
+        pl.BlockSpec((1, 1, LANES), lambda b_, h_, i, j: (h_, 0, 0)),
+        pl.BlockSpec((1, 1, k_block), lambda b_, h_, i, j: (b_, 0, k_map(i, j))),
     ]
 
 

@@ -35,6 +35,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.accelerator.device import on_tpu
+
 NEG_INF = -1e30
 
 
@@ -87,7 +89,7 @@ def _paged_kernel(
 ):
     """(T, B [+1])-grid kernel body. ``refs`` layout — scalar prefetch
     (SMEM): bt [T, B], qpos [T], trash [1], limit [T] if ``has_limit`` —
-    then tensor blocks (VMEM): epos (1, E) if ``E``, q (1, nh, d),
+    then tensor blocks (VMEM): epos (1, 1, E) if ``E``, q (1, nh, d),
     k (1, bs, nkv, d), v, ks/vs scale planes (1, bs, nkv) if ``int8``,
     ke/ve (1, E, nkv, d) if ``E`` — then o (1, nh, d) and the m/l/acc
     flash scratch.
@@ -184,7 +186,7 @@ def _paged_kernel(
 
         @pl.when(j == B)
         def _extra():
-            epos = epos_ref[...]  # [1, E]
+            epos = epos_ref[0]  # [1, E]
             valid = (epos >= 0) & (epos <= qpos)
             if window:
                 from deepspeed_tpu.ops.attention.core import window_too_far
@@ -253,9 +255,7 @@ def paged_attention(
             f"{k_cache.dtype}, not int8"
         )
     if impl is None or impl == "auto":
-        impl = "kernel" if (
-            jax.default_backend() == "tpu" and d in (64, 128, 256)
-        ) else "dense"
+        impl = "kernel" if (on_tpu() and d in (64, 128, 256)) else "dense"
     if impl == "reference":
         if extra_kv is not None or pool_limit is not None:
             raise ValueError(
@@ -279,7 +279,7 @@ def paged_attention(
         )
 
     # kernel path; off-TPU it only runs interpreted (CPU tests)
-    interpret = bool(interpret) or jax.default_backend() != "tpu"
+    interpret = bool(interpret) or not on_tpu()
     B = block_tables.shape[1]
     has_limit = pool_limit is not None
     E = 0 if extra_kv is None else int(extra_kv[0].shape[1])
@@ -291,7 +291,9 @@ def paged_attention(
         blk_idx = lambda t, j, *s: (s[0][t, j], 0, 0, 0)
     in_specs = []
     if E:
-        in_specs.append(pl.BlockSpec((1, E), lambda t, j, *s: (t, 0)))
+        # [T, 1, E]: a (1, E) window of a [T, E] plane is not a legal Mosaic
+        # block (last two dims must be (8, 128)-aligned or whole)
+        in_specs.append(pl.BlockSpec((1, 1, E), lambda t, j, *s: (t, 0, 0)))
     in_specs.append(pl.BlockSpec((1, nh, d), lambda t, j, *s: (t, 0, 0)))
     in_specs.append(pl.BlockSpec((1, bs, nkv, d), blk_idx))
     in_specs.append(pl.BlockSpec((1, bs, nkv, d), blk_idx))
@@ -326,7 +328,7 @@ def paged_attention(
     if has_limit:
         operands.append(jnp.asarray(pool_limit, jnp.int32).reshape(T))
     if E:
-        operands.append(jnp.asarray(extra_kv[2], jnp.int32).reshape(T, E))
+        operands.append(jnp.asarray(extra_kv[2], jnp.int32).reshape(T, 1, E))
     operands.append(q)
     operands.extend([k_cache, v_cache])
     if int8_pool:
@@ -337,10 +339,7 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, nh, d), q.dtype),
-        # pre-0.5 jax spells it TPUCompilerParams
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(
+        compiler_params=pltpu.CompilerParams(
             # tokens are independent (scratch re-inits at j==0) → megacore
             # can split the T dim; only the block dim accumulates
             dimension_semantics=("parallel", "arbitrary")

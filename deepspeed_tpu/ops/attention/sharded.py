@@ -2,7 +2,7 @@
 
 Two ways to run the Pallas flash kernel (ops/attention/flash_pallas.py) on a
 multi-device mesh — pallas_call is opaque to the GSPMD partitioner, so both
-wrap it in a fully-manual ``shard_map``:
+wrap it in a fully-manual ``shard_map`` (``topology.manual_over``):
 
   * :func:`head_sharded_flash` — splash-style: batch and heads are
     embarrassingly parallel for self-attention, so each device runs the
@@ -63,6 +63,7 @@ from deepspeed_tpu.parallel.topology import (
     MODEL_AXIS,
     SEQUENCE_AXIS,
     get_topology,
+    manual_over,
 )
 
 HEAD_AXES = (MODEL_AXIS, SEQUENCE_AXIS)
@@ -146,14 +147,7 @@ def head_sharded_flash(q, k, v, causal=True, segment_ids=None, scale=None,
                                alibi_positions=pos, window=window,
                                window_flag=wf, interpret=interpret)
 
-    fn = jax.shard_map(
-        body,
-        mesh=topo.mesh,
-        in_specs=(spec, spec, spec, *extra_specs),
-        out_specs=spec,
-        axis_names={*BATCH_AXES, *HEAD_AXES},
-        check_vma=False,
-    )
+    fn = manual_over(body, topo.mesh, (spec, spec, spec, *extra_specs), spec)
     return fn(q, k, v, *extra_ops)
 
 
@@ -221,14 +215,11 @@ def head_sharded_splash(q, k, v, schedule, segment_ids=None, scale=None,
         return _splash_core(q_, k_, v_, seg_, kvi_, kind_, kvi_t_, kind_t_,
                             base_, params)
 
-    fn = jax.shard_map(
-        body,
-        mesh=topo.mesh,
-        in_specs=(spec, spec, spec, sched_spec, sched_spec, sched_spec,
-                  sched_spec, P(None), *seg_specs),
-        out_specs=spec,
-        axis_names={*BATCH_AXES, *HEAD_AXES},
-        check_vma=False,
+    fn = manual_over(
+        body, topo.mesh,
+        (spec, spec, spec, sched_spec, sched_spec, sched_spec, sched_spec,
+         P(None), *seg_specs),
+        spec,
     )
     return fn(q, k, v, *sched_ops, base, *seg_ops)
 
@@ -509,12 +500,5 @@ def ring_flash_attention(q, k, v, causal=True, segment_ids=None, scale=None,
             interpret=interpret,
         )
 
-    fn = jax.shard_map(
-        body,
-        mesh=topo.mesh,
-        in_specs=(spec, spec, spec, *extra_specs),
-        out_specs=spec,
-        axis_names={*BATCH_AXES, *HEAD_AXES, CONTEXT_AXIS},
-        check_vma=False,
-    )
+    fn = manual_over(body, topo.mesh, (spec, spec, spec, *extra_specs), spec)
     return fn(q, k, v, *extra_ops)

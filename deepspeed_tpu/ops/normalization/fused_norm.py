@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.accelerator.device import on_tpu
+
 
 def rms_norm_reference(x, w, eps=1e-5):
     xf = x.astype(jnp.float32)
@@ -56,7 +58,7 @@ def fused_rms_norm(x, w, eps=1e-5, interpret=False):
 def _use_pallas(interpret):
     # single-shard gate only: multi-device dispatch happens in rms_norm(),
     # which runs this kernel per-shard under shard_map
-    return interpret or jax.default_backend() == "tpu"
+    return interpret or on_tpu()
 
 
 def _rows_view(x):
@@ -134,8 +136,6 @@ def _rms_bwd(eps, interpret, res, g):
 
 fused_rms_norm.defvjp(_rms_fwd, _rms_bwd)
 
-_SHARDED_FALLBACK_WARNED = False
-
 
 def rms_norm(x, w, eps=1e-5, interpret=False):
     """Mesh-aware RMSNorm entry point (the one model code should call).
@@ -143,7 +143,7 @@ def rms_norm(x, w, eps=1e-5, interpret=False):
     Single device: the Pallas kernel directly. Multi-device mesh: pallas_call
     is opaque to GSPMD, so the activation is pinned to the canonical layout
     (batch over data/expert, seq over sequence, h replicated) and the kernel
-    runs per-shard under partial-manual shard_map — same pattern as
+    runs per-shard under a fully-manual shard_map — same pattern as
     ops/attention/core._flash_sharded. shard_map is differentiable: w enters
     replicated (P()), so its cotangent is psum'd across shards by the
     transpose, and dx stays in the activation layout. Falls back to the jnp
@@ -167,36 +167,19 @@ def rms_norm(x, w, eps=1e-5, interpret=False):
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from deepspeed_tpu.parallel.topology import BATCH_AXES, SEQUENCE_AXIS
+    from deepspeed_tpu.parallel.topology import (
+        BATCH_AXES,
+        SEQUENCE_AXIS,
+        manual_over,
+    )
 
     spec = P(BATCH_AXES, SEQUENCE_AXIS, None)
     x = jax.lax.with_sharding_constraint(x, NamedSharding(topo.mesh, spec))
-    fn = jax.shard_map(
+    fn = manual_over(
         lambda x_, w_: fused_rms_norm(x_, w_, eps, interpret),
-        mesh=topo.mesh,
-        in_specs=(spec, P()),
-        out_specs=spec,
-        axis_names={*BATCH_AXES, SEQUENCE_AXIS},
-        check_vma=False,
+        topo.mesh, (spec, P()), spec,
     )
-    try:
-        return fn(x, w)
-    except Exception as e:
-        # e.g. nested-manual-axis contexts the current JAX can't compose;
-        # trace-time failure, so the jnp path is a safe same-semantics swap —
-        # but say so once, or a dead kernel path hides as an MFU regression
-        global _SHARDED_FALLBACK_WARNED
-        if not _SHARDED_FALLBACK_WARNED:
-            _SHARDED_FALLBACK_WARNED = True
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "sharded rms_norm kernel dispatch failed (%s: %s); "
-                "falling back to the jnp reference path",
-                type(e).__name__,
-                e,
-            )
-        return rms_norm_reference(x, w, eps)
+    return fn(x, w)
 
 
 def fused_layer_norm(x, w, b, eps=1e-5):

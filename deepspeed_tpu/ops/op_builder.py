@@ -60,8 +60,9 @@ def jit_native(name, sources, extra_flags=(), verbose=False):
     the .so path, rebuilding only when a source is newer than the artifact
     (reference ``OpBuilder.jit_load`` builder.py:544, minus ninja).
 
-    Returns None (with a logged warning) when the toolchain or compile fails —
-    callers fall back to their pure-Python path.
+    Raises ``RuntimeError`` when there is no toolchain or the compile fails:
+    a path that needs the op must not quietly take the pure-Python route (the
+    ``DSTPU_DISABLE_NATIVE_<NAME>`` switch is how to ask for that route).
     """
     srcs = [s if os.path.isabs(s) else os.path.join(_CSRC_DIR, s) for s in sources]
 
@@ -90,8 +91,7 @@ def jit_native(name, sources, extra_flags=(), verbose=False):
         try:
             proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         except (OSError, subprocess.TimeoutExpired) as e:  # no g++ / hung compile
-            logger.warning(f"native build of {name} unavailable: {e}")
-            return None
+            raise RuntimeError(f"native build of {name} unavailable: {e}") from e
         if proc.returncode != 0:
             logger.warning(f"native build of {name} with {list(flags)} failed:\n"
                            f"{proc.stderr[-2000:]}")
@@ -113,7 +113,11 @@ def jit_native(name, sources, extra_flags=(), verbose=False):
         out = compile_to(out_full, extra_flags)
         if out is None and extra_flags:
             out = out_base if fresh(out_base) else compile_to(out_base, base_flags)
-        if out is not None and verbose:
+        if out is None:
+            raise RuntimeError(
+                f"native build of {name} failed with and without {list(extra_flags)} "
+                "(compiler output logged above)")
+        if verbose:
             logger.info(f"built native op {name} -> {out}")
         return out
 
@@ -135,15 +139,16 @@ class NativeOpBuilder(OpBuilder):
     def is_compatible(self, verbose=False):
         # Cheap capability probe (reference ds_report semantics): do NOT
         # compile as a side effect — a toolchain or an already-built artifact
-        # means the op can load. A cached None means a FAILED build (or the
-        # kill switch): report incompatible, not available.
+        # means the op can load. A cached None means the kill switch: report
+        # incompatible, not available.
         if self.NAME in self._lib_cache:
             return self._lib_cache[self.NAME] is not None
         return shutil.which("g++") is not None
 
     @classmethod
     def lib(cls):
-        """Load (building if needed) and cache the CDLL; None => fallback."""
+        """Load (building if needed) and cache the CDLL. None only when the
+        ``DSTPU_DISABLE_NATIVE_<NAME>`` switch asked for the Python route."""
         if cls.NAME not in NativeOpBuilder._lib_cache:
             if os.environ.get(f"DSTPU_DISABLE_NATIVE_{cls.NAME.upper()}") == "1":
                 NativeOpBuilder._lib_cache[cls.NAME] = None
@@ -154,15 +159,8 @@ class NativeOpBuilder(OpBuilder):
     def _build(self):
         import ctypes
 
-        so = jit_native(self.NAME, self.SOURCES, self.EXTRA_FLAGS)
-        if so is None:
-            return None
-        try:
-            lib = ctypes.CDLL(so)
-            self._bind(lib)
-        except OSError as e:  # corrupt artifact — fall back to pure Python
-            logger.warning(f"native op {self.NAME} failed to load ({e}); using fallback")
-            return None
+        lib = ctypes.CDLL(jit_native(self.NAME, self.SOURCES, self.EXTRA_FLAGS))
+        self._bind(lib)
         return lib
 
     def _bind(self, lib):
@@ -187,15 +185,15 @@ def build_all_ops(verbose=True):
     results = {}
     for name, cls in sorted(ALL_OPS.items()):
         builder = cls()
-        if isinstance(builder, NativeOpBuilder):
-            results[name] = cls.lib() is not None
-        else:
-            try:
+        try:
+            if isinstance(builder, NativeOpBuilder):
+                results[name] = cls.lib() is not None
+            else:
                 builder.load(verbose=False)
                 results[name] = True
-            except Exception as e:
-                logger.warning(f"build_all_ops: {name} failed: {e!r}")
-                results[name] = False
+        except Exception as e:
+            logger.warning(f"build_all_ops: {name} failed: {e!r}")
+            results[name] = False
         if verbose:
             logger.info(f"build_all_ops: {name} -> {'ok' if results[name] else 'FAILED'}")
     return results

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.accelerator.device import on_tpu
 from deepspeed_tpu.ops.sparse_attention.mask import FULL
 from deepspeed_tpu.ops.sparse_attention.schedule import BlockSchedule
 
@@ -70,7 +71,7 @@ def _compiler_kwargs(params: _SplashParams):
     if params.interpret:
         return {}
     return {
-        "compiler_params": pltpu.TPUCompilerParams(
+        "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=params.vmem_limit,
         )
@@ -91,7 +92,7 @@ def _partial_mask(logits, kind, q_pos, k_pos, segq_ref, segk_ref, params):
         # THE shared band convention (core.window_too_far): out iff q-k >= w
         keep = _and(keep, (q_pos - k_pos) < params.window)
     if params.seg_mode == "schedule":
-        keep = _and(keep, segq_ref[:][:, None] == segk_ref[:][None, :])
+        keep = _and(keep, segq_ref[:, :1] == segk_ref[:])
     if keep is None:
         return logits
     return jnp.where(jnp.logical_or(kind == FULL, keep), logits, NEG_INF)
@@ -141,8 +142,7 @@ def _splash_fwd_kernel(kvi_ref, kind_ref, base_ref, *refs, params, hs_shared,
                                    segq_ref, segk_ref, params)
         if params.seg_mode == "all":
             # traced ids the schedule knows nothing about: every step masks
-            logits = jnp.where(
-                segq_ref[:][:, None] == segk_ref[:][None, :], logits, NEG_INF)
+            logits = jnp.where(segq_ref[:, :1] == segk_ref[:], logits, NEG_INF)
         m = m_sc[:, 0]
         l = l_sc[:, 0]
         m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
@@ -213,8 +213,7 @@ def _splash_bwd_dq_kernel(kvi_ref, kind_ref, base_ref, *refs, params,
             logits = _partial_mask(logits, kind, q_pos, k_pos,
                                    segq_ref, segk_ref, params)
         if params.seg_mode == "all":
-            logits = jnp.where(
-                segq_ref[:][:, None] == segk_ref[:][None, :], logits, NEG_INF)
+            logits = jnp.where(segq_ref[:, :1] == segk_ref[:], logits, NEG_INF)
         p = jnp.where(logits > NEG_INF / 2,
                       jnp.exp(logits - lse[:, None]), 0.0)
         dp = jax.lax.dot_general(
@@ -273,8 +272,7 @@ def _splash_bwd_dkv_kernel(qi_ref, kind_ref, base_ref, *refs, params,
             logits = _partial_mask(logits, kind, q_pos, k_pos,
                                    segq_ref, segk_ref, params)
         if params.seg_mode == "all":
-            logits = jnp.where(
-                segq_ref[:][:, None] == segk_ref[:][None, :], logits, NEG_INF)
+            logits = jnp.where(segq_ref[:, :1] == segk_ref[:], logits, NEG_INF)
         p = jnp.where(logits > NEG_INF / 2,
                       jnp.exp(logits - lse[:, None]), 0.0)
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
@@ -294,11 +292,25 @@ def _splash_bwd_dkv_kernel(qi_ref, kind_ref, base_ref, *refs, params,
 
 
 def _seg_ops_specs(seg, bq, q_map, bk, k_map):
-    """Segment-id operands + specs ([b, s] planes, streamed per block)."""
+    """Segment-id operands + specs, streamed per block. ``q_map``/``k_map``
+    give (batch, block) indices. Same layout as flash_pallas._seg_specs (a
+    (1, block) window of a [b, s] plane is not a legal Mosaic block once
+    b > 1): q ids lane-broadcast [b, s, LANES], k ids a [b, 1, s] row, and
+    the kernels compare [bq, 1] == [1, bk] by broadcast."""
     if seg is None:
         return [], []
-    ops = [seg, seg]
-    specs = [pl.BlockSpec((1, bq), q_map), pl.BlockSpec((1, bk), k_map)]
+
+    def q_idx(*a):
+        b_, i = q_map(*a)
+        return b_, i, 0
+
+    def k_idx(*a):
+        b_, j = k_map(*a)
+        return b_, 0, j
+
+    ops = [jnp.broadcast_to(seg[:, :, None], seg.shape + (LANES,)),
+           seg[:, None, :]]
+    specs = [pl.BlockSpec((1, bq, LANES), q_idx), pl.BlockSpec((1, 1, bk), k_idx)]
     return ops, specs
 
 
@@ -472,7 +484,7 @@ _splash_core.defvjp(_splash_vjp_fwd, _splash_vjp_bwd)
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return not on_tpu()
     return bool(interpret)
 
 

@@ -25,6 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.accelerator.device import on_tpu
 from deepspeed_tpu.parallel.sequence.ring import (
     NEG_INF,
     _local_attention_stats,
@@ -58,7 +59,7 @@ def fpdt_attention(
     """
     b, h, s, d = q.shape
     if offload_kv is None:
-        offload_kv = jax.default_backend() == "tpu"
+        offload_kv = on_tpu()
 
     sc = s // n_chunks
     qc = _chunk(q, n_chunks, 2).reshape(n_chunks, sc, -1)  # scan xs stay 3-D

@@ -209,6 +209,21 @@ def constrain(x, *spec):
         return x
 
 
+def manual_over(body, mesh, in_specs, out_specs):
+    """``jax.shard_map`` for wrapping GSPMD-opaque Pallas calls: manual over
+    EVERY mesh axis that is not already manual in an enclosing region, with
+    the specs naming only the axes the operands are split over. Mosaic
+    refuses a kernel under a map that leaves any axis to GSPMD, even one of
+    size 1 ("Mosaic kernels cannot be automatically partitioned"), and jax
+    0.9.0 rejects such a partial map outright when called eagerly. Jitted,
+    because an eager shard_map runs its body op by op; under an enclosing
+    jit that is an inlined call."""
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(mesh.axis_names) - _manual_axis_names(), check_vma=False,
+    ))
+
+
 _TOPOLOGY: Optional[Topology] = None
 
 

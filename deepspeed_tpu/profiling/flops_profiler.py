@@ -99,8 +99,6 @@ def analyze_fn(fn: Callable, *args, **kwargs) -> Dict[str, Any]:
     the jaxpr."""
     lowered = jax.jit(fn).lower(*args, **kwargs)
     cost = lowered.compile().cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # pre-0.5 jax: one dict per device
-        cost = cost[0] if cost else {}
     jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.accelerator.device import on_tpu
 from deepspeed_tpu.comm.logging import get_comms_logger
 from deepspeed_tpu.parallel.topology import (
     BATCH_AXES,
@@ -248,7 +249,7 @@ class DeepSpeedEngine:
         # XLA SPMD-partitioner RET_CHECK on memory-kind annotations, so it
         # stages state through device memory inside the step and parks it
         # back to pinned_host eagerly between steps (same semantics).
-        self._offload_native = jax.default_backend() == "tpu"
+        self._offload_native = on_tpu()
         # ZeRO-Infinity weight streaming (models/transformer.py weight_stream):
         # the MODEL stages one layer of host-resident weights per scan step
         # and its grads stream back to host — the engine must NOT whole-tree
@@ -466,8 +467,14 @@ class DeepSpeedEngine:
         else:
             if config.zero_optimization.zeropp_loco_param is not None:
                 self._loco_enabled()  # raises with the real reason
+            # placed on the mesh like the step's outputs: jax 0.9.0 types
+            # carry the mesh, and an unplaced placeholder made step 2
+            # retrace and recompile the whole train step
+            # (one buffer per leaf: the step donates them)
             self._loco_state = jax.tree.map(
-                lambda _: jnp.zeros((0,), jnp.bfloat16), self.params
+                lambda _: jax.device_put(
+                    jnp.zeros((0,), jnp.bfloat16), self.topo.replicated()),
+                self.params,
             )
 
         # timers / throughput
@@ -1837,8 +1844,6 @@ class DeepSpeedEngine:
         try:
             log_dist("flops profile: lowering step for cost analysis (one-time)", ranks=[0])
             cost = self._train_step_jit.lower(*args).compile().cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):  # pre-0.5 jax: per-device dicts
-                cost = cost[0] if cost else {}
         except Exception as e:  # profiling must never break training
             logger.warning(f"flops profile failed: {e}")
             return

@@ -35,8 +35,15 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from deepspeed_tpu._jax_compat import host_memory_kind
 from deepspeed_tpu.parallel.topology import DATA_AXIS, ZERO_AXES, Topology
+
+
+def host_memory_kind() -> Optional[str]:
+    """``"pinned_host"`` when the default device exposes that memory kind,
+    else ``None`` (NamedSharding reads ``memory_kind=None`` as the default
+    memory, so offload placements degrade to numerics-only)."""
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
+    return "pinned_host" if "pinned_host" in kinds else None
 
 
 def _spec_axes(spec: Optional[PartitionSpec]):

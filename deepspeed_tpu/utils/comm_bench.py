@@ -35,24 +35,11 @@ def main(argv=None) -> int:
                    help="force an N-device virtual CPU mesh (plumbing checks)")
     args = p.parse_args(argv)
 
-    if args.cpu_devices:
-        # pre-0.5 jax has no jax_num_cpu_devices option: the XLA flag (set
-        # before jax initializes its backend) covers both generations
-        import os
-
-        if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count={args.cpu_devices}"
-            ).strip()
     import jax
 
     if args.cpu_devices:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.cpu_devices)
-        except AttributeError:
-            pass  # XLA_FLAGS fallback above
+        jax.config.update("jax_num_cpu_devices", args.cpu_devices)
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 

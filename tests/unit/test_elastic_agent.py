@@ -31,10 +31,7 @@ os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={{world}}"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", world)
-except AttributeError:
-    pass  # pre-0.5 jax: the XLA_FLAGS fallback above covers it
+jax.config.update("jax_num_cpu_devices", world)
 import numpy as np
 import deepspeed_tpu
 

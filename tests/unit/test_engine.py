@@ -9,15 +9,15 @@ import optax
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu._jax_compat import host_memory_kind
 from deepspeed_tpu.parallel.topology import Topology, set_topology
+from deepspeed_tpu.runtime.zero.partition import host_memory_kind
 
 from tests.unit.simple_model import batch_of, make_mlp_params, mlp_loss_fn, random_dataset
 
 LR = 1e-2
 
-# None on runtimes whose CPU devices expose a single memory space (jax<0.5):
-# offload there is numerics-only — placement assertions don't apply
+# None on a device that exposes a single memory space: offload there is
+# numerics-only — placement assertions don't apply
 HOST_KIND = host_memory_kind()
 
 

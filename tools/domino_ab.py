@@ -10,18 +10,10 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    # pre-0.5 jax has no jax_num_cpu_devices; the flag must precede import
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-    ).strip()
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass  # XLA_FLAGS fallback above
+jax.config.update("jax_num_cpu_devices", 8)
 
 import jax.numpy as jnp
 import numpy as np
