@@ -1,0 +1,3 @@
+"""Layer: serving programs (v2/engine_v2.py). stage_ms_per_step in a cell at saturation, where
+throughput is judged (PERF.md section 2). Should move gen_tok_s."""
+from benchmarks.metrics.stage_ms_per_step import read  # noqa: F401

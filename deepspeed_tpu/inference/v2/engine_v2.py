@@ -68,6 +68,29 @@ def serving_benchmark(eng, n_seq=32, max_new=64, repeats=2, prompt_min=64,
     return best
 
 
+def _entry_array(entry, want_tokens: bool):
+    """(device array, row index or None) of one step-result entry: the
+    in-program token array instead of logits when ``want_tokens`` and
+    present; plain arrays (test doubles) have no row."""
+    if isinstance(entry, tuple):
+        return (entry[2] if (want_tokens and len(entry) > 2) else entry[0]), entry[1]
+    return entry, None
+
+
+def _start_host_copies(arrays) -> None:
+    """Ask for each array's copy to the host NOW, queued behind the program
+    that computes it, so a blocking wait that follows ends with the values
+    already on their way. Asked only after the wait, the copy costs one more
+    host round trip with the device idle (``np.asarray`` of an array still
+    being computed queues it the same way, which is what a step did before
+    its wait became a span of its own). Test doubles' numpy arrays have
+    nothing to copy."""
+    for arr in arrays:
+        start = getattr(arr, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+
 def _materialize_rows(res: dict, want_tokens: bool = False) -> dict:
     """{uid: (logits array, row[, token array])} -> {uid: host row}, pulling
     each distinct ARRAY from the device exactly once (rows of one step share
@@ -77,11 +100,7 @@ def _materialize_rows(res: dict, want_tokens: bool = False) -> dict:
     hosts = {}
     out = {}
     for uid, entry in res.items():
-        if isinstance(entry, tuple):
-            arr = entry[2] if (want_tokens and len(entry) > 2) else entry[0]
-            idx = entry[1]
-        else:
-            arr, idx = entry, None
+        arr, idx = _entry_array(entry, want_tokens)
         key = id(arr)
         if key not in hosts:
             # memoized by id(): each distinct device array transfers once
@@ -293,7 +312,12 @@ class InferenceEngineV2:
             self.state_manager.host_readmit = self._host_readmit
         self._spec_rr = 0  # rotation cursor for budget-capped spec rounds
         self.last_spec = {"drafted": 0, "accepted": 0, "per_uid": {}}
+        # what the last step or round was sized to and what it carried: the
+        # serving core folds these into the grid_slots_total /
+        # scheduled_tokens_total / steps_with_prefill_total counters
+        self.last_grid_slots = 0
         self.last_scheduled_tokens = 0
+        self.last_prefill_tokens = 0
         self.last_capped = set()
         # sampling state: one base key; programs fold in each row's (uid,
         # source position) so a token's key is content-addressed — invariant
@@ -1593,60 +1617,64 @@ class InferenceEngineV2:
             uids.append(uid)
         if not uids:
             return {}
+        self.last_grid_slots = R * n
+        self.last_scheduled_tokens = len(uids) * n
+        self.last_prefill_tokens = 0
         tr = get_tracer()
-        t0 = tr.now() if tr.enabled else 0.0
-        kv = self.config.kv_cache
-        B = kv.max_blocks_per_seq
-        trash = kv.num_blocks
-        tokens = np.zeros(R, np.int32)
-        positions = np.zeros(R, np.int32)
-        tables = np.full((R, B), trash, np.int32)
-        uid_arr = np.zeros(R, np.int32)
-        active = np.zeros(R, bool)
-        for i, uid in enumerate(uids):
-            seq = self.state_manager.get_sequence(uid)
-            tokens[i] = sched.peek_next_token(uid)
-            positions[i] = seq.seen_tokens
-            tables[i, : len(seq.block_table)] = seq.block_table
-            uid_arr[i] = uid
-            active[i] = True
-        if self._multistep_jit is None or self._multistep_n != n:
-            self._multistep_jit = self._build_multistep_decode(n)
-            self._multistep_n = n
-        outs = self._multistep_jit(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            jnp.asarray(uid_arr),
-            jnp.asarray(active),
-            self._rng,
-            jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
-            self._k_cache,
-            self._v_cache,
-            *self._scale_args(),
-        )
-        toks_out, logps_out, self._k_cache, self._v_cache = outs[:4]
-        if self._kv_int8:
-            self._ks_cache, self._vs_cache = outs[4], outs[5]
-        if tr.enabled:
-            # dispatch (staging + async launch) vs device wait, on this
-            # replica's engine track
-            track = getattr(self, "_trace_name", "engine")
-            tr.complete("engine.dispatch", t0, track=track,
-                        args={"rows": len(uids), "steps": n})
-            t1 = tr.now()
+        track = getattr(self, "_trace_name", "engine")
+        # dispatch (staging + async launch) vs device wait, on this
+        # replica's engine track
+        with tr.span("engine.dispatch", track=track,
+                     args={"rows": len(uids), "steps": n} if tr.enabled else None):
+            with tr.span("engine.stage", track=track):
+                kv = self.config.kv_cache
+                B = kv.max_blocks_per_seq
+                trash = kv.num_blocks
+                tokens = np.zeros(R, np.int32)
+                positions = np.zeros(R, np.int32)
+                tables = np.full((R, B), trash, np.int32)
+                uid_arr = np.zeros(R, np.int32)
+                active = np.zeros(R, bool)
+                for i, uid in enumerate(uids):
+                    seq = self.state_manager.get_sequence(uid)
+                    tokens[i] = sched.peek_next_token(uid)
+                    positions[i] = seq.seen_tokens
+                    tables[i, : len(seq.block_table)] = seq.block_table
+                    uid_arr[i] = uid
+                    active[i] = True
+            with tr.span("engine.launch", track=track):
+                if self._multistep_jit is None or self._multistep_n != n:
+                    self._multistep_jit = self._build_multistep_decode(n)
+                    self._multistep_n = n
+                outs = self._multistep_jit(
+                    self.params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    jnp.asarray(tables),
+                    jnp.asarray(uid_arr),
+                    jnp.asarray(active),
+                    self._rng,
+                    jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
+                    self._k_cache,
+                    self._v_cache,
+                    *self._scale_args(),
+                )
+                toks_out, logps_out, self._k_cache, self._v_cache = outs[:4]
+                if self._kv_int8:
+                    self._ks_cache, self._vs_cache = outs[4], outs[5]
+        _start_host_copies((toks_out, logps_out))
+        with tr.span("engine.device_wait", track=track):
             device_synchronize((toks_out, logps_out))
-            tr.complete("engine.device_wait", t1, track=track)
-        toks_out = np.asarray(toks_out)  # [n, R]
-        logps_out = np.asarray(logps_out)
         results: Dict[int, np.ndarray] = {}
         self.last_logprobs = {}
-        for i, uid in enumerate(uids):
-            gen = toks_out[:, i]
-            sched.apply_decode_round(uid, gen)
-            results[uid] = gen
-            self.last_logprobs[uid] = logps_out[:, i]
+        with tr.span("engine.materialize", track=track):
+            toks_out = np.asarray(toks_out)  # [n, R]
+            logps_out = np.asarray(logps_out)
+            for i, uid in enumerate(uids):
+                gen = toks_out[:, i]
+                sched.apply_decode_round(uid, gen)
+                results[uid] = gen
+                self.last_logprobs[uid] = logps_out[:, i]
         return results
 
     # ------------------------------------------------------------------
@@ -1847,70 +1875,74 @@ class InferenceEngineV2:
             pre_blocks[uid] = pre
         if not uids:
             return {}
-        kv = self.config.kv_cache
-        B = kv.max_blocks_per_seq
-        trash = kv.num_blocks
-        tokens = np.zeros((R, K1), np.int32)
-        positions = np.zeros(R, np.int32)
-        tables = np.full((R, B), trash, np.int32)
-        uid_arr = np.zeros(R, np.int32)
-        active = np.zeros(R, bool)
-        n_input = np.ones(R, np.int32)
-        for i, (uid, d) in enumerate(zip(uids, row_drafts)):
-            seq = self.state_manager.get_sequence(uid)
-            tokens[i, 0] = sched.peek_next_token(uid)
-            if d:
-                tokens[i, 1 : 1 + len(d)] = d
-            positions[i] = seq.seen_tokens
-            tables[i, : len(seq.block_table)] = seq.block_table
-            uid_arr[i] = uid
-            active[i] = True
-            n_input[i] = 1 + len(d)
-        if k not in self._verify_jit:
-            self._verify_jit[k] = self._build_verify_step(k)
+        self.last_grid_slots = R * K1
+        self.last_scheduled_tokens = len(uids) + sum(len(d) for d in row_drafts)
+        self.last_prefill_tokens = 0
         tr = get_tracer()
-        t0 = tr.now() if tr.enabled else 0.0
-        outs = self._verify_jit[k](
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            jnp.asarray(uid_arr),
-            jnp.asarray(active),
-            jnp.asarray(n_input),
-            self._rng,
-            jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
-            self._k_cache,
-            self._v_cache,
-            *self._scale_args(),
-        )
-        tgt, n_emit, logp, self._k_cache, self._v_cache = outs[:5]
-        if self._kv_int8:
-            self._ks_cache, self._vs_cache = outs[5], outs[6]
-        if tr.enabled:
-            track = getattr(self, "_trace_name", "engine")
-            tr.complete("engine.dispatch", t0, track=track,
-                        args={"rows": len(uids), "k": k})
-            t1 = tr.now()
+        track = getattr(self, "_trace_name", "engine")
+        with tr.span("engine.dispatch", track=track,
+                     args={"rows": len(uids), "k": k} if tr.enabled else None):
+            with tr.span("engine.stage", track=track):
+                kv = self.config.kv_cache
+                B = kv.max_blocks_per_seq
+                trash = kv.num_blocks
+                tokens = np.zeros((R, K1), np.int32)
+                positions = np.zeros(R, np.int32)
+                tables = np.full((R, B), trash, np.int32)
+                uid_arr = np.zeros(R, np.int32)
+                active = np.zeros(R, bool)
+                n_input = np.ones(R, np.int32)
+                for i, (uid, d) in enumerate(zip(uids, row_drafts)):
+                    seq = self.state_manager.get_sequence(uid)
+                    tokens[i, 0] = sched.peek_next_token(uid)
+                    if d:
+                        tokens[i, 1 : 1 + len(d)] = d
+                    positions[i] = seq.seen_tokens
+                    tables[i, : len(seq.block_table)] = seq.block_table
+                    uid_arr[i] = uid
+                    active[i] = True
+                    n_input[i] = 1 + len(d)
+            with tr.span("engine.launch", track=track):
+                if k not in self._verify_jit:
+                    self._verify_jit[k] = self._build_verify_step(k)
+                outs = self._verify_jit[k](
+                    self.params,
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    jnp.asarray(tables),
+                    jnp.asarray(uid_arr),
+                    jnp.asarray(active),
+                    jnp.asarray(n_input),
+                    self._rng,
+                    jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
+                    self._k_cache,
+                    self._v_cache,
+                    *self._scale_args(),
+                )
+                tgt, n_emit, logp, self._k_cache, self._v_cache = outs[:5]
+                if self._kv_int8:
+                    self._ks_cache, self._vs_cache = outs[5], outs[6]
+        _start_host_copies((tgt, n_emit, logp))
+        with tr.span("engine.device_wait", track=track):
             device_synchronize((tgt, n_emit, logp))
-            tr.complete("engine.device_wait", t1, track=track)
-        tgt = np.asarray(tgt)
-        n_emit = np.asarray(n_emit)
-        logp = np.asarray(logp)
         results: Dict[int, np.ndarray] = {}
         self.last_logprobs = {}
         drafted_total = accepted_total = 0
         per_uid: Dict[int, Tuple[int, int]] = {}
-        for i, uid in enumerate(uids):
-            n = int(n_emit[i])
-            gen = tgt[i, :n].astype(np.int32)
-            sched.apply_spec_round(uid, gen, pre_blocks[uid])
-            results[uid] = gen
-            self.last_logprobs[uid] = logp[i, :n]
-            d, a = int(n_input[i]) - 1, n - 1
-            drafted_total += d
-            accepted_total += a
-            per_uid[uid] = (d, a)
+        with tr.span("engine.materialize", track=track):
+            tgt = np.asarray(tgt)
+            n_emit = np.asarray(n_emit)
+            logp = np.asarray(logp)
+            for i, uid in enumerate(uids):
+                n = int(n_emit[i])
+                gen = tgt[i, :n].astype(np.int32)
+                sched.apply_spec_round(uid, gen, pre_blocks[uid])
+                results[uid] = gen
+                self.last_logprobs[uid] = logp[i, :n]
+                d, a = int(n_input[i]) - 1, n - 1
+                drafted_total += d
+                accepted_total += a
+                per_uid[uid] = (d, a)
         self.last_spec = {
             "drafted": drafted_total, "accepted": accepted_total,
             "per_uid": per_uid,
@@ -1939,32 +1971,34 @@ class InferenceEngineV2:
         the engine's static sampling config), never a host argmax, so driven
         serving reproduces ``generate()`` token-for-token.
 
-        When tracing is on, the step is bracketed into an ``engine.dispatch``
-        span (host-side staging + async program launch) and an
-        ``engine.device_wait`` span (blocking on the result arrays), so
+        The step is bracketed into an ``engine.dispatch`` span (host-side
+        scheduling and staging + async program launch: ``_step_device``
+        nests ``engine.schedule`` / ``engine.stage`` / ``engine.launch`` in
+        it), an ``engine.device_wait`` span (blocking on the result arrays)
+        and an ``engine.materialize`` span (tokens to the host), so
         host-side queueing and device time separate on the timeline. The
-        hooks deliberately wrap the CALLER of ``_step_device`` — that
-        function itself must stay sync-free so ``generate()``'s prefill
-        pipelining is untouched."""
+        token arrays' host copy is requested between dispatch and the wait,
+        in neither span. One path, traced or not: the null tracer's spans
+        are a shared no-op.
+        The wait wraps the CALLER of ``_step_device`` — that function
+        itself must stay sync-free so ``generate()``'s prefill pipelining
+        is untouched."""
         tr = get_tracer()
-        if not tr.enabled:
-            out: Dict[int, int] = {}
-            for uid, tok in _materialize_rows(self._step_device(), want_tokens=True).items():
-                out[uid] = int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
-            return out
         track = getattr(self, "_trace_name", "engine")
-        t0 = tr.now()
-        res = self._step_device()
-        tr.complete("engine.dispatch", t0, track=track, args={
-            "rows": len(res),
-            "tokens": int(getattr(self, "last_scheduled_tokens", 0) or 0),
-        })
-        t1 = tr.now()
-        device_synchronize(list(res.values()))
-        tr.complete("engine.device_wait", t1, track=track)
-        out = {}
-        for uid, tok in _materialize_rows(res, want_tokens=True).items():
-            out[uid] = int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
+        with tr.span("engine.dispatch", track=track) as sp:
+            res = self._step_device()
+            if tr.enabled:
+                sp.args = {"rows": len(res), "tokens": self.last_scheduled_tokens}
+        # the arrays the tokens come from (rows of one step share them)
+        waited = list({id(a): a for a in (
+            _entry_array(e, True)[0] for e in res.values())}.values())
+        _start_host_copies(waited)
+        with tr.span("engine.device_wait", track=track):
+            device_synchronize(waited)
+        out: Dict[int, int] = {}
+        with tr.span("engine.materialize", track=track):
+            for uid, tok in _materialize_rows(res, want_tokens=True).items():
+                out[uid] = int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
         return out
 
     def _step_device(self) -> Dict[int, jax.Array]:
@@ -1974,114 +2008,122 @@ class InferenceEngineV2:
         token) completed — no host sync happens here, so prefill steps
         pipeline behind the host round trip (~90 ms on r05's host) instead
         of paying it each."""
-        batch = self.scheduler.next_batch()
-        self.last_scheduled_tokens = batch.total_tokens if batch is not None else 0
-        self.last_capped |= self.scheduler.drain_capped()
+        tr = get_tracer()
+        track = getattr(self, "_trace_name", "engine")
+        with tr.span("engine.schedule", track=track):
+            batch = self.scheduler.next_batch()
+            self.last_capped |= self.scheduler.drain_capped()
+        self.last_grid_slots = self.last_scheduled_tokens = self.last_prefill_tokens = 0
         if batch is None:
             return {}
-        kv = self.config.kv_cache
-        sm = self.config.state_manager
-        R = sm.max_ragged_sequence_count
-        Rc = self.scheduler.max_prompt_chunks
-        B = kv.max_blocks_per_seq
-        bs = kv.block_size
-        trash = kv.num_blocks
+        with tr.span("engine.stage", track=track):
+            kv = self.config.kv_cache
+            sm = self.config.state_manager
+            R = sm.max_ragged_sequence_count
+            Rc = self.scheduler.max_prompt_chunks
+            B = kv.max_blocks_per_seq
+            bs = kv.block_size
+            trash = kv.num_blocks
 
-        dec_rows = [
-            (uid, toks, start)
-            for uid, toks, start, dec in zip(
-                batch.uids, batch.tokens, batch.start_positions, batch.is_decode
-            )
-            if dec
-        ]
-        chk_rows = [
-            (uid, toks, start, chunked)
-            for uid, toks, start, chunked, dec in zip(
-                batch.uids, batch.tokens, batch.start_positions,
-                batch.is_prompt_chunk, batch.is_decode,
-            )
-            if not dec
-        ]
-        if len(dec_rows) > R or len(chk_rows) > Rc:
-            raise RuntimeError(
-                f"split-phase batch overflow: {len(dec_rows)} decode rows "
-                f"(cap {R}), {len(chk_rows)} prompt chunks (cap {Rc})"
-            )
-        max_chunk = max((len(t) for _, t, _, _ in chk_rows), default=1)
-        # chunk-length buckets: two shapes keep short prompts off the full
-        # prompt_chunk pad without a compile per ragged length
-        tq = 128 if max_chunk <= 128 else self.scheduler.prompt_chunk
-        tq = min(tq, self.scheduler.prompt_chunk)
-        T_ = R + Rc * tq
-
-        tokens = np.zeros(T_, np.int32)
-        positions = np.zeros(T_, np.int32)
-        blk = np.full(T_, trash, np.int32)
-        row = np.zeros(T_, np.int32)
-        dec_tables = np.full((R, B), trash, np.int32)
-        dec_pos = np.full(R, -1, np.int32)  # -1 = inactive slot (masks all)
-        dec_uids = np.zeros(R, np.int32)
-        chk_tables = np.full((Rc, B), trash, np.int32)
-        chk_pos = np.full((Rc, tq), -1, np.int32)
-        chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
-        chk_last = np.zeros(Rc, np.int32)
-        chk_uids = np.zeros(Rc, np.int32)
-
-        for i, (uid, toks, start) in enumerate(dec_rows):
-            seq = self.state_manager.get_sequence(uid)
-            tokens[i] = toks[0]
-            positions[i] = start
-            nblk = len(seq.block_table)
-            dec_tables[i, :nblk] = seq.block_table
-            dec_pos[i] = start
-            dec_uids[i] = uid
-            blk[i] = seq.block_table[min(start // bs, nblk - 1)]
-            row[i] = start % bs
-        for j, (uid, toks, start, _chunked) in enumerate(chk_rows):
-            seq = self.state_manager.get_sequence(uid)
-            n = len(toks)
-            off = R + j * tq
-            tokens[off : off + n] = toks
-            pos = start + np.arange(n)
-            positions[off : off + n] = pos
-            nblk = len(seq.block_table)
-            chk_tables[j, :nblk] = seq.block_table
-            chk_pos[j, :n] = pos
-            chk_start[j] = start
-            chk_uids[j] = uid
-            # host-side scheduler metadata, not a device value
-            blk[off : off + n] = np.asarray(seq.block_table, np.int32)[  # dstpu: noqa[host-sync-in-loop]
-                np.minimum(pos // bs, nblk - 1)
+            dec_rows = [
+                (uid, toks, start)
+                for uid, toks, start, dec in zip(
+                    batch.uids, batch.tokens, batch.start_positions, batch.is_decode
+                )
+                if dec
             ]
-            row[off : off + n] = pos % bs
-            chk_last[j] = off + n - 1
+            chk_rows = [
+                (uid, toks, start, chunked)
+                for uid, toks, start, chunked, dec in zip(
+                    batch.uids, batch.tokens, batch.start_positions,
+                    batch.is_prompt_chunk, batch.is_decode,
+                )
+                if not dec
+            ]
+            if len(dec_rows) > R or len(chk_rows) > Rc:
+                raise RuntimeError(
+                    f"split-phase batch overflow: {len(dec_rows)} decode rows "
+                    f"(cap {R}), {len(chk_rows)} prompt chunks (cap {Rc})"
+                )
+            max_chunk = max((len(t) for _, t, _, _ in chk_rows), default=1)
+            # chunk-length buckets: two shapes keep short prompts off the full
+            # prompt_chunk pad without a compile per ragged length
+            tq = 128 if max_chunk <= 128 else self.scheduler.prompt_chunk
+            tq = min(tq, self.scheduler.prompt_chunk)
+            T_ = R + Rc * tq
+            self.last_grid_slots = T_
+            self.last_scheduled_tokens = batch.total_tokens
+            self.last_prefill_tokens = sum(len(t) for _, t, _, _ in chk_rows)
 
-        if tq not in self._split_jit:
-            self._split_jit[tq] = self._build_split_step(tq)
-        outs = self._split_jit[tq](
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(blk),
-            jnp.asarray(row),
-            jnp.asarray(dec_tables),
-            jnp.asarray(dec_pos),
-            jnp.asarray(dec_uids),
-            jnp.asarray(chk_tables),
-            jnp.asarray(chk_pos),
-            jnp.asarray(chk_start),
-            jnp.asarray(chk_last),
-            jnp.asarray(chk_uids),
-            self._rng,
-            jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
-            self._k_cache,
-            self._v_cache,
-            *self._scale_args(),
-        )
-        (logits_dec, logits_chk, toks_dec, toks_chk,
-         self._k_cache, self._v_cache) = outs[:6]
-        if self._kv_int8:
-            self._ks_cache, self._vs_cache = outs[6], outs[7]
+            tokens = np.zeros(T_, np.int32)
+            positions = np.zeros(T_, np.int32)
+            blk = np.full(T_, trash, np.int32)
+            row = np.zeros(T_, np.int32)
+            dec_tables = np.full((R, B), trash, np.int32)
+            dec_pos = np.full(R, -1, np.int32)  # -1 = inactive slot (masks all)
+            dec_uids = np.zeros(R, np.int32)
+            chk_tables = np.full((Rc, B), trash, np.int32)
+            chk_pos = np.full((Rc, tq), -1, np.int32)
+            chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
+            chk_last = np.zeros(Rc, np.int32)
+            chk_uids = np.zeros(Rc, np.int32)
+
+            for i, (uid, toks, start) in enumerate(dec_rows):
+                seq = self.state_manager.get_sequence(uid)
+                tokens[i] = toks[0]
+                positions[i] = start
+                nblk = len(seq.block_table)
+                dec_tables[i, :nblk] = seq.block_table
+                dec_pos[i] = start
+                dec_uids[i] = uid
+                blk[i] = seq.block_table[min(start // bs, nblk - 1)]
+                row[i] = start % bs
+            for j, (uid, toks, start, _chunked) in enumerate(chk_rows):
+                seq = self.state_manager.get_sequence(uid)
+                n = len(toks)
+                off = R + j * tq
+                tokens[off : off + n] = toks
+                pos = start + np.arange(n)
+                positions[off : off + n] = pos
+                nblk = len(seq.block_table)
+                chk_tables[j, :nblk] = seq.block_table
+                chk_pos[j, :n] = pos
+                chk_start[j] = start
+                chk_uids[j] = uid
+                # host-side scheduler metadata, not a device value
+                blk[off : off + n] = np.asarray(seq.block_table, np.int32)[  # dstpu: noqa[host-sync-in-loop]
+                    np.minimum(pos // bs, nblk - 1)
+                ]
+                row[off : off + n] = pos % bs
+                chk_last[j] = off + n - 1
+
+        with tr.span("engine.launch", track=track):
+            if tq not in self._split_jit:
+                self._split_jit[tq] = self._build_split_step(tq)
+            outs = self._split_jit[tq](
+                self.params,
+                jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(blk),
+                jnp.asarray(row),
+                jnp.asarray(dec_tables),
+                jnp.asarray(dec_pos),
+                jnp.asarray(dec_uids),
+                jnp.asarray(chk_tables),
+                jnp.asarray(chk_pos),
+                jnp.asarray(chk_start),
+                jnp.asarray(chk_last),
+                jnp.asarray(chk_uids),
+                self._rng,
+                jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
+                self._k_cache,
+                self._v_cache,
+                *self._scale_args(),
+            )
+            (logits_dec, logits_chk, toks_dec, toks_chk,
+             self._k_cache, self._v_cache) = outs[:6]
+            if self._kv_int8:
+                self._ks_cache, self._vs_cache = outs[6], outs[7]
         # rows are referenced as (logits array, row index, greedy-token
         # array): slicing logits_dec[i] here would issue one tiny device op
         # per completed row per step — at r05's ~90 ms round trip those

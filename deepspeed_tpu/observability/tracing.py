@@ -28,6 +28,14 @@ returns the shared :data:`_NULL_SPAN` singleton (its own no-op context
 manager), so ``with get_tracer().span(...):`` costs no allocation when
 tracing is off — callers only guard *args construction* behind
 ``tracer.enabled``.
+
+Profiler timeline: a ring span opened with ``SpanTracer.span()`` also
+enters a ``jax.profiler.TraceAnnotation`` named ``"dstpu." + name``, so a
+process traced under ``jax.profiler`` / xprof shows the program's phases
+(``dstpu.engine.stage``, ``dstpu.loop.bookkeeping``, ...) on the host
+line above the device's ops.  It follows from tracing being on: outside a
+profiler session the annotation is a no-op check, and where ``jax``
+cannot be imported the tracer records without it.
 """
 
 from __future__ import annotations
@@ -93,20 +101,37 @@ class Span:
 
 
 class _SpanHandle:
-    """Context manager returned by ``SpanTracer.span``."""
+    """Context manager returned by ``SpanTracer.span``; ``annotation`` is
+    the profiler-timeline twin of a ring span (None for request spans)."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
-    def __init__(self, tracer: "SpanTracer", span: Span):
+    def __init__(self, tracer: "SpanTracer", span: Span, annotation=None):
         self._tracer = tracer
         self.span = span
+        self._annotation = annotation
 
     def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         self._tracer.end(self.span)
         return False
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where ``jax`` cannot be
+    imported (this module stays importable, and the tracer usable, without
+    it)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class _NullSpan:
@@ -213,6 +238,10 @@ class SpanTracer:
         self._e2e_samples: deque = deque(maxlen=self.RESERVOIR)
         self.dropped_traces = 0
         self.dropped_spans = 0
+        # ring spans pushed out by newer ones: normal wrap of a long run,
+        # kept apart from request-tree spans lost to the budget above
+        self.ring_evicted_spans = 0
+        self._annotation = _trace_annotation()
 
     # ---- clock ----------------------------------------------------------
 
@@ -261,6 +290,8 @@ class SpanTracer:
                     self.dropped_spans += 1
                 return sp
             sp = Span(sid, pid, name, track or "engine", t0, args)
+            if len(self._ring) == self.max_events:
+                self.ring_evicted_spans += 1  # the deque drops its oldest
             self._ring.append(sp)
             return sp
 
@@ -294,9 +325,13 @@ class SpanTracer:
     def span(self, name: str, key=None, parent: Optional[Span] = None,
              track: Optional[str] = None,
              args: Optional[dict] = None) -> _SpanHandle:
-        """``with tracer.span("round.fused", args={...}) as sp:``"""
+        """``with tracer.span("engine.stage") as sp:`` — a ring span
+        (``key=None``) is also put on the profiler's timeline."""
+        annotation = None
+        if key is None and self._annotation is not None:
+            annotation = self._annotation("dstpu." + name)
         return _SpanHandle(self, self.start(key, name, parent=parent,
-                                            track=track, args=args))
+                                            track=track, args=args), annotation)
 
     # ---- trace completion / retention ----------------------------------
 
@@ -393,6 +428,7 @@ class SpanTracer:
                 "ring_spans": len(self._ring),
                 "dropped_traces": self.dropped_traces,
                 "dropped_spans": self.dropped_spans,
+                "ring_evicted_spans": self.ring_evicted_spans,
             }
 
 
@@ -427,17 +463,13 @@ class TraceContext:
     lifecycle *phase* span (queued | prefill | decode | preempted), so
     round/handoff spans can parent onto the phase they occurred in."""
 
-    __slots__ = ("uid", "tracer", "root", "phase", "t_first")
+    __slots__ = ("uid", "tracer", "root", "phase")
 
     def __init__(self, uid, tracer, root: Span, phase: Span):
         self.uid = uid
         self.tracer = tracer
         self.root = root
         self.phase = phase
-        # first-token stamp, recorded at the prefill->decode switch so the
-        # ServingMetrics.observe_trace bridge reads latencies off the SPAN
-        # endpoints rather than re-deriving them from the Request
-        self.t_first: Optional[float] = None
 
     def _switch_phase(self, name: str, t: Optional[float] = None,
                       args: Optional[dict] = None) -> Span:
@@ -485,7 +517,6 @@ def mark_first_token(req):
     ctx = req.trace
     if ctx is None:
         return
-    ctx.t_first = req.t_first_token
     ctx._switch_phase("decode", t=req.t_first_token)
 
 

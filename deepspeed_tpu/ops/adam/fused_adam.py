@@ -17,6 +17,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.accelerator.device import on_tpu
 
+# names of the Mosaic custom calls in a device trace (metadata only)
+FUSED_ADAM = "dstpu_fused_adam"
+
 
 class AdamParams(NamedTuple):
     lr: float = 1e-3
@@ -120,6 +123,7 @@ def fused_adam_step(
             jax.ShapeDtypeStruct(shape2, jnp.float32),
         ],
         interpret=interpret,
+        name=FUSED_ADAM,
     )(lr, c1, c2, p, g, mm, vv)
     unflat = lambda a: a.reshape(-1)[:n].reshape(orig_shape)
     return unflat(p_new), unflat(m_new), unflat(v_new)

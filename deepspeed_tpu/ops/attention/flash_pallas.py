@@ -42,6 +42,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+# The kernels' names in a device trace (``pallas_call(name=...)`` names the Mosaic
+# custom call); metadata only. benchmarks/metrics readers find them by these.
+FLASH_FWD = "dstpu_flash_fwd"
+FLASH_BWD_DQ = "dstpu_flash_bwd_dq"
+FLASH_BWD_DKV = "dstpu_flash_bwd_dkv"
+FLASH_FWD_CHUNK = "dstpu_flash_fwd_chunk"
+FLASH_BWD_DQ_CHUNK = "dstpu_flash_bwd_dq_chunk"
+FLASH_BWD_DKV_CHUNK = "dstpu_flash_bwd_dkv_chunk"
 
 
 def _alibi_term(alibi_ref, kpos_ref):
@@ -497,6 +505,7 @@ def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, inter
             pltpu.VMEM((bq, d), jnp.float32),      # output accumulator
         ],
         interpret=interpret,
+        name=FLASH_FWD,
     )(q, k, v, *seg_ops, *alibi_ops, *wf_ops)
     return out, lse
 
@@ -577,6 +586,7 @@ def _flash_bwd(causal, scale, window, interpret, res, g):
             pltpu.VMEM((bq, d), jnp.float32),      # dq accumulator
         ],
         interpret=interpret,
+        name=FLASH_BWD_DQ,
     )(q, k, v, out, g, lse, *seg_ops, *alibi_ops, *wf_ops)
 
     # dk/dv computed per q-head (reduced over the GQA group after), with the
@@ -630,6 +640,7 @@ def _flash_bwd(causal, scale, window, interpret, res, g):
             pltpu.VMEM((bk, d), jnp.float32),  # dv accumulator
         ],
         interpret=interpret,
+        name=FLASH_BWD_DKV,
     )(q, k, v, out, g, lse, *dkv_seg_ops, *dkv_alibi_ops, *wf_ops)
 
     if group > 1:
@@ -771,6 +782,7 @@ def flash_fwd_chunk(q, k, v, carry, segment_ids=None, alibi=None,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_FWD_CHUNK,
     )(q, k, v, m, l, acc, *seg_ops, *alibi_ops)
     return m_out, l_out, acc_out
 
@@ -832,6 +844,7 @@ def flash_dq_chunk(q, k, v, out, do, lse, dq_acc, segment_ids=None,
             pltpu.VMEM((bq, d), jnp.float32),      # dq accumulator
         ],
         interpret=interpret,
+        name=FLASH_BWD_DQ_CHUNK,
     )(q, k, v, out, do, lse, dq_acc, *seg_ops, *alibi_ops)
 
 
@@ -897,6 +910,7 @@ def flash_dkv_chunk(q, k, v, out, do, lse, dk_acc, dv_acc, segment_ids=None,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_BWD_DKV_CHUNK,
     )(q, k, v, out, do, lse, dk_acc, dv_acc, *seg_ops, *alibi_ops)
 
 

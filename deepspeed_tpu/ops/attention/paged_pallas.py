@@ -38,6 +38,9 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.accelerator.device import on_tpu
 
 NEG_INF = -1e30
+# The kernels' names in a device trace (``pallas_call(name=...)`` names the Mosaic
+# custom call); metadata only. benchmarks/metrics readers find them by these.
+PAGED_DECODE = "dstpu_paged_decode"
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables, q_pos, trash_block,
@@ -345,6 +348,7 @@ def paged_attention(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=PAGED_DECODE,
     )(*operands)
 
 

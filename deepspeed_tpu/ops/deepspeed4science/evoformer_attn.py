@@ -22,6 +22,10 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 LANES = 128
+# names of the Mosaic custom calls in a device trace (metadata only)
+EVOFORMER_FWD = "dstpu_evoformer_fwd"
+EVOFORMER_BWD_DQ = "dstpu_evoformer_bwd_dq"
+EVOFORMER_BWD_DKV = "dstpu_evoformer_bwd_dkv"
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, *, scale, bq, bk):
@@ -163,6 +167,7 @@ def _evo_call(q, k, v, bias, scale, interpret):
             jax.ShapeDtypeStruct((b, h, s, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name=EVOFORMER_FWD,
     )(q, k, v, bias)
     return out, lse
 
@@ -203,6 +208,7 @@ def _evo_bwd(scale, interpret, res, g):
             jax.ShapeDtypeStruct((b, h, s, s), jnp.float32),
         ],
         interpret=interpret,
+        name=EVOFORMER_BWD_DQ,
     )(q, k, v, bias, out, g, lse)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale, bq=bq, bk=bk)
@@ -230,6 +236,7 @@ def _evo_bwd(scale, interpret, res, g):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
         ],
         interpret=interpret,
+        name=EVOFORMER_BWD_DKV,
     )(q, k, v, bias, out, g, lse)
     return dq, dk, dv, dbias
 

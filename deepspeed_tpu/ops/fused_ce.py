@@ -25,6 +25,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128
+# names of the Mosaic custom calls in a device trace (metadata only)
+CE_FWD = "dstpu_ce_fwd"
+CE_BWD_DX = "dstpu_ce_bwd_dx"
+CE_BWD_DW = "dstpu_ce_bwd_dw"
 
 
 def _pick(n, target, multiple=1):
@@ -197,6 +201,7 @@ def _ce_call(x, w, labels, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((bn, 3 * LANES), jnp.float32)],
         interpret=interpret,
+        name=CE_FWD,
     )(x, w, labels.astype(jnp.int32).reshape(1, -1))
     return loss[:, 0], lse
 
@@ -237,6 +242,7 @@ def _ce_bwd(interpret, res, g):
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((bn, h), jnp.float32)],
         interpret=interpret,
+        name=CE_BWD_DX,
     )(x, w, lbl2, lse, g2)
 
     dw = pl.pallas_call(
@@ -253,6 +259,7 @@ def _ce_bwd(interpret, res, g):
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
         scratch_shapes=[pltpu.VMEM((h, bv_w), jnp.float32)],
         interpret=interpret,
+        name=CE_BWD_DW,
     )(x, w, lbl2, lse, g2)
     return dx, dw, None  # labels get no cotangent
 

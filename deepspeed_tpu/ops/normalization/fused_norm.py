@@ -14,6 +14,10 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.accelerator.device import on_tpu
 
+# names of the Mosaic custom calls in a device trace (metadata only)
+RMSNORM_FWD = "dstpu_rmsnorm_fwd"
+RMSNORM_BWD = "dstpu_rmsnorm_bwd"
+
 
 def rms_norm_reference(x, w, eps=1e-5):
     xf = x.astype(jnp.float32)
@@ -96,6 +100,7 @@ def _rms_fwd(x, w, eps, interpret):
         out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
         interpret=interpret,
+        name=RMSNORM_FWD,
     )(x2, w)
     return out.reshape(shape), (x, w)
 
@@ -125,6 +130,7 @@ def _rms_bwd(eps, interpret, res, g):
         out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h), x.dtype),
         interpret=interpret,
+        name=RMSNORM_BWD,
     )(x2, w, g2)
     # dw reduction is one fused elementwise+sum in XLA; keeping it out of the
     # kernel avoids the (8,128) output-tile constraint on the [1, h] partial

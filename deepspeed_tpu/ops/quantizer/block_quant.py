@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 
 _QMAX = {8: 127.0, 4: 7.0}
+# names of the Mosaic custom calls in a device trace (metadata only)
+BLOCK_QUANT = "dstpu_block_quant"
 
 
 class QuantizedTensor(NamedTuple):
@@ -573,6 +575,7 @@ def quantize_blockwise_pallas(
             jax.ShapeDtypeStruct((rows, 128), jnp.float32),
         ],
         interpret=interpret,
+        name=BLOCK_QUANT,
     )(seed_arr, blocks)
     if bits == 4:
         values = _pack_int4(values.astype(jnp.float32))

@@ -27,6 +27,10 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 LANES = 128
+# names of the Mosaic custom calls in a device trace (metadata only)
+SPARSE_FWD = "dstpu_sparse_fwd"
+SPARSE_BWD_DQ = "dstpu_sparse_bwd_dq"
+SPARSE_BWD_DKV = "dstpu_sparse_bwd_dkv"
 
 
 def _sparse_fwd_kernel(q_ref, k_ref, v_ref, lay_ref, o_ref, lse_ref, *, scale, causal, bq, bk):
@@ -223,6 +227,7 @@ def _sparse_fwd(q, k, v, layout, block, causal, scale, interpret):
             jax.ShapeDtypeStruct((b, h, s, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name=SPARSE_FWD,
     )(q, k, v, layout)
     return out, (q, k, v, layout, out, lse)
 
@@ -252,6 +257,7 @@ def _sparse_bwd(block, causal, scale, interpret, res, g):
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name=SPARSE_BWD_DQ,
     )(q, k, v, out, g, lse, layout)
 
     layout_t = jnp.swapaxes(layout, 1, 2)  # [h, nk, nq]
@@ -280,6 +286,7 @@ def _sparse_bwd(block, causal, scale, interpret, res, g):
             jax.ShapeDtypeStruct(q.shape, q.dtype),
         ],
         interpret=interpret,
+        name=SPARSE_BWD_DKV,
     )(q, k, v, out, g, lse, layout_t)
     return dq, dk, dv, None  # layout gets no cotangent
 

@@ -44,6 +44,10 @@ from deepspeed_tpu.ops.sparse_attention.schedule import BlockSchedule
 
 NEG_INF = -1e30
 LANES = 128
+# names of the Mosaic custom calls in a device trace (metadata only)
+SPLASH_FWD = "dstpu_splash_fwd"
+SPLASH_BWD_DQ = "dstpu_splash_bwd_dq"
+SPLASH_BWD_DKV = "dstpu_splash_bwd_dkv"
 
 
 def _default_vmem_limit() -> Optional[int]:
@@ -360,6 +364,7 @@ def _splash_fwd_call(q, k, v, seg, kvi, kind, base, params: _SplashParams):
             jax.ShapeDtypeStruct((b, h, sq, LANES), jnp.float32),
         ],
         interpret=params.interpret,
+        name=SPLASH_FWD,
         **_compiler_kwargs(params),
     )(kvi, kind, base, q, k, v, *seg_ops)
     return out, lse
@@ -428,6 +433,7 @@ def _splash_vjp_bwd(params: _SplashParams, res, g):
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=params.interpret,
+        name=SPLASH_BWD_DQ,
         **_compiler_kwargs(params),
     )(kvi, kind, base, q, k, v, out, g, lse, *seg_ops)
 
@@ -471,6 +477,7 @@ def _splash_vjp_bwd(params: _SplashParams, res, g):
             jax.ShapeDtypeStruct((b, h, sk, d), q.dtype),
         ],
         interpret=params.interpret,
+        name=SPLASH_BWD_DKV,
         **_compiler_kwargs(params),
     )(kvi_t, kind_t, base, q, k, v, out, g, lse, *seg_ops_t)
     if group > 1:

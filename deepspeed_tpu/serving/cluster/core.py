@@ -175,6 +175,18 @@ class EngineCore:
         if self.metrics is not None:
             self.metrics.inc(name, delta)
 
+    def _count_step(self) -> None:
+        """One step or round ran: count it, and beside it what the engine
+        sized it to and what it carried (``last_*``, left by the engine as
+        it decides them), so that useful slots over computed slots is
+        measured where the work is decided."""
+        eng = self.engine
+        self._inc("engine_steps_total")
+        self._inc("grid_slots_total", getattr(eng, "last_grid_slots", 0))
+        self._inc("scheduled_tokens_total", getattr(eng, "last_scheduled_tokens", 0))
+        if getattr(eng, "last_prefill_tokens", 0):
+            self._inc("steps_with_prefill_total")
+
     # -- admission accounting --------------------------------------------
     def blocks_needed(self, req: Request, prefill_only: bool = False) -> int:
         """Blocks this request would CHARGE against ``free_blocks``: its
@@ -346,7 +358,7 @@ class EngineCore:
             # every row was skipped (context/block caps, pool exhaustion):
             # the per-step path knows how to cap/stall them
             return False
-        self._inc("engine_steps_total")
+        self._count_step()
         per_uid = dict(self.engine.last_spec.get("per_uid", {}))
         if tr.enabled:
             last = getattr(self.engine, "last_spec", None) or {}
@@ -359,18 +371,32 @@ class EngineCore:
             self.metrics.observe_spec_round(per_uid)
         for uid, (drafted, accepted) in per_uid.items():
             self.spec_ctl.update(uid, drafted, accepted)
-        for uid, toks in round_res.items():
-            req = self.requests.get(uid)
-            if req is None:
-                sched.finish(uid)
-                continue
-            for tok in toks:
-                # apply_spec_round already advanced the scheduler: deliver
-                # without feedback, exactly like fused decode rounds
-                if not sink.deliver(self, req, int(tok), feedback=False):
-                    break
-        self._reap_capped(sink)
+        # apply_spec_round already advanced the scheduler: deliver without
+        # feedback, exactly like fused decode rounds
+        self._deliver_results(sink, sched, round_res, feedback=False)
         return True
+
+    def _deliver_results(self, sink, sched, results, feedback: bool) -> bool:
+        """Hand a step's or round's tokens ({uid: tokens in order}) to the
+        sink, then finish the sequences the engine capped: the
+        ``step.deliver`` span. ``feedback=False`` for rounds whose engine
+        call already advanced the scheduler. Returns True if any token
+        reached a live request."""
+        progress = False
+        with get_tracer().span("step.deliver", track=self.name):
+            for uid, toks in results.items():
+                req = self.requests.get(uid)
+                if req is None:
+                    # finished between steps (cancel/timeout): drop the tokens,
+                    # make sure scheduler state is gone
+                    sched.finish(uid)
+                    continue
+                for tok in toks:
+                    progress = True
+                    if not sink.deliver(self, req, int(tok), feedback=feedback):
+                        break
+            self._reap_capped(sink)
+        return progress
 
     def step_once(self, sink) -> bool:
         """One engine step (or fused decode / speculative verify round).
@@ -405,7 +431,6 @@ class EngineCore:
             and not sched.has_pending()
             and bool(sched.running_uids())
         )
-        progress = False
         tr = get_tracer()
         try:
             faults = get_fault_injector()
@@ -422,7 +447,7 @@ class EngineCore:
                 t0 = tr.now() if tr.enabled else 0.0
                 round_res = self.engine.decode_round(self.decode_steps)
                 if round_res:
-                    self._inc("engine_steps_total")
+                    self._count_step()
                     if tr.enabled:
                         self._trace_round(tr, "round.fused", t0, tr.now(),
                                           round_res, {
@@ -430,20 +455,10 @@ class EngineCore:
                             "steps": self.decode_steps,
                             "tokens": sum(len(t) for t in round_res.values()),
                         })
-                    for uid, toks in round_res.items():
-                        req = self.requests.get(uid)
-                        if req is None:
-                            sched.finish(uid)
-                            continue
-                        for tok in toks:
-                            progress = True
-                            if not sink.deliver(self, req, int(tok), feedback=False):
-                                break
-                    self._reap_capped(sink)
-                    return progress
+                    return self._deliver_results(sink, sched, round_res, feedback=False)
             t0 = tr.now() if tr.enabled else 0.0
             results = self.engine.step_tokens()
-            self._inc("engine_steps_total")
+            self._count_step()
             if tr.enabled:
                 self._trace_round(tr, "step.split", t0, tr.now(), results, {
                     "rows": len(results),
@@ -474,17 +489,8 @@ class EngineCore:
                         f"serving[{self.name}]: prefix-cache clear failed: {ce}"
                     )
             return True
-        for uid, tok in results.items():
-            req = self.requests.get(uid)
-            if req is None:
-                # finished between steps (cancel/timeout): drop the token,
-                # make sure scheduler state is gone
-                sched.finish(uid)
-                continue
-            progress = True
-            sink.deliver(self, req, int(tok))
-        self._reap_capped(sink)
-        return progress
+        return self._deliver_results(
+            sink, sched, {uid: (tok,) for uid, tok in results.items()}, feedback=True)
 
     # -- probation probes -------------------------------------------------
     def probe(self, lock_timeout_s: float = 0.5) -> None:

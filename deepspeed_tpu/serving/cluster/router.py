@@ -798,13 +798,9 @@ class Router:
         if req.stream is not None:
             req.stream.close(reason, error=error)
         req._done.set()
+        self.metrics.observe_request(req)
         if req.trace is not None:
-            # traced path: histograms fold from the SPAN endpoints (same
-            # numbers — the spans carry the request's own stamps)
-            self.metrics.observe_trace(req)
             finish_request_trace(req, reason=reason)
-        else:
-            self.metrics.observe_request(req)
         key = {
             RequestState.FINISHED: "requests_finished_total",
             RequestState.CANCELLED: "requests_cancelled_total",

@@ -319,14 +319,11 @@ class ServingDriver:
         if req.stream is not None:
             req.stream.close(reason, error=error)
         req._done.set()
+        self.metrics.observe_request(req)
         if req.trace is not None:
-            # traced path: histograms fold from the SPAN endpoints (same
-            # numbers — the spans carry the request's own stamps), then
-            # the tree is closed and retention policy runs
-            self.metrics.observe_trace(req)
+            # close the tree (its spans carry the request's own stamps) and
+            # run the retention policy
             finish_request_trace(req, reason=reason)
-        else:
-            self.metrics.observe_request(req)
         key = {
             RequestState.FINISHED: "requests_finished_total",
             RequestState.CANCELLED: "requests_cancelled_total",
@@ -461,10 +458,36 @@ class ServingDriver:
             except Exception as e:
                 logger.warning(f"serving: monitor write failed: {e}")
 
+    def _update_metrics_locked(self):
+        """The gauges and mirrored counters refreshed after every step."""
+        self.metrics.update_kv(self._free_blocks(), self._kv_total)
+        cache = self._prefix_cache()
+        if cache is not None:
+            self.metrics.update_prefix_cache(cache.stats())
+        tier = self.core.host_tier()
+        if tier is not None:
+            self.metrics.update_host_tier(tier.stats())
+        if hasattr(self.engine, "comm_wire_info"):
+            # wire counters accrue as step programs TRACE, so a
+            # per-step refresh catches late-compiled shapes
+            self.metrics.update_comm_quant(self.engine.comm_wire_info())
+        self.metrics.update_replica(
+            self.core.name, self.core.replica_stats(),
+            role=self.core.role,
+        )
+        self.metrics.set_gauge("active_requests", len(self._active))
+        if not self._active and not self._queue:
+            self._idle.set()
+            self._flush_monitor()
+
     # the loop ----------------------------------------------------------
     def _loop(self):
         stall_wait = False
         while True:
+            # everything the loop does between two engine steps is a ring
+            # span (loop.wait / loop.admit / loop.bookkeeping), so a device
+            # gap can be charged to it; the tracer can change between passes
+            tr = get_tracer()
             with self._cond:
                 while True:
                     if self._stopping and not self._active and not self._queue:
@@ -492,35 +515,21 @@ class ServingDriver:
                         timeout = max(0.0, deadline - now)
                     if stall_wait:
                         timeout = min(self.poll_interval_s, timeout) if timeout else self.poll_interval_s
-                    self._cond.wait(timeout)
+                    with tr.span("loop.wait", track="driver"):
+                        self._cond.wait(timeout)
                     stall_wait = False
                 self._idle.clear()
-                self._expire_locked()
-                self._admit_locked()
+                with tr.span("loop.admit", track="driver"):
+                    self._expire_locked()
+                    self._admit_locked()
             stepped = False
             if self.engine.scheduler.has_work():
                 stepped = self._step_once()
                 with self._cond:
-                    self._admit_locked()  # finished requests freed blocks
-                    self.metrics.update_kv(self._free_blocks(), self._kv_total)
-                    cache = self._prefix_cache()
-                    if cache is not None:
-                        self.metrics.update_prefix_cache(cache.stats())
-                    tier = self.core.host_tier()
-                    if tier is not None:
-                        self.metrics.update_host_tier(tier.stats())
-                    if hasattr(self.engine, "comm_wire_info"):
-                        # wire counters accrue as step programs TRACE, so a
-                        # per-step refresh catches late-compiled shapes
-                        self.metrics.update_comm_quant(self.engine.comm_wire_info())
-                    self.metrics.update_replica(
-                        self.core.name, self.core.replica_stats(),
-                        role=self.core.role,
-                    )
-                    self.metrics.set_gauge("active_requests", len(self._active))
-                    if not self._active and not self._queue:
-                        self._idle.set()
-                        self._flush_monitor()
+                    with tr.span("loop.admit", track="driver"):
+                        self._admit_locked()  # finished requests freed blocks
+                    with tr.span("loop.bookkeeping", track="driver"):
+                        self._update_metrics_locked()
             # a zero-progress pass with work outstanding means the scheduler
             # is waiting on KV blocks (or the queue head is inadmissible):
             # back off onto the condition instead of spinning

@@ -118,6 +118,12 @@ class ServingMetrics:
             "prefill_tokens_total": 0,
             "decode_tokens_total": 0,
             "engine_steps_total": 0,
+            # what the steps were sized to and what they carried (EngineCore.
+            # _count_step): slots of the padded program grid against real
+            # tokens in them, and how many steps carried a prompt chunk
+            "grid_slots_total": 0,
+            "scheduled_tokens_total": 0,
+            "steps_with_prefill_total": 0,
             "admission_blocked_total": 0,
             # prefix cache (mirrors of PrefixCache's monotone counters)
             "prefix_queries_total": 0,
@@ -235,33 +241,6 @@ class ServingMetrics:
                 self.tpot.observe(req.tpot_s)
             if req.e2e_s is not None:
                 self.e2e.observe(req.e2e_s)
-
-    def observe_trace(self, req) -> None:
-        """Histogram bridge from SPAN endpoints, for traced requests.
-
-        The trace helpers stamp phase boundaries with the request's own
-        monotonic stamps, so this folds numbers numerically identical to
-        ``observe_request`` (a unit test asserts it) — but when tracing
-        is on the span tree is the source of truth, so the timeline view
-        and the histogram view cannot drift apart.  Falls back to
-        ``observe_request`` when the request carries no trace.
-        """
-        ctx = getattr(req, "trace", None)
-        if ctx is None:
-            self.observe_request(req)
-            return
-        t_submit = ctx.root.t0
-        t_first = ctx.t_first
-        t_finish = ctx.root.t1 if ctx.root.t1 is not None else req.t_finish
-        with self._lock:
-            if t_first is not None:
-                self.ttft.observe(t_first - t_submit)
-            if t_first is not None and t_finish is not None:
-                n = len(req.generated) - 1
-                if n >= 1:
-                    self.tpot.observe((t_finish - t_first) / n)
-            if t_finish is not None:
-                self.e2e.observe(t_finish - t_submit)
 
     def update_kv(self, free_blocks: int, total_blocks: int) -> None:
         with self._lock:

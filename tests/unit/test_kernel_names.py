@@ -1,0 +1,105 @@
+"""Every Mosaic kernel of the main path carries a stable name: the constant in
+the kernel's file is the ``kernel_name`` of the custom call it lowers to, which
+is what a device trace shows (``%dstpu_paged_decode.1 = ... custom-call``) and
+what the benchmark's kernel-share readers look for. Lowering only: the Mosaic
+lowering for the TPU platform needs no chip and compiles nothing.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.ops import fused_ce
+from deepspeed_tpu.ops.adam import fused_adam
+from deepspeed_tpu.ops.attention import flash_pallas, paged_pallas
+from deepspeed_tpu.ops.normalization import fused_norm
+
+
+def _tpu_text(fn, *shapes):
+    """StableHLO of ``fn`` lowered for the TPU platform (from the CPU)."""
+    return jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def _kernel_names(text):
+    return {part.split('"')[1] for part in text.split("kernel_name = ")[1:]}
+
+
+def _s(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def test_flash_forward_and_backward_are_named():
+    q, kv = _s((1, 4, 256, 128)), _s((1, 2, 256, 128))
+
+    def loss(q, k, v):
+        return flash_pallas.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    fwd = _kernel_names(_tpu_text(lambda q, k, v: flash_pallas.flash_attention(q, k, v), q, kv, kv))
+    assert fwd == {flash_pallas.FLASH_FWD}
+    both = _kernel_names(_tpu_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv))
+    assert both == {flash_pallas.FLASH_FWD, flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV}
+    # the readers tell forward from backward by these prefixes
+    assert flash_pallas.FLASH_FWD.startswith("dstpu_flash_fwd")
+    assert all(n.startswith("dstpu_flash_bwd")
+               for n in (flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV))
+
+
+def test_flash_ring_chunks_are_named():
+    q, kv = _s((1, 4, 256, 128)), _s((1, 2, 256, 128))
+    f32, lanes = jnp.float32, flash_pallas.LANES
+    carry = (_s((1, 4, 256, 128), f32), _s((1, 4, 256, lanes), f32), _s((1, 4, 256, lanes), f32))
+    names = _kernel_names(_tpu_text(
+        lambda q, k, v, c: flash_pallas.flash_fwd_chunk(q, k, v, c, causal=False), q, kv, kv, carry))
+    assert names == {flash_pallas.FLASH_FWD_CHUNK}
+
+
+def test_paged_decode_is_named(monkeypatch):
+    # the kernel asks on_tpu() whether to interpret itself; lowering for the
+    # TPU from here, the test answers for it
+    monkeypatch.setattr(paged_pallas, "on_tpu", lambda: True)
+    T, nh, nkv, d, NB, bs, B = 8, 16, 8, 128, 12, 128, 4
+    names = _kernel_names(_tpu_text(
+        lambda q, k, v, bt, pos: paged_pallas.paged_attention(q, k, v, bt, pos, NB, impl="kernel"),
+        _s((T, nh, d)), _s((NB + 1, bs, nkv, d)), _s((NB + 1, bs, nkv, d)),
+        _s((T, B), jnp.int32), _s((T,), jnp.int32)))
+    assert names == {paged_pallas.PAGED_DECODE} == {"dstpu_paged_decode"}
+
+
+def test_rmsnorm_is_named(monkeypatch):
+    monkeypatch.setattr(fused_norm, "on_tpu", lambda: True)
+
+    def loss(x, w):
+        return fused_norm.fused_rms_norm(x, w).astype(jnp.float32).sum()
+
+    names = _kernel_names(_tpu_text(jax.value_and_grad(loss), _s((256, 1024)), _s((1024,))))
+    assert names == {fused_norm.RMSNORM_FWD, fused_norm.RMSNORM_BWD}
+
+
+def test_fused_ce_is_named():
+    def loss(x, w, y):
+        return fused_ce.fused_ce_loss(x, w, y).sum()
+
+    names = _kernel_names(_tpu_text(
+        jax.grad(loss, argnums=(0, 1)), _s((256, 256)), _s((256, 2048)), _s((256,), jnp.int32)))
+    assert names == {fused_ce.CE_FWD, fused_ce.CE_BWD_DX, fused_ce.CE_BWD_DW}
+
+
+def test_fused_adam_is_named():
+    p = _s((8 * 2048,), jnp.float32)
+    names = _kernel_names(_tpu_text(
+        lambda p, g, m, v: fused_adam.fused_adam_step(p, g, m, v, 1), p, p, p, p))
+    assert names == {fused_adam.FUSED_ADAM}
+
+
+@pytest.mark.parametrize("module, constants", [
+    (flash_pallas, ("FLASH_FWD", "FLASH_BWD_DQ", "FLASH_BWD_DKV", "FLASH_FWD_CHUNK",
+                    "FLASH_BWD_DQ_CHUNK", "FLASH_BWD_DKV_CHUNK")),
+    (paged_pallas, ("PAGED_DECODE",)),
+])
+def test_names_are_distinct_trace_safe_identifiers(module, constants):
+    names = [getattr(module, c) for c in constants]
+    assert len(set(names)) == len(names)
+    for n in names:
+        # no dot or space: benchmarks/harness/xplane.py short_name() keeps the
+        # instruction's name up to its numeric suffix
+        assert n.startswith("dstpu_") and n.replace("_", "").isalnum()

@@ -1,0 +1,283 @@
+"""The serving step measured from inside: the ring spans of every phase
+between two device programs, the scheduler's grid counters, the profiler
+bridge of ``SpanTracer.span()``, and the single step path that runs traced or
+not. On the real v2 engine at a toy size (CPU); the request-tree side of the
+tracer is covered in test_tracing.py.
+"""
+
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+from deepspeed_tpu.observability import NULL_TRACER, SpanTracer, get_tracer, set_tracer
+from deepspeed_tpu.observability import tracing
+from deepspeed_tpu.serving.driver import ServingDriver
+from deepspeed_tpu.serving.request import RequestState, SamplingParams
+
+# the contract of names: benchmarks/metrics readers and docs/OBSERVABILITY.md
+STEP_SPANS = {"engine.schedule", "engine.stage", "engine.launch", "engine.dispatch",
+              "engine.device_wait", "engine.materialize", "step.split", "step.deliver"}
+LOOP_SPANS = {"loop.admit", "loop.bookkeeping", "loop.wait"}
+
+
+@pytest.fixture(autouse=True)
+def _isolated_tracer():
+    set_tracer(NULL_TRACER)
+    yield
+    set_tracer(NULL_TRACER)
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    import jax
+
+    from deepspeed_tpu.models import get_config, init_params
+
+    cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=1024)
+    return cfg, init_params(cfg, jax.random.key(0))
+
+
+def _engine(tiny_model, decode_steps=1):
+    """R = 4 decode slots and one prompt chunk of at most 512 tokens a step:
+    the split step's grid is 4 + 128 or 4 + 512 slots."""
+    from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+
+    cfg, params = tiny_model
+    rc = RaggedInferenceEngineConfig.from_dict({
+        "dtype": "float32",
+        "decode_steps": decode_steps,
+        "kv_cache": {"block_size": 16, "num_blocks": 96, "max_blocks_per_seq": 32},
+        "state_manager": {"max_tracked_sequences": 8, "max_ragged_batch_size": 512,
+                          "max_ragged_sequence_count": 4, "max_context": 512},
+    })
+    return InferenceEngineV2(cfg, params, rc)
+
+
+def _serve(engine, prompts_and_new, **driver_kw):
+    """Queue every request BEFORE the loop starts, so that the loop admits
+    them all in its first pass and the steps that follow are the same in
+    every run. Returns the driver (stopped) and the finished requests."""
+    driver = ServingDriver(engine, **driver_kw)
+    reqs = [driver.submit(np.arange(1, n + 1, dtype=np.int32) + 7 * i,
+                          params=SamplingParams(max_new_tokens=new, ignore_eos=True))
+            for i, (n, new) in enumerate(prompts_and_new)]
+    driver.start()
+    try:
+        for r in reqs:
+            assert r.wait(300), "request did not finish"
+    finally:
+        driver.shutdown(drain=False)
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    return driver, reqs
+
+
+def _assert_overlap_only_by_nesting(spans):
+    """Spans of one thread, as a profiler's host line needs them: any two are
+    disjoint or one lies inside the other."""
+    stack = []
+    for sp in sorted(spans, key=lambda s: (s.t0, -(s.t1 - s.t0))):
+        while stack and stack[-1].t1 <= sp.t0:
+            stack.pop()
+        if stack:
+            assert sp.t1 <= stack[-1].t1, (
+                f"{sp.name} [{sp.t0}, {sp.t1}] straddles the end of "
+                f"{stack[-1].name} [{stack[-1].t0}, {stack[-1].t1}]")
+        stack.append(sp)
+
+
+def _inside(inner, outer):
+    return outer.t0 <= inner.t0 and inner.t1 <= outer.t1
+
+
+class TestServingSpans:
+    def test_one_traced_run_yields_every_phase_nested_on_one_timeline(self, tiny_model):
+        tracer = set_tracer(SpanTracer())
+        _serve(_engine(tiny_model), [(200, 3), (20, 2)])
+        ring = tracer.ring_spans()
+        assert all(sp.t1 is not None for sp in ring)
+        names = {sp.name for sp in ring}
+        assert STEP_SPANS | LOOP_SPANS <= names, sorted((STEP_SPANS | LOOP_SPANS) - names)
+        _assert_overlap_only_by_nesting(ring)  # the loop is one thread
+        dispatches = [sp for sp in ring if sp.name == "engine.dispatch"]
+        for part in ("engine.schedule", "engine.stage", "engine.launch"):
+            parts = [sp for sp in ring if sp.name == part]
+            assert len(parts) == len(dispatches)
+            for sp in parts:
+                assert any(_inside(sp, d) for d in dispatches), part
+        # a step on the timeline: dispatch, then the wait, then the tokens come
+        # to the host, all inside the core's step.split; delivery follows it
+        steps = [sp for sp in ring if sp.name == "step.split"]
+        assert len(steps) == len(dispatches) == 3
+        for name in ("engine.dispatch", "engine.device_wait", "engine.materialize"):
+            for sp in (s for s in ring if s.name == name):
+                assert any(_inside(sp, st) for st in steps), name
+        order = [sp.name for sp in sorted(ring, key=lambda s: s.t0)
+                 if sp.name in ("engine.dispatch", "engine.device_wait",
+                                "engine.materialize", "step.deliver")]
+        assert order == ["engine.dispatch", "engine.device_wait",
+                         "engine.materialize", "step.deliver"] * 3
+        # the dispatch span still says what the step carried
+        assert [d.args["tokens"] for d in dispatches] == [200, 21, 2]
+        assert tracer.stats()["dropped_spans"] == 0
+        assert tracer.stats()["ring_evicted_spans"] == 0
+
+    def test_fused_round_has_the_same_phases(self, tiny_model):
+        tracer = set_tracer(SpanTracer())
+        _serve(_engine(tiny_model, decode_steps=4), [(20, 9)], decode_steps=4)
+        ring = tracer.ring_spans()
+        rounds = [sp for sp in ring if sp.name == "round.fused"]
+        assert rounds
+        for name in ("engine.stage", "engine.launch", "engine.dispatch",
+                     "engine.device_wait", "engine.materialize"):
+            assert any(_inside(sp, r) for r in rounds for sp in ring if sp.name == name), name
+        assert sum(sp.name == "step.deliver" for sp in ring) == \
+            sum(sp.name in ("round.fused", "step.split") for sp in ring)
+        _assert_overlap_only_by_nesting(ring)
+
+    def test_ring_overflow_is_counted(self):
+        tracer = SpanTracer(max_events=256)
+        for _ in range(260):
+            with tracer.span("engine.stage"):
+                pass
+        assert len(tracer.ring_spans()) == 256
+        # wrap of the ring is counted apart from request-tree spans lost
+        assert tracer.stats()["ring_evicted_spans"] == 4
+        assert tracer.stats()["dropped_spans"] == 0
+
+
+class TestGridCounters:
+    def test_counters_equal_the_hand_computed_grid_with_tracing_off(self, tiny_model):
+        """Three steps: [chunk of 200 in the 512 bucket], [1 decode row + a
+        chunk of 20 in the 128 bucket], [2 decode rows and NO chunk: the 128
+        grid still runs]. R = 4, Rc = 1."""
+        assert get_tracer() is NULL_TRACER
+        driver, reqs = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
+        c = driver.metrics.counters
+        assert c["engine_steps_total"] == 3
+        assert c["grid_slots_total"] == (4 + 512) + (4 + 128) + (4 + 128)
+        assert c["scheduled_tokens_total"] == 200 + (1 + 20) + 2
+        assert c["prefill_tokens_total"] == 200 + 20
+        assert c["steps_with_prefill_total"] == 2
+        assert c["decode_tokens_total"] == 3 + 2
+        text = driver.metrics.prometheus_text()
+        assert "grid_slots_total 780" in text and "steps_with_prefill_total 2" in text
+
+    def test_fused_round_counts_rows_times_steps(self, tiny_model):
+        driver, _ = _serve(_engine(tiny_model, decode_steps=4), [(20, 9)], decode_steps=4)
+        c = driver.metrics.counters
+        # one prefill step (first token), then two fused rounds of 4 tokens
+        # for the one running row on a grid of R x steps = 16 slots each
+        assert c["engine_steps_total"] == 3
+        assert c["grid_slots_total"] == (4 + 128) + 16 + 16
+        assert c["scheduled_tokens_total"] == 20 + 4 + 4
+        assert c["steps_with_prefill_total"] == 1
+
+
+class TestOnePath:
+    def test_off_path_is_the_on_path_with_the_shared_null_span(self, tiny_model, monkeypatch):
+        """Traced or not, a step runs the same statements: the spans are
+        entered on both, and with tracing off every one of them is the one
+        shared no-op object (nothing allocated per call) and the device wait
+        still happens."""
+        from deepspeed_tpu.inference.v2 import engine_v2
+
+        entered, waits = [], []
+
+        class Recording(type(NULL_TRACER)):
+            def span(self, name, **kw):
+                entered.append(name)
+                return super().span(name, **kw)
+
+        real_sync = engine_v2.device_synchronize
+        monkeypatch.setattr(engine_v2, "device_synchronize",
+                            lambda tree=None: (waits.append(1), real_sync(tree))[1])
+        off = set_tracer(Recording())
+        assert not off.enabled and off.span("x") is NULL_TRACER.span("y")
+        _, reqs = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
+        off_names, off_waits = list(entered), len(waits)
+        off_tokens = [r.generated for r in reqs]
+        assert off.ring_spans() == [] and off.recent() == []
+
+        entered.clear()
+        waits.clear()
+        on = set_tracer(SpanTracer())
+        _, reqs = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
+        on_names = [sp.name for sp in sorted(on.ring_spans(), key=lambda s: s.span_id)
+                    if sp.name not in ("step.split",)]  # recorded after the fact, both ways
+        assert [r.generated for r in reqs] == off_tokens
+        assert len(waits) == off_waits == 3
+
+        def steps_only(names):
+            return [n for n in names if n.startswith(("engine.", "step."))]
+
+        assert steps_only(off_names) == steps_only(on_names)
+        assert set(off_names) >= (STEP_SPANS - {"step.split"}) | (LOOP_SPANS - {"loop.wait"})
+
+
+class TestProfilerBridge:
+    def test_ring_spans_are_annotated_request_spans_are_not(self, monkeypatch):
+        seen = []
+
+        class FakeAnnotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                seen.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                seen.append(("exit", self.name))
+
+        monkeypatch.setattr(tracing, "_trace_annotation", lambda: FakeAnnotation)
+        tr = SpanTracer()
+        with tr.span("engine.stage"):
+            with tr.span("engine.launch"):
+                pass
+        tr.begin_trace(5, "request")
+        with tr.span("prefill", key=5):
+            pass
+        assert seen == [("enter", "dstpu.engine.stage"), ("enter", "dstpu.engine.launch"),
+                        ("exit", "dstpu.engine.launch"), ("exit", "dstpu.engine.stage")]
+
+    def test_records_with_no_profiler_session(self):
+        """The real annotation, outside any profiler session: a no-op check."""
+        pytest.importorskip("jax")
+        tr = SpanTracer()
+        assert tr._annotation is not None
+        with tr.span("engine.stage") as sp:
+            pass
+        assert sp.t1 is not None and [s.name for s in tr.ring_spans()] == ["engine.stage"]
+
+    def test_records_with_jax_unimportable(self, monkeypatch):
+        monkeypatch.setitem(sys.modules, "jax", None)
+        monkeypatch.setitem(sys.modules, "jax.profiler", None)
+        tr = SpanTracer()
+        assert tr._annotation is None
+        with tr.span("engine.stage") as sp:
+            pass
+        assert sp.t1 is not None and sp.t1 >= sp.t0
+        assert [s.name for s in tr.ring_spans()] == ["engine.stage"]
+
+    def test_annotations_from_two_threads_do_not_cross(self):
+        """Each span enters and leaves its annotation on its own thread."""
+        tr = SpanTracer()
+        errors = []
+
+        def work(name):
+            try:
+                for _ in range(200):
+                    with tr.span(name):
+                        pass
+            except Exception as e:  # pragma: no cover - the assertion below reports it
+                errors.append(e)
+
+        threads = [threading.Thread(target=work, args=(f"t{i}",)) for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not errors and not any(t.is_alive() for t in threads)
+        assert len(tr.ring_spans()) == 800

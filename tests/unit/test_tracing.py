@@ -1,6 +1,6 @@
 """End-to-end request tracing tests: span tracer semantics, capture
-policy, Chrome-trace export, the driver/router span threading, the
-ServingMetrics histogram bridge, and the observability satellites
+policy, Chrome-trace export, the driver/router span threading, that the
+histograms and the span trees read the same stamps, and the observability satellites
 (label escaping/validation, quantile clamp, device_synchronize, the
 to_events -> Monitor bridge).
 
@@ -298,7 +298,7 @@ class TestChromeExport:
 
 # -- serving integration: single driver ----------------------------------
 class TestDriverTracing:
-    def test_rooted_tree_and_histogram_bridge(self):
+    def test_rooted_tree_endpoints_are_the_requests_stamps(self):
         tracer = set_tracer(SpanTracer())
         eng = FakeEngine()
         driver = ServingDriver(eng, max_queue=8)
@@ -324,10 +324,16 @@ class TestDriverTracing:
         assert root.args["finish_reason"] == "max_tokens"
         assert root.args["tokens"] == 4
         assert rec["meta"]["tenant"] == "default"
-        # the histogram bridge folded the SAME stamps the spans carry
+        # the tree's endpoints ARE the request's own stamps, so the timeline
+        # and the histograms (folded from the stamps, traced or not) agree
+        assert (root.t0, root.t1) == (req.t_submit, req.t_finish)
+        assert names["prefill"][0].t0 == req.t_admitted
+        assert names["decode"][0].t0 == req.t_first_token
         assert driver.metrics.e2e.count == 1
         assert driver.metrics.ttft.count == 1
         assert driver.metrics.e2e.total == pytest.approx(root.t1 - root.t0)
+        assert driver.metrics.ttft.total == pytest.approx(
+            names["decode"][0].t0 - root.t0)
         # and the tree exports as a valid Chrome-trace document
         assert validate_chrome_trace(trace_to_chrome(rec)) == []
 
@@ -342,7 +348,7 @@ class TestDriverTracing:
             driver.shutdown(drain=False)
         assert req.trace is None
         assert get_tracer() is NULL_TRACER
-        assert driver.metrics.e2e.count == 1  # observe_request fallback
+        assert driver.metrics.e2e.count == 1
 
 
 # -- serving integration: router (disagg + elastic) ----------------------
@@ -417,29 +423,34 @@ class TestRouterTracing:
         assert "preempt" in kinds and "resume" in kinds
 
 
-# -- satellite: observe_trace == observe_request -------------------------
-class TestHistogramBridgeEquality:
-    def test_span_bridge_matches_request_stamps_exactly(self):
-        """observe_trace reads latencies off SPAN endpoints; because the
-        trace helpers stamp phases with the request's own monotonic
-        stamps, both views must fold numerically identical values."""
-        tracer = SpanTracer()
-        req = Request(uid=11, prompt_tokens=np.asarray([1, 2], np.int32),
-                      params=_params(8))
-        req.t_submit = 100.0
-        req.generated = [3, 4, 5, 6]
+# -- one source for the histograms: the request's stamps ------------------
+def _stamped_request(uid, tracer=None):
+    req = Request(uid=uid, prompt_tokens=np.asarray([1, 2], np.int32),
+                  params=_params(8))
+    req.t_submit = 100.0
+    req.generated = [3, 4, 5, 6]
+    if tracer is not None:
         begin_request_trace(tracer, req)
-        req.t_admitted = 100.5
-        mark_admitted(req, core="d0")
-        req.t_first_token = 101.0
-        mark_first_token(req)
-        req.t_finish = 103.0
-        req.finish_reason = "max_tokens"
+    req.t_admitted = 100.5
+    mark_admitted(req, core="d0")
+    req.t_first_token = 101.0
+    mark_first_token(req)
+    req.t_finish = 103.0
+    req.finish_reason = "max_tokens"
+    return req
 
+
+class TestHistogramsFoldTheStamps:
+    def test_traced_and_untraced_requests_fold_the_same_values(self):
+        """A traced run records more; it does not compute its figures
+        differently: the same stamps fold the same histogram values."""
+        tracer = SpanTracer()
+        traced_req, plain_req = _stamped_request(11, tracer), _stamped_request(12)
+        assert traced_req.trace is not None and plain_req.trace is None
         traced, plain = ServingMetrics(), ServingMetrics()
-        traced.observe_trace(req)     # before finish: root still open
-        finish_request_trace(req)
-        plain.observe_request(req)
+        traced.observe_request(traced_req)
+        finish_request_trace(traced_req)
+        plain.observe_request(plain_req)
         for attr in ("ttft", "tpot", "e2e"):
             a, b = getattr(traced, attr), getattr(plain, attr)
             assert (a.count, a.total) == (b.count, b.total), attr
@@ -448,13 +459,16 @@ class TestHistogramBridgeEquality:
         assert traced.tpot.total == pytest.approx(2.0 / 3.0)
         assert traced.e2e.total == pytest.approx(3.0)
 
-    def test_untraced_request_falls_back(self):
-        req = Request(uid=12, prompt_tokens=np.asarray([1], np.int32),
-                      params=_params(2))
-        req.t_submit, req.t_finish = 10.0, 11.0
-        m = ServingMetrics()
-        m.observe_trace(req)  # trace is None -> observe_request path
-        assert m.e2e.count == 1 and m.e2e.total == pytest.approx(1.0)
+    def test_span_tree_endpoints_equal_the_stamps(self):
+        tracer = SpanTracer()
+        req = _stamped_request(13, tracer)
+        finish_request_trace(req)
+        names = _by_name(tracer.trace(13)["spans"])
+        root = names["request"][0]
+        assert (root.t0, root.t1) == (100.0, 103.0)
+        assert (names["queued"][0].t0, names["queued"][0].t1) == (100.0, 100.5)
+        assert (names["prefill"][0].t0, names["prefill"][0].t1) == (100.5, 101.0)
+        assert (names["decode"][0].t0, names["decode"][0].t1) == (101.0, 103.0)
 
 
 # -- satellite: quantile clamp -------------------------------------------
@@ -587,19 +601,26 @@ class TestMonitorBridge:
         assert "Serving_tier_acme_interactive_finished_total 1.0" in text
 
 
-# -- overhead: tracing-on must not add per-token locking stalls ----------
+# -- one step path, traced or not ----------------------------------------
 class TestTracingOverheadShape:
-    def test_disabled_step_path_takes_fast_branch(self):
-        """With the NULL tracer installed, a FakeEngine driver run must
-        record zero spans anywhere (the guard is `tracer.enabled`, checked
-        once per step round, not per token)."""
-        eng = FakeEngine()
-        driver = ServingDriver(eng, max_queue=8)
-        driver.start()
-        try:
-            req = driver.submit(np.asarray([2], np.int32), params=_params(3))
-            assert req.wait(30)
-        finally:
-            driver.shutdown(drain=False)
+    def test_disabled_step_path_records_nothing_and_serves_the_same(self):
+        """With the NULL tracer installed a driver run records zero spans
+        anywhere, and serves exactly the tokens the traced run serves: the
+        step has ONE path, whose spans are the shared no-op when off (the
+        real-engine side is in test_serving_spans.py)."""
+        served = {}
+        for label, tracer in (("off", NULL_TRACER), ("on", SpanTracer())):
+            set_tracer(tracer)
+            driver = ServingDriver(FakeEngine(), max_queue=8)
+            driver.start()
+            try:
+                req = driver.submit(np.asarray([2], np.int32), params=_params(3))
+                assert req.wait(30)
+            finally:
+                driver.shutdown(drain=False)
+            served[label] = (req.generated, dict(driver.metrics.counters))
         assert NULL_TRACER.ring_spans() == []
         assert NULL_TRACER.recent() == []
+        assert served["off"] == served["on"]
+        names = {sp.name for sp in tracer.ring_spans()}
+        assert {"step.split", "step.deliver", "loop.admit", "loop.bookkeeping"} <= names
