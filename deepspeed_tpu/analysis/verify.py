@@ -11,6 +11,13 @@ index points at the wrong argument) is a silent full-buffer copy per step:
 the exact class of bug the split-step's ``donate_argnums=(13, 14)``
 off-by-one would have been.
 
+Aliasing is necessary, not sufficient: a step whose layer loop both reads
+the step-start pool and scatters into the carried pool aliases every donated
+buffer and still copies each pool twice (into a second buffer before the
+loop, back after it). ``check_pool_copies`` reads the COMPILED module of
+the split step, the fused decode round and the verify step and fails on any
+``copy`` the size of a KV pool or a scale plane.
+
 It also counts retraces: a fixed-shape entry point that traces more than
 once across representative same-shape calls is quietly recompiling on the
 hot path (weak-typed scalars, python-hash-unstable statics, ...).
@@ -38,6 +45,7 @@ __all__ = [
     "CheckResult",
     "DonatedBuffer",
     "check_donation",
+    "check_pool_copies",
     "check_recompile",
     "run_verify",
     "verify_disagg",
@@ -69,7 +77,7 @@ class DonatedBuffer:
 @dataclass
 class CheckResult:
     name: str
-    kind: str  # "donation" | "recompile"
+    kind: str  # "donation" | "recompile" | "pool-copy"
     ok: bool
     detail: str = ""
     buffers: List[DonatedBuffer] = field(default_factory=list)
@@ -96,6 +104,11 @@ class CheckResult:
 # ---------------------------------------------------------------------------
 # core checks
 # ---------------------------------------------------------------------------
+# an optimized-HLO copy: "%copy.3 = f32[2,129,4,4,32]{4,3,2,1,0} copy(%k_cache.1)"
+_COPY_RE = re.compile(r"(%[\w.\-]+) = (\w+)\[([\d,]*)\]\S* copy\(([^)]*)\)")
+_HLO_DTYPES = {"float32": "f32", "bfloat16": "bf16", "float16": "f16", "int8": "s8"}
+
+
 def _alias_positions(lowered_text: str) -> Dict[int, bool]:
     """Lowered-module position -> carries tf.aliasing_output. Positions are
     the KEPT flat inputs in order (jit drops unused arguments)."""
@@ -234,6 +247,34 @@ def check_donation(name: str, jitted, args: Sequence, kwargs: Optional[dict] = N
     return CheckResult(name, "donation", True, detail, buffers)
 
 
+def check_pool_copies(name: str, jitted, args: Sequence, pools: Sequence,
+                      lowered=None) -> CheckResult:
+    """Compile ``jitted(*args)`` and fail on every ``copy`` instruction whose
+    result is as large as one of ``pools`` (arrays or shape structs: the KV
+    pools and, for int8, their scale planes) in that pool's dtype. Sizes are
+    compared by element count, so a copy of a reshaped view counts too. A
+    serving step moves the tokens of one step; a copy the size of the pool
+    is XLA resolving a buffer that is read as an invariant and written as a
+    carry in one loop (PERF.md, PR 24), and costs the same whatever the
+    step holds."""
+    import math
+
+    low = lowered if lowered is not None else jitted.lower(*args)
+    hlo = low.compile().as_text()
+    sizes = {(_HLO_DTYPES.get(str(p.dtype), str(p.dtype)), math.prod(p.shape))
+             for p in pools}
+    found = []
+    for m in _COPY_RE.finditer(hlo):
+        dims = [int(d) for d in m.group(3).split(",") if d]
+        if (m.group(2), math.prod(dims)) in sizes:
+            found.append(f"{m.group(1)} = {m.group(2)}[{m.group(3)}] copy({m.group(4)})")
+    if found:
+        return CheckResult(name, "pool-copy", False,
+                           f"{len(found)} pool-sized copy(ies): " + "; ".join(found))
+    return CheckResult(name, "pool-copy", True,
+                       f"no copy the size of any of {len(sizes)} pool shape(s)")
+
+
 def check_recompile(name: str, jitted, max_traces: int = 1) -> CheckResult:
     """A fixed-shape entry point must trace once across representative
     calls; every extra cache entry is a silent recompile on the hot path."""
@@ -291,46 +332,29 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
     return cfg, InferenceEngineV2(cfg, params, rc)
 
 
-def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
-    """One donation/recompile sweep over the v2 serving programs for a pool
-    payload dtype. int8 mode appends the fp32 scale planes as donated
-    trailing args on every step — the exact new-leaf case where a wrong
-    variadic index would silently copy a full plane per step, so both
-    dtypes get the full sweep."""
+def _engine_v2_programs(kv_dtype: str):
+    """The v2 serving programs of a tiny engine with a ``kv_dtype`` pool:
+    (engine, {name: (jitted, args)}). The split step and the fused decode
+    round are captured from two same-shape ``generate()`` passes (pass 1
+    traces, pass 2 must hit the caches); the row step and the verify step
+    are lowered directly with config shapes (lowering reads shapes only,
+    so passing the live pools is safe). int8 appends the donated scale
+    planes to every argument list."""
     import jax.numpy as jnp
     import numpy as np
 
-    tag = "" if kv_dtype == "bf16" else f"[{kv_dtype}]"
-    results: List[CheckResult] = []
     cfg, eng = _tiny_v2_engine(kv_dtype=kv_dtype)
-    captured: dict = {}
-    _capture_builder(eng, "_build_split_step", captured, "split_step")
-    _capture_builder(eng, "_build_multistep_decode", captured, "multistep_decode")
-
-    def prompts(seed):
+    programs: dict = {}
+    _capture_builder(eng, "_build_split_step", programs, "split_step")
+    _capture_builder(eng, "_build_multistep_decode", programs, "multistep_decode")
+    for seed in (0, 1):
         rng = np.random.default_rng(seed)
-        return [rng.integers(1, cfg.vocab_size, size=(12,)).astype(np.int32)
-                for _ in range(2)]
+        eng.generate([rng.integers(1, cfg.vocab_size, size=(12,)).astype(np.int32)
+                      for _ in range(2)], max_new_tokens=6)
 
-    # two same-shape passes: pass 1 traces, pass 2 must hit the caches
-    eng.generate(prompts(0), max_new_tokens=6)
-    eng.generate(prompts(1), max_new_tokens=6)
-
-    for key, label in (("split_step", f"engine_v2.split_step{tag}"),
-                       ("multistep_decode", f"engine_v2.multistep_decode{tag}")):
-        if key not in captured:
-            results.append(CheckResult(label, "donation", False,
-                                       "entry point never executed in harness"))
-            continue
-        fn, args = captured[key]
-        results.append(check_donation(label, fn, args))
-        results.append(check_recompile(label, fn))
-
-    # row step (per-row baseline path): lower directly with config shapes.
-    # int8 appends the donated scale planes (argnums 7, 8).
+    # row step: the per-row baseline path
     kv = eng.config.kv_cache
-    fn = eng._build_row_step(8)
-    row_args = (
+    programs["row_step"] = (eng._build_row_step(8), (
         eng.params,
         jnp.zeros((1, 8), jnp.int32),
         jnp.int32(0),
@@ -338,17 +362,13 @@ def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
         jnp.zeros((kv.max_blocks_per_seq,), jnp.int32),
         eng._k_cache,
         eng._v_cache,
-    ) + eng._scale_args()
-    results.append(check_donation(f"engine_v2.row_step{tag}", fn, row_args))
+    ) + eng._scale_args())
 
     # speculative verify step (serving/spec): the K+1-token draft-and-verify
     # program declares both KV pools donated — without aliasing, every spec
-    # round would copy the whole paged pool, erasing the subsystem's win.
-    # Lowering reads shapes only, so passing the live pools is safe (same
-    # precedent as row_step above).
+    # round would copy the whole paged pool, erasing the subsystem's win
     R = eng.config.state_manager.max_ragged_sequence_count
-    fn = eng._build_verify_step(4)
-    verify_args = (
+    programs["verify_step"] = (eng._build_verify_step(4), (
         eng.params,
         jnp.zeros((R, 5), jnp.int32),
         jnp.zeros((R,), jnp.int32),
@@ -360,14 +380,38 @@ def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
         jnp.float32(1.0),
         eng._k_cache,
         eng._v_cache,
-    ) + eng._scale_args()
-    results.append(check_donation(f"engine_v2.verify_step{tag}", fn, verify_args))
+    ) + eng._scale_args())
+    return eng, programs
+
+
+def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
+    """One donation / pool-copy / recompile sweep over the v2 serving
+    programs for a pool payload dtype. int8 mode appends the fp32 scale
+    planes as donated trailing args on every step — the exact new-leaf case
+    where a wrong variadic index would silently copy a full plane per step,
+    so both dtypes get the full sweep."""
+    tag = "" if kv_dtype == "bf16" else f"[{kv_dtype}]"
+    results: List[CheckResult] = []
+    eng, programs = _engine_v2_programs(kv_dtype)
+    pools = (eng._k_cache, eng._v_cache) + eng._scale_args()
+    for key in ("split_step", "multistep_decode", "row_step", "verify_step"):
+        label = f"engine_v2.{key}{tag}"
+        if key not in programs:
+            results.append(CheckResult(label, "donation", False,
+                                       "entry point never executed in harness"))
+            continue
+        fn, args = programs[key]
+        lowered = fn.lower(*args)
+        results.append(check_donation(label, fn, args, lowered=lowered))
+        results.append(check_pool_copies(label, fn, args, pools, lowered=lowered))
+        if key in ("split_step", "multistep_decode"):  # the captured, live jits
+            results.append(check_recompile(label, fn))
     return results
 
 
 def verify_engine_v2() -> List[CheckResult]:
     # both pool payload dtypes: int8 adds donated scale-plane leaves to
-    # every serving program (split, multistep, verify)
+    # every serving program (split, multistep, row, verify)
     return _engine_v2_pass("bf16") + _engine_v2_pass("int8")
 
 
@@ -1207,17 +1251,27 @@ def verify_splash() -> List[CheckResult]:
         grads = jax.grad(loss)(params)
         return jax.tree.map(lambda p, g: p - 1e-3 * g, params, grads)
 
-    fn = jax.jit(step, donate_argnums=(0,))
-    results = [check_donation(
-        "splash.train_step", fn, (T.init_params(cfg, jax.random.key(0)), tok))]
+    # a one-device topology for the whole harness: forward()'s sharding
+    # constraints name the topology's mesh, and the default one spans every
+    # device the process has while the params below are committed to one
+    from deepspeed_tpu.parallel.topology import Topology, reset_topology, set_topology
 
-    # committed params (device_put) so step 1's host-staged signature equals
-    # the steady state — exactly how a real trainer holds them
-    p = jax.device_put(T.init_params(cfg, jax.random.key(0)), jax.devices()[0])
-    before = _derived_splash_schedule.cache_info()
-    for _ in range(3):
-        p = fn(p, tok)
-    results.append(check_recompile("splash.train_step", fn))
+    reset_topology()
+    set_topology(Topology(devices=jax.devices()[:1]))
+    try:
+        fn = jax.jit(step, donate_argnums=(0,))
+        results = [check_donation(
+            "splash.train_step", fn, (T.init_params(cfg, jax.random.key(0)), tok))]
+
+        # committed params (device_put) so step 1's host-staged signature
+        # equals the steady state — exactly how a real trainer holds them
+        p = jax.device_put(T.init_params(cfg, jax.random.key(0)), jax.devices()[0])
+        before = _derived_splash_schedule.cache_info()
+        for _ in range(3):
+            p = fn(p, tok)
+        results.append(check_recompile("splash.train_step", fn))
+    finally:
+        reset_topology()
 
     # trace-time-constant schedule: however many times the step traces or
     # runs, the schedule is BUILT at most once more (first trace) and then

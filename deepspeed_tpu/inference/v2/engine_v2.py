@@ -889,108 +889,88 @@ class InferenceEngineV2:
 
     # ------------------------------------------------------------------
     def _build_row_step(self, t_bucket: int):
+        """The per-row step (one compiled call per sequence): ``t_bucket``
+        tokens of ONE sequence against its block table. Same pool protocol
+        as the batched steps: the layer loop reads the step-start pool,
+        each layer records its new K/V in the side buffers, and
+        _scatter_kv writes them back after the loop."""
         c = self._mc
         kv = self.config.kv_cache
         bs = kv.block_size
         B = kv.max_blocks_per_seq
         S = B * bs  # gathered context window
-        kv_int8 = self._kv_int8
+        NBp = kv.num_blocks + 1
+        trash = kv.num_blocks  # last cache row (see __init__ +1)
+        dtype = T.DTYPES[c.dtype]
 
         def row_step(params, tokens, start, n_valid, block_table, k_cache,
-                     v_cache, *scale_caches):
+                     v_cache, *scales):
             """tokens: [1, t]; start: scalar first position; n_valid: actual
-            new tokens (≤ t); block_table: [B]. ``scale_caches`` = the int8
+            new tokens (≤ t); block_table: [B]. ``scales`` = the int8
             pools' (ks, vs) fp32 planes, or empty in bf16 mode. Returns
             (logits_last [vocab], k_cache, v_cache[, ks_cache, vs_cache])."""
             t = tokens.shape[1]
-            positions = start + jnp.arange(t, dtype=jnp.int32)
-            x = T._scale_embed(params["embed"].astype(T.DTYPES[c.dtype])[tokens], c, T.DTYPES[c.dtype])
+            nkv, d = c.kv_heads, c.head_dim
+            positions = start + jnp.arange(t, dtype=jnp.int32)  # global positions
+            x = T._scale_embed(params["embed"].astype(dtype)[tokens], c, dtype)
             if c.position == "learned":
                 x = x + params["pos_embed"][jnp.clip(positions, 0, c.max_seq_len - 1)][None]
             if c.embed_norm:
                 x = T._embed_norm(params, c, x, stream=False)
 
-            glob = positions  # [t] global positions of the new tokens
-            blk = block_table[jnp.clip(glob // bs, 0, B - 1)]  # [t] physical block
             # bucketing pads the chunk tail: those writes go to the trash block
-            trash = kv.num_blocks  # last cache row (see __init__ +1)
             valid = jnp.arange(t, dtype=jnp.int32) < n_valid
-            blk = jnp.where(valid, blk, trash)
-            row = glob % bs
+            blk = jnp.where(valid, block_table[jnp.clip(positions // bs, 0, B - 1)], trash)
+            row = positions % bs
+            # live length (HF max(position_ids)+1) from the VALID tokens only
+            # — positions covers the padded bucket tail, whose max would flip
+            # longrope's factor switch early
+            live = start + n_valid
+            k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
+            ks_pool0, vs_pool0 = self._scale_views(*scales)
 
-            def layer_step(x, inputs):
-                if kv_int8:
-                    lp, kc_l, vc_l, ks_l, vs_l = inputs
-                else:
-                    lp, kc_l, vc_l = inputs  # kc_l: [num_blocks, bs, nkv, d]
-                    ks_l = vs_l = None
-                lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-                a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
-                b_, t_, h = a.shape
-                nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
-                q, k, v = a @ lp["wq"], a @ lp["wk"], a @ lp["wv"]
-                if c.attn_qkv_bias:
-                    q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
-                q = q.reshape(1, t_, nh, d).transpose(0, 2, 1, 3)
-                k = k.reshape(1, t_, nkv, d).transpose(0, 2, 1, 3)
-                v = v.reshape(1, t_, nkv, d).transpose(0, 2, 1, 3)
-                if c.qk_norm:
-                    q = T.qk_norm_apply(c, q, lp["q_norm"], head_axis=1, b=lp.get("q_norm_b"))
-                    k = T.qk_norm_apply(c, k, lp["k_norm"], head_axis=1, b=lp.get("k_norm_b"))
-                if c.position == "rope":
-                    # live length (HF max(position_ids)+1) from the VALID
-                    # tokens only — positions covers the padded bucket tail,
-                    # whose max would flip longrope's factor switch early
-                    live = start + n_valid
-                    q = T._rope(q, positions[None], c, live)
-                    k = T._rope(k, positions[None], c, live)
-                # scatter new K/V into the paged cache (mask invalid rows to
-                # a scratch block write at their own position — clip keeps
-                # them inside the table; n_valid < t only pads the tail,
-                # whose writes land at future positions and are re-written)
-                if kv_int8:
+            def layer_fn(lp, x, li, carry, window=None):
+                w = int(c.sliding_window if window is None else window)
+                lp = T._dequant_tree(lp, dtype)
+                _, q, k, v = self._layer_qkv(lp, x, positions, live)  # [t, nh|nkv, d]
+                tables_l = li * NBp + block_table  # [B] into the flat pools
+                if scales:
                     # int8 pool: attend through the paged dense impl (pool
                     # dequantizes inside its gather — raw int8 payloads never
-                    # reach the softmax), mirroring the batched step's
-                    # write-after-read protocol so per-row streams match it
-                    # bit-for-bit: the pool is gathered BEFORE this chunk's
-                    # writes (pool_limit = start masks everything newer) and
-                    # the chunk's own K/V ride alongside in compute dtype as
-                    # extra columns (epos -1 disables the padded tail).
+                    # reach the softmax), exactly as the batched step does,
+                    # so per-row streams match it bit-for-bit: the pool is
+                    # read below ``start`` only and the chunk's own K/V ride
+                    # alongside in compute dtype as extra columns (epos -1
+                    # disables the padded tail).
                     from deepspeed_tpu.ops.attention.paged_pallas import paged_attention
-                    from deepspeed_tpu.ops.quantizer.block_quant import quantize_kv
 
-                    k_rows = k[0].transpose(1, 0, 2)  # [t, nkv, d]
-                    v_rows = v[0].transpose(1, 0, 2)
-                    epos = jnp.where(valid, glob, -1)
+                    epos = jnp.where(valid, positions, -1)
                     out = paged_attention(
-                        q[0].transpose(1, 0, 2), kc_l, vc_l,
-                        jnp.broadcast_to(block_table[None], (t_, B)), glob,
-                        trash, impl="dense", window=c.sliding_window or 0,
-                        scale=c.attn_scale, k_scale=ks_l, v_scale=vs_l,
+                        q, k_pool0, v_pool0,
+                        jnp.broadcast_to(tables_l[None], (t, B)), positions,
+                        li * NBp + trash, impl="dense", window=w,
+                        scale=c.attn_scale, k_scale=ks_pool0, v_scale=vs_pool0,
                         extra_kv=(
-                            jnp.broadcast_to(k_rows[None], (t_, t_, nkv, d)),
-                            jnp.broadcast_to(v_rows[None], (t_, t_, nkv, d)),
-                            jnp.broadcast_to(epos[None], (t_, t_)),
+                            jnp.broadcast_to(k[None], (t, t, nkv, d)),
+                            jnp.broadcast_to(v[None], (t, t, nkv, d)),
+                            jnp.broadcast_to(epos[None], (t, t)),
                         ),
-                        pool_limit=jnp.full((t_,), start, jnp.int32),
+                        pool_limit=jnp.full((t,), start, jnp.int32),
                     )
-                    out = out.reshape(t_, nh * d)[None]
-                    # quantize-on-write (same per-head-vector scheme as the
-                    # batched _scatter_kv); write-only after the gather above
-                    k_q, k_s = quantize_kv(k_rows)
-                    v_q, v_s = quantize_kv(v_rows)
-                    kc_l = kc_l.at[blk, row].set(k_q)
-                    vc_l = vc_l.at[blk, row].set(v_q)
-                    ks_l = ks_l.at[blk, row].set(k_s)
-                    vs_l = vs_l.at[blk, row].set(v_s)
                 else:
-                    kc_l = kc_l.at[blk, row].set(k[0].transpose(1, 0, 2))
-                    vc_l = vc_l.at[blk, row].set(v[0].transpose(1, 0, 2))
-                    # gather the sequence's context and run masked attention
-                    k_ctx = kc_l[block_table].reshape(S, nkv, d).transpose(1, 0, 2)[None]
-                    v_ctx = vc_l[block_table].reshape(S, nkv, d).transpose(1, 0, 2)[None]
-                    if c.attention_impl == "splash" and c.sliding_window > 0:
+                    # the sequence's context as the pool will hold it after
+                    # the write-back: gathered from the step-start pool,
+                    # the chunk's valid rows laid over it (index S: dropped)
+                    at = jnp.where(valid, positions, S)
+
+                    def context(pool, new):
+                        ctx = pool[tables_l].reshape(S, nkv, d)
+                        ctx = ctx.at[at].set(new, mode="drop")
+                        return ctx.transpose(1, 0, 2)[None]  # [1, nkv, S, d]
+
+                    k_ctx, v_ctx = context(k_pool0, k), context(v_pool0, v)
+                    qh = q.transpose(1, 0, 2)[None]  # [1, nh, t, d]
+                    if c.attention_impl == "splash" and w > 0:
                         # scheduled prefill: the kv-block schedule is computed
                         # IN-JIT from the traced chunk start (one compiled
                         # program per (t, S) bucket, no host rebuild) and the
@@ -1003,71 +983,60 @@ class InferenceEngineV2:
                         )
 
                         out = splash_prefill_attention(
-                            q, k_ctx, v_ctx, start,
-                            window=c.sliding_window, block_kv=bs,
-                            scale=c.attn_scale,
+                            qh, k_ctx, v_ctx, start,
+                            window=w, block_kv=bs, scale=c.attn_scale,
                         )
                     else:
                         kpos = jnp.arange(S, dtype=jnp.int32)
-                        mask = kpos[None, :] <= glob[:, None]  # [t, S] causal vs global pos
-                        if c.sliding_window:
+                        mask = kpos[None, :] <= positions[:, None]  # [t, S] causal
+                        if w:
                             from deepspeed_tpu.ops.attention.core import window_too_far
 
                             mask = jnp.logical_and(
                                 mask,
                                 jnp.logical_not(
-                                    window_too_far(glob[:, None], kpos[None, :], c.sliding_window)
+                                    window_too_far(positions[:, None], kpos[None, :], w)
                                 ),
                             )
                         bias = jnp.where(mask, 0.0, -1e30).astype(jnp.float32)[None, None]
                         from deepspeed_tpu.ops.attention import mha_reference
 
-                        out = mha_reference(q, k_ctx, v_ctx, causal=False, bias=bias,
+                        out = mha_reference(qh, k_ctx, v_ctx, causal=False, bias=bias,
                                             scale=c.attn_scale)
-                    out = out.transpose(0, 2, 1, 3).reshape(1, t_, nh * d)
-                if self._tp_wire:
-                    attn_out = self._tp_row_matmul(out[0], lp["wo"], "tp_attn_out")[None]
-                else:
-                    attn_out = out @ lp["wo"]
-                if c.attn_out_bias:
-                    attn_out = attn_out + lp["wo_b"]
-                caches = (kc_l, vc_l, ks_l, vs_l) if kv_int8 else (kc_l, vc_l)
-                quant_mlp = self._tp_wire and c.n_experts == 0
-                if c.parallel_block:
-                    # falcon/phi: both branches read the pre-attention state
-                    m = T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
-                    mlp_out = self._mlp_quant(lp, m) if quant_mlp else T._mlp_block(c, lp, m)[0]
-                    return x + attn_out + mlp_out, caches
-                x = x + attn_out
-                m = T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
-                mlp_out = self._mlp_quant(lp, m) if quant_mlp else T._mlp_block(c, lp, m)[0]
-                return x + mlp_out, caches
+                    out = out[0].transpose(1, 0, 2)  # [t, nh, d]
+                return self._layer_tail(lp, x, out), self._record_kv(carry, li, k, v)
 
-            xs = (params["layers"], k_cache, v_cache) + tuple(scale_caches)
-            x, new_caches = jax.lax.scan(layer_step, x, xs)
+            x, side = self._drive_layers(layer_fn, params, x, self._side_buffers(t))
+            caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
             x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
             last = jnp.take_along_axis(x, jnp.clip(n_valid - 1, 0, t - 1)[None, None, None], axis=1)[:, 0]
             logits = T._apply_lm_head(params, last, c)
-            return (logits[0].astype(jnp.float32),) + tuple(new_caches)
+            return (logits[0].astype(jnp.float32),) + caches
 
-        donate = (5, 6, 7, 8) if kv_int8 else (5, 6)
+        donate = (5, 6, 7, 8) if self._kv_int8 else (5, 6)
         return jax.jit(row_step, donate_argnums=donate)
 
     # ------------------------------------------------------------------
     def _pool_views(self, k_cache, v_cache):
         """Flat multi-layer block-pool views [L*NBp, bs, nkv, d] of the
-        carried 5-D caches — reshapes of contiguous leading dims (free),
-        never a per-layer slice (slicing a scan-carried cache copied 200 MB
-        per layer-step; PERF.md serving roofline)."""
+        5-D pools as the step received them — reshapes of contiguous
+        leading dims (free), never a per-layer slice. The layer loops read
+        these views and nothing else of the pools: they are loop
+        invariants, and the one write of a step comes after the loop
+        (_scatter_kv)."""
         c = self._mc
         kv = self.config.kv_cache
         L, NBp = c.n_layers, kv.num_blocks + 1
         shape = (L * NBp, kv.block_size, c.kv_heads, c.head_dim)
         return k_cache.reshape(shape), v_cache.reshape(shape)
 
-    def _scale_views(self, ks_cache, vs_cache):
+    def _scale_views(self, *scales):
         """Flat views [L*NBp, bs, nkv] of the int8 pools' fp32 scale planes
-        (same layer-offset indexing as _pool_views)."""
+        (same layer-offset indexing as _pool_views); (None, None) for a
+        bf16 pool, whose steps take no scale planes."""
+        if not scales:
+            return None, None
+        ks_cache, vs_cache = scales
         c = self._mc
         kv = self.config.kv_cache
         L, NBp = c.n_layers, kv.num_blocks + 1
@@ -1090,10 +1059,11 @@ class InferenceEngineV2:
         in-VMEM behind the halved HBM reads), the dense XLA gather+einsum
         as ``impl="dense"`` (GSPMD shards it on the kv-head dim without a
         shard_map island, and it wins at CPU/tp shapes).
-        ``extra_kv``/``pool_limit``: the write-after-read protocol (this
-        step's K/V ride alongside instead of a scatter-then-gather that
-        copies the pool). ``k_scale``/``v_scale``: flat int8 dequant
-        planes (_scale_views)."""
+        ``extra_kv``/``pool_limit``: this step's K/V ride alongside and
+        the pool is read below the step's first position only, so no read
+        needs this step's writes from the pool (they wait for _scatter_kv).
+        ``k_scale``/``v_scale``: flat int8 dequant planes
+        (_scale_views)."""
         from deepspeed_tpu.ops.attention.paged_pallas import paged_attention
 
         c = self._mc
@@ -1105,39 +1075,72 @@ class InferenceEngineV2:
             extra_kv=extra_kv, pool_limit=pool_limit,
         )
 
-    def _scatter_kv(self, k_cache, v_cache, li, blk, row, k, v, scales=None):
-        """Write the new tokens' K/V into the carried caches via ONE
-        single-dimension scatter on a flat slot view [L*NBp*bs, nkv, d] —
-        XLA applies it in place on the donated carry. The earlier
-        scan-over-layers form (caches as scan xs/ys, per-layer
-        advanced-index scatter) copied the 200 MB layer slice per
-        layer-step and dominated the decode round (PERF.md).
+    def _side_buffers(self, *token_dims):
+        """A zeroed (k, v) pair [L, *token_dims, nkv, d] in compute dtype:
+        what a step's layer loop carries in place of the pools. Each layer
+        records its new K/V at its own index (_record_kv) and _scatter_kv
+        writes all of them back after the loop. Sized by the tokens of one
+        step, not by the pool; at tp>1 sharded on the kv-head dim like the
+        pool, so the write-back moves nothing across devices."""
+        c = self._mc
+        shape = (c.n_layers,) + tuple(token_dims) + (c.kv_heads, c.head_dim)
+        side = jnp.zeros(shape, T.DTYPES[c.dtype])
+        if self._mesh is not None:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
 
-        ``scales`` = (ks_cache, vs_cache) in int8 mode: the new K/V
-        quantize on write (block_quant.quantize_kv, per head vector — the
-        granularity that needs no read-modify-write of neighbor slots) and
-        the fp32 scales scatter through the same slot ids. Returns the
-        carry-shaped cache tuple (2 or 4 leaves)."""
+            from deepspeed_tpu.parallel.topology import MODEL_AXIS
+
+            spec = P(*([None] * (len(shape) - 2)), MODEL_AXIS, None)
+            side = jax.lax.with_sharding_constraint(side, NamedSharding(self._mesh, spec))
+        return side, side
+
+    @staticmethod
+    def _record_kv(side, li, k, v):
+        """Layer ``li``'s new K/V [..., nkv, d] into the side buffers."""
+        side_k, side_v = side
+        return (
+            jax.lax.dynamic_update_index_in_dim(side_k, k, li, 0),
+            jax.lax.dynamic_update_index_in_dim(side_v, v, li, 0),
+        )
+
+    def _scatter_kv(self, caches, blk, row, side):
+        """THE pool write of a serving step: after the layer loop, ONE
+        single-dimension scatter a pool on its flat slot view
+        [L*NBp*bs, nkv, d] puts every layer's new K/V in place, at slot
+        ``(li*NBp + blk)*bs + row``. ``caches`` are the step's donated pool
+        arguments (k, v[, ks, vs]), untouched until here: the layer loop
+        reads them as invariants and carries the side buffers
+        (_side_buffers), so XLA updates the donated buffers in place and
+        copies no pool. It must stay outside every loop: a scatter inside
+        one makes the pool both the invariant the loop reads and the carry
+        it mutates, which XLA resolves with two whole-pool copies a pool a
+        step (PERF.md, PR 24; analysis/verify.check_pool_copies guards it).
+
+        ``blk``/``row``: [n] block and row of each of the step's n token
+        slots, the same for every layer (padded slots name the trash
+        block); ``side``: (k, v) [L, n..., nkv, d]. int8 pools quantize
+        here (block_quant.quantize_kv, per head vector: the granularity
+        that needs no read-modify-write of neighbor slots) and the fp32
+        scales scatter through the same slot ids. Returns the pools in the
+        order given."""
         c = self._mc
         kv = self.config.kv_cache
         L, NBp, bs = c.n_layers, kv.num_blocks + 1, kv.block_size
         nkv, d = c.kv_heads, c.head_dim
-        shape = k_cache.shape
-        slot = (li * NBp + blk) * bs + row
-        if scales:
+        n = blk.shape[0]
+        li = jnp.arange(L, dtype=jnp.int32)[:, None]
+        slot = ((li * NBp + blk[None]) * bs + row[None]).reshape(L * n)
+        new = [a.reshape(L * n, nkv, d) for a in side]
+        if len(caches) == 4:
             from deepspeed_tpu.ops.quantizer.block_quant import quantize_kv
 
-            k, sk = quantize_kv(k)
-            v, sv = quantize_kv(v)
-            ks_cache, vs_cache = scales
-            sshape = ks_cache.shape
-            ks_cache = ks_cache.reshape(L * NBp * bs, nkv).at[slot].set(sk).reshape(sshape)
-            vs_cache = vs_cache.reshape(L * NBp * bs, nkv).at[slot].set(sv).reshape(sshape)
-        k_cache = k_cache.reshape(L * NBp * bs, nkv, d).at[slot].set(k).reshape(shape)
-        v_cache = v_cache.reshape(L * NBp * bs, nkv, d).at[slot].set(v).reshape(shape)
-        if scales:
-            return k_cache, v_cache, ks_cache, vs_cache
-        return k_cache, v_cache
+            (k, sk), (v, sv) = (quantize_kv(a) for a in new)
+            new = [k, v, sk, sv]
+        return tuple(
+            pool.reshape((L * NBp * bs,) + pool.shape[3:]).at[slot].set(a).reshape(pool.shape)
+            for pool, a in zip(caches, new)
+        )
 
     def _layer_windows(self):
         """Static per-layer window values: an int (uniform — one loop body
@@ -1155,10 +1158,10 @@ class InferenceEngineV2:
 
     def _drive_layers(self, layer_fn, params, x, carry):
         """Run ``layer_fn(lp, x, li, carry, window=...) -> (x, carry)`` over
-        the stack. Uniform windows: lax.fori_loop with a traced layer index
-        (the caches inside ``carry`` stay donated — in-place updates).
+        the stack. Uniform windows: lax.fori_loop with a traced layer index.
         Per-layer windows (true alternating patterns): unrolled Python loop
-        with static indices."""
+        with static indices. ``carry`` holds the step's side buffers and
+        never a pool: the pools are invariants of either loop."""
         windows = self._layer_windows()
         L = self._mc.n_layers
         if not isinstance(windows, list):
@@ -1298,10 +1301,9 @@ class InferenceEngineV2:
         decode rows through _attn_decode (their own new K/V as the
         extra_kv self column), chunk rows through paged_chunk_attention
         (in-chunk causal over the chunk's fresh K/V + pool context below
-        the chunk start). The pool is gathered BEFORE the write and the
-        scatter is write-only — a scatter-then-gather made XLA copy the
-        full cache per layer-step (PERF.md serving roofline)."""
-        k_cache, v_cache = carry[0], carry[1]
+        the chunk start). No read needs this step's K/V from the pool, so
+        the layer only records them in ``carry`` (the side buffers) and the
+        pool is written once, after the loop."""
         c = self._mc
         kv = self.config.kv_cache
         NBp = kv.num_blocks + 1
@@ -1310,13 +1312,8 @@ class InferenceEngineV2:
         R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
         _, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"])
-        # gathers read the STEP-START pool views (meta): layer li's region
-        # is untouched when layer li runs, and reading the carried cache
-        # after any layer's scatter would force XLA to copy the pool per
-        # layer (cross-layer read-after-write on one buffer)
         k_pool, v_pool = meta["k_pool0"], meta["v_pool0"]
-        ks_pool = meta.get("ks_pool0")
-        vs_pool = meta.get("vs_pool0")
+        ks_pool, vs_pool = meta["ks_pool0"], meta["vs_pool0"]
         from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
 
         out_d = self._attn_decode(
@@ -1335,12 +1332,8 @@ class InferenceEngineV2:
             pool_limit=meta["chk_start"],
             k_scale=ks_pool, v_scale=vs_pool,
         )
-        caches = self._scatter_kv(
-            k_cache, v_cache, li, meta["blk"], meta["row"], k, v,
-            scales=carry[2:] or None,
-        )
         out = jnp.concatenate([out_d, out_c.reshape(Rc * tq, nh, d)], axis=0)
-        return self._layer_tail(lp, x, out), caches
+        return self._layer_tail(lp, x, out), self._record_kv(carry, li, k, v)
 
     def _build_split_step(self, tq: int):
         """ONE compiled step over the split-phase batch: R decode slots +
@@ -1365,23 +1358,23 @@ class InferenceEngineV2:
             # switch: padded slots carry position 0, so the plain max works
             live = jnp.max(positions) + 1
             k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
+            ks_pool0, vs_pool0 = self._scale_views(*scales)
             meta = {
-                "R": R, "Rc": Rc, "tq": tq, "positions": positions,
-                "blk": blk, "row": row, "live": live,
+                "R": R, "Rc": Rc, "tq": tq, "positions": positions, "live": live,
                 "dec_tables": dec_tables, "dec_pos": dec_pos,
                 "chk_tables": chk_tables, "chk_pos": chk_pos,
                 "chk_start": chk_start,
                 "k_pool0": k_pool0, "v_pool0": v_pool0,
+                "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
             }
-            if scales:
-                meta["ks_pool0"], meta["vs_pool0"] = self._scale_views(*scales)
 
             def layer_fn(lp, x, li, carry, window=None):
                 return self._split_layer(lp, x, li, meta, carry, window=window)
 
-            x, caches = self._drive_layers(
-                layer_fn, params, x, (k_cache, v_cache) + tuple(scales)
+            x, side = self._drive_layers(
+                layer_fn, params, x, self._side_buffers(tokens.shape[0])
             )
+            caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
             x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
             dec_h = x[0, :R]  # [R, h]
             chk_h = x[0, jnp.clip(chk_last, 0, x.shape[1] - 1)]  # [Rc, h]
@@ -1412,10 +1405,10 @@ class InferenceEngineV2:
             return (
                 logits_dec.astype(jnp.float32), logits_chk.astype(jnp.float32),
                 toks_dec, toks_chk,
-            ) + tuple(caches)
+            ) + caches
 
         # donate BOTH cache pools (args 15 and 16 — k_cache, v_cache) so the
-        # scatter updates alias in place; donating 14 would hand XLA the
+        # write-back updates them in place; donating 14 would hand XLA the
         # scalar `temperature` instead of v_cache and copy a full V pool.
         # int8 mode appends the scale planes (16 + 17/18) as variadic
         # trailing args — bf16 signatures and donation indices stay
@@ -1428,10 +1421,10 @@ class InferenceEngineV2:
         """One layer of one step of a fused decode ROUND: queries are the
         round's step-``s`` tokens (one per row); context = the ROUND-START
         pool (read-only all round) + the round's earlier tokens from the
-        carried side buffers [L, R, n, nkv, d]. The pool scatter is
-        write-only within the round, so XLA keeps the 2 GB carry in place;
-        the side buffers are the (40 MB) read-write surface."""
-        side_k, side_v, k_cache, v_cache = carry[:4]
+        carried side buffers [L, R, n, nkv, d]. The side buffers are the
+        round's only read-write surface; the pool is written from them
+        once, after the last step."""
+        side_k, side_v = carry
         c = self._mc
         kv = self.config.kv_cache
         NBp = kv.num_blocks + 1
@@ -1448,21 +1441,14 @@ class InferenceEngineV2:
         )
         sk = jax.lax.dynamic_index_in_dim(side_k, li, 0, keepdims=False)
         sv = jax.lax.dynamic_index_in_dim(side_v, li, 0, keepdims=False)
-        # gathers read the ROUND-START pool views (meta), never the carried
-        # cache being scattered into — that read-after-write would force
-        # XLA to copy the pool every layer-step
         out = self._attn_decode(
             q, meta["k_pool0"], meta["v_pool0"], li * NBp + meta["tables"],
             meta["pos"], w, li * NBp + kv.num_blocks,
             extra_kv=(sk, sv, meta["epos"]),
             pool_limit=meta["pos0"],
-            k_scale=meta.get("ks_pool0"), v_scale=meta.get("vs_pool0"),
+            k_scale=meta["ks_pool0"], v_scale=meta["vs_pool0"],
         )
-        caches = self._scatter_kv(
-            k_cache, v_cache, li, meta["blk"], meta["row"], k, v,
-            scales=carry[4:] or None,
-        )
-        return self._layer_tail(lp, x, out), (side_k, side_v) + caches
+        return self._layer_tail(lp, x, out), (side_k, side_v)
 
     def _build_multistep_decode(self, n_steps: int):
         """``n_steps`` greedy decode iterations in ONE device program, the
@@ -1477,8 +1463,10 @@ class InferenceEngineV2:
         their context masks to nothing and their tokens freeze). Block
         capacity for ``n_steps`` tokens per row must be allocated by the
         caller BEFORE the call (decode_round does). Context protocol: the
-        pool is read at its ROUND-START state; the round's own tokens ride
-        in side buffers (see _round_layer)."""
+        pool is read at its ROUND-START state and is no part of any loop's
+        carry; the round's own tokens ride in side buffers (see
+        _round_layer), and one write-back after the last step puts all
+        ``n_steps`` tokens of every layer into the donated pools."""
         c = self._mc
         kv = self.config.kv_cache
         bs = kv.block_size
@@ -1486,40 +1474,27 @@ class InferenceEngineV2:
         trash = kv.num_blocks
         R = self.config.state_manager.max_ragged_sequence_count
         dtype = T.DTYPES[c.dtype]
-        L = c.n_layers
 
         def fused(params, tokens, positions, tables, uids, active, rng,
                   temperature, k_cache, v_cache, *scales):
             tok_tables = jnp.where(active[:, None], tables, trash)
             pos0 = positions  # round-start positions (pool validity limit)
-            nkv, d = c.kv_heads, c.head_dim
-            side_shape = (L, R, n_steps, nkv, d)
-            side_k0 = jnp.zeros(side_shape, dtype)
-            side_v0 = jnp.zeros(side_shape, dtype)
             j_idx = jnp.arange(n_steps, dtype=jnp.int32)
             # round-start pool views: read-only for the whole round (the
-            # in-round tokens come from the side buffers); XLA pays one
-            # pool copy for the round's write chain instead of one per
-            # layer-step
+            # in-round tokens come from the side buffers)
             k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
-            ks_pool0 = vs_pool0 = None
-            if scales:
-                ks_pool0, vs_pool0 = self._scale_views(*scales)
+            ks_pool0, vs_pool0 = self._scale_views(*scales)
 
             from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
 
             kw = self._sampling_kw()
 
-            def one_token(params, toks, pos, s, side_k, side_v, caches):
+            def one_token(params, toks, pos, s, side):
                 x = T._scale_embed(params["embed"].astype(dtype)[toks][None], c, dtype)
                 if c.position == "learned":
                     x = x + params["pos_embed"][jnp.clip(pos, 0, c.max_seq_len - 1)][None]
                 if c.embed_norm:
                     x = T._embed_norm(params, c, x, stream=False)
-                blk = jnp.take_along_axis(
-                    tok_tables, jnp.clip(pos // bs, 0, B - 1)[:, None], axis=1
-                )[:, 0]
-                row = pos % bs
                 # side slots 0..s are valid for active rows; -1 masks the rest
                 epos = jnp.where(
                     (j_idx[None] <= s) & active[:, None],
@@ -1529,7 +1504,7 @@ class InferenceEngineV2:
                     "tables": tok_tables, "pos": pos,
                     # inactive rows: pos0 == 0 -> pool masks to nothing
                     "pos0": jnp.where(active, pos0, 0),
-                    "s": s, "epos": epos, "blk": blk, "row": row,
+                    "s": s, "epos": epos,
                     "k_pool0": k_pool0, "v_pool0": v_pool0,
                     "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
                     # inactive rows carry position 0: exclude them from the
@@ -1540,10 +1515,7 @@ class InferenceEngineV2:
                 def layer_fn(lp, x, li, carry, window=None):
                     return self._round_layer(lp, x, li, meta, carry, window=window)
 
-                x, st = self._drive_layers(
-                    layer_fn, params, x, (side_k, side_v) + tuple(caches)
-                )
-                side_k, side_v, caches = st[0], st[1], st[2:]
+                x, side = self._drive_layers(layer_fn, params, x, side)
                 x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
                 logits = T._apply_lm_head(params, x[0], c)  # [R, vocab]
                 # content-addressed per-row keys on (uid, source position):
@@ -1555,28 +1527,29 @@ class InferenceEngineV2:
                     row_keys(rng, uids, jnp.where(active, pos, -1)),
                     temperature=temperature, return_logprobs=True, **kw,
                 )
-                return nxt, logp, side_k, side_v, caches
+                return nxt, logp, side
 
             def step_fn(carry, s):
-                toks, pos, side_k, side_v = carry[:4]
-                nxt, logp, side_k, side_v, caches = one_token(
-                    params, toks, pos, s, side_k, side_v, carry[4:]
-                )
+                toks, pos, side = carry
+                nxt, logp, side = one_token(params, toks, pos, s, side)
                 nxt = jnp.where(active, nxt, toks)  # inactive rows freeze
-                return (
-                    (nxt, pos + active.astype(jnp.int32), side_k, side_v)
-                    + tuple(caches),
-                    (nxt, logp),
-                )
+                return (nxt, pos + active.astype(jnp.int32), side), (nxt, logp)
 
-            final, (toks_out, logps_out) = jax.lax.scan(
+            (_, _, side), (toks_out, logps_out) = jax.lax.scan(
                 step_fn,
-                (tokens, positions, side_k0, side_v0, k_cache, v_cache)
-                + tuple(scales),
-                jnp.arange(n_steps, dtype=jnp.int32),
+                (tokens, positions, self._side_buffers(R, n_steps)),
+                j_idx,
             )
-            # toks_out/logps_out: [n_steps, R]; tail = carried cache pools
-            return (toks_out, logps_out) + tuple(final[4:])
+            # the round's write-back: step s of row r sits at position
+            # pos0 + s (inactive rows never advance and name the trash block)
+            pos_all = pos0[:, None] + j_idx[None] * active[:, None]  # [R, n_steps]
+            blk = jnp.take_along_axis(tok_tables, jnp.clip(pos_all // bs, 0, B - 1), axis=1)
+            caches = self._scatter_kv(
+                (k_cache, v_cache) + scales,
+                blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps), side,
+            )
+            # toks_out/logps_out: [n_steps, R]; tail = the written pools
+            return (toks_out, logps_out) + caches
 
         donate = (8, 9, 10, 11) if self._kv_int8 else (8, 9)
         return jax.jit(fused, donate_argnums=donate)
@@ -1736,13 +1709,12 @@ class InferenceEngineV2:
             blk = jnp.take_along_axis(tok_tables, jnp.clip(pos // bs, 0, B - 1), axis=1)
             blk = jnp.where(valid, blk, trash).reshape(R * K1)
             row = flat_pos % bs
-            # round-start pool views: reads below each row's write cursor
-            # only (pool_limit), writes go through the donated carry —
-            # the same write-after-read protocol as the split step
+            # step-start pool views: reads below each row's write cursor
+            # only (pool_limit); the K1 fresh K/V of a row ride alongside and
+            # reach the pool in one write-back after the loop, as in the
+            # split step
             k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
-            ks_pool0 = vs_pool0 = None
-            if scales:
-                ks_pool0, vs_pool0 = self._scale_views(*scales)
+            ks_pool0, vs_pool0 = self._scale_views(*scales)
             pool_lim = jnp.where(active, positions0, 0)
             from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
 
@@ -1762,7 +1734,6 @@ class InferenceEngineV2:
                 ).reshape(R * K1, K1)
 
             def layer_fn(lp, x, li, carry, window=None):
-                kc, vc = carry[0], carry[1]
                 w = c.sliding_window if window is None else window
                 lp = T._dequant_tree(lp, dtype)
                 _, q, k_, v_ = self._layer_qkv(lp, x, flat_pos, live)
@@ -1788,14 +1759,13 @@ class InferenceEngineV2:
                         pool_limit=pool_lim,
                         k_scale=ks_pool0, v_scale=vs_pool0,
                     ).reshape(R * K1, nh, d)
-                caches = self._scatter_kv(
-                    kc, vc, li, blk, row, k_, v_, scales=carry[2:] or None
-                )
-                return self._layer_tail(lp, x, out.reshape(R * K1, nh, d)), caches
+                x = self._layer_tail(lp, x, out.reshape(R * K1, nh, d))
+                return x, self._record_kv(carry, li, k_, v_)
 
-            x, caches = self._drive_layers(
-                layer_fn, params, x, (k_cache, v_cache) + tuple(scales)
+            x, side = self._drive_layers(
+                layer_fn, params, x, self._side_buffers(R * K1)
             )
+            caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
             x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
             logits = T._apply_lm_head(params, x[0], c)  # [R*K1, vocab]
             from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
@@ -1812,10 +1782,10 @@ class InferenceEngineV2:
             match = (tokens[:, 1:] == tgt[:, :k]) & (jj[None] < (n_input - 1)[:, None])
             n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
             n_emit = jnp.where(active, n_acc + 1, 0)
-            return (tgt, n_emit, logp) + tuple(caches)
+            return (tgt, n_emit, logp) + caches
 
         # donate BOTH cache pools (args 9 and 10 — k_cache, v_cache) so the
-        # verify scatter aliases in place like every other serving step;
+        # write-back updates them in place like every other serving step;
         # int8 appends the scale planes (11/12) variadically
         donate = (9, 10, 11, 12) if self._kv_int8 else (9, 10)
         return jax.jit(verify, donate_argnums=donate)

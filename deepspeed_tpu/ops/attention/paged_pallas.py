@@ -11,8 +11,8 @@ Layout:
   k/v pool     [NB, bs, nkv, d] — paged block pool (token-major). The engine
                  passes a FLAT multi-layer view ([L*NBp, bs, nkv, d]) with
                  layer-offset block tables, so the pool never needs a
-                 per-layer slice (slicing a scan-carried cache copied 200 MB
-                 per layer-step — the round-4 serving bottleneck, PERF.md)
+                 per-layer slice, and reads it as the step received it:
+                 the engine writes a step's K/V once, after its layer loop
   block_tables per token [T, B] or per row [R, B]
   q_pos        global position of each query in its sequence
 
@@ -379,9 +379,10 @@ def paged_decode_attention_dense(
     tokens (this step's / this round's K/V), appended as extra score
     columns; epos are their global positions, -1 = invalid. ``pool_limit``
     [R]: pool positions >= pool_limit are masked (default q_pos + 1, i.e.
-    the causal <=). The pool is gathered BEFORE this step's writes — a
-    scatter-then-gather of the same pool made XLA materialize a full cache
-    copy per layer-step (PERF.md serving roofline, the round-4 bottleneck).
+    the causal <=). The pool is gathered BEFORE this step's writes: the
+    engine scatters a step's K/V once, after its layer loop
+    (engine_v2._scatter_kv), so no read ever depends on a write of the
+    same step and XLA copies no pool.
     ``k_scale``/``v_scale`` [NB, bs, nkv] fp32: int8-pool dequant planes
     (extras stay in compute dtype — only the pool payload is quantized).
     """

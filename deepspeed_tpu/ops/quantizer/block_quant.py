@@ -165,7 +165,7 @@ def quantize_kv(x: jax.Array):
     ``q * scale`` (the ds_quantize symmetric convention above).
 
     Per-VECTOR (not per-block) granularity is what makes quantize-on-write
-    compatible with the engine's write-only scatter protocol: a new token's
+    compatible with the engine's one write-back scatter a step: a new token's
     row never changes an already-written row's scale, so incremental
     appends need no read-modify-write of neighbouring pool slots."""
     qmax = _QMAX[8]

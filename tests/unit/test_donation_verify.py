@@ -2,9 +2,13 @@
 KV-cache pools (the donate_argnums off-by-one class this suite exists to
 catch), the streamed-adam leaf must alias all four donated state buffers
 (including the bf16 param mirror), and fixed-shape entry points must not
-retrace across same-shape calls."""
+retrace across same-shape calls. Aliasing is not enough: the compiled
+serving programs must also hold no copy the size of a KV pool (a layer loop
+that reads the step-start pool and scatters into the carried one aliases
+both pools and still copies each twice a step)."""
 
-import numpy as np
+import functools
+
 import pytest
 
 import jax.numpy as jnp
@@ -12,20 +16,18 @@ import jax.numpy as jnp
 from deepspeed_tpu.analysis import verify as dv
 
 
+@functools.lru_cache(maxsize=None)
+def _programs(kv_dtype):
+    """(engine, {name: (jitted, args)}) after two same-shape generate()
+    passes: pass 1 traces, pass 2 must hit the caches."""
+    return dv._engine_v2_programs(kv_dtype)
+
+
 @pytest.fixture(scope="module")
 def split_step_capture():
-    cfg, eng = dv._tiny_v2_engine()
-    cap = {}
-    dv._capture_builder(eng, "_build_split_step", cap, "split_step")
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(1, cfg.vocab_size, size=(12,)).astype(np.int32)
-               for _ in range(2)]
-    # two same-shape passes: pass 1 traces, pass 2 must hit the cache
-    eng.generate(prompts, max_new_tokens=4)
-    eng.generate(prompts, max_new_tokens=4)
-    assert "split_step" in cap, "harness never hit the split-step path"
-    fn, args = cap["split_step"]
-    return eng, fn, args
+    eng, programs = _programs("bf16")
+    assert "split_step" in programs, "harness never hit the split-step path"
+    return (eng,) + programs["split_step"]
 
 
 def test_split_step_aliases_both_kv_pools(split_step_capture):
@@ -44,6 +46,57 @@ def test_split_step_traces_once(split_step_capture):
     _, fn, _ = split_step_capture
     res = dv.check_recompile("split_step", fn)
     assert res.ok, res.detail
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("program", ["split_step", "multistep_decode", "verify_step", "row_step"])
+def test_serving_programs_copy_no_pool(program, kv_dtype):
+    eng, programs = _programs(kv_dtype)
+    fn, args = programs[program]
+    pools = (eng._k_cache, eng._v_cache) + eng._scale_args()
+    assert len(pools) == (4 if kv_dtype == "int8" else 2)
+    res = dv.check_pool_copies(program, fn, args, pools)
+    assert res.ok, res.detail
+
+
+def test_pool_copy_check_flags_a_scatter_inside_the_layer_loop():
+    """The protocol this check exists to keep out: the loop reads the
+    step-start pool as an invariant and scatters into the carried pool, so
+    XLA copies the pool into a second buffer and back, for all the
+    aliasing. With the scatter after the loop the same program is clean."""
+    import jax
+
+    L, N, D = 3, 64, 8
+    pool = jnp.zeros((L, N, D), jnp.float32)
+    slot = jnp.arange(4, dtype=jnp.int32)
+
+    def read(pool0, li):  # what attention does: gather rows of layer li
+        return pool0.reshape(L * N, D)[li * N + slot + 8].sum(0)
+
+    def inside(pool, x):
+        def body(li, st):
+            x, carried = st
+            x = x + read(pool, li)
+            carried = carried.reshape(L * N, D).at[li * N + slot].set(x).reshape(L, N, D)
+            return x, carried
+        return jax.lax.fori_loop(0, L, body, (x, pool))
+
+    def after(pool, x):
+        def body(li, st):
+            x, side = st
+            x = x + read(pool, li)
+            return x, jax.lax.dynamic_update_index_in_dim(side, jnp.broadcast_to(x, (4, D)), li, 0)
+        x, side = jax.lax.fori_loop(0, L, body, (x, jnp.zeros((L, 4, D), jnp.float32)))
+        at = (jnp.arange(L, dtype=jnp.int32)[:, None] * N + slot[None]).reshape(L * 4)
+        return x, pool.reshape(L * N, D).at[at].set(side.reshape(L * 4, D)).reshape(L, N, D)
+
+    args = (pool, jnp.ones((D,), jnp.float32))
+    for fn, clean in ((inside, False), (after, True)):
+        jitted = jax.jit(fn, donate_argnums=0)
+        assert dv.check_donation(fn.__name__, jitted, args).ok  # aliased either way
+        res = dv.check_pool_copies(fn.__name__, jitted, args, [pool])
+        assert res.ok == clean, res.detail
+    assert res.kind == "pool-copy"
 
 
 def test_streamed_adam_leaf_donates_all_state():
