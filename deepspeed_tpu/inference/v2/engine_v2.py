@@ -129,7 +129,24 @@ class InferenceEngineV2:
         params = jax.tree.map(
             lambda p: p.astype(dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p, params
         )
-        if getattr(self.config, "quant", None) and self.config.quant.enabled:
+        quantized = bool(getattr(self.config, "quant", None) and self.config.quant.enabled)
+        tp = int(getattr(self.config, "tp_size", 1) or 1)
+        if model_config.n_experts > 0 and (quantized or tp > 1):
+            # the expert layer's grouped matmul reads whole bf16 expert
+            # weights on one device; neither variant has a test
+            raise NotImplementedError(
+                "v2 paged engine: a mixture-of-experts model with "
+                + ("quantized weights" if quantized else f"tp_size={tp}")
+                + " is not supported: the grouped expert matmul "
+                "(parallel/moe/grouped.py) has no int8 or model-sharded form yet"
+            )
+        if T.qk_norm_full(model_config) and tp > 1:
+            raise NotImplementedError(
+                f"v2 paged engine: qk_norm_kind='rmsnorm_full' with tp_size={tp}: "
+                "the norm spans every head, so under head sharding it needs a "
+                "reduction over the model axis that has no test yet"
+            )
+        if quantized:
             from deepspeed_tpu.inference.quantization import quantize_inference_params
 
             params = quantize_inference_params(
@@ -318,6 +335,12 @@ class InferenceEngineV2:
         self.last_grid_slots = 0
         self.last_scheduled_tokens = 0
         self.last_prefill_tokens = 0
+        # expert models: what the last step's expert layers routed and what
+        # their kernel covered ({"routed", "computed", "hot", "calls"}, rows
+        # and layer calls), from the [.., L, E] count the step programs
+        # return; the serving core folds it into the moe_*_total counters
+        self.last_moe = None
+        self._moe_pending = None  # (device counts, tokens of one layer call)
         self.last_capped = set()
         # sampling state: one base key; programs fold in each row's (uid,
         # source position) so a token's key is content-addressed — invariant
@@ -1004,14 +1027,15 @@ class InferenceEngineV2:
                         out = mha_reference(qh, k_ctx, v_ctx, causal=False, bias=bias,
                                             scale=c.attn_scale)
                     out = out[0].transpose(1, 0, 2)  # [t, nh, d]
-                return self._layer_tail(lp, x, out), self._record_kv(carry, li, k, v)
+                x, moe = self._layer_tail(lp, x, out, valid, li)
+                return x, self._record_kv(carry, li, k, v, moe)
 
             x, side = self._drive_layers(layer_fn, params, x, self._side_buffers(t))
             caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
             x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
             last = jnp.take_along_axis(x, jnp.clip(n_valid - 1, 0, t - 1)[None, None, None], axis=1)[:, 0]
             logits = T._apply_lm_head(params, last, c)
-            return (logits[0].astype(jnp.float32),) + caches
+            return (logits[0].astype(jnp.float32),) + caches + side[2:]
 
         donate = (5, 6, 7, 8) if self._kv_int8 else (5, 6)
         return jax.jit(row_step, donate_argnums=donate)
@@ -1093,16 +1117,29 @@ class InferenceEngineV2:
 
             spec = P(*([None] * (len(shape) - 2)), MODEL_AXIS, None)
             side = jax.lax.with_sharding_constraint(side, NamedSharding(self._mesh, spec))
+        if c.n_experts > 0:
+            # an expert model's loop also carries what each layer routed:
+            # [L, E] rows an expert, returned with the step's tokens
+            return side, side, jnp.zeros((c.n_layers, c.n_experts), jnp.int32)
         return side, side
 
     @staticmethod
-    def _record_kv(side, li, k, v):
-        """Layer ``li``'s new K/V [..., nkv, d] into the side buffers."""
-        side_k, side_v = side
-        return (
-            jax.lax.dynamic_update_index_in_dim(side_k, k, li, 0),
-            jax.lax.dynamic_update_index_in_dim(side_v, v, li, 0),
+    def _record_moe(carry, li, moe_counts):
+        """Layer ``li``'s rows routed to each expert into the carry's third
+        part (expert models; a dense layer's None leaves the pair as it is)."""
+        if moe_counts is None:
+            return carry
+        return carry[:2] + (jax.lax.dynamic_update_index_in_dim(carry[2], moe_counts, li, 0),)
+
+    @classmethod
+    def _record_kv(cls, carry, li, k, v, moe_counts=None):
+        """Layer ``li``'s new K/V [..., nkv, d] into the side buffers, and
+        what it routed beside them (_record_moe)."""
+        side = (
+            jax.lax.dynamic_update_index_in_dim(carry[0], k, li, 0),
+            jax.lax.dynamic_update_index_in_dim(carry[1], v, li, 0),
         )
+        return cls._record_moe(side + carry[2:], li, moe_counts)
 
     def _scatter_kv(self, caches, blk, row, side):
         """THE pool write of a serving step: after the layer loop, ONE
@@ -1131,7 +1168,7 @@ class InferenceEngineV2:
         n = blk.shape[0]
         li = jnp.arange(L, dtype=jnp.int32)[:, None]
         slot = ((li * NBp + blk[None]) * bs + row[None]).reshape(L * n)
-        new = [a.reshape(L * n, nkv, d) for a in side]
+        new = [a.reshape(L * n, nkv, d) for a in side[:2]]
         if len(caches) == 4:
             from deepspeed_tpu.ops.quantizer.block_quant import quantize_kv
 
@@ -1164,20 +1201,30 @@ class InferenceEngineV2:
         never a pool: the pools are invariants of either loop."""
         windows = self._layer_windows()
         L = self._mc.n_layers
+        # an expert model's per-expert weights stay whole: the grouped kernel
+        # indexes its layer's blocks itself (_layer_tail passes ``li`` on); a
+        # slice in front of a custom call would be copied, every layer
+        whole = {}
+        if self._mc.n_experts > 0:
+            from deepspeed_tpu.parallel.moe.sharded_moe import EXPERT_STACKS
+
+            whole = {k: v for k, v in params["layers"].items() if k in EXPERT_STACKS}
+        sliced = {k: v for k, v in params["layers"].items() if k not in whole}
+
+        def layer_params(take):
+            return {**jax.tree.map(take, sliced), **whole}
+
         if not isinstance(windows, list):
             def body(li, st):
                 x, carry = st
-                lp = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
-                    params["layers"],
-                )
+                lp = layer_params(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False))
                 return layer_fn(lp, x, li, carry, window=windows)
 
             x, carry = jax.lax.fori_loop(0, L, body, (x, carry))
             return x, carry
         for li, w in enumerate(windows):
-            lp = jax.tree.map(lambda a: a[li], params["layers"])
-            x, carry = layer_fn(lp, x, li, carry, window=w)
+            x, carry = layer_fn(layer_params(lambda a: a[li]), x, li, carry, window=w)
         return x, carry
 
     def _layer_qkv(self, lp, x, positions, live):
@@ -1194,10 +1241,13 @@ class InferenceEngineV2:
         q, k, v = a[0] @ lp["wq"], a[0] @ lp["wk"], a[0] @ lp["wv"]
         if c.attn_qkv_bias:
             q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
+        if T.qk_norm_full(c):
+            q = T.qk_norm_apply(c, q, lp["q_norm"], head_axis=-1)
+            k = T.qk_norm_apply(c, k, lp["k_norm"], head_axis=-1)
         q = q.reshape(t, nh, d)
         k = k.reshape(t, nkv, d)
         v = v.reshape(t, nkv, d)
-        if c.qk_norm:
+        if c.qk_norm and not T.qk_norm_full(c):
             q = T.qk_norm_apply(c, q, lp["q_norm"], head_axis=1, b=lp.get("q_norm_b"))
             k = T.qk_norm_apply(c, k, lp["k_norm"], head_axis=1, b=lp.get("k_norm_b"))
         if c.position == "rope":
@@ -1245,7 +1295,8 @@ class InferenceEngineV2:
         """Dense-MLP mirror of ``T._mlp_block`` for the quantized TP path:
         w_up/w_gate stay implicit GSPMD column-parallel (no psum on that
         wire), the w_down row-parallel matmul runs through the quantized
-        psum island. MoE configs never reach here (caller falls back)."""
+        psum island. Dense models only: an MoE model at tp>1 is refused at
+        engine build."""
         c = self._mc
         up = T._proj(c, m, lp["w_up"])
         if c.mlp_bias:
@@ -1267,11 +1318,15 @@ class InferenceEngineV2:
             out = out + lp["w_down_b"]
         return out
 
-    def _layer_tail(self, lp, x, out):
+    def _layer_tail(self, lp, x, out, live, li):
         """Shared per-layer epilogue: wo projection (+ bias), then the
         parallel-block (falcon/phi) or sequential residual + MLP. With
         comm_quant="int8" at tp>1, the two MODEL_AXIS reductions (behind
-        wo and w_down) run int8-inside-the-collective."""
+        wo and w_down) run int8-inside-the-collective. ``live`` [t] bool:
+        the slots of the step's grid that hold a token; an expert layer
+        routes and counts those alone, and reads its weights at layer
+        ``li`` of the whole stacks (_drive_layers). Returns (x, rows routed to each
+        expert [E] int32, or None for a dense MLP)."""
         c = self._mc
         nh, d = c.n_heads, c.head_dim
         t = x.shape[1]
@@ -1283,15 +1338,20 @@ class InferenceEngineV2:
             attn_out = (out.reshape(t, nh * d) @ lp["wo"])[None]
         if c.attn_out_bias:
             attn_out = attn_out + lp["wo_b"]
-        quant_mlp = self._tp_wire and c.n_experts == 0
-        if c.parallel_block:
-            m = T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
-            mlp_out = self._mlp_quant(lp, m) if quant_mlp else T._mlp_block(c, lp, m)[0]
-            return x + attn_out + mlp_out
-        x = x + attn_out
+        if not c.parallel_block:
+            x = x + attn_out
         m = T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
-        mlp_out = self._mlp_quant(lp, m) if quant_mlp else T._mlp_block(c, lp, m)[0]
-        return x + mlp_out
+        counts = None
+        if c.n_experts > 0:
+            from deepspeed_tpu.parallel.moe import moe_mlp
+
+            mlp_out, _, counts = moe_mlp(c, lp, m, live=live[None], layer=li)
+        elif self._tp_wire:
+            mlp_out = self._mlp_quant(lp, m)
+        else:
+            mlp_out = T._mlp_block(c, lp, m)[0]
+        x = x + attn_out + mlp_out if c.parallel_block else x + mlp_out
+        return x, counts
 
     # ------------------------------------------------------------------
     def _split_layer(self, lp, x, li, meta, carry, window=None):
@@ -1333,7 +1393,8 @@ class InferenceEngineV2:
             k_scale=ks_pool, v_scale=vs_pool,
         )
         out = jnp.concatenate([out_d, out_c.reshape(Rc * tq, nh, d)], axis=0)
-        return self._layer_tail(lp, x, out), self._record_kv(carry, li, k, v)
+        x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li)
+        return x, self._record_kv(carry, li, k, v, moe)
 
     def _build_split_step(self, tq: int):
         """ONE compiled step over the split-phase batch: R decode slots +
@@ -1366,6 +1427,8 @@ class InferenceEngineV2:
                 "chk_start": chk_start,
                 "k_pool0": k_pool0, "v_pool0": v_pool0,
                 "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
+                # the grid's padding: decode slots with no row, chunk tails
+                "slot_live": jnp.concatenate([dec_pos >= 0, chk_pos.reshape(Rc * tq) >= 0]),
             }
 
             def layer_fn(lp, x, li, carry, window=None):
@@ -1402,10 +1465,11 @@ class InferenceEngineV2:
                 row_keys(rng, chk_uids, chk_src),
                 temperature=temperature, **kw,
             )
+            # an expert model's [L, E] routed rows ride behind the pools
             return (
                 logits_dec.astype(jnp.float32), logits_chk.astype(jnp.float32),
                 toks_dec, toks_chk,
-            ) + caches
+            ) + caches + side[2:]
 
         # donate BOTH cache pools (args 15 and 16 — k_cache, v_cache) so the
         # write-back updates them in place; donating 14 would hand XLA the
@@ -1424,7 +1488,7 @@ class InferenceEngineV2:
         carried side buffers [L, R, n, nkv, d]. The side buffers are the
         round's only read-write surface; the pool is written from them
         once, after the last step."""
-        side_k, side_v = carry
+        side_k, side_v = carry[:2]
         c = self._mc
         kv = self.config.kv_cache
         NBp = kv.num_blocks + 1
@@ -1448,7 +1512,8 @@ class InferenceEngineV2:
             pool_limit=meta["pos0"],
             k_scale=meta["ks_pool0"], v_scale=meta["vs_pool0"],
         )
-        return self._layer_tail(lp, x, out), (side_k, side_v)
+        x, moe = self._layer_tail(lp, x, out, meta["active"], li)
+        return x, self._record_moe((side_k, side_v) + carry[2:], li, moe)
 
     def _build_multistep_decode(self, n_steps: int):
         """``n_steps`` greedy decode iterations in ONE device program, the
@@ -1501,7 +1566,7 @@ class InferenceEngineV2:
                     pos0[:, None] + j_idx[None], -1,
                 )
                 meta = {
-                    "tables": tok_tables, "pos": pos,
+                    "tables": tok_tables, "pos": pos, "active": active,
                     # inactive rows: pos0 == 0 -> pool masks to nothing
                     "pos0": jnp.where(active, pos0, 0),
                     "s": s, "epos": epos,
@@ -1533,9 +1598,10 @@ class InferenceEngineV2:
                 toks, pos, side = carry
                 nxt, logp, side = one_token(params, toks, pos, s, side)
                 nxt = jnp.where(active, nxt, toks)  # inactive rows freeze
-                return (nxt, pos + active.astype(jnp.int32), side), (nxt, logp)
+                # an expert model: what this step's layers routed, [L, E]
+                return (nxt, pos + active.astype(jnp.int32), side), (nxt, logp) + side[2:]
 
-            (_, _, side), (toks_out, logps_out) = jax.lax.scan(
+            (_, _, side), (toks_out, logps_out, *moe) = jax.lax.scan(
                 step_fn,
                 (tokens, positions, self._side_buffers(R, n_steps)),
                 j_idx,
@@ -1548,8 +1614,9 @@ class InferenceEngineV2:
                 (k_cache, v_cache) + scales,
                 blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps), side,
             )
-            # toks_out/logps_out: [n_steps, R]; tail = the written pools
-            return (toks_out, logps_out) + caches
+            # toks_out/logps_out: [n_steps, R]; then the written pools; an
+            # expert model's routed rows [n_steps, L, E] behind them
+            return (toks_out, logps_out) + caches + tuple(moe)
 
         donate = (8, 9, 10, 11) if self._kv_int8 else (8, 9)
         return jax.jit(fused, donate_argnums=donate)
@@ -1635,12 +1702,15 @@ class InferenceEngineV2:
                 toks_out, logps_out, self._k_cache, self._v_cache = outs[:4]
                 if self._kv_int8:
                     self._ks_cache, self._vs_cache = outs[4], outs[5]
-        _start_host_copies((toks_out, logps_out))
+                self._moe_pending = (outs[-1], R) if self._mc.n_experts > 0 else None
+        waited = [toks_out, logps_out] + self._moe_arrays()
+        _start_host_copies(waited)
         with tr.span("engine.device_wait", track=track):
-            device_synchronize((toks_out, logps_out))
+            device_synchronize(waited)
         results: Dict[int, np.ndarray] = {}
         self.last_logprobs = {}
         with tr.span("engine.materialize", track=track):
+            self._count_moe()
             toks_out = np.asarray(toks_out)  # [n, R]
             logps_out = np.asarray(logps_out)
             for i, uid in enumerate(uids):
@@ -1759,8 +1829,9 @@ class InferenceEngineV2:
                         pool_limit=pool_lim,
                         k_scale=ks_pool0, v_scale=vs_pool0,
                     ).reshape(R * K1, nh, d)
-                x = self._layer_tail(lp, x, out.reshape(R * K1, nh, d))
-                return x, self._record_kv(carry, li, k_, v_)
+                x, moe = self._layer_tail(
+                    lp, x, out.reshape(R * K1, nh, d), valid.reshape(R * K1), li)
+                return x, self._record_kv(carry, li, k_, v_, moe)
 
             x, side = self._drive_layers(
                 layer_fn, params, x, self._side_buffers(R * K1)
@@ -1782,7 +1853,7 @@ class InferenceEngineV2:
             match = (tokens[:, 1:] == tgt[:, :k]) & (jj[None] < (n_input - 1)[:, None])
             n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
             n_emit = jnp.where(active, n_acc + 1, 0)
-            return (tgt, n_emit, logp) + caches
+            return (tgt, n_emit, logp) + caches + side[2:]
 
         # donate BOTH cache pools (args 9 and 10 — k_cache, v_cache) so the
         # write-back updates them in place like every other serving step;
@@ -1892,14 +1963,17 @@ class InferenceEngineV2:
                 tgt, n_emit, logp, self._k_cache, self._v_cache = outs[:5]
                 if self._kv_int8:
                     self._ks_cache, self._vs_cache = outs[5], outs[6]
-        _start_host_copies((tgt, n_emit, logp))
+                self._moe_pending = (outs[-1], self.last_grid_slots) if self._mc.n_experts > 0 else None
+        waited = [tgt, n_emit, logp] + self._moe_arrays()
+        _start_host_copies(waited)
         with tr.span("engine.device_wait", track=track):
-            device_synchronize((tgt, n_emit, logp))
+            device_synchronize(waited)
         results: Dict[int, np.ndarray] = {}
         self.last_logprobs = {}
         drafted_total = accepted_total = 0
         per_uid: Dict[int, Tuple[int, int]] = {}
         with tr.span("engine.materialize", track=track):
+            self._count_moe()
             tgt = np.asarray(tgt)
             n_emit = np.asarray(n_emit)
             logp = np.asarray(logp)
@@ -1918,6 +1992,36 @@ class InferenceEngineV2:
             "per_uid": per_uid,
         }
         return results
+
+    def _moe_arrays(self):
+        """The routed-rows array of the step just launched (expert models),
+        for the host copy that is requested with the tokens'."""
+        return [self._moe_pending[0]] if self._moe_pending else []
+
+    def _count_moe(self) -> None:
+        """After the wait: reduce the step's ``[.., L, E]`` routed rows to
+        ``last_moe``. One layer call a row of E: rows routed, rows the
+        dispatch computed (the grouped kernel: its tile size for every tile
+        visit; the capacity dispatch: E x capacity), the fullest expert's
+        rows. None for a dense model or a step that launched nothing."""
+        pending, self._moe_pending, self.last_moe = self._moe_pending, None, None
+        if pending is None:
+            return
+        from deepspeed_tpu.parallel.moe import grouped, sharded_moe
+
+        c = self._mc
+        counts = np.asarray(pending[0]).reshape(-1, c.n_experts)
+        pairs = pending[1] * c.moe_top_k
+        if c.moe_drop_tokens:
+            computed = counts.shape[0] * c.n_experts * sharded_moe._capacity(
+                pairs, c.n_experts, c.moe_capacity_factor)
+        else:
+            itemsize = jnp.dtype(T.DTYPES[c.dtype]).itemsize
+            computed = grouped.computed_rows(counts, grouped.row_tile(pairs, itemsize))
+        self.last_moe = {
+            "routed": int(counts.sum()), "computed": int(computed),
+            "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
+        }
 
     def put(self, batch_uids, batch_tokens) -> Dict[int, np.ndarray]:
         """Submit new sequences (reference put :107) and run ONE engine step.
@@ -1962,11 +2066,15 @@ class InferenceEngineV2:
         # the arrays the tokens come from (rows of one step share them)
         waited = list({id(a): a for a in (
             _entry_array(e, True)[0] for e in res.values())}.values())
+        # an expert model's routed rows come from the same program: nothing
+        # more to wait for, but for a step that completed no row
+        waited += self._moe_arrays()
         _start_host_copies(waited)
         with tr.span("engine.device_wait", track=track):
             device_synchronize(waited)
         out: Dict[int, int] = {}
         with tr.span("engine.materialize", track=track):
+            self._count_moe()
             for uid, tok in _materialize_rows(res, want_tokens=True).items():
                 out[uid] = int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
         return out
@@ -1984,6 +2092,7 @@ class InferenceEngineV2:
             batch = self.scheduler.next_batch()
             self.last_capped |= self.scheduler.drain_capped()
         self.last_grid_slots = self.last_scheduled_tokens = self.last_prefill_tokens = 0
+        self._moe_pending = None
         if batch is None:
             return {}
         with tr.span("engine.stage", track=track):
@@ -2094,6 +2203,8 @@ class InferenceEngineV2:
              self._k_cache, self._v_cache) = outs[:6]
             if self._kv_int8:
                 self._ks_cache, self._vs_cache = outs[6], outs[7]
+            if self._mc.n_experts > 0:
+                self._moe_pending = (outs[-1], T_)
         # rows are referenced as (logits array, row index, greedy-token
         # array): slicing logits_dec[i] here would issue one tiny device op
         # per completed row per step — at r05's ~90 ms round trip those

@@ -8,7 +8,9 @@ a (:class:`TransformerConfig`, stacked-params pytree) pair that trains or
 serves through ``deepspeed_tpu.initialize`` / ``init_inference`` unchanged.
 
 Supported ``model_type``s: llama, mistral, qwen2, qwen2_moe, qwen3,
-qwen3_moe (per-head q/k RMSNorm), mixtral, falcon, phi (incl. qk_layernorm),
+qwen3_moe (per-head q/k RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole
+projection width; the four MoE types import drop-free: ``moe_drop_tokens``
+false), falcon, phi (incl. qk_layernorm),
 phi3, gpt2, gpt_neo, opt, gemma, bloom, gptj, gpt_neox, internlm, stablelm
 (incl. qk_layernorm), starcoder2, megatron_gpt (Megatron-LM GPT state-dict
 naming, per-head-interleaved fused qkv), plus the bert/distilbert encoder
@@ -198,8 +200,7 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
             n_experts=get("num_experts"),
             moe_top_k=get("num_experts_per_tok"),
             moe_norm_topk_prob=bool(get("norm_topk_prob", True)),
-            # drop-free (HF semantics) — same capacity stance as qwen2_moe
-            moe_capacity_factor=float(get("num_experts")) / float(get("num_experts_per_tok")),
+            moe_drop_tokens=False,  # HF never drops a token
             moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
         )
     if mt == "qwen2_moe":
@@ -212,12 +213,6 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
                 f"qwen2_moe: decoder_sparse_step={sparse_step}, mlp_only_layers="
                 f"{mlp_only} — only uniform MoE stacks are supported"
             )
-        logger.warning(
-            "qwen2_moe import sets moe_capacity_factor=E/k (drop-free, HF "
-            "semantics): the dense dispatch/combine einsums are O(tokens² · "
-            "experts · hidden) at this bound — for long-sequence training "
-            "lower capacity_factor (accepting drops) or expect high memory"
-        )
         return _llama_like_config(
             get,
             attn_qkv_bias=True,
@@ -225,12 +220,7 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
             n_experts=get("num_experts"),
             moe_top_k=get("num_experts_per_tok"),
             moe_norm_topk_prob=bool(get("norm_topk_prob", False)),
-            # HF qwen2-moe never drops tokens. capacity = ceil(t·k·cf/E), and
-            # a token contributes at most ONE slot per expert, so cf = E/k
-            # gives capacity = t — the minimal drop-free bound (all tokens on
-            # one expert). Dense dispatch is still O(t·E·t) at this bound;
-            # lower cf (accepting drops) for long-sequence training runs.
-            moe_capacity_factor=float(get("num_experts")) / float(get("num_experts_per_tok")),
+            moe_drop_tokens=False,  # HF never drops a token
             moe_shared_expert_dim=get("shared_expert_intermediate_size", 0) or 0,
             moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
         )
@@ -242,10 +232,32 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
             moe_top_k=get("num_experts_per_tok"),
             # HF mixtral ALWAYS renormalizes the top-k routing weights
             moe_norm_topk_prob=True,
-            # dropless (HF never drops): cf = E/k gives capacity = tokens,
-            # the minimal drop-free bound — same stance as qwen2_moe above
-            moe_capacity_factor=float(get("num_local_experts")) / float(get("num_experts_per_tok")),
+            moe_drop_tokens=False,  # HF never drops a token
             moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
+        )
+    if mt == "olmoe":
+        # llama attention with an RMSNorm over the WHOLE q and k projection
+        # (OlmoeAttention: q_norm(q_proj(x)), before the head reshape), every
+        # layer a sparse block of num_experts experts of width
+        # intermediate_size, top-k of the float32 softmax, renormalised only
+        # where norm_topk_prob says so (the published checkpoints: false)
+        if get("clip_qkv", None) is not None:
+            raise ValueError(
+                f"olmoe: clip_qkv={get('clip_qkv')} is not supported: the q/k/v "
+                "projections would have to be clamped before the norm, and no "
+                "published checkpoint sets it (OLMoE-1B-7B-0125: null)"
+            )
+        return _llama_like_config(
+            get,
+            qk_norm=True,
+            qk_norm_kind="rmsnorm_full",
+            attn_qkv_bias=bool(get("attention_bias", False)),
+            attn_out_bias=bool(get("attention_bias", False)),
+            n_experts=get("num_experts"),
+            moe_top_k=get("num_experts_per_tok"),
+            moe_norm_topk_prob=bool(get("norm_topk_prob", False)),
+            moe_drop_tokens=False,  # HF never drops a token
+            moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.01)),
         )
     if mt == "stablelm":
         return TransformerConfig(
@@ -658,7 +670,7 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         )
     raise ValueError(
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
-        "qwen2_moe, mixtral, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
+        "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
         "qwen3, qwen3_moe, megatron_gpt, bert, distilbert, clip_text_model"
     )
@@ -1105,6 +1117,10 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "gpt_neox": _gptneox_layer,
     "megatron_gpt": _megatron_gpt_layer,
     "mixtral": _mixtral_layer,
+    # olmoe's checkpoint names are the llama layer's with qwen3's q_norm /
+    # k_norm (here [nh*d] / [nkv*d] wide) and qwen2_moe's mlp.gate +
+    # mlp.experts.{e}.{gate,up,down}_proj: the same extractor reads them
+    "olmoe": _llama_layer,
     "stablelm": _stablelm_layer,
     "starcoder2": _starcoder2_layer,
 }
@@ -1146,6 +1162,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
         "text_model.embeddings.position_embedding.weight",
     ),
     "mixtral": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "olmoe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "stablelm": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "starcoder2": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
 }

@@ -120,7 +120,9 @@ class TransformerConfig:
     # shared across heads. "layernorm_per_head" (stablelm-2 qk_layernorm):
     # biasless LayerNorm with PER-HEAD weights ([nh, d] / [nkv, d]).
     # "layernorm" (phi qk_layernorm): one affine LayerNorm ([d] weight +
-    # bias) shared across heads.
+    # bias) shared across heads. "rmsnorm_full" (olmoe / olmo2): one RMSNorm
+    # over the WHOLE projection width ([nh*d] / [nkv*d] weights), applied
+    # before the head reshape.
     qk_norm: bool = False
     qk_norm_kind: str = "rmsnorm"
     attn_qkv_bias: bool = False  # qwen2-style bias on q/k/v projections
@@ -184,6 +186,13 @@ class TransformerConfig:
     n_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # the reference's ``drop_tokens`` (TopKGate, sharded_moe.py:452). False:
+    # every token reaches its top-k experts whatever the load, as the served
+    # HF MoE models compute; on one device's experts that is the sorted,
+    # grouped-matmul dispatch (parallel/moe/grouped.py), under expert
+    # parallelism the einsum dispatch at the capacity that drops nothing.
+    # moe_capacity_factor is then unused.
+    moe_drop_tokens: bool = True
     moe_aux_loss_coef: float = 0.01
     # Residual-MoE (reference moe/layer.py:29 use_residual, arXiv 2201.05596):
     # out = expert_out·coef₀ + dense_mlp(x)·coef₁ with coef = softmax of a
@@ -243,10 +252,10 @@ class TransformerConfig:
     def __post_init__(self):
         if self.norm_scheme not in ("pre", "post"):
             raise ValueError(f"norm_scheme={self.norm_scheme!r}: expected 'pre' or 'post'")
-        if self.qk_norm_kind not in ("rmsnorm", "layernorm", "layernorm_per_head"):
+        if self.qk_norm_kind not in ("rmsnorm", "rmsnorm_full", "layernorm", "layernorm_per_head"):
             raise ValueError(
                 f"qk_norm_kind={self.qk_norm_kind!r}: expected 'rmsnorm', "
-                "'layernorm' or 'layernorm_per_head'"
+                "'rmsnorm_full', 'layernorm' or 'layernorm_per_head'"
             )
         if self.position == "alibi" and (self.sliding_window > 0 or self.attn_scale is not None):
             # the alibi training branch rides the flash kernel's rank-1 bias
@@ -422,6 +431,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         if c.qk_norm_kind == "layernorm_per_head":
             layers["q_norm"] = jnp.ones((L, nh, d), dtype)
             layers["k_norm"] = jnp.ones((L, nkv, d), dtype)
+        elif c.qk_norm_kind == "rmsnorm_full":
+            layers["q_norm"] = jnp.ones((L, nh * d), dtype)
+            layers["k_norm"] = jnp.ones((L, nkv * d), dtype)
         else:
             layers["q_norm"] = jnp.ones((L, d), dtype)
             layers["k_norm"] = jnp.ones((L, d), dtype)
@@ -522,6 +534,10 @@ def param_partition_specs(config: TransformerConfig) -> Dict[str, Any]:
             # per-head weights shard with the heads (column-parallel q/k)
             layers["q_norm"] = P(None, m, None)
             layers["k_norm"] = P(None, m, None)
+        elif c.qk_norm_kind == "rmsnorm_full":
+            # one weight a projection column: sharded as the columns are
+            layers["q_norm"] = P(None, m)
+            layers["k_norm"] = P(None, m)
         else:
             # head-count-free [d] weights: replicated
             layers["q_norm"] = P(None, None)
@@ -930,8 +946,10 @@ def qk_norm_apply(c: TransformerConfig, x, w, head_axis: int, b=None):
     block and both v2 paged layer bodies. x: [..., d] with a head axis at
     ``head_axis``; w: [d] (qwen3 rmsnorm / phi affine layernorm, shared
     across heads) or [n_heads, d] (stablelm-2 biasless per-head LayerNorm);
-    ``b``: [d] bias for the phi form."""
-    if c.qk_norm_kind == "rmsnorm":
+    ``b``: [d] bias for the phi form. The olmoe form ("rmsnorm_full") norms
+    the whole projection width, so its callers apply it BEFORE the head
+    reshape (``qk_norm_full``): x [..., n_heads*d], w [n_heads*d]."""
+    if c.qk_norm_kind in ("rmsnorm", "rmsnorm_full"):
         from deepspeed_tpu.ops.normalization.fused_norm import rms_norm_reference
 
         return rms_norm_reference(x, w, c.norm_eps)
@@ -948,6 +966,12 @@ def qk_norm_apply(c: TransformerConfig, x, w, head_axis: int, b=None):
     shape[head_axis] = w.shape[0]
     shape[-1] = w.shape[1]
     return (y * w.astype(jnp.float32).reshape(shape)).astype(x.dtype)
+
+
+def qk_norm_full(c: TransformerConfig) -> bool:
+    """True where the q/k norm spans the whole projection width and so runs
+    before the head reshape; the per-head kinds run after it."""
+    return c.qk_norm and c.qk_norm_kind == "rmsnorm_full"
 
 
 def _window_bias(c: TransformerConfig, q_glob, k_pos, local_flag):
@@ -972,10 +996,13 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
     v = _proj(c, x, lp["wv"])
     if c.attn_qkv_bias:
         q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
+    if qk_norm_full(c):
+        q = qk_norm_apply(c, q, lp["q_norm"], head_axis=-1)
+        k = qk_norm_apply(c, k, lp["k_norm"], head_axis=-1)
     q = q.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
     k = k.reshape(b, s, nkv, d).transpose(0, 2, 1, 3)
     v = v.reshape(b, s, nkv, d).transpose(0, 2, 1, 3)
-    if c.qk_norm:
+    if c.qk_norm and not qk_norm_full(c):
         # qwen3 rmsnorm / phi affine layernorm / stablelm-2 per-head, pre-rope
         q = qk_norm_apply(c, q, lp["q_norm"], head_axis=1, b=lp.get("q_norm_b"))
         k = qk_norm_apply(c, k, lp["k_norm"], head_axis=1, b=lp.get("k_norm_b"))
@@ -1106,7 +1133,7 @@ def _mlp_block(c: TransformerConfig, lp, x):
     if c.n_experts > 0:
         from deepspeed_tpu.parallel.moe import moe_mlp
 
-        return moe_mlp(c, lp, x)
+        return moe_mlp(c, lp, x)[:2]
     up = _proj(c, x, lp["w_up"])
     if c.mlp_bias:
         up = up + lp["w_up_b"]

@@ -1,0 +1,244 @@
+"""Drop-free expert layer: rows sorted by expert, matmuls grouped by expert.
+
+What a served mixture-of-experts model computes as published (HF
+``MixtralSparseMoeBlock`` / ``Qwen2MoeSparseMoeBlock`` / ``OlmoeSparseMoeBlock``):
+every token goes to its top-k experts, whatever the load. The capacity-padded
+einsum dispatch of ``sharded_moe.py`` can only say that with a capacity equal to
+the token count, which costs O(t^2 E h) in dispatch and combine and makes every
+expert multiply a ``[t, h]`` buffer. Here the ``t * k`` (token, expert) pairs are
+sorted by expert (a stable sort, so a token's rows keep their order inside an
+expert), each expert multiplies only its own rows, and the result is unsorted
+and summed with the gate values:
+
+  probs = softmax(x Wr)              float32, over the E experts
+  p, e  = top_k(probs)               (renormalised where the model says so)
+  rows  = x[token of each pair], pairs sorted by e; group_sizes[E]
+  y     = gmm(silu(gmm(rows, Wg)) * gmm(rows, Wu), Wd)
+  out_t = sum over the token's k pairs of p * y
+
+Slots that are not ``live`` (the padding of a serving step's packed grid) are
+given to no expert: their pairs sort behind the last group and are neither
+multiplied nor counted.
+
+``grouped_matmul`` is the one new operation. On a TPU it is the Pallas kernel
+``dstpu_moe_gmm`` (the shape of ``jax.experimental.pallas.ops.tpu.megablox``: row
+tiles aligned to group boundaries through scalar prefetch, a tile that straddles
+a boundary visited once a group and stored under a row mask); elsewhere, and as
+its oracle, ``jax.lax.ragged_dot``. Its backward is the plain ``ragged_dot``
+forms: no train cell measures it yet.
+"""
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.accelerator.device import on_tpu
+
+# The kernel's name in a device trace (``pallas_call(name=...)`` names the Mosaic
+# custom call), beside the other ``dstpu_*`` names.
+MOE_GMM = "dstpu_moe_gmm"
+# one expert's weight block held in VMEM (twice: the pipeline's two buffers)
+_WEIGHT_BLOCK_BYTES = 4 << 20
+_VMEM_LIMIT_BYTES = 48 << 20
+
+
+def row_tile(m_rows: int, itemsize: int) -> int:
+    """Rows of one tile, from the shapes alone: the MXU's 128, or all the rows
+    of a smaller problem rounded up to the dtype's sublane tile (8 rows of 4
+    bytes, 16 of 2). One rule serves both regimes of a serving step because
+    the weights' bytes bound both: on the v5e a decode step's 256 rows over 64
+    experts of [2048, 1024] take 392 us a matmul under tiles of 16 and 374
+    under tiles of 128 (717 GB/s of weights), a chunk step's 8,448 rows 1,238
+    and 609 (PERF.md, PR 25): a tile's idle rows cost nothing beside its
+    expert's 4 MB, a visit does."""
+    lo = 8 * max(1, 4 // itemsize)
+    return min(128, -(-m_rows // lo) * lo)
+
+
+def _col_tile(k: int, n: int, itemsize: int) -> int:
+    """Columns of one weight block: the whole of ``n`` where ``[k, n]`` fits the
+    block budget, else the widest multiple of 128 that divides ``n`` and does."""
+    if n % 128 or k * n * itemsize <= _WEIGHT_BLOCK_BYTES:
+        return n
+    tn = max(128, (_WEIGHT_BLOCK_BYTES // (k * itemsize)) // 128 * 128)
+    while n % tn:
+        tn -= 128
+    return tn
+
+
+def tile_visits(group_sizes, tm: int, xp=jnp):
+    """(first tile, visits) of each group over rows sorted by group: a group
+    visits every ``tm``-row tile it has a row in. ``xp`` is ``jnp`` in the
+    program and ``numpy`` where the host counts what the kernel covered."""
+    ends = xp.cumsum(group_sizes, axis=-1)
+    first = (ends - group_sizes) // tm
+    visits = xp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    return first, visits
+
+
+def computed_rows(counts: np.ndarray, tm: int) -> int:
+    """Rows the kernel's tiles covered for these ``[..., E]`` group sizes (one
+    layer call a row): ``tm`` for every visit."""
+    return int(tile_visits(np.asarray(counts, np.int64), tm, np)[1].sum()) * tm
+
+
+def _gmm_kernel(offsets, group_of, tile_of, n_visits, layer, x_ref, w_ref, o_ref, *, tm):
+    del layer  # the weight block's index map reads it
+    v = pl.program_id(1)
+
+    @pl.when(v < n_visits[0])
+    def _():
+        g = group_of[v]
+        rows = tile_of[v] * tm + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+        mine = (rows >= offsets[g]) & (rows < offsets[g + 1])
+        acc = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+        # rows of the tile that belong to an earlier group keep what that
+        # group's visit stored (the block stays in VMEM between visits)
+        o_ref[...] = jnp.where(mine, acc, o_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+
+
+def _gmm_pallas(lhs, rhs, group_sizes, layer, tm: int, interpret: bool):
+    m, k = lhs.shape
+    _, E, _, n = rhs.shape
+    tn = _col_tile(k, n, rhs.dtype.itemsize)
+    V = m // tm + E - 1  # every tile once, and once more for each boundary inside one
+    first, visits = tile_visits(group_sizes.astype(jnp.int32), tm)
+    vend = jnp.cumsum(visits)
+    total = vend[-1]
+    # visits past the last real one repeat it (no new block is fetched) and are skipped
+    v = jnp.minimum(jnp.arange(V, dtype=jnp.int32), jnp.maximum(total - 1, 0))
+    group_of = jnp.minimum(jnp.searchsorted(vend, v, side="right"), E - 1).astype(jnp.int32)
+    tile_of = (first[group_of] + v - (vend[group_of] - visits[group_of])).astype(jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(group_sizes.astype(jnp.int32))])
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(n // tn, V),
+        in_specs=[
+            pl.BlockSpec((tm, k), lambda j, v, off, g, t, nv, ly: (t[v], 0)),
+            pl.BlockSpec((None, None, k, tn), lambda j, v, off, g, t, nv, ly: (ly[0], g[v], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((tm, tn), lambda j, v, off, g, t, nv, ly: (t[v], j)),
+    )
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # column tiles are independent; the visits of one row tile follow
+            # each other and share its output block
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
+        name=MOE_GMM,
+    )(offsets, group_of, tile_of, total.reshape(1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), lhs, rhs)
+
+
+def _gmm_ragged(lhs, rhs, group_sizes, layer):
+    w = jax.lax.dynamic_index_in_dim(rhs, layer, 0, keepdims=False)
+    return jax.lax.ragged_dot(lhs, w, group_sizes.astype(jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gmm_kernel_vjp(lhs, rhs, group_sizes, layer, tm, interpret):
+    return _gmm_pallas(lhs, rhs, group_sizes, layer, tm, interpret)
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, layer, tm, interpret):
+    return _gmm_pallas(lhs, rhs, group_sizes, layer, tm, interpret), (lhs, rhs, group_sizes, layer)
+
+
+def _gmm_bwd(tm, interpret, res, g):
+    lhs, rhs, group_sizes, layer = res
+    live = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
+    g = jnp.where(live[:, None], g, 0)  # rows of no group were never written
+    _, vjp = jax.vjp(lambda a, b: _gmm_ragged(a, b, group_sizes, layer), lhs, rhs)
+    d_lhs, d_rhs = vjp(g)
+    return (d_lhs, d_rhs, np.zeros(group_sizes.shape, jax.dtypes.float0),
+            np.zeros(jnp.shape(layer), jax.dtypes.float0))
+
+
+_gmm_kernel_vjp.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, tm: int, impl: Optional[str] = None, layer=None):
+    """``out[r] = lhs[r] @ rhs[group of r]`` for rows sorted by group.
+
+    lhs ``[m, k]`` with ``m`` a multiple of ``tm``; rhs ``[E, k, n]``, or with
+    ``layer`` (an index, traced or not) the whole stack ``[L, E, k, n]`` of
+    which the kernel reads layer ``layer``'s blocks in place: a slice in
+    front of a custom call is a copy, 805 MB a layer at OLMoE's widths
+    (PERF.md, PR 25). group_sizes ``[E]`` int32 whose sum may be under ``m``:
+    the rows behind the last group belong to none, and what the result holds
+    there is undefined (the caller masks them). ``impl``: ``"kernel"`` (the
+    Pallas kernel; on a TPU), ``"interpret"`` (the same kernel interpreted, for
+    tests on the CPU) or ``"ragged"`` (``jax.lax.ragged_dot``); None picks by
+    the platform."""
+    impl = impl or ("kernel" if on_tpu() else "ragged")
+    if layer is None:
+        rhs, layer = rhs[None], 0
+    if impl == "ragged":
+        return _gmm_ragged(lhs, rhs, group_sizes, layer)
+    return _gmm_kernel_vjp(lhs, rhs, group_sizes, jnp.asarray(layer, jnp.int32), tm, impl == "interpret")
+
+
+def route(config, logits, live=None):
+    """Router as published. logits ``[t, E]`` float32. Returns (gate values
+    ``[t, k]`` float32, expert ids ``[t, k]``, aux loss)."""
+    E, k = config.n_experts, config.moe_top_k
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    if config.moe_norm_topk_prob:
+        top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    # load-balancing loss of topkgating (sharded_moe.py): E/k * <probs_e> . <share_e>
+    chosen = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1)  # [t, E]
+    w = jnp.ones(probs.shape[0], jnp.float32) if live is None else live.astype(jnp.float32)
+    n = jnp.maximum(jnp.sum(w), 1.0)
+    me = jnp.sum(probs * w[:, None], axis=0) / n
+    ce = jnp.sum(chosen * w[:, None], axis=0) / n
+    return top_p, top_e, jnp.sum(me * ce) * E / k
+
+
+def experts_grouped(config, lp, tokens, logits, live=None, layer=None
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The routed experts of one layer on ``tokens [t, h]`` under the router's
+    ``logits [t, E]``. ``live [t]`` bool marks the slots that hold a token.
+    With ``layer``, the expert weights in ``lp`` are the whole stacks
+    ``[L, E, ...]`` (see ``grouped_matmul``). Returns (out ``[t, h]``, aux loss,
+    ``[E]`` int32 rows routed to each expert)."""
+    t, h = tokens.shape
+    E, k = config.n_experts, config.moe_top_k
+    top_p, top_e, aux = route(config, logits, live)
+
+    tm = row_tile(t * k, tokens.dtype.itemsize)
+    m = -(-t * k // tm) * tm
+    pair_e = top_e.astype(jnp.int32)
+    if live is not None:
+        pair_e = jnp.where(live[:, None], pair_e, E)  # behind the last group
+    pair_e = jnp.pad(pair_e.reshape(t * k), (0, m - t * k), constant_values=E)
+    order = jnp.argsort(pair_e, stable=True)
+    counts = jnp.sum(jax.nn.one_hot(pair_e, E + 1, dtype=jnp.int32), axis=0)[:E]
+    rows = tokens[jnp.minimum(order // k, t - 1)]
+
+    gmm = functools.partial(grouped_matmul, group_sizes=counts, tm=tm, layer=layer)
+    up = gmm(rows, lp["w_up"])
+    if config.activation in ("swiglu", "geglu"):
+        gate = gmm(rows, lp["w_gate"])
+        act = (jax.nn.gelu(gate) if config.activation == "geglu" else jax.nn.silu(gate)) * up
+    else:
+        act = jax.nn.gelu(up, approximate=config.activation != "gelu_exact")
+    y = gmm(act, lp["w_down"])
+
+    p_sorted = jnp.pad(top_p.reshape(t * k), (0, m - t * k))[order]
+    routed = jnp.arange(m) < jnp.sum(counts)
+    y = jnp.where(routed[:, None], y.astype(jnp.float32) * p_sorted[:, None], 0.0)
+    back = jnp.argsort(order)[: t * k]  # where each (token, choice) pair went
+    out = jnp.sum(y[back].reshape(t, k, h), axis=1)
+    return out.astype(tokens.dtype), aux, counts

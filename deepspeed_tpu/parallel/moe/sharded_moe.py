@@ -24,6 +24,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu.parallel.topology import EXPERT_AXIS, MODEL_AXIS, constrain, get_topology
 
 
+# the per-expert weights of a layer: what ``moe_mlp(layer=...)`` takes whole
+EXPERT_STACKS = ("w_up", "w_gate", "w_down")
+
+
 def _capacity(num_tokens: int, num_experts: int, capacity_factor: float, min_capacity: int = 4) -> int:
     """Reference _capacity (sharded_moe.py:167): ceil(tokens * cf / experts)."""
     cap = math.ceil(num_tokens * capacity_factor / num_experts)
@@ -117,9 +121,12 @@ def topkgating(
     drop_tokens: bool = True,
     drop_policy: str = "probs",
     normalize: bool = True,
+    live: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Reference topkgating (sharded_moe.py:374): general top-k with
-    normalized combine weights and per-expert capacity dropping.
+    normalized combine weights and per-expert capacity dropping. ``live``
+    ([s] bool): slots that hold a token; the others (a serving step's padding)
+    take no capacity slot, weigh nothing and are not counted.
 
     drop_policy (reference default "probs"): which tokens lose when an
     expert's capacity overflows —
@@ -131,13 +138,17 @@ def topkgating(
         locations2 += sum(mask1) offset semantics).
     """
     s, e = logits.shape
-    c = s * k if not drop_tokens else _capacity(s * k, e, capacity_factor, min_capacity)
+    # drop_tokens=False: a token takes at most one slot of an expert, so the
+    # token count is the capacity that drops nothing
+    c = s if not drop_tokens else _capacity(s * k, e, capacity_factor, min_capacity)
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # [s, e]
 
     topk_vals, topk_idx = jax.lax.top_k(gates, k)  # [s, k]
 
     # aux loss over the top-k mask (reference: uses full mask counts)
     mask = jnp.sum(_one_hot(topk_idx, e), axis=1)  # [s, e] (0/1, k ones)
+    if live is not None:
+        mask = mask * live[:, None].astype(mask.dtype)
     me = jnp.mean(gates, axis=0)
     ce = jnp.mean(mask, axis=0)
     l_aux = jnp.sum(me * ce) * e / k
@@ -292,25 +303,54 @@ def _moe_exchange_quant(config, lp, tokens, dispatch, combine, dtype):
     return fn(tokens, dispatch, combine, weights)
 
 
-def moe_mlp(config, lp, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """MoE MLP block used by models/transformer.py.
+def moe_mlp(config, lp, x: jax.Array, live: Optional[jax.Array] = None, layer=None
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """MoE MLP block used by models/transformer.py and the serving steps.
 
     lp: layer params with router [h,E], w_up [E,h,f], w_down [E,f,h]
-    (+ w_gate [E,h,f] for swiglu). x: [b, s, h].
-    Returns (out [b, s, h], aux_loss scalar).
+    (+ w_gate [E,h,f] for swiglu). x: [b, s, h]. ``live`` ([b, s] bool, or
+    None for all): the slots that hold a token; the padding of a serving
+    step's grid goes to no expert. ``layer``: where given, ``lp``'s expert
+    weights (``EXPERT_STACKS``) are the whole stacks [L, E, ...] and this is
+    the layer's index: the grouped kernel reads its layer's blocks in place,
+    where a slice taken by the caller would be copied in front of it.
+    Returns (out [b, s, h], aux_loss scalar, [E] int32 rows routed to each
+    expert).
 
-    The einsum pipeline (reference MOELayer.forward, sharded_moe.py:589):
-      gate → dispatch [s,e,c] → expert buffers [e,c,h] (GSPMD all-to-all as
-      e is expert-sharded) → per-expert MLP → combine back.
+    Two dispatches, chosen from the configuration and the mesh:
+      * ``moe_drop_tokens=False`` on one device's experts (``expert`` and
+        ``model`` axes of 1): rows sorted by expert and grouped matmuls
+        (grouped.py): no capacity, no dropped token.
+      * otherwise the einsum pipeline (reference MOELayer.forward,
+        sharded_moe.py:589): gate → dispatch [s,e,c] → expert buffers [e,c,h]
+        (GSPMD all-to-all as e is expert-sharded) → per-expert MLP → combine
+        back; with ``moe_drop_tokens=False`` at the capacity that drops
+        nothing (every token's k slots), which is what expert parallelism
+        keeps until a ragged all-to-all exists.
     """
     b, s, h = x.shape
     tokens = x.reshape(b * s, h)
-    logits = tokens @ lp["router"]
-    l_aux, combine, dispatch, _counts = topkgating(
+    live = None if live is None else live.reshape(b * s)
+    # the router as published: logits and softmax in float32 (a bf16 logit
+    # moves a near-tie between the k-th and the next expert)
+    logits = jnp.dot(tokens.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    topo = get_topology()
+    if not config.moe_drop_tokens and topo.axis_size(EXPERT_AXIS) == 1 and topo.axis_size(MODEL_AXIS) == 1:
+        from deepspeed_tpu.parallel.moe.grouped import experts_grouped
+
+        out, l_aux, counts = experts_grouped(config, lp, tokens, logits, live, layer)
+        return _moe_tail(config, lp, tokens, out).reshape(b, s, h), l_aux, counts
+    if layer is not None:  # an einsum reads a slice in place
+        lp = {k: jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False) if k in EXPERT_STACKS
+              else v for k, v in lp.items()}
+    l_aux, combine, dispatch, counts = topkgating(
         logits,
         k=config.moe_top_k,
         capacity_factor=config.moe_capacity_factor,
-        normalize=getattr(config, "moe_norm_topk_prob", True),
+        drop_tokens=config.moe_drop_tokens,
+        normalize=config.moe_norm_topk_prob,
+        live=live,
     )
     from deepspeed_tpu.parallel.moe.mappings import quantized_ep_active
 
@@ -337,6 +377,13 @@ def moe_mlp(config, lp, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
         # combine back to tokens (reverse all-to-all via resharding)
         out = jnp.einsum("tec,ech->th", combine.astype(x.dtype), expert_out)
+    out = _moe_tail(config, lp, tokens, out)
+    return out.reshape(b, s, h), l_aux, counts.astype(jnp.int32)
+
+
+def _moe_tail(config, lp, tokens, out):
+    """What every token gets beside its routed experts, on either dispatch:
+    the residual expert and the shared expert. tokens, out: [t, h]."""
 
     def _dense_mlp(prefix):
         up = tokens @ lp[f"{prefix}_up"]
@@ -347,18 +394,18 @@ def moe_mlp(config, lp, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
             act = jax.nn.gelu(up, approximate=config.activation != "gelu_exact")
         return act @ lp[f"{prefix}_down"]
 
-    if getattr(config, "moe_residual", False) and "res_coef" in lp:
+    if config.moe_residual and "res_coef" in lp:
         # Residual-MoE (reference moe/layer.py:29,47 — arXiv 2201.05596): a
         # dense MLP runs on every token; a learned 2-way softmax coefficient
         # mixes it with the (possibly dropped) expert output
         coef = jax.nn.softmax((tokens @ lp["res_coef"]).astype(jnp.float32), axis=-1)
         out = out * coef[:, 0:1].astype(out.dtype) + _dense_mlp("res") * coef[:, 1:2].astype(out.dtype)
-    if getattr(config, "moe_shared_expert_dim", 0) > 0 and "shared_up" in lp:
+    if config.moe_shared_expert_dim > 0 and "shared_up" in lp:
         # qwen2-moe shared expert: always-on dense expert scaled by a
         # sigmoid gate (HF Qwen2MoeSparseMoeBlock.shared_expert_gate)
         gate = jax.nn.sigmoid((tokens @ lp["shared_gate_proj"]).astype(jnp.float32))
         out = out + gate.astype(out.dtype) * _dense_mlp("shared")
-    return out.reshape(b, s, h), l_aux
+    return out
 
 
 class MoE:
@@ -374,5 +421,4 @@ class MoE:
         self.lp = layer_params
 
     def __call__(self, x):
-        out, l_aux = moe_mlp(self.config, self.lp, x)
-        return out, l_aux, None
+        return moe_mlp(self.config, self.lp, x)

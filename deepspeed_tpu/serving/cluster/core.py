@@ -186,6 +186,12 @@ class EngineCore:
         self._inc("scheduled_tokens_total", getattr(eng, "last_scheduled_tokens", 0))
         if getattr(eng, "last_prefill_tokens", 0):
             self._inc("steps_with_prefill_total")
+        moe = getattr(eng, "last_moe", None)
+        if moe:  # an expert model: what its expert layers routed and computed
+            self._inc("moe_routed_rows_total", moe["routed"])
+            self._inc("moe_computed_rows_total", moe["computed"])
+            self._inc("moe_hot_expert_rows_total", moe["hot"])
+            self._inc("moe_layer_calls_total", moe["calls"])
 
     # -- admission accounting --------------------------------------------
     def blocks_needed(self, req: Request, prefill_only: bool = False) -> int:

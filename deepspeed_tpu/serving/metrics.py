@@ -124,6 +124,14 @@ class ServingMetrics:
             "grid_slots_total": 0,
             "scheduled_tokens_total": 0,
             "steps_with_prefill_total": 0,
+            # expert models (EngineCore._count_step, from the [L, E] routed
+            # rows a step returns): live (token, expert) pairs, rows the
+            # expert matmuls covered, the fullest expert's rows summed over
+            # layer calls, and the layer calls
+            "moe_routed_rows_total": 0,
+            "moe_computed_rows_total": 0,
+            "moe_hot_expert_rows_total": 0,
+            "moe_layer_calls_total": 0,
             "admission_blocked_total": 0,
             # prefix cache (mirrors of PrefixCache's monotone counters)
             "prefix_queries_total": 0,

@@ -66,7 +66,7 @@ _SMOKE_FILES = {
     "test_compressed.py", "test_zero_one_lamb.py", "test_elastic_agent.py",
     "test_overlap.py", "test_serving.py", "test_prefix_cache.py",
     "test_flash_attention.py", "test_paged_attention.py", "test_kernels.py",
-    "test_qmatmul.py", "test_moe_gemm.py", "test_native_ops.py",
+    "test_qmatmul.py", "test_moe_grouped.py", "test_native_ops.py",
     "test_sparse_attention.py", "test_transformer_layer.py",
     "test_fused_ce.py", "test_misc_ops.py", "test_evoformer.py",
     "test_sharded_attention.py", "test_kv_transport.py",

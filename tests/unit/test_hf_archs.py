@@ -367,6 +367,21 @@ def tiny_qwen3_moe(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
+def tiny_olmoe(tmp_path_factory):
+    # q/k RMSNorm over the whole projection width, every layer sparse,
+    # top-2 of 8 NOT renormalised, untied head (the published OLMoE shape)
+    return _save_tiny(
+        tmp_path_factory, "hf_olmoe",
+        transformers.OlmoeConfig, transformers.OlmoeForCausalLM,
+        vocab_size=256, hidden_size=64, intermediate_size=48,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        max_position_embeddings=128, tie_word_embeddings=False,
+        output_router_logits=False,
+    )
+
+
+@pytest.fixture(scope="module")
 def tiny_bert(tmp_path_factory):
     # post-LN bidirectional encoder + token types + masked-LM head
     return _save_tiny(
@@ -482,6 +497,7 @@ _FIXTURES = {
     "distilbert": "tiny_distilbert",
     "qwen3": "tiny_qwen3",
     "qwen3_moe": "tiny_qwen3_moe",
+    "olmoe": "tiny_olmoe",
 }
 
 # gpt_neo's attn_scale=1.0 skips the 1/sqrt(d) shrink and bert's post-LN
@@ -819,11 +835,18 @@ def test_logits_parity(arch, request):
     elif arch == "qwen3_moe":
         assert cfg.qk_norm and cfg.n_experts == 4 and cfg.moe_norm_topk_prob
         assert cfg.moe_shared_expert_dim == 0
+    elif arch == "olmoe":
+        assert cfg.qk_norm and cfg.qk_norm_kind == "rmsnorm_full"
+        assert cfg.n_experts == 8 and cfg.moe_top_k == 2 and cfg.ffn_dim == 48
+        assert not cfg.moe_norm_topk_prob and not cfg.tie_embeddings
+    if cfg.n_experts:
+        assert not cfg.moe_drop_tokens  # HF never drops a token
 
 
 @pytest.mark.parametrize(
     "arch",
-    ["qwen2_moe", "falcon", "phi", "gemma", "bloom", "gptj", "gptneox", "mixtral", "stablelm"],
+    ["qwen2_moe", "falcon", "phi", "gemma", "bloom", "gptj", "gptneox", "mixtral", "stablelm",
+     "olmoe"],
 )
 def test_greedy_decode_parity(arch, request):
     hf_model, path = request.getfixturevalue(_FIXTURES[arch])
@@ -978,10 +1001,19 @@ def test_unsupported_arch_raises(tmp_path):
         load_hf_model(str(tmp_path))
 
 
-@pytest.mark.parametrize("arch", ["gpt2", "phi"])
+def test_olmoe_clip_qkv_is_refused():
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    hf = transformers.OlmoeConfig(num_hidden_layers=1, clip_qkv=8.0).to_dict()
+    with pytest.raises(ValueError, match="clip_qkv"):
+        config_from_hf(hf)
+
+
+@pytest.mark.parametrize("arch", ["gpt2", "phi", "olmoe"])
 def test_v2_engine_serves_biased_archs(arch, request):
     """The v2 paged engine must honor attention biases, partial rotary, the
-    parallel block, and learned positions — its layer_step is a separate
+    parallel block, learned positions, and (olmoe) the whole-width q/k norm
+    and the expert layer — its layer_step is a separate
     implementation from the training forward, so parity is asserted against
     the HF greedy decode through the FULL continuous-batching path."""
     hf_model, path = request.getfixturevalue(_FIXTURES[arch])

@@ -246,11 +246,11 @@ class TestResidualMoE:
         params = init_params(cfg, jax.random.key(0))
         lp = jax.tree.map(lambda a: a[0], params["layers"])  # layer 0
         x = jax.random.normal(jax.random.key(1), (2, 16, cfg.hidden_size), jnp.float32)
-        out, _ = moe_mlp(cfg, lp, x)
+        out, _, _ = moe_mlp(cfg, lp, x)
 
         # manual: coef-softmax mix of expert path and the dense residual MLP
         cfg_plain = get_config("mixtral-tiny", dtype="float32")
-        expert_out, _ = moe_mlp(cfg_plain, lp, x)
+        expert_out, _, _ = moe_mlp(cfg_plain, lp, x)
         tok = x.reshape(-1, cfg.hidden_size)
         coef = jax.nn.softmax(tok @ lp["res_coef"], axis=-1)
         dense = (jax.nn.silu(tok @ lp["res_gate"]) * (tok @ lp["res_up"])) @ lp["res_down"]
@@ -266,9 +266,9 @@ class TestResidualMoE:
         params = init_params(cfg, jax.random.key(0))
         lp = jax.tree.map(lambda a: a[0], params["layers"])
         x = jax.random.normal(jax.random.key(1), (1, 8, cfg.hidden_size), jnp.float32)
-        out, _ = moe_mlp(cfg, lp, x)
+        out, _, _ = moe_mlp(cfg, lp, x)
         cfg_plain = get_config("mixtral-tiny", dtype="float32")
-        base, _ = moe_mlp(cfg_plain, lp, x)
+        base, _, _ = moe_mlp(cfg_plain, lp, x)
         tok = x.reshape(-1, cfg.hidden_size)
         gate = jax.nn.sigmoid(tok @ lp["shared_gate_proj"])
         shared = (jax.nn.silu(tok @ lp["shared_gate"]) * (tok @ lp["shared_up"])) @ lp["shared_down"]

@@ -1,0 +1,104 @@
+"""OLMoE through the system against the benchmark's plain reference
+(benchmarks/reference/olmoe.py) on seeded random weights in float32:
+``models.forward`` on a whole sequence, and ``InferenceEngineV2`` serving it
+through the paged cache: a prompt prefilled in two chunks beside a decoding
+row, then both rows decoding, every returned logits row held to the
+reference's full forward pass over that sequence's own history."""
+
+import dataclasses
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.reference import olmoe as ref
+from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
+from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.models import forward, init_params
+from deepspeed_tpu.models.hf import config_from_hf
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# Logits here are of unit scale (largest ~4). The system and the reference do
+# the same float32 sums in another order (sorted rows against every expert
+# masked; a paged cache against one causal pass): measured 2e-6 on the CPU. The
+# same model computed in bfloat16 lands 1e-2 to 1e-1 away, a wrong norm width,
+# a renormalised gate or a dropped token whole tenths: 2e-4 separates them, and
+# test_a_bfloat16_computation_fails_the_tolerance holds the limit to that.
+TOL = 2e-4
+
+
+@pytest.fixture(scope="module")
+def model():
+    """The benchmark's configuration file at toy sizes: every key, the
+    published shape (MHA, every layer sparse, no renormalisation; 2 experts a
+    token of 8 where the published model has 8 of 64)."""
+    hf = json.load(open(os.path.join(REPO, "benchmarks", "configs", "olmoe-1b-7b.json")))
+    hf.update(hidden_size=64, intermediate_size=32, num_attention_heads=4, num_key_value_heads=4,
+              num_experts=8, num_experts_per_tok=2, num_hidden_layers=2, vocab_size=128,
+              max_position_embeddings=128)
+    cfg = dataclasses.replace(config_from_hf(hf), dtype="float32")
+    params = init_params(cfg, jax.random.key(0))
+    # norm weights away from 1, so that a norm over the wrong width shows
+    for i, name in enumerate(("q_norm", "k_norm", "attn_norm", "mlp_norm")):
+        w = params["layers"][name]
+        params["layers"][name] = w + 0.2 * jax.random.normal(jax.random.key(10 + i), w.shape)
+    return hf, cfg, params
+
+
+def test_forward_equals_the_reference(model):
+    hf, cfg, params = model
+    tokens = np.random.default_rng(0).integers(1, 128, size=40).astype(np.int32)
+    got, _ = forward(params, jnp.asarray(tokens)[None], cfg)
+    want = ref.logits(params, tokens, hf)
+    assert float(jnp.abs(want).max()) > 1.0
+    np.testing.assert_allclose(got[0], want, atol=TOL, rtol=0)
+    assert abs(float(ref.loss(params, tokens, hf)) - np.log(128)) < 1.5
+
+
+def test_a_bfloat16_computation_fails_the_tolerance(model):
+    hf, cfg, params = model
+    tokens = np.random.default_rng(0).integers(1, 128, size=40).astype(np.int32)
+    low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    got, _ = forward(low, jnp.asarray(tokens)[None], dataclasses.replace(cfg, dtype="bfloat16"))
+    want = ref.logits(params, tokens, hf)
+    assert float(jnp.abs(got[0].astype(jnp.float32) - want).max()) > 10 * TOL
+
+
+def test_the_reference_refuses_another_architecture(model):
+    hf, _, params = model
+    with pytest.raises(ValueError, match="OLMoE"):
+        ref.logits(params, np.arange(4), dict(hf, model_type="qwen3"))
+    with pytest.raises(ValueError, match="clip_qkv"):
+        ref.logits(params, np.arange(4), dict(hf, clip_qkv=8.0))
+
+
+def test_prefill_in_chunks_then_decode_through_the_paged_cache(model):
+    hf, cfg, params = model
+    rc = RaggedInferenceEngineConfig.from_dict({
+        "dtype": "float32", "prompt_chunk": 8, "max_prompt_chunks": 2,
+        "kv_cache": {"block_size": 4, "num_blocks": 48, "max_blocks_per_seq": 16},
+        "state_manager": {"max_tracked_sequences": 8, "max_ragged_batch_size": 64,
+                          "max_ragged_sequence_count": 4, "max_context": 64}})
+    eng = InferenceEngineV2(cfg, params, rc)
+    rng = np.random.default_rng(1)
+    history = {0: list(rng.integers(1, 128, size=5)), 1: list(rng.integers(1, 128, size=13))}
+    eng.scheduler.submit(0, np.asarray(history[0], np.int32))
+    checked, mixed = {0: 0, 1: 0}, 0
+    for step in range(9):
+        if step == 1:   # row 0 decodes from here on; row 1's prompt takes two chunks of 8 and 5
+            eng.scheduler.submit(1, np.asarray(history[1], np.int32))
+        out = eng.step()
+        mixed += eng.last_prefill_tokens > 0 and eng.last_scheduled_tokens > eng.last_prefill_tokens
+        for uid, row in out.items():
+            want = ref.logits(params, np.asarray(history[uid], np.int32), hf, rows=[-1])[0]
+            np.testing.assert_allclose(row, want, atol=TOL, rtol=0, err_msg=f"step {step} uid {uid}")
+            tok = int(np.argmax(row))
+            history[uid].append(tok)
+            eng.scheduler.feedback(uid, tok)
+            checked[uid] += 1
+    assert mixed == 2                      # both chunks of row 1 rode beside row 0's decode
+    assert checked[0] == 9 and checked[1] == 7 and len(history[1]) == 20

@@ -347,8 +347,8 @@ class TestMoEQuantWire:
         x = jax.random.normal(jax.random.key(1), (2, 16, cfg_q.hidden_size),
                               jnp.float32)
         try:
-            out_q, aux_q = moe_mlp(cfg_q, lp, x)
-            out_n, aux_n = moe_mlp(cfg_n, lp, x)
+            out_q, aux_q, _ = moe_mlp(cfg_q, lp, x)
+            out_n, aux_n, _ = moe_mlp(cfg_n, lp, x)
         finally:
             reset_topology()
         # gating is identical (it runs outside the island), so aux matches
