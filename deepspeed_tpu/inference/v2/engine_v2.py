@@ -335,6 +335,12 @@ class InferenceEngineV2:
         self.last_grid_slots = 0
         self.last_scheduled_tokens = 0
         self.last_prefill_tokens = 0
+        # decode attention: blocks the decode rows' contexts cover against
+        # the slots of their tables, summed over one layer's calls of the
+        # step or round (_count_paged); paged_live_blocks_total /
+        # paged_table_slots_total
+        self.last_paged_live_blocks = 0
+        self.last_paged_table_slots = 0
         # expert models: what the last step's expert layers routed and what
         # their kernel covered ({"routed", "computed", "hot", "calls"}, rows
         # and layer calls), from the [.., L, E] count the step programs
@@ -1078,11 +1084,13 @@ class InferenceEngineV2:
                      v_scale=None):
         """Decode attention: one token per row, per-ROW layer-offset tables
         [R, B] into the flat pools, dispatched through ``paged_attention``
-        with the impl resolved at engine init — the (T, B)-grid Pallas
-        kernel on TPU (scalar-prefetched block DMA; int8 pools dequantize
-        in-VMEM behind the halved HBM reads), the dense XLA gather+einsum
-        as ``impl="dense"`` (GSPMD shards it on the kv-head dim without a
-        shard_map island, and it wins at CPU/tp shapes).
+        with the impl resolved at engine init — on TPU the Pallas kernel,
+        whose grid is the blocks the rows' contexts cover and one tail a
+        row, read off ``positions`` / ``pool_limit`` / ``window`` (a table
+        slot a row does not hold costs nothing; int8 pools dequantize
+        in-VMEM behind the halved HBM reads), elsewhere the dense XLA
+        gather+einsum over whole tables (``impl="dense"``: GSPMD shards it
+        on the kv-head dim without a shard_map island; CPU and tp shapes).
         ``extra_kv``/``pool_limit``: this step's K/V ride alongside and
         the pool is read below the step's first position only, so no read
         needs this step's writes from the pool (they wait for _scatter_kv).
@@ -1098,6 +1106,18 @@ class InferenceEngineV2:
             k_scale=k_scale, v_scale=v_scale,
             extra_kv=extra_kv, pool_limit=pool_limit,
         )
+
+    def _count_paged(self, pool_tokens, calls: int = 1):
+        """Leave in ``last_paged_*`` what one layer's decode attention had to
+        read against what it was handed: ``pool_tokens`` [R] are the tokens
+        each row's pool window holds (<= 0: an inactive slot), a row's live
+        blocks are ``ceil(tokens / bs)`` and its table has B slots;
+        ``calls`` kernel calls a layer walk the same rows (a fused round's
+        steps, a verify round's K1 queries a row)."""
+        kv = self.config.kv_cache
+        held = np.maximum(np.asarray(pool_tokens, np.int64), 0)
+        self.last_paged_live_blocks = calls * int((-(-held // kv.block_size)).sum())
+        self.last_paged_table_slots = calls * len(held) * kv.max_blocks_per_seq
 
     def _side_buffers(self, *token_dims):
         """A zeroed (k, v) pair [L, *token_dims, nkv, d] in compute dtype:
@@ -1682,6 +1702,7 @@ class InferenceEngineV2:
                     tables[i, : len(seq.block_table)] = seq.block_table
                     uid_arr[i] = uid
                     active[i] = True
+                self._count_paged(positions, calls=n)
             with tr.span("engine.launch", track=track):
                 if self._multistep_jit is None or self._multistep_n != n:
                     self._multistep_jit = self._build_multistep_decode(n)
@@ -1943,6 +1964,7 @@ class InferenceEngineV2:
                     uid_arr[i] = uid
                     active[i] = True
                     n_input[i] = 1 + len(d)
+                self._count_paged(positions, calls=K1)
             with tr.span("engine.launch", track=track):
                 if k not in self._verify_jit:
                     self._verify_jit[k] = self._build_verify_step(k)
@@ -2092,6 +2114,7 @@ class InferenceEngineV2:
             batch = self.scheduler.next_batch()
             self.last_capped |= self.scheduler.drain_capped()
         self.last_grid_slots = self.last_scheduled_tokens = self.last_prefill_tokens = 0
+        self.last_paged_live_blocks = self.last_paged_table_slots = 0
         self._moe_pending = None
         if batch is None:
             return {}
@@ -2157,6 +2180,7 @@ class InferenceEngineV2:
                 dec_uids[i] = uid
                 blk[i] = seq.block_table[min(start // bs, nblk - 1)]
                 row[i] = start % bs
+            self._count_paged(dec_pos)
             for j, (uid, toks, start, _chunked) in enumerate(chk_rows):
                 seq = self.state_manager.get_sequence(uid)
                 n = len(toks)

@@ -16,14 +16,22 @@ Layout:
   block_tables per token [T, B] or per row [R, B]
   q_pos        global position of each query in its sequence
 
-Implementations:
-  * ``paged_decode_attention_dense`` / ``paged_chunk_attention`` — plain XLA
-    (block gather + masked einsum). Profiled fastest on the bench shapes:
-    per-Pallas-program launch overhead (~9 us) dominates grid kernels at
-    serving grids, while the gather is one fused op.
-  * ``paged_attention`` — the (T, B)-grid Pallas kernel (one program per
-    (token, context-block), scalar-prefetched DMA). Kept for the per-token
-    fused path and as the ``kernel`` impl option.
+Implementations (``paged_attention(impl=...)``; ``auto`` is the kernel on a
+TPU for head sizes 64 / 128 / 256 and the dense form elsewhere):
+  * ``kernel`` — the Pallas kernel ``dstpu_paged_decode``. Its grid is the
+    list of visits the call's rows need (``_visit_list``): for each query
+    row the table slots its context covers, in order, then one tail program
+    for the extra columns and the finish. A slot a row does not hold is no
+    program, so a call costs what its rows hold, not ``T x B``. The block
+    of a visit comes in through a scalar-prefetched index map; the list is
+    computed from ``q_pos`` / ``pool_limit`` / ``window`` by a few small XLA
+    fusions in front of the call.
+  * ``dense`` (``paged_decode_attention_dense``) / ``paged_chunk_attention``
+    — plain XLA: gather every slot of every table, then a masked einsum.
+    What runs off the TPU, at ``tp_size > 1`` (GSPMD shards it on the
+    kv-head dim) and for prefill chunks; it reads the whole table whatever
+    the rows hold.
+  * ``reference`` — the per-token jnp oracle of the tests.
 """
 
 import functools
@@ -87,15 +95,17 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables, q_pos, trash_bl
 
 
 def _paged_kernel(
-    *refs, bs, nh, nkv, d, B, E=0, window=0, scale=None, int8=False,
-    has_limit=False
+    *refs, bs, nh, nkv, d, B, E=0, window=0, scale=None, int8=False
 ):
-    """(T, B [+1])-grid kernel body. ``refs`` layout — scalar prefetch
-    (SMEM): bt [T, B], qpos [T], trash [1], limit [T] if ``has_limit`` —
-    then tensor blocks (VMEM): epos (1, 1, E) if ``E``, q (1, nh, d),
-    k (1, bs, nkv, d), v, ks/vs scale planes (1, bs, nkv) if ``int8``,
-    ke/ve (1, E, nkv, d) if ``E`` — then o (1, nh, d) and the m/l/acc
-    flash scratch.
+    """One program a VISIT: the grid is the list of (row, table slot) pairs
+    the rows' contexts cover, each row's pool blocks in order and then its
+    tail (the extra columns and the finish), so a table slot a row does not
+    hold costs nothing. ``refs`` layout — scalar prefetch (SMEM): bt [T, B],
+    qpos [T], trash [1], limit [T], vrow [G], vslot [G] (the visit list,
+    ``_visit_list``) — then tensor blocks (VMEM): epos (1, 1, E) if ``E``,
+    q (1, nh, d), k (1, bs, nkv, d), v, ks/vs scale planes (1, bs, nkv) if
+    ``int8``, ke/ve (1, E, nkv, d) if ``E`` — then o (1, nh, d) and the
+    m/l/acc flash scratch.
 
     ``trash`` rides as a prefetch operand (not a static kwarg) because the
     engine's flat multi-layer views use layer-offset trash ids — traced
@@ -105,8 +115,8 @@ def _paged_kernel(
     in fp32 right after the halved-HBM block DMA, so the VPU multiply
     hides under the transfer (the EQuARX argument applied to HBM)."""
     it = iter(refs)
-    bt_ref, qpos_ref, trash_ref = next(it), next(it), next(it)
-    limit_ref = next(it) if has_limit else None
+    bt_ref, qpos_ref, trash_ref, limit_ref = next(it), next(it), next(it), next(it)
+    vrow_ref, vslot_ref = next(it), next(it)
     epos_ref = next(it) if E else None
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     ks_ref = next(it) if int8 else None
@@ -116,15 +126,14 @@ def _paged_kernel(
     o_ref = next(it)
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
 
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
+    g = pl.program_id(0)
+    t = vrow_ref[g]
+    slot = vslot_ref[g]  # >= B: the row's tail
     group = nh // nkv
     scale = scale if scale is not None else d**-0.5
-    trash = trash_ref[0]
     qpos = qpos_ref[t]
 
-    @pl.when(j == 0)
+    @pl.when((g == 0) | (vrow_ref[jnp.maximum(g - 1, 0)] != t))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -134,8 +143,8 @@ def _paged_kernel(
 
     def flash_accum(k, v, valid):
         """One online-softmax accumulation sweep: k/v [nk, nkv, d] fp32,
-        valid [1, nk]. Disjoint per-kv-head scratch slices, so reading
-        m/l once up front is safe."""
+        valid [1, nk] or None for all. Disjoint per-kv-head scratch slices,
+        so reading m/l once up front is safe."""
         m_prev = m_scr[...]  # [nh, 128] (col 0 meaningful)
         l_prev = l_scr[...]
         for n in range(nkv):
@@ -145,7 +154,8 @@ def _paged_kernel(
             s = jax.lax.dot_general(
                 qn, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )  # [group, nk]
-            s = jnp.where(valid, s, NEG_INF)
+            if valid is not None:
+                s = jnp.where(valid, s, NEG_INF)
             m_p = m_prev[n * group : (n + 1) * group, :1]  # [group, 1]
             m_new = jnp.maximum(m_p, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_p - m_new)
@@ -159,36 +169,46 @@ def _paged_kernel(
             m_scr[n * group : (n + 1) * group, :1] = m_new
             l_scr[n * group : (n + 1) * group, :1] = l_new
 
-    def pool_block():
-        jb = jnp.minimum(j, B - 1)  # clamped: the j == B step is the extras
-        blk = bt_ref[t, jb]
-        kpos = jb * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)  # [1, bs]
-        if has_limit:
-            # explicit pool window (write-after-read: the pool holds only
-            # positions below the step/round start); qpos < 0 marks padded
-            # query slots that must see nothing
-            valid = (kpos < limit_ref[t]) & (qpos >= 0)
-        else:
-            valid = kpos <= qpos
-        valid = valid & (blk != trash)
+    # the pool holds the row's keys below ``limit`` (the explicit pool window
+    # of the write-after-read protocol, else the causal <=; 0 for a padded
+    # query slot, which must see nothing). A block wholly inside the row's
+    # context takes no mask: all but a row's last (under a window, and its
+    # first) are such
+    limit = limit_ref[t]
+    live = bt_ref[t, jnp.minimum(slot, B - 1)] != trash_ref[0]
+    whole = live & ((slot + 1) * bs <= limit)
+    if window:
+        whole = whole & (qpos - slot * bs < window)
+
+    def in_context(kpos):
+        ok = (kpos < limit) & live
         if window:
             from deepspeed_tpu.ops.attention.core import window_too_far
 
-            valid = valid & jnp.logical_not(window_too_far(qpos, kpos, window))
+            ok = ok & jnp.logical_not(window_too_far(qpos, kpos, window))
+        return ok
+
+    def pool_block(masked):
         k = k_ref[0].astype(jnp.float32)  # [bs, nkv, d]
         v = v_ref[0].astype(jnp.float32)
         if int8:
             k = k * ks_ref[0][..., None]
             v = v * vs_ref[0][..., None]
+        valid = None
+        if masked:
+            # a masked key's weight is exactly 0, and 0 x NaN is not: what the
+            # block holds outside the row's context must not reach the sum
+            krow = slot * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+            v = jnp.where(in_context(krow), v, 0.0)
+            valid = in_context(slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1))
         flash_accum(k, v, valid)
 
-    if E:
-        @pl.when(j < B)
-        def _pool():
-            pool_block()
+    pl.when((slot < B) & whole)(lambda: pool_block(masked=False))
+    pl.when((slot < B) & jnp.logical_not(whole))(lambda: pool_block(masked=True))
 
-        @pl.when(j == B)
-        def _extra():
+    @pl.when(slot >= B)
+    def _tail():
+        if E:
             epos = epos_ref[0]  # [1, E]
             valid = (epos >= 0) & (epos <= qpos)
             if window:
@@ -198,11 +218,6 @@ def _paged_kernel(
             flash_accum(
                 ke_ref[0].astype(jnp.float32), ve_ref[0].astype(jnp.float32), valid
             )
-    else:
-        pool_block()
-
-    @pl.when(j == nj - 1)
-    def _finish():
         l = l_scr[:, :1]
         # fully-masked token (all-trash padding): m never left NEG_INF and
         # every p degenerated to exp(0) — emit 0, matching the reference
@@ -284,35 +299,46 @@ def paged_attention(
     # kernel path; off-TPU it only runs interpreted (CPU tests)
     interpret = bool(interpret) or not on_tpu()
     B = block_tables.shape[1]
-    has_limit = pool_limit is not None
     E = 0 if extra_kv is None else int(extra_kv[0].shape[1])
-    num_scalar = 3 + (1 if has_limit else 0)
-
-    if E:
-        blk_idx = lambda t, j, *s: (s[0][t, jnp.minimum(j, B - 1)], 0, 0, 0)
+    q_pos = q_pos.astype(jnp.int32)
+    if pool_limit is None:
+        limit = q_pos + 1  # the causal <=
     else:
-        blk_idx = lambda t, j, *s: (s[0][t, j], 0, 0, 0)
+        limit = jnp.where(q_pos >= 0, jnp.asarray(pool_limit, jnp.int32).reshape(T), 0)
+    n_visits, vrow, vslot = _visit_list(q_pos, limit, bs, B, int(window))
+
+    # index maps see (g, bt, qpos, trash, limit, vrow, vslot): a row's operands
+    # follow vrow[g]; the pool's follow the table. A tail's slot is B + the
+    # slot its row fetched last: the same block index as the program before
+    # it, so nothing is fetched for it
+    def per_row(*shape):
+        return pl.BlockSpec((1,) + shape, lambda g, *s: (s[4][g],) + (0,) * len(shape))
+
+    def per_block(*shape):
+        def index(g, *s):
+            slot = s[5][g]
+            blk = s[0][s[4][g], jnp.where(slot >= B, slot - B, slot)]
+            return (blk,) + (0,) * len(shape)
+
+        return pl.BlockSpec((1,) + shape, index)
+
     in_specs = []
     if E:
         # [T, 1, E]: a (1, E) window of a [T, E] plane is not a legal Mosaic
         # block (last two dims must be (8, 128)-aligned or whole)
-        in_specs.append(pl.BlockSpec((1, 1, E), lambda t, j, *s: (t, 0, 0)))
-    in_specs.append(pl.BlockSpec((1, nh, d), lambda t, j, *s: (t, 0, 0)))
-    in_specs.append(pl.BlockSpec((1, bs, nkv, d), blk_idx))
-    in_specs.append(pl.BlockSpec((1, bs, nkv, d), blk_idx))
+        in_specs.append(per_row(1, E))
+    in_specs.append(per_row(nh, d))
+    in_specs.extend([per_block(bs, nkv, d), per_block(bs, nkv, d)])
     if int8_pool:
-        scale_idx = lambda t, j, *s: blk_idx(t, j, *s)[:3]
-        in_specs.append(pl.BlockSpec((1, bs, nkv), scale_idx))
-        in_specs.append(pl.BlockSpec((1, bs, nkv), scale_idx))
+        in_specs.extend([per_block(bs, nkv), per_block(bs, nkv)])
     if E:
-        in_specs.append(pl.BlockSpec((1, E, nkv, d), lambda t, j, *s: (t, 0, 0, 0)))
-        in_specs.append(pl.BlockSpec((1, E, nkv, d), lambda t, j, *s: (t, 0, 0, 0)))
+        in_specs.extend([per_row(E, nkv, d), per_row(E, nkv, d)])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_scalar,
-        grid=(T, B + (1 if E else 0)),
+        num_scalar_prefetch=6,
+        grid=(n_visits,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, nh, d), lambda t, j, *s: (t, 0, 0)),
+        out_specs=per_row(nh, d),
         scratch_shapes=[
             pltpu.VMEM((nh, 128), jnp.float32),
             pltpu.VMEM((nh, 128), jnp.float32),
@@ -321,15 +347,16 @@ def paged_attention(
     )
     kernel = functools.partial(
         _paged_kernel, bs=bs, nh=nh, nkv=nkv, d=d, B=B, E=E,
-        window=int(window), scale=scale, int8=int8_pool, has_limit=has_limit,
+        window=int(window), scale=scale, int8=int8_pool,
     )
     operands = [
         block_tables.astype(jnp.int32),
-        q_pos.astype(jnp.int32),
+        q_pos,
         jnp.asarray(trash_block, jnp.int32).reshape(1),
+        limit,
+        vrow,
+        vslot,
     ]
-    if has_limit:
-        operands.append(jnp.asarray(pool_limit, jnp.int32).reshape(T))
     if E:
         operands.append(jnp.asarray(extra_kv[2], jnp.int32).reshape(T, 1, E))
     operands.append(q)
@@ -343,13 +370,40 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, nh, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            # tokens are independent (scratch re-inits at j==0) → megacore
-            # can split the T dim; only the block dim accumulates
-            dimension_semantics=("parallel", "arbitrary")
+            # one flat axis of visits: a row's programs follow one another
+            # and accumulate into the same scratch
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
         name=PAGED_DECODE,
     )(*operands)
+
+
+def _visit_list(q_pos, limit, bs: int, B: int, window: int):
+    """The programs of one kernel call, from what the call is given. Row
+    ``t``'s context covers table slots ``lo..hi``: ``hi = ceil(limit / bs)``
+    and ``lo`` the block of the first key a sliding ``window`` still admits
+    (core.window_too_far: ``q_pos - window + 1``), else 0. Its programs are
+    those slots in order and then its tail. Returns (the number of programs,
+    vrow [T * (B + 1)], vslot [T * (B + 1)]): program ``g`` works for row
+    ``vrow[g]`` on table slot ``vslot[g]``, or is the row's tail where
+    ``vslot[g] >= B`` (then ``vslot[g] - B`` is the slot the row fetched
+    last). Entries past the number of programs are never run."""
+    T = q_pos.shape[0]
+    hi = jnp.clip((limit + bs - 1) // bs, 0, B)
+    lo = jnp.maximum(q_pos - window + 1, 0) // bs if window else jnp.zeros_like(hi)
+    n = jnp.maximum(hi - lo, 0)
+    ends = jnp.cumsum(n + 1)
+    starts = ends - (n + 1)
+    g = jnp.arange(T * (B + 1), dtype=jnp.int32)
+    # program g is row t's iff starts[t] <= g < ends[t]; a [G, T] compare and
+    # a sum stand in for the gathers, which the TPU runs an element at a time
+    mine = (g[:, None] >= starts[None]) & (g[:, None] < ends[None])
+    of_row = lambda x: jnp.sum(jnp.where(mine, x[None], 0), axis=1)
+    j, n_g, lo_g = g - of_row(starts), of_row(n), of_row(lo)
+    last = jnp.minimum(lo_g + jnp.maximum(n_g - 1, 0), B - 1)
+    vslot = jnp.where(j < n_g, lo_g + j, B + last)
+    return ends[-1], of_row(jnp.arange(T, dtype=jnp.int32)), vslot
 
 
 def paged_decode_attention_dense(
@@ -367,10 +421,9 @@ def paged_decode_attention_dense(
     v_scale=None,
 ) -> jax.Array:
     """Decode attention as plain XLA (block gather + masked einsum) — no
-    Pallas. On the profile (PERF.md serving roofline) per-program launch
-    overhead (~9 us x grid size) dominates grid kernels at decode shapes,
-    while the whole-table gather is a single fused op; the gather over-reads
-    unallocated (trash) slots but stays ahead until contexts are long.
+    Pallas. One fused gather over every slot of every table: it over-reads
+    unallocated (trash) slots, which the kernel skips, so it is the path
+    for where the kernel does not run (off the TPU, tp_size > 1).
     GSPMD shards it (cache on the kv-head dim) without a shard_map island.
     q [R, nh, d], tables [R, B] per-row; ``trash_block`` may be traced
     (layer-offset trash ids).
@@ -465,10 +518,10 @@ def paged_chunk_attention(
     """Prefill-chunk attention: Rc rows x tq new tokens each, every row's
     tokens sharing that ROW's block table (q [Rc, tq, nh, d],
     row_tables [Rc, B], q_pos [Rc, tq] global positions, -1 = padding).
-    One context gather per ROW (not per token — the (T, B)-grid kernel's
-    launch-overhead failure mode at prefill grids) then a dense masked
-    softmax; chunk MXU work is real matmuls. Padded tail tokens (q_pos < 0)
-    emit exactly 0.
+    One context gather per ROW (not per token: the decode kernel would
+    walk the row's context once for every token of the chunk) then a dense
+    masked softmax; chunk MXU work is real matmuls. Padded tail tokens
+    (q_pos < 0) emit exactly 0.
 
     ``new_kv`` = (ke [Rc, tq, nkv, d], ve): THIS chunk's not-yet-cached
     K/V — in-chunk attention runs causally over them while the pool covers

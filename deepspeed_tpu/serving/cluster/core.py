@@ -186,6 +186,8 @@ class EngineCore:
         self._inc("scheduled_tokens_total", getattr(eng, "last_scheduled_tokens", 0))
         if getattr(eng, "last_prefill_tokens", 0):
             self._inc("steps_with_prefill_total")
+        self._inc("paged_live_blocks_total", getattr(eng, "last_paged_live_blocks", 0))
+        self._inc("paged_table_slots_total", getattr(eng, "last_paged_table_slots", 0))
         moe = getattr(eng, "last_moe", None)
         if moe:  # an expert model: what its expert layers routed and computed
             self._inc("moe_routed_rows_total", moe["routed"])

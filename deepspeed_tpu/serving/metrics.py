@@ -124,6 +124,11 @@ class ServingMetrics:
             "grid_slots_total": 0,
             "scheduled_tokens_total": 0,
             "steps_with_prefill_total": 0,
+            # decode attention (EngineCore._count_step): blocks the decode
+            # rows' contexts cover against the slots of their block tables,
+            # one layer's kernel calls of every step or round
+            "paged_live_blocks_total": 0,
+            "paged_table_slots_total": 0,
             # expert models (EngineCore._count_step, from the [L, E] routed
             # rows a step returns): live (token, expert) pairs, rows the
             # expert matmuls covered, the fullest expert's rows summed over

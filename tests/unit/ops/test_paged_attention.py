@@ -468,3 +468,191 @@ def test_paged_attention_impl_and_scale_validation():
     with pytest.raises(ValueError, match="not int8"):
         paged_attention(q, kc, vc, bt, qpos, trash, impl="dense",
                         k_scale=ks, v_scale=vs)
+
+
+# ---------------------------------------------------------------------------
+# the kernel visits only the blocks a row's context covers: ragged rows in
+# one call, the bounds at a block's edges, and what lies outside a context
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.ops.attention.paged_pallas import _visit_list
+
+RAGGED_BS, RAGGED_B = 16, 4
+# tokens the pool holds for each row: nothing, one, a block less one, a whole
+# block, a block and one, a full table; the last row is an inactive slot
+RAGGED_CONTEXTS = (0, 1, RAGGED_BS - 1, RAGGED_BS, RAGGED_BS + 1, RAGGED_B * RAGGED_BS)
+
+
+def _ragged_call(rng, nh, nkv, d, int8=False):
+    """Rows of RAGGED_CONTEXTS plus one inactive slot, every row on blocks of
+    its own. Returns (q, pools, scales kwargs, tables, contexts, trash)."""
+    bs, B = RAGGED_BS, RAGGED_B
+    ctx = np.array(RAGGED_CONTEXTS + (-1,), np.int32)
+    T = len(ctx)
+    NB = T * B + 1
+    trash = NB - 1
+    bt = np.full((T, B), trash, np.int32)
+    for t, c in enumerate(ctx):
+        n = min(max(int(c), 0) // bs + 1, B)  # the block the next token lands in too
+        if c >= 0:
+            bt[t, :n] = t * B + np.arange(n)
+    q = jnp.asarray(rng.normal(size=(T, nh, d)), jnp.float32)
+    kc, vc, kq, ks, vq, vs = _quantized_pool(rng, NB, bs, nkv, d)
+    pools, kw = ((kq, vq), dict(k_scale=ks, v_scale=vs)) if int8 else ((kc, vc), {})
+    return q, pools, kw, bt, ctx, trash
+
+
+@pytest.mark.parametrize("form", ["plain", "split", "split-int8"])
+@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (4, 1, 64)])
+def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form):
+    """Contexts 0, 1, bs-1, bs, bs+1 and a full table beside an inactive slot
+    in ONE call. ``plain``: the query is the context's last token, against
+    the per-token reference. ``split``: the engine's split-step form (the pool
+    holds the context, the query's own K/V rides as the extra column),
+    against the dense form; ``split-int8`` the same over an int8 pool."""
+    rng = np.random.default_rng(20)
+    q, (pk, pv), kw, bt, ctx, trash = _ragged_call(rng, nh, nkv, d, int8=form.endswith("int8"))
+    T = len(ctx)
+    if form == "plain":
+        qpos = jnp.asarray(ctx - 1)  # context 0 and the inactive slot: nothing to see
+        ref = paged_attention_reference(
+            q, pk, pv, jnp.asarray(np.where(ctx[:, None] > 0, bt, trash)),
+            jnp.maximum(qpos, 0), trash)
+        out = paged_attention(q, pk, pv, jnp.asarray(bt), qpos, trash,
+                              impl="kernel", interpret=True)
+        for t in np.flatnonzero(ctx <= 0):
+            np.testing.assert_array_equal(np.asarray(out[t]), 0.0)
+    else:
+        ke = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+        ve = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+        kw.update(extra_kv=(ke, ve, jnp.asarray(ctx[:, None])),
+                  pool_limit=jnp.asarray(np.maximum(ctx, 0)))
+        ref = paged_attention(q, pk, pv, jnp.asarray(bt), jnp.asarray(ctx), trash,
+                              impl="dense", **kw)
+        out = paged_attention(q, pk, pv, jnp.asarray(bt), jnp.asarray(ctx), trash,
+                              impl="kernel", interpret=True, **kw)
+        # context 0 sees its own token alone; the inactive slot sees nothing
+        np.testing.assert_allclose(
+            np.asarray(out[0]), np.repeat(np.asarray(ve[0, 0]), nh // nkv, axis=0), atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(out[-1]), 0.0)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["plain", "split"])
+def test_paged_kernel_window_starts_past_block_zero(form):
+    """A window whose first block in band is not block 0: the kernel starts
+    its walk there, and a row still inside the window starts at 0."""
+    rng = np.random.default_rng(21)
+    T, nh, nkv, d, bs, NB, B, window = 4, 4, 2, 64, 16, 17, 4, 20
+    trash = NB - 1
+    q = jnp.asarray(rng.normal(size=(T, nh, d)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
+    bt = jnp.asarray(np.arange(T * B, dtype=np.int32).reshape(T, B))
+    qpos = np.array([60, 36, 35, 7], np.int32)  # first block in band: 2, 1, 1, 0
+    _, vrow, vslot = _visit_list(jnp.asarray(qpos), jnp.asarray(qpos + 1), bs, B, window)
+    first = [int(vslot[np.flatnonzero(np.asarray(vrow) == t)[0]]) for t in range(T)]
+    assert first == [2, 1, 1, 0]
+    if form == "plain":
+        ref = paged_attention_reference(q, kc, vc, bt, jnp.asarray(qpos), trash, window=window)
+        out = paged_attention(q, kc, vc, bt, jnp.asarray(qpos), trash, impl="kernel",
+                              interpret=True, window=window)
+    else:
+        ke = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+        ve = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+        kw = dict(extra_kv=(ke, ve, jnp.asarray(qpos[:, None])), pool_limit=jnp.asarray(qpos),
+                  window=window)
+        ref = paged_attention(q, kc, vc, bt, jnp.asarray(qpos), trash, impl="dense", **kw)
+        out = paged_attention(q, kc, vc, bt, jnp.asarray(qpos), trash, impl="kernel",
+                              interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+def test_paged_kernel_verify_round_per_token_form(int8):
+    """The speculative verify round's call: T = R x K1 queries, each carrying
+    its ROW's table and pool window, the row's K1 fresh K/V as shared extra
+    columns whose mask is the in-chunk causal one; padded slots (q_pos -1)
+    emit 0. Against the dense form on the same arguments."""
+    rng = np.random.default_rng(22)
+    R, K1, nh, nkv, d, bs, B = 3, 4, 8, 4, 64, 16, 3
+    NB = R * B + 1
+    trash = NB - 1
+    pos0 = np.array([37, 16, 5], np.int32)        # tokens each row has cached
+    n_new = np.array([4, 2, 3], np.int32)         # live tokens of its K1
+    tables = np.full((R, B), trash, np.int32)
+    for r in range(R):
+        n = (pos0[r] + K1 - 1) // bs + 1
+        tables[r, :n] = r * B + np.arange(n)
+    qpos = np.where(np.arange(K1)[None] < n_new[:, None], pos0[:, None] + np.arange(K1)[None], -1)
+    q = jnp.asarray(rng.normal(size=(R * K1, nh, d)), jnp.float32)
+    kc, vc, kq, ks, vq, vs = _quantized_pool(rng, NB, bs, nkv, d)
+    (pk, pv), kw = ((kq, vq), dict(k_scale=ks, v_scale=vs)) if int8 else ((kc, vc), {})
+    k_new = rng.normal(size=(R, K1, nkv, d)).astype(np.float32)
+    v_new = rng.normal(size=(R, K1, nkv, d)).astype(np.float32)
+    rep = lambda a: jnp.asarray(np.repeat(a, K1, axis=0))
+    kw.update(extra_kv=(rep(k_new), rep(v_new), rep(qpos.astype(np.int32))),
+              pool_limit=rep(pos0))
+    args = (q, pk, pv, rep(tables), jnp.asarray(qpos.reshape(-1).astype(np.int32)), trash)
+    ref = paged_attention(*args, impl="dense", **kw)
+    out = paged_attention(*args, impl="kernel", interpret=True, **kw)
+    # the dense form has no padded-slot convention of its own (the engine's
+    # alternative there is paged_chunk_attention): compare the live slots
+    live = qpos.reshape(-1) >= 0
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live], atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[~live], 0.0)
+
+
+@pytest.mark.parametrize("row", range(len(RAGGED_CONTEXTS) + 1))
+@pytest.mark.parametrize("form", ["plain", "split"])
+def test_paged_kernel_ignores_what_a_row_does_not_hold(form, row):
+    """NaN in every pool block row ``row`` does not hold, in the trash block,
+    and in the rows of its last block at or beyond its context: the row's
+    output is finite and equal to the clean pool's, bit for bit."""
+    rng = np.random.default_rng(23)
+    nh, nkv, d, bs = 8, 4, 64, RAGGED_BS
+    q, (kc, vc), _, bt, ctx, trash = _ragged_call(rng, nh, nkv, d)
+    T = len(ctx)
+    c = int(max(ctx[row], 0))
+
+    def poisoned(pool):
+        bad = np.full(pool.shape, np.nan, np.float32)
+        for j in range(-(-c // bs)):  # the blocks the row's context covers
+            keep = min(bs, c - j * bs)
+            bad[bt[row, j], :keep] = np.asarray(pool)[bt[row, j], :keep]
+        return jnp.asarray(bad)
+
+    if form == "plain":  # an empty context has no query: q_pos -1, the output 0
+        kw, qpos = {}, jnp.asarray(ctx - 1)
+    else:
+        ke = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+        ve = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+        kw = dict(extra_kv=(ke, ve, jnp.asarray(ctx[:, None])),
+                  pool_limit=jnp.asarray(np.maximum(ctx, 0)))
+        qpos = jnp.asarray(ctx)
+    run = lambda k, v: np.asarray(paged_attention(
+        q, k, v, jnp.asarray(bt), qpos, trash, impl="kernel", interpret=True, **kw))[row]
+    clean, dirty = run(kc, vc), run(poisoned(kc), poisoned(vc))
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+    if form == "plain" and c == 0:
+        np.testing.assert_array_equal(dirty, 0.0)
+
+
+@pytest.mark.parametrize("window", [0, 20])
+def test_visit_list_against_a_hand_count(window):
+    """The kernel's programs, from the bounds alone: each row's slots
+    lo..hi in order, then its tail on the slot it fetched last."""
+    bs, B = 16, 4
+    qpos = np.array([-1, 0, 15, 16, 40, 63, 63], np.int32)
+    limit = np.array([0, 1, 16, 17, 40, 64, 0], np.int32)  # the last row: an empty pool window
+    n, vrow, vslot = _visit_list(jnp.asarray(qpos), jnp.asarray(limit), bs, B, window)
+    want = []
+    for t, (p, lim) in enumerate(zip(qpos, limit)):
+        hi = min(-(-int(lim) // bs), B)
+        lo = max(int(p) - window + 1, 0) // bs if window else 0
+        slots = list(range(lo, hi))
+        want += [(t, s) for s in slots] + [(t, B + (slots[-1] if slots else min(lo, B - 1)))]
+    assert int(n) == len(want)
+    assert list(zip(np.asarray(vrow)[: len(want)].tolist(),
+                    np.asarray(vslot)[: len(want)].tolist())) == want
+    assert vrow.shape == vslot.shape == (len(qpos) * (B + 1),)

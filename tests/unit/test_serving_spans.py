@@ -176,6 +176,28 @@ class TestGridCounters:
         assert c["steps_with_prefill_total"] == 1
 
 
+    @pytest.mark.parametrize("decode_steps,work,live,slots", [
+        # the three steps of the first test. Decode rows: none; one whose pool
+        # holds 200 tokens = 13 blocks of 16; that row at 201 (13) and one at
+        # 20 (2). Every step's tables have R x B = 4 x 32 slots
+        (1, [(200, 3), (20, 2)], 0 + 13 + (13 + 2), 3 * 128),
+        # a prefill step, then two fused rounds of 4 steps: the row's pool
+        # window stays at the round's start (20, then 24 tokens: 2 blocks),
+        # and each of a round's 4 kernel calls a layer walks it again
+        (4, [(20, 9)], 0 + 4 * 2 + 4 * 2, 128 + 2 * 4 * 128),
+    ])
+    def test_paged_counters_are_live_blocks_over_table_slots(
+            self, tiny_model, decode_steps, work, live, slots):
+        """``ceil(pool tokens / block size)`` summed over the decode rows
+        against ``R x B``, for one layer's decode attention calls."""
+        driver, _ = _serve(_engine(tiny_model, decode_steps=decode_steps), work,
+                           **({"decode_steps": decode_steps} if decode_steps > 1 else {}))
+        c = driver.metrics.counters
+        assert c["paged_live_blocks_total"] == live
+        assert c["paged_table_slots_total"] == slots
+        assert f"paged_table_slots_total {slots}" in driver.metrics.prometheus_text()
+
+
 class TestOnePath:
     def test_off_path_is_the_on_path_with_the_shared_null_span(self, tiny_model, monkeypatch):
         """Traced or not, a step runs the same statements: the spans are
