@@ -567,7 +567,8 @@ def serve_leg(size, devices, tp=1):
     t0 = time.perf_counter()
     want = generate_each()
     t_warm = time.perf_counter() - t0
-    decode_jit = engine._multistep_jit = CaptureArgs(engine._multistep_jit)
+    round_key = ("round", decode_steps)
+    decode_jit = engine._programs[round_key] = CaptureArgs(engine._programs[round_key])
 
     def body(i):
         return {"tokens": [int(t) for t in prompts[i]], "max_new_tokens": max_new,

@@ -23,7 +23,7 @@ once across representative same-shape calls is quietly recompiling on the
 hot path (weak-typed scalars, python-hash-unstable statics, ...).
 
 Entry points covered (the compiled hot paths every perf PR leans on):
-  * ``engine_v2`` row step, split step, fused multistep decode
+  * ``engine_v2`` split step, fused multistep decode, speculative verify step
   * ``runtime.engine`` fused ZeRO-3 train step (bucketed-collective overlap)
   * ``runtime.streamed_adam`` per-leaf donated update
   * quantized-collective variants: TP decode through the int8 psum islands,
@@ -336,10 +336,11 @@ def _engine_v2_programs(kv_dtype: str):
     """The v2 serving programs of a tiny engine with a ``kv_dtype`` pool:
     (engine, {name: (jitted, args)}). The split step and the fused decode
     round are captured from two same-shape ``generate()`` passes (pass 1
-    traces, pass 2 must hit the caches); the row step and the verify step
-    are lowered directly with config shapes (lowering reads shapes only,
-    so passing the live pools is safe). int8 appends the donated scale
-    planes to every argument list."""
+    traces, pass 2 must hit the caches); the verify step is lowered directly
+    with the inputs of an empty round (lowering reads shapes only, so passing
+    the live pools is safe). Every program takes ``(params, inputs, rng,
+    temperature, pools)`` and donates ``pools`` whole: int8 adds the scale
+    planes as two more leaves of it."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -352,49 +353,30 @@ def _engine_v2_programs(kv_dtype: str):
         eng.generate([rng.integers(1, cfg.vocab_size, size=(12,)).astype(np.int32)
                       for _ in range(2)], max_new_tokens=6)
 
-    # row step: the per-row baseline path
-    kv = eng.config.kv_cache
-    programs["row_step"] = (eng._build_row_step(8), (
-        eng.params,
-        jnp.zeros((1, 8), jnp.int32),
-        jnp.int32(0),
-        jnp.int32(8),
-        jnp.zeros((kv.max_blocks_per_seq,), jnp.int32),
-        eng._k_cache,
-        eng._v_cache,
-    ) + eng._scale_args())
-
     # speculative verify step (serving/spec): the K+1-token draft-and-verify
-    # program declares both KV pools donated — without aliasing, every spec
-    # round would copy the whole paged pool, erasing the subsystem's win
-    R = eng.config.state_manager.max_ragged_sequence_count
+    # program declares the pools donated — without aliasing, every spec
+    # round would copy the whole paged pool, erasing the subsystem's win.
+    # Its inputs are what the engine stages for a round with no row.
+    _, inputs = eng._stage_verify([], [], 4)
     programs["verify_step"] = (eng._build_verify_step(4), (
         eng.params,
-        jnp.zeros((R, 5), jnp.int32),
-        jnp.zeros((R,), jnp.int32),
-        jnp.zeros((R, kv.max_blocks_per_seq), jnp.int32),
-        jnp.zeros((R,), jnp.int32),
-        jnp.zeros((R,), jnp.bool_),
-        jnp.ones((R,), jnp.int32),
+        {name: jnp.asarray(a) for name, a in inputs.items()},
         eng._rng,
         jnp.float32(1.0),
-        eng._k_cache,
-        eng._v_cache,
-    ) + eng._scale_args())
+        eng._pools(),
+    ))
     return eng, programs
 
 
 def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
     """One donation / pool-copy / recompile sweep over the v2 serving
-    programs for a pool payload dtype. int8 mode appends the fp32 scale
-    planes as donated trailing args on every step — the exact new-leaf case
-    where a wrong variadic index would silently copy a full plane per step,
-    so both dtypes get the full sweep."""
+    programs for a pool payload dtype. int8 mode adds the fp32 scale planes
+    to the donated pools argument of every step, so both dtypes get the
+    full sweep."""
     tag = "" if kv_dtype == "bf16" else f"[{kv_dtype}]"
     results: List[CheckResult] = []
     eng, programs = _engine_v2_programs(kv_dtype)
-    pools = (eng._k_cache, eng._v_cache) + eng._scale_args()
-    for key in ("split_step", "multistep_decode", "row_step", "verify_step"):
+    for key in ("split_step", "multistep_decode", "verify_step"):
         label = f"engine_v2.{key}{tag}"
         if key not in programs:
             results.append(CheckResult(label, "donation", False,
@@ -403,7 +385,7 @@ def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
         fn, args = programs[key]
         lowered = fn.lower(*args)
         results.append(check_donation(label, fn, args, lowered=lowered))
-        results.append(check_pool_copies(label, fn, args, pools, lowered=lowered))
+        results.append(check_pool_copies(label, fn, args, eng._pools(), lowered=lowered))
         if key in ("split_step", "multistep_decode"):  # the captured, live jits
             results.append(check_recompile(label, fn))
     return results
@@ -411,7 +393,7 @@ def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
 
 def verify_engine_v2() -> List[CheckResult]:
     # both pool payload dtypes: int8 adds donated scale-plane leaves to
-    # every serving program (split, multistep, row, verify)
+    # every serving program (split, multistep, verify)
     return _engine_v2_pass("bf16") + _engine_v2_pass("int8")
 
 
@@ -1104,8 +1086,9 @@ def verify_elastic() -> List[CheckResult]:
     sched.finish(uid)
 
     # the warmed split program itself must be single-trace per bucket
-    for key, fn in getattr(eng, "_split_jit", {}).items():
-        results.append(check_recompile(f"elastic.split_step[tq={key}]", fn))
+    for (kind, tq), fn in eng._programs.items():
+        if kind == "split":
+            results.append(check_recompile(f"elastic.split_step[tq={tq}]", fn))
     return results
 
 

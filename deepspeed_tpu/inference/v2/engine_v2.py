@@ -6,12 +6,19 @@ blocked KV cache and returns next-token logits per sequence.
 
 TPU adaptation:
   * the paged KV cache is [L, num_blocks, block_size, n_kv, d] per k/v;
-  * per-row paged attention = block-table gather → dense attention with a
-    length mask (a Pallas blocked-attention kernel can swap in underneath);
-  * token chunks are bucketed to a small set of compiled shapes (the
-    SplitFuse "fixed-shape friendly" re-think for compiled step functions).
+  * paged attention = block-table gather → dense attention with a length
+    mask, or the Pallas paged kernel underneath (``paged_attention``);
+  * a step is one compiled program over a fixed grid (the SplitFuse
+    "fixed-shape friendly" re-think for compiled step functions): the split
+    step, the fused decode round or the speculative verify step.
+
+Every step goes one way: ``_stage_<shape>`` (numpy only: the inputs by name,
+and the step's ``StepStats``) → ``_launch`` (the one call site of a step
+program; the pools go in and come back as one donated argument) →
+``_dispatch_and_collect`` (host copies, wait, materialize: one span bracket).
 """
 
+import dataclasses
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -27,14 +34,12 @@ from deepspeed_tpu.observability.tracing import get_tracer
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import device_synchronize
 
-_CHUNK_BUCKETS = (1, 8, 32, 64, 128, 256, 512)
-
-
-def _bucket(n):
-    for b in _CHUNK_BUCKETS:
-        if n <= b:
-            return b
-    return ((n + 255) // 256) * 256
+# step program of each cache key's kind: ("split", tq) | ("round", n) | ("verify", k)
+_BUILDERS = {
+    "split": "_build_split_step",
+    "round": "_build_multistep_decode",
+    "verify": "_build_verify_step",
+}
 
 
 def serving_benchmark(eng, n_seq=32, max_new=64, repeats=2, prompt_min=64,
@@ -107,6 +112,25 @@ def _materialize_rows(res: dict, want_tokens: bool = False) -> dict:
             hosts[key] = np.asarray(arr)  # dstpu: noqa[host-sync-in-loop]
         out[uid] = hosts[key] if idx is None else hosts[key][idx]
     return out
+
+
+@dataclasses.dataclass
+class StepStats:
+    """What one step or round was sized to and what it carried, filled where
+    the step is staged (``moe`` after its wait). The serving core folds it
+    into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
+    paged_live_blocks_total / paged_table_slots_total and moe_*_total."""
+
+    grid_slots: int = 0
+    scheduled_tokens: int = 0
+    prefill_tokens: int = 0
+    # decode attention: blocks the decode rows' contexts cover against the
+    # slots of their tables, summed over one layer's calls (_count_paged)
+    paged_live_blocks: int = 0
+    paged_table_slots: int = 0
+    # expert models: {"routed", "computed", "hot", "calls"} (rows and layer
+    # calls) of the step's expert layers (_count_moe); None for a dense model
+    moe: Optional[dict] = None
 
 
 class InferenceEngineV2:
@@ -295,11 +319,7 @@ class InferenceEngineV2:
             if self._kv_int8:
                 self._ks_cache = jnp.zeros(sshape, jnp.float32)
                 self._vs_cache = jnp.zeros(sshape, jnp.float32)
-        self._row_jit = {}
-        self._split_jit = {}  # (tq bucket,) -> compiled split-phase step
-        self._multistep_jit = None
-        self._multistep_n = 0
-        self._verify_jit = {}  # k -> compiled speculative verify step
+        self._programs = {}  # (kind, shape) -> compiled step program (_launch)
         self._kv_scatter_jit = None  # handoff import: donated pool scatter
         # chunked re-import: ONE fixed window shape (tail padded into the
         # trash row) so the donated scatter never recompiles in steady state
@@ -329,24 +349,10 @@ class InferenceEngineV2:
             self.state_manager.host_readmit = self._host_readmit
         self._spec_rr = 0  # rotation cursor for budget-capped spec rounds
         self.last_spec = {"drafted": 0, "accepted": 0, "per_uid": {}}
-        # what the last step or round was sized to and what it carried: the
-        # serving core folds these into the grid_slots_total /
-        # scheduled_tokens_total / steps_with_prefill_total counters
-        self.last_grid_slots = 0
-        self.last_scheduled_tokens = 0
-        self.last_prefill_tokens = 0
-        # decode attention: blocks the decode rows' contexts cover against
-        # the slots of their tables, summed over one layer's calls of the
-        # step or round (_count_paged); paged_live_blocks_total /
-        # paged_table_slots_total
-        self.last_paged_live_blocks = 0
-        self.last_paged_table_slots = 0
-        # expert models: what the last step's expert layers routed and what
-        # their kernel covered ({"routed", "computed", "hot", "calls"}, rows
-        # and layer calls), from the [.., L, E] count the step programs
-        # return; the serving core folds it into the moe_*_total counters
-        self.last_moe = None
-        self._moe_pending = None  # (device counts, tokens of one layer call)
+        self.last_step = StepStats()
+        # an expert model's [.., L, E] routed rows of the step just launched,
+        # on the device until _count_moe reduces them into last_step.moe
+        self._moe_pending = None
         self.last_capped = set()
         # sampling state: one base key; programs fold in each row's (uid,
         # source position) so a token's key is content-addressed — invariant
@@ -785,12 +791,10 @@ class InferenceEngineV2:
         def _n(fn) -> int:
             return int(fn._cache_size())
 
-        sig: Dict[str, int] = {}
-        for name in ("_row_jit", "_split_jit", "_verify_jit"):
-            for key, fn in getattr(self, name, {}).items():
-                sig[f"{name}[{key}]"] = _n(fn)
-        for name in ("_multistep_jit", "_kv_scatter_jit", "_kv_readmit_jit",
-                     "_kv_export_jit"):
+        sig: Dict[str, int] = {
+            f"{kind}[{shape}]": _n(fn) for (kind, shape), fn in self._programs.items()
+        }
+        for name in ("_kv_scatter_jit", "_kv_readmit_jit", "_kv_export_jit"):
             fn = getattr(self, name, None)
             if fn is not None:
                 sig[name] = _n(fn)
@@ -887,9 +891,7 @@ class InferenceEngineV2:
             cfg.top_p = float(top_p)
         if seed is not None:
             self._rng = jax.random.key(int(seed))
-        self._split_jit = {}
-        self._multistep_jit = None
-        self._verify_jit = {}
+        self._programs = {}
 
     def _sampling_kw(self):
         cfg = self.config
@@ -915,136 +917,6 @@ class InferenceEngineV2:
                 return P()
 
         return jax.tree_util.tree_map_with_path(pick, params)
-
-    # ------------------------------------------------------------------
-    def _build_row_step(self, t_bucket: int):
-        """The per-row step (one compiled call per sequence): ``t_bucket``
-        tokens of ONE sequence against its block table. Same pool protocol
-        as the batched steps: the layer loop reads the step-start pool,
-        each layer records its new K/V in the side buffers, and
-        _scatter_kv writes them back after the loop."""
-        c = self._mc
-        kv = self.config.kv_cache
-        bs = kv.block_size
-        B = kv.max_blocks_per_seq
-        S = B * bs  # gathered context window
-        NBp = kv.num_blocks + 1
-        trash = kv.num_blocks  # last cache row (see __init__ +1)
-        dtype = T.DTYPES[c.dtype]
-
-        def row_step(params, tokens, start, n_valid, block_table, k_cache,
-                     v_cache, *scales):
-            """tokens: [1, t]; start: scalar first position; n_valid: actual
-            new tokens (≤ t); block_table: [B]. ``scales`` = the int8
-            pools' (ks, vs) fp32 planes, or empty in bf16 mode. Returns
-            (logits_last [vocab], k_cache, v_cache[, ks_cache, vs_cache])."""
-            t = tokens.shape[1]
-            nkv, d = c.kv_heads, c.head_dim
-            positions = start + jnp.arange(t, dtype=jnp.int32)  # global positions
-            x = T._scale_embed(params["embed"].astype(dtype)[tokens], c, dtype)
-            if c.position == "learned":
-                x = x + params["pos_embed"][jnp.clip(positions, 0, c.max_seq_len - 1)][None]
-            if c.embed_norm:
-                x = T._embed_norm(params, c, x, stream=False)
-
-            # bucketing pads the chunk tail: those writes go to the trash block
-            valid = jnp.arange(t, dtype=jnp.int32) < n_valid
-            blk = jnp.where(valid, block_table[jnp.clip(positions // bs, 0, B - 1)], trash)
-            row = positions % bs
-            # live length (HF max(position_ids)+1) from the VALID tokens only
-            # — positions covers the padded bucket tail, whose max would flip
-            # longrope's factor switch early
-            live = start + n_valid
-            k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
-            ks_pool0, vs_pool0 = self._scale_views(*scales)
-
-            def layer_fn(lp, x, li, carry, window=None):
-                w = int(c.sliding_window if window is None else window)
-                lp = T._dequant_tree(lp, dtype)
-                _, q, k, v = self._layer_qkv(lp, x, positions, live)  # [t, nh|nkv, d]
-                tables_l = li * NBp + block_table  # [B] into the flat pools
-                if scales:
-                    # int8 pool: attend through the paged dense impl (pool
-                    # dequantizes inside its gather — raw int8 payloads never
-                    # reach the softmax), exactly as the batched step does,
-                    # so per-row streams match it bit-for-bit: the pool is
-                    # read below ``start`` only and the chunk's own K/V ride
-                    # alongside in compute dtype as extra columns (epos -1
-                    # disables the padded tail).
-                    from deepspeed_tpu.ops.attention.paged_pallas import paged_attention
-
-                    epos = jnp.where(valid, positions, -1)
-                    out = paged_attention(
-                        q, k_pool0, v_pool0,
-                        jnp.broadcast_to(tables_l[None], (t, B)), positions,
-                        li * NBp + trash, impl="dense", window=w,
-                        scale=c.attn_scale, k_scale=ks_pool0, v_scale=vs_pool0,
-                        extra_kv=(
-                            jnp.broadcast_to(k[None], (t, t, nkv, d)),
-                            jnp.broadcast_to(v[None], (t, t, nkv, d)),
-                            jnp.broadcast_to(epos[None], (t, t)),
-                        ),
-                        pool_limit=jnp.full((t,), start, jnp.int32),
-                    )
-                else:
-                    # the sequence's context as the pool will hold it after
-                    # the write-back: gathered from the step-start pool,
-                    # the chunk's valid rows laid over it (index S: dropped)
-                    at = jnp.where(valid, positions, S)
-
-                    def context(pool, new):
-                        ctx = pool[tables_l].reshape(S, nkv, d)
-                        ctx = ctx.at[at].set(new, mode="drop")
-                        return ctx.transpose(1, 0, 2)[None]  # [1, nkv, S, d]
-
-                    k_ctx, v_ctx = context(k_pool0, k), context(v_pool0, v)
-                    qh = q.transpose(1, 0, 2)[None]  # [1, nh, t, d]
-                    if c.attention_impl == "splash" and w > 0:
-                        # scheduled prefill: the kv-block schedule is computed
-                        # IN-JIT from the traced chunk start (one compiled
-                        # program per (t, S) bucket, no host rebuild) and the
-                        # kernel visits ~(window + t)/block blocks, not all
-                        # S/block — out-of-band context blocks are never
-                        # streamed. window==0 configs keep the dense path
-                        # below (bit-identical streams vs pre-splash).
-                        from deepspeed_tpu.ops.sparse_attention import (
-                            splash_prefill_attention,
-                        )
-
-                        out = splash_prefill_attention(
-                            qh, k_ctx, v_ctx, start,
-                            window=w, block_kv=bs, scale=c.attn_scale,
-                        )
-                    else:
-                        kpos = jnp.arange(S, dtype=jnp.int32)
-                        mask = kpos[None, :] <= positions[:, None]  # [t, S] causal
-                        if w:
-                            from deepspeed_tpu.ops.attention.core import window_too_far
-
-                            mask = jnp.logical_and(
-                                mask,
-                                jnp.logical_not(
-                                    window_too_far(positions[:, None], kpos[None, :], w)
-                                ),
-                            )
-                        bias = jnp.where(mask, 0.0, -1e30).astype(jnp.float32)[None, None]
-                        from deepspeed_tpu.ops.attention import mha_reference
-
-                        out = mha_reference(qh, k_ctx, v_ctx, causal=False, bias=bias,
-                                            scale=c.attn_scale)
-                    out = out[0].transpose(1, 0, 2)  # [t, nh, d]
-                x, moe = self._layer_tail(lp, x, out, valid, li)
-                return x, self._record_kv(carry, li, k, v, moe)
-
-            x, side = self._drive_layers(layer_fn, params, x, self._side_buffers(t))
-            caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
-            x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
-            last = jnp.take_along_axis(x, jnp.clip(n_valid - 1, 0, t - 1)[None, None, None], axis=1)[:, 0]
-            logits = T._apply_lm_head(params, last, c)
-            return (logits[0].astype(jnp.float32),) + caches + side[2:]
-
-        donate = (5, 6, 7, 8) if self._kv_int8 else (5, 6)
-        return jax.jit(row_step, donate_argnums=donate)
 
     # ------------------------------------------------------------------
     def _pool_views(self, k_cache, v_cache):
@@ -1073,11 +945,47 @@ class InferenceEngineV2:
         shape = (L * NBp, kv.block_size, c.kv_heads)
         return ks_cache.reshape(shape), vs_cache.reshape(shape)
 
-    def _scale_args(self):
-        """Variadic trailing scale-plane args for the serving jits: the
-        int8 planes, or nothing in bf16 mode — bf16 signatures and
-        donation indices stay exactly as before."""
-        return (self._ks_cache, self._vs_cache) if self._kv_int8 else ()
+    def _pools(self):
+        """The pools as the step programs take them and give them back: ONE
+        argument, ``(k, v)`` or with an int8 pool ``(k, v, ks, vs)``, donated
+        whole — whatever else a program takes, and whichever dtype the pool
+        holds, every leaf of it is updated in place."""
+        return tuple(self._kv_pool_planes().values())
+
+    def _embed(self, params, tokens, positions):
+        """The one prologue of a step program: ``tokens`` [t] at
+        ``positions`` [t] -> x [1, t, h] (scaled embedding, learned
+        positions, embedding norm)."""
+        c = self._mc
+        dtype = T.DTYPES[c.dtype]
+        x = T._scale_embed(params["embed"].astype(dtype)[tokens][None], c, dtype)
+        if c.position == "learned":
+            x = x + params["pos_embed"][jnp.clip(positions, 0, c.max_seq_len - 1)][None]
+        if c.embed_norm:
+            x = T._embed_norm(params, c, x, stream=False)
+        return x
+
+    def _sample_rows(self, params, x, rows, rng, temperature, uids, src_pos,
+                     return_logprobs=False):
+        """The one epilogue of a step program: final norm over the step's
+        hidden states x [1, t, h], ``rows`` of them (a slice or an index
+        array) through the LM head, and one next token a row computed
+        IN-program (sampled or greedy per the static config knobs). Keys are
+        per-row, content-addressed on (uid, logits-source position)
+        (sampling.row_keys): the token for a given position is the same
+        whether the split step, a fused round under any ``decode_steps`` or
+        a verify step produced it, whatever the batch packing, prompt
+        chunking and prefix-cache state. Returns (fp32 logits [n, vocab],
+        what ``sample_tokens`` returns: tokens, or (tokens, logprobs))."""
+        from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
+
+        c = self._mc
+        x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
+        logits = T._apply_lm_head(params, x[0, rows], c).astype(jnp.float32)
+        return logits, sample_tokens(
+            logits, row_keys(rng, uids, src_pos), temperature=temperature,
+            return_logprobs=return_logprobs, **self._sampling_kw(),
+        )
 
     def _attn_decode(self, q, k_pool, v_pool, tables_l, positions, window,
                      trash_l, extra_kv=None, pool_limit=None, k_scale=None,
@@ -1108,16 +1016,17 @@ class InferenceEngineV2:
         )
 
     def _count_paged(self, pool_tokens, calls: int = 1):
-        """Leave in ``last_paged_*`` what one layer's decode attention had to
-        read against what it was handed: ``pool_tokens`` [R] are the tokens
-        each row's pool window holds (<= 0: an inactive slot), a row's live
-        blocks are ``ceil(tokens / bs)`` and its table has B slots;
-        ``calls`` kernel calls a layer walk the same rows (a fused round's
-        steps, a verify round's K1 queries a row)."""
+        """What one layer's decode attention had to read against what it was
+        handed, as ``StepStats``' (paged_live_blocks, paged_table_slots):
+        ``pool_tokens`` [R] are the tokens each row's pool window holds
+        (<= 0: an inactive slot), a row's live blocks are
+        ``ceil(tokens / bs)`` and its table has B slots; ``calls`` kernel
+        calls a layer walk the same rows (a fused round's steps, a verify
+        round's K1 queries a row)."""
         kv = self.config.kv_cache
         held = np.maximum(np.asarray(pool_tokens, np.int64), 0)
-        self.last_paged_live_blocks = calls * int((-(-held // kv.block_size)).sum())
-        self.last_paged_table_slots = calls * len(held) * kv.max_blocks_per_seq
+        return (calls * int((-(-held // kv.block_size)).sum()),
+                calls * len(held) * kv.max_blocks_per_seq)
 
     def _side_buffers(self, *token_dims):
         """A zeroed (k, v) pair [L, *token_dims, nkv, d] in compute dtype:
@@ -1150,6 +1059,12 @@ class InferenceEngineV2:
         if moe_counts is None:
             return carry
         return carry[:2] + (jax.lax.dynamic_update_index_in_dim(carry[2], moe_counts, li, 0),)
+
+    @staticmethod
+    def _moe_rows(carry):
+        """An expert model's routed rows, the third part of a layer loop's
+        carry, as a step program returns them; None for a dense model."""
+        return carry[2] if len(carry) > 2 else None
 
     @classmethod
     def _record_kv(cls, carry, li, k, v, moe_counts=None):
@@ -1419,32 +1334,26 @@ class InferenceEngineV2:
     def _build_split_step(self, tq: int):
         """ONE compiled step over the split-phase batch: R decode slots +
         Rc prompt chunks of tq tokens (the static-shape SplitFuse). blk/row/
-        positions come pre-staged from the host — data-dependent anyway.
-        Returns (decode logits [R, vocab], chunk logits [Rc, vocab], caches).
-        """
-        c = self._mc
+        positions come pre-staged from the host (_stage_split) —
+        data-dependent anyway. Outputs: (decode logits [R, vocab], chunk
+        logits [Rc, vocab], decode tokens [R], chunk tokens [Rc])."""
         R = self.config.state_manager.max_ragged_sequence_count
         Rc = self.scheduler.max_prompt_chunks
-        dtype = T.DTYPES[c.dtype]
 
-        def step(params, tokens, positions, blk, row, dec_tables, dec_pos,
-                 dec_uids, chk_tables, chk_pos, chk_start, chk_last, chk_uids,
-                 rng, temperature, k_cache, v_cache, *scales):
-            x = T._scale_embed(params["embed"].astype(dtype)[tokens][None], c, dtype)
-            if c.position == "learned":
-                x = x + params["pos_embed"][jnp.clip(positions, 0, c.max_seq_len - 1)][None]
-            if c.embed_norm:
-                x = T._embed_norm(params, c, x, stream=False)
-            # live length (HF max(position_ids)+1) for the rope-scaling
-            # switch: padded slots carry position 0, so the plain max works
-            live = jnp.max(positions) + 1
-            k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
-            ks_pool0, vs_pool0 = self._scale_views(*scales)
+        def step(params, inputs, rng, temperature, pools):
+            tokens, positions = inputs["tokens"], inputs["positions"]
+            dec_pos, chk_pos, chk_last = inputs["dec_pos"], inputs["chk_pos"], inputs["chk_last"]
+            x = self._embed(params, tokens, positions)
+            k_pool0, v_pool0 = self._pool_views(*pools[:2])
+            ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
             meta = {
-                "R": R, "Rc": Rc, "tq": tq, "positions": positions, "live": live,
-                "dec_tables": dec_tables, "dec_pos": dec_pos,
-                "chk_tables": chk_tables, "chk_pos": chk_pos,
-                "chk_start": chk_start,
+                "R": R, "Rc": Rc, "tq": tq, "positions": positions,
+                # live length (HF max(position_ids)+1) for the rope-scaling
+                # switch: padded slots carry position 0, so the plain max works
+                "live": jnp.max(positions) + 1,
+                "dec_tables": inputs["dec_tables"], "dec_pos": dec_pos,
+                "chk_tables": inputs["chk_tables"], "chk_pos": chk_pos,
+                "chk_start": inputs["chk_start"],
                 "k_pool0": k_pool0, "v_pool0": v_pool0,
                 "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
                 # the grid's padding: decode slots with no row, chunk tails
@@ -1457,49 +1366,17 @@ class InferenceEngineV2:
             x, side = self._drive_layers(
                 layer_fn, params, x, self._side_buffers(tokens.shape[0])
             )
-            caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
-            x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
-            dec_h = x[0, :R]  # [R, h]
-            chk_h = x[0, jnp.clip(chk_last, 0, x.shape[1] - 1)]  # [Rc, h]
-            logits_dec = T._apply_lm_head(params, dec_h, c)
-            logits_chk = T._apply_lm_head(params, chk_h, c)
-            # next tokens computed IN-program (sampled or greedy per the
-            # static config knobs): generate() holds only these tiny arrays
-            # across the prefill phase and drops the logits refs — holding
-            # the 4 MB logits buffers alive measurably stalled the step
-            # pipeline on r05's host. Keys are per-row,
-            # content-addressed on (uid, logits-source position) so the
-            # sampled stream is invariant to batch packing, prompt
-            # chunking, and prefix-cache hits.
-            from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
+            pools = self._scatter_kv(pools, inputs["blk"], inputs["row"], side)
+            # generate() holds only the token arrays across its prefill
+            # phase and drops the logits
+            logits_dec, toks_dec = self._sample_rows(
+                params, x, slice(0, R), rng, temperature, inputs["dec_uids"], dec_pos)
+            chk_at = jnp.clip(chk_last, 0, tokens.shape[0] - 1)
+            logits_chk, toks_chk = self._sample_rows(
+                params, x, chk_at, rng, temperature, inputs["chk_uids"], positions[chk_at])
+            return (logits_dec, logits_chk, toks_dec, toks_chk), pools, self._moe_rows(side)
 
-            kw = self._sampling_kw()
-            toks_dec = sample_tokens(
-                logits_dec.astype(jnp.float32),
-                row_keys(rng, dec_uids, dec_pos),
-                temperature=temperature, **kw,
-            )
-            chk_src = positions[jnp.clip(chk_last, 0, positions.shape[0] - 1)]
-            toks_chk = sample_tokens(
-                logits_chk.astype(jnp.float32),
-                row_keys(rng, chk_uids, chk_src),
-                temperature=temperature, **kw,
-            )
-            # an expert model's [L, E] routed rows ride behind the pools
-            return (
-                logits_dec.astype(jnp.float32), logits_chk.astype(jnp.float32),
-                toks_dec, toks_chk,
-            ) + caches + side[2:]
-
-        # donate BOTH cache pools (args 15 and 16 — k_cache, v_cache) so the
-        # write-back updates them in place; donating 14 would hand XLA the
-        # scalar `temperature` instead of v_cache and copy a full V pool.
-        # int8 mode appends the scale planes (16 + 17/18) as variadic
-        # trailing args — bf16 signatures and donation indices stay
-        # unchanged, and no always-present-but-unused arg gets dropped
-        # (the Tier-B donation verifier flags dropped donated inputs).
-        donate = (15, 16, 17, 18) if self._kv_int8 else (15, 16)
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(step, donate_argnums=(4,))
 
     def _round_layer(self, lp, x, li, meta, carry, window=None):
         """One layer of one step of a fused decode ROUND: queries are the
@@ -1536,12 +1413,11 @@ class InferenceEngineV2:
         return x, self._record_moe((side_k, side_v) + carry[2:], li, moe)
 
     def _build_multistep_decode(self, n_steps: int):
-        """``n_steps`` greedy decode iterations in ONE device program, the
-        argmax fed back in-device (reference FastGen keeps sampling
-        on-device for the same reason): the per-token host round trip —
-        ~90-120 ms on r05's host, ~0.6 ms on the v5e today (chip_smoke.py,
-        PR 21), and the classic serving bottleneck everywhere — is paid once
-        per ``n_steps`` tokens.
+        """``n_steps`` decode iterations in ONE device program, each token
+        fed back in-device (reference FastGen keeps sampling on-device for
+        the same reason): what the host spends between two programs (the
+        launch 4.2 ms, the whole gap 5.7-7.4 ms a step: ledger, PR 26) is
+        paid once per ``n_steps`` tokens.
 
         Every row is one running sequence (R = max_ragged_sequence_count;
         inactive rows carry an all-trash block table and position 0, so
@@ -1551,35 +1427,27 @@ class InferenceEngineV2:
         pool is read at its ROUND-START state and is no part of any loop's
         carry; the round's own tokens ride in side buffers (see
         _round_layer), and one write-back after the last step puts all
-        ``n_steps`` tokens of every layer into the donated pools."""
-        c = self._mc
+        ``n_steps`` tokens of every layer into the donated pools. Outputs:
+        (tokens [n_steps, R], logprobs [n_steps, R]); an expert model's
+        routed rows are [n_steps, L, E]."""
         kv = self.config.kv_cache
         bs = kv.block_size
         B = kv.max_blocks_per_seq
         trash = kv.num_blocks
         R = self.config.state_manager.max_ragged_sequence_count
-        dtype = T.DTYPES[c.dtype]
 
-        def fused(params, tokens, positions, tables, uids, active, rng,
-                  temperature, k_cache, v_cache, *scales):
-            tok_tables = jnp.where(active[:, None], tables, trash)
-            pos0 = positions  # round-start positions (pool validity limit)
+        def fused(params, inputs, rng, temperature, pools):
+            tokens, uids, active = inputs["tokens"], inputs["uids"], inputs["active"]
+            tok_tables = jnp.where(active[:, None], inputs["tables"], trash)
+            pos0 = inputs["positions"]  # round-start positions (pool validity limit)
             j_idx = jnp.arange(n_steps, dtype=jnp.int32)
             # round-start pool views: read-only for the whole round (the
             # in-round tokens come from the side buffers)
-            k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
-            ks_pool0, vs_pool0 = self._scale_views(*scales)
-
-            from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
-
-            kw = self._sampling_kw()
+            k_pool0, v_pool0 = self._pool_views(*pools[:2])
+            ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
 
             def one_token(params, toks, pos, s, side):
-                x = T._scale_embed(params["embed"].astype(dtype)[toks][None], c, dtype)
-                if c.position == "learned":
-                    x = x + params["pos_embed"][jnp.clip(pos, 0, c.max_seq_len - 1)][None]
-                if c.embed_norm:
-                    x = T._embed_norm(params, c, x, stream=False)
+                x = self._embed(params, toks, pos)
                 # side slots 0..s are valid for active rows; -1 masks the rest
                 epos = jnp.where(
                     (j_idx[None] <= s) & active[:, None],
@@ -1601,145 +1469,32 @@ class InferenceEngineV2:
                     return self._round_layer(lp, x, li, meta, carry, window=window)
 
                 x, side = self._drive_layers(layer_fn, params, x, side)
-                x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
-                logits = T._apply_lm_head(params, x[0], c)  # [R, vocab]
-                # content-addressed per-row keys on (uid, source position):
-                # the stream for a given token is identical whether it was
-                # produced here, by the split-phase step, or under a
-                # different decode_steps partitioning or prefix-cache state
-                nxt, logp = sample_tokens(
-                    logits.astype(jnp.float32),
-                    row_keys(rng, uids, jnp.where(active, pos, -1)),
-                    temperature=temperature, return_logprobs=True, **kw,
-                )
+                _, (nxt, logp) = self._sample_rows(
+                    params, x, slice(None), rng, temperature, uids,
+                    jnp.where(active, pos, -1), return_logprobs=True)
                 return nxt, logp, side
 
             def step_fn(carry, s):
                 toks, pos, side = carry
                 nxt, logp, side = one_token(params, toks, pos, s, side)
                 nxt = jnp.where(active, nxt, toks)  # inactive rows freeze
-                # an expert model: what this step's layers routed, [L, E]
-                return (nxt, pos + active.astype(jnp.int32), side), (nxt, logp) + side[2:]
+                return (nxt, pos + active.astype(jnp.int32), side), (nxt, logp, self._moe_rows(side))
 
-            (_, _, side), (toks_out, logps_out, *moe) = jax.lax.scan(
+            (_, _, side), (toks_out, logps_out, moe) = jax.lax.scan(
                 step_fn,
-                (tokens, positions, self._side_buffers(R, n_steps)),
+                (tokens, pos0, self._side_buffers(R, n_steps)),
                 j_idx,
             )
             # the round's write-back: step s of row r sits at position
             # pos0 + s (inactive rows never advance and name the trash block)
             pos_all = pos0[:, None] + j_idx[None] * active[:, None]  # [R, n_steps]
             blk = jnp.take_along_axis(tok_tables, jnp.clip(pos_all // bs, 0, B - 1), axis=1)
-            caches = self._scatter_kv(
-                (k_cache, v_cache) + scales,
-                blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps), side,
+            pools = self._scatter_kv(
+                pools, blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps), side,
             )
-            # toks_out/logps_out: [n_steps, R]; then the written pools; an
-            # expert model's routed rows [n_steps, L, E] behind them
-            return (toks_out, logps_out) + caches + tuple(moe)
+            return (toks_out, logps_out), pools, moe
 
-        donate = (8, 9, 10, 11) if self._kv_int8 else (8, 9)
-        return jax.jit(fused, donate_argnums=donate)
-
-    def decode_round(self, n_steps: Optional[int] = None) -> Dict[int, np.ndarray]:
-        """One fused decode round: ``n_steps`` greedy tokens for every
-        eligible RUNNING sequence in a single device call. Only legal when no
-        prompt chunks are pending (prefill through step()/put() first).
-        Returns {uid: [n_steps] generated tokens}; the caller truncates at
-        EOS and calls scheduler.finish for completed sequences.
-
-        Sequences that cannot take a FULL round — within ``n_steps`` of
-        max_context or the per-sequence block cap, or whose block extension
-        fails because the pool is momentarily exhausted — are simply left
-        untouched (still running): capping, max-context stops, and
-        memory-pressure waiting all stay the per-step scheduler's job
-        (generate() falls back to step() when a round serves nobody)."""
-        n = int(n_steps or self.config.decode_steps)
-        sched = self.scheduler
-        if sched.has_pending():
-            raise RuntimeError(
-                "decode_round: prompt chunks are still pending — drive step() "
-                "until prefill completes before fused decode"
-            )
-        max_context = self.config.state_manager.max_context
-        R = self.config.state_manager.max_ragged_sequence_count
-        uids = []
-        for uid in sched.running_uids():
-            if len(uids) >= R:
-                break
-            seq = self.state_manager.get_sequence(uid)
-            if seq.seen_tokens + n > max_context:
-                continue  # near the context limit: per-step path stops it
-            if self.state_manager.seq_capped(seq, n):
-                continue  # near the block cap: per-step path caps it
-            if not self.state_manager.extend(seq, n):
-                continue  # pool momentarily exhausted: sequence waits
-            uids.append(uid)
-        if not uids:
-            return {}
-        self.last_grid_slots = R * n
-        self.last_scheduled_tokens = len(uids) * n
-        self.last_prefill_tokens = 0
-        tr = get_tracer()
-        track = getattr(self, "_trace_name", "engine")
-        # dispatch (staging + async launch) vs device wait, on this
-        # replica's engine track
-        with tr.span("engine.dispatch", track=track,
-                     args={"rows": len(uids), "steps": n} if tr.enabled else None):
-            with tr.span("engine.stage", track=track):
-                kv = self.config.kv_cache
-                B = kv.max_blocks_per_seq
-                trash = kv.num_blocks
-                tokens = np.zeros(R, np.int32)
-                positions = np.zeros(R, np.int32)
-                tables = np.full((R, B), trash, np.int32)
-                uid_arr = np.zeros(R, np.int32)
-                active = np.zeros(R, bool)
-                for i, uid in enumerate(uids):
-                    seq = self.state_manager.get_sequence(uid)
-                    tokens[i] = sched.peek_next_token(uid)
-                    positions[i] = seq.seen_tokens
-                    tables[i, : len(seq.block_table)] = seq.block_table
-                    uid_arr[i] = uid
-                    active[i] = True
-                self._count_paged(positions, calls=n)
-            with tr.span("engine.launch", track=track):
-                if self._multistep_jit is None or self._multistep_n != n:
-                    self._multistep_jit = self._build_multistep_decode(n)
-                    self._multistep_n = n
-                outs = self._multistep_jit(
-                    self.params,
-                    jnp.asarray(tokens),
-                    jnp.asarray(positions),
-                    jnp.asarray(tables),
-                    jnp.asarray(uid_arr),
-                    jnp.asarray(active),
-                    self._rng,
-                    jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
-                    self._k_cache,
-                    self._v_cache,
-                    *self._scale_args(),
-                )
-                toks_out, logps_out, self._k_cache, self._v_cache = outs[:4]
-                if self._kv_int8:
-                    self._ks_cache, self._vs_cache = outs[4], outs[5]
-                self._moe_pending = (outs[-1], R) if self._mc.n_experts > 0 else None
-        waited = [toks_out, logps_out] + self._moe_arrays()
-        _start_host_copies(waited)
-        with tr.span("engine.device_wait", track=track):
-            device_synchronize(waited)
-        results: Dict[int, np.ndarray] = {}
-        self.last_logprobs = {}
-        with tr.span("engine.materialize", track=track):
-            self._count_moe()
-            toks_out = np.asarray(toks_out)  # [n, R]
-            logps_out = np.asarray(logps_out)
-            for i, uid in enumerate(uids):
-                gen = toks_out[:, i]
-                sched.apply_decode_round(uid, gen)
-                results[uid] = gen
-                self.last_logprobs[uid] = logps_out[:, i]
-        return results
+        return jax.jit(fused, donate_argnums=(4,))
 
     # ------------------------------------------------------------------
     def _build_verify_step(self, k: int):
@@ -1778,22 +1533,17 @@ class InferenceEngineV2:
         dtype = T.DTYPES[c.dtype]
         K1 = k + 1
 
-        def verify(params, tokens, positions0, tables, uids, active, n_input,
-                   rng, temperature, k_cache, v_cache, *scales):
+        def verify(params, inputs, rng, temperature, pools):
+            tokens, active, n_input = inputs["tokens"], inputs["active"], inputs["n_input"]
+            positions0 = inputs["positions"]
             nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
-            tok_tables = jnp.where(active[:, None], tables, trash)
+            tok_tables = jnp.where(active[:, None], inputs["tables"], trash)
             j = jnp.arange(K1, dtype=jnp.int32)
             pos = positions0[:, None] + j[None]  # [R, K1]
             valid = (j[None] < n_input[:, None]) & active[:, None]
             qpos = jnp.where(valid, pos, -1)  # -1: padded query/key slot
             flat_pos = pos.reshape(R * K1)
-            x = T._scale_embed(
-                params["embed"].astype(dtype)[tokens.reshape(R * K1)][None], c, dtype
-            )
-            if c.position == "learned":
-                x = x + params["pos_embed"][jnp.clip(flat_pos, 0, c.max_seq_len - 1)][None]
-            if c.embed_norm:
-                x = T._embed_norm(params, c, x, stream=False)
+            x = self._embed(params, tokens.reshape(R * K1), flat_pos)
             # rope live length from VALID positions only (padded slots would
             # flip a longrope factor switch early)
             live = jnp.max(jnp.where(valid, pos, 0)) + 1
@@ -1804,8 +1554,8 @@ class InferenceEngineV2:
             # only (pool_limit); the K1 fresh K/V of a row ride alongside and
             # reach the pool in one write-back after the loop, as in the
             # split step
-            k_pool0, v_pool0 = self._pool_views(k_cache, v_cache)
-            ks_pool0, vs_pool0 = self._scale_views(*scales)
+            k_pool0, v_pool0 = self._pool_views(*pools[:2])
+            ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
             pool_lim = jnp.where(active, positions0, 0)
             from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
 
@@ -1857,30 +1607,284 @@ class InferenceEngineV2:
             x, side = self._drive_layers(
                 layer_fn, params, x, self._side_buffers(R * K1)
             )
-            caches = self._scatter_kv((k_cache, v_cache) + scales, blk, row, side)
-            x = T._norm(x, params["final_norm"], params.get("final_norm_b"), c.norm, c.norm_eps)
-            logits = T._apply_lm_head(params, x[0], c)  # [R*K1, vocab]
-            from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
-
-            kw = self._sampling_kw()
-            tgt, logp = sample_tokens(
-                logits.astype(jnp.float32),
-                row_keys(rng, jnp.repeat(uids, K1), qpos.reshape(R * K1)),
-                temperature=temperature, return_logprobs=True, **kw,
-            )
+            pools = self._scatter_kv(pools, blk, row, side)
+            _, (tgt, logp) = self._sample_rows(
+                params, x, slice(None), rng, temperature,
+                jnp.repeat(inputs["uids"], K1), qpos.reshape(R * K1), return_logprobs=True)
             tgt = tgt.reshape(R, K1)
             logp = logp.reshape(R, K1)
             jj = jnp.arange(k, dtype=jnp.int32)
             match = (tokens[:, 1:] == tgt[:, :k]) & (jj[None] < (n_input - 1)[:, None])
             n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
             n_emit = jnp.where(active, n_acc + 1, 0)
-            return (tgt, n_emit, logp) + caches + side[2:]
+            return (tgt, n_emit, logp), pools, self._moe_rows(side)
 
-        # donate BOTH cache pools (args 9 and 10 — k_cache, v_cache) so the
-        # write-back updates them in place like every other serving step;
-        # int8 appends the scale planes (11/12) variadically
-        donate = (9, 10, 11, 12) if self._kv_int8 else (9, 10)
-        return jax.jit(verify, donate_argnums=donate)
+        return jax.jit(verify, donate_argnums=(4,))
+
+    # -- the step protocol: stage -> launch -> collect ----------------------
+    def _stage_split(self, total_tokens, dec_rows, chk_rows):
+        """The scheduler's batch onto the fixed [R decode slots | Rc chunks x
+        tq] grid: ``dec_rows`` (uid, tokens, start), ``chk_rows`` (uid,
+        tokens, start, chunked). Returns the split step's cache key and its
+        inputs by name."""
+        kv = self.config.kv_cache
+        R = self.config.state_manager.max_ragged_sequence_count
+        Rc = self.scheduler.max_prompt_chunks
+        B = kv.max_blocks_per_seq
+        bs = kv.block_size
+        trash = kv.num_blocks
+        if len(dec_rows) > R or len(chk_rows) > Rc:
+            raise RuntimeError(
+                f"split-phase batch overflow: {len(dec_rows)} decode rows "
+                f"(cap {R}), {len(chk_rows)} prompt chunks (cap {Rc})"
+            )
+        max_chunk = max((len(t) for _, t, _, _ in chk_rows), default=1)
+        # chunk-length buckets: two shapes keep short prompts off the full
+        # prompt_chunk pad without a compile per ragged length
+        tq = 128 if max_chunk <= 128 else self.scheduler.prompt_chunk
+        tq = min(tq, self.scheduler.prompt_chunk)
+        T_ = R + Rc * tq
+
+        tokens = np.zeros(T_, np.int32)
+        positions = np.zeros(T_, np.int32)
+        blk = np.full(T_, trash, np.int32)
+        row = np.zeros(T_, np.int32)
+        dec_tables = np.full((R, B), trash, np.int32)
+        dec_pos = np.full(R, -1, np.int32)  # -1 = inactive slot (masks all)
+        dec_uids = np.zeros(R, np.int32)
+        chk_tables = np.full((Rc, B), trash, np.int32)
+        chk_pos = np.full((Rc, tq), -1, np.int32)
+        chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
+        chk_last = np.zeros(Rc, np.int32)
+        chk_uids = np.zeros(Rc, np.int32)
+
+        for i, (uid, toks, start) in enumerate(dec_rows):
+            seq = self.state_manager.get_sequence(uid)
+            tokens[i] = toks[0]
+            positions[i] = start
+            nblk = len(seq.block_table)
+            dec_tables[i, :nblk] = seq.block_table
+            dec_pos[i] = start
+            dec_uids[i] = uid
+            blk[i] = seq.block_table[min(start // bs, nblk - 1)]
+            row[i] = start % bs
+        for j, (uid, toks, start, _chunked) in enumerate(chk_rows):
+            seq = self.state_manager.get_sequence(uid)
+            n = len(toks)
+            off = R + j * tq
+            tokens[off : off + n] = toks
+            pos = start + np.arange(n)
+            positions[off : off + n] = pos
+            nblk = len(seq.block_table)
+            chk_tables[j, :nblk] = seq.block_table
+            chk_pos[j, :n] = pos
+            chk_start[j] = start
+            chk_uids[j] = uid
+            # host-side scheduler metadata, not a device value
+            blk[off : off + n] = np.asarray(seq.block_table, np.int32)[  # dstpu: noqa[host-sync-in-loop]
+                np.minimum(pos // bs, nblk - 1)
+            ]
+            row[off : off + n] = pos % bs
+            chk_last[j] = off + n - 1
+        self.last_step = StepStats(
+            T_, total_tokens, sum(len(t) for _, t, _, _ in chk_rows),
+            *self._count_paged(dec_pos),
+        )
+        return ("split", tq), {
+            "tokens": tokens, "positions": positions, "blk": blk, "row": row,
+            "dec_tables": dec_tables, "dec_pos": dec_pos, "dec_uids": dec_uids,
+            "chk_tables": chk_tables, "chk_pos": chk_pos, "chk_start": chk_start,
+            "chk_last": chk_last, "chk_uids": chk_uids,
+        }
+
+    def _stage_rows(self, uids, width: int):
+        """What the fused round and the verify step share: one row a running
+        sequence of ``uids``, [R]-shaped (tokens [R, width]: a row's pending
+        token first), inactive rows all-trash at position 0."""
+        kv = self.config.kv_cache
+        R = self.config.state_manager.max_ragged_sequence_count
+        inputs = {
+            "tokens": np.zeros((R, width), np.int32),
+            "positions": np.zeros(R, np.int32),
+            "tables": np.full((R, kv.max_blocks_per_seq), kv.num_blocks, np.int32),
+            "uids": np.zeros(R, np.int32),
+            "active": np.zeros(R, bool),
+        }
+        for i, uid in enumerate(uids):
+            seq = self.state_manager.get_sequence(uid)
+            inputs["tokens"][i, 0] = self.scheduler.peek_next_token(uid)
+            inputs["positions"][i] = seq.seen_tokens
+            inputs["tables"][i, : len(seq.block_table)] = seq.block_table
+            inputs["uids"][i] = uid
+            inputs["active"][i] = True
+        return R, inputs
+
+    def _stage_round(self, uids, n: int):
+        """A fused decode round of ``n`` steps over ``uids``: cache key and
+        inputs by name."""
+        R, inputs = self._stage_rows(uids, 1)
+        inputs["tokens"] = inputs["tokens"][:, 0]
+        self.last_step = StepStats(
+            R * n, len(uids) * n, 0, *self._count_paged(inputs["positions"], calls=n))
+        return ("round", n), inputs
+
+    def _stage_verify(self, uids, row_drafts, k: int):
+        """A verify step of up to ``k`` drafts a row (``row_drafts`` beside
+        ``uids``): cache key and inputs by name."""
+        R, inputs = self._stage_rows(uids, k + 1)
+        inputs["n_input"] = np.ones(R, np.int32)
+        for i, d in enumerate(row_drafts):
+            inputs["tokens"][i, 1 : 1 + len(d)] = d
+            inputs["n_input"][i] = 1 + len(d)
+        self.last_step = StepStats(
+            R * (k + 1), len(uids) + sum(len(d) for d in row_drafts), 0,
+            *self._count_paged(inputs["positions"], calls=k + 1),
+        )
+        return ("verify", k), inputs
+
+    def _launch(self, key, inputs):
+        """THE call site of a step program. Looks the program of ``key`` up
+        (or builds it), moves the staged arrays to the device one
+        ``jnp.asarray`` an input, passes the sampling state and the pools,
+        stores the pools the program returns and parks an expert model's
+        routed rows for _count_moe. Returns the program's outputs: device
+        arrays, nothing waited for."""
+        fn = self._programs.get(key)
+        if fn is None:
+            kind, shape = key
+            fn = self._programs[key] = getattr(self, _BUILDERS[kind])(shape)
+        outputs, pools, self._moe_pending = fn(
+            self.params,
+            {name: jnp.asarray(a) for name, a in inputs.items()},
+            self._rng,
+            jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
+            self._pools(),
+        )
+        self._k_cache, self._v_cache = pools[:2]
+        if self._kv_int8:
+            self._ks_cache, self._vs_cache = pools[2:]
+        return outputs
+
+    def _start(self, stage, *args):
+        """``stage(*args)`` then _launch, each under its span; sync-free."""
+        tr = get_tracer()
+        track = getattr(self, "_trace_name", "engine")
+        with tr.span("engine.stage", track=track):
+            key, inputs = stage(*args)
+        with tr.span("engine.launch", track=track):
+            return self._launch(key, inputs)
+
+    def _dispatch_and_collect(self, dispatch):
+        """The one bracket of a served step, on this replica's engine track.
+        ``dispatch()`` runs under ``engine.dispatch`` (host-side scheduling,
+        staging and the async launch: ``engine.schedule`` / ``engine.stage``
+        / ``engine.launch`` nest in it) and returns (the device arrays the
+        results come from, ``finish``, the dispatch span's args). The
+        arrays' host copies are requested between dispatch and the wait, in
+        neither span; ``engine.device_wait`` blocks on them;
+        ``engine.materialize`` reduces an expert model's routed rows and
+        runs ``finish()``, whose value is returned. So host-side queueing
+        and device time separate on the timeline. One path, traced or not:
+        the null tracer's spans are a shared no-op."""
+        tr = get_tracer()
+        track = getattr(self, "_trace_name", "engine")
+        with tr.span("engine.dispatch", track=track) as sp:
+            waited, finish, span_args = dispatch()
+            if tr.enabled:
+                sp.args = span_args
+        # an expert model's routed rows come from the same program: nothing
+        # more to wait for, but for a step that completed no row
+        waited = list(waited) + ([] if self._moe_pending is None else [self._moe_pending])
+        _start_host_copies(waited)
+        with tr.span("engine.device_wait", track=track):
+            device_synchronize(waited)
+        with tr.span("engine.materialize", track=track):
+            self._count_moe()
+            return finish()
+
+    def _count_moe(self) -> None:
+        """After the wait: reduce the step's routed rows ([L, E], or a fused
+        round's [n_steps, L, E]) to ``last_step.moe``. One layer call a row
+        of E: rows routed, rows the dispatch computed (the grouped kernel:
+        its tile size for every tile visit; the capacity dispatch: E x
+        capacity), the fullest expert's rows. None stays for a dense model
+        or a step that launched nothing."""
+        pending, self._moe_pending = self._moe_pending, None
+        if pending is None:
+            return
+        from deepspeed_tpu.parallel.moe import grouped, sharded_moe
+
+        c = self._mc
+        rows = np.asarray(pending)
+        counts = rows.reshape(-1, c.n_experts)
+        # tokens of one layer call: the grid, a step of it for a fused round
+        steps = rows.shape[0] if rows.ndim == 3 else 1
+        pairs = self.last_step.grid_slots // steps * c.moe_top_k
+        if c.moe_drop_tokens:
+            computed = counts.shape[0] * c.n_experts * sharded_moe._capacity(
+                pairs, c.n_experts, c.moe_capacity_factor)
+        else:
+            itemsize = jnp.dtype(T.DTYPES[c.dtype]).itemsize
+            computed = grouped.computed_rows(counts, grouped.row_tile(pairs, itemsize))
+        self.last_step.moe = {
+            "routed": int(counts.sum()), "computed": int(computed),
+            "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
+        }
+
+    # -- entry points --------------------------------------------------------
+    def decode_round(self, n_steps: Optional[int] = None) -> Dict[int, np.ndarray]:
+        """One fused decode round: ``n_steps`` tokens for every eligible
+        RUNNING sequence in a single device call. Only legal when no
+        prompt chunks are pending (prefill through step()/put() first).
+        Returns {uid: [n_steps] generated tokens}; the caller truncates at
+        EOS and calls scheduler.finish for completed sequences.
+
+        Sequences that cannot take a FULL round — within ``n_steps`` of
+        max_context or the per-sequence block cap, or whose block extension
+        fails because the pool is momentarily exhausted — are simply left
+        untouched (still running): capping, max-context stops, and
+        memory-pressure waiting all stay the per-step scheduler's job
+        (generate() falls back to step() when a round serves nobody)."""
+        n = int(n_steps or self.config.decode_steps)
+        sched = self.scheduler
+        if sched.has_pending():
+            raise RuntimeError(
+                "decode_round: prompt chunks are still pending — drive step() "
+                "until prefill completes before fused decode"
+            )
+        max_context = self.config.state_manager.max_context
+        R = self.config.state_manager.max_ragged_sequence_count
+        uids = []
+        for uid in sched.running_uids():
+            if len(uids) >= R:
+                break
+            seq = self.state_manager.get_sequence(uid)
+            if seq.seen_tokens + n > max_context:
+                continue  # near the context limit: per-step path stops it
+            if self.state_manager.seq_capped(seq, n):
+                continue  # near the block cap: per-step path caps it
+            if not self.state_manager.extend(seq, n):
+                continue  # pool momentarily exhausted: sequence waits
+            uids.append(uid)
+        if not uids:
+            return {}
+
+        def dispatch():
+            toks_out, logps_out = self._start(self._stage_round, uids, n)
+
+            def finish():
+                toks, logps = np.asarray(toks_out), np.asarray(logps_out)  # [n, R]
+                results: Dict[int, np.ndarray] = {}
+                self.last_logprobs = {}
+                for i, uid in enumerate(uids):
+                    sched.apply_decode_round(uid, toks[:, i])
+                    results[uid] = toks[:, i]
+                    self.last_logprobs[uid] = logps[:, i]
+                return results
+
+            return (toks_out, logps_out), finish, {"rows": len(uids), "steps": n}
+
+        return self._dispatch_and_collect(dispatch)
 
     def spec_round(self, k: Optional[int] = None, drafts=None) -> Dict[int, np.ndarray]:
         """One speculative draft-and-verify round over eligible RUNNING
@@ -1910,8 +1914,7 @@ class InferenceEngineV2:
         max_context = self.config.state_manager.max_context
         R = self.config.state_manager.max_ragged_sequence_count
         budget = self.config.state_manager.max_ragged_batch_size
-        K1 = k + 1
-        max_rows = min(R, max(1, budget // K1))
+        max_rows = min(R, max(1, budget // (k + 1)))
         run = sched.running_uids()
         if len(run) > max_rows:
             off = self._spec_rr % len(run)
@@ -1937,113 +1940,34 @@ class InferenceEngineV2:
             pre_blocks[uid] = pre
         if not uids:
             return {}
-        self.last_grid_slots = R * K1
-        self.last_scheduled_tokens = len(uids) + sum(len(d) for d in row_drafts)
-        self.last_prefill_tokens = 0
-        tr = get_tracer()
-        track = getattr(self, "_trace_name", "engine")
-        with tr.span("engine.dispatch", track=track,
-                     args={"rows": len(uids), "k": k} if tr.enabled else None):
-            with tr.span("engine.stage", track=track):
-                kv = self.config.kv_cache
-                B = kv.max_blocks_per_seq
-                trash = kv.num_blocks
-                tokens = np.zeros((R, K1), np.int32)
-                positions = np.zeros(R, np.int32)
-                tables = np.full((R, B), trash, np.int32)
-                uid_arr = np.zeros(R, np.int32)
-                active = np.zeros(R, bool)
-                n_input = np.ones(R, np.int32)
-                for i, (uid, d) in enumerate(zip(uids, row_drafts)):
-                    seq = self.state_manager.get_sequence(uid)
-                    tokens[i, 0] = sched.peek_next_token(uid)
-                    if d:
-                        tokens[i, 1 : 1 + len(d)] = d
-                    positions[i] = seq.seen_tokens
-                    tables[i, : len(seq.block_table)] = seq.block_table
-                    uid_arr[i] = uid
-                    active[i] = True
-                    n_input[i] = 1 + len(d)
-                self._count_paged(positions, calls=K1)
-            with tr.span("engine.launch", track=track):
-                if k not in self._verify_jit:
-                    self._verify_jit[k] = self._build_verify_step(k)
-                outs = self._verify_jit[k](
-                    self.params,
-                    jnp.asarray(tokens),
-                    jnp.asarray(positions),
-                    jnp.asarray(tables),
-                    jnp.asarray(uid_arr),
-                    jnp.asarray(active),
-                    jnp.asarray(n_input),
-                    self._rng,
-                    jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
-                    self._k_cache,
-                    self._v_cache,
-                    *self._scale_args(),
-                )
-                tgt, n_emit, logp, self._k_cache, self._v_cache = outs[:5]
-                if self._kv_int8:
-                    self._ks_cache, self._vs_cache = outs[5], outs[6]
-                self._moe_pending = (outs[-1], self.last_grid_slots) if self._mc.n_experts > 0 else None
-        waited = [tgt, n_emit, logp] + self._moe_arrays()
-        _start_host_copies(waited)
-        with tr.span("engine.device_wait", track=track):
-            device_synchronize(waited)
-        results: Dict[int, np.ndarray] = {}
-        self.last_logprobs = {}
-        drafted_total = accepted_total = 0
-        per_uid: Dict[int, Tuple[int, int]] = {}
-        with tr.span("engine.materialize", track=track):
-            self._count_moe()
-            tgt = np.asarray(tgt)
-            n_emit = np.asarray(n_emit)
-            logp = np.asarray(logp)
-            for i, uid in enumerate(uids):
-                n = int(n_emit[i])
-                gen = tgt[i, :n].astype(np.int32)
-                sched.apply_spec_round(uid, gen, pre_blocks[uid])
-                results[uid] = gen
-                self.last_logprobs[uid] = logp[i, :n]
-                d, a = int(n_input[i]) - 1, n - 1
-                drafted_total += d
-                accepted_total += a
-                per_uid[uid] = (d, a)
-        self.last_spec = {
-            "drafted": drafted_total, "accepted": accepted_total,
-            "per_uid": per_uid,
-        }
-        return results
 
-    def _moe_arrays(self):
-        """The routed-rows array of the step just launched (expert models),
-        for the host copy that is requested with the tokens'."""
-        return [self._moe_pending[0]] if self._moe_pending else []
+        def dispatch():
+            outputs = self._start(self._stage_verify, uids, row_drafts, k)
 
-    def _count_moe(self) -> None:
-        """After the wait: reduce the step's ``[.., L, E]`` routed rows to
-        ``last_moe``. One layer call a row of E: rows routed, rows the
-        dispatch computed (the grouped kernel: its tile size for every tile
-        visit; the capacity dispatch: E x capacity), the fullest expert's
-        rows. None for a dense model or a step that launched nothing."""
-        pending, self._moe_pending, self.last_moe = self._moe_pending, None, None
-        if pending is None:
-            return
-        from deepspeed_tpu.parallel.moe import grouped, sharded_moe
+            def finish():
+                tgt, n_emit, logp = (np.asarray(a) for a in outputs)
+                results: Dict[int, np.ndarray] = {}
+                self.last_logprobs = {}
+                drafted_total = accepted_total = 0
+                per_uid: Dict[int, Tuple[int, int]] = {}
+                for i, (uid, drafted) in enumerate(zip(uids, row_drafts)):
+                    n = int(n_emit[i])
+                    gen = tgt[i, :n].astype(np.int32)
+                    sched.apply_spec_round(uid, gen, pre_blocks[uid])
+                    results[uid] = gen
+                    self.last_logprobs[uid] = logp[i, :n]
+                    drafted_total += len(drafted)
+                    accepted_total += n - 1
+                    per_uid[uid] = (len(drafted), n - 1)
+                self.last_spec = {
+                    "drafted": drafted_total, "accepted": accepted_total,
+                    "per_uid": per_uid,
+                }
+                return results
 
-        c = self._mc
-        counts = np.asarray(pending[0]).reshape(-1, c.n_experts)
-        pairs = pending[1] * c.moe_top_k
-        if c.moe_drop_tokens:
-            computed = counts.shape[0] * c.n_experts * sharded_moe._capacity(
-                pairs, c.n_experts, c.moe_capacity_factor)
-        else:
-            itemsize = jnp.dtype(T.DTYPES[c.dtype]).itemsize
-            computed = grouped.computed_rows(counts, grouped.row_tile(pairs, itemsize))
-        self.last_moe = {
-            "routed": int(counts.sum()), "computed": int(computed),
-            "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
-        }
+            return outputs, finish, {"rows": len(uids), "k": k}
+
+        return self._dispatch_and_collect(dispatch)
 
     def put(self, batch_uids, batch_tokens) -> Dict[int, np.ndarray]:
         """Submit new sequences (reference put :107) and run ONE engine step.
@@ -2065,175 +1989,62 @@ class InferenceEngineV2:
         completed a prompt or decode token — the serving driver's step
         primitive. Takes the IN-PROGRAM sampled token (greedy or sampled per
         the engine's static sampling config), never a host argmax, so driven
-        serving reproduces ``generate()`` token-for-token.
+        serving reproduces ``generate()`` token-for-token. The wait wraps
+        the CALLER of ``_step_device`` (_dispatch_and_collect) — that
+        function itself must stay sync-free so ``generate()``'s prefill
+        pipelining is untouched."""
 
-        The step is bracketed into an ``engine.dispatch`` span (host-side
-        scheduling and staging + async program launch: ``_step_device``
-        nests ``engine.schedule`` / ``engine.stage`` / ``engine.launch`` in
-        it), an ``engine.device_wait`` span (blocking on the result arrays)
-        and an ``engine.materialize`` span (tokens to the host), so
-        host-side queueing and device time separate on the timeline. The
-        token arrays' host copy is requested between dispatch and the wait,
-        in neither span. One path, traced or not: the null tracer's spans
-        are a shared no-op.
-        The wait wraps the CALLER of ``_step_device`` — that function
-        itself must stay sync-free so ``generate()``'s prefill pipelining
-        is untouched."""
-        tr = get_tracer()
-        track = getattr(self, "_trace_name", "engine")
-        with tr.span("engine.dispatch", track=track) as sp:
+        def dispatch():
             res = self._step_device()
-            if tr.enabled:
-                sp.args = {"rows": len(res), "tokens": self.last_scheduled_tokens}
-        # the arrays the tokens come from (rows of one step share them)
-        waited = list({id(a): a for a in (
-            _entry_array(e, True)[0] for e in res.values())}.values())
-        # an expert model's routed rows come from the same program: nothing
-        # more to wait for, but for a step that completed no row
-        waited += self._moe_arrays()
-        _start_host_copies(waited)
-        with tr.span("engine.device_wait", track=track):
-            device_synchronize(waited)
-        out: Dict[int, int] = {}
-        with tr.span("engine.materialize", track=track):
-            self._count_moe()
-            for uid, tok in _materialize_rows(res, want_tokens=True).items():
-                out[uid] = int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
-        return out
+            # the arrays the tokens come from (rows of one step share them)
+            waited = {id(a): a for a in (_entry_array(e, True)[0] for e in res.values())}
+
+            def finish():
+                return {
+                    uid: int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
+                    for uid, tok in _materialize_rows(res, want_tokens=True).items()
+                }
+
+            return waited.values(), finish, {
+                "rows": len(res), "tokens": self.last_step.scheduled_tokens}
+
+        return self._dispatch_and_collect(dispatch)
 
     def _step_device(self) -> Dict[int, jax.Array]:
-        """The split-phase step: stage the scheduler's batch onto the fixed
-        [R decode slots | Rc chunks x tq] grid, run ONE compiled program,
-        return {uid: DEVICE logits row} for rows whose prompt (or decode
-        token) completed — no host sync happens here, so prefill steps
-        pipeline behind the host round trip (~90 ms on r05's host) instead
-        of paying it each."""
+        """The split-phase step: schedule, stage the batch (_stage_split),
+        run ONE compiled program, return {uid: DEVICE logits row} for rows
+        whose prompt (or decode token) completed — no host sync happens
+        here, so consecutive prefill steps are dispatched without one wait
+        between them."""
         tr = get_tracer()
-        track = getattr(self, "_trace_name", "engine")
-        with tr.span("engine.schedule", track=track):
+        with tr.span("engine.schedule", track=getattr(self, "_trace_name", "engine")):
             batch = self.scheduler.next_batch()
             self.last_capped |= self.scheduler.drain_capped()
-        self.last_grid_slots = self.last_scheduled_tokens = self.last_prefill_tokens = 0
-        self.last_paged_live_blocks = self.last_paged_table_slots = 0
+        self.last_step = StepStats()
         self._moe_pending = None
         if batch is None:
             return {}
-        with tr.span("engine.stage", track=track):
-            kv = self.config.kv_cache
-            sm = self.config.state_manager
-            R = sm.max_ragged_sequence_count
-            Rc = self.scheduler.max_prompt_chunks
-            B = kv.max_blocks_per_seq
-            bs = kv.block_size
-            trash = kv.num_blocks
-
-            dec_rows = [
-                (uid, toks, start)
-                for uid, toks, start, dec in zip(
-                    batch.uids, batch.tokens, batch.start_positions, batch.is_decode
-                )
-                if dec
-            ]
-            chk_rows = [
-                (uid, toks, start, chunked)
-                for uid, toks, start, chunked, dec in zip(
-                    batch.uids, batch.tokens, batch.start_positions,
-                    batch.is_prompt_chunk, batch.is_decode,
-                )
-                if not dec
-            ]
-            if len(dec_rows) > R or len(chk_rows) > Rc:
-                raise RuntimeError(
-                    f"split-phase batch overflow: {len(dec_rows)} decode rows "
-                    f"(cap {R}), {len(chk_rows)} prompt chunks (cap {Rc})"
-                )
-            max_chunk = max((len(t) for _, t, _, _ in chk_rows), default=1)
-            # chunk-length buckets: two shapes keep short prompts off the full
-            # prompt_chunk pad without a compile per ragged length
-            tq = 128 if max_chunk <= 128 else self.scheduler.prompt_chunk
-            tq = min(tq, self.scheduler.prompt_chunk)
-            T_ = R + Rc * tq
-            self.last_grid_slots = T_
-            self.last_scheduled_tokens = batch.total_tokens
-            self.last_prefill_tokens = sum(len(t) for _, t, _, _ in chk_rows)
-
-            tokens = np.zeros(T_, np.int32)
-            positions = np.zeros(T_, np.int32)
-            blk = np.full(T_, trash, np.int32)
-            row = np.zeros(T_, np.int32)
-            dec_tables = np.full((R, B), trash, np.int32)
-            dec_pos = np.full(R, -1, np.int32)  # -1 = inactive slot (masks all)
-            dec_uids = np.zeros(R, np.int32)
-            chk_tables = np.full((Rc, B), trash, np.int32)
-            chk_pos = np.full((Rc, tq), -1, np.int32)
-            chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
-            chk_last = np.zeros(Rc, np.int32)
-            chk_uids = np.zeros(Rc, np.int32)
-
-            for i, (uid, toks, start) in enumerate(dec_rows):
-                seq = self.state_manager.get_sequence(uid)
-                tokens[i] = toks[0]
-                positions[i] = start
-                nblk = len(seq.block_table)
-                dec_tables[i, :nblk] = seq.block_table
-                dec_pos[i] = start
-                dec_uids[i] = uid
-                blk[i] = seq.block_table[min(start // bs, nblk - 1)]
-                row[i] = start % bs
-            self._count_paged(dec_pos)
-            for j, (uid, toks, start, _chunked) in enumerate(chk_rows):
-                seq = self.state_manager.get_sequence(uid)
-                n = len(toks)
-                off = R + j * tq
-                tokens[off : off + n] = toks
-                pos = start + np.arange(n)
-                positions[off : off + n] = pos
-                nblk = len(seq.block_table)
-                chk_tables[j, :nblk] = seq.block_table
-                chk_pos[j, :n] = pos
-                chk_start[j] = start
-                chk_uids[j] = uid
-                # host-side scheduler metadata, not a device value
-                blk[off : off + n] = np.asarray(seq.block_table, np.int32)[  # dstpu: noqa[host-sync-in-loop]
-                    np.minimum(pos // bs, nblk - 1)
-                ]
-                row[off : off + n] = pos % bs
-                chk_last[j] = off + n - 1
-
-        with tr.span("engine.launch", track=track):
-            if tq not in self._split_jit:
-                self._split_jit[tq] = self._build_split_step(tq)
-            outs = self._split_jit[tq](
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(blk),
-                jnp.asarray(row),
-                jnp.asarray(dec_tables),
-                jnp.asarray(dec_pos),
-                jnp.asarray(dec_uids),
-                jnp.asarray(chk_tables),
-                jnp.asarray(chk_pos),
-                jnp.asarray(chk_start),
-                jnp.asarray(chk_last),
-                jnp.asarray(chk_uids),
-                self._rng,
-                jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
-                self._k_cache,
-                self._v_cache,
-                *self._scale_args(),
+        dec_rows = [
+            (uid, toks, start)
+            for uid, toks, start, dec in zip(
+                batch.uids, batch.tokens, batch.start_positions, batch.is_decode
             )
-            (logits_dec, logits_chk, toks_dec, toks_chk,
-             self._k_cache, self._v_cache) = outs[:6]
-            if self._kv_int8:
-                self._ks_cache, self._vs_cache = outs[6], outs[7]
-            if self._mc.n_experts > 0:
-                self._moe_pending = (outs[-1], T_)
-        # rows are referenced as (logits array, row index, greedy-token
-        # array): slicing logits_dec[i] here would issue one tiny device op
-        # per completed row per step — at r05's ~90 ms round trip those
-        # dominated the whole prefill phase. Callers materialize each ARRAY
-        # once; generate() keeps only the token arrays alive.
+            if dec
+        ]
+        chk_rows = [
+            (uid, toks, start, chunked)
+            for uid, toks, start, chunked, dec in zip(
+                batch.uids, batch.tokens, batch.start_positions,
+                batch.is_prompt_chunk, batch.is_decode,
+            )
+            if not dec
+        ]
+        logits_dec, logits_chk, toks_dec, toks_chk = self._start(
+            self._stage_split, batch.total_tokens, dec_rows, chk_rows)
+        # rows are referenced as (logits array, row index, token array):
+        # slicing logits_dec[i] here would issue one tiny device op per
+        # completed row per step. Callers materialize each ARRAY once;
+        # generate() keeps only the token arrays alive.
         results: Dict[int, tuple] = {}
         for i, (uid, toks, _start) in enumerate(dec_rows):
             seq = self.state_manager.get_sequence(uid)
@@ -2244,50 +2055,6 @@ class InferenceEngineV2:
             seq.seen_tokens += len(toks)
             if not chunked:  # prompt complete: last-token logits usable
                 results[uid] = (logits_chk, j, toks_chk)
-        return results
-
-    def _step_per_row(self) -> Dict[int, np.ndarray]:
-        """Round-1 execution model (one compiled call per sequence) — kept as
-        the baseline the batched step is benchmarked against."""
-        if self._mc.attn_layer_pattern is not None:
-            raise NotImplementedError(
-                "_step_per_row: alternating layer patterns run only through "
-                "the batched step (its unrolled layer loop)"
-            )
-        batch = self.scheduler.next_batch()
-        self.last_scheduled_tokens = batch.total_tokens if batch is not None else 0
-        self.last_capped |= self.scheduler.drain_capped()
-        if batch is None:
-            return {}
-        results: Dict[int, np.ndarray] = {}
-        for uid, toks, start, chunked in zip(
-            batch.uids, batch.tokens, batch.start_positions, batch.is_prompt_chunk
-        ):
-            seq = self.state_manager.get_sequence(uid)
-            t = len(toks)
-            tb = _bucket(t)
-            if tb not in self._row_jit:
-                self._row_jit[tb] = self._build_row_step(tb)
-            padded = np.zeros((1, tb), np.int32)
-            padded[0, :t] = toks
-            table = jnp.asarray(self.state_manager.block_table_array(seq))
-            outs = self._row_jit[tb](
-                self.params,
-                jnp.asarray(padded),
-                jnp.int32(start),
-                jnp.int32(t),
-                table,
-                self._k_cache,
-                self._v_cache,
-                *self._scale_args(),
-            )
-            logits, self._k_cache, self._v_cache = outs[0], outs[1], outs[2]
-            if self._kv_int8:
-                self._ks_cache, self._vs_cache = outs[3], outs[4]
-            seq.seen_tokens += t
-            if not chunked:  # prompt complete (or decode token): logits usable
-                # deliberate materialization point: one transfer per finished row
-                results[uid] = np.asarray(logits)  # dstpu: noqa[host-sync-in-loop]
         return results
 
     # -- convenience generation loop (greedy) ---------------------------------
@@ -2311,12 +2078,11 @@ class InferenceEngineV2:
 
         # ---- phase 1: prefill without per-step syncs ----
         # Completed rows' next tokens accumulate ON DEVICE in one rolling
-        # DONATED buffer; the host holds only {uid: slot} ints. Retaining
-        # ANY step output array across subsequent dispatches stalled the
-        # pipeline ~75 ms/step on r05's host (measured: 120 vs 44 ms/step;
-        # replaying identical calls showed holding itself is free — the
-        # cost was in that host's device attachment; not re-measured on
-        # today's), so no step output may outlive the next call.
+        # DONATED buffer; the host holds only {uid: slot} ints, and no step
+        # output outlives the next call. (The rule dates from a host on
+        # which a retained output array stalled the next dispatch; whether
+        # it still buys anything is not measured on today's: no cell of the
+        # benchmark runs generate().)
         held: Dict[int, tuple] = {}
         slots: Dict[int, int] = {}
         cap = self.config.state_manager.max_tracked_sequences
@@ -2329,7 +2095,7 @@ class InferenceEngineV2:
         next_slot = 0
         while self.scheduler.has_pending():
             res = self._step_device()
-            if self.last_scheduled_tokens == 0:
+            if self.last_step.scheduled_tokens == 0:
                 break  # pool pressure: the interleaved loop below owns waiting
             groups: Dict[int, list] = {}
             for u, e in res.items():
@@ -2397,7 +2163,7 @@ class InferenceEngineV2:
             # make below can change scheduler state — fail loudly instead of
             # busy-looping (e.g. KV pool too fragmented for any pending
             # prompt with no running sequence left to free blocks).
-            if self.last_scheduled_tokens == 0 and self.scheduler.has_work():
+            if self.last_step.scheduled_tokens == 0 and self.scheduler.has_work():
                 raise RuntimeError(
                     "scheduler deadlock: work pending but nothing schedulable "
                     f"(free KV blocks={self.state_manager.free_blocks}); "

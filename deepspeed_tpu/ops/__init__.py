@@ -128,7 +128,6 @@ from deepspeed_tpu.ops.sparse_attention import (  # noqa: F401
     sparse_attention,
     sparse_attention_reference,
     splash_attention,
-    splash_prefill_attention,
 )
 
 # Compatibility table (reference deepspeed.ops.__compatible_ops__)

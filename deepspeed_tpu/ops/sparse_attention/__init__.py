@@ -48,7 +48,6 @@ from deepspeed_tpu.ops.sparse_attention.sparse_pallas import (
 )
 from deepspeed_tpu.ops.sparse_attention.splash_pallas import (
     splash_attention,
-    splash_prefill_attention,
 )
 
 
@@ -162,5 +161,4 @@ __all__ = [
     "sparse_attention_reference",
     "sparse_attention_with_bias",
     "splash_attention",
-    "splash_prefill_attention",
 ]

@@ -551,62 +551,3 @@ def splash_attention(q, k, v, schedule: BlockSchedule, *,
     kind_t = jnp.asarray(schedule.step_kind_t)
     base = jnp.zeros((1,), jnp.int32)
     return _splash_core(q, k, v, seg, kvi, kind, kvi_t, kind_t, base, params)
-
-
-def splash_prefill_attention(q, k, v, start, *, window: int = 0,
-                             block_kv: int, scale: Optional[float] = None,
-                             interpret: Optional[bool] = None,
-                             vmem_limit_bytes: Optional[int] = None):
-    """Forward-only scheduled attention for serving chunked prefill.
-
-    ``q`` is one [b, h, t, d] chunk whose rows sit at global positions
-    ``start .. start+t-1`` (``start`` a traced int32 scalar); k/v are the
-    gathered paged context [b, h_kv, S, d] at positions 0..S-1. Causal,
-    plus an optional sliding-window band. The schedule is computed IN-JIT
-    from ``start`` — scalar-prefetch operands are ordinary arrays, so one
-    compiled program serves every chunk position (no host rebuild) while
-    the kernel still visits only ~(window + t)/block_kv blocks instead of
-    all S/block_kv.
-    """
-    b, h, t, d = q.shape
-    S = k.shape[2]
-    if S % block_kv:
-        raise ValueError(f"context length {S} not divisible by block_kv {block_kv}")
-    nk = S // block_kv
-    if window:
-        width = min(nk, (t + window - 2) // block_kv + 2)
-    else:
-        width = nk
-    start = jnp.asarray(start, jnp.int32)
-    hi = start + t - 1                    # last q position in the chunk
-    last = hi // block_kv                 # last kv block any row attends
-    if window:
-        first = jnp.maximum(start - (window - 1), 0) // block_kv
-    else:
-        first = jnp.zeros((), jnp.int32)
-    idx = first + jnp.arange(width, dtype=jnp.int32)      # candidate blocks
-    k_lo = idx * block_kv
-    k_hi = k_lo + block_kv - 1
-    in_range = idx <= last
-    if window:
-        full = (k_hi <= start) & ((hi - k_lo) < window)
-        empty = ~in_range | ((start - k_hi) >= window)
-    else:
-        full = k_hi <= start
-        empty = ~in_range
-    kind = jnp.where(empty, 0, jnp.where(full, FULL, 1)).astype(jnp.int32)
-    # clamp padding steps to the last active block -> copy elided
-    kvi = jnp.clip(idx, 0, jnp.maximum(last, 0)).astype(jnp.int32)
-    params = _SplashParams(
-        bq=t, bk=block_kv, causal=True, window=int(window),
-        scale=float(scale if scale is not None else d ** -0.5),
-        has_partial=True, seg_mode="none",
-        interpret=_auto_interpret(interpret),
-        vmem_limit=(vmem_limit_bytes if vmem_limit_bytes is not None
-                    else _default_vmem_limit()),
-    )
-    out, _ = _splash_fwd_call(
-        q, k, v, None,
-        kvi.reshape(1, 1, width), kind.reshape(1, 1, width),
-        start.reshape(1), params)
-    return out

@@ -177,18 +177,23 @@ class EngineCore:
 
     def _count_step(self) -> None:
         """One step or round ran: count it, and beside it what the engine
-        sized it to and what it carried (``last_*``, left by the engine as
-        it decides them), so that useful slots over computed slots is
-        measured where the work is decided."""
-        eng = self.engine
+        sized it to and what it carried (its ``last_step``, a ``StepStats``
+        filled as the engine stages the step), so that useful slots over
+        computed slots is measured where the work is decided. An engine
+        without one (a compute-free fake) counts zeros."""
+        stats = getattr(self.engine, "last_step", None)
+
+        def held(field):
+            return getattr(stats, field, 0)
+
         self._inc("engine_steps_total")
-        self._inc("grid_slots_total", getattr(eng, "last_grid_slots", 0))
-        self._inc("scheduled_tokens_total", getattr(eng, "last_scheduled_tokens", 0))
-        if getattr(eng, "last_prefill_tokens", 0):
+        self._inc("grid_slots_total", held("grid_slots"))
+        self._inc("scheduled_tokens_total", held("scheduled_tokens"))
+        if held("prefill_tokens"):
             self._inc("steps_with_prefill_total")
-        self._inc("paged_live_blocks_total", getattr(eng, "last_paged_live_blocks", 0))
-        self._inc("paged_table_slots_total", getattr(eng, "last_paged_table_slots", 0))
-        moe = getattr(eng, "last_moe", None)
+        self._inc("paged_live_blocks_total", held("paged_live_blocks"))
+        self._inc("paged_table_slots_total", held("paged_table_slots"))
+        moe = getattr(stats, "moe", None)
         if moe:  # an expert model: what its expert layers routed and computed
             self._inc("moe_routed_rows_total", moe["routed"])
             self._inc("moe_computed_rows_total", moe["computed"])
@@ -470,8 +475,8 @@ class EngineCore:
             if tr.enabled:
                 self._trace_round(tr, "step.split", t0, tr.now(), results, {
                     "rows": len(results),
-                    "tokens": int(getattr(self.engine,
-                                          "last_scheduled_tokens", 0) or 0),
+                    "tokens": int(getattr(getattr(self.engine, "last_step", None),
+                                          "scheduled_tokens", 0)),
                 })
         except Exception as e:
             # engine-level failure: per-request state is unknowable, so the

@@ -1,6 +1,7 @@
 """Paged block-table attention kernel numerics (interpret mode on CPU) and
-batched-engine-step equivalence/throughput (analogue of reference
-tests/unit/inference/v2 ragged_ops kernel tests)."""
+the batched engine step's call count (analogue of reference
+tests/unit/inference/v2 ragged_ops kernel tests; the batched step's tokens
+are held to the no-cache reference in test_inference.py)."""
 
 import numpy as np
 import pytest
@@ -86,7 +87,7 @@ def test_paged_kernel_bf16():
 
 
 # ---------------------------------------------------------------------------
-# engine: batched step ≡ per-row loop, and faster
+# engine: one device call a step
 # ---------------------------------------------------------------------------
 def _make_engine(seed=0):
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
@@ -106,28 +107,6 @@ def _make_engine(seed=0):
     return InferenceEngineV2(mc, params, cfg), mc
 
 
-def test_batched_step_matches_per_row():
-    """The fused single-call split step must produce the same tokens as the
-    round-1 per-sequence loop."""
-    prompts = [
-        np.arange(1, 9, dtype=np.int32),
-        np.arange(20, 25, dtype=np.int32),
-        np.arange(40, 52, dtype=np.int32),
-    ]
-    eng_a, _ = _make_engine()
-    out_a = eng_a.generate([p.copy() for p in prompts], max_new_tokens=6)
-
-    eng_b, _ = _make_engine()
-    # force the legacy execution model under generate()'s phased loop
-    eng_b.step = eng_b._step_per_row
-    eng_b._step_device = lambda: {
-        u: jnp.asarray(l) for u, l in eng_b._step_per_row().items()
-    }
-    out_b = eng_b.generate([p.copy() for p in prompts], max_new_tokens=6)
-    for a, b in zip(out_a, out_b):
-        np.testing.assert_array_equal(a, b)
-
-
 class _CountingJit:
     def __init__(self, fn):
         self.fn = fn
@@ -139,10 +118,9 @@ class _CountingJit:
 
 
 def test_batched_step_is_one_device_call():
-    """Multi-sequence decode must be ONE device call per engine step, vs one
-    per sequence in the per-row loop — the deterministic form of the >2x
-    throughput criterion (call count, not wall clock, so CI noise cannot
-    flake it; at n_seq=8 the dispatch ratio is 8:1)."""
+    """Multi-sequence decode must be ONE device call per engine step,
+    however many sequences it carries (call count, not wall clock, so CI
+    noise cannot flake it): at n_seq=8 a call a sequence would be 8 a step."""
     n_seq, steps = 8, 6
     prompts = [np.arange(1 + i, 9 + i, dtype=np.int32) for i in range(n_seq)]
 
@@ -158,28 +136,7 @@ def test_batched_step_is_one_device_call():
     eng_a._build_split_step = counting_split
     eng_a.generate([p.copy() for p in prompts], max_new_tokens=steps)
     batched_calls = sum(c.calls for c in split_counters.values())
-
-    eng_b, _ = _make_engine()
-    eng_b.step = eng_b._step_per_row
-    eng_b._step_device = lambda: {
-        u: jnp.asarray(l) for u, l in eng_b._step_per_row().items()
-    }
-    counters = {}
-
-    orig_build = eng_b._build_row_step
-
-    def counting_build(tb):
-        c = _CountingJit(orig_build(tb))
-        counters[tb] = c
-        return c
-
-    eng_b._build_row_step = counting_build
-    eng_b.generate([p.copy() for p in prompts], max_new_tokens=steps)
-    per_row_calls = sum(c.calls for c in counters.values())
-
-    # per-row: ~n_seq calls per decode step; batched: exactly 1
-    assert per_row_calls >= 2 * batched_calls, (batched_calls, per_row_calls)
-    assert batched_calls <= steps + n_seq + 2, batched_calls
+    assert 0 < batched_calls <= steps + n_seq + 2, batched_calls
 
 
 # ---------------------------------------------------------------------------

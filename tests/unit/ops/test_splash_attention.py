@@ -30,7 +30,6 @@ from deepspeed_tpu.ops.sparse_attention import (
     sparse_attention,
     sparse_attention_reference,
     splash_attention,
-    splash_prefill_attention,
 )
 from deepspeed_tpu.ops.sparse_attention.mask import EMPTY, FULL, PARTIAL, LayoutMask
 
@@ -286,32 +285,6 @@ class TestSplashKernel:
             sparse_attention_reference(q, k, v, layout, 64, bias=bias)
 
 
-class TestPrefill:
-    def test_prefill_matches_dense_mask_across_starts(self):
-        b, h, t, d, S, w = 1, 2, 32, 64, 256, 48
-        kq, kk, kv = jax.random.split(jax.random.key(3), 3)
-        q = jax.random.normal(kq, (b, h, t, d))
-        k = jax.random.normal(kk, (b, h, S, d))
-        v = jax.random.normal(kv, (b, h, S, d))
-
-        def dense(start):
-            qpos = start + jnp.arange(t)
-            kpos = jnp.arange(S)
-            keep = (kpos[None] <= qpos[:, None]) & (qpos[:, None] - kpos[None] < w)
-            bias = jnp.where(keep, 0.0, -1e30).astype(jnp.float32)[None, None]
-            return mha_reference(q, k, v, causal=False, bias=bias)
-
-        jitted = jax.jit(lambda s: splash_prefill_attention(
-            q, k, v, s, window=w, block_kv=32, interpret=True))
-        for start in (0, 32, 100, S - t):
-            np.testing.assert_allclose(
-                np.asarray(jitted(jnp.int32(start))), np.asarray(dense(start)),
-                rtol=2e-4, atol=2e-4, err_msg=f"start={start}")
-        # the schedule is computed IN-JIT from the traced start: every chunk
-        # position reuses ONE compiled program (no per-position retrace)
-        assert jitted._cache_size() == 1
-
-
 class TestAttentionSeam:
     def test_impl_splash_derived_schedule(self):
         q, k, v = _qkv(s=256, seed=4)
@@ -370,35 +343,6 @@ class TestModelAndServing:
         with pytest.raises(ValueError, match="attn_layer_pattern"):
             get_config("tiny", attention_impl="splash", sliding_window=8,
                        attn_layer_pattern=(1,) * 2)
-
-    def test_serving_prefill_stream_parity(self):
-        """Windowed chunked prefill through splash produces the same greedy
-        stream as the dense-masked path; window=None stays bit-identical
-        dense (the splash gate never fires)."""
-        from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
-        from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
-        from deepspeed_tpu.models import get_config, init_params
-
-        cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
-        params = init_params(cfg, jax.random.key(0))
-
-        def engine(c):
-            rc = RaggedInferenceEngineConfig.from_dict({
-                "dtype": "float32",
-                "kv_cache": {"block_size": 16, "num_blocks": 64,
-                             "max_blocks_per_seq": 8},
-                "state_manager": {"max_ragged_batch_size": 64,
-                                  "max_ragged_sequence_count": 4},
-            })
-            return InferenceEngineV2(c, params, rc)
-
-        prompt = np.arange(1, 41, dtype=np.int32)
-        wdense = dataclasses.replace(cfg, sliding_window=24)
-        wsplash = dataclasses.replace(cfg, sliding_window=24,
-                                      attention_impl="splash")
-        o_dense = engine(wdense).generate([prompt], max_new_tokens=6)[0]
-        o_splash = engine(wsplash).generate([prompt], max_new_tokens=6)[0]
-        np.testing.assert_array_equal(np.asarray(o_dense), np.asarray(o_splash))
 
 
 class TestSelfAttentionModule:

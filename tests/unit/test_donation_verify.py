@@ -1,11 +1,12 @@
-"""Tier-B donation regressions: the compiled split step must alias BOTH
-KV-cache pools (the donate_argnums off-by-one class this suite exists to
-catch), the streamed-adam leaf must alias all four donated state buffers
-(including the bf16 param mirror), and fixed-shape entry points must not
-retrace across same-shape calls. Aliasing is not enough: the compiled
-serving programs must also hold no copy the size of a KV pool (a layer loop
-that reads the step-start pool and scatters into the carried one aliases
-both pools and still copies each twice a step)."""
+"""Tier-B donation regressions: every compiled serving program must alias
+EVERY leaf of its pools argument (the class this suite exists to catch: a
+donation that names the wrong argument, or misses the int8 scale planes,
+copies a whole pool every step), the streamed-adam leaf must alias all four
+donated state buffers (including the bf16 param mirror), and fixed-shape
+entry points must not retrace across same-shape calls. Aliasing is not
+enough: the compiled serving programs must also hold no copy the size of a
+KV pool (a layer loop that reads the step-start pool and scatters into the
+carried one aliases both pools and still copies each twice a step)."""
 
 import functools
 
@@ -15,6 +16,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.analysis import verify as dv
 
+PROGRAMS = ["split_step", "multistep_decode", "verify_step"]
+
 
 @functools.lru_cache(maxsize=None)
 def _programs(kv_dtype):
@@ -23,39 +26,35 @@ def _programs(kv_dtype):
     return dv._engine_v2_programs(kv_dtype)
 
 
-@pytest.fixture(scope="module")
-def split_step_capture():
-    eng, programs = _programs("bf16")
-    assert "split_step" in programs, "harness never hit the split-step path"
-    return (eng,) + programs["split_step"]
-
-
-def test_split_step_aliases_both_kv_pools(split_step_capture):
-    eng, fn, args = split_step_capture
-    res = dv.check_donation("split_step", fn, args)
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_step_programs_alias_every_pool_leaf(program, kv_dtype):
+    eng, programs = _programs(kv_dtype)
+    assert program in programs, f"harness never hit the {program} path"
+    fn, args = programs[program]
+    res = dv.check_donation(program, fn, args)
     assert res.ok, res.detail
-    assert len(res.buffers) == 2, [b.render() for b in res.buffers]
     assert all(b.aliased for b in res.buffers)
-    # the two donated buffers ARE the k/v pools, not some other leaves
-    got = sorted(tuple(b.shape) for b in res.buffers)
-    want = sorted((tuple(eng._k_cache.shape), tuple(eng._v_cache.shape)))
-    assert got == want
+    # the donated buffers ARE the pools' leaves (k, v and, for int8, the two
+    # scale planes), and nothing else is donated
+    pools = eng._pools()
+    assert len(pools) == (4 if kv_dtype == "int8" else 2)
+    got = sorted((tuple(b.shape), b.dtype) for b in res.buffers)
+    assert got == sorted((tuple(p.shape), str(p.dtype)) for p in pools)
 
 
-def test_split_step_traces_once(split_step_capture):
-    _, fn, _ = split_step_capture
-    res = dv.check_recompile("split_step", fn)
+def test_split_step_traces_once():
+    _, programs = _programs("bf16")
+    res = dv.check_recompile("split_step", programs["split_step"][0])
     assert res.ok, res.detail
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-@pytest.mark.parametrize("program", ["split_step", "multistep_decode", "verify_step", "row_step"])
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_serving_programs_copy_no_pool(program, kv_dtype):
     eng, programs = _programs(kv_dtype)
     fn, args = programs[program]
-    pools = (eng._k_cache, eng._v_cache) + eng._scale_args()
-    assert len(pools) == (4 if kv_dtype == "int8" else 2)
-    res = dv.check_pool_copies(program, fn, args, pools)
+    res = dv.check_pool_copies(program, fn, args, eng._pools())
     assert res.ok, res.detail
 
 

@@ -263,14 +263,62 @@ class TestInferenceV2:
             InferenceEngineV2(cfg, params, rc)
         reset_topology()
 
-    def test_continuous_batching_multi_sequence(self, tiny_model):
-        cfg, params = tiny_model
+    @pytest.mark.parametrize("geometry", ["tiny", "gqa_4_2"])
+    def test_continuous_batching_multi_sequence(self, tiny_model, geometry):
+        """Batched generate() (the split step, several sequences a call)
+        against an oracle that shares no code with it: the no-cache full
+        forward. gqa_4_2: 4 query heads over 2 kv heads, prompts of 8, 5 and
+        12 tokens."""
+        if geometry == "tiny":
+            cfg, params = tiny_model
+            prompts, n_new = [np.arange(1, 9), np.arange(21, 33), np.arange(5, 10)], 5
+        else:
+            from deepspeed_tpu.models import TransformerConfig
+
+            cfg = TransformerConfig(
+                vocab_size=128, hidden_size=64, n_layers=2, n_heads=4, n_kv_heads=2,
+                max_seq_len=256, dtype="float32",
+            )
+            params = init_params(cfg, jax.random.key(0))
+            prompts, n_new = [np.arange(1, 9), np.arange(20, 25), np.arange(40, 52)], 6
         engine = self._engine(cfg, params)
-        prompts = [np.arange(1, 9), np.arange(21, 33), np.arange(5, 10)]
-        refs = [_greedy_reference(cfg, params, p, 5) for p in prompts]
-        outs = engine.generate(prompts, max_new_tokens=5)
+        refs = [_greedy_reference(cfg, params, p, n_new) for p in prompts]
+        outs = engine.generate(prompts, max_new_tokens=n_new)
         for o, r in zip(outs, refs):
             np.testing.assert_array_equal(o, r)
+
+    @pytest.mark.parametrize("entry", ["step_tokens", "decode_round", "spec_round"])
+    def test_step_stats_filled_by(self, tiny_model, entry):
+        """Every entry point leaves ONE fresh record of its step in
+        ``last_step``: what the grid was sized to, what it carried, and what
+        one layer's decode attention held (R = 4 rows, tables of 8 blocks of
+        16, one chunk of 64 slots a split step). The values are the ones the
+        six ``last_*`` attributes held before the record replaced them."""
+        from deepspeed_tpu.inference.v2.engine_v2 import StepStats
+
+        cfg, params = tiny_model
+        engine = self._engine(cfg, params)
+        engine.scheduler.submit(0, np.arange(1, 21, dtype=np.int32))
+        toks = engine.step_tokens()  # the prompt's 20 tokens: no decode row yet
+        prefill = engine.last_step
+        assert prefill == StepStats(4 + 64, 20, 20, 0, 4 * 8)
+        engine.scheduler.feedback(0, toks[0])
+        if entry == "step_tokens":
+            engine.scheduler.feedback(0, engine.step_tokens()[0])
+            # one decode row whose pool window holds 20 tokens = 2 blocks
+            assert engine.last_step == StepStats(4 + 64, 1, 0, 2, 4 * 8)
+        elif entry == "decode_round":
+            assert len(engine.decode_round(3)[0]) == 3
+            # a round's 3 kernel calls a layer walk the round-start window
+            assert engine.last_step == StepStats(4 * 3, 3, 0, 3 * 2, 3 * 4 * 8)
+        else:
+            assert 1 <= len(engine.spec_round(2, drafts={0: [5]})[0]) <= 2
+            # the pending token and one draft on a grid of R x (k + 1)
+            assert engine.last_step == StepStats(4 * 3, 2, 0, 3 * 2, 3 * 4 * 8)
+        assert engine.last_step is not prefill and prefill.prefill_tokens == 20
+        # a step with nothing to schedule starts from zeros again
+        engine.scheduler.finish(0)
+        assert engine.step_tokens() == {} and engine.last_step == StepStats()
 
     @pytest.mark.parametrize("ds", [4, 8])
     def test_fused_multistep_decode_matches_per_step(self, tiny_model, ds):

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.kv_pool import (
     blocks_for_budget,
@@ -132,23 +131,6 @@ class TestEngineInt8:
         per = bytes_per_block(16, mc.kv_heads, mc.head_dim, mc.n_layers, "int8")
         assert info["kv_pool_bytes"] == (64 + 1) * per
         assert info["kv_bytes_per_block"] == per
-
-    def test_row_step_int8_matches_batched(self):
-        """The per-row baseline path quantizes on write and attends through
-        the paged dense impl (in-gather dequant) — it must stream the same
-        tokens as the batched int8 step (regression: this path used to raise
-        NotImplementedError for int8 pools)."""
-        eng_a, _ = _make_engine(kv_dtype="int8")
-        out_a = eng_a.generate(_prompts(seed=3), max_new_tokens=6)
-        eng_b, _ = _make_engine(kv_dtype="int8")
-        # force the legacy execution model under generate()'s phased loop
-        eng_b.step = eng_b._step_per_row
-        eng_b._step_device = lambda: {
-            u: jnp.asarray(l) for u, l in eng_b._step_per_row().items()
-        }
-        out_b = eng_b.generate(_prompts(seed=3), max_new_tokens=6)
-        for a, b in zip(out_a, out_b):
-            np.testing.assert_array_equal(a, b)
 
     def test_bad_kv_dtype_raises(self):
         with pytest.raises(ValueError):

@@ -51,7 +51,7 @@ def _prompts(vocab):
 
 def _pools(eng):
     """The pools (and int8 scale planes) on the host, as [L, NBp, bs, nkv(, d)]."""
-    return [np.asarray(p) for p in (eng._k_cache, eng._v_cache) + eng._scale_args()]
+    return [np.asarray(p) for p in eng._pools()]
 
 
 def _rows(pools, table, n):

@@ -211,12 +211,12 @@ def test_serving_counters_equal_a_count_by_hand():
     for real in (8, 5, 1):                       # chunk, chunk, one decode row
         toks = eng.step_tokens()
         core._count_step()
-        assert eng.last_scheduled_tokens == real and eng.last_grid_slots == 4 + 2 * 8
-        assert eng.last_moe["routed"] == real * k * L and eng.last_moe["calls"] == L
+        assert eng.last_step.scheduled_tokens == real and eng.last_step.grid_slots == 4 + 2 * 8
+        assert eng.last_step.moe["routed"] == real * k * L and eng.last_step.moe["calls"] == L
         # the fullest expert holds at least the mean and at most every token
-        assert real * k * L / 8 <= eng.last_moe["hot"] <= real * L
-        assert eng.last_moe["computed"] % 8 == 0 and eng.last_moe["computed"] >= eng.last_moe["routed"]
-        want.append(dict(eng.last_moe))
+        assert real * k * L / 8 <= eng.last_step.moe["hot"] <= real * L
+        assert eng.last_step.moe["computed"] % 8 == 0 and eng.last_step.moe["computed"] >= eng.last_step.moe["routed"]
+        want.append(dict(eng.last_step.moe))
         for uid, tok in toks.items():
             eng.scheduler.feedback(uid, tok)
     c = core.metrics.counters
@@ -230,7 +230,7 @@ def test_serving_counters_equal_a_count_by_hand():
     eng = InferenceEngineV2(dense, init_params(dense, jax.random.key(0)), rc)
     eng.scheduler.submit(0, np.arange(1, 6, dtype=np.int32))
     eng.step_tokens()
-    assert eng.last_moe is None
+    assert eng.last_step.moe is None
 
 
 @pytest.mark.parametrize("case", ["moe_tp2", "moe_int8_weights", "full_norm_tp2"])

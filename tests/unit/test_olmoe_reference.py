@@ -92,7 +92,7 @@ def test_prefill_in_chunks_then_decode_through_the_paged_cache(model):
         if step == 1:   # row 0 decodes from here on; row 1's prompt takes two chunks of 8 and 5
             eng.scheduler.submit(1, np.asarray(history[1], np.int32))
         out = eng.step()
-        mixed += eng.last_prefill_tokens > 0 and eng.last_scheduled_tokens > eng.last_prefill_tokens
+        mixed += 0 < eng.last_step.prefill_tokens < eng.last_step.scheduled_tokens
         for uid, row in out.items():
             want = ref.logits(params, np.asarray(history[uid], np.int32), hf, rows=[-1])[0]
             np.testing.assert_allclose(row, want, atol=TOL, rtol=0, err_msg=f"step {step} uid {uid}")

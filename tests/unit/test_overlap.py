@@ -443,9 +443,9 @@ class TestSplitStepDonation:
         assert "fn_args" in captured, "split step never ran"
         fn, args = captured["fn_args"]
 
-        # the cache pools are always the trailing pair of the split-step
-        # signature (donated), regardless of how many metadata args precede
-        kc_shape, vc_shape = args[-2].shape, args[-1].shape
+        # the cache pools are the step's last argument, (k, v), donated
+        # whole, whatever staged inputs precede it
+        kc_shape, vc_shape = (pool.shape for pool in args[-1])
         txt = fn.lower(*args).as_text()
         # every donated arg carries tf.aliasing_output in the lowered module;
         # collect the tensor types they annotate

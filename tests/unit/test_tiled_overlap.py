@@ -311,8 +311,9 @@ class TestHLOStructure:
 
     @pytest.mark.slow
     def test_engine_decode_program_has_tiled_peers(self, devices8):
-        """Same assertion against a real serving program: the tp2 row-step
+        """Same assertion against a real serving program: the tp2 split-step
         lowering must hand XLA >= tp_overlap_tiles independent permutes."""
+        from deepspeed_tpu.analysis import verify as dv
         from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
         from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
         from deepspeed_tpu.models import get_config, init_params
@@ -332,17 +333,10 @@ class TestHLOStructure:
                                   "max_ragged_sequence_count": 4},
             })
             eng = InferenceEngineV2(cfg, params, rc)
-            kv = eng.config.kv_cache
-            fn = eng._build_row_step(8)
-            args = (
-                eng.params,
-                jnp.zeros((1, 8), jnp.int32),
-                jnp.int32(0),
-                jnp.int32(8),
-                jnp.zeros((kv.max_blocks_per_seq,), jnp.int32),
-                eng._k_cache,
-                eng._v_cache,
-            ) + eng._scale_args()
+            captured = {}
+            dv._capture_builder(eng, "_build_split_step", captured, "split")
+            eng.generate([np.arange(1, 9, dtype=np.int32)], max_new_tokens=2)
+            fn, args = captured["split"]
             n, antichain = _permute_antichain(fn.lower(*args).as_text())
             assert antichain >= 4, (
                 f"decode program max antichain {antichain} < 4"
