@@ -23,7 +23,8 @@ once across representative same-shape calls is quietly recompiling on the
 hot path (weak-typed scalars, python-hash-unstable statics, ...).
 
 Entry points covered (the compiled hot paths every perf PR leans on):
-  * ``engine_v2`` split step, fused multistep decode, speculative verify step
+  * ``engine_v2`` split step (a chunk bucket and the decode-only shape), fused
+    multistep decode, speculative verify step
   * ``runtime.engine`` fused ZeRO-3 train step (bucketed-collective overlap)
   * ``runtime.streamed_adam`` per-leaf donated update
   * quantized-collective variants: TP decode through the int8 psum islands,
@@ -334,13 +335,14 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
 
 def _engine_v2_programs(kv_dtype: str):
     """The v2 serving programs of a tiny engine with a ``kv_dtype`` pool:
-    (engine, {name: (jitted, args)}). The split step and the fused decode
-    round are captured from two same-shape ``generate()`` passes (pass 1
-    traces, pass 2 must hit the caches); the verify step is lowered directly
-    with the inputs of an empty round (lowering reads shapes only, so passing
-    the live pools is safe). Every program takes ``(params, inputs, rng,
-    temperature, pools)`` and donates ``pools`` whole: int8 adds the scale
-    planes as two more leaves of it."""
+    (engine, {name: (jitted, args)}). The split step (at a chunk bucket) and
+    the fused decode round are captured from two same-shape ``generate()``
+    passes (pass 1 traces, pass 2 must hit the caches); the split step's
+    decode-only shape and the verify step are lowered directly with the
+    inputs of an empty step (lowering reads shapes only, so passing the live
+    pools is safe). Every program takes ``(params, inputs, rng, temperature,
+    pools)`` and donates ``pools`` whole: int8 adds the scale planes as two
+    more leaves of it."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -353,18 +355,27 @@ def _engine_v2_programs(kv_dtype: str):
         eng.generate([rng.integers(1, cfg.vocab_size, size=(12,)).astype(np.int32)
                       for _ in range(2)], max_new_tokens=6)
 
+    def staged(build, inputs):
+        return build, (
+            eng.params,
+            {name: jnp.asarray(a) for name, a in inputs.items()},
+            eng._rng,
+            jnp.float32(1.0),
+            eng._pools(),
+        )
+
+    # the split step of a batch with no chunk row (what every decode step of
+    # a served request runs at decode_steps 1): the generate() passes above
+    # decode in fused rounds, so it is staged here, with no row (the class's
+    # builder: the instance's is shadowed by the capture above)
+    (_, tq), inputs = eng._stage_split(0, [], [])
+    programs["decode_only_step"] = staged(type(eng)._build_split_step(eng, tq), inputs)
     # speculative verify step (serving/spec): the K+1-token draft-and-verify
     # program declares the pools donated — without aliasing, every spec
     # round would copy the whole paged pool, erasing the subsystem's win.
     # Its inputs are what the engine stages for a round with no row.
     _, inputs = eng._stage_verify([], [], 4)
-    programs["verify_step"] = (eng._build_verify_step(4), (
-        eng.params,
-        {name: jnp.asarray(a) for name, a in inputs.items()},
-        eng._rng,
-        jnp.float32(1.0),
-        eng._pools(),
-    ))
+    programs["verify_step"] = staged(eng._build_verify_step(4), inputs)
     return eng, programs
 
 
@@ -376,7 +387,7 @@ def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
     tag = "" if kv_dtype == "bf16" else f"[{kv_dtype}]"
     results: List[CheckResult] = []
     eng, programs = _engine_v2_programs(kv_dtype)
-    for key in ("split_step", "multistep_decode", "verify_step"):
+    for key in ("split_step", "decode_only_step", "multistep_decode", "verify_step"):
         label = f"engine_v2.{key}{tag}"
         if key not in programs:
             results.append(CheckResult(label, "donation", False,
@@ -1027,8 +1038,11 @@ def verify_elastic() -> List[CheckResult]:
     results: List[CheckResult] = []
 
     # -- warm spare: serving-shaped traffic after warm_trace is compile-free
+    # tables of 64 blocks: a prompt can pass 128 tokens, so the spare's
+    # baseline holds all three split shapes (decode-only, 128, prompt_chunk)
     pool = WarmSparePool(
-        factory=lambda: _tiny_v2_engine(decode_steps=2)[1],
+        factory=lambda: _tiny_v2_engine(
+            decode_steps=2, kv_extra={"max_blocks_per_seq": 64})[1],
         count=1,
         warm_kw={"decode_steps": 2, "spec_k": 0},
     )

@@ -389,6 +389,24 @@ def engine_config_from_args(args, cfg):
     })
 
 
+def _serving_engine(cfg, params, rc):
+    """An engine as a serving entry point admits requests into it: every
+    shape of the split step is built first (``warm_split_shapes``), so no
+    request, whatever its length, compiles one in the middle of its TTFT.
+    The programs are traced as a served step traces them, from a thread of
+    their own: tracing costs more the deeper the stack that calls it (the
+    three shapes of the OLMoE cell: 8.9 s from the benchmark's main thread,
+    6.0 s from a fresh one, on one machine in one call: PERF.md, PR 28)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+
+    engine = InferenceEngineV2(cfg, params, rc)
+    with ThreadPoolExecutor(max_workers=1, thread_name_prefix="serving-warm") as pool:
+        pool.submit(engine.warm_split_shapes).result()
+    return engine
+
+
 def build_serving_stack(args, cfg=None, params=None, tok=None):
     """Engine(s) + driver from parsed serve args (split out so tests can
     build the stack without a socket). Pass cfg/params/tok to skip
@@ -454,7 +472,7 @@ def build_serving_stack(args, cfg=None, params=None, tok=None):
         )
     if (n_prefill == 0 and n_decode == 1 and elastic_cfg is None
             and resilience_cfg is None):
-        engine = InferenceEngineV2(cfg, params, rc)
+        engine = _serving_engine(cfg, params, rc)
         driver = ServingDriver(
             engine,
             eos_token_id=getattr(tok, "eos_token_id", None),
@@ -467,9 +485,7 @@ def build_serving_stack(args, cfg=None, params=None, tok=None):
         return driver, tok
     # params are read-only at inference time: every engine shares them,
     # only the per-engine KV pools and scheduler state are separate
-    engines = [
-        InferenceEngineV2(cfg, params, rc) for _ in range(n_prefill + n_decode)
-    ]
+    engines = [_serving_engine(cfg, params, rc) for _ in range(n_prefill + n_decode)]
     spare_pool = None
     if elastic_cfg is not None:
         from deepspeed_tpu.serving.elastic import WarmSparePool
@@ -544,7 +560,6 @@ def build_agent_core(args, cfg=None, params=None, tok=None):
     the SAME flag->config mapping as the router's replicas — same seed,
     same sampling keys, so the streams it decodes are bit-identical to a
     local replica's."""
-    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.serving.cluster.core import EngineCore
     from deepspeed_tpu.serving.metrics import ServingMetrics
 
@@ -561,7 +576,7 @@ def build_agent_core(args, cfg=None, params=None, tok=None):
 
         set_topology(Topology(model=args.tp, data=0))
     rc = engine_config_from_args(args, cfg)
-    engine = InferenceEngineV2(cfg, params, rc)
+    engine = _serving_engine(cfg, params, rc)
     core = EngineCore(
         engine, name=args.name or "agent", role="decode",
         decode_steps=args.decode_steps, kv_headroom=args.kv_headroom,

@@ -800,80 +800,100 @@ class InferenceEngineV2:
                 sig[name] = _n(fn)
         return sig
 
-    def warm_trace(self, decode_steps: int = 1, spec_k: int = 0,
-                   uid: int = (1 << 30) + 7) -> Dict[str, int]:
-        """Pre-trace every step program the serving loop will drive, so a
-        warm-spare engine admits requests with ZERO admission-time
-        compiles: the split-phase step at both chunk buckets (128 and
-        ``prompt_chunk``), the fused decode round at ``decode_steps``, the
-        speculative verify step at ``spec_k``, and the fixed-window
-        chunked re-import scatter (preemption resume / host-tier readmit).
-        The throwaway sequences are finished and scrubbed from the prefix
-        trie afterwards, and sampling keys are content-addressed — warm
-        tracing never perturbs later streams. Returns the post-warm
-        ``trace_signature`` (the baseline scale-up asserts against).
-        Call BEFORE serving and AFTER the final ``set_sampling`` (sampling
-        knobs shape the programs and invalidate these caches)."""
+    def warm_split_shapes(self, uid: int = (1 << 30) + 7, while_running=None) -> None:
+        """Build every shape of the split step before a request is admitted,
+        whatever clients send first: a short throwaway prompt traces the 128
+        bucket, its first decode step the decode-only shape (``tq == 0``),
+        and the longest prompt one chunk can hold the ``prompt_chunk``
+        bucket. The serving entry points call this on every engine they
+        build (``inference/cli.py``), so the first prompt with a short tail
+        compiles nothing in the middle of its TTFT. ``while_running(uid)``
+        runs once, while the first sequence is running with a token fed
+        back (warm_trace's rounds). The throwaway sequences are finished
+        and scrubbed from the prefix trie afterwards, and sampling keys are
+        content-addressed — warming never perturbs later streams. Call
+        AFTER the final ``set_sampling`` (it invalidates the programs)."""
         sched = self.scheduler
+        kv = self.config.kv_cache
         vocab = int(getattr(self._mc, "vocab_size", 0) or 2)
         cache = self.state_manager.prefix_cache
         spill = getattr(cache, "spill_fn", None) if cache is not None else None
         if cache is not None:
             cache.spill_fn = None  # warm KV must not demote into the tier
-        lens = [8]
         pc = int(sched.prompt_chunk)
-        if pc > 128 and int(self.config.state_manager.max_context) > pc + 8:
-            lens.append(pc)  # the long-prompt chunk bucket (tq=prompt_chunk)
+        # the longest chunk an admissible prompt can make: past 128 tokens
+        # it lands in the prompt_chunk bucket
+        longest = min(pc, int(self.config.state_manager.max_context) - 1,
+                      min(kv.max_blocks_per_seq, kv.num_blocks) * kv.block_size)
+        lens = [8, longest] if longest > 128 else [8]
+
+        def next_token(wuid, length):
+            for _ in range(8 + length // max(1, pc)):
+                out = self.step_tokens()
+                if wuid in out:
+                    return out[wuid]
+            raise RuntimeError(
+                f"warm_split_shapes: a prompt of {length} tokens never produced a token")
+
         try:
             for i, length in enumerate(lens):
                 wuid = uid + i
-                toks = (np.arange(length, dtype=np.int32) % max(1, vocab - 1)) + 1
+                # no shared first token: a prefix hit would shorten the chunk
+                toks = ((np.arange(length, dtype=np.int32) + i) % max(1, vocab - 1)) + 1
                 sched.submit(wuid, toks)
                 try:
-                    tok = None
-                    for _ in range(8 + length // max(1, pc)):
-                        out = self.step_tokens()
-                        if wuid in out:
-                            tok = out[wuid]
-                            break
-                    if tok is None:
-                        raise RuntimeError(
-                            f"warm_trace: prefill of {length} tokens never "
-                            "produced a first token"
-                        )
-                    sched.feedback(wuid, tok)
+                    sched.feedback(wuid, next_token(wuid, length))
                     if i == 0:
-                        if decode_steps > 1 and hasattr(self, "decode_round"):
-                            self.decode_round(int(decode_steps))
-                        if spec_k > 0 and hasattr(self, "spec_round"):
-                            self.spec_round(
-                                int(spec_k), drafts={wuid: [1] * int(spec_k)}
-                            )
+                        # the short prompt's first decode step has no chunk beside it
+                        sched.feedback(wuid, next_token(wuid, length))
+                        if while_running is not None:
+                            while_running(wuid)
                 finally:
                     sched.finish(wuid)
-            # the fixed-window re-import scatter (resume/readmit path): one
-            # chunk+1-block round trip traces the padded-tail window shape
-            kv = self.config.kv_cache
-            chunk = int(getattr(kv, "host_tier_chunk_blocks", 8) or 8)
-            n = min(chunk + 1, int(kv.num_blocks))
-            if n > chunk:
-                blocks = list(range(n))
-                self.import_kv_blocks_chunked(
-                    blocks, self.export_kv_blocks(blocks), chunk_blocks=chunk
-                )
-                # ... and the device-resident wire (zero-copy handoff):
-                # the windowed gather + the same readmit scatter fed
-                # device windows, so a device-transport import on a warm
-                # spare traces nothing at admission time either
-                wins, ch = self.export_kv_blocks_windows(
-                    blocks, chunk_blocks=chunk)
-                self.import_kv_blocks_device(blocks, wins, ch)
         finally:
             if cache is not None:
                 try:
                     cache.clear()  # warm prefixes must never serve a hit
                 finally:
                     cache.spill_fn = spill
+
+    def warm_trace(self, decode_steps: int = 1, spec_k: int = 0,
+                   uid: int = (1 << 30) + 7) -> Dict[str, int]:
+        """Pre-trace every step program the serving loop will drive, so a
+        warm-spare engine admits requests with ZERO admission-time
+        compiles: the split-phase step at its three shapes (decode-only,
+        128 and ``prompt_chunk``: warm_split_shapes), the fused decode round
+        at ``decode_steps``, the speculative verify step at ``spec_k``, and
+        the fixed-window chunked re-import scatter (preemption resume /
+        host-tier readmit). Returns the post-warm ``trace_signature`` (the
+        baseline scale-up asserts against). Call BEFORE serving and AFTER
+        the final ``set_sampling`` (sampling knobs shape the programs and
+        invalidate these caches)."""
+
+        def rounds(wuid):
+            if decode_steps > 1:
+                self.decode_round(int(decode_steps))
+            if spec_k > 0:
+                self.spec_round(int(spec_k), drafts={wuid: [1] * int(spec_k)})
+
+        self.warm_split_shapes(uid, while_running=rounds)
+        # the fixed-window re-import scatter (resume/readmit path): one
+        # chunk+1-block round trip traces the padded-tail window shape
+        kv = self.config.kv_cache
+        chunk = int(getattr(kv, "host_tier_chunk_blocks", 8) or 8)
+        n = min(chunk + 1, int(kv.num_blocks))
+        if n > chunk:
+            blocks = list(range(n))
+            self.import_kv_blocks_chunked(
+                blocks, self.export_kv_blocks(blocks), chunk_blocks=chunk
+            )
+            # ... and the device-resident wire (zero-copy handoff): the
+            # windowed gather + the same readmit scatter fed device
+            # windows, so a device-transport import on a warm spare traces
+            # nothing at admission time either
+            wins, ch = self.export_kv_blocks_windows(
+                blocks, chunk_blocks=chunk)
+            self.import_kv_blocks_device(blocks, wins, ch)
         return self.trace_signature()
 
     def set_sampling(self, greedy=None, temperature=None, top_k=None,
@@ -1296,8 +1316,10 @@ class InferenceEngineV2:
         decode rows through _attn_decode (their own new K/V as the
         extra_kv self column), chunk rows through paged_chunk_attention
         (in-chunk causal over the chunk's fresh K/V + pool context below
-        the chunk start). No read needs this step's K/V from the pool, so
-        the layer only records them in ``carry`` (the side buffers) and the
+        the chunk start). At ``tq == 0`` the grid is the R decode slots
+        alone and the chunk half is absent, not empty: no chunk attention
+        is traced. No read needs this step's K/V from the pool, so the
+        layer only records them in ``carry`` (the side buffers) and the
         pool is written once, after the loop."""
         c = self._mc
         kv = self.config.kv_cache
@@ -1309,25 +1331,26 @@ class InferenceEngineV2:
         _, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"])
         k_pool, v_pool = meta["k_pool0"], meta["v_pool0"]
         ks_pool, vs_pool = meta["ks_pool0"], meta["vs_pool0"]
-        from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
-
-        out_d = self._attn_decode(
+        out = self._attn_decode(
             q[:R], k_pool, v_pool, li * NBp + meta["dec_tables"],
             meta["dec_pos"], w, li * NBp + kv.num_blocks,
             extra_kv=(k[:R, None], v[:R, None], meta["dec_pos"][:, None]),
             pool_limit=meta["dec_pos"],
             k_scale=ks_pool, v_scale=vs_pool,
         )
-        out_c = paged_chunk_attention(
-            q[R:].reshape(Rc, tq, nh, d), k_pool, v_pool,
-            li * NBp + meta["chk_tables"], meta["chk_pos"],
-            li * NBp + kv.num_blocks,
-            window=int(w), scale=c.attn_scale,
-            new_kv=(k[R:].reshape(Rc, tq, nkv, d), v[R:].reshape(Rc, tq, nkv, d)),
-            pool_limit=meta["chk_start"],
-            k_scale=ks_pool, v_scale=vs_pool,
-        )
-        out = jnp.concatenate([out_d, out_c.reshape(Rc * tq, nh, d)], axis=0)
+        if tq:
+            from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
+
+            out_c = paged_chunk_attention(
+                q[R:].reshape(Rc, tq, nh, d), k_pool, v_pool,
+                li * NBp + meta["chk_tables"], meta["chk_pos"],
+                li * NBp + kv.num_blocks,
+                window=int(w), scale=c.attn_scale,
+                new_kv=(k[R:].reshape(Rc, tq, nkv, d), v[R:].reshape(Rc, tq, nkv, d)),
+                pool_limit=meta["chk_start"],
+                k_scale=ks_pool, v_scale=vs_pool,
+            )
+            out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, d)], axis=0)
         x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li)
         return x, self._record_kv(carry, li, k, v, moe)
 
@@ -1335,14 +1358,20 @@ class InferenceEngineV2:
         """ONE compiled step over the split-phase batch: R decode slots +
         Rc prompt chunks of tq tokens (the static-shape SplitFuse). blk/row/
         positions come pre-staged from the host (_stage_split) —
-        data-dependent anyway. Outputs: (decode logits [R, vocab], chunk
-        logits [Rc, vocab], decode tokens [R], chunk tokens [Rc])."""
+        data-dependent anyway. Three shapes of the one program, by what the
+        batch holds: ``tq`` 128 or ``prompt_chunk`` (the chunk-length
+        buckets), and ``tq == 0`` for a batch with no chunk row: the grid
+        is the R decode slots, the program takes no ``chk_*`` input and
+        runs no chunk attention (the fused round's and the verify step's
+        grids are R rows wide already). Outputs: (decode logits [R, vocab],
+        chunk logits [Rc, vocab], decode tokens [R], chunk tokens [Rc]); the
+        chunk pair is None at ``tq == 0``, where no row reads it."""
         R = self.config.state_manager.max_ragged_sequence_count
         Rc = self.scheduler.max_prompt_chunks
 
         def step(params, inputs, rng, temperature, pools):
             tokens, positions = inputs["tokens"], inputs["positions"]
-            dec_pos, chk_pos, chk_last = inputs["dec_pos"], inputs["chk_pos"], inputs["chk_last"]
+            dec_pos = inputs["dec_pos"]
             x = self._embed(params, tokens, positions)
             k_pool0, v_pool0 = self._pool_views(*pools[:2])
             ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
@@ -1352,13 +1381,19 @@ class InferenceEngineV2:
                 # switch: padded slots carry position 0, so the plain max works
                 "live": jnp.max(positions) + 1,
                 "dec_tables": inputs["dec_tables"], "dec_pos": dec_pos,
-                "chk_tables": inputs["chk_tables"], "chk_pos": chk_pos,
-                "chk_start": inputs["chk_start"],
                 "k_pool0": k_pool0, "v_pool0": v_pool0,
                 "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
-                # the grid's padding: decode slots with no row, chunk tails
-                "slot_live": jnp.concatenate([dec_pos >= 0, chk_pos.reshape(Rc * tq) >= 0]),
+                # the grid's padding: decode slots with no row
+                "slot_live": dec_pos >= 0,
             }
+            if tq:
+                chk_pos = inputs["chk_pos"]
+                meta.update(
+                    chk_tables=inputs["chk_tables"], chk_pos=chk_pos,
+                    chk_start=inputs["chk_start"],
+                    # ... and chunk tails
+                    slot_live=jnp.concatenate([dec_pos >= 0, chk_pos.reshape(Rc * tq) >= 0]),
+                )
 
             def layer_fn(lp, x, li, carry, window=None):
                 return self._split_layer(lp, x, li, meta, carry, window=window)
@@ -1371,9 +1406,11 @@ class InferenceEngineV2:
             # phase and drops the logits
             logits_dec, toks_dec = self._sample_rows(
                 params, x, slice(0, R), rng, temperature, inputs["dec_uids"], dec_pos)
-            chk_at = jnp.clip(chk_last, 0, tokens.shape[0] - 1)
-            logits_chk, toks_chk = self._sample_rows(
-                params, x, chk_at, rng, temperature, inputs["chk_uids"], positions[chk_at])
+            logits_chk = toks_chk = None
+            if tq:
+                chk_at = jnp.clip(inputs["chk_last"], 0, tokens.shape[0] - 1)
+                logits_chk, toks_chk = self._sample_rows(
+                    params, x, chk_at, rng, temperature, inputs["chk_uids"], positions[chk_at])
             return (logits_dec, logits_chk, toks_dec, toks_chk), pools, self._moe_rows(side)
 
         return jax.jit(step, donate_argnums=(4,))
@@ -1626,7 +1663,8 @@ class InferenceEngineV2:
         """The scheduler's batch onto the fixed [R decode slots | Rc chunks x
         tq] grid: ``dec_rows`` (uid, tokens, start), ``chk_rows`` (uid,
         tokens, start, chunked). Returns the split step's cache key and its
-        inputs by name."""
+        inputs by name: the seven a decode slot needs and, for a batch that
+        holds a chunk, the five ``chk_*``."""
         kv = self.config.kv_cache
         R = self.config.state_manager.max_ragged_sequence_count
         Rc = self.scheduler.max_prompt_chunks
@@ -1638,11 +1676,14 @@ class InferenceEngineV2:
                 f"split-phase batch overflow: {len(dec_rows)} decode rows "
                 f"(cap {R}), {len(chk_rows)} prompt chunks (cap {Rc})"
             )
-        max_chunk = max((len(t) for _, t, _, _ in chk_rows), default=1)
         # chunk-length buckets: two shapes keep short prompts off the full
-        # prompt_chunk pad without a compile per ragged length
-        tq = 128 if max_chunk <= 128 else self.scheduler.prompt_chunk
-        tq = min(tq, self.scheduler.prompt_chunk)
+        # prompt_chunk pad without a compile per ragged length; a batch
+        # with no chunk takes the grid of its decode slots alone
+        tq = 0
+        if chk_rows:
+            max_chunk = max(len(t) for _, t, _, _ in chk_rows)
+            tq = min(128 if max_chunk <= 128 else self.scheduler.prompt_chunk,
+                     self.scheduler.prompt_chunk)
         T_ = R + Rc * tq
 
         tokens = np.zeros(T_, np.int32)
@@ -1690,12 +1731,16 @@ class InferenceEngineV2:
             T_, total_tokens, sum(len(t) for _, t, _, _ in chk_rows),
             *self._count_paged(dec_pos),
         )
-        return ("split", tq), {
+        inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,
             "dec_tables": dec_tables, "dec_pos": dec_pos, "dec_uids": dec_uids,
-            "chk_tables": chk_tables, "chk_pos": chk_pos, "chk_start": chk_start,
-            "chk_last": chk_last, "chk_uids": chk_uids,
         }
+        if tq:  # the decode-only shape takes, and is sent, no chunk input
+            inputs.update(
+                chk_tables=chk_tables, chk_pos=chk_pos, chk_start=chk_start,
+                chk_last=chk_last, chk_uids=chk_uids,
+            )
+        return ("split", tq), inputs
 
     def _stage_rows(self, uids, width: int):
         """What the fused round and the verify step share: one row a running
@@ -1797,7 +1842,16 @@ class InferenceEngineV2:
         waited = list(waited) + ([] if self._moe_pending is None else [self._moe_pending])
         _start_host_copies(waited)
         with tr.span("engine.device_wait", track=track):
-            device_synchronize(waited)
+            # A step that completed no row (prompt chunks with more to come
+            # and no decode row) has nothing to copy; it is waited for all
+            # the same, on the pool it returns. Left to run ahead, a long
+            # prompt's chunk steps queue on the device and the last one's
+            # wait holds the serving loop for all of them (no admission, no
+            # cancel, for up to max_context / prompt_chunk steps), and a
+            # chunk step costs one thing beside a decode row and another
+            # without: a first-token tail then reads which of the two its
+            # arrivals happened to meet.
+            device_synchronize(waited or [self._k_cache])
         with tr.span("engine.materialize", track=track):
             self._count_moe()
             return finish()
@@ -1992,7 +2046,8 @@ class InferenceEngineV2:
         serving reproduces ``generate()`` token-for-token. The wait wraps
         the CALLER of ``_step_device`` (_dispatch_and_collect) — that
         function itself must stay sync-free so ``generate()``'s prefill
-        pipelining is untouched."""
+        pipelining is untouched. Every step is waited for here, one that
+        completed no row too (``{}``): a served step is synchronous."""
 
         def dispatch():
             res = self._step_device()

@@ -413,8 +413,8 @@ class EngineCore:
 
     def step_once(self, sink) -> bool:
         """One engine step (or fused decode / speculative verify round).
-        Returns True if any token landed / request advanced (progress).
-        Caller holds ``step_lock``.
+        Returns True if any token landed or any prompt advanced by a chunk
+        (progress). Caller holds ``step_lock``.
 
         Wraps the step in the watchdog window — ``step_started_at`` is
         the monotonic stamp the coordinator's hung-step scan reads
@@ -502,8 +502,13 @@ class EngineCore:
                         f"serving[{self.name}]: prefix-cache clear failed: {ce}"
                     )
             return True
-        return self._deliver_results(
+        delivered = self._deliver_results(
             sink, sched, {uid: (tok,) for uid, tok in results.items()}, feedback=True)
+        # A step of prompt chunks with more to come lands no token and is
+        # progress all the same: read as none, it sent the serving loop into
+        # its stalled-on-KV-blocks poll between two chunks of one prompt.
+        return delivered or bool(
+            getattr(getattr(self.engine, "last_step", None), "scheduled_tokens", 0))
 
     # -- probation probes -------------------------------------------------
     def probe(self, lock_timeout_s: float = 0.5) -> None:

@@ -16,13 +16,14 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.analysis import verify as dv
 
-PROGRAMS = ["split_step", "multistep_decode", "verify_step"]
+PROGRAMS = ["split_step", "decode_only_step", "multistep_decode", "verify_step"]
 
 
 @functools.lru_cache(maxsize=None)
 def _programs(kv_dtype):
     """(engine, {name: (jitted, args)}) after two same-shape generate()
-    passes: pass 1 traces, pass 2 must hit the caches."""
+    passes: pass 1 traces, pass 2 must hit the caches. ``decode_only_step``
+    is the split step's shape for a batch with no chunk row."""
     return dv._engine_v2_programs(kv_dtype)
 
 

@@ -5,6 +5,9 @@ decode loop must exactly match greedy generation recomputing the full
 sequence each step (the no-cache reference).
 """
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,6 +41,50 @@ def tiny_model():
     cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
     params = init_params(cfg, jax.random.key(0))
     return cfg, params
+
+
+@functools.lru_cache(maxsize=None)
+def _geometry(name):
+    """(cfg, params, prompts, n_new, greedy references) of a tiny model:
+    ``mha`` 4:4 heads, ``gqa_4_2`` 4 query heads over 2 kv heads, ``experts``
+    8 experts of which 2 a token, dropless. The references are the no-cache
+    forward's, computed once a geometry."""
+    from deepspeed_tpu.models import TransformerConfig
+
+    cfg = {
+        "mha": lambda: get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512),
+        "gqa_4_2": lambda: TransformerConfig(
+            vocab_size=128, hidden_size=64, n_layers=2, n_heads=4, n_kv_heads=2,
+            max_seq_len=256, dtype="float32"),
+        "experts": lambda: TransformerConfig(
+            vocab_size=64, hidden_size=32, n_layers=2, n_heads=4, ffn_hidden_size=24,
+            n_experts=8, moe_top_k=2, moe_drop_tokens=False, moe_norm_topk_prob=False,
+            max_seq_len=64, dtype="float32"),
+    }[name]()
+    params = init_params(cfg, jax.random.key(0))
+    prompts, n_new = [np.arange(1, 9), np.arange(20, 25), np.arange(40, 52)], 5
+    return cfg, params, prompts, n_new, [_greedy_reference(cfg, params, p, n_new) for p in prompts]
+
+
+def _as_chunk_shape(engine, inputs, tq):
+    """The inputs of a decode-only split step as the split step took them
+    before it had that shape: the same decode rows on the grid of
+    ``R + Rc x tq`` slots, every chunk row empty."""
+    kv = engine.config.kv_cache
+    Rc = engine.scheduler.max_prompt_chunks
+
+    def grid(a, fill):
+        return np.concatenate([a, np.full(Rc * tq, fill, np.int32)])
+
+    return {
+        **inputs,
+        "tokens": grid(inputs["tokens"], 0), "positions": grid(inputs["positions"], 0),
+        "blk": grid(inputs["blk"], kv.num_blocks), "row": grid(inputs["row"], 0),
+        "chk_tables": np.full((Rc, kv.max_blocks_per_seq), kv.num_blocks, np.int32),
+        "chk_pos": np.full((Rc, tq), -1, np.int32),
+        "chk_start": np.zeros(Rc, np.int32), "chk_last": np.zeros(Rc, np.int32),
+        "chk_uids": np.zeros(Rc, np.int32),
+    }
 
 
 class TestInferenceV1:
@@ -287,7 +334,52 @@ class TestInferenceV2:
         for o, r in zip(outs, refs):
             np.testing.assert_array_equal(o, r)
 
-    @pytest.mark.parametrize("entry", ["step_tokens", "decode_round", "spec_round"])
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    @pytest.mark.parametrize("geometry", ["mha", "gqa_4_2", "experts"])
+    def test_decode_only_shape_equals_the_chunk_shape(self, geometry, pool):
+        """A step with no prompt chunk runs the split step's decode-only
+        shape: the grid of its R decode slots, no ``chk_*`` input. Its
+        tokens and logits are those of the chunk shape fed the same rows
+        beside empty chunk rows (what such a step ran before), and the
+        streams both shapes serve in turn are the no-cache forward's."""
+        cfg, params, prompts, n_new, refs = _geometry(geometry)
+        engine = self._engine(cfg, params, kv_cache_dtype=pool)
+        tq = min(128, engine.scheduler.prompt_chunk)
+        for out, ref in zip(engine.generate(prompts, max_new_tokens=n_new), refs):
+            np.testing.assert_array_equal(out, ref)
+        assert sorted(engine._programs) == [("split", 0), ("split", tq)]
+
+        # the prompts again, up to a batch of three decode rows and no chunk
+        sched = engine.scheduler
+        first = {}
+        for uid, p in enumerate(prompts):
+            sched.submit(uid, p)
+        while sched.has_pending():
+            first.update(engine.step_tokens())
+        for uid, tok in first.items():
+            sched.feedback(uid, tok)
+        batch = sched.next_batch()
+        assert all(batch.is_decode) and len(batch.uids) == len(prompts)
+        key, inputs = engine._stage_split(
+            batch.total_tokens, list(zip(batch.uids, batch.tokens, batch.start_positions)), [])
+        assert key == ("split", 0)
+        assert sorted(inputs) == ["blk", "dec_pos", "dec_tables", "dec_uids", "positions",
+                                  "row", "tokens"]
+        assert {len(inputs[k]) for k in ("tokens", "positions", "blk", "row", "dec_pos")} == {4}
+        # both programs read the pool below the rows' positions and write
+        # the same K/V at them: run one after the other on the same pools
+        logits0, no_logits, toks0, no_toks = engine._launch(key, inputs)
+        logits1, _, toks1, _ = engine._launch(("split", tq), _as_chunk_shape(engine, inputs, tq))
+        assert no_logits is None and no_toks is None
+        live = slice(0, len(batch.uids))
+        np.testing.assert_array_equal(np.asarray(toks0)[live], np.asarray(toks1)[live])
+        np.testing.assert_allclose(
+            np.asarray(logits0)[live], np.asarray(logits1)[live], atol=2e-5, rtol=2e-5)
+        for i, uid in enumerate(batch.uids):
+            assert int(toks0[i]) == refs[uid][len(prompts[uid]) + 1]
+
+    @pytest.mark.parametrize("entry", ["step_tokens", "step_tokens_experts", "decode_round",
+                                       "spec_round"])
     def test_step_stats_filled_by(self, tiny_model, entry):
         """Every entry point leaves ONE fresh record of its step in
         ``last_step``: what the grid was sized to, what it carried, and what
@@ -296,17 +388,27 @@ class TestInferenceV2:
         six ``last_*`` attributes held before the record replaced them."""
         from deepspeed_tpu.inference.v2.engine_v2 import StepStats
 
-        cfg, params = tiny_model
+        cfg, params = _geometry("experts")[:2] if entry == "step_tokens_experts" else tiny_model
         engine = self._engine(cfg, params)
         engine.scheduler.submit(0, np.arange(1, 21, dtype=np.int32))
         toks = engine.step_tokens()  # the prompt's 20 tokens: no decode row yet
         prefill = engine.last_step
-        assert prefill == StepStats(4 + 64, 20, 20, 0, 4 * 8)
+        assert dataclasses.replace(prefill, moe=None) == StepStats(4 + 64, 20, 20, 0, 4 * 8)
         engine.scheduler.feedback(0, toks[0])
-        if entry == "step_tokens":
+        if entry.startswith("step_tokens"):
             engine.scheduler.feedback(0, engine.step_tokens()[0])
-            # one decode row whose pool window holds 20 tokens = 2 blocks
-            assert engine.last_step == StepStats(4 + 64, 1, 0, 2, 4 * 8)
+            # a step with no chunk is sized to its R decode slots alone; one
+            # decode row whose pool window holds 20 tokens = 2 blocks
+            moe = None
+            if cfg.n_experts:
+                # what _count_moe reads off the grid: L layer calls, each the
+                # row's top-2 pairs in tiles sized for R x 2 pairs
+                from deepspeed_tpu.parallel.moe import grouped
+
+                tile = grouped.row_tile(4 * cfg.moe_top_k, 4)
+                moe = {"routed": 2 * 2, "computed": 2 * 2 * tile, "hot": 2, "calls": 2}
+                assert prefill.moe["routed"] == 20 * 2 * 2
+            assert engine.last_step == StepStats(4, 1, 0, 2, 4 * 8, moe)
         elif entry == "decode_round":
             assert len(engine.decode_round(3)[0]) == 3
             # a round's 3 kernel calls a layer walk the round-start window

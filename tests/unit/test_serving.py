@@ -450,3 +450,80 @@ class TestServingRealEngine:
             assert len(r.generated) == 6
             assert all(0 <= t < cfg.vocab_size for t in r.generated)
         assert engine.state_manager.free_blocks == 64
+
+    @pytest.mark.parametrize("arrivals", ["alone", "overlapping"])
+    def test_serve_entry_point_builds_every_split_shape_first(self, arrivals):
+        """The stack as ``dstpu serve`` builds it has all three shapes of the
+        split step (decode-only, the 128 bucket, ``prompt_chunk``) before the
+        first request is admitted: prompts whose chunks and tails land in
+        every bucket, one at a time or all at once, trace nothing."""
+        import jax
+
+        from deepspeed_tpu.inference.cli import build_serving_stack, serve_parse_args
+        from deepspeed_tpu.models import get_config, init_params
+        from deepspeed_tpu.serving.elastic import assert_no_new_traces
+
+        cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=2048)
+        args = serve_parse_args([
+            "--model", "", "--port", "0", "--dtype", "float32", "--block-size", "16",
+            "--num-blocks", "256", "--max-blocks-per-seq", "96", "--max-context", "1536",
+            "--max-concurrent", "4",
+        ])
+        driver, _ = build_serving_stack(args, cfg=cfg, params=init_params(cfg, jax.random.key(0)))
+        engine = driver.engine
+        pc = engine.scheduler.prompt_chunk
+        baseline = engine.trace_signature()
+        assert sorted(baseline) == sorted(f"split[{tq}]" for tq in (0, 128, pc)) and pc > 128
+        assert all(n == 1 for n in baseline.values())
+        rng = np.random.default_rng(0)
+        lengths = [1, 128, 129, pc, pc + 100, 2 * pc + 300]
+        with driver:
+            reqs = []
+            for n in lengths:
+                reqs.append(driver.submit(
+                    rng.integers(1, cfg.vocab_size, size=n, dtype=np.int32),
+                    params=SamplingParams(max_new_tokens=3, ignore_eos=True)))
+                if arrivals == "alone":
+                    assert reqs[-1].wait(300), f"a prompt of {n} tokens did not finish"
+            for n, r in zip(lengths, reqs):
+                assert r.wait(300), f"a prompt of {n} tokens did not finish"
+                assert r.state == RequestState.FINISHED and len(r.generated) == 3
+        assert_no_new_traces(engine, baseline, label=f"served {arrivals}")
+
+    def test_a_step_that_lands_no_token_is_waited_for_and_is_progress(self, tiny_model,
+                                                                      monkeypatch):
+        """A step of prompt chunks with more to come completes no row. The
+        engine waits for it all the same, on the pool it returns, and the core
+        reports it as progress: the serving loop neither runs ahead of the
+        device (a chunk step then costs the same with a decode row beside it
+        or none) nor takes it for a stall on KV blocks and polls between two
+        chunks of one prompt. A pass that schedules nothing is still none."""
+        from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
+        from deepspeed_tpu.inference.v2 import engine_v2
+
+        cfg, params = tiny_model
+        rc = RaggedInferenceEngineConfig.from_dict({
+            "dtype": "float32", "prompt_chunk": 32,
+            "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 8},
+            "state_manager": {"max_tracked_sequences": 8, "max_ragged_batch_size": 64,
+                              "max_ragged_sequence_count": 4, "max_context": 128},
+        })
+        engine = engine_v2.InferenceEngineV2(cfg, params, rc)
+        waits = []
+        real_wait = engine_v2.device_synchronize
+        monkeypatch.setattr(engine_v2, "device_synchronize",
+                            lambda tree=None: (waits.append(list(tree)), real_wait(tree))[1])
+        driver = ServingDriver(engine)  # never started: the test is its loop
+        req = driver.submit(np.arange(1, 81, dtype=np.int32),
+                            params=SamplingParams(max_new_tokens=2, ignore_eos=True))
+        with driver._cond:
+            driver._admit_locked()
+        for chunk in range(2):  # 32 + 32 of 80 tokens: more to come
+            assert driver._step_once(), f"chunk {chunk} of three is progress"
+            assert req.generated == [] and engine.last_step.prefill_tokens == 32
+            assert len(waits[-1]) == 1 and waits[-1][0] is engine._k_cache
+        assert driver._step_once() and len(req.generated) == 1  # the tail: first token
+        assert waits[-1] and all(w is not engine._k_cache for w in waits[-1])
+        assert driver._step_once() and req.state == RequestState.FINISHED
+        assert not driver._step_once(), "a pass that scheduled nothing is no progress"
+        assert engine.last_step.scheduled_tokens == 0

@@ -151,19 +151,19 @@ class TestServingSpans:
 class TestGridCounters:
     def test_counters_equal_the_hand_computed_grid_with_tracing_off(self, tiny_model):
         """Three steps: [chunk of 200 in the 512 bucket], [1 decode row + a
-        chunk of 20 in the 128 bucket], [2 decode rows and NO chunk: the 128
-        grid still runs]. R = 4, Rc = 1."""
+        chunk of 20 in the 128 bucket], [2 decode rows and NO chunk: the
+        decode-only shape, the R slots alone]. R = 4, Rc = 1."""
         assert get_tracer() is NULL_TRACER
         driver, reqs = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
         c = driver.metrics.counters
         assert c["engine_steps_total"] == 3
-        assert c["grid_slots_total"] == (4 + 512) + (4 + 128) + (4 + 128)
+        assert c["grid_slots_total"] == (4 + 512) + (4 + 128) + 4
         assert c["scheduled_tokens_total"] == 200 + (1 + 20) + 2
         assert c["prefill_tokens_total"] == 200 + 20
         assert c["steps_with_prefill_total"] == 2
         assert c["decode_tokens_total"] == 3 + 2
         text = driver.metrics.prometheus_text()
-        assert "grid_slots_total 780" in text and "steps_with_prefill_total 2" in text
+        assert "grid_slots_total 652" in text and "steps_with_prefill_total 2" in text
 
     def test_fused_round_counts_rows_times_steps(self, tiny_model):
         driver, _ = _serve(_engine(tiny_model, decode_steps=4), [(20, 9)], decode_steps=4)
