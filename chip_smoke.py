@@ -339,6 +339,30 @@ def kernel_cases(size):
                 paged("kernel"), (qq, kc, vc, tb, qpos, scales, extra, limit),
                 paged("dense"), 2e-2,
             ))
+
+    # the one-token Gated DeltaNet update on the state pool in place, at the
+    # Qwen3-Next geometry: 32 rows (28 live on scattered slots, the grid's
+    # padding on the spare slot with g = beta = 0), 16 key / 32 value heads of
+    # 128, a layer's 33 slots of [32, 128, 128] float32
+    from deepspeed_tpu.ops.linear_attention.gated_delta import gdn_decode, qk_heads
+
+    Rg, nkg, nvg, dg, NS = (4, 2, 4, 16, 6) if TINY else (32, 16, 32, 128, 33)
+    live = jnp.arange(Rg) < Rg - Rg // 8
+    slots = jnp.where(live, jnp.asarray(rs.permutation(NS - 1)[:Rg], jnp.int32), NS - 1)
+    qg, kg = qk_heads(rnd((Rg, nkg, dg), jnp.float32), rnd((Rg, nkg, dg), jnp.float32))
+    gates = jnp.where(live[:, None], -jnp.abs(rnd((Rg, nvg), jnp.float32, 0.1)), 0.0)
+    betas = jnp.where(live[:, None], jax.nn.sigmoid(rnd((Rg, nvg), jnp.float32)), 0.0)
+
+    def gdn(impl):
+        return lambda *a: gdn_decode(*a, impl=impl)
+
+    cases.append((
+        "gdn decode, the state pool in place",
+        gdn("interpret" if interp else "kernel"),
+        (qg, kg, rnd((Rg, nvg, dg), jnp.float32), gates, betas,
+         rnd((NS, nvg, dg, dg), jnp.float32, 0.1), slots),
+        gdn("jnp"), 1e-4,
+    ))
     return cases
 
 

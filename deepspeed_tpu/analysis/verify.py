@@ -252,7 +252,8 @@ def check_pool_copies(name: str, jitted, args: Sequence, pools: Sequence,
                       lowered=None) -> CheckResult:
     """Compile ``jitted(*args)`` and fail on every ``copy`` instruction whose
     result is as large as one of ``pools`` (arrays or shape structs: the KV
-    pools and, for int8, their scale planes) in that pool's dtype. Sizes are
+    pools, for int8 their scale planes, for a model with DeltaNet layers its
+    recurrent-state and conv pools) in that pool's dtype. Sizes are
     compared by element count, so a copy of a reshaped view counts too. A
     serving step moves the tokens of one step; a copy the size of the pool
     is XLA resolving a buffer that is read as an invariant and written as a
@@ -310,7 +311,7 @@ def _capture_builder(obj, attr: str, store: dict, key: str):
 
 
 def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
-                    kv_extra: Optional[dict] = None):
+                    kv_extra: Optional[dict] = None, model: str = "dense"):
     import jax
 
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
@@ -318,6 +319,13 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
     from deepspeed_tpu.models import get_config, init_params
 
     cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
+    if model == "gdn":
+        # the second kind of cache: two periods of one Gated DeltaNet layer
+        # and one attention layer, so that the loop over periods is a loop
+        cfg = get_config(
+            "tiny", n_layers=4, dtype="float32", max_seq_len=512,
+            layer_kinds=("gdn", "full") * 2, gdn_key_heads=2, gdn_value_heads=4,
+            gdn_key_dim=16, gdn_value_dim=16)
     params = init_params(cfg, jax.random.key(0))
     kv = {"block_size": 4, "num_blocks": 128, "max_blocks_per_seq": 32,
           "kv_cache_dtype": kv_dtype}
@@ -333,7 +341,7 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
     return cfg, InferenceEngineV2(cfg, params, rc)
 
 
-def _engine_v2_programs(kv_dtype: str):
+def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     """The v2 serving programs of a tiny engine with a ``kv_dtype`` pool:
     (engine, {name: (jitted, args)}). The split step (at a chunk bucket) and
     the fused decode round are captured from two same-shape ``generate()``
@@ -342,11 +350,13 @@ def _engine_v2_programs(kv_dtype: str):
     inputs of an empty step (lowering reads shapes only, so passing the live
     pools is safe). Every program takes ``(params, inputs, rng, temperature,
     pools)`` and donates ``pools`` whole: int8 adds the scale planes as two
-    more leaves of it."""
+    more leaves of it, ``model="gdn"`` (a model with Gated DeltaNet layers)
+    the recurrent-state and conv pools; such a model has no verify step (the
+    engine refuses it)."""
     import jax.numpy as jnp
     import numpy as np
 
-    cfg, eng = _tiny_v2_engine(kv_dtype=kv_dtype)
+    cfg, eng = _tiny_v2_engine(kv_dtype=kv_dtype, model=model)
     programs: dict = {}
     _capture_builder(eng, "_build_split_step", programs, "split_step")
     _capture_builder(eng, "_build_multistep_decode", programs, "multistep_decode")
@@ -374,20 +384,23 @@ def _engine_v2_programs(kv_dtype: str):
     # program declares the pools donated — without aliasing, every spec
     # round would copy the whole paged pool, erasing the subsystem's win.
     # Its inputs are what the engine stages for a round with no row.
-    _, inputs = eng._stage_verify([], [], 4)
-    programs["verify_step"] = staged(eng._build_verify_step(4), inputs)
+    if not eng._hybrid:
+        _, inputs = eng._stage_verify([], [], 4)
+        programs["verify_step"] = staged(eng._build_verify_step(4), inputs)
     return eng, programs
 
 
-def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
+def _engine_v2_pass(kv_dtype: str, model: str = "dense") -> List[CheckResult]:
     """One donation / pool-copy / recompile sweep over the v2 serving
     programs for a pool payload dtype. int8 mode adds the fp32 scale planes
     to the donated pools argument of every step, so both dtypes get the
     full sweep."""
-    tag = "" if kv_dtype == "bf16" else f"[{kv_dtype}]"
+    tag = "".join(f"[{t}]" for t in (kv_dtype, model) if t not in ("bf16", "dense"))
     results: List[CheckResult] = []
-    eng, programs = _engine_v2_programs(kv_dtype)
+    eng, programs = _engine_v2_programs(kv_dtype, model)
     for key in ("split_step", "decode_only_step", "multistep_decode", "verify_step"):
+        if key == "verify_step" and eng._hybrid:
+            continue  # refused at build: a rejected draft would need the state rolled back
         label = f"engine_v2.{key}{tag}"
         if key not in programs:
             results.append(CheckResult(label, "donation", False,
@@ -405,7 +418,8 @@ def _engine_v2_pass(kv_dtype: str) -> List[CheckResult]:
 def verify_engine_v2() -> List[CheckResult]:
     # both pool payload dtypes: int8 adds donated scale-plane leaves to
     # every serving program (split, multistep, verify)
-    return _engine_v2_pass("bf16") + _engine_v2_pass("int8")
+    # ... and a model with DeltaNet layers the state pools
+    return _engine_v2_pass("bf16") + _engine_v2_pass("int8") + _engine_v2_pass("bf16", "gdn")
 
 
 def verify_streamed_adam() -> List[CheckResult]:

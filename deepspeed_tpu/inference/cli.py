@@ -351,11 +351,14 @@ def engine_config_from_args(args, cfg):
         # size the pool from a byte budget: under int8 the same budget
         # holds ~2x blocks (kv_pool.bytes_per_block) — this is where the
         # capacity multiplier reaches admission
-        from deepspeed_tpu.inference.v2.kv_pool import blocks_for_budget
+        # (a DeltaNet model's state slots, one a tracked sequence and a
+        # spare, come out of the same budget first)
+        from deepspeed_tpu.inference.v2.kv_pool import blocks_for_budget, state_slot_bytes
 
         num_blocks = blocks_for_budget(
             int(args.kv_pool_bytes), args.block_size, cfg.kv_heads,
-            cfg.head_dim, cfg.n_layers, kv_dtype,
+            cfg.head_dim, cfg.kv_layers, kv_dtype,
+            state_bytes=(args.max_concurrent + 1) * state_slot_bytes(cfg),
         )
     return RaggedInferenceEngineConfig.from_dict({
         "dtype": args.dtype, "tp_size": args.tp,

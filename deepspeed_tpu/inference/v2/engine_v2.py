@@ -5,7 +5,11 @@ call advances every scheduled sequence by its packed tokens against the
 blocked KV cache and returns next-token logits per sequence.
 
 TPU adaptation:
-  * the paged KV cache is [L, num_blocks, block_size, n_kv, d] per k/v;
+  * the paged KV cache is [L, num_blocks, block_size, n_kv, d] per k/v, L the
+    layers that attend over keys and values (``kv_layers``); a model with
+    Gated DeltaNet layers (``layer_kinds``) has a second kind of cache beside
+    it, one fixed-size slot a tracked sequence: the layers' recurrent states
+    [Lg * slots, nv, dk, dv] float32 and conv inputs [Lg * slots, (K - 1) * C];
   * paged attention = block-table gather → dense attention with a length
     mask, or the Pallas paged kernel underneath (``paged_attention``);
   * a step is one compiled program over a fixed grid (the SplitFuse
@@ -119,7 +123,8 @@ class StepStats:
     """What one step or round was sized to and what it carried, filled where
     the step is staged (``moe`` after its wait). The serving core folds it
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
-    paged_live_blocks_total / paged_table_slots_total and moe_*_total."""
+    paged_live_blocks_total / paged_table_slots_total, moe_*_total and
+    gdn_*_total."""
 
     grid_slots: int = 0
     scheduled_tokens: int = 0
@@ -128,9 +133,13 @@ class StepStats:
     # slots of their tables, summed over one layer's calls (_count_paged)
     paged_live_blocks: int = 0
     paged_table_slots: int = 0
-    # expert models: {"routed", "computed", "hot", "calls"} (rows and layer
-    # calls) of the step's expert layers (_count_moe); None for a dense model
+    # expert models: {"routed", "computed", "hot", "calls", "hit"} (rows,
+    # layer calls, experts with a row) of the step's expert layers
+    # (_count_moe); None for a dense model
     moe: Optional[dict] = None
+    # DeltaNet layers: rows whose state took the one-token update, of ONE
+    # layer (every such layer sees the same); 0 for a model without them
+    gdn_decode_rows: int = 0
 
 
 class InferenceEngineV2:
@@ -155,6 +164,9 @@ class InferenceEngineV2:
         )
         quantized = bool(getattr(self.config, "quant", None) and self.config.quant.enabled)
         tp = int(getattr(self.config, "tp_size", 1) or 1)
+        self._hybrid = model_config.hybrid
+        if self._hybrid:
+            self._refuse_at_build(model_config, quantized, tp)
         if model_config.n_experts > 0 and (quantized or tp > 1):
             # the expert layer's grouped matmul reads whole bf16 expert
             # weights on one device; neither variant has a test
@@ -178,7 +190,12 @@ class InferenceEngineV2:
             )
         self.params = params
         kv = self.config.kv_cache
-        self.state_manager = DSStateManager(self.config.state_manager, kv)
+        # a DeltaNet model: one state slot a tracked sequence, and one spare
+        # that the padding of a step's grid points at
+        self._state_slots = (
+            self.config.state_manager.max_tracked_sequences + 1 if self._hybrid else 0)
+        self.state_manager = DSStateManager(
+            self.config.state_manager, kv, state_slots=max(0, self._state_slots - 1))
         self.scheduler = RaggedScheduler(
             self.config.state_manager,
             self.state_manager,
@@ -299,7 +316,7 @@ class InferenceEngineV2:
         # +1 trash block: padded tail tokens of bucketed chunks scatter there
         # instead of corrupting block 0 (which belongs to a live sequence)
         pool_dtype = jnp.int8 if self._kv_int8 else dtype
-        shape = (c.n_layers, kv.num_blocks + 1, kv.block_size, c.kv_heads, c.head_dim)
+        shape = (c.kv_layers, kv.num_blocks + 1, kv.block_size, c.kv_heads, c.head_dim)
         sshape = shape[:-1]  # fp32 scale planes: one scalar per head vector
         self._ks_cache = self._vs_cache = None
         if self._tp > 1:
@@ -319,6 +336,20 @@ class InferenceEngineV2:
             if self._kv_int8:
                 self._ks_cache = jnp.zeros(sshape, jnp.float32)
                 self._vs_cache = jnp.zeros(sshape, jnp.float32)
+        # the second kind of cache, flat over the DeltaNet layers so that the
+        # decode kernel indexes [layer's ordinal * slots + slot] in place:
+        # recurrent states float32 (as transformers keeps them) and the conv's
+        # last K - 1 inputs in the compute dtype
+        self._gdn_state = self._gdn_conv = None
+        self._gdn_impl = None  # gdn_decode's pick by platform; tests name one
+        self._ordinals = np.asarray(T.kind_ordinals(c), np.int32)
+        if self._hybrid:
+            n = c.kind_count("gdn") * self._state_slots
+            self._gdn_state = jnp.zeros(
+                (n, c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim), jnp.float32)
+            # (a slot's K - 1 inputs flat in one row: a [.., 3, C] pool takes a
+            # padded tiling and a layout copy a step on either side of the loop)
+            self._gdn_conv = jnp.zeros((n, (c.gdn_conv_kernel - 1) * c.gdn_conv_dim), dtype)
         self._programs = {}  # (kind, shape) -> compiled step program (_launch)
         self._kv_scatter_jit = None  # handoff import: donated pool scatter
         # chunked re-import: ONE fixed window shape (tail padded into the
@@ -368,9 +399,46 @@ class InferenceEngineV2:
             + (", comm_quant=int8" if self._tp_quant else "")
             + (f", comm_overlap=tiled({self._overlap_tiles})" if self._tp_tiled else "")
             + (", prefix_cache=on" if self.state_manager.prefix_cache is not None else "")
-            + (f", host_tier={htb}B" if self._host_tier is not None else ""),
+            + (f", host_tier={htb}B" if self._host_tier is not None else "")
+            + (f", state_slots={self._state_slots}" if self._hybrid else ""),
             ranks=[0],
         )
+
+    def _refuse_at_build(self, c, quantized: bool, tp: int) -> None:
+        """A model with recurrent-state layers: what cannot carry the state
+        yet raises here or is switched off with one log line, and never
+        drops it. A cache hit, a spilled block, an exported block or a
+        rejected draft names K/V blocks alone; the state a sequence's
+        DeltaNet layers hold after the same tokens would be lost or stale."""
+        kv = self.config.kv_cache
+        what = None
+        if quantized or tp > 1:
+            what = "quantized weights" if quantized else f"tp_size={tp}"
+        elif str(getattr(kv, "kv_cache_dtype", "bf16") or "bf16") != "bf16":
+            what = f"kv_cache_dtype={kv.kv_cache_dtype!r}"
+        elif int(getattr(kv, "host_tier_bytes", 0) or 0) > 0:
+            what = "a host block tier (host_tier_bytes)"
+        elif int(getattr(self.config, "spec_k", 0) or 0) > 0:
+            what = "speculative decoding (spec_k): a rejected draft would need the state rolled back"
+        if what is not None:
+            raise NotImplementedError(
+                f"v2 paged engine: a model with Gated DeltaNet layers and {what} "
+                "is not supported: the recurrent-state pool has no such form yet")
+        if getattr(kv, "prefix_cache", False):
+            log_dist(
+                "InferenceEngineV2: prefix cache switched off: a hit shares K/V blocks "
+                "and would skip the DeltaNet layers' recurrent state for those tokens",
+                ranks=[0])
+            self.config.kv_cache = dataclasses.replace(kv, prefix_cache=False)
+
+    def _refuse_state_loss(self, what: str) -> None:
+        """Raise where an operation moves or rolls back a sequence's cache by
+        K/V blocks alone (handoff, recovery, host tier, speculative verify):
+        with DeltaNet layers the recurrent state would be dropped."""
+        if self._hybrid:
+            raise NotImplementedError(
+                f"{what}: this model's Gated DeltaNet layers keep a recurrent state "
+                "beside the K/V blocks, and nothing moves or snapshots it yet")
 
     @property
     def prefix_cache(self):
@@ -423,15 +491,27 @@ class InferenceEngineV2:
     def kv_pool_info(self) -> Dict:
         """Byte-accounting snapshot for health()/metrics: pool bytes,
         bytes/block, dtype, capacity multiplier vs bf16 (kv_pool.describe),
-        plus the resolved attention impl."""
+        plus the resolved attention impl, and for a model with DeltaNet
+        layers the state slots and their bytes."""
         from deepspeed_tpu.inference.v2.kv_pool import describe
 
         c, kv = self._mc, self.config.kv_cache
         info = describe(
             kv.num_blocks, kv.block_size, c.kv_heads, c.head_dim,
-            c.n_layers, self._kv_dtype,
+            c.kv_layers, self._kv_dtype,
         )
         info["paged_attention_impl"] = self._attn_impl
+        if self._hybrid:
+            # the second kind of cache: one slot a tracked sequence + a spare
+            from deepspeed_tpu.inference.v2.kv_pool import state_slot_bytes
+
+            per_slot = state_slot_bytes(c, self._gdn_conv.dtype.itemsize)
+            info.update(
+                state_slots=self._state_slots,
+                state_slots_in_use=self.state_manager.state_slot_accounting()["live"],
+                state_bytes_per_slot=per_slot,
+                state_pool_bytes=self._state_slots * per_slot,
+            )
         return info
 
     # -- cross-engine KV-block handoff (disaggregated prefill/decode) ------
@@ -440,6 +520,7 @@ class InferenceEngineV2:
         plane name. The payload is the unit of prefill→decode handoff: it
         carries the quantized int8 codes + fp32 scale planes verbatim when
         the pool is int8, so a re-import is bitwise (no requantization)."""
+        self._refuse_state_loss("export_kv_blocks")
         idx = jnp.asarray(np.asarray(list(block_ids), np.int32))
         out = {
             "k": np.asarray(self._k_cache[:, idx]),
@@ -482,6 +563,7 @@ class InferenceEngineV2:
         exporter's). Donated functional update: the pool array is consumed
         and reassigned, same discipline as the step programs' KV carry, so
         callers must serialize this against stepping (router step_lock)."""
+        self._refuse_state_loss("import_kv_blocks")
         n = len(block_ids)
         if n == 0:
             return
@@ -519,6 +601,7 @@ class InferenceEngineV2:
         plane family — zero steady-state recompiles (Tier-B
         ``verify_host_tier`` pins this). Same locking contract as
         ``import_kv_blocks``."""
+        self._refuse_state_loss("import_kv_blocks_chunked")
         n = len(block_ids)
         if n == 0:
             return
@@ -578,6 +661,7 @@ class InferenceEngineV2:
         payload is still in flight to the importer. Shape varies with the
         block count — the fixed-window pipelined path below is the one
         steady-state handoffs ride."""
+        self._refuse_state_loss("export_kv_blocks_device")
         idx = jnp.asarray(np.asarray(list(block_ids), np.int32))
         return {name: pool[:, idx]
                 for name, pool in self._kv_pool_planes().items()}
@@ -593,6 +677,7 @@ class InferenceEngineV2:
         asynchronously up front: the importer can scatter (and the
         decode replica can start its first round on the trie-covered
         prefix) while the tail windows are still materializing."""
+        self._refuse_state_loss("export_kv_blocks_windows")
         kv = self.config.kv_cache
         chunk = int(chunk_blocks) or int(
             getattr(kv, "host_tier_chunk_blocks", 8) or 8)
@@ -628,6 +713,7 @@ class InferenceEngineV2:
         donated scatter, which is the per-shard import the TP>1 decode
         placement rides. Same locking contract as ``import_kv_blocks``;
         returns the number of block columns actually scattered."""
+        self._refuse_state_loss("import_kv_blocks_device")
         n = len(block_ids)
         chunk = int(chunk_blocks)
         if n == 0 or not windows:
@@ -882,7 +968,7 @@ class InferenceEngineV2:
         kv = self.config.kv_cache
         chunk = int(getattr(kv, "host_tier_chunk_blocks", 8) or 8)
         n = min(chunk + 1, int(kv.num_blocks))
-        if n > chunk:
+        if n > chunk and not self._hybrid:  # no K/V mover serves a state pool
             blocks = list(range(n))
             self.import_kv_blocks_chunked(
                 blocks, self.export_kv_blocks(blocks), chunk_blocks=chunk
@@ -948,7 +1034,7 @@ class InferenceEngineV2:
         (_scatter_kv)."""
         c = self._mc
         kv = self.config.kv_cache
-        L, NBp = c.n_layers, kv.num_blocks + 1
+        L, NBp = c.kv_layers, kv.num_blocks + 1
         shape = (L * NBp, kv.block_size, c.kv_heads, c.head_dim)
         return k_cache.reshape(shape), v_cache.reshape(shape)
 
@@ -961,16 +1047,31 @@ class InferenceEngineV2:
         ks_cache, vs_cache = scales
         c = self._mc
         kv = self.config.kv_cache
-        L, NBp = c.n_layers, kv.num_blocks + 1
+        L, NBp = c.kv_layers, kv.num_blocks + 1
         shape = (L * NBp, kv.block_size, c.kv_heads)
         return ks_cache.reshape(shape), vs_cache.reshape(shape)
 
     def _pools(self):
         """The pools as the step programs take them and give them back: ONE
-        argument, ``(k, v)`` or with an int8 pool ``(k, v, ks, vs)``, donated
-        whole — whatever else a program takes, and whichever dtype the pool
-        holds, every leaf of it is updated in place."""
-        return tuple(self._kv_pool_planes().values())
+        argument, ``(k, v)``, with an int8 pool ``(k, v, ks, vs)``, with
+        DeltaNet layers ``(k, v, states, conv inputs)``, donated whole —
+        whatever else a program takes, and whichever kinds of cache the model
+        has, every leaf of it is updated in place."""
+        state = (self._gdn_state, self._gdn_conv) if self._hybrid else ()
+        return tuple(self._kv_pool_planes().values()) + state
+
+    def _split_pools(self, pools):
+        """(the K/V planes, the state pools or ()) of a program's ``pools``."""
+        n = len(pools) - (2 if self._hybrid else 0)
+        return pools[:n], pools[n:]
+
+    def _ordinal(self, li):
+        """Layer ``li``'s ordinal among the layers of its kind (its index in
+        its kind's parameter stack and cache pool): ``li`` itself where every
+        layer is alike, else read off a constant table, traced or not."""
+        if not self._hybrid:
+            return li
+        return int(self._ordinals[li]) if isinstance(li, int) else jnp.asarray(self._ordinals)[li]
 
     def _embed(self, params, tokens, positions):
         """The one prologue of a step program: ``tokens`` [t] at
@@ -1049,14 +1150,18 @@ class InferenceEngineV2:
                 calls * len(held) * kv.max_blocks_per_seq)
 
     def _side_buffers(self, *token_dims):
-        """A zeroed (k, v) pair [L, *token_dims, nkv, d] in compute dtype:
-        what a step's layer loop carries in place of the pools. Each layer
-        records its new K/V at its own index (_record_kv) and _scatter_kv
-        writes all of them back after the loop. Sized by the tokens of one
-        step, not by the pool; at tp>1 sharded on the kv-head dim like the
-        pool, so the write-back moves nothing across devices."""
+        """What a step's layer loop carries, by name: ``k`` / ``v``, a zeroed
+        pair [L, *token_dims, nkv, d] in compute dtype in place of the K/V
+        pools (L the layers that attend). Each such layer records its new
+        K/V at its ordinal (_record_kv) and _scatter_kv writes all of them
+        back after the loop. Sized by the tokens of one step, not by the
+        pool; at tp>1 sharded on the kv-head dim like the pool, so the
+        write-back moves nothing across devices. ``moe``: an expert model's
+        routed rows. A DeltaNet model's state pools ride the same carry
+        (``_with_state``): they are never a loop's invariant, every read and
+        the in-place update of a layer go through the carried value."""
         c = self._mc
-        shape = (c.n_layers,) + tuple(token_dims) + (c.kv_heads, c.head_dim)
+        shape = (c.kv_layers,) + tuple(token_dims) + (c.kv_heads, c.head_dim)
         side = jnp.zeros(shape, T.DTYPES[c.dtype])
         if self._mesh is not None:
             from jax.sharding import NamedSharding
@@ -1066,35 +1171,50 @@ class InferenceEngineV2:
 
             spec = P(*([None] * (len(shape) - 2)), MODEL_AXIS, None)
             side = jax.lax.with_sharding_constraint(side, NamedSharding(self._mesh, spec))
+        carry = {"k": side, "v": side}
         if c.n_experts > 0:
             # an expert model's loop also carries what each layer routed:
             # [L, E] rows an expert, returned with the step's tokens
-            return side, side, jnp.zeros((c.n_layers, c.n_experts), jnp.int32)
-        return side, side
+            carry["moe"] = jnp.zeros((c.n_layers, c.n_experts), jnp.int32)
+        return carry
+
+    @staticmethod
+    def _with_state(carry, state_pools):
+        """The carry with a DeltaNet model's (states, conv inputs) in it."""
+        if state_pools:
+            carry = dict(carry, gdn_state=state_pools[0], gdn_conv=state_pools[1])
+        return carry
+
+    @staticmethod
+    def _state_of(carry):
+        """The state pools a layer loop gives back: the tail of ``_pools()``."""
+        return (carry["gdn_state"], carry["gdn_conv"]) if "gdn_state" in carry else ()
 
     @staticmethod
     def _record_moe(carry, li, moe_counts):
-        """Layer ``li``'s rows routed to each expert into the carry's third
-        part (expert models; a dense layer's None leaves the pair as it is)."""
+        """Layer ``li``'s rows routed to each expert into the carry's ``moe``
+        (expert models; a dense layer's None leaves the carry as it is)."""
         if moe_counts is None:
             return carry
-        return carry[:2] + (jax.lax.dynamic_update_index_in_dim(carry[2], moe_counts, li, 0),)
+        return dict(carry, moe=jax.lax.dynamic_update_index_in_dim(carry["moe"], moe_counts, li, 0))
 
     @staticmethod
     def _moe_rows(carry):
-        """An expert model's routed rows, the third part of a layer loop's
-        carry, as a step program returns them; None for a dense model."""
-        return carry[2] if len(carry) > 2 else None
+        """An expert model's routed rows as a step program returns them;
+        None for a dense model."""
+        return carry.get("moe")
 
-    @classmethod
-    def _record_kv(cls, carry, li, k, v, moe_counts=None):
-        """Layer ``li``'s new K/V [..., nkv, d] into the side buffers, and
-        what it routed beside them (_record_moe)."""
-        side = (
-            jax.lax.dynamic_update_index_in_dim(carry[0], k, li, 0),
-            jax.lax.dynamic_update_index_in_dim(carry[1], v, li, 0),
+    def _record_kv(self, carry, li, k, v, moe_counts=None):
+        """Layer ``li``'s new K/V [..., nkv, d] into the side buffers at the
+        layer's ordinal among those that attend, and what it routed beside
+        them (_record_moe)."""
+        fi = self._ordinal(li)
+        carry = dict(
+            carry,
+            k=jax.lax.dynamic_update_index_in_dim(carry["k"], k, fi, 0),
+            v=jax.lax.dynamic_update_index_in_dim(carry["v"], v, fi, 0),
         )
-        return cls._record_moe(side + carry[2:], li, moe_counts)
+        return self._record_moe(carry, li, moe_counts)
 
     def _scatter_kv(self, caches, blk, row, side):
         """THE pool write of a serving step: after the layer loop, ONE
@@ -1111,19 +1231,19 @@ class InferenceEngineV2:
 
         ``blk``/``row``: [n] block and row of each of the step's n token
         slots, the same for every layer (padded slots name the trash
-        block); ``side``: (k, v) [L, n..., nkv, d]. int8 pools quantize
+        block); ``side``: the carry, its ``k`` / ``v`` [L, n..., nkv, d]. int8 pools quantize
         here (block_quant.quantize_kv, per head vector: the granularity
         that needs no read-modify-write of neighbor slots) and the fp32
         scales scatter through the same slot ids. Returns the pools in the
         order given."""
         c = self._mc
         kv = self.config.kv_cache
-        L, NBp, bs = c.n_layers, kv.num_blocks + 1, kv.block_size
+        L, NBp, bs = c.kv_layers, kv.num_blocks + 1, kv.block_size
         nkv, d = c.kv_heads, c.head_dim
         n = blk.shape[0]
         li = jnp.arange(L, dtype=jnp.int32)[:, None]
         slot = ((li * NBp + blk[None]) * bs + row[None]).reshape(L * n)
-        new = [a.reshape(L * n, nkv, d) for a in side[:2]]
+        new = [a.reshape(L * n, nkv, d) for a in (side["k"], side["v"])]
         if len(caches) == 4:
             from deepspeed_tpu.ops.quantizer.block_quant import quantize_kv
 
@@ -1150,21 +1270,53 @@ class InferenceEngineV2:
 
     def _drive_layers(self, layer_fn, params, x, carry):
         """Run ``layer_fn(lp, x, li, carry, window=...) -> (x, carry)`` over
-        the stack. Uniform windows: lax.fori_loop with a traced layer index.
-        Per-layer windows (true alternating patterns): unrolled Python loop
-        with static indices. ``carry`` holds the step's side buffers and
-        never a pool: the pools are invariants of either loop."""
+        the stack, ``li`` the layer's index in it. Three shapes of one loop:
+        uniform layers run a lax.fori_loop with a traced layer index;
+        per-layer windows (true alternating patterns) an unrolled Python loop
+        with static indices; layers of two KINDS (``layer_kinds``) a
+        fori_loop over the PERIODS of the pattern with one period's layers
+        unrolled in its body, so there is one traced body a kind whatever the
+        depth (a stack of a single period is that body alone). A layer's
+        parameters are what every layer has at ``li`` and its kind's stack
+        at the layer's ordinal among its kind (``T.kind_ordinals``); the
+        body knows its kind from the keys it is handed. ``carry`` holds the
+        step's side buffers, an expert model's routed rows and a DeltaNet
+        model's state pools, and never a K/V pool: those are invariants of
+        every loop."""
+        c = self._mc
         windows = self._layer_windows()
-        L = self._mc.n_layers
+        L = c.n_layers
         # an expert model's per-expert weights stay whole: the grouped kernel
-        # indexes its layer's blocks itself (_layer_tail passes ``li`` on); a
+        # indexes its layer's blocks itself (_mlp_tail passes ``li`` on); a
         # slice in front of a custom call would be copied, every layer
         whole = {}
-        if self._mc.n_experts > 0:
+        if c.n_experts > 0:
             from deepspeed_tpu.parallel.moe.sharded_moe import EXPERT_STACKS
 
             whole = {k: v for k, v in params["layers"].items() if k in EXPERT_STACKS}
         sliced = {k: v for k, v in params["layers"].items() if k not in whole}
+
+        def traced(a, i):
+            return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+
+        if self._hybrid:
+            period, n = T.layer_period(c)
+            P = len(period)
+            ords = T.kind_ordinals(c)[:P]
+            common = {k: v for k, v in sliced.items() if k not in ("full", "gdn")}
+
+            def run_period(pi, x, carry, take):
+                for j, kind in enumerate(period):
+                    li, ki = pi * P + j, pi * period.count(kind) + ords[j]
+                    lp = {**jax.tree.map(lambda a: take(a, li), common),
+                          **jax.tree.map(lambda a: take(a, ki), sliced[kind]), **whole}
+                    x, carry = layer_fn(lp, x, li, carry, window=windows)
+                return x, carry
+
+            if n == 1:
+                return run_period(0, x, carry, lambda a, i: a[i])
+            return jax.lax.fori_loop(
+                0, n, lambda pi, st: run_period(pi, *st, traced), (x, carry))
 
         def layer_params(take):
             return {**jax.tree.map(take, sliced), **whole}
@@ -1172,9 +1324,7 @@ class InferenceEngineV2:
         if not isinstance(windows, list):
             def body(li, st):
                 x, carry = st
-                lp = layer_params(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False))
-                return layer_fn(lp, x, li, carry, window=windows)
+                return layer_fn(layer_params(lambda a: traced(a, li)), x, li, carry, window=windows)
 
             x, carry = jax.lax.fori_loop(0, L, body, (x, carry))
             return x, carry
@@ -1273,26 +1423,34 @@ class InferenceEngineV2:
             out = out + lp["w_down_b"]
         return out
 
-    def _layer_tail(self, lp, x, out, live, li):
-        """Shared per-layer epilogue: wo projection (+ bias), then the
-        parallel-block (falcon/phi) or sequential residual + MLP. With
-        comm_quant="int8" at tp>1, the two MODEL_AXIS reductions (behind
-        wo and w_down) run int8-inside-the-collective. ``live`` [t] bool:
-        the slots of the step's grid that hold a token; an expert layer
-        routes and counts those alone, and reads its weights at layer
-        ``li`` of the whole stacks (_drive_layers). Returns (x, rows routed to each
-        expert [E] int32, or None for a dense MLP)."""
+    def _layer_tail(self, lp, x, out, live, li, a=None):
+        """Shared epilogue of an attention layer: the heads' output under its
+        gate where the model has one (``a``: the normed input the gate is
+        projected from), the wo projection (+ bias), then ``_mlp_tail``. With
+        comm_quant="int8" at tp>1, the MODEL_AXIS reduction behind wo runs
+        int8-inside-the-collective."""
         c = self._mc
         nh, d = c.n_heads, c.head_dim
         t = x.shape[1]
+        out = out.reshape(t, nh * d)
+        if c.attn_out_gate:
+            out = T.attn_gate(out, a[0] @ lp["wq_gate"])
         if self._tp_wire:
-            attn_out = self._tp_row_matmul(
-                out.reshape(t, nh * d), lp["wo"], "tp_attn_out"
-            )[None]
+            attn_out = self._tp_row_matmul(out, lp["wo"], "tp_attn_out")[None]
         else:
-            attn_out = (out.reshape(t, nh * d) @ lp["wo"])[None]
+            attn_out = (out @ lp["wo"])[None]
         if c.attn_out_bias:
             attn_out = attn_out + lp["wo_b"]
+        return self._mlp_tail(lp, x, attn_out, live, li)
+
+    def _mlp_tail(self, lp, x, attn_out, live, li):
+        """What follows a layer's token mixer, attention or DeltaNet alike:
+        the parallel-block (falcon/phi) or sequential residual + MLP.
+        ``live`` [t] bool: the slots of the step's grid that hold a token; an
+        expert layer routes and counts those alone, and reads its weights at
+        layer ``li`` of the whole stacks (_drive_layers). Returns (x, rows
+        routed to each expert [E] int32, or None for a dense MLP)."""
+        c = self._mc
         if not c.parallel_block:
             x = x + attn_out
         m = T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
@@ -1308,6 +1466,59 @@ class InferenceEngineV2:
         x = x + attn_out + mlp_out if c.parallel_block else x + mlp_out
         return x, counts
 
+    def _gdn_layer(self, lp, x, li, rows, carry):
+        """One Gated DeltaNet layer of a step, on the carried state pools.
+        ``rows`` describes the step's grid: its first ``R`` slots are decode
+        rows, one token each, at ``slots`` [R] with ``live`` [R]; a split
+        step with chunks has ``Rc`` rows of ``tq`` tokens behind them
+        (``chk_slots`` / ``chk_start`` [Rc], ``chk_pos`` [Rc, tq], -1 where
+        a slot holds no token). Decode rows take the one-token update IN the
+        pool (``gdn_decode``: on a TPU the kernel, one read and one write of
+        a row's state); chunk rows run the chunked rule from the slot's
+        state to the slot's state, a chunk at position 0 from zero whatever
+        the slot holds. A slot of the grid that is not live has ``g = beta =
+        0`` and takes no conv input, so state and conv state stay as they
+        were; padding points at the spare slot. Returns (x, carry, the
+        layer's routed rows)."""
+        from deepspeed_tpu.ops.linear_attention import causal_conv, gdn_chunked, gdn_decode
+
+        c = self._mc
+        R = rows["R"]
+        nv = c.gdn_value_heads
+        base = self._ordinal(li) * self._state_slots
+        state, conv = carry["gdn_state"], carry["gdn_conv"]
+        a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
+        qkv, z, g, beta = T.gdn_project(c, lp, a[0])
+
+        taps = (c.gdn_conv_kernel - 1, c.gdn_conv_dim)  # a slot's row of the conv pool
+        slots, live = base + rows["slots"], rows["live"]
+        y, new = causal_conv(qkv[:R, None], lp["gdn_conv"], conv[slots].reshape((R,) + taps),
+                             n=live.astype(jnp.int32))
+        conv = conv.at[slots].set(new.reshape(R, -1))
+        q, k, v = T.gdn_heads(c, y[:, 0])
+        o, state = gdn_decode(
+            q, k, v, jnp.where(live[:, None], g[:R], 0.0), jnp.where(live[:, None], beta[:R], 0.0),
+            state, slots, impl=self._gdn_impl)
+        if rows.get("tq"):
+            Rc, tq = rows["Rc"], rows["tq"]
+            slots = base + rows["chk_slots"]
+            tok_live = rows["chk_pos"] >= 0                              # [Rc, tq]
+            fresh = rows["chk_start"] == 0
+            conv0 = jnp.where(fresh[:, None, None], 0, conv[slots].reshape((Rc,) + taps))
+            y, new = causal_conv(qkv[R:].reshape(Rc, tq, -1), lp["gdn_conv"], conv0,
+                                 n=jnp.sum(tok_live, axis=1, dtype=jnp.int32))
+            conv = conv.at[slots].set(new.reshape(Rc, -1))
+            q, k, v = T.gdn_heads(c, y)
+            o_c, new = gdn_chunked(
+                q, k, v, jnp.where(tok_live[..., None], g[R:].reshape(Rc, tq, nv), 0.0),
+                jnp.where(tok_live[..., None], beta[R:].reshape(Rc, tq, nv), 0.0),
+                jnp.where(fresh[:, None, None, None], 0.0, state[slots]))
+            state = state.at[slots].set(new)
+            o = jnp.concatenate([o, o_c.reshape((Rc * tq,) + o_c.shape[2:])], axis=0)
+        out = T.gdn_output(c, lp, o, z, x.dtype)[None]
+        x, moe = self._mlp_tail(lp, x, out, rows["slot_live"], li)
+        return x, self._record_moe(dict(carry, gdn_state=state, gdn_conv=conv), li, moe)
+
     # ------------------------------------------------------------------
     def _split_layer(self, lp, x, li, meta, carry, window=None):
         """One transformer layer of the SPLIT-PHASE step: the packed token
@@ -1320,7 +1531,8 @@ class InferenceEngineV2:
         alone and the chunk half is absent, not empty: no chunk attention
         is traced. No read needs this step's K/V from the pool, so the
         layer only records them in ``carry`` (the side buffers) and the
-        pool is written once, after the loop."""
+        pool is written once, after the loop. A DeltaNet layer of the same
+        step (``layer_kinds``) goes through ``_gdn_layer`` instead."""
         c = self._mc
         kv = self.config.kv_cache
         NBp = kv.num_blocks + 1
@@ -1328,12 +1540,19 @@ class InferenceEngineV2:
         nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
         R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        _, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"])
+        if "gdn_qkv" in lp:  # a DeltaNet layer: no K/V, the state pools instead
+            return self._gdn_layer(lp, x, li, {
+                "R": R, "Rc": Rc, "tq": tq, "slots": meta.get("dec_slots"),
+                "live": meta["dec_pos"] >= 0, "slot_live": meta["slot_live"],
+                "chk_slots": meta.get("chk_slots"), "chk_start": meta.get("chk_start"),
+                "chk_pos": meta.get("chk_pos")}, carry)
+        a, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"])
         k_pool, v_pool = meta["k_pool0"], meta["v_pool0"]
         ks_pool, vs_pool = meta["ks_pool0"], meta["vs_pool0"]
+        li_kv = self._ordinal(li)  # the K/V pool holds the layers that attend
         out = self._attn_decode(
-            q[:R], k_pool, v_pool, li * NBp + meta["dec_tables"],
-            meta["dec_pos"], w, li * NBp + kv.num_blocks,
+            q[:R], k_pool, v_pool, li_kv * NBp + meta["dec_tables"],
+            meta["dec_pos"], w, li_kv * NBp + kv.num_blocks,
             extra_kv=(k[:R, None], v[:R, None], meta["dec_pos"][:, None]),
             pool_limit=meta["dec_pos"],
             k_scale=ks_pool, v_scale=vs_pool,
@@ -1343,15 +1562,15 @@ class InferenceEngineV2:
 
             out_c = paged_chunk_attention(
                 q[R:].reshape(Rc, tq, nh, d), k_pool, v_pool,
-                li * NBp + meta["chk_tables"], meta["chk_pos"],
-                li * NBp + kv.num_blocks,
+                li_kv * NBp + meta["chk_tables"], meta["chk_pos"],
+                li_kv * NBp + kv.num_blocks,
                 window=int(w), scale=c.attn_scale,
                 new_kv=(k[R:].reshape(Rc, tq, nkv, d), v[R:].reshape(Rc, tq, nkv, d)),
                 pool_limit=meta["chk_start"],
                 k_scale=ks_pool, v_scale=vs_pool,
             )
             out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, d)], axis=0)
-        x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li)
+        x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li, a)
         return x, self._record_kv(carry, li, k, v, moe)
 
     def _build_split_step(self, tq: int):
@@ -1373,10 +1592,13 @@ class InferenceEngineV2:
             tokens, positions = inputs["tokens"], inputs["positions"]
             dec_pos = inputs["dec_pos"]
             x = self._embed(params, tokens, positions)
+            pools, state_pools = self._split_pools(pools)
             k_pool0, v_pool0 = self._pool_views(*pools[:2])
             ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
             meta = {
                 "R": R, "Rc": Rc, "tq": tq, "positions": positions,
+                # DeltaNet models: the rows' state slots (None otherwise)
+                "dec_slots": inputs.get("dec_slots"), "chk_slots": inputs.get("chk_slots"),
                 # live length (HF max(position_ids)+1) for the rope-scaling
                 # switch: padded slots carry position 0, so the plain max works
                 "live": jnp.max(positions) + 1,
@@ -1399,9 +1621,9 @@ class InferenceEngineV2:
                 return self._split_layer(lp, x, li, meta, carry, window=window)
 
             x, side = self._drive_layers(
-                layer_fn, params, x, self._side_buffers(tokens.shape[0])
-            )
-            pools = self._scatter_kv(pools, inputs["blk"], inputs["row"], side)
+                layer_fn, params, x,
+                self._with_state(self._side_buffers(tokens.shape[0]), state_pools))
+            pools = self._scatter_kv(pools, inputs["blk"], inputs["row"], side) + self._state_of(side)
             # generate() holds only the token arrays across its prefill
             # phase and drops the logits
             logits_dec, toks_dec = self._sample_rows(
@@ -1422,32 +1644,37 @@ class InferenceEngineV2:
         carried side buffers [L, R, n, nkv, d]. The side buffers are the
         round's only read-write surface; the pool is written from them
         once, after the last step."""
-        side_k, side_v = carry[:2]
         c = self._mc
         kv = self.config.kv_cache
         NBp = kv.num_blocks + 1
         w = c.sliding_window if window is None else window
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        _, q, k, v = self._layer_qkv(lp, x, meta["pos"], meta["live"])
+        if "gdn_qkv" in lp:  # a DeltaNet layer: the carried state, updated in place
+            return self._gdn_layer(lp, x, li, {
+                "R": x.shape[1], "slots": meta["slots"], "live": meta["active"],
+                "slot_live": meta["active"]}, carry)
+        side_k, side_v = carry["k"], carry["v"]
+        a, q, k, v = self._layer_qkv(lp, x, meta["pos"], meta["live"])
+        li_kv = self._ordinal(li)
         # record this step's K/V in the side buffer BEFORE attention (the
         # query sees itself through the extra columns)
         side_k = jax.lax.dynamic_update_slice(
-            side_k, k[None, :, None], (li, 0, meta["s"], 0, 0)
+            side_k, k[None, :, None], (li_kv, 0, meta["s"], 0, 0)
         )
         side_v = jax.lax.dynamic_update_slice(
-            side_v, v[None, :, None], (li, 0, meta["s"], 0, 0)
+            side_v, v[None, :, None], (li_kv, 0, meta["s"], 0, 0)
         )
-        sk = jax.lax.dynamic_index_in_dim(side_k, li, 0, keepdims=False)
-        sv = jax.lax.dynamic_index_in_dim(side_v, li, 0, keepdims=False)
+        sk = jax.lax.dynamic_index_in_dim(side_k, li_kv, 0, keepdims=False)
+        sv = jax.lax.dynamic_index_in_dim(side_v, li_kv, 0, keepdims=False)
         out = self._attn_decode(
-            q, meta["k_pool0"], meta["v_pool0"], li * NBp + meta["tables"],
-            meta["pos"], w, li * NBp + kv.num_blocks,
+            q, meta["k_pool0"], meta["v_pool0"], li_kv * NBp + meta["tables"],
+            meta["pos"], w, li_kv * NBp + kv.num_blocks,
             extra_kv=(sk, sv, meta["epos"]),
             pool_limit=meta["pos0"],
             k_scale=meta["ks_pool0"], v_scale=meta["vs_pool0"],
         )
-        x, moe = self._layer_tail(lp, x, out, meta["active"], li)
-        return x, self._record_moe((side_k, side_v) + carry[2:], li, moe)
+        x, moe = self._layer_tail(lp, x, out, meta["active"], li, a)
+        return x, self._record_moe(dict(carry, k=side_k, v=side_v), li, moe)
 
     def _build_multistep_decode(self, n_steps: int):
         """``n_steps`` decode iterations in ONE device program, each token
@@ -1466,7 +1693,8 @@ class InferenceEngineV2:
         _round_layer), and one write-back after the last step puts all
         ``n_steps`` tokens of every layer into the donated pools. Outputs:
         (tokens [n_steps, R], logprobs [n_steps, R]); an expert model's
-        routed rows are [n_steps, L, E]."""
+        routed rows are [n_steps, L, E]. A DeltaNet model's recurrent state
+        needs no side buffer: it is the scan's carry."""
         kv = self.config.kv_cache
         bs = kv.block_size
         B = kv.max_blocks_per_seq
@@ -1479,7 +1707,10 @@ class InferenceEngineV2:
             pos0 = inputs["positions"]  # round-start positions (pool validity limit)
             j_idx = jnp.arange(n_steps, dtype=jnp.int32)
             # round-start pool views: read-only for the whole round (the
-            # in-round tokens come from the side buffers)
+            # in-round tokens come from the side buffers); a DeltaNet
+            # model's state pools ride the scan's carry, each step's
+            # update made in place
+            pools, state_pools = self._split_pools(pools)
             k_pool0, v_pool0 = self._pool_views(*pools[:2])
             ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
 
@@ -1492,6 +1723,7 @@ class InferenceEngineV2:
                 )
                 meta = {
                     "tables": tok_tables, "pos": pos, "active": active,
+                    "slots": inputs.get("slots"),
                     # inactive rows: pos0 == 0 -> pool masks to nothing
                     "pos0": jnp.where(active, pos0, 0),
                     "s": s, "epos": epos,
@@ -1519,7 +1751,7 @@ class InferenceEngineV2:
 
             (_, _, side), (toks_out, logps_out, moe) = jax.lax.scan(
                 step_fn,
-                (tokens, pos0, self._side_buffers(R, n_steps)),
+                (tokens, pos0, self._with_state(self._side_buffers(R, n_steps), state_pools)),
                 j_idx,
             )
             # the round's write-back: step s of row r sits at position
@@ -1528,7 +1760,7 @@ class InferenceEngineV2:
             blk = jnp.take_along_axis(tok_tables, jnp.clip(pos_all // bs, 0, B - 1), axis=1)
             pools = self._scatter_kv(
                 pools, blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps), side,
-            )
+            ) + self._state_of(side)
             return (toks_out, logps_out), pools, moe
 
         return jax.jit(fused, donate_argnums=(4,))
@@ -1560,6 +1792,7 @@ class InferenceEngineV2:
         donated; rejected drafts leave stale KV only at positions past the
         new write cursor (masked by position on every later read, and
         overwritten before they re-enter any pool window)."""
+        self._refuse_state_loss("the speculative verify step")
         c = self._mc
         kv = self.config.kv_cache
         bs = kv.block_size
@@ -1614,7 +1847,7 @@ class InferenceEngineV2:
             def layer_fn(lp, x, li, carry, window=None):
                 w = c.sliding_window if window is None else window
                 lp = T._dequant_tree(lp, dtype)
-                _, q, k_, v_ = self._layer_qkv(lp, x, flat_pos, live)
+                a, q, k_, v_ = self._layer_qkv(lp, x, flat_pos, live)
                 if use_kernel:
                     ke = jnp.broadcast_to(
                         k_.reshape(R, 1, K1, nkv, d), (R, K1, K1, nkv, d)
@@ -1638,7 +1871,7 @@ class InferenceEngineV2:
                         k_scale=ks_pool0, v_scale=vs_pool0,
                     ).reshape(R * K1, nh, d)
                 x, moe = self._layer_tail(
-                    lp, x, out.reshape(R * K1, nh, d), valid.reshape(R * K1), li)
+                    lp, x, out.reshape(R * K1, nh, d), valid.reshape(R * K1), li, a)
                 return x, self._record_kv(carry, li, k_, v_, moe)
 
             x, side = self._drive_layers(
@@ -1698,9 +1931,14 @@ class InferenceEngineV2:
         chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
         chk_last = np.zeros(Rc, np.int32)
         chk_uids = np.zeros(Rc, np.int32)
+        # DeltaNet models: each row's state slot; padding points at the spare
+        spare = self._state_slots - 1
+        dec_slots = np.full(R, spare, np.int32)
+        chk_slots = np.full(Rc, spare, np.int32)
 
         for i, (uid, toks, start) in enumerate(dec_rows):
             seq = self.state_manager.get_sequence(uid)
+            dec_slots[i] = seq.state_slot
             tokens[i] = toks[0]
             positions[i] = start
             nblk = len(seq.block_table)
@@ -1721,15 +1959,17 @@ class InferenceEngineV2:
             chk_pos[j, :n] = pos
             chk_start[j] = start
             chk_uids[j] = uid
+            chk_slots[j] = seq.state_slot
             # host-side scheduler metadata, not a device value
             blk[off : off + n] = np.asarray(seq.block_table, np.int32)[  # dstpu: noqa[host-sync-in-loop]
                 np.minimum(pos // bs, nblk - 1)
             ]
             row[off : off + n] = pos % bs
             chk_last[j] = off + n - 1
+        prefill = sum(len(t) for _, t, _, _ in chk_rows)
         self.last_step = StepStats(
-            T_, total_tokens, sum(len(t) for _, t, _, _ in chk_rows),
-            *self._count_paged(dec_pos),
+            T_, total_tokens, prefill, *self._count_paged(dec_pos),
+            gdn_decode_rows=len(dec_rows) if self._hybrid else 0,
         )
         inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,
@@ -1740,6 +1980,10 @@ class InferenceEngineV2:
                 chk_tables=chk_tables, chk_pos=chk_pos, chk_start=chk_start,
                 chk_last=chk_last, chk_uids=chk_uids,
             )
+        if self._hybrid:
+            inputs["dec_slots"] = dec_slots
+            if tq:
+                inputs["chk_slots"] = chk_slots
         return ("split", tq), inputs
 
     def _stage_rows(self, uids, width: int):
@@ -1755,8 +1999,12 @@ class InferenceEngineV2:
             "uids": np.zeros(R, np.int32),
             "active": np.zeros(R, bool),
         }
+        if self._hybrid:
+            inputs["slots"] = np.full(R, self._state_slots - 1, np.int32)
         for i, uid in enumerate(uids):
             seq = self.state_manager.get_sequence(uid)
+            if self._hybrid:
+                inputs["slots"][i] = seq.state_slot
             inputs["tokens"][i, 0] = self.scheduler.peek_next_token(uid)
             inputs["positions"][i] = seq.seen_tokens
             inputs["tables"][i, : len(seq.block_table)] = seq.block_table
@@ -1770,7 +2018,8 @@ class InferenceEngineV2:
         R, inputs = self._stage_rows(uids, 1)
         inputs["tokens"] = inputs["tokens"][:, 0]
         self.last_step = StepStats(
-            R * n, len(uids) * n, 0, *self._count_paged(inputs["positions"], calls=n))
+            R * n, len(uids) * n, 0, *self._count_paged(inputs["positions"], calls=n),
+            gdn_decode_rows=len(uids) * n if self._hybrid else 0)
         return ("round", n), inputs
 
     def _stage_verify(self, uids, row_drafts, k: int):
@@ -1805,9 +2054,12 @@ class InferenceEngineV2:
             jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
             self._pools(),
         )
+        pools, state = self._split_pools(pools)
         self._k_cache, self._v_cache = pools[:2]
         if self._kv_int8:
             self._ks_cache, self._vs_cache = pools[2:]
+        if state:
+            self._gdn_state, self._gdn_conv = state
         return outputs
 
     def _start(self, stage, *args):
@@ -1861,8 +2113,8 @@ class InferenceEngineV2:
         round's [n_steps, L, E]) to ``last_step.moe``. One layer call a row
         of E: rows routed, rows the dispatch computed (the grouped kernel:
         its tile size for every tile visit; the capacity dispatch: E x
-        capacity), the fullest expert's rows. None stays for a dense model
-        or a step that launched nothing."""
+        capacity), the fullest expert's rows and the experts that had a row.
+        None stays for a dense model or a step that launched nothing."""
         pending, self._moe_pending = self._moe_pending, None
         if pending is None:
             return
@@ -1883,6 +2135,7 @@ class InferenceEngineV2:
         self.last_step.moe = {
             "routed": int(counts.sum()), "computed": int(computed),
             "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
+            "hit": int((counts > 0).sum()),
         }
 
     # -- entry points --------------------------------------------------------
@@ -1955,6 +2208,7 @@ class InferenceEngineV2:
         drafts' blocks are rolled back via ``scheduler.apply_spec_round``.
         Rows are capped so rows x (k+1) fits the step token budget, with a
         rotating start so a capped round cannot starve later uids."""
+        self._refuse_state_loss("spec_round")
         k = int(k if k is not None else getattr(self.config, "spec_k", 0) or 0)
         if k < 1:
             raise ValueError(f"spec_round needs k >= 1 draft slots, got {k}")

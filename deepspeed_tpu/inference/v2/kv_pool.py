@@ -1,11 +1,14 @@
-"""KV-pool byte accounting: pool dtypes, bytes-per-block, budget sizing.
+"""Cache-pool byte accounting: pool dtypes, bytes-per-block, bytes a state
+slot, budget sizing. Two kinds of cache are paid from one byte budget.
 
 One shared source of truth for "how big is a KV block" so the engine
 (allocating the pools), the serve CLI (sizing ``num_blocks`` from a byte
 budget), the driver (health/metrics) and the capacity tests cannot drift.
 
 Layout recap (engine_v2): each of K and V is [L, num_blocks+1, block_size,
-kv_heads, head_dim] in the payload dtype; ``int8`` mode adds a per-token-row
+kv_heads, head_dim] in the payload dtype, L the layers that HAVE keys and
+values (``TransformerConfig.kv_layers``: all of them, or with ``layer_kinds``
+the full-attention ones); ``int8`` mode adds a per-token-row
 per-kv-head fp32 scale plane [L, num_blocks+1, block_size, kv_heads] per
 pool (quantize_kv's per-vector granularity — see ops/quantizer/block_quant).
 A "block" here is one (block_size, kv_heads, head_dim) slab counted across
@@ -13,6 +16,13 @@ all L layers and BOTH pools, i.e. the unit ``free_blocks`` admission counts.
 
 At head_dim=128 the int8 ratio is 2*128/(128+4) ≈ 1.94x — the ≥1.9x
 capacity bar the acceptance tests pin.
+
+A model with Gated DeltaNet layers also keeps, for every tracked sequence and
+whatever its length, one STATE SLOT: a layer's recurrent state [value heads,
+key dim, value dim] float32 and its conv's last K - 1 inputs, over the Lg such
+layers (``state_slot_bytes``). ``blocks_for_budget`` takes the slots, one a
+tracked sequence and the spare, from the budget first and sizes the K/V
+blocks from what is left.
 """
 
 from typing import Any, Dict, Tuple
@@ -90,16 +100,28 @@ def bytes_per_block(block_size: int, kv_heads: int, head_dim: int,
     return 2 * n_layers * per_pool
 
 
+def state_slot_bytes(config, conv_itemsize: int = 2) -> int:
+    """HBM bytes of one sequence's state slot over a model's DeltaNet layers
+    (``config``: the TransformerConfig): float32 states and the conv inputs in
+    the compute dtype. 0 for a model without such layers."""
+    layers = config.kind_count("gdn")
+    state = config.gdn_value_heads * config.gdn_key_dim * config.gdn_value_dim * 4
+    conv = (config.gdn_conv_kernel - 1) * config.gdn_conv_dim * conv_itemsize
+    return layers * (state + conv) if layers else 0
+
+
 def blocks_for_budget(budget_bytes: int, block_size: int, kv_heads: int,
                       head_dim: int, n_layers: int,
-                      kv_dtype: str = "bf16") -> int:
+                      kv_dtype: str = "bf16", state_bytes: int = 0) -> int:
     """How many pool blocks fit a fixed byte budget (the +1 trash block is
-    charged too, so the returned count is directly ``num_blocks``)."""
+    charged too, so the returned count is directly ``num_blocks``).
+    ``n_layers``: the layers that have K/V. ``state_bytes``: what the state
+    slots of a recurrent-state model take from the budget first."""
     per = bytes_per_block(block_size, kv_heads, head_dim, n_layers, kv_dtype)
-    n = budget_bytes // per - 1  # -1: the engine allocates num_blocks + 1
+    n = (budget_bytes - state_bytes) // per - 1  # -1: the engine allocates num_blocks + 1
     if n < 1:
         raise ValueError(
-            f"kv pool budget {budget_bytes} bytes holds no blocks at "
+            f"kv pool budget {budget_bytes} bytes ({state_bytes} of them state slots) holds no blocks at "
             f"{per} bytes/block (block_size={block_size}, kv_heads={kv_heads}, "
             f"head_dim={head_dim}, n_layers={n_layers}, dtype={kv_dtype})"
         )

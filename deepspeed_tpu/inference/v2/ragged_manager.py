@@ -8,6 +8,13 @@ The paged KV cache itself lives on device as
   k/v: [n_layers, num_blocks, block_size, n_kv_heads, head_dim]
 and each sequence owns an ordered list of block ids; token t of a sequence
 lives in block ``table[t // block_size]`` at row ``t % block_size``.
+
+A model with recurrent-state layers (Gated DeltaNet) has a second kind of
+cache: ONE fixed-size state slot a tracked sequence, whatever its length,
+taken when the sequence is created (admission) and given back when it is
+flushed (finish, cancel, expiry: every one of them ends in
+``flush_sequence``). The engine's pools hold one slot more, the spare that the
+padding of a step's grid points at; the manager never hands it out.
 """
 
 from dataclasses import dataclass, field
@@ -26,6 +33,7 @@ class DSSequenceDescriptor:
     tokens: List[int] = field(default_factory=list)  # full history (host)
     block_table: List[int] = field(default_factory=list)
     finished: bool = False
+    state_slot: int = -1  # recurrent-state models: the sequence's slot in the state pools
 
     @property
     def cur_allocated_blocks(self) -> int:
@@ -33,9 +41,12 @@ class DSSequenceDescriptor:
 
 
 class DSStateManager:
-    def __init__(self, config, kv_config):
+    def __init__(self, config, kv_config, state_slots: int = 0):
         self._config = config
         self._kv = kv_config
+        # free state slots, lowest first (0 for a model without such layers)
+        self._n_state_slots = int(state_slots)
+        self._free_slots: List[int] = list(range(self._n_state_slots))[::-1]
         self._alloc = BlockedAllocator(kv_config.num_blocks)
         self._seqs: Dict[int, DSSequenceDescriptor] = {}
         self.prefix_cache: Optional[PrefixCache] = (
@@ -74,6 +85,12 @@ class DSStateManager:
                 f"{self._config.max_tracked_sequences}"
             )
         seq = DSSequenceDescriptor(uid=uid)
+        if self._n_state_slots:
+            if not self._free_slots:
+                raise RuntimeError(
+                    f"no free state slot: {self._n_state_slots} slots for "
+                    f"max_tracked_sequences={self._config.max_tracked_sequences}")
+            seq.state_slot = self._free_slots.pop()
         self._seqs[uid] = seq
         return seq
 
@@ -206,6 +223,19 @@ class DSStateManager:
             "cached_only": len(cached - live),
         }
 
+    @property
+    def state_slots_in_use(self) -> int:
+        return self._n_state_slots - len(self._free_slots)
+
+    def state_slot_accounting(self) -> Dict[str, int]:
+        """The state slots' conservation law: every slot is free or held by
+        exactly one tracked sequence (all zero for a model without
+        recurrent-state layers)."""
+        live = [s.state_slot for s in self._seqs.values() if s.state_slot >= 0]
+        if len(set(live)) != len(live):
+            raise RuntimeError(f"a state slot is held twice: {sorted(live)}")
+        return {"total": self._n_state_slots, "free": len(self._free_slots), "live": len(live)}
+
     def alloc_stats(self) -> Dict[str, int]:
         """Allocator occupancy counters (total/free/held/shared) for
         per-replica health surfaces."""
@@ -231,6 +261,9 @@ class DSStateManager:
         seq = self._seqs.pop(uid, None)
         if seq is not None and seq.block_table:
             self._alloc.free(seq.block_table)
+        if seq is not None and seq.state_slot >= 0:
+            self._free_slots.append(seq.state_slot)
+            seq.state_slot = -1
 
     def block_table_array(self, seq: DSSequenceDescriptor) -> np.ndarray:
         out = np.zeros((self._kv.max_blocks_per_seq,), np.int32)

@@ -7,7 +7,8 @@ qwen_v2_moe,falcon,phi,phi3}``): a HF causal-LM checkpoint directory becomes
 a (:class:`TransformerConfig`, stacked-params pytree) pair that trains or
 serves through ``deepspeed_tpu.initialize`` / ``init_inference`` unchanged.
 
-Supported ``model_type``s: llama, mistral, qwen2, qwen2_moe, qwen3,
+Supported ``model_type``s: llama, mistral, qwen2, qwen2_moe, qwen3, qwen3_next
+(Gated DeltaNet and gated-attention layers, a share of the experts),
 qwen3_moe (per-head q/k RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole
 projection width; the four MoE types import drop-free: ``moe_drop_tokens``
 false), falcon, phi (incl. qk_layernorm),
@@ -201,6 +202,60 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
             moe_top_k=get("num_experts_per_tok"),
             moe_norm_topk_prob=bool(get("norm_topk_prob", True)),
             moe_drop_tokens=False,  # HF never drops a token
+            moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
+        )
+    if mt == "qwen3_next":
+        # three Gated DeltaNet layers to one gated softmax-attention layer
+        # (layer i is full attention where (i + 1) % full_attention_interval
+        # == 0), every layer with 512 routed experts, top 10 renormalised, and
+        # a sigmoid-gated shared expert; every norm but the DeltaNet's gated
+        # one is rms(x) * (1 + w). ``deployment_share`` (this repo's key, not
+        # Hugging Face's) says that num_experts is one chip's share of
+        # deployment_share.num_experts, and which.
+        sparse_step = get("decoder_sparse_step", 1)
+        mlp_only = get("mlp_only_layers", []) or []
+        if sparse_step != 1 or mlp_only:
+            raise ValueError(
+                f"qwen3_next: decoder_sparse_step={sparse_step}, mlp_only_layers="
+                f"{mlp_only}: only stacks with the expert block in every layer are supported"
+            )
+        n_layers = int(get("num_hidden_layers"))
+        every = int(get("full_attention_interval", 4))
+        kinds = get("layer_types", None) or [
+            "full_attention" if (i + 1) % every == 0 else "linear_attention"
+            for i in range(n_layers)]
+        unknown = set(kinds) - {"full_attention", "linear_attention"}
+        if unknown or len(kinds) != n_layers:
+            raise ValueError(f"qwen3_next: layer_types={kinds!r} for {n_layers} layers")
+        held = int(get("num_experts"))
+        share = get("deployment_share", None) or {}
+        total = int(share.get("num_experts", held))
+        chips = int(share.get("chips_per_layer", 1))
+        if total != held * chips:
+            raise ValueError(
+                f"qwen3_next: deployment_share says {chips} chips share {total} experts; "
+                f"num_experts={held} is not one chip's share of them")
+        return _llama_like_config(
+            get,
+            norm="rmsnorm_1p",
+            qk_norm=True,
+            head_dim_override=int(get("head_dim")),
+            rope_frac=float(get("partial_rotary_factor", 1.0)),
+            attn_out_gate=True,
+            layer_kinds=tuple("full" if k == "full_attention" else "gdn" for k in kinds),
+            gdn_key_heads=int(get("linear_num_key_heads")),
+            gdn_value_heads=int(get("linear_num_value_heads")),
+            gdn_key_dim=int(get("linear_key_head_dim")),
+            gdn_value_dim=int(get("linear_value_head_dim")),
+            gdn_conv_kernel=int(get("linear_conv_kernel_dim", 4)),
+            ffn_hidden_size=get("moe_intermediate_size"),
+            n_experts=held,
+            moe_experts_total=total if total != held else 0,
+            moe_expert_shard=int(share.get("share_index", 0)),
+            moe_top_k=get("num_experts_per_tok"),
+            moe_norm_topk_prob=bool(get("norm_topk_prob", True)),
+            moe_drop_tokens=False,  # HF never drops a token
+            moe_shared_expert_dim=get("shared_expert_intermediate_size", 0) or 0,
             moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
         )
     if mt == "qwen2_moe":
@@ -672,7 +727,7 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
         "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
-        "qwen3, qwen3_moe, megatron_gpt, bert, distilbert, clip_text_model"
+        "qwen3, qwen3_moe, qwen3_next, megatron_gpt, bert, distilbert, clip_text_model"
     )
 
 
@@ -725,6 +780,58 @@ def _llama_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str,
         layers["w_gate"].append(take.linear(f"{p}.mlp.gate_proj.weight"))
         layers["w_up"].append(take.linear(f"{p}.mlp.up_proj.weight"))
         layers["w_down"].append(take.linear(f"{p}.mlp.down_proj.weight"))
+
+
+def _qwen3_next_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
+    """One Qwen3-Next layer, by its kind. The checkpoint fuses what the system
+    stores by use: ``q_proj`` is [nh, (d query | d gate)] and splits into wq /
+    wq_gate; ``in_proj_qkvz`` is laid out by KEY head, [nk, (dk q | dk k |
+    rep*dv v | rep*dv z)], and ``in_proj_ba`` [nk, (rep b | rep a)] with
+    rep = value heads a key head: they regroup into gdn_qkv (q | k | v, the
+    conv's channel order), gdn_z and gdn_ba (b | a). Under an expert share the
+    chip's own experts are read, the router whole."""
+    h = cfg.hidden_size
+    i = int(p.rsplit(".", 1)[1])
+    layers["attn_norm"].append(take(f"{p}.input_layernorm.weight"))
+    layers["mlp_norm"].append(take(f"{p}.post_attention_layernorm.weight"))
+    if cfg.layer_kinds[i] == "full":
+        nh, d = cfg.n_heads, cfg.head_dim
+        full = layers["full"]
+        qg = take.linear(f"{p}.self_attn.q_proj.weight").reshape(h, nh, 2, d)
+        full["wq"].append(qg[:, :, 0].reshape(h, nh * d))
+        full["wq_gate"].append(qg[:, :, 1].reshape(h, nh * d))
+        full["wk"].append(take.linear(f"{p}.self_attn.k_proj.weight"))
+        full["wv"].append(take.linear(f"{p}.self_attn.v_proj.weight"))
+        full["wo"].append(take.linear(f"{p}.self_attn.o_proj.weight"))
+        full["q_norm"].append(take(f"{p}.self_attn.q_norm.weight"))
+        full["k_norm"].append(take(f"{p}.self_attn.k_norm.weight"))
+    else:
+        nk, nv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+        rep = nv // nk
+        gdn = layers["gdn"]
+        qkvz = take.linear(f"{p}.linear_attn.in_proj_qkvz.weight").reshape(h, nk, 2 * dk + 2 * rep * dv)
+        q, k, v, z = np.split(qkvz, [dk, 2 * dk, 2 * dk + rep * dv], axis=-1)
+        gdn["gdn_qkv"].append(np.concatenate(
+            [a.reshape(h, -1) for a in (q, k, v)], axis=-1))
+        gdn["gdn_z"].append(z.reshape(h, nv * dv))
+        ba = take.linear(f"{p}.linear_attn.in_proj_ba.weight").reshape(h, nk, 2 * rep)
+        gdn["gdn_ba"].append(np.concatenate(
+            [ba[:, :, :rep].reshape(h, nv), ba[:, :, rep:].reshape(h, nv)], axis=-1))
+        # Conv1d weight [C, 1, K] -> [K, C]
+        gdn["gdn_conv"].append(take(f"{p}.linear_attn.conv1d.weight")[:, 0, :].T)
+        gdn["gdn_dt_bias"].append(take(f"{p}.linear_attn.dt_bias"))
+        gdn["gdn_a_log"].append(take(f"{p}.linear_attn.A_log"))
+        gdn["gdn_norm"].append(take(f"{p}.linear_attn.norm.weight"))
+        gdn["gdn_out"].append(take.linear(f"{p}.linear_attn.out_proj.weight"))
+    layers["router"].append(take.linear(f"{p}.mlp.gate.weight"))
+    first = cfg.moe_expert_shard * cfg.n_experts
+    for name, hf in (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj")):
+        layers[name].append(np.stack([
+            take.linear(f"{p}.mlp.experts.{first + e}.{hf}.weight") for e in range(cfg.n_experts)]))
+    layers["shared_gate"].append(take.linear(f"{p}.mlp.shared_expert.gate_proj.weight"))
+    layers["shared_up"].append(take.linear(f"{p}.mlp.shared_expert.up_proj.weight"))
+    layers["shared_down"].append(take.linear(f"{p}.mlp.shared_expert.down_proj.weight"))
+    layers["shared_gate_proj"].append(take.linear(f"{p}.mlp.shared_expert_gate.weight"))
 
 
 def _phi3_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
@@ -1100,6 +1207,7 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "qwen2": _llama_layer,
     "qwen2_moe": _llama_layer,
     "qwen3": _llama_layer,
+    "qwen3_next": _qwen3_next_layer,
     "qwen3_moe": _llama_layer,
     "falcon": _falcon_layer,
     "phi": _phi_layer,
@@ -1132,6 +1240,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
     "qwen2": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen2_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "qwen3_next": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi": ("model.embed_tokens.weight", "model.final_layernorm", "model.layers", None),
@@ -1185,10 +1294,20 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
             keys += ["q_norm_b", "k_norm_b"]
     if cfg.mlp_bias and cfg.n_experts == 0:
         keys += ["w_up_b", "w_down_b"] + (["w_gate_b"] if cfg.activation in ("swiglu", "geglu") else [])
+    if cfg.attn_out_gate:
+        keys.append("wq_gate")
     if cfg.n_experts > 0:
         keys.append("router")
         if cfg.moe_shared_expert_dim > 0:
             keys += ["shared_gate", "shared_up", "shared_down", "shared_gate_proj"]
+    if cfg.hybrid:  # stacked by kind (transformer.init_params)
+        from deepspeed_tpu.models.transformer import ATTENTION_KEYS
+
+        out: Dict[str, Any] = {k: [] for k in keys if k not in ATTENTION_KEYS}
+        out["full"] = {k: [] for k in keys if k in ATTENTION_KEYS}
+        out["gdn"] = {k: [] for k in ("gdn_qkv", "gdn_z", "gdn_ba", "gdn_conv", "gdn_dt_bias",
+                                      "gdn_a_log", "gdn_norm", "gdn_out")}
+        return out
     return {k: [] for k in keys}
 
 
@@ -1277,7 +1396,8 @@ def load_hf_model(
     params: Dict[str, Any] = {
         "embed": take(embed_key),
         "final_norm": take(f"{norm_key}.weight"),
-        "layers": {k: np.stack(v) for k, v in layers.items()},
+        "layers": {k: ({n: np.stack(a) for n, a in v.items()} if isinstance(v, dict)
+                       else np.stack(v)) for k, v in layers.items()},
     }
     if cfg.norm == "layernorm":
         params["final_norm_b"] = take(f"{norm_key}.bias")
@@ -1303,7 +1423,11 @@ def load_hf_model(
             cfg = dataclass_replace(cfg, tie_embeddings=True)
     else:
         state.pop("lm_head.weight", None)
-    leftover = [k for k in state if not k.endswith("rotary_emb.inv_freq")]
+    # qwen3_next: the multi-token-prediction head is no part of the causal LM
+    # (transformers ignores mtp.* on load), and an expert share leaves the
+    # other chips' experts where they are
+    leftover = [k for k in state if not k.endswith("rotary_emb.inv_freq")
+                and not k.startswith("mtp.") and not (cfg.moe_experts_total and ".mlp.experts." in k)]
     if leftover:
         logger.warning(f"unmapped HF weights ignored: {leftover[:8]}{'...' if len(leftover) > 8 else ''}")
     return cfg, params

@@ -204,6 +204,31 @@ class TransformerConfig:
     # renormalize top-k combine weights over surviving experts (mixtral /
     # qwen2 norm_topk_prob=True); False keeps raw softmax mass (qwen1.5-moe)
     moe_norm_topk_prob: bool = True
+    # this chip's SHARE of each layer's experts (expert parallelism seen from
+    # one chip): > n_experts is the published count. The router is that wide
+    # and takes its top-k over all of them; n_experts are held here, those
+    # numbered from moe_expert_shard * n_experts; a (token, expert) pair of an
+    # expert held elsewhere is neither multiplied nor summed, and the partial
+    # sum plus what every chip computes alike (the shared expert) goes on.
+    # 0: every expert is held (the router is n_experts wide).
+    moe_experts_total: int = 0
+    moe_expert_shard: int = 0
+    # per-layer KIND (qwen3-next): n_layers names, "full" (softmax attention
+    # over cached keys and values) or "gdn" (Gated DeltaNet: a recurrent state
+    # and a short causal conv, ops/linear_attention). None: every layer "full".
+    # Parameters are stacked by kind: what every layer has (norms, MLP or
+    # experts) on [n_layers], attention under params["layers"]["full"] on
+    # [number of full layers], DeltaNet under params["layers"]["gdn"].
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv_kernel: int = 4
+    # qwen3-next gated attention output: q_proj is twice as wide, per head a
+    # query and a gate, and the heads' output is multiplied by sigmoid(gate)
+    # in front of wo (stored apart as wq_gate [h, n_heads * head_dim])
+    attn_out_gate: bool = False
     vocab_parallel: bool = True  # shard embedding/lm_head vocab dim on `model`
     # sequence-parallel attention: "ulysses" (all-to-all head scatter) or
     # "ring" (ppermute blockwise — O(s/N) per-device memory, unbounded SP
@@ -323,6 +348,35 @@ class TransformerConfig:
                     f"attn_layer_pattern has {len(self.attn_layer_pattern)} "
                     f"entries for {self.n_layers} layers"
                 )
+        if self.layer_kinds is not None:
+            bad = set(self.layer_kinds) - {"full", "gdn"}
+            if bad or len(self.layer_kinds) != self.n_layers:
+                raise ValueError(
+                    f"layer_kinds={self.layer_kinds!r}: expected {self.n_layers} "
+                    "names, each 'full' or 'gdn'"
+                )
+            if "gdn" in self.layer_kinds and (
+                min(self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
+                    self.gdn_value_dim) < 1
+                or self.gdn_value_heads % self.gdn_key_heads
+            ):
+                raise ValueError(
+                    "a 'gdn' layer needs gdn_key_heads / gdn_value_heads / gdn_key_dim / "
+                    "gdn_value_dim, the value heads a multiple of the key heads"
+                )
+            if self.sliding_window or self.norm_scheme != "pre" or self.parallel_block:
+                raise ValueError(
+                    "layer_kinds composes with neither sliding_window, post-norm "
+                    "nor parallel blocks (no architecture combines them)"
+                )
+        if self.moe_experts_total and (
+            self.moe_experts_total % max(1, self.n_experts)
+            or not 0 <= self.moe_expert_shard < self.moe_experts_total // max(1, self.n_experts)
+        ):
+            raise ValueError(
+                f"moe_experts_total={self.moe_experts_total} is shared in shares of "
+                f"n_experts={self.n_experts}: shard {self.moe_expert_shard} is not one of them"
+            )
         if self.comm_quant not in ("none", "int8"):
             raise ValueError(
                 f"comm_quant={self.comm_quant!r}: expected 'none' or 'int8' "
@@ -347,6 +401,31 @@ class TransformerConfig:
             raise ValueError(f"hidden_size {self.hidden_size} not divisible by "
                              f"n_heads {self.n_heads}")
         return self.hidden_size // self.n_heads
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router chooses among: the published count."""
+        return self.moe_experts_total or self.n_experts
+
+    @property
+    def hybrid(self) -> bool:
+        """True where the layers are of more than one kind (``layer_kinds``)."""
+        return self.layer_kinds is not None and "gdn" in self.layer_kinds
+
+    def kind_count(self, kind: str) -> int:
+        if self.layer_kinds is None:
+            return self.n_layers if kind == "full" else 0
+        return self.layer_kinds.count(kind)
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that cache keys and values: the pool's leading dimension."""
+        return self.kind_count("full")
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels of a DeltaNet layer's conv: q and k at the key heads, v."""
+        return 2 * self.gdn_key_heads * self.gdn_key_dim + self.gdn_value_heads * self.gdn_value_dim
 
     @property
     def ffn_dim(self) -> int:
@@ -394,6 +473,45 @@ def get_config(preset: str = "tiny", **overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+# the per-layer parameters of softmax attention: with layer_kinds they are
+# stacked on the "full" layers alone, under params["layers"]["full"]
+ATTENTION_KEYS = frozenset({
+    "wq", "wk", "wv", "wo", "wq_gate", "wq_b", "wk_b", "wv_b", "wo_b",
+    "q_norm", "k_norm", "q_norm_b", "k_norm_b",
+})
+
+
+def layer_period(c: TransformerConfig) -> Tuple[Tuple[str, ...], int]:
+    """(the kinds of one period, how many periods) of ``layer_kinds``: the
+    shortest pattern the stack repeats, so a loop over periods with the
+    period's layers unrolled in its body serves the stack with one traced
+    body a kind."""
+    kinds = c.layer_kinds or ("full",) * c.n_layers
+    for p in range(1, len(kinds) + 1):
+        if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
+            return kinds[:p], len(kinds) // p
+    raise AssertionError("unreachable: the whole stack is a period")
+
+
+def kind_ordinals(c: TransformerConfig) -> Tuple[int, ...]:
+    """For each layer its ordinal among the layers of its kind: where it sits
+    in its kind's parameter stack and in its kind's cache pool."""
+    kinds = c.layer_kinds or ("full",) * c.n_layers
+    return tuple(kinds[:i].count(k) for i, k in enumerate(kinds))
+
+
+def take_layer(layers, c: TransformerConfig, li, take):
+    """One layer's parameters out of the stacked tree: ``take(stack, index)``
+    on what every layer has at ``li`` and on its kind's stack at the layer's
+    ordinal. ``li`` is static."""
+    if not c.hybrid:
+        return jax.tree.map(lambda a: take(a, li), layers)
+    kind, ki = c.layer_kinds[li], kind_ordinals(c)[li]
+    common = {k: v for k, v in layers.items() if k not in ("full", "gdn")}
+    return {**jax.tree.map(lambda a: take(a, li), common),
+            **jax.tree.map(lambda a: take(a, ki), layers[kind])}
+
+
 # ---------------------------------------------------------------------------
 # parameter init
 # ---------------------------------------------------------------------------
@@ -405,48 +523,94 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     h, d, nh, nkv = c.hidden_size, c.head_dim, c.n_heads, c.kv_heads
     ffn = c.ffn_dim
     L = c.n_layers
-    keys = iter(jax.random.split(key, 32))
+    keys = iter(jax.random.split(key, 48))
 
-    def dense(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32) * (1.0 / math.sqrt(fan_in))).astype(dtype)
+    def dense(k, shape, fan_in, gain=1.0):
+        return (jax.random.normal(k, shape, jnp.float32) * (gain / math.sqrt(fan_in))).astype(dtype)
+
+    # Seeded weights stand in for a checkpoint wherever the served tokens are
+    # held to a float32 reference. With the unit-gain draws below every
+    # block's output is as large as the stream it is added to (the 0.02
+    # embedding is gone after layer 0), which a model of matmuls and softmaxes
+    # bears. A model that DECIDES does not: the top 10 of a 512-wide router,
+    # renormalised, turn a rounding of their input into another choice of
+    # experts, and one turned choice then moves all that follows (at the
+    # published Qwen3-Next widths bf16 missed float32 by a whole logit). So a
+    # hybrid model is seeded as a pre-norm model is trained from and ends up:
+    # the stream starts at unit rms and the projections INTO it are drawn at
+    # 1 / sqrt(2 layers) of the others (the GPT-2 / Megatron residual
+    # scaling): a block adds a fraction of the stream, as in a checkpoint.
+    into_stream = 1.0 / math.sqrt(2 * L) if c.hybrid else 1.0
 
     # rmsnorm_1p's effective scale is (1 + w): identity init is ZEROS there
     norm_one = jnp.zeros if c.norm == "rmsnorm_1p" else jnp.ones
+    # attention weights live on the layers that attend: all of them, or with
+    # layer_kinds the "full" ones (stacked apart, under layers["full"])
+    La = c.kv_layers
     layers: Dict[str, Any] = {
         "attn_norm": norm_one((L, h), dtype),
-        "wq": dense(next(keys), (L, h, nh * d), h),
-        "wk": dense(next(keys), (L, h, nkv * d), h),
-        "wv": dense(next(keys), (L, h, nkv * d), h),
-        "wo": dense(next(keys), (L, nh * d, h), nh * d),
+        "wq": dense(next(keys), (La, h, nh * d), h),
+        "wk": dense(next(keys), (La, h, nkv * d), h),
+        "wv": dense(next(keys), (La, h, nkv * d), h),
+        "wo": dense(next(keys), (La, nh * d, h), nh * d, into_stream),
         "mlp_norm": norm_one((L, h), dtype),
     }
+    if c.attn_out_gate:
+        layers["wq_gate"] = dense(next(keys), (La, h, nh * d), h)
     if c.norm == "layernorm":
         layers["attn_norm_b"] = jnp.zeros((L, h), dtype)
         layers["mlp_norm_b"] = jnp.zeros((L, h), dtype)
     if c.attn_qkv_bias:
-        layers["wq_b"] = jnp.zeros((L, nh * d), dtype)
-        layers["wk_b"] = jnp.zeros((L, nkv * d), dtype)
-        layers["wv_b"] = jnp.zeros((L, nkv * d), dtype)
+        layers["wq_b"] = jnp.zeros((La, nh * d), dtype)
+        layers["wk_b"] = jnp.zeros((La, nkv * d), dtype)
+        layers["wv_b"] = jnp.zeros((La, nkv * d), dtype)
     if c.qk_norm:
         if c.qk_norm_kind == "layernorm_per_head":
-            layers["q_norm"] = jnp.ones((L, nh, d), dtype)
-            layers["k_norm"] = jnp.ones((L, nkv, d), dtype)
+            layers["q_norm"] = jnp.ones((La, nh, d), dtype)
+            layers["k_norm"] = jnp.ones((La, nkv, d), dtype)
         elif c.qk_norm_kind == "rmsnorm_full":
-            layers["q_norm"] = jnp.ones((L, nh * d), dtype)
-            layers["k_norm"] = jnp.ones((L, nkv * d), dtype)
+            layers["q_norm"] = jnp.ones((La, nh * d), dtype)
+            layers["k_norm"] = jnp.ones((La, nkv * d), dtype)
         else:
-            layers["q_norm"] = jnp.ones((L, d), dtype)
-            layers["k_norm"] = jnp.ones((L, d), dtype)
+            # under rmsnorm_1p the per-head norm is (1 + w) too (qk_norm_apply)
+            qk_one = norm_one if c.qk_norm_kind == "rmsnorm" else jnp.ones
+            layers["q_norm"] = qk_one((La, d), dtype)
+            layers["k_norm"] = qk_one((La, d), dtype)
             if c.qk_norm_kind == "layernorm":
-                layers["q_norm_b"] = jnp.zeros((L, d), dtype)
-                layers["k_norm_b"] = jnp.zeros((L, d), dtype)
+                layers["q_norm_b"] = jnp.zeros((La, d), dtype)
+                layers["k_norm_b"] = jnp.zeros((La, d), dtype)
     if c.attn_out_bias:
-        layers["wo_b"] = jnp.zeros((L, h), dtype)
+        layers["wo_b"] = jnp.zeros((La, h), dtype)
+    if c.hybrid:
+        layers = {k: v for k, v in layers.items() if k not in ATTENTION_KEYS} | {
+            "full": {k: v for k, v in layers.items() if k in ATTENTION_KEYS}}
+        Lg, nv = c.kind_count("gdn"), c.gdn_value_heads
+        vd = nv * c.gdn_value_dim
+        # the gates as such layers are TRAINED from (the flash-linear-attention
+        # library's GatedDeltaNet): a step dt log-uniform in [1e-3, 1e-1] and
+        # dt_bias its inverse softplus, A uniform in (0, 16), so that a head
+        # forgets over tens to hundreds of tokens. ``transformers`` draws the
+        # same A beside a placeholder dt_bias of 1, under which eight heads in
+        # nine decay their state to nothing within one token
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (Lg, nv), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        layers["gdn"] = {
+            # q | k at the key heads | v at the value heads: the conv's channels
+            "gdn_qkv": dense(next(keys), (Lg, h, c.gdn_conv_dim), h),
+            "gdn_z": dense(next(keys), (Lg, h, vd), h),
+            "gdn_ba": dense(next(keys), (Lg, h, 2 * nv), h),  # b | a
+            "gdn_conv": dense(next(keys), (Lg, c.gdn_conv_kernel, c.gdn_conv_dim), c.gdn_conv_kernel),
+            "gdn_dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "gdn_a_log": jnp.log(jax.random.uniform(
+                next(keys), (Lg, nv), jnp.float32, 1e-3, 16.0)).astype(dtype),
+            "gdn_norm": jnp.ones((Lg, c.gdn_value_dim), dtype),
+            "gdn_out": dense(next(keys), (Lg, vd, h), vd, into_stream),
+        }
     if c.n_experts > 0:
         E = c.n_experts
-        layers["router"] = dense(next(keys), (L, h, E), h)
+        layers["router"] = dense(next(keys), (L, h, c.router_width), h)
         layers["w_up"] = dense(next(keys), (L, E, h, ffn), h)
-        layers["w_down"] = dense(next(keys), (L, E, ffn, h), ffn)
+        layers["w_down"] = dense(next(keys), (L, E, ffn, h), ffn, into_stream)
         if c.activation in ("swiglu", "geglu"):
             layers["w_gate"] = dense(next(keys), (L, E, h, ffn), h)
         if c.moe_residual:
@@ -459,7 +623,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         if c.moe_shared_expert_dim > 0:
             sd = c.moe_shared_expert_dim
             layers["shared_up"] = dense(next(keys), (L, h, sd), h)
-            layers["shared_down"] = dense(next(keys), (L, sd, h), sd)
+            layers["shared_down"] = dense(next(keys), (L, sd, h), sd, into_stream)
             if c.activation in ("swiglu", "geglu"):
                 layers["shared_gate"] = dense(next(keys), (L, h, sd), h)
             layers["shared_gate_proj"] = dense(next(keys), (L, h, 1), h)
@@ -475,7 +639,8 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             layers["w_gate_b"] = jnp.zeros((L, ffn), dtype)
 
     params: Dict[str, Any] = {
-        "embed": (jax.random.normal(next(keys), (c.vocab_size, h), jnp.float32) * 0.02).astype(dtype),
+        "embed": (jax.random.normal(next(keys), (c.vocab_size, h), jnp.float32)
+                  * (1.0 if c.hybrid else 0.02)).astype(dtype),
         "layers": layers,
     }
     if c.final_norm:
@@ -512,6 +677,10 @@ def param_partition_specs(config: TransformerConfig) -> Dict[str, Any]:
     never sharded. ZeRO later adds the ``data`` axis on free dims
     (runtime/zero/partition.py choose_zero_spec)."""
     c = config
+    if c.hybrid or c.attn_out_gate or c.moe_experts_total:
+        raise NotImplementedError(
+            "tensor-parallel partition specs for layer_kinds / attn_out_gate / an "
+            "expert share: no sharded form of these has a test yet")
     m = MODEL_AXIS
     layers: Dict[str, Any] = {
         "attn_norm": P(None, None),
@@ -952,6 +1121,8 @@ def qk_norm_apply(c: TransformerConfig, x, w, head_axis: int, b=None):
     if c.qk_norm_kind in ("rmsnorm", "rmsnorm_full"):
         from deepspeed_tpu.ops.normalization.fused_norm import rms_norm_reference
 
+        if c.norm == "rmsnorm_1p" and c.qk_norm_kind == "rmsnorm":
+            w = 1.0 + w.astype(jnp.float32)  # zero-centred, as the layer norms (qwen3-next)
         return rms_norm_reference(x, w, c.norm_eps)
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -1123,10 +1294,65 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
                 window_flag=local_flag, impl=impl, schedule=schedule,
             )
     out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * d)
+    if c.attn_out_gate:
+        out = attn_gate(out, _proj(c, x, lp["wq_gate"]))
     out = _proj(c, out, lp["wo"])
     if c.attn_out_bias:
         out = out + lp["wo_b"]
     return out, new_cache
+
+
+def attn_gate(out, gate):
+    """The heads' output under its gate: ``out * sigmoid(gate)`` (qwen3-next)."""
+    return out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+
+
+def gdn_project(c: TransformerConfig, lp, a):
+    """A DeltaNet layer's projections of the normed input ``a [..., h]``: (the
+    conv's input ``[..., C]``: q | k at the key heads | v, the output gate z
+    ``[..., nv, dv]``, g and beta ``[..., nv]`` float32)."""
+    from deepspeed_tpu.ops.linear_attention import gdn_gates
+
+    nv = c.gdn_value_heads
+    z = _proj(c, a, lp["gdn_z"])
+    ba = a @ lp["gdn_ba"]
+    g, beta = gdn_gates(ba[..., :nv], ba[..., nv:], lp["gdn_a_log"], lp["gdn_dt_bias"])
+    return _proj(c, a, lp["gdn_qkv"]), z.reshape(z.shape[:-1] + (nv, c.gdn_value_dim)), g, beta
+
+
+def gdn_heads(c: TransformerConfig, qkv):
+    """The conv's output ``[..., C]`` as the rule takes it: q, k
+    ``[..., nk, dk]`` L2-normalised (q scaled), v ``[..., nv, dv]``, float32."""
+    from deepspeed_tpu.ops.linear_attention.gated_delta import qk_heads
+
+    nk, dk, nv, dv = c.gdn_key_heads, c.gdn_key_dim, c.gdn_value_heads, c.gdn_value_dim
+    lead = qkv.shape[:-1]
+    q, k, v = jnp.split(qkv.astype(jnp.float32), [nk * dk, 2 * nk * dk], axis=-1)
+    q, k = qk_heads(q.reshape(lead + (nk, dk)), k.reshape(lead + (nk, dk)))
+    return q, k, v.reshape(lead + (nv, dv))
+
+
+def gdn_output(c: TransformerConfig, lp, o, z, dtype):
+    """The rule's output ``o [..., nv, dv]`` through the gated norm and the
+    output projection: ``[..., h]``."""
+    from deepspeed_tpu.ops.linear_attention import gated_rms_norm
+
+    y = gated_rms_norm(o, z, lp["gdn_norm"], c.norm_eps).astype(dtype)
+    return _proj(c, y.reshape(y.shape[:-2] + (-1,)), lp["gdn_out"])
+
+
+def _gdn_block(c: TransformerConfig, lp, x):
+    """Gated DeltaNet for one layer with no cache: conv and state start from
+    zero. x: [b, s, h] (normed)."""
+    from deepspeed_tpu.ops.linear_attention import causal_conv, gdn_chunked
+
+    b = x.shape[0]
+    qkv, z, g, beta = gdn_project(c, lp, x)
+    conv0 = jnp.zeros((b, c.gdn_conv_kernel - 1, c.gdn_conv_dim), x.dtype)
+    q, k, v = gdn_heads(c, causal_conv(qkv, lp["gdn_conv"], conv0)[0])
+    state0 = jnp.zeros((b, c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim), jnp.float32)
+    o, _ = gdn_chunked(q, k, v, g, beta, state0)
+    return gdn_output(c, lp, o, z, x.dtype)
 
 
 def _mlp_block(c: TransformerConfig, lp, x):
@@ -1197,7 +1423,13 @@ def _layer(c: TransformerConfig, lp, x, positions, segment_ids, local_flag=None)
         x = _norm(x + mlp_out, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
         return _act_constraint(x), aux_loss
     a = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
-    attn_out, _ = _attention_block(c, lp, a, positions, segment_ids, local_flag=local_flag)
+    if "gdn_qkv" in lp:  # a DeltaNet layer (layer_kinds): its own keys say so
+        if segment_ids is not None:
+            raise NotImplementedError("packed sequences through a 'gdn' layer: the "
+                                      "state would have to reset at each boundary")
+        attn_out = _gdn_block(c, lp, a)
+    else:
+        attn_out, _ = _attention_block(c, lp, a, positions, segment_ids, local_flag=local_flag)
     if c.parallel_block:
         # falcon/phi: both branches from the pre-attention state, one residual
         m = _norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
@@ -1255,7 +1487,33 @@ def forward_hidden(
     if c.remat:
         layer_fn = jax.checkpoint(layer_fn, policy=remat_policy(c.remat_policy))
 
-    if c.attn_layer_pattern is not None:
+    if c.hybrid:
+        # two kinds of layer: one scan step a PERIOD of the pattern, the
+        # period's layers unrolled in it, each kind's stack split by period
+        period, n = layer_period(c)
+        ords = kind_ordinals(c)[: len(period)]
+        pl_ = params["layers"]
+        by_period = {
+            kind: jax.tree.map(
+                lambda a: a.reshape((n, a.shape[0] // n) + a.shape[1:]), pl_[kind])
+            for kind in set(period)}
+        common = jax.tree.map(
+            lambda a: a.reshape((n, len(period)) + a.shape[1:]),
+            {k: v for k, v in pl_.items() if k not in ("full", "gdn")})
+
+        def period_body(x, xs_p):
+            common_p, kinds_p = xs_p
+            aux = jnp.float32(0.0)
+            for j, kind in enumerate(period):
+                lp = {**jax.tree.map(lambda a: a[j], common_p),
+                      **jax.tree.map(lambda a: a[ords[j]], kinds_p[kind])}
+                x, a = layer_fn(lp, x, positions, segment_ids)
+                aux = aux + a
+            return x, aux
+
+        x, aux_losses = jax.lax.scan(period_body, x, (common, by_period))
+        xs = None
+    elif c.attn_layer_pattern is not None:
         flags = jnp.asarray(c.attn_layer_pattern, jnp.int32)
         xs = (params["layers"], flags)
 
@@ -1268,9 +1526,11 @@ def forward_hidden(
         def call_layer(xs_i, x):
             return layer_fn(xs_i, x, positions, segment_ids)
 
-    n_layer = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    n_layer = jax.tree_util.tree_leaves(xs)[0].shape[0] if xs is not None else 0
     chunk = _OVERLAP_SCAN_CHUNK
-    if chunk > 1 and n_layer % chunk == 0:
+    if xs is None:
+        pass  # the hybrid stack ran above
+    elif chunk > 1 and n_layer % chunk == 0:
         # bucketed prefetch: scan L/chunk chunks, the inner `chunk` layers
         # unrolled so layer b+1's weight gather/stage (which stays INSIDE
         # the remat'd layer body — hoisting it out would pin every gathered

@@ -20,6 +20,14 @@ Slots that are not ``live`` (the padding of a serving step's packed grid) are
 given to no expert: their pairs sort behind the last group and are neither
 multiplied nor counted.
 
+The layer is told which experts it holds (``moe_experts_total`` /
+``moe_expert_shard``: one chip's share under expert parallelism). The router is
+as wide as the published count and the top-k is taken, and renormalised, over
+all of them; a pair whose expert is held elsewhere sorts behind the last group
+with the padding, so what comes back is this share's PART of the sum. On one
+chip the layer runs without its exchange, and nothing stands in for the chips
+that are not there.
+
 ``grouped_matmul`` is the one new operation. On a TPU it is the Pallas kernel
 ``dstpu_moe_gmm`` (the shape of ``jax.experimental.pallas.ops.tpu.megablox``: row
 tiles aligned to group boundaries through scalar prefetch, a tile that straddles
@@ -190,9 +198,10 @@ def grouped_matmul(lhs, rhs, group_sizes, tm: int, impl: Optional[str] = None, l
 
 
 def route(config, logits, live=None):
-    """Router as published. logits ``[t, E]`` float32. Returns (gate values
-    ``[t, k]`` float32, expert ids ``[t, k]``, aux loss)."""
-    E, k = config.n_experts, config.moe_top_k
+    """Router as published. logits ``[t, E]`` float32 over every expert of the
+    layer, held here or not. Returns (gate values ``[t, k]`` float32, expert
+    ids ``[t, k]``, aux loss)."""
+    E, k = logits.shape[-1], config.moe_top_k
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_p, top_e = jax.lax.top_k(probs, k)
     if config.moe_norm_topk_prob:
@@ -209,19 +218,24 @@ def route(config, logits, live=None):
 def experts_grouped(config, lp, tokens, logits, live=None, layer=None
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The routed experts of one layer on ``tokens [t, h]`` under the router's
-    ``logits [t, E]``. ``live [t]`` bool marks the slots that hold a token.
-    With ``layer``, the expert weights in ``lp`` are the whole stacks
-    ``[L, E, ...]`` (see ``grouped_matmul``). Returns (out ``[t, h]``, aux loss,
-    ``[E]`` int32 rows routed to each expert)."""
+    ``logits [t, experts of the layer]``. ``live [t]`` bool marks the slots that
+    hold a token. With ``layer``, the expert weights in ``lp`` are the whole
+    stacks ``[L, E, ...]`` (see ``grouped_matmul``). ``E`` is the experts HELD
+    here; the logits span more under an expert share. Returns (out ``[t, h]``:
+    the held experts' part of the sum, aux loss, ``[E]`` int32 rows routed to
+    each held expert)."""
     t, h = tokens.shape
     E, k = config.n_experts, config.moe_top_k
     top_p, top_e, aux = route(config, logits, live)
 
     tm = row_tile(t * k, tokens.dtype.itemsize)
     m = -(-t * k // tm) * tm
-    pair_e = top_e.astype(jnp.int32)
+    # an expert's number among those held here; outside [0, E): held elsewhere
+    pair_e = top_e.astype(jnp.int32) - config.moe_expert_shard * E
+    here = (pair_e >= 0) & (pair_e < E)
     if live is not None:
-        pair_e = jnp.where(live[:, None], pair_e, E)  # behind the last group
+        here = here & live[:, None]
+    pair_e = jnp.where(here, pair_e, E)  # behind the last group
     pair_e = jnp.pad(pair_e.reshape(t * k), (0, m - t * k), constant_values=E)
     order = jnp.argsort(pair_e, stable=True)
     counts = jnp.sum(jax.nn.one_hot(pair_e, E + 1, dtype=jnp.int32), axis=0)[:E]

@@ -308,7 +308,8 @@ def moe_mlp(config, lp, x: jax.Array, live: Optional[jax.Array] = None, layer=No
     """MoE MLP block used by models/transformer.py and the serving steps.
 
     lp: layer params with router [h,E], w_up [E,h,f], w_down [E,f,h]
-    (+ w_gate [E,h,f] for swiglu). x: [b, s, h]. ``live`` ([b, s] bool, or
+    (+ w_gate [E,h,f] for swiglu); under an expert share the router spans the
+    published count and E experts are held (grouped.py). x: [b, s, h]. ``live`` ([b, s] bool, or
     None for all): the slots that hold a token; the padding of a serving
     step's grid goes to no expert. ``layer``: where given, ``lp``'s expert
     weights (``EXPERT_STACKS``) are the whole stacks [L, E, ...] and this is
@@ -341,6 +342,11 @@ def moe_mlp(config, lp, x: jax.Array, live: Optional[jax.Array] = None, layer=No
 
         out, l_aux, counts = experts_grouped(config, lp, tokens, logits, live, layer)
         return _moe_tail(config, lp, tokens, out).reshape(b, s, h), l_aux, counts
+    if config.router_width != config.n_experts:
+        raise NotImplementedError(
+            "an expert share (moe_experts_total > n_experts) runs the grouped "
+            "dispatch on one device's experts (moe_drop_tokens=False, expert and "
+            "model axes of 1); the capacity dispatch has no held-expert form")
     if layer is not None:  # an einsum reads a slice in place
         lp = {k: jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False) if k in EXPERT_STACKS
               else v for k, v in lp.items()}

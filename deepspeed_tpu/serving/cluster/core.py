@@ -199,6 +199,9 @@ class EngineCore:
             self._inc("moe_computed_rows_total", moe["computed"])
             self._inc("moe_hot_expert_rows_total", moe["hot"])
             self._inc("moe_layer_calls_total", moe["calls"])
+            self._inc("moe_experts_hit_total", moe.get("hit", 0))
+        # a model with DeltaNet layers: the rows whose states took the update
+        self._inc("gdn_decode_rows_total", held("gdn_decode_rows"))
 
     # -- admission accounting --------------------------------------------
     def blocks_needed(self, req: Request, prefill_only: bool = False) -> int:
@@ -276,6 +279,7 @@ class EngineCore:
         except while a replay recovery is in flight)."""
         self.engine.scheduler.submit(req.uid, req.engine_prompt)
         self.requests[req.uid] = req
+        self._gauge_state_slots()
 
     def release(self, uid: int, scheduler_done: bool = False) -> None:
         """Detach a request from this engine: drop scheduler state (frees
@@ -289,6 +293,14 @@ class EngineCore:
         self.requests.pop(uid, None)
         if self.spec_ctl is not None:
             self.spec_ctl.forget(uid)
+        self._gauge_state_slots()
+
+    def _gauge_state_slots(self) -> None:
+        """A state slot is taken as a sequence is admitted and given back as
+        it is flushed: the gauge moves there and nowhere else."""
+        if self.metrics is not None:
+            self.metrics.set_gauge(
+                "state_slots_in_use", getattr(self.engine.state_manager, "state_slots_in_use", 0))
 
     def has_work(self) -> bool:
         return self.engine.scheduler.has_work()
@@ -548,6 +560,12 @@ class EngineCore:
         ep = getattr(self.engine, "_kv_endpoint", None)
         return dict(ep.stats()) if ep is not None else {}
 
+    def state_slots(self) -> Dict[str, int]:
+        """The engine's state slots (recurrent-state models): total / free /
+        live, all 0 for a model without them or an engine that has none."""
+        acct = getattr(self.engine.state_manager, "state_slot_accounting", None)
+        return acct() if acct is not None else {"total": 0, "free": 0, "live": 0}
+
     def replica_stats(self) -> Dict[str, float]:
         """Per-replica gauge snapshot for the labeled /metrics samples."""
         free = self.free_blocks()
@@ -567,6 +585,10 @@ class EngineCore:
         alloc_stats = getattr(self.engine.state_manager, "alloc_stats", None)
         if alloc_stats is not None:
             stats["kv_blocks_shared"] = alloc_stats()["shared"]
+        slots = self.state_slots()
+        if slots["total"]:
+            stats["state_slots_total"] = slots["total"]
+            stats["state_slots_in_use"] = slots["live"]
         tier = self.host_tier()
         if tier is not None:
             t = tier.stats()

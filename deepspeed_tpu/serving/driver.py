@@ -286,6 +286,9 @@ class ServingDriver:
                 "kv_capacity_multiplier": self._kv_info.get(
                     "kv_capacity_multiplier", 1.0
                 ),
+                # the second kind of cache (0 / 0 without recurrent-state layers)
+                "state_slots_total": self._kv_info.get("state_slots", 0),
+                "state_slots_in_use": self.core.state_slots()["live"],
                 "kv_host_tier": self._host_tier_health(),
                 "spec": {
                     "enabled": self._spec_ctl is not None,

@@ -137,6 +137,12 @@ class ServingMetrics:
             "moe_computed_rows_total": 0,
             "moe_hot_expert_rows_total": 0,
             "moe_layer_calls_total": 0,
+            # ... and the experts that had a row, summed over layer calls
+            "moe_experts_hit_total": 0,
+            # Gated DeltaNet layers (EngineCore._count_step): rows whose
+            # recurrent state took the one-token update, of one such layer
+            # a step
+            "gdn_decode_rows_total": 0,
             "admission_blocked_total": 0,
             # prefix cache (mirrors of PrefixCache's monotone counters)
             "prefix_queries_total": 0,
@@ -193,6 +199,10 @@ class ServingMetrics:
             "kv_cache_int8": 0,
             "kv_pool_bytes": 0,
             "kv_capacity_multiplier": 1.0,
+            # the second kind of cache (recurrent-state models): one slot a
+            # tracked sequence and a spare; 0 for a model without one
+            "state_slots_total": 0,
+            "state_slots_in_use": 0,
             # quantized-collectives flag (engine.comm_wire_info); per-wire
             # byte counters render as labeled comm_wire_* samples
             "comm_quant_int8": 0,
@@ -271,6 +281,7 @@ class ServingMetrics:
                 info.get("kv_cache_dtype") == "int8"
             )
             self.gauges["kv_pool_bytes"] = info.get("kv_pool_bytes", 0)
+            self.gauges["state_slots_total"] = info.get("state_slots", 0)
             self.gauges["kv_capacity_multiplier"] = info.get(
                 "kv_capacity_multiplier", 1.0
             )

@@ -459,7 +459,8 @@ def _ragged_call(rng, nh, nkv, d, int8=False):
 
 
 @pytest.mark.parametrize("form", ["plain", "split", "split-int8"])
-@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (4, 1, 64)])
+# (16, 2, 256): Qwen3-Next's full-attention layers, 8 query heads a KV head
+@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (4, 1, 64), (16, 2, 256)])
 def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form):
     """Contexts 0, 1, bs-1, bs, bs+1 and a full table beside an inactive slot
     in ONE call. ``plain``: the query is the context's last token, against

@@ -20,15 +20,21 @@ PROGRAMS = ["split_step", "decode_only_step", "multistep_decode", "verify_step"]
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(kv_dtype):
+def _programs(kind):
     """(engine, {name: (jitted, args)}) after two same-shape generate()
     passes: pass 1 traces, pass 2 must hit the caches. ``decode_only_step``
     is the split step's shape for a batch with no chunk row."""
-    return dv._engine_v2_programs(kv_dtype)
+    return dv._engine_v2_programs("bf16", model="gdn") if kind == "gdn" else dv._engine_v2_programs(kind)
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-@pytest.mark.parametrize("program", PROGRAMS)
+# "gdn": a model with Gated DeltaNet layers over a bf16 pool; its pools
+# argument ends with the recurrent-state and conv pools, and it has no verify
+# step (refused at build)
+CASES = [(p, d) for d in ("bf16", "int8", "gdn") for p in PROGRAMS
+         if not (d == "gdn" and p == "verify_step")]
+
+
+@pytest.mark.parametrize("program,kv_dtype", CASES)
 def test_step_programs_alias_every_pool_leaf(program, kv_dtype):
     eng, programs = _programs(kv_dtype)
     assert program in programs, f"harness never hit the {program} path"
@@ -39,7 +45,7 @@ def test_step_programs_alias_every_pool_leaf(program, kv_dtype):
     # the donated buffers ARE the pools' leaves (k, v and, for int8, the two
     # scale planes), and nothing else is donated
     pools = eng._pools()
-    assert len(pools) == (4 if kv_dtype == "int8" else 2)
+    assert len(pools) == (2 if kv_dtype == "bf16" else 4)
     got = sorted((tuple(b.shape), b.dtype) for b in res.buffers)
     assert got == sorted((tuple(p.shape), str(p.dtype)) for p in pools)
 
@@ -50,8 +56,7 @@ def test_split_step_traces_once():
     assert res.ok, res.detail
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("program,kv_dtype", CASES)
 def test_serving_programs_copy_no_pool(program, kv_dtype):
     eng, programs = _programs(kv_dtype)
     fn, args = programs[program]

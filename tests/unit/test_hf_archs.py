@@ -381,6 +381,38 @@ def tiny_olmoe(tmp_path_factory):
     )
 
 
+QWEN3_NEXT_TINY = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+    shared_expert_intermediate_size=48, num_experts=8, num_experts_per_tok=3,
+    norm_topk_prob=True, decoder_sparse_step=1, mlp_only_layers=[],
+    num_hidden_layers=4, full_attention_interval=4, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=32, partial_rotary_factor=0.25,
+    linear_conv_kernel_dim=4, linear_key_head_dim=16, linear_value_head_dim=24,
+    linear_num_key_heads=2, linear_num_value_heads=6,
+    max_position_embeddings=128, tie_word_embeddings=False, output_router_logits=False,
+)
+
+
+@pytest.fixture(scope="module")
+def tiny_qwen3_next(tmp_path_factory):
+    # 3 Gated DeltaNet layers + 1 gated-attention layer, value heads 3 a key
+    # head with a value width unlike the key's (so a q/k/v/z or b/a slice
+    # taken in the wrong order cannot pass), partial rotary, top-3 of 8
+    # renormalised + a shared expert. Norm weights and the DeltaNet's
+    # dt_bias / A_log are moved off their initial values: a (1 + w) norm
+    # read as w, or a plain norm read as (1 + w), would not show at init.
+    torch.manual_seed(0)
+    cfg = transformers.Qwen3NextConfig(**QWEN3_NEXT_TINY)
+    model = transformers.Qwen3NextForCausalLM(cfg).eval()
+    with torch.no_grad():
+        for name, prm in model.named_parameters():
+            if "norm" in name or name.endswith(("dt_bias", "A_log")):
+                prm.add_(0.2 * torch.randn_like(prm))
+    path = tmp_path_factory.mktemp("hf_qwen3_next")
+    model.save_pretrained(path)
+    return model, str(path)
+
+
 @pytest.fixture(scope="module")
 def tiny_bert(tmp_path_factory):
     # post-LN bidirectional encoder + token types + masked-LM head
@@ -498,6 +530,7 @@ _FIXTURES = {
     "qwen3": "tiny_qwen3",
     "qwen3_moe": "tiny_qwen3_moe",
     "olmoe": "tiny_olmoe",
+    "qwen3_next": "tiny_qwen3_next",
 }
 
 # gpt_neo's attn_scale=1.0 skips the 1/sqrt(d) shrink and bert's post-LN
@@ -511,6 +544,8 @@ _ATOL_OVERRIDES = {
     # reduced-precision CPU matmuls perturb the router softmax enough to
     # shift expert mixing weights (exact-precision parity is 7e-7)
     "qwen3_moe": 2e-2,
+    # the same, over eight experts and a sigmoid-gated shared one
+    "qwen3_next": 2e-2,
 }
 
 
@@ -839,6 +874,13 @@ def test_logits_parity(arch, request):
         assert cfg.qk_norm and cfg.qk_norm_kind == "rmsnorm_full"
         assert cfg.n_experts == 8 and cfg.moe_top_k == 2 and cfg.ffn_dim == 48
         assert not cfg.moe_norm_topk_prob and not cfg.tie_embeddings
+    elif arch == "qwen3_next":
+        assert cfg.layer_kinds == ("gdn", "gdn", "gdn", "full") and cfg.hybrid
+        assert cfg.norm == "rmsnorm_1p" and cfg.qk_norm and cfg.attn_out_gate
+        assert cfg.rope_frac == 0.25 and cfg.head_dim == 32 and cfg.kv_layers == 1
+        assert (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim) == (2, 6, 16, 24)
+        assert cfg.n_experts == 8 and cfg.router_width == 8 and cfg.moe_top_k == 3
+        assert cfg.moe_shared_expert_dim == 48 and cfg.ffn_dim == 32
     if cfg.n_experts:
         assert not cfg.moe_drop_tokens  # HF never drops a token
 
@@ -846,7 +888,7 @@ def test_logits_parity(arch, request):
 @pytest.mark.parametrize(
     "arch",
     ["qwen2_moe", "falcon", "phi", "gemma", "bloom", "gptj", "gptneox", "mixtral", "stablelm",
-     "olmoe"],
+     "olmoe", "qwen3_next"],
 )
 def test_greedy_decode_parity(arch, request):
     hf_model, path = request.getfixturevalue(_FIXTURES[arch])
@@ -1035,3 +1077,54 @@ def test_v2_engine_serves_biased_archs(arch, request):
         ).numpy()[0]
     out = engine.generate([prompt], max_new_tokens=6)
     np.testing.assert_array_equal(np.asarray(out[0]), ref)
+
+
+def test_qwen3_next_fused_projections_split_by_use(tiny_qwen3_next):
+    """The extractor's regrouping of in_proj_qkvz / in_proj_ba (laid out by
+    KEY head) and q_proj (a query and a gate a head), against the slices
+    transformers' fix_query_key_value_ordering takes."""
+    hf_model, path = tiny_qwen3_next
+    cfg, params = load_hf_model(path, dtype="float32")
+    lin = hf_model.model.layers[1].linear_attn
+    x = torch.randn(1, 5, 64)
+    with torch.no_grad():
+        q, k, v, z, b, a = lin.fix_query_key_value_ordering(lin.in_proj_qkvz(x), lin.in_proj_ba(x))
+    gdn = {n: np.asarray(w[1]) for n, w in params["layers"]["gdn"].items()}
+    xn = x[0].numpy()
+    want = np.concatenate([t[0].reshape(5, -1).numpy() for t in (q, k, v)], axis=-1)
+    np.testing.assert_allclose(xn @ gdn["gdn_qkv"], want, atol=1e-5)
+    np.testing.assert_allclose(xn @ gdn["gdn_z"], z[0].reshape(5, -1).numpy(), atol=1e-5)
+    np.testing.assert_allclose(
+        xn @ gdn["gdn_ba"], np.concatenate([b[0].numpy(), a[0].numpy()], axis=-1), atol=1e-5)
+    np.testing.assert_allclose(gdn["gdn_conv"].T, lin.conv1d.weight[:, 0].detach().numpy())
+    att = hf_model.model.layers[3].self_attn
+    with torch.no_grad():
+        qg = att.q_proj(x).view(1, 5, 4, 64)
+    full = {n: np.asarray(w[0]) for n, w in params["layers"]["full"].items()}
+    np.testing.assert_allclose(xn @ full["wq"], qg[0, :, :, :32].reshape(5, -1).numpy(), atol=1e-5)
+    np.testing.assert_allclose(xn @ full["wq_gate"], qg[0, :, :, 32:].reshape(5, -1).numpy(), atol=1e-5)
+
+
+def test_qwen3_next_expert_share_loads_its_own_experts(tiny_qwen3_next, tmp_path):
+    """``deployment_share`` in config.json: the router whole, the chip's own
+    experts (share 1 of 4: experts 2-3 of 8), and a share that does not
+    divide the published count refused."""
+    import json
+    import shutil
+
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    hf_model, path = tiny_qwen3_next
+    shutil.copytree(path, tmp_path / "share", dirs_exist_ok=True)
+    hf = json.load(open(tmp_path / "share" / "config.json"))
+    hf.update(num_experts=2, deployment_share={"num_experts": 8, "chips_per_layer": 4, "share_index": 1})
+    json.dump(hf, open(tmp_path / "share" / "config.json", "w"))
+    cfg, params = load_hf_model(str(tmp_path / "share"), dtype="float32")
+    assert (cfg.n_experts, cfg.router_width, cfg.moe_expert_shard) == (2, 8, 1)
+    assert params["layers"]["router"].shape == (4, 64, 8)
+    assert params["layers"]["w_up"].shape == (4, 2, 64, 32)
+    mlp = hf_model.model.layers[2].mlp
+    np.testing.assert_array_equal(
+        np.asarray(params["layers"]["w_down"][2, 1]), mlp.experts[3].down_proj.weight.detach().numpy().T)
+    with pytest.raises(ValueError, match="not one chip's share"):
+        config_from_hf({**hf, "num_experts": 3})

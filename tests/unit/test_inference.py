@@ -406,7 +406,9 @@ class TestInferenceV2:
                 from deepspeed_tpu.parallel.moe import grouped
 
                 tile = grouped.row_tile(4 * cfg.moe_top_k, 4)
-                moe = {"routed": 2 * 2, "computed": 2 * 2 * tile, "hot": 2, "calls": 2}
+                # (one row's two pairs hit two experts a layer; every expert is held)
+                moe = {"routed": 2 * 2, "computed": 2 * 2 * tile, "hot": 2, "calls": 2,
+                       "hit": 2 * 2}
                 assert prefill.moe["routed"] == 20 * 2 * 2
             assert engine.last_step == StepStats(4, 1, 0, 2, 4 * 8, moe)
         elif entry == "decode_round":

@@ -103,3 +103,19 @@ def test_names_are_distinct_trace_safe_identifiers(module, constants):
         # no dot or space: benchmarks/harness/xplane.py short_name() keeps the
         # instruction's name up to its numeric suffix
         assert n.startswith("dstpu_") and n.replace("_", "").isalnum()
+
+
+def test_gdn_decode_is_named_and_updates_the_pool_in_place():
+    """The one-token state update at Qwen3-Next's widths: the custom call
+    carries the name the benchmark's readers look for, and the state pool is
+    aliased to its output (one read and one write of a row's state)."""
+    from deepspeed_tpu.ops.linear_attention import gated_delta
+
+    R, nk, nv, dk, dv, slots = 32, 16, 32, 128, 128, 9 * 33
+    f32 = jnp.float32
+    text = _tpu_text(
+        lambda q, k, v, g, b, pool, s: gated_delta.gdn_decode(q, k, v, g, b, pool, s, impl="kernel"),
+        _s((R, nk, dk), f32), _s((R, nk, dk), f32), _s((R, nv, dv), f32), _s((R, nv), f32),
+        _s((R, nv), f32), _s((slots, nv, dk, dv), f32), _s((R,), jnp.int32))
+    assert _kernel_names(text) == {gated_delta.GDN_DECODE} == {"dstpu_gdn_decode"}
+    assert "output_operand_aliases" in text or "operand_index = 6" in text
