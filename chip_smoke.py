@@ -340,6 +340,35 @@ def kernel_cases(size):
                 paged("dense"), 2e-2,
             ))
 
+    # a split step's prompt chunks at the Qwen3 cells' geometry (16 query / 8
+    # KV heads of 128, tq 512, 32-slot tables of 128-token blocks): a chunk at
+    # 1,024 over 8 pool blocks, a prompt's first 400 tokens, and an empty row
+    from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
+
+    cnh, cnkv, cd, tq, cbs, cB = (4, 2, 32, 32, 16, 8) if TINY else (16, 8, 128, 512, 128, 32)
+    chunks = [(2 * tq, tq), (0, tq - tq // 4), (0, 0)]  # (start, live tokens) a row
+    ctab = np.full((len(chunks), cB), trash, np.int32)
+    cpos = np.full((len(chunks), tq), -1, np.int32)
+    for r, (start, n) in enumerate(chunks):
+        nb = -(-(start + n) // cbs)
+        ctab[r, :nb] = rs.choice(NB, size=nb, replace=False)
+        cpos[r, :n] = start + np.arange(n)
+
+    def chunk(impl):
+        def run(q, kc, vc, tb, qpos, ke, ve, limit):
+            return paged_chunk_attention(q, kc, vc, tb, qpos, trash, new_kv=(ke, ve),
+                                         pool_limit=limit, impl=impl, interpret=interp)
+        return run
+
+    cases.append((
+        "paged chunk, the pool in place",
+        chunk("kernel"),
+        (rnd((len(chunks), tq, cnh, cd)), rnd((NB + 1, cbs, cnkv, cd)), rnd((NB + 1, cbs, cnkv, cd)),
+         jnp.asarray(ctab), jnp.asarray(cpos), rnd((len(chunks), tq, cnkv, cd)),
+         rnd((len(chunks), tq, cnkv, cd)), jnp.asarray([c[0] for c in chunks], jnp.int32)),
+        chunk("dense"), 2e-2,
+    ))
+
     # the one-token Gated DeltaNet update on the state pool in place, at the
     # Qwen3-Next geometry: 32 rows (28 live on scattered slots, the grid's
     # padding on the spare slot with g = beta = 0), 16 key / 32 value heads of

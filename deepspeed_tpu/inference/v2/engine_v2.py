@@ -123,8 +123,8 @@ class StepStats:
     """What one step or round was sized to and what it carried, filled where
     the step is staged (``moe`` after its wait). The serving core folds it
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
-    paged_live_blocks_total / paged_table_slots_total, moe_*_total and
-    gdn_*_total."""
+    paged_live_blocks_total / paged_table_slots_total, chunk_live_blocks_total
+    / chunk_table_slots_total, moe_*_total and gdn_*_total."""
 
     grid_slots: int = 0
     scheduled_tokens: int = 0
@@ -140,6 +140,11 @@ class StepStats:
     # DeltaNet layers: rows whose state took the one-token update, of ONE
     # layer (every such layer sees the same); 0 for a model without them
     gdn_decode_rows: int = 0
+    # chunk attention: key blocks the chunk rows hold (pool blocks below the
+    # chunk's start + the chunk's own) against the slots a walk of whole
+    # tables and whole chunks covers, of one layer (_count_chunk)
+    chunk_live_blocks: int = 0
+    chunk_table_slots: int = 0
 
 
 class InferenceEngineV2:
@@ -1149,6 +1154,19 @@ class InferenceEngineV2:
         return (calls * int((-(-held // kv.block_size)).sum()),
                 calls * len(held) * kv.max_blocks_per_seq)
 
+    def _count_chunk(self, chk_rows, tq: int):
+        """What one layer's chunk attention had to read against what the
+        dense form walks, as ``StepStats``' chunk_live_blocks and
+        chunk_table_slots: a chunk row (uid, tokens, start, chunked) holds
+        ``ceil(start / bs)`` pool blocks below it and ``ceil(n / bs)`` key
+        blocks of its own; the grid's ``Rc`` rows have ``B`` table slots and
+        ``tq / bs`` chunk blocks each. Zeros for a step with no chunk."""
+        kv = self.config.kv_cache
+        bs = kv.block_size
+        live = sum(-(-start // bs) + -(-len(toks) // bs) for _, toks, start, _ in chk_rows)
+        slots = self.scheduler.max_prompt_chunks * (kv.max_blocks_per_seq + -(-tq // bs))
+        return {"chunk_live_blocks": live, "chunk_table_slots": slots if tq else 0}
+
     def _side_buffers(self, *token_dims):
         """What a step's layer loop carries, by name: ``k`` / ``v``, a zeroed
         pair [L, *token_dims, nkv, d] in compute dtype in place of the K/V
@@ -1527,9 +1545,11 @@ class InferenceEngineV2:
         decode rows through _attn_decode (their own new K/V as the
         extra_kv self column), chunk rows through paged_chunk_attention
         (in-chunk causal over the chunk's fresh K/V + pool context below
-        the chunk start). At ``tq == 0`` the grid is the R decode slots
-        alone and the chunk half is absent, not empty: no chunk attention
-        is traced. No read needs this step's K/V from the pool, so the
+        the chunk start; with the kernel impl the flash kernel
+        ``dstpu_paged_chunk``, which walks the blocks a row holds, else
+        the dense gather over whole tables). At ``tq == 0`` the grid is
+        the R decode slots alone and the chunk half is absent, not empty:
+        no chunk attention is traced. No read needs this step's K/V from the pool, so the
         layer only records them in ``carry`` (the side buffers) and the
         pool is written once, after the loop. A DeltaNet layer of the same
         step (``layer_kinds``) goes through ``_gdn_layer`` instead."""
@@ -1568,6 +1588,7 @@ class InferenceEngineV2:
                 new_kv=(k[R:].reshape(Rc, tq, nkv, d), v[R:].reshape(Rc, tq, nkv, d)),
                 pool_limit=meta["chk_start"],
                 k_scale=ks_pool, v_scale=vs_pool,
+                impl=self._attn_impl,
             )
             out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, d)], axis=0)
         x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li, a)
@@ -1970,6 +1991,7 @@ class InferenceEngineV2:
         self.last_step = StepStats(
             T_, total_tokens, prefill, *self._count_paged(dec_pos),
             gdn_decode_rows=len(dec_rows) if self._hybrid else 0,
+            **self._count_chunk(chk_rows, tq),
         )
         inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,

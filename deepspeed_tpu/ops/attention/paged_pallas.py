@@ -26,12 +26,25 @@ TPU for head sizes 64 / 128 / 256 and the dense form elsewhere):
     of a visit comes in through a scalar-prefetched index map; the list is
     computed from ``q_pos`` / ``pool_limit`` / ``window`` by a few small XLA
     fusions in front of the call.
-  * ``dense`` (``paged_decode_attention_dense``) / ``paged_chunk_attention``
-    — plain XLA: gather every slot of every table, then a masked einsum.
-    What runs off the TPU, at ``tp_size > 1`` (GSPMD shards it on the
-    kv-head dim) and for prefill chunks; it reads the whole table whatever
-    the rows hold.
+  * ``dense`` (``paged_decode_attention_dense``) — plain XLA: gather every
+    slot of every table, then a masked einsum. What runs off the TPU and at
+    ``tp_size > 1`` (GSPMD shards it on the kv-head dim); it reads the whole
+    table whatever the rows hold.
   * ``reference`` — the per-token jnp oracle of the tests.
+
+Prompt chunks (``paged_chunk_attention(impl=...)``: Rc rows of tq queries
+that share their row's table; the engine passes the impl it resolved for
+the decode rows):
+  * ``kernel`` — the Pallas flash kernel ``dstpu_paged_chunk``. A program
+    holds a tile of a row's queries, every head, against one block of keys:
+    the pool blocks the row holds below the chunk, read in place off its
+    table, then the chunk's own K/V, causal by tiles (``_chunk_visit_list``).
+    Scores, softmax state and the accumulator never leave VMEM; an empty
+    row and a chunk's padded tail cost a program that writes zeros.
+  * ``dense`` — the gather of whole tables and a masked softmax over
+    ``B x bs + tq`` columns a query. Off the TPU, at ``tp_size > 1``, in the
+    verify step's dense branch, and for what ``chunk_kernel_takes`` leaves
+    (an int8 pool, a head of 64, blocks that do not fill Mosaic's tiles).
 """
 
 import functools
@@ -49,6 +62,7 @@ NEG_INF = -1e30
 # The kernels' names in a device trace (``pallas_call(name=...)`` names the Mosaic
 # custom call); metadata only. benchmarks/metrics readers find them by these.
 PAGED_DECODE = "dstpu_paged_decode"
+PAGED_CHUNK = "dstpu_paged_chunk"
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables, q_pos, trash_block,
@@ -393,17 +407,28 @@ def _visit_list(q_pos, limit, bs: int, B: int, window: int):
     hi = jnp.clip((limit + bs - 1) // bs, 0, B)
     lo = jnp.maximum(q_pos - window + 1, 0) // bs if window else jnp.zeros_like(hi)
     n = jnp.maximum(hi - lo, 0)
-    ends = jnp.cumsum(n + 1)
-    starts = ends - (n + 1)
-    g = jnp.arange(T * (B + 1), dtype=jnp.int32)
-    # program g is row t's iff starts[t] <= g < ends[t]; a [G, T] compare and
-    # a sum stand in for the gathers, which the TPU runs an element at a time
-    mine = (g[:, None] >= starts[None]) & (g[:, None] < ends[None])
-    of_row = lambda x: jnp.sum(jnp.where(mine, x[None], 0), axis=1)
-    j, n_g, lo_g = g - of_row(starts), of_row(n), of_row(lo)
+    n_visits, j, of_row = _programs_of(n + 1, T * (B + 1))
+    n_g, lo_g = of_row(n), of_row(lo)
     last = jnp.minimum(lo_g + jnp.maximum(n_g - 1, 0), B - 1)
     vslot = jnp.where(j < n_g, lo_g + j, B + last)
-    return ends[-1], of_row(jnp.arange(T, dtype=jnp.int32)), vslot
+    return n_visits, of_row(jnp.arange(T, dtype=jnp.int32)), vslot
+
+
+def _programs_of(cnt, G: int):
+    """A flat axis of programs from how many each owner has (``cnt`` [T], an
+    owner's programs following one another). Returns (the number of
+    programs, ``j`` [G]: a program's ordinal among its owner's, ``of``: a
+    function that takes a [T] array of the owners to [G], each program
+    reading its owner's entry). Program g is owner t's iff ``starts[t] <= g
+    < ends[t]``; a [G, T] compare and a sum stand in for the gathers, which
+    the TPU runs an element at a time. Entries past the number of programs
+    read 0."""
+    ends = jnp.cumsum(cnt)
+    starts = ends - cnt
+    g = jnp.arange(G, dtype=jnp.int32)
+    mine = (g[:, None] >= starts[None]) & (g[:, None] < ends[None])
+    of = lambda x: jnp.sum(jnp.where(mine, x[None], 0), axis=1)
+    return ends[-1], g - of(starts), of
 
 
 def paged_decode_attention_dense(
@@ -501,6 +526,233 @@ def paged_decode_attention_dense(
     return out.reshape(R, nh, d).astype(q.dtype)
 
 
+def chunk_kernel_takes(q_shape, pool_shape, pool_dtype, split_form: bool, interpret: bool) -> bool:
+    """Whether ``dstpu_paged_chunk`` serves a call, from its shapes alone. It
+    serves the split step's form (the chunk's own K/V beside the pool, read
+    below ``pool_limit``) with the chunk a whole number of pool blocks. On
+    the chip the blocks must fill Mosaic's tiles as well: 128 keys a block, a
+    head of 128 or 256, and 2, 4 or a multiple of 8 KV heads: where the
+    compiler for the chip reads a ``[bs, nkv, d]`` block of the pool in place
+    (at 6 heads, at one, at a head of 64 it copies the whole pool into
+    another layout first: described v5e, PR 30). An int8 pool (its scale
+    planes), such geometries and the form whose pool already holds the chunk
+    stay on the dense form."""
+    _, tq, nh, d = q_shape
+    bs, nkv = pool_shape[1], pool_shape[2]
+    if not split_form or jnp.dtype(pool_dtype) == jnp.int8 or tq % bs or nh % nkv:
+        return False
+    return interpret or (bs % 128 == 0 and d % 128 == 0 and (nkv in (2, 4) or nkv % 8 == 0))
+
+
+def _chunk_tile(tq: int, bs: int, nh: int, d: int) -> int:
+    """Queries a program of ``dstpu_paged_chunk`` holds: up to 256, as many
+    blocks of the chunk as keep the float32 accumulator ``[tile x nh, d]``
+    within 4 MiB (256 rows of 16 heads of 256), at least one block. A pool
+    block is then read once for 256 queries; tiles of 128 ran 10-30% longer
+    at the cells' geometries, 512 no shorter (my chip run, PR 30)."""
+    tile = bs
+    while tile * 2 <= 256 and tq % (tile * 2) == 0 and tile * 2 * nh * d * 4 <= 4 << 20:
+        tile *= 2
+    return tile
+
+
+def _chunk_visit_list(n, q0, limit, bs: int, B: int, tq: int, tile: int, window: int):
+    """The programs of one ``dstpu_paged_chunk`` call. A UNIT is one tile of
+    one row's queries (``tq / tile`` a row); its programs are the pool slots
+    its row holds below ``limit`` (``lo..hi``, ``lo`` the block of the first
+    key a sliding ``window`` admits to the tile's first query), then the
+    chunk's own key blocks at or below the tile's last live query, the last
+    of them also the finish. A tile with no live query (``n`` live queries a
+    row, at consecutive positions from ``q0``) is one program that emits
+    zeros. Returns (the number of programs, then five [G] arrays): the row,
+    the tile, the table slot the pool's index map points at (for a chunk
+    visit: where the row's walk ended, so nothing is fetched), the chunk key
+    block the side values' index map points at (for a pool visit: the first
+    the unit will need), and flags: 1 a pool visit, 2 the unit's first
+    program, 4 its last. Entries past the number of programs never run."""
+    Rc = n.shape[0]
+    nqt, nkt = tq // tile, tq // bs
+    i0 = jnp.tile(jnp.arange(nqt, dtype=jnp.int32) * tile, Rc)  # [U], U = Rc * nqt
+    row = jnp.repeat(jnp.arange(Rc, dtype=jnp.int32), nqt)
+    n_u, q0_u, lim_u = n[row], q0[row], limit[row]
+    rows = jnp.clip(n_u - i0, 0, tile)
+    hi = jnp.clip((lim_u + bs - 1) // bs, 0, B)
+    lo = jnp.maximum(q0_u + i0 - window + 1, 0) // bs if window else jnp.zeros_like(hi)
+    n_pool = jnp.where(rows > 0, jnp.maximum(hi - lo, 0), 0)
+    khi = jnp.minimum((i0 + rows - 1) // bs + 1, nkt)
+    klo = jnp.maximum(i0 - window + 1, 0) // bs if window else jnp.zeros_like(khi)
+    n_chunk = jnp.where(rows > 0, khi - klo, 1)
+    cnt = n_pool + n_chunk
+    n_visits, j, of_unit = _programs_of(cnt, Rc * nqt * (B + nkt))
+    np_g, cnt_g, lo_g, klo_g = of_unit(n_pool), of_unit(cnt), of_unit(lo), of_unit(klo)
+    is_pool = j < np_g
+    # a chunk visit keeps the pool's window where the unit's walk ended
+    vpool = jnp.clip(jnp.where(is_pool, lo_g + j, lo_g + np_g - 1), 0, B - 1)
+    vkt = jnp.clip(jnp.where(is_pool, klo_g, klo_g + j - np_g), 0, nkt - 1)
+    flags = is_pool + 2 * (j == 0) + 4 * (j == cnt_g - 1)
+    return n_visits, of_unit(row), of_unit(i0) // tile, vpool, vkt, flags.astype(jnp.int32)
+
+
+def _chunk_kernel(*refs, bs, tile, nh, nkv, d, window):
+    """One program of ``dstpu_paged_chunk``: a tile of one row's queries
+    against one block of keys, flash state in scratch across the unit's
+    programs (``_chunk_visit_list``). ``refs`` — scalar prefetch (SMEM): bt
+    [Rc, B], n / q0 / limit [Rc], trash [1], vrow / vqt / vpool / vkt / vflag
+    [G] — then tensor blocks (VMEM): q (1, nh, tile, d) head-major and
+    scaled, the pool's k / v (1, bs, nkv, d), the chunk's own ke / ve
+    (1, bs, nkv, d) — then o (1, nh, tile, d) and the m / l / acc scratch
+    [nkv, M, .], a KV head's M rows its query heads' tiles. Operands enter
+    the MXU in the queries' dtype; scores, softmax state and the accumulator
+    are float32 and stay here."""
+    (bt_ref, n_ref, q0_ref, limit_ref, trash_ref, vrow_ref, vqt_ref, vpool_ref, vkt_ref,
+     vflag_ref, q_ref, k_ref, v_ref, ke_ref, ve_ref, o_ref, m_scr, l_scr, acc_scr) = refs
+    g = pl.program_id(0)
+    r, flag = vrow_ref[g], vflag_ref[g]
+    i0 = vqt_ref[g] * tile
+    group = nh // nkv
+    M = group * tile  # score rows of one KV head: its query heads, tile rows each
+    n, q0, limit = n_ref[r], q0_ref[r], limit_ref[r]
+    rows = jnp.clip(n - i0, 0, tile)  # live queries of the tile
+
+    @pl.when((flag & 2) != 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # One body serves both kinds of visit: which block of keys it reads and
+    # what a query may see of it are scalars. ``base``: the position of the
+    # block's first key. ``bound``: keys at or past it are not the row's (the
+    # pool's limit; the chunk's live tokens: a row's live queries are its
+    # first n, at consecutive positions from q0). ``causal``: a chunk's key is
+    # seen from its own position on, a pool's by every query
+    is_pool = (flag & 1) != 0
+    slot, kt = vpool_ref[g], vkt_ref[g]
+    held = bt_ref[r, slot] != trash_ref[0]
+    base = jnp.where(is_pool, slot * bs, q0 + kt * bs)
+    bound = jnp.where(is_pool, jnp.where(held, limit, 0), q0 + n)
+    causal = jnp.logical_not(is_pool)
+    first_pos, last_pos = q0 + i0, q0 + i0 + rows - 1  # the tile's live queries
+    # no key of the block is masked for any live query of the tile
+    whole = (base + bs <= bound) & (is_pool | (base + bs - 1 <= first_pos))
+    if window:
+        from deepspeed_tpu.ops.attention.core import window_too_far
+
+        whole = whole & (last_pos - base < window)
+
+    def visit(masked):
+        """Fold the block into the flash state, every KV head in one batched
+        product, [nkv, M, d] x [nkv, bs, d] (an operation a head is traced
+        and lowered a head, 1.4 s a step shape at 16 KV heads, and ran 15-25%
+        longer: my chip runs, PR 30). ``masked``: scores of keys a query may
+        not see go to NEG_INF, and the values of keys NO query of the tile
+        may see to 0: a masked key's weight is exactly 0, and 0 x NaN is not."""
+        qa = q_ref[0].reshape(nkv, M, d)
+        # [bs, nkv, d] from where the keys live, then head-major [nkv, bs, d]
+        ka = jnp.swapaxes(jnp.where(is_pool, k_ref[0], ke_ref[0]), 0, 1).astype(qa.dtype)
+        va = jnp.swapaxes(jnp.where(is_pool, v_ref[0], ve_ref[0]), 0, 1).astype(qa.dtype)
+        if masked:
+            q_pos = q0 + i0 + (jax.lax.broadcasted_iota(jnp.int32, (1, M, bs), 1) & (tile - 1))
+            k_pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, M, bs), 2)
+            valid = (k_pos < bound) & (jnp.logical_not(causal) | (k_pos <= q_pos))
+            key_pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1)
+            key_ok = key_pos < bound
+            if window:
+                valid = valid & jnp.logical_not(window_too_far(q_pos, k_pos, window))
+                # the tile's first query reaches furthest back
+                key_ok = key_ok & jnp.logical_not(window_too_far(first_pos, key_pos, window))
+            va = jnp.where(key_ok, va, jnp.zeros_like(va))
+        s = jax.lax.dot_general(
+            qa, ka, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32)  # [nkv, M, bs]
+        if masked:
+            s = jnp.where(valid, s, NEG_INF)
+        m_p = m_scr[:, :, :1]
+        m_new = jnp.maximum(m_p, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_p - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(qa.dtype), va, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_scr[:, :, :1] = m_new
+
+    # a tile with no live query is one program: nothing to fold, zeros out
+    pl.when((rows > 0) & whole)(lambda: visit(masked=False))
+    pl.when((rows > 0) & jnp.logical_not(whole))(lambda: visit(masked=True))
+
+    @pl.when((flag & 4) != 0)
+    def _finish():
+        # a padded query, and one no key reached (m never left NEG_INF), emit 0
+        i = i0 + (jax.lax.broadcasted_iota(jnp.int32, (1, M, 1), 1) & (tile - 1))
+        live = (i < n) & (m_scr[:, :, :1] > NEG_INF * 0.5)
+        out = jnp.where(live, acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30), 0.0)
+        o_ref[0] = out.reshape(nh, tile, d).astype(o_ref.dtype)
+
+
+def _paged_chunk_kernel_call(q, k_cache, v_cache, row_tables, q_pos, trash_block, new_kv,
+                             pool_limit, *, window, scale, interpret, tile):
+    """``paged_chunk_attention`` through ``dstpu_paged_chunk``: the pool read
+    in place, a ``[bs, nkv, d]`` block a visit off the row's table, the
+    chunk's own K/V from ``new_kv`` in blocks of the same shape, the walk
+    bounded by what the row holds and causal by tiles. The queries go in
+    head-major and scaled and the output comes back head-major: two small
+    transposes XLA fuses into their neighbours."""
+    Rc, tq, nh, d = q.shape
+    NB, bs, nkv, _ = k_cache.shape
+    B = row_tables.shape[1]
+    tile = int(tile) if tile else _chunk_tile(tq, bs, nh, d)
+    if tq % tile or tile % bs or tile & (tile - 1):
+        raise ValueError(f"paged_chunk_attention: tile {tile} for tq {tq}, block size {bs}")
+    q_pos = q_pos.astype(jnp.int32)
+    n = jnp.sum(q_pos >= 0, axis=1, dtype=jnp.int32)
+    q0 = jnp.maximum(q_pos[:, 0], 0)
+    limit = jnp.where(n > 0, jnp.asarray(pool_limit, jnp.int32).reshape(Rc), 0)
+    n_visits, vrow, vqt, vpool, vkt, vflag = _chunk_visit_list(
+        n, q0, limit, bs, B, tq, tile, window)
+    qs = (q.astype(jnp.float32) * (scale if scale is not None else d**-0.5)).astype(q.dtype)
+    ke, ve = new_kv
+
+    # index maps see (g, bt, n, q0, limit, trash, vrow, vqt, vpool, vkt, vflag)
+    q_spec = pl.BlockSpec((1, nh, tile, d), lambda g, *s: (s[5][g], 0, s[6][g], 0))
+    pool_spec = pl.BlockSpec(
+        (1, bs, nkv, d), lambda g, *s: (s[0][s[5][g], s[7][g]], 0, 0, 0))
+    # [Rc * tq / bs, bs, nkv, d]: the chunk's own K/V as blocks of the pool's shape
+    side_spec = pl.BlockSpec(
+        (1, bs, nkv, d), lambda g, *s: (s[5][g] * (tq // bs) + s[8][g], 0, 0, 0))
+    M = nh // nkv * tile
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, bs=bs, tile=tile, nh=nh, nkv=nkv, d=d, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=10,
+            grid=(n_visits,),
+            in_specs=[q_spec, pool_spec, pool_spec, side_spec, side_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((nkv, M, 128), jnp.float32),
+                pltpu.VMEM((nkv, M, 128), jnp.float32),
+                pltpu.VMEM((nkv, M, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((Rc, nh, tq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # one flat axis of visits: a unit's programs follow one another
+            # and accumulate into the same scratch
+            dimension_semantics=("arbitrary",),
+            # the flash state of 256 queries x 16 heads of 256 is 8 MiB, the
+            # query and output tiles twice 2 MiB each: over the 16 MiB default
+            vmem_limit_bytes=64 << 20,
+        ),
+        interpret=interpret,
+        name=PAGED_CHUNK,
+    )(
+        row_tables.astype(jnp.int32), n, q0, limit,
+        jnp.asarray(trash_block, jnp.int32).reshape(1), vrow, vqt, vpool, vkt, vflag,
+        qs.transpose(0, 2, 1, 3), k_cache, v_cache,
+        ke.reshape(Rc * tq // bs, bs, nkv, d), ve.reshape(Rc * tq // bs, bs, nkv, d),
+    )
+    return out.transpose(0, 2, 1, 3)
+
+
 def paged_chunk_attention(
     q: jax.Array,
     k_cache: jax.Array,
@@ -514,14 +766,24 @@ def paged_chunk_attention(
     pool_limit=None,
     k_scale=None,
     v_scale=None,
+    impl: str = "dense",
+    interpret: bool = False,
+    tile: Optional[int] = None,
 ) -> jax.Array:
     """Prefill-chunk attention: Rc rows x tq new tokens each, every row's
     tokens sharing that ROW's block table (q [Rc, tq, nh, d],
     row_tables [Rc, B], q_pos [Rc, tq] global positions, -1 = padding).
-    One context gather per ROW (not per token: the decode kernel would
-    walk the row's context once for every token of the chunk) then a dense
-    masked softmax; chunk MXU work is real matmuls. Padded tail tokens
-    (q_pos < 0) emit exactly 0.
+    Padded tail tokens (q_pos < 0) emit exactly 0.
+
+    ``impl="kernel"``: the Pallas flash kernel ``dstpu_paged_chunk``
+    (``_paged_chunk_kernel_call``) where ``chunk_kernel_takes`` the call,
+    the dense form otherwise. It takes a row's live queries to be its first
+    n, at consecutive positions (what ``_stage_split`` makes; the dense form
+    reads every ``q_pos`` for itself); ``tile`` overrides its query tile
+    (tests: several tiles at small sizes).
+    ``impl="dense"``: one context gather per ROW (not per token: the decode
+    kernel would walk the row's context once for every token of the chunk)
+    then a dense masked softmax over the whole table.
 
     ``new_kv`` = (ke [Rc, tq, nkv, d], ve): THIS chunk's not-yet-cached
     K/V — in-chunk attention runs causally over them while the pool covers
@@ -533,6 +795,15 @@ def paged_chunk_attention(
     Rc, tq, nh, d = q.shape
     NB, bs, nkv, _ = k_cache.shape
     B = row_tables.shape[1]
+    if impl not in ("dense", "kernel"):
+        raise ValueError(f"paged_chunk_attention: unknown impl {impl!r} (expected 'dense' or 'kernel')")
+    interpret = bool(interpret) or not on_tpu()
+    if impl == "kernel" and chunk_kernel_takes(
+            q.shape, k_cache.shape, k_cache.dtype, new_kv is not None and pool_limit is not None,
+            interpret):
+        return _paged_chunk_kernel_call(
+            q, k_cache, v_cache, row_tables, q_pos, trash_block, new_kv, pool_limit,
+            window=int(window), scale=scale, interpret=interpret, tile=tile)
     S = B * bs
     group = nh // nkv
     k_ctx = (

@@ -193,6 +193,8 @@ class EngineCore:
             self._inc("steps_with_prefill_total")
         self._inc("paged_live_blocks_total", held("paged_live_blocks"))
         self._inc("paged_table_slots_total", held("paged_table_slots"))
+        self._inc("chunk_live_blocks_total", held("chunk_live_blocks"))
+        self._inc("chunk_table_slots_total", held("chunk_table_slots"))
         moe = getattr(stats, "moe", None)
         if moe:  # an expert model: what its expert layers routed and computed
             self._inc("moe_routed_rows_total", moe["routed"])

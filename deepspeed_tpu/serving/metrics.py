@@ -129,6 +129,11 @@ class ServingMetrics:
             # one layer's kernel calls of every step or round
             "paged_live_blocks_total": 0,
             "paged_table_slots_total": 0,
+            # chunk attention (EngineCore._count_step): key blocks the chunk
+            # rows hold (pool blocks below a chunk + the chunk's own) against
+            # the slots of whole tables and whole chunks, one layer a step
+            "chunk_live_blocks_total": 0,
+            "chunk_table_slots_total": 0,
             # expert models (EngineCore._count_step, from the [L, E] routed
             # rows a step returns): live (token, expert) pairs, rows the
             # expert matmuls covered, the fullest expert's rows summed over

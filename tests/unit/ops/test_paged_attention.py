@@ -614,3 +614,218 @@ def test_visit_list_against_a_hand_count(window):
     assert list(zip(np.asarray(vrow)[: len(want)].tolist(),
                     np.asarray(vslot)[: len(want)].tolist())) == want
     assert vrow.shape == vslot.shape == (len(qpos) * (B + 1),)
+
+
+# ---------------------------------------------------------------------------
+# dstpu_paged_chunk: a chunk's queries by tiles against the blocks its row
+# holds and the chunk's own K/V, against the dense form on the same call
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.ops.attention.paged_pallas import _chunk_visit_list, chunk_kernel_takes
+
+CHUNK_BS, CHUNK_B = 16, 8
+
+
+def _chunk_call(rng, nh, nkv, d, tq, rows, int8=False):
+    """Chunk rows ``(start, n)``: ``n`` live queries at positions from
+    ``start`` over a pool that holds the ``start`` tokens below them, every
+    row on blocks of its own; ``n == 0`` is an empty row (an all-trash
+    table). Returns (positional arguments, keyword arguments, tables)."""
+    bs, B = CHUNK_BS, CHUNK_B
+    Rc = len(rows)
+    NB = Rc * B + 1
+    trash = NB - 1
+    bt = np.full((Rc, B), trash, np.int32)
+    qpos = np.full((Rc, tq), -1, np.int32)
+    for r, (start, n) in enumerate(rows):
+        if n:
+            nb = -(-(start + n) // bs)  # the blocks its own tokens land in too
+            bt[r, :nb] = r * B + np.arange(nb)
+            qpos[r, :n] = start + np.arange(n)
+    q = jnp.asarray(rng.normal(size=(Rc, tq, nh, d)), jnp.float32)
+    kc, vc, kq, ks, vq, vs = _quantized_pool(rng, NB, bs, nkv, d)
+    pools, kw = ((kq, vq), dict(k_scale=ks, v_scale=vs)) if int8 else ((kc, vc), {})
+    kw.update(new_kv=(jnp.asarray(rng.normal(size=(Rc, tq, nkv, d)), jnp.float32),
+                      jnp.asarray(rng.normal(size=(Rc, tq, nkv, d)), jnp.float32)),
+              pool_limit=jnp.asarray(np.array([s for s, _ in rows], np.int32)))
+    return (q, *pools, jnp.asarray(bt), jnp.asarray(qpos), trash), kw, bt
+
+
+def _chunk_rows(tq):
+    """In ONE call: a prompt's first chunk (an empty pool), a chunk that
+    starts inside a block and has a padded tail, one over several whole
+    blocks, one that fills its table, and an empty row."""
+    S = CHUNK_BS * CHUNK_B
+    return [(0, tq), (5, tq - 3), (3 * CHUNK_BS, tq - CHUNK_BS // 2), (S - tq, tq), (0, 0)]
+
+
+# the serving cells' geometries: Qwen3, OLMoE, Qwen3-Next's attention layers
+@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (16, 2, 256)])
+@pytest.mark.parametrize("tq,tile", [(16, 16), (64, 16), (64, 32)])  # one query tile, four, two
+def test_chunk_kernel_matches_dense(nh, nkv, d, tq, tile):
+    rng = np.random.default_rng(30)
+    rows = _chunk_rows(tq)
+    args, kw, _ = _chunk_call(rng, nh, nkv, d, tq, rows)
+    ref = paged_chunk_attention(*args, **kw)
+    out = paged_chunk_attention(*args, impl="kernel", interpret=True, tile=tile, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for r, (_, n) in enumerate(rows):  # a padded tail and an empty row: zeros, as the dense form's
+        np.testing.assert_array_equal(np.asarray(out[r, n:]), 0.0)
+
+
+@pytest.mark.parametrize("nkv", [2, 4])
+def test_chunk_kernel_bf16_operands(nkv):
+    """bf16 as the cells hold it: the products take bf16 operands, scores and
+    softmax state are float32. Against the dense form, which gathers in
+    float32: the error is bf16's rounding of q x scale and of the weights."""
+    rng = np.random.default_rng(34)
+    args, kw, _ = _chunk_call(rng, 8, nkv, 64, 32, _chunk_rows(32))
+    bf = lambda a: a.astype(jnp.bfloat16)
+    args = (bf(args[0]), bf(args[1]), bf(args[2])) + args[3:]
+    kw["new_kv"] = tuple(bf(a) for a in kw["new_kv"])
+    ref = paged_chunk_attention(*args, **kw)
+    out = paged_chunk_attention(*args, impl="kernel", interpret=True, tile=16, **kw)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=3e-2)
+
+
+@pytest.mark.parametrize("kw", [{"window": 12}, {"window": 40}, {"scale": 1.0},
+                                {"window": 24, "scale": 0.5}])
+def test_chunk_kernel_window_and_scale(kw):
+    """A window shorter than a tile, one that reaches into the pool past its
+    first block, and the softmax scale override."""
+    rng = np.random.default_rng(31)
+    args, call_kw, _ = _chunk_call(rng, 4, 2, 64, 64, _chunk_rows(64))
+    ref = paged_chunk_attention(*args, **call_kw, **kw)
+    out = paged_chunk_attention(*args, impl="kernel", interpret=True, tile=32, **call_kw, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["int8 pool", "chunk already in the pool"])
+def test_chunk_kernel_leaves_to_the_dense_form(case):
+    """What ``chunk_kernel_takes`` does not: an int8 pool and the form without
+    ``new_kv``. ``impl="kernel"`` then IS the dense form, bit for bit."""
+    rng = np.random.default_rng(32)
+    args, kw, _ = _chunk_call(rng, 4, 2, 64, 32, _chunk_rows(32), int8=case.startswith("int8"))
+    if not case.startswith("int8"):
+        kw = {}
+    assert not chunk_kernel_takes(args[0].shape, args[1].shape, args[1].dtype,
+                                  "new_kv" in kw, True)
+    ref = paged_chunk_attention(*args, **kw)
+    out = paged_chunk_attention(*args, impl="kernel", interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    with pytest.raises(ValueError, match="unknown impl"):
+        paged_chunk_attention(*args, impl="flash", **kw)
+
+
+@pytest.mark.parametrize("nh,nkv,d,bs,takes", [
+    (16, 8, 128, 128, True), (16, 16, 128, 128, True), (16, 2, 256, 128, True),  # the cells'
+    (8, 4, 128, 128, True),
+    (18, 6, 128, 128, False),   # 6 KV heads in a tile of 8: the pool is copied
+    (8, 1, 128, 128, False),    # one head of a bf16 pair: the pool is copied
+    (16, 8, 64, 128, False),    # a head of 64, half a lane tile: the pool is copied
+    (16, 8, 128, 16, False),    # 16 keys a block
+])
+def test_chunk_kernel_takes_what_reads_the_pool_in_place(nh, nkv, d, bs, takes):
+    """The geometries at which the described v5e's compiler reads the pool in
+    place and those at which it copies it first (PR 30), as the rule the
+    dispatch reads; interpreted, every geometry runs."""
+    shapes = ((2, 512, nh, d), (64, bs, nkv, d), jnp.bfloat16, True)
+    assert chunk_kernel_takes(*shapes, False) == takes
+    assert chunk_kernel_takes(*shapes, True)
+    assert not chunk_kernel_takes((2, 520, nh, d), *shapes[1:], True)  # not whole blocks
+
+
+@pytest.mark.parametrize("row", range(5))
+def test_chunk_kernel_ignores_what_a_row_does_not_hold(row):
+    """NaN in every pool block the row does not hold below its chunk, in the
+    trash block, and in its last block from the chunk's start on: the row's
+    output is finite and equal to the clean pool's, bit for bit."""
+    rng = np.random.default_rng(33)
+    tq, bs = 32, CHUNK_BS
+    rows = _chunk_rows(tq)
+    (q, kc, vc, bt, qpos, trash), kw, tables = _chunk_call(rng, 8, 4, 64, tq, rows)
+    start = rows[row][0]
+
+    def poisoned(pool):
+        bad = np.full(pool.shape, np.nan, np.float32)
+        for j in range(-(-start // bs)):  # the blocks below the chunk
+            keep = min(bs, start - j * bs)
+            bad[tables[row, j], :keep] = np.asarray(pool)[tables[row, j], :keep]
+        return jnp.asarray(bad)
+
+    run = lambda k, v: np.asarray(paged_chunk_attention(
+        q, k, v, bt, qpos, trash, impl="kernel", interpret=True, tile=16, **kw))[row]
+    clean, dirty = run(kc, vc), run(poisoned(kc), poisoned(vc))
+    assert np.isfinite(dirty).all()
+    np.testing.assert_array_equal(dirty, clean)
+
+
+@pytest.mark.parametrize("window", [0, 20])
+def test_chunk_visit_list_against_a_hand_count(window):
+    """The kernel's programs from the bounds alone: for each tile of each
+    row the pool slots lo..hi, then the chunk's key blocks up to the tile's
+    last live query; a tile with no live query is one program."""
+    bs, B, tq, tile = 16, 8, 64, 32
+    n = np.array([64, 40, 0, 7], np.int32)          # live queries a row
+    start = np.array([0, 37, 0, 96], np.int32)      # = the pool's limit
+    got = _chunk_visit_list(jnp.asarray(n), jnp.asarray(start), jnp.asarray(start),
+                            bs, B, tq, tile, window)
+    want = []  # (row, tile, pool slot or None, chunk block or None)
+    for r in range(len(n)):
+        for t in range(tq // tile):
+            i0 = t * tile
+            live = min(max(int(n[r]) - i0, 0), tile)
+            if not live:
+                want.append((r, t, None, None))
+                continue
+            lo = max(int(start[r]) + i0 - window + 1, 0) // bs if window else 0
+            want += [(r, t, s, None) for s in range(lo, -(-int(start[r]) // bs))]
+            klo = max(i0 - window + 1, 0) // bs if window else 0
+            want += [(r, t, None, k) for k in range(klo, (i0 + live - 1) // bs + 1)]
+    count, vrow, vqt, vpool, vkt, vflag = (np.asarray(a) for a in got)
+    assert int(count) == len(want)
+    # no window. Row 0, an empty pool: its tiles see 2 and 4 chunk blocks. Row
+    # 1, 3 pool blocks a tile: + 2 chunk blocks, + 3 (8 live queries in the
+    # second tile). The empty row: a program a tile. Row 3: 6 pool blocks + 1,
+    # then a tile with no live query
+    if not window:
+        assert len(want) == (2 + 4) + (3 + 2 + 3 + 3) + 2 + (6 + 1 + 1)
+    for g, (r, t, slot, k) in enumerate(want):
+        assert (vrow[g], vqt[g], bool(vflag[g] & 1)) == (r, t, slot is not None)
+        if slot is not None:
+            assert vpool[g] == slot
+        elif k is not None:
+            assert vkt[g] == k
+        first = g == 0 or want[g - 1][:2] != (r, t)
+        last = g == len(want) - 1 or want[g + 1][:2] != (r, t)
+        assert (bool(vflag[g] & 2), bool(vflag[g] & 4)) == (first, last)
+    assert vrow.shape == (len(n) * (tq // tile) * (B + tq // bs),)
+
+
+def test_chunked_prefill_through_the_kernel_equals_the_dense_form():
+    """The engine's split step with ``paged_attention_impl="kernel"`` (both
+    kernels interpreted on the CPU): a prompt of three chunks beside a short
+    one streams the tokens of the dense form. The later chunks read the
+    pool blocks the earlier ones wrote."""
+    from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.models import TransformerConfig, init_params
+
+    mc = TransformerConfig(vocab_size=128, hidden_size=128, n_layers=2, n_heads=2,
+                           n_kv_heads=1, max_seq_len=512, dtype="float32")
+    params = init_params(mc, jax.random.key(3))
+
+    def tokens(impl):
+        rc = RaggedInferenceEngineConfig.from_dict({
+            "dtype": "float32", "paged_attention_impl": impl, "prompt_chunk": 128,
+            "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 24},
+            "state_manager": {"max_tracked_sequences": 4, "max_ragged_batch_size": 256,
+                              "max_ragged_sequence_count": 4, "max_context": 384},
+        })
+        eng = InferenceEngineV2(mc, params, rc)
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(1, 128, size=(n,)).astype(np.int32) for n in (300, 40)]
+        return eng.generate(prompts, max_new_tokens=4)
+
+    for a, b in zip(tokens("dense"), tokens("kernel")):
+        np.testing.assert_array_equal(a, b)

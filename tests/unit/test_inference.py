@@ -393,7 +393,10 @@ class TestInferenceV2:
         engine.scheduler.submit(0, np.arange(1, 21, dtype=np.int32))
         toks = engine.step_tokens()  # the prompt's 20 tokens: no decode row yet
         prefill = engine.last_step
-        assert dataclasses.replace(prefill, moe=None) == StepStats(4 + 64, 20, 20, 0, 4 * 8)
+        # ... and its chunk attention: no pool block below the chunk + 2 of
+        # its own, of the 8 table slots + 4 chunk blocks a dense walk covers
+        assert dataclasses.replace(prefill, moe=None) == StepStats(
+            4 + 64, 20, 20, 0, 4 * 8, chunk_live_blocks=2, chunk_table_slots=8 + 4)
         engine.scheduler.feedback(0, toks[0])
         if entry.startswith("step_tokens"):
             engine.scheduler.feedback(0, engine.step_tokens()[0])

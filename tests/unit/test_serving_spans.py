@@ -198,6 +198,18 @@ class TestGridCounters:
         assert f"paged_table_slots_total {slots}" in driver.metrics.prometheus_text()
 
 
+    def test_chunk_counters_are_held_blocks_over_whole_walks(self, tiny_model):
+        """The first test's three steps, Rc = 1, blocks of 16, B = 32. A chunk
+        of 200 at position 0 in the 512 bucket: 0 pool blocks + 13 of its own
+        over 32 + 32 slots; one of 20 in the 128 bucket: 2 over 32 + 8; the
+        step with no chunk counts nothing."""
+        driver, _ = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
+        c = driver.metrics.counters
+        assert c["chunk_live_blocks_total"] == 13 + 2
+        assert c["chunk_table_slots_total"] == (32 + 32) + (32 + 8)
+        assert "chunk_table_slots_total 104" in driver.metrics.prometheus_text()
+
+
 class TestOnePath:
     def test_off_path_is_the_on_path_with_the_shared_null_span(self, tiny_model, monkeypatch):
         """Traced or not, a step runs the same statements: the spans are
