@@ -385,6 +385,53 @@ def kernel_cases(size):
     def gdn(impl):
         return lambda *a: gdn_decode(*a, impl=impl)
 
+    # a mixed stack's WINDOW layer at the K-EXAONE geometry (64 query / 8 KV
+    # heads of 128, window 128 over blocks of 128: rings of 2): both paged
+    # kernels walk ring tables (logical block j of slot s is ring block
+    # 2 s + j % 2) bounded by the window, rows deep into their contexts, the
+    # rings wrapped many times; the grid's padding sits on the spare ring
+    wnh, wnkv, wd, win, wbs, wB, wtq, WS = (
+        (4, 2, 32, 16, 16, 8, 32, 5) if TINY else (64, 8, 128, 128, 128, 80, 512, 33))
+    wb = -(-(win - 1) // wbs) + 1
+    wpool = lambda: rnd((WS * wb, wbs, wnkv, wd))  # noqa: E731
+    ring = lambda slots: slots[:, None] * wb + (jnp.arange(wB, dtype=jnp.int32) % wb)[None]  # noqa: E731
+    wtrash = (WS - 1) * wb
+    Rw = 4 if TINY else 32
+    wslots = jnp.where(jnp.arange(Rw) < Rw - 1, jnp.arange(Rw, dtype=jnp.int32) % (WS - 1), WS - 1)
+    wpos = jnp.where(jnp.arange(Rw) < Rw - 1,
+                     jnp.asarray(rs.integers(1, wB * wbs - 1, size=Rw), jnp.int32), -1)
+
+    def ring_decode(impl):
+        def run(q, kc, vc, tb, qpos, ke, ve):
+            return paged_attention(q, kc, vc, tb, qpos, wtrash, impl=impl, interpret=interp,
+                                   window=win, extra_kv=(ke, ve, qpos[:, None]), pool_limit=qpos)
+        return run
+
+    cases.append((
+        "paged decode, a window layer's ring tables",
+        ring_decode("kernel"),
+        (rnd((Rw, wnh, wd)), wpool(), wpool(), ring(wslots), wpos,
+         rnd((Rw, 1, wnkv, wd)), rnd((Rw, 1, wnkv, wd))),
+        ring_decode("dense"), 2e-2,
+    ))
+    wstart = jnp.asarray([3 * wtq, 0], jnp.int32)   # a chunk behind three others; a first chunk
+    wcpos = jnp.stack([3 * wtq + jnp.arange(wtq), jnp.where(jnp.arange(wtq) < wtq - wtq // 4,
+                                                          jnp.arange(wtq), -1)]).astype(jnp.int32)
+
+    def ring_chunk(impl):
+        def run(q, kc, vc, tb, qpos, ke, ve, limit):
+            return paged_chunk_attention(q, kc, vc, tb, qpos, wtrash, window=win, new_kv=(ke, ve),
+                                         pool_limit=limit, impl=impl, interpret=interp)
+        return run
+
+    cases.append((
+        "paged chunk, a window layer's ring tables",
+        ring_chunk("kernel"),
+        (rnd((2, wtq, wnh, wd)), wpool(), wpool(), ring(jnp.asarray([1, 2], jnp.int32)), wcpos,
+         rnd((2, wtq, wnkv, wd)), rnd((2, wtq, wnkv, wd)), wstart),
+        ring_chunk("dense"), 2e-2,
+    ))
+
     cases.append((
         "gdn decode, the state pool in place",
         gdn("interpret" if interp else "kernel"),

@@ -326,6 +326,11 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
             "tiny", n_layers=4, dtype="float32", max_seq_len=512,
             layer_kinds=("gdn", "full") * 2, gdn_key_heads=2, gdn_value_heads=4,
             gdn_key_dim=16, gdn_value_dim=16)
+    if model == "window":
+        # ... or a window pool: window and global layers in one stack
+        cfg = get_config(
+            "tiny", n_layers=4, dtype="float32", max_seq_len=512,
+            sliding_window=6, attn_layer_pattern=(1, 1, 1, 0))
     params = init_params(cfg, jax.random.key(0))
     kv = {"block_size": 4, "num_blocks": 128, "max_blocks_per_seq": 32,
           "kv_cache_dtype": kv_dtype}
@@ -351,8 +356,9 @@ def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     pools is safe). Every program takes ``(params, inputs, rng, temperature,
     pools)`` and donates ``pools`` whole: int8 adds the scale planes as two
     more leaves of it, ``model="gdn"`` (a model with Gated DeltaNet layers)
-    the recurrent-state and conv pools; such a model has no verify step (the
-    engine refuses it)."""
+    the recurrent-state and conv pools, ``model="window"`` (window and global
+    layers in one stack) the window pools; such models have no verify step
+    (the engine refuses it)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -384,7 +390,7 @@ def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     # program declares the pools donated — without aliasing, every spec
     # round would copy the whole paged pool, erasing the subsystem's win.
     # Its inputs are what the engine stages for a round with no row.
-    if not eng._hybrid:
+    if not eng._beside:
         _, inputs = eng._stage_verify([], [], 4)
         programs["verify_step"] = staged(eng._build_verify_step(4), inputs)
     return eng, programs
@@ -399,8 +405,8 @@ def _engine_v2_pass(kv_dtype: str, model: str = "dense") -> List[CheckResult]:
     results: List[CheckResult] = []
     eng, programs = _engine_v2_programs(kv_dtype, model)
     for key in ("split_step", "decode_only_step", "multistep_decode", "verify_step"):
-        if key == "verify_step" and eng._hybrid:
-            continue  # refused at build: a rejected draft would need the state rolled back
+        if key == "verify_step" and eng._beside:
+            continue  # refused at build: a rejected draft would need the second cache rolled back
         label = f"engine_v2.{key}{tag}"
         if key not in programs:
             results.append(CheckResult(label, "donation", False,
@@ -418,8 +424,10 @@ def _engine_v2_pass(kv_dtype: str, model: str = "dense") -> List[CheckResult]:
 def verify_engine_v2() -> List[CheckResult]:
     # both pool payload dtypes: int8 adds donated scale-plane leaves to
     # every serving program (split, multistep, verify)
-    # ... and a model with DeltaNet layers the state pools
-    return _engine_v2_pass("bf16") + _engine_v2_pass("int8") + _engine_v2_pass("bf16", "gdn")
+    # ... a model with DeltaNet layers the state pools, and one that mixes
+    # window and global layers the window pools
+    return (_engine_v2_pass("bf16") + _engine_v2_pass("int8") + _engine_v2_pass("bf16", "gdn")
+            + _engine_v2_pass("bf16", "window"))
 
 
 def verify_streamed_adam() -> List[CheckResult]:

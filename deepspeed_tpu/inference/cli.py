@@ -351,14 +351,14 @@ def engine_config_from_args(args, cfg):
         # size the pool from a byte budget: under int8 the same budget
         # holds ~2x blocks (kv_pool.bytes_per_block) — this is where the
         # capacity multiplier reaches admission
-        # (a DeltaNet model's state slots, one a tracked sequence and a
-        # spare, come out of the same budget first)
-        from deepspeed_tpu.inference.v2.kv_pool import blocks_for_budget, state_slot_bytes
+        # (a DeltaNet model's state slots or a mixed stack's window rings,
+        # one a tracked sequence and a spare, come out of the same budget first)
+        from deepspeed_tpu.inference.v2.kv_pool import blocks_for_budget, slot_bytes
 
         num_blocks = blocks_for_budget(
             int(args.kv_pool_bytes), args.block_size, cfg.kv_heads,
             cfg.head_dim, cfg.kv_layers, kv_dtype,
-            state_bytes=(args.max_concurrent + 1) * state_slot_bytes(cfg),
+            state_bytes=(args.max_concurrent + 1) * slot_bytes(cfg, args.block_size),
         )
     return RaggedInferenceEngineConfig.from_dict({
         "dtype": args.dtype, "tp_size": args.tp,

@@ -10,6 +10,10 @@ TPU adaptation:
     Gated DeltaNet layers (``layer_kinds``) has a second kind of cache beside
     it, one fixed-size slot a tracked sequence: the layers' recurrent states
     [Lg * slots, nv, dk, dv] float32 and conv inputs [Lg * slots, (K - 1) * C];
+    a stack that mixes window and global layers holds the window layers' K/V
+    in a window pool [Lw, slots * wb, block_size, n_kv, d], a ring of wb
+    blocks a tracked sequence (kv_pool.py), and the block pool the global
+    layers' alone;
   * paged attention = block-table gather → dense attention with a length
     mask, or the Pallas paged kernel underneath (``paged_attention``);
   * a step is one compiled program over a fixed grid (the SplitFuse
@@ -124,7 +128,8 @@ class StepStats:
     the step is staged (``moe`` after its wait). The serving core folds it
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
     paged_live_blocks_total / paged_table_slots_total, chunk_live_blocks_total
-    / chunk_table_slots_total, moe_*_total and gdn_*_total."""
+    / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total and
+    paged_window_live_blocks_total."""
 
     grid_slots: int = 0
     scheduled_tokens: int = 0
@@ -145,6 +150,14 @@ class StepStats:
     # tables and whole chunks covers, of one layer (_count_chunk)
     chunk_live_blocks: int = 0
     chunk_table_slots: int = 0
+    # the cache as the step found it: blocks of the block pool held, ring
+    # blocks of the window pool held (0 without one), tokens of context the
+    # tracked sequences hold; and, of ONE window layer, the blocks the decode
+    # rows' walks visit (beside paged_live_blocks, what a global layer's do)
+    kv_global_blocks: int = 0
+    kv_window_blocks: int = 0
+    kv_context_tokens: int = 0
+    paged_window_live_blocks: int = 0
 
 
 class InferenceEngineV2:
@@ -170,7 +183,13 @@ class InferenceEngineV2:
         quantized = bool(getattr(self.config, "quant", None) and self.config.quant.enabled)
         tp = int(getattr(self.config, "tp_size", 1) or 1)
         self._hybrid = model_config.hybrid
-        if self._hybrid:
+        # a stack that mixes window and global layers: a window pool beside
+        # the block pool, a ring a tracked sequence
+        self._windowed = model_config.window_layers > 0
+        # what a sequence holds beside its K/V blocks, for the refusals' words
+        self._beside = ("Gated DeltaNet layers keep a recurrent state" if self._hybrid else
+                        "window layers keep their K/V in a window pool" if self._windowed else None)
+        if self._beside:
             self._refuse_at_build(model_config, quantized, tp)
         if model_config.n_experts > 0 and (quantized or tp > 1):
             # the expert layer's grouped matmul reads whole bf16 expert
@@ -195,12 +214,18 @@ class InferenceEngineV2:
             )
         self.params = params
         kv = self.config.kv_cache
-        # a DeltaNet model: one state slot a tracked sequence, and one spare
-        # that the padding of a step's grid points at
+        # a DeltaNet model, or one with a window pool: one slot a tracked
+        # sequence, and one spare that the padding of a step's grid points at
         self._state_slots = (
-            self.config.state_manager.max_tracked_sequences + 1 if self._hybrid else 0)
+            self.config.state_manager.max_tracked_sequences + 1 if self._beside else 0)
+        from deepspeed_tpu.inference.v2.kv_pool import window_blocks
+
+        # ring blocks a slot a window layer
+        self._win_blocks = (
+            window_blocks(model_config.sliding_window, kv.block_size) if self._windowed else 0)
         self.state_manager = DSStateManager(
-            self.config.state_manager, kv, state_slots=max(0, self._state_slots - 1))
+            self.config.state_manager, kv, state_slots=max(0, self._state_slots - 1),
+            window_blocks=self._win_blocks)
         self.scheduler = RaggedScheduler(
             self.config.state_manager,
             self.state_manager,
@@ -347,7 +372,14 @@ class InferenceEngineV2:
         # last K - 1 inputs in the compute dtype
         self._gdn_state = self._gdn_conv = None
         self._gdn_impl = None  # gdn_decode's pick by platform; tests name one
-        self._ordinals = np.asarray(T.kind_ordinals(c), np.int32)
+        self._cache_kinds = T.cache_kinds(c)
+        self._ordinals = np.asarray(T.cache_ordinals(c), np.int32)
+        # the window pool: the spare slot's ring is its trash
+        self._wk_cache = self._wv_cache = None
+        if self._windowed:
+            wshape = (c.window_layers, self._state_slots * self._win_blocks) + shape[2:]
+            self._wk_cache = jnp.zeros(wshape, dtype)
+            self._wv_cache = jnp.zeros(wshape, dtype)
         if self._hybrid:
             n = c.kind_count("gdn") * self._state_slots
             self._gdn_state = jnp.zeros(
@@ -405,16 +437,20 @@ class InferenceEngineV2:
             + (f", comm_overlap=tiled({self._overlap_tiles})" if self._tp_tiled else "")
             + (", prefix_cache=on" if self.state_manager.prefix_cache is not None else "")
             + (f", host_tier={htb}B" if self._host_tier is not None else "")
-            + (f", state_slots={self._state_slots}" if self._hybrid else ""),
+            + (f", state_slots={self._state_slots}" if self._hybrid else "")
+            + (f", window_pool={c.window_layers} layers x {self._state_slots} rings of "
+               f"{self._win_blocks} blocks" if self._windowed else ""),
             ranks=[0],
         )
 
     def _refuse_at_build(self, c, quantized: bool, tp: int) -> None:
-        """A model with recurrent-state layers: what cannot carry the state
-        yet raises here or is switched off with one log line, and never
-        drops it. A cache hit, a spilled block, an exported block or a
-        rejected draft names K/V blocks alone; the state a sequence's
-        DeltaNet layers hold after the same tokens would be lost or stale."""
+        """A model with a second kind of cache (DeltaNet layers' recurrent
+        state, or window layers' window pool): what cannot carry it yet
+        raises here or is switched off with one log line, and never drops it.
+        A cache hit, a spilled block, an exported block or a rejected draft
+        names K/V blocks alone; the state a sequence's DeltaNet layers hold
+        after the same tokens, or the window layers' keys and values of
+        them, would be lost, stale or misread."""
         kv = self.config.kv_cache
         what = None
         if quantized or tp > 1:
@@ -427,22 +463,23 @@ class InferenceEngineV2:
             what = "speculative decoding (spec_k): a rejected draft would need the state rolled back"
         if what is not None:
             raise NotImplementedError(
-                f"v2 paged engine: a model with Gated DeltaNet layers and {what} "
-                "is not supported: the recurrent-state pool has no such form yet")
+                f"v2 paged engine: a model whose {self._beside} beside the K/V blocks, "
+                f"with {what}, is not supported: that pool has no such form yet")
         if getattr(kv, "prefix_cache", False):
             log_dist(
-                "InferenceEngineV2: prefix cache switched off: a hit shares K/V blocks "
-                "and would skip the DeltaNet layers' recurrent state for those tokens",
+                "InferenceEngineV2: prefix cache switched off: a hit shares K/V blocks, and this "
+                f"model's {self._beside} beside them, which a hit would skip for those tokens",
                 ranks=[0])
             self.config.kv_cache = dataclasses.replace(kv, prefix_cache=False)
 
     def _refuse_state_loss(self, what: str) -> None:
         """Raise where an operation moves or rolls back a sequence's cache by
         K/V blocks alone (handoff, recovery, host tier, speculative verify):
-        with DeltaNet layers the recurrent state would be dropped."""
-        if self._hybrid:
+        with DeltaNet layers the recurrent state would be dropped, with a
+        window pool the window layers' keys and values."""
+        if self._beside:
             raise NotImplementedError(
-                f"{what}: this model's Gated DeltaNet layers keep a recurrent state "
+                f"{what}: this model's {self._beside} "
                 "beside the K/V blocks, and nothing moves or snapshots it yet")
 
     @property
@@ -506,6 +543,19 @@ class InferenceEngineV2:
             c.kv_layers, self._kv_dtype,
         )
         info["paged_attention_impl"] = self._attn_impl
+        if self._windowed:
+            # by kind: the block pool is the global layers'; the window layers'
+            # rings, one a tracked sequence + a spare, are paid beside it
+            from deepspeed_tpu.inference.v2.kv_pool import window_slot_bytes
+
+            per_slot = window_slot_bytes(c, kv.block_size)
+            info.update(
+                window_slots=self._state_slots,
+                window_slots_in_use=self.state_manager.state_slot_accounting()["live"],
+                window_blocks_per_slot=self._win_blocks,
+                window_bytes_per_slot=per_slot,
+                window_pool_bytes=self._state_slots * per_slot,
+            )
         if self._hybrid:
             # the second kind of cache: one slot a tracked sequence + a spare
             from deepspeed_tpu.inference.v2.kv_pool import state_slot_bytes
@@ -973,7 +1023,7 @@ class InferenceEngineV2:
         kv = self.config.kv_cache
         chunk = int(getattr(kv, "host_tier_chunk_blocks", 8) or 8)
         n = min(chunk + 1, int(kv.num_blocks))
-        if n > chunk and not self._hybrid:  # no K/V mover serves a state pool
+        if n > chunk and not self._beside:  # no K/V mover serves a second kind of cache
             blocks = list(range(n))
             self.import_kv_blocks_chunked(
                 blocks, self.export_kv_blocks(blocks), chunk_blocks=chunk
@@ -1043,6 +1093,57 @@ class InferenceEngineV2:
         shape = (L * NBp, kv.block_size, c.kv_heads, c.head_dim)
         return k_cache.reshape(shape), v_cache.reshape(shape)
 
+    def _cache_views(self, pools, second):
+        """What a step's layers read of the caches, by name, for ``meta``:
+        the flat views of the block pools (and scale planes) and, for a mixed
+        stack, of the window pools ``second`` [Lw * NWp, bs, nkv, d]."""
+        k_pool0, v_pool0 = self._pool_views(*pools[:2])
+        ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
+        views = {"k_pool0": k_pool0, "v_pool0": v_pool0,
+                 "ks_pool0": ks_pool0, "vs_pool0": vs_pool0}
+        if self._windowed:
+            wk, wv = second
+            flat = (wk.shape[0] * wk.shape[1],) + wk.shape[2:]
+            views.update(wk_pool0=wk.reshape(flat), wv_pool0=wv.reshape(flat))
+        return views
+
+    def _ring_tables(self, slots):
+        """[R, B] tables of a window layer for rows at ``slots`` [R]: logical
+        block ``j`` of a row is ring block ``slot * wb + j % wb``, whatever
+        ``j``: the walk is bounded by the window, so it visits the blocks the
+        ring still holds. Padding rows sit at the spare slot, the pool's trash."""
+        B, wb = self.config.kv_cache.max_blocks_per_seq, self._win_blocks
+        return slots[:, None] * wb + (jnp.arange(B, dtype=jnp.int32) % wb)[None]
+
+    def _ring_blocks(self, slots, positions, keep):
+        """Ring block of each token at ``positions`` of rows at ``slots`` (same
+        shape), the spare slot's where ``keep`` is false."""
+        bs, wb = self.config.kv_cache.block_size, self._win_blocks
+        return jnp.where(keep, slots * wb + (positions // bs) % wb, (self._state_slots - 1) * wb)
+
+    def _kv_source(self, meta, li, tables: str):
+        """(K view, V view, layer-offset tables, layer-offset trash block) of
+        what layer ``li``'s attention reads: the block pool under
+        the rows' block tables ``meta[tables]``, or for a window layer the
+        window pool under the rows' ring tables ``meta["win_" + tables]``."""
+        kv = self.config.kv_cache
+        o = self._ordinal(li)
+        if self._windowed and self._cache_kinds[li] == "window":
+            NWp = self._state_slots * self._win_blocks
+            return (meta["wk_pool0"], meta["wv_pool0"], o * NWp + meta["win_" + tables],
+                    o * NWp + NWp - self._win_blocks)
+        NBp = kv.num_blocks + 1
+        return meta["k_pool0"], meta["v_pool0"], o * NBp + meta[tables], o * NBp + kv.num_blocks
+
+    def _side_index(self, li):
+        """Where layer ``li``'s new K/V sit in the side buffers: at the
+        layer's ordinal among those that cache alike, a mixed stack's window
+        layers behind its global ones."""
+        fi = self._ordinal(li)
+        if self._windowed and self._cache_kinds[li] == "window":
+            fi += self._mc.kv_layers
+        return fi
+
     def _scale_views(self, *scales):
         """Flat views [L*NBp, bs, nkv] of the int8 pools' fp32 scale planes
         (same layer-offset indexing as _pool_views); (None, None) for a
@@ -1062,19 +1163,23 @@ class InferenceEngineV2:
         DeltaNet layers ``(k, v, states, conv inputs)``, donated whole —
         whatever else a program takes, and whichever kinds of cache the model
         has, every leaf of it is updated in place."""
-        state = (self._gdn_state, self._gdn_conv) if self._hybrid else ()
-        return tuple(self._kv_pool_planes().values()) + state
+        second = ((self._gdn_state, self._gdn_conv) if self._hybrid else
+                  (self._wk_cache, self._wv_cache) if self._windowed else ())
+        return tuple(self._kv_pool_planes().values()) + second
 
     def _split_pools(self, pools):
-        """(the K/V planes, the state pools or ()) of a program's ``pools``."""
-        n = len(pools) - (2 if self._hybrid else 0)
+        """(the K/V planes, the second kind of cache or ()) of a program's
+        ``pools``: a DeltaNet model's state pools, which ride the layer
+        loop's carry, or a mixed stack's window pools (k, v), which are its
+        invariants like the block pools."""
+        n = len(pools) - (2 if self._beside else 0)
         return pools[:n], pools[n:]
 
     def _ordinal(self, li):
         """Layer ``li``'s ordinal among the layers of its kind (its index in
         its kind's parameter stack and cache pool): ``li`` itself where every
         layer is alike, else read off a constant table, traced or not."""
-        if not self._hybrid:
+        if not self._beside:
             return li
         return int(self._ordinals[li]) if isinstance(li, int) else jnp.asarray(self._ordinals)[li]
 
@@ -1154,6 +1259,27 @@ class InferenceEngineV2:
         return (calls * int((-(-held // kv.block_size)).sum()),
                 calls * len(held) * kv.max_blocks_per_seq)
 
+    def _count_cache(self, dec_pos, new_tokens: int, calls: int = 1):
+        """The cache as the step finds it, as ``StepStats``' kv_global_blocks
+        (blocks of the block pool held), kv_window_blocks (ring blocks held: a
+        tracked sequence's ``wb``), kv_context_tokens (tokens the tracked
+        sequences hold, this step's ``new_tokens`` included) and paged_window_live_blocks:
+        the blocks ONE window layer's decode walks visit, for a row at
+        position p those of keys p - window + 1 .. p - 1 (``dec_pos`` [R], < 0
+        an inactive slot; ``calls`` walks a row). Host arithmetic over the
+        tracked sequences, inside ``engine.stage``."""
+        sm, kv = self.state_manager, self.config.kv_cache
+        bs = kv.block_size
+        out = {"kv_global_blocks": kv.num_blocks - sm.free_blocks,
+               "kv_context_tokens": sm.context_tokens + int(new_tokens)}
+        if self._windowed:
+            p = np.asarray(dec_pos, np.int64)
+            lo = np.maximum(p - self._mc.sliding_window + 1, 0) // bs
+            walks = np.where(p > 0, (p - 1) // bs - lo + 1, 0)
+            out.update(kv_window_blocks=sm.state_slots_in_use * self._win_blocks,
+                       paged_window_live_blocks=calls * int(walks.sum()))
+        return out
+
     def _count_chunk(self, chk_rows, tq: int):
         """What one layer's chunk attention had to read against what the
         dense form walks, as ``StepStats``' chunk_live_blocks and
@@ -1179,7 +1305,7 @@ class InferenceEngineV2:
         (``_with_state``): they are never a loop's invariant, every read and
         the in-place update of a layer go through the carried value."""
         c = self._mc
-        shape = (c.kv_layers,) + tuple(token_dims) + (c.kv_heads, c.head_dim)
+        shape = (c.kv_layers + c.window_layers,) + tuple(token_dims) + (c.kv_heads, c.head_dim)
         side = jnp.zeros(shape, T.DTYPES[c.dtype])
         if self._mesh is not None:
             from jax.sharding import NamedSharding
@@ -1196,11 +1322,11 @@ class InferenceEngineV2:
             carry["moe"] = jnp.zeros((c.n_layers, c.n_experts), jnp.int32)
         return carry
 
-    @staticmethod
-    def _with_state(carry, state_pools):
-        """The carry with a DeltaNet model's (states, conv inputs) in it."""
-        if state_pools:
-            carry = dict(carry, gdn_state=state_pools[0], gdn_conv=state_pools[1])
+    def _with_state(self, carry, second):
+        """The carry with a DeltaNet model's (states, conv inputs) in it (a
+        window pool is no part of a carry: the layers read it as it was)."""
+        if self._hybrid:
+            carry = dict(carry, gdn_state=second[0], gdn_conv=second[1])
         return carry
 
     @staticmethod
@@ -1226,7 +1352,7 @@ class InferenceEngineV2:
         """Layer ``li``'s new K/V [..., nkv, d] into the side buffers at the
         layer's ordinal among those that attend, and what it routed beside
         them (_record_moe)."""
-        fi = self._ordinal(li)
+        fi = self._side_index(li)
         carry = dict(
             carry,
             k=jax.lax.dynamic_update_index_in_dim(carry["k"], k, fi, 0),
@@ -1249,19 +1375,19 @@ class InferenceEngineV2:
 
         ``blk``/``row``: [n] block and row of each of the step's n token
         slots, the same for every layer (padded slots name the trash
-        block); ``side``: the carry, its ``k`` / ``v`` [L, n..., nkv, d]. int8 pools quantize
+        block); ``side``: the new (k, v), each [L, n..., nkv, d], L the pools'
+        layers (_write_back hands each kind of pool its layers). int8 pools quantize
         here (block_quant.quantize_kv, per head vector: the granularity
         that needs no read-modify-write of neighbor slots) and the fp32
         scales scatter through the same slot ids. Returns the pools in the
         order given."""
         c = self._mc
-        kv = self.config.kv_cache
-        L, NBp, bs = c.kv_layers, kv.num_blocks + 1, kv.block_size
+        L, NBp, bs = caches[0].shape[:3]
         nkv, d = c.kv_heads, c.head_dim
         n = blk.shape[0]
         li = jnp.arange(L, dtype=jnp.int32)[:, None]
         slot = ((li * NBp + blk[None]) * bs + row[None]).reshape(L * n)
-        new = [a.reshape(L * n, nkv, d) for a in (side["k"], side["v"])]
+        new = [a.reshape(L * n, nkv, d) for a in side]
         if len(caches) == 4:
             from deepspeed_tpu.ops.quantizer.block_quant import quantize_kv
 
@@ -1272,6 +1398,37 @@ class InferenceEngineV2:
             for pool, a in zip(caches, new)
         )
 
+    def _write_back(self, pools, second, blk, row, side, wblk=None, x=None):
+        """Every pool of a step's ``pools`` argument as the program returns
+        it: the block pools written from the side buffers (_scatter_kv), then
+        a mixed stack's window pools from their layers' part of the same
+        buffers at the tokens' ring blocks ``wblk`` [n] (a token that no later
+        query can see names the spare ring: no ring slot is written twice),
+        or a DeltaNet model's state pools as the carry holds them. ``x``: the
+        stream an UNROLLED stack left (the split step of a mixed stack), which
+        orders the write behind the last layer and changes nothing of it."""
+        if not self._windowed:
+            return self._scatter_kv(pools, blk, row, (side["k"], side["v"])) + self._state_of(side)
+        if x is not None:
+            # A loop ends before the pool write that follows it. An unrolled
+            # stack's last layer hands on its new K/V before its attention has
+            # read the pool, so the write may come first, and XLA keeps the
+            # two apart by copying every pool that layer reads, twice a step
+            # (check_pool_copies; an optimization_barrier does not survive to
+            # where that is decided, and a loop of one pass hoists every
+            # layer's weight slices out as copies). So the write's indices are
+            # made to wait for the stream the last layer left, through a
+            # predicate that is False whatever the stream holds (a NaN is
+            # unequal to itself, and then not equal either): every token's
+            # K/V lands where it belongs, and the compiler cannot know it.
+            p = x[0, 0, 0]
+            never = (p != p) & (p == p)
+            blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
+            wblk = jnp.where(never, (self._state_slots - 1) * self._win_blocks, wblk)
+        Lg = self._mc.kv_layers
+        return (self._scatter_kv(pools, blk, row, (side["k"][:Lg], side["v"][:Lg]))
+                + self._scatter_kv(second, wblk, row, (side["k"][Lg:], side["v"][Lg:])))
+
     def _layer_windows(self):
         """Static per-layer window values: an int (uniform — one loop body
         serves every layer) or a list (alternating local/global stacks,
@@ -1279,10 +1436,10 @@ class InferenceEngineV2:
         the uniform int — unrolling them only multiplied compile time
         (round-4 advisor finding)."""
         c = self._mc
-        if c.attn_layer_pattern is None:
-            return int(c.sliding_window or 0)
-        vals = [int(c.sliding_window or 0) if f else 0 for f in c.attn_layer_pattern]
-        if len(set(vals)) == 1:
+        vals = [int(c.sliding_window or 0) if f else 0
+                for f in c.attn_layer_pattern or (1,) * c.n_layers]
+        # (lead layers of another shape are unrolled too)
+        if len(set(vals)) == 1 and not c.moe_dense_lead:
             return vals[0]
         return vals
 
@@ -1307,11 +1464,12 @@ class InferenceEngineV2:
         # an expert model's per-expert weights stay whole: the grouped kernel
         # indexes its layer's blocks itself (_mlp_tail passes ``li`` on); a
         # slice in front of a custom call would be copied, every layer
-        whole = {}
+        whole, whole_keys = {}, ()
         if c.n_experts > 0:
             from deepspeed_tpu.parallel.moe.sharded_moe import EXPERT_STACKS
 
-            whole = {k: v for k, v in params["layers"].items() if k in EXPERT_STACKS}
+            whole_keys = EXPERT_STACKS
+            whole = {k: v for k, v in params["layers"].items() if k in whole_keys}
         sliced = {k: v for k, v in params["layers"].items() if k not in whole}
 
         def traced(a, i):
@@ -1346,21 +1504,34 @@ class InferenceEngineV2:
 
             x, carry = jax.lax.fori_loop(0, L, body, (x, carry))
             return x, carry
+        # unrolled: a layer's own sub-stack (a dense lead layer's MLP, an
+        # expert layer's block: T.layer_stack) beside what every layer has; an
+        # expert layer reads the whole expert stacks at its index in them
+        common = {k: v for k, v in sliced.items() if not isinstance(v, dict)}
         for li, w in enumerate(windows):
-            x, carry = layer_fn(layer_params(lambda a: a[li]), x, li, carry, window=w)
+            name, ki = T.layer_stack(c, li)
+            own = dict(params["layers"][name]) if name else {}
+            # (_mlp_tail knows an expert layer's index in the stacks from li)
+            stacks = {k: own.pop(k) for k in whole_keys if name == "sparse" and k in own}
+            lp = {**jax.tree.map(lambda a: a[li], common),
+                  **jax.tree.map(lambda a: a[ki], own), **whole, **stacks}
+            x, carry = layer_fn(lp, x, li, carry, window=w)
         return x, carry
 
-    def _layer_qkv(self, lp, x, positions, live):
+    def _layer_qkv(self, lp, x, positions, live, window=None):
         """Shared per-layer prologue for the serving step bodies: pre-norm →
         QKV projections (+ biases) → qk-norm → rope. One definition so the
         split step and the fused round cannot drift on arch features
         (qk_layernorm, biases, rope scaling). lp must be pre-dequantized.
-        Returns (a, q, k, v): the normed activations and [t, nh|nkv, d]
-        heads."""
+        ``window``: the layer's (static) window, which says whether a
+        ``rope_window_only`` model's layer rotates at all. Returns (a, q, k,
+        v): the normed activations (the stream itself where the block's
+        output is normed instead) and [t, nh|nkv, d] heads."""
         c = self._mc
         nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
         t = x.shape[1]
-        a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
+        a = x if c.norm_scheme == "out" else T._norm(
+            x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
         q, k, v = a[0] @ lp["wq"], a[0] @ lp["wk"], a[0] @ lp["wv"]
         if c.attn_qkv_bias:
             q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
@@ -1373,7 +1544,7 @@ class InferenceEngineV2:
         if c.qk_norm and not T.qk_norm_full(c):
             q = T.qk_norm_apply(c, q, lp["q_norm"], head_axis=1, b=lp.get("q_norm_b"))
             k = T.qk_norm_apply(c, k, lp["k_norm"], head_axis=1, b=lp.get("k_norm_b"))
-        if c.position == "rope":
+        if c.position == "rope" and not (c.rope_window_only and not window):
             q = T._rope(q.transpose(1, 0, 2)[None], positions[None], c, live)[0].transpose(1, 0, 2)
             k = T._rope(k.transpose(1, 0, 2)[None], positions[None], c, live)[0].transpose(1, 0, 2)
         return a, q, k, v
@@ -1459,6 +1630,8 @@ class InferenceEngineV2:
             attn_out = (out @ lp["wo"])[None]
         if c.attn_out_bias:
             attn_out = attn_out + lp["wo_b"]
+        if c.norm_scheme == "out":
+            attn_out = T._norm(attn_out, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
         return self._mlp_tail(lp, x, attn_out, live, li)
 
     def _mlp_tail(self, lp, x, attn_out, live, li):
@@ -1469,18 +1642,23 @@ class InferenceEngineV2:
         layer ``li`` of the whole stacks (_drive_layers). Returns (x, rows
         routed to each expert [E] int32, or None for a dense MLP)."""
         c = self._mc
+        out_norm = c.norm_scheme == "out"  # the block's OUTPUT is normed, its input is not
         if not c.parallel_block:
             x = x + attn_out
-        m = T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
+        m = x if out_norm else T._norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
         counts = None
-        if c.n_experts > 0:
+        if "router" in lp:  # an expert layer: its own keys say so
             from deepspeed_tpu.parallel.moe import moe_mlp
 
-            mlp_out, _, counts = moe_mlp(c, lp, m, live=live[None], layer=li)
+            # (its index in the whole stacks: behind the dense lead layers)
+            mlp_out, _, counts = moe_mlp(
+                c, lp, m, live=live[None], layer=li - c.moe_dense_lead if c.moe_dense_lead else li)
         elif self._tp_wire:
             mlp_out = self._mlp_quant(lp, m)
         else:
             mlp_out = T._mlp_block(c, lp, m)[0]
+        if out_norm:
+            mlp_out = T._norm(mlp_out, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
         x = x + attn_out + mlp_out if c.parallel_block else x + mlp_out
         return x, counts
 
@@ -1554,8 +1732,6 @@ class InferenceEngineV2:
         pool is written once, after the loop. A DeltaNet layer of the same
         step (``layer_kinds``) goes through ``_gdn_layer`` instead."""
         c = self._mc
-        kv = self.config.kv_cache
-        NBp = kv.num_blocks + 1
         w = c.sliding_window if window is None else window
         nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
         R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
@@ -1566,13 +1742,12 @@ class InferenceEngineV2:
                 "live": meta["dec_pos"] >= 0, "slot_live": meta["slot_live"],
                 "chk_slots": meta.get("chk_slots"), "chk_start": meta.get("chk_start"),
                 "chk_pos": meta.get("chk_pos")}, carry)
-        a, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"])
-        k_pool, v_pool = meta["k_pool0"], meta["v_pool0"]
+        a, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"], w)
         ks_pool, vs_pool = meta["ks_pool0"], meta["vs_pool0"]
-        li_kv = self._ordinal(li)  # the K/V pool holds the layers that attend
+        # the pool of the layer's kind at the layer's ordinal in it
+        k_pool, v_pool, tables_l, trash_l = self._kv_source(meta, li, "dec_tables")
         out = self._attn_decode(
-            q[:R], k_pool, v_pool, li_kv * NBp + meta["dec_tables"],
-            meta["dec_pos"], w, li_kv * NBp + kv.num_blocks,
+            q[:R], k_pool, v_pool, tables_l, meta["dec_pos"], w, trash_l,
             extra_kv=(k[:R, None], v[:R, None], meta["dec_pos"][:, None]),
             pool_limit=meta["dec_pos"],
             k_scale=ks_pool, v_scale=vs_pool,
@@ -1580,10 +1755,10 @@ class InferenceEngineV2:
         if tq:
             from deepspeed_tpu.ops.attention.paged_pallas import paged_chunk_attention
 
+            _, _, tables_l, trash_l = self._kv_source(meta, li, "chk_tables")
             out_c = paged_chunk_attention(
                 q[R:].reshape(Rc, tq, nh, d), k_pool, v_pool,
-                li_kv * NBp + meta["chk_tables"], meta["chk_pos"],
-                li_kv * NBp + kv.num_blocks,
+                tables_l, meta["chk_pos"], trash_l,
                 window=int(w), scale=c.attn_scale,
                 new_kv=(k[R:].reshape(Rc, tq, nkv, d), v[R:].reshape(Rc, tq, nkv, d)),
                 pool_limit=meta["chk_start"],
@@ -1613,22 +1788,21 @@ class InferenceEngineV2:
             tokens, positions = inputs["tokens"], inputs["positions"]
             dec_pos = inputs["dec_pos"]
             x = self._embed(params, tokens, positions)
-            pools, state_pools = self._split_pools(pools)
-            k_pool0, v_pool0 = self._pool_views(*pools[:2])
-            ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
+            pools, second = self._split_pools(pools)
             meta = {
                 "R": R, "Rc": Rc, "tq": tq, "positions": positions,
-                # DeltaNet models: the rows' state slots (None otherwise)
+                **self._cache_views(pools, second),
+                # a second kind of cache: the rows' slots (None otherwise)
                 "dec_slots": inputs.get("dec_slots"), "chk_slots": inputs.get("chk_slots"),
                 # live length (HF max(position_ids)+1) for the rope-scaling
                 # switch: padded slots carry position 0, so the plain max works
                 "live": jnp.max(positions) + 1,
                 "dec_tables": inputs["dec_tables"], "dec_pos": dec_pos,
-                "k_pool0": k_pool0, "v_pool0": v_pool0,
-                "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
                 # the grid's padding: decode slots with no row
                 "slot_live": dec_pos >= 0,
             }
+            if self._windowed:
+                meta["win_dec_tables"] = self._ring_tables(inputs["dec_slots"])
             if tq:
                 chk_pos = inputs["chk_pos"]
                 meta.update(
@@ -1637,14 +1811,17 @@ class InferenceEngineV2:
                     # ... and chunk tails
                     slot_live=jnp.concatenate([dec_pos >= 0, chk_pos.reshape(Rc * tq) >= 0]),
                 )
+                if self._windowed:
+                    meta["win_chk_tables"] = self._ring_tables(inputs["chk_slots"])
 
             def layer_fn(lp, x, li, carry, window=None):
                 return self._split_layer(lp, x, li, meta, carry, window=window)
 
             x, side = self._drive_layers(
                 layer_fn, params, x,
-                self._with_state(self._side_buffers(tokens.shape[0]), state_pools))
-            pools = self._scatter_kv(pools, inputs["blk"], inputs["row"], side) + self._state_of(side)
+                self._with_state(self._side_buffers(tokens.shape[0]), second))
+            pools = self._write_back(
+                pools, second, inputs["blk"], inputs["row"], side, inputs.get("wblk"), x)
             # generate() holds only the token arrays across its prefill
             # phase and drops the logits
             logits_dec, toks_dec = self._sample_rows(
@@ -1666,8 +1843,6 @@ class InferenceEngineV2:
         round's only read-write surface; the pool is written from them
         once, after the last step."""
         c = self._mc
-        kv = self.config.kv_cache
-        NBp = kv.num_blocks + 1
         w = c.sliding_window if window is None else window
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
         if "gdn_qkv" in lp:  # a DeltaNet layer: the carried state, updated in place
@@ -1675,8 +1850,8 @@ class InferenceEngineV2:
                 "R": x.shape[1], "slots": meta["slots"], "live": meta["active"],
                 "slot_live": meta["active"]}, carry)
         side_k, side_v = carry["k"], carry["v"]
-        a, q, k, v = self._layer_qkv(lp, x, meta["pos"], meta["live"])
-        li_kv = self._ordinal(li)
+        a, q, k, v = self._layer_qkv(lp, x, meta["pos"], meta["live"], w)
+        li_kv = self._side_index(li)
         # record this step's K/V in the side buffer BEFORE attention (the
         # query sees itself through the extra columns)
         side_k = jax.lax.dynamic_update_slice(
@@ -1687,9 +1862,9 @@ class InferenceEngineV2:
         )
         sk = jax.lax.dynamic_index_in_dim(side_k, li_kv, 0, keepdims=False)
         sv = jax.lax.dynamic_index_in_dim(side_v, li_kv, 0, keepdims=False)
+        k_pool, v_pool, tables_l, trash_l = self._kv_source(meta, li, "tables")
         out = self._attn_decode(
-            q, meta["k_pool0"], meta["v_pool0"], li_kv * NBp + meta["tables"],
-            meta["pos"], w, li_kv * NBp + kv.num_blocks,
+            q, k_pool, v_pool, tables_l, meta["pos"], w, trash_l,
             extra_kv=(sk, sv, meta["epos"]),
             pool_limit=meta["pos0"],
             k_scale=meta["ks_pool0"], v_scale=meta["vs_pool0"],
@@ -1721,6 +1896,10 @@ class InferenceEngineV2:
         B = kv.max_blocks_per_seq
         trash = kv.num_blocks
         R = self.config.state_manager.max_ragged_sequence_count
+        if self._windowed and n_steps > bs:
+            raise ValueError(
+                f"decode_steps={n_steps} over a window pool of {bs}-token blocks: a round writes "
+                "its tokens after its last step, at most a block of them a row")
 
         def fused(params, inputs, rng, temperature, pools):
             tokens, uids, active = inputs["tokens"], inputs["uids"], inputs["active"]
@@ -1731,9 +1910,10 @@ class InferenceEngineV2:
             # in-round tokens come from the side buffers); a DeltaNet
             # model's state pools ride the scan's carry, each step's
             # update made in place
-            pools, state_pools = self._split_pools(pools)
-            k_pool0, v_pool0 = self._pool_views(*pools[:2])
-            ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
+            pools, second = self._split_pools(pools)
+            views = self._cache_views(pools, second)
+            if self._windowed:
+                views["win_tables"] = self._ring_tables(inputs["slots"])
 
             def one_token(params, toks, pos, s, side):
                 x = self._embed(params, toks, pos)
@@ -1747,9 +1927,7 @@ class InferenceEngineV2:
                     "slots": inputs.get("slots"),
                     # inactive rows: pos0 == 0 -> pool masks to nothing
                     "pos0": jnp.where(active, pos0, 0),
-                    "s": s, "epos": epos,
-                    "k_pool0": k_pool0, "v_pool0": v_pool0,
-                    "ks_pool0": ks_pool0, "vs_pool0": vs_pool0,
+                    "s": s, "epos": epos, **views,
                     # inactive rows carry position 0: exclude them from the
                     # rope live-length switch
                     "live": jnp.max(jnp.where(active, pos, 0)) + 1,
@@ -1772,16 +1950,20 @@ class InferenceEngineV2:
 
             (_, _, side), (toks_out, logps_out, moe) = jax.lax.scan(
                 step_fn,
-                (tokens, pos0, self._with_state(self._side_buffers(R, n_steps), state_pools)),
+                (tokens, pos0, self._with_state(self._side_buffers(R, n_steps), second)),
                 j_idx,
             )
             # the round's write-back: step s of row r sits at position
             # pos0 + s (inactive rows never advance and name the trash block)
             pos_all = pos0[:, None] + j_idx[None] * active[:, None]  # [R, n_steps]
             blk = jnp.take_along_axis(tok_tables, jnp.clip(pos_all // bs, 0, B - 1), axis=1)
-            pools = self._scatter_kv(
-                pools, blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps), side,
-            ) + self._state_of(side)
+            wblk = None
+            if self._windowed:  # n_steps <= a ring's tokens: no ring slot twice
+                wblk = self._ring_blocks(
+                    inputs["slots"][:, None], pos_all, active[:, None]).reshape(R * n_steps)
+            pools = self._write_back(
+                pools, second, blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps),
+                side, wblk)
             return (toks_out, logps_out), pools, moe
 
         return jax.jit(fused, donate_argnums=(4,))
@@ -1898,7 +2080,7 @@ class InferenceEngineV2:
             x, side = self._drive_layers(
                 layer_fn, params, x, self._side_buffers(R * K1)
             )
-            pools = self._scatter_kv(pools, blk, row, side)
+            pools = self._scatter_kv(pools, blk, row, (side["k"], side["v"]))
             _, (tgt, logp) = self._sample_rows(
                 params, x, slice(None), rng, temperature,
                 jnp.repeat(inputs["uids"], K1), qpos.reshape(R * K1), return_logprobs=True)
@@ -1952,10 +2134,13 @@ class InferenceEngineV2:
         chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
         chk_last = np.zeros(Rc, np.int32)
         chk_uids = np.zeros(Rc, np.int32)
-        # DeltaNet models: each row's state slot; padding points at the spare
+        # a second kind of cache: each row's slot; padding points at the spare
         spare = self._state_slots - 1
         dec_slots = np.full(R, spare, np.int32)
         chk_slots = np.full(Rc, spare, np.int32)
+        # a window pool: each token's ring block (the spare ring: not written)
+        wb = self._win_blocks
+        wblk = np.full(T_, spare * wb, np.int32)
 
         for i, (uid, toks, start) in enumerate(dec_rows):
             seq = self.state_manager.get_sequence(uid)
@@ -1968,6 +2153,8 @@ class InferenceEngineV2:
             dec_uids[i] = uid
             blk[i] = seq.block_table[min(start // bs, nblk - 1)]
             row[i] = start % bs
+            if wb:
+                wblk[i] = seq.state_slot * wb + (start // bs) % wb
         for j, (uid, toks, start, _chunked) in enumerate(chk_rows):
             seq = self.state_manager.get_sequence(uid)
             n = len(toks)
@@ -1987,11 +2174,16 @@ class InferenceEngineV2:
             ]
             row[off : off + n] = pos % bs
             chk_last[j] = off + n - 1
+            if wb:
+                # a chunk longer than a ring keeps its last ring of tokens, all a
+                # later query can see, and no ring slot is written twice
+                wblk[off : off + n] = np.where(
+                    pos >= start + n - wb * bs, seq.state_slot * wb + (pos // bs) % wb, spare * wb)
         prefill = sum(len(t) for _, t, _, _ in chk_rows)
         self.last_step = StepStats(
             T_, total_tokens, prefill, *self._count_paged(dec_pos),
             gdn_decode_rows=len(dec_rows) if self._hybrid else 0,
-            **self._count_chunk(chk_rows, tq),
+            **self._count_chunk(chk_rows, tq), **self._count_cache(dec_pos, total_tokens),
         )
         inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,
@@ -2002,10 +2194,12 @@ class InferenceEngineV2:
                 chk_tables=chk_tables, chk_pos=chk_pos, chk_start=chk_start,
                 chk_last=chk_last, chk_uids=chk_uids,
             )
-        if self._hybrid:
+        if self._beside:
             inputs["dec_slots"] = dec_slots
             if tq:
                 inputs["chk_slots"] = chk_slots
+        if wb:
+            inputs["wblk"] = wblk
         return ("split", tq), inputs
 
     def _stage_rows(self, uids, width: int):
@@ -2021,11 +2215,11 @@ class InferenceEngineV2:
             "uids": np.zeros(R, np.int32),
             "active": np.zeros(R, bool),
         }
-        if self._hybrid:
+        if self._beside:
             inputs["slots"] = np.full(R, self._state_slots - 1, np.int32)
         for i, uid in enumerate(uids):
             seq = self.state_manager.get_sequence(uid)
-            if self._hybrid:
+            if self._beside:
                 inputs["slots"][i] = seq.state_slot
             inputs["tokens"][i, 0] = self.scheduler.peek_next_token(uid)
             inputs["positions"][i] = seq.seen_tokens
@@ -2041,7 +2235,9 @@ class InferenceEngineV2:
         inputs["tokens"] = inputs["tokens"][:, 0]
         self.last_step = StepStats(
             R * n, len(uids) * n, 0, *self._count_paged(inputs["positions"], calls=n),
-            gdn_decode_rows=len(uids) * n if self._hybrid else 0)
+            gdn_decode_rows=len(uids) * n if self._hybrid else 0,
+            **self._count_cache(
+                np.where(inputs["active"], inputs["positions"], -1), len(uids) * n, calls=n))
         return ("round", n), inputs
 
     def _stage_verify(self, uids, row_drafts, k: int):
@@ -2055,6 +2251,7 @@ class InferenceEngineV2:
         self.last_step = StepStats(
             R * (k + 1), len(uids) + sum(len(d) for d in row_drafts), 0,
             *self._count_paged(inputs["positions"], calls=k + 1),
+            **self._count_cache((), len(uids) + sum(len(d) for d in row_drafts)),
         )
         return ("verify", k), inputs
 
@@ -2076,12 +2273,14 @@ class InferenceEngineV2:
             jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
             self._pools(),
         )
-        pools, state = self._split_pools(pools)
+        pools, second = self._split_pools(pools)
         self._k_cache, self._v_cache = pools[:2]
         if self._kv_int8:
             self._ks_cache, self._vs_cache = pools[2:]
-        if state:
-            self._gdn_state, self._gdn_conv = state
+        if self._hybrid:
+            self._gdn_state, self._gdn_conv = second
+        elif self._windowed:
+            self._wk_cache, self._wv_cache = second
         return outputs
 
     def _start(self, stage, *args):
@@ -2143,7 +2342,7 @@ class InferenceEngineV2:
         from deepspeed_tpu.parallel.moe import grouped, sharded_moe
 
         c = self._mc
-        rows = np.asarray(pending)
+        rows = np.asarray(pending)[..., c.moe_dense_lead:, :]  # the layers that have experts
         counts = rows.reshape(-1, c.n_experts)
         # tokens of one layer call: the grid, a step of it for a fused round
         steps = rows.shape[0] if rows.ndim == 3 else 1

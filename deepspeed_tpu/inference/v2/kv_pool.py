@@ -23,6 +23,16 @@ key dim, value dim] float32 and its conv's last K - 1 inputs, over the Lg such
 layers (``state_slot_bytes``). ``blocks_for_budget`` takes the slots, one a
 tracked sequence and the spare, from the budget first and sizes the K/V
 blocks from what is left.
+
+A stack that mixes window layers with global ones (``window_layers`` > 0)
+has a WINDOW POOL beside the block pool, [Lw, slots * wb, block_size, kv_heads,
+head_dim] per K and V: a tracked sequence's slot is a ring of ``wb =
+window_blocks(window, block_size)`` blocks a window layer, the blocks a query
+at its position can still see and the one being written, whatever the
+context: logical block ``b`` of slot ``s`` is ring block ``s * wb + b % wb``.
+It is paid like the state slots, one ring a tracked sequence and a spare
+(``window_slot_bytes``), from the budget first; the block pool then holds the
+global layers alone (``kv_layers``).
 """
 
 from typing import Any, Dict, Tuple
@@ -110,18 +120,43 @@ def state_slot_bytes(config, conv_itemsize: int = 2) -> int:
     return layers * (state + conv) if layers else 0
 
 
+def window_blocks(window: int, block_size: int) -> int:
+    """Blocks of a window layer's ring: those a query can still see
+    (``window - 1`` keys behind it span at most ``ceil((window - 1) /
+    block_size)`` blocks beside its own) and the one being written."""
+    return -(-(int(window) - 1) // int(block_size)) + 1
+
+
+def window_slot_bytes(config, block_size: int) -> int:
+    """HBM bytes of one sequence's rings over a model's window layers
+    (``config``: the TransformerConfig; bf16 pools only). 0 for a stack that
+    does not mix window and global layers."""
+    if not config.window_layers:
+        return 0
+    return window_blocks(config.sliding_window, block_size) * bytes_per_block(
+        block_size, config.kv_heads, config.head_dim, config.window_layers)
+
+
+def slot_bytes(config, block_size: int, conv_itemsize: int = 2) -> int:
+    """What one tracked sequence holds beside its K/V blocks, whatever its
+    length: a recurrent-state slot, or its window layers' rings."""
+    return state_slot_bytes(config, conv_itemsize) + window_slot_bytes(config, block_size)
+
+
 def blocks_for_budget(budget_bytes: int, block_size: int, kv_heads: int,
                       head_dim: int, n_layers: int,
                       kv_dtype: str = "bf16", state_bytes: int = 0) -> int:
     """How many pool blocks fit a fixed byte budget (the +1 trash block is
     charged too, so the returned count is directly ``num_blocks``).
-    ``n_layers``: the layers that have K/V. ``state_bytes``: what the state
-    slots of a recurrent-state model take from the budget first."""
+    ``n_layers``: the layers that keep the whole context's K/V. ``state_bytes``:
+    what the slots of the second kind of cache (recurrent states, or the window
+    layers' rings: ``slot_bytes`` a tracked sequence and a spare) take from the
+    budget first."""
     per = bytes_per_block(block_size, kv_heads, head_dim, n_layers, kv_dtype)
     n = (budget_bytes - state_bytes) // per - 1  # -1: the engine allocates num_blocks + 1
     if n < 1:
         raise ValueError(
-            f"kv pool budget {budget_bytes} bytes ({state_bytes} of them state slots) holds no blocks at "
+            f"kv pool budget {budget_bytes} bytes ({state_bytes} of them state or window slots) holds no blocks at "
             f"{per} bytes/block (block_size={block_size}, kv_heads={kv_heads}, "
             f"head_dim={head_dim}, n_layers={n_layers}, dtype={kv_dtype})"
         )

@@ -15,6 +15,12 @@ taken when the sequence is created (admission) and given back when it is
 flushed (finish, cancel, expiry: every one of them ends in
 ``flush_sequence``). The engine's pools hold one slot more, the spare that the
 padding of a step's grid points at; the manager never hands it out.
+
+A stack that mixes window and global layers uses the same slots for its window
+layers' K/V: a slot is a ring of ``window_blocks`` blocks a window layer
+(``kv_pool.window_blocks``), so a window layer holds that many blocks a
+sequence at any context, the ``block_table`` names the global layers' blocks
+alone, and admission stalls on those alone.
 """
 
 from dataclasses import dataclass, field
@@ -41,9 +47,10 @@ class DSSequenceDescriptor:
 
 
 class DSStateManager:
-    def __init__(self, config, kv_config, state_slots: int = 0):
+    def __init__(self, config, kv_config, state_slots: int = 0, window_blocks: int = 0):
         self._config = config
         self._kv = kv_config
+        self._window_blocks = int(window_blocks)  # ring blocks a slot (0: no window pool)
         # free state slots, lowest first (0 for a model without such layers)
         self._n_state_slots = int(state_slots)
         self._free_slots: List[int] = list(range(self._n_state_slots))[::-1]
@@ -216,12 +223,22 @@ class DSStateManager:
         for seq in self._seqs.values():
             live.update(int(b) for b in seq.block_table)
         cached = set(self.prefix_cache.cached_block_ids()) if self.prefix_cache else set()
-        return {
+        out = {
             "total": self._alloc.total_blocks,
             "free": self._alloc.free_blocks,
             "live": len(live),
             "cached_only": len(cached - live),
         }
+        if self._window_blocks:
+            # the second kind: ring blocks, held by slot and never by table
+            slots = self.state_slot_accounting()
+            out.update({f"window_{k}": v * self._window_blocks for k, v in slots.items()})
+        return out
+
+    @property
+    def context_tokens(self) -> int:
+        """Tokens of context the tracked sequences hold in the cache."""
+        return sum(s.seen_tokens for s in self._seqs.values())
 
     @property
     def state_slots_in_use(self) -> int:

@@ -9,8 +9,9 @@ serves through ``deepspeed_tpu.initialize`` / ``init_inference`` unchanged.
 
 Supported ``model_type``s: llama, mistral, qwen2, qwen2_moe, qwen3, qwen3_next
 (Gated DeltaNet and gated-attention layers, a share of the experts),
-qwen3_moe (per-head q/k RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole
-projection width; the four MoE types import drop-free: ``moe_drop_tokens``
+exaone_moe (K-EXAONE: window and full attention layers, output-normed blocks,
+a dense lead layer then sigmoid-routed experts), qwen3_moe (per-head q/k
+RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole projection width; the four MoE types import drop-free: ``moe_drop_tokens``
 false), falcon, phi (incl. qk_layernorm),
 phi3, gpt2, gpt_neo, opt, gemma, bloom, gptj, gpt_neox, internlm, stablelm
 (incl. qk_layernorm), starcoder2, megatron_gpt (Megatron-LM GPT state-dict
@@ -146,6 +147,80 @@ def _llama_like_config(get, **extra) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def _exaone_moe_config(get) -> TransformerConfig:
+    """K-EXAONE (``exaone_moe``): window and full attention layers in one stack
+    (``layer_types``, window ``sliding_window``), a dense lead MLP then expert
+    layers (``mlp_layer_types``), the expert block DeepseekV3MoE's: a sigmoid
+    router with a selection bias, top-k renormalised and scaled, ungated shared
+    experts. What ``config.json`` has no key for is the family's published
+    hybrid model's (transformers ``Exaone4DecoderLayer`` / ``Exaone4Attention``):
+    RMSNorm on each block's OUTPUT, per-head q/k norm, rotary on the window
+    layers only. Under a pipeline stage ``num_hidden_layers`` is below the
+    lists' length and the stage is their head; ``deployment_share`` as for
+    qwen3_next. The multi-token-prediction layer is no part of the model's own
+    logits and is left out."""
+    n_layers = int(get("num_hidden_layers"))
+    kinds = list(get("layer_types", None) or [])[:n_layers]
+    mlps = list(get("mlp_layer_types", None) or [])[:n_layers]
+    lead = int(get("first_k_dense_replace", 0) or 0)
+    if not mlps:
+        mlps = ["dense"] * lead + ["sparse"] * (n_layers - lead)
+    if (len(kinds) != n_layers or set(kinds) - {"sliding_attention", "full_attention"}):
+        raise ValueError(f"exaone_moe: layer_types={kinds!r} for {n_layers} layers")
+    lead = mlps.index("sparse") if "sparse" in mlps else n_layers
+    if (len(mlps) != n_layers or not 0 < lead < n_layers
+            or mlps != ["dense"] * lead + ["sparse"] * (n_layers - lead)):
+        raise ValueError(
+            f"exaone_moe: mlp_layer_types={mlps!r}: supported are dense lead layers "
+            "followed by expert layers, some of each")
+    if int(get("n_group", 1) or 1) != 1 or int(get("topk_group", 1) or 1) != 1:
+        raise ValueError("exaone_moe: n_group / topk_group other than 1 (a grouped top-k) "
+                         "is not supported")
+    if get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"exaone_moe: scoring_func={get('scoring_func')!r}, expected 'sigmoid'")
+    window = int(get("sliding_window", 0) or 0)
+    local = tuple(int(k == "sliding_attention") for k in kinds)
+    if any(local) and window <= 0:
+        raise ValueError("exaone_moe: sliding_attention layers without a sliding_window")
+    if not any(local):
+        raise ValueError("exaone_moe: no sliding_attention layer: rotary sits on those alone, "
+                         "and a stack of none has no position term at all")
+    held = int(get("num_experts"))
+    share = get("deployment_share", None) or {}
+    total = int(share.get("num_experts", held))
+    chips = int(share.get("chips_per_layer", 1))
+    if total != held * chips:
+        raise ValueError(
+            f"exaone_moe: deployment_share says {chips} chips share {total} experts; "
+            f"num_experts={held} is not one chip's share of them")
+    rope = get("rope_parameters", None) or {}
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"exaone_moe: rope_type={rope.get('rope_type')!r} is not supported")
+    expert_dim = int(get("moe_intermediate_size"))
+    return _llama_like_config(
+        get,
+        rope_theta=float(rope.get("rope_theta", get("rope_theta", 10000.0))),
+        norm_scheme="out",
+        qk_norm=True,
+        head_dim_override=int(get("head_dim")),
+        sliding_window=window,
+        attn_layer_pattern=local,
+        rope_window_only=True,
+        n_experts=held,
+        moe_experts_total=total if total != held else 0,
+        moe_expert_shard=int(share.get("share_index", 0)),
+        moe_top_k=get("num_experts_per_tok"),
+        moe_norm_topk_prob=bool(get("norm_topk_prob", True)),
+        moe_drop_tokens=False,  # the published block never drops a token
+        moe_dense_lead=lead,
+        moe_expert_dim=expert_dim,
+        moe_score="sigmoid",
+        moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+        moe_shared_expert_dim=expert_dim * int(get("num_shared_experts", 0) or 0),
+        moe_shared_gated=False,
+    )
+
+
 def config_from_hf(hf_cfg) -> TransformerConfig:
     """HF config (object or dict) → TransformerConfig; dispatches on
     ``model_type`` (llama when absent)."""
@@ -258,6 +333,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
             moe_shared_expert_dim=get("shared_expert_intermediate_size", 0) or 0,
             moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
         )
+    if mt == "exaone_moe":
+        return _exaone_moe_config(get)
     if mt == "qwen2_moe":
         sparse_step = get("decoder_sparse_step", 1)
         mlp_only = get("mlp_only_layers", []) or []
@@ -727,7 +804,7 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
         "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
-        "qwen3, qwen3_moe, qwen3_next, megatron_gpt, bert, distilbert, clip_text_model"
+        "qwen3, qwen3_moe, qwen3_next, exaone_moe, megatron_gpt, bert, distilbert, clip_text_model"
     )
 
 
@@ -832,6 +909,33 @@ def _qwen3_next_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict
     layers["shared_up"].append(take.linear(f"{p}.mlp.shared_expert.up_proj.weight"))
     layers["shared_down"].append(take.linear(f"{p}.mlp.shared_expert.down_proj.weight"))
     layers["shared_gate_proj"].append(take.linear(f"{p}.mlp.shared_expert_gate.weight"))
+
+
+def _exaone_moe_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
+    """One K-EXAONE layer: Exaone4's attention and output norms, then a dense
+    MLP (the lead layers, under ``lead``) or DeepseekV3MoE's block (under
+    ``sparse``: the router whole, the chip's own experts, the shared experts
+    as one MLP of their summed width)."""
+    i = int(p.rsplit(".", 1)[1])
+    layers["attn_norm"].append(take(f"{p}.post_attention_layernorm.weight"))
+    layers["mlp_norm"].append(take(f"{p}.post_feedforward_layernorm.weight"))
+    for name in ("q", "k", "v", "o"):
+        layers[f"w{name}"].append(take.linear(f"{p}.self_attn.{name}_proj.weight"))
+    layers["q_norm"].append(take(f"{p}.self_attn.q_norm.weight"))
+    layers["k_norm"].append(take(f"{p}.self_attn.k_norm.weight"))
+    names = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+    if i < cfg.moe_dense_lead:
+        for name, hf in names:
+            layers["lead"][name].append(take.linear(f"{p}.mlp.{hf}.weight"))
+        return
+    moe = layers["sparse"]
+    moe["router"].append(take.linear(f"{p}.mlp.gate.weight"))
+    moe["router_bias"].append(take(f"{p}.mlp.gate.e_score_correction_bias"))
+    first = cfg.moe_expert_shard * cfg.n_experts
+    for name, hf in names:
+        moe[name].append(np.stack([
+            take.linear(f"{p}.mlp.experts.{first + e}.{hf}.weight") for e in range(cfg.n_experts)]))
+        moe[f"shared_{name[2:]}"].append(take.linear(f"{p}.mlp.shared_experts.{hf}.weight"))
 
 
 def _phi3_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
@@ -1208,6 +1312,7 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "qwen2_moe": _llama_layer,
     "qwen3": _llama_layer,
     "qwen3_next": _qwen3_next_layer,
+    "exaone_moe": _exaone_moe_layer,
     "qwen3_moe": _llama_layer,
     "falcon": _falcon_layer,
     "phi": _phi_layer,
@@ -1241,6 +1346,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
     "qwen2_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_next": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "exaone_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi": ("model.embed_tokens.weight", "model.final_layernorm", "model.layers", None),
@@ -1298,8 +1404,18 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
         keys.append("wq_gate")
     if cfg.n_experts > 0:
         keys.append("router")
+        if cfg.moe_score == "sigmoid":
+            keys.append("router_bias")
         if cfg.moe_shared_expert_dim > 0:
-            keys += ["shared_gate", "shared_up", "shared_down", "shared_gate_proj"]
+            keys += ["shared_gate", "shared_up", "shared_down"]
+            if cfg.moe_shared_gated:
+                keys.append("shared_gate_proj")
+    if cfg.moe_dense_lead:  # the MLPs stacked apart (transformer.init_params)
+        mlp = ("w_up", "w_down", "w_gate")
+        out = {k: [] for k in keys if k not in mlp and not k.startswith(("router", "shared_"))}
+        out["lead"] = {k: [] for k in mlp}
+        out["sparse"] = {k: [] for k in keys if k in mlp or k.startswith(("router", "shared_"))}
+        return out
     if cfg.hybrid:  # stacked by kind (transformer.init_params)
         from deepspeed_tpu.models.transformer import ATTENTION_KEYS
 

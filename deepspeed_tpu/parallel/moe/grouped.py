@@ -197,15 +197,28 @@ def grouped_matmul(lhs, rhs, group_sizes, tm: int, impl: Optional[str] = None, l
     return _gmm_kernel_vjp(lhs, rhs, group_sizes, jnp.asarray(layer, jnp.int32), tm, impl == "interpret")
 
 
-def route(config, logits, live=None):
+def route(config, logits, live=None, bias=None):
     """Router as published. logits ``[t, E]`` float32 over every expert of the
-    layer, held here or not. Returns (gate values ``[t, k]`` float32, expert
-    ids ``[t, k]``, aux loss)."""
+    layer, held here or not. ``moe_score`` "softmax": the k most probable,
+    renormalised where the model says so. "sigmoid" (DeepseekV3TopkRouter with
+    one group): scores ``sigmoid(logits)``, the k CHOSEN on ``score + bias``
+    (``bias [E]``: the checkpoint's e_score_correction_bias) and weighted by
+    the score alone, renormalised, times ``moe_routed_scale``. Returns (gate
+    values ``[t, k]`` float32, expert ids ``[t, k]``, aux loss)."""
     E, k = logits.shape[-1], config.moe_top_k
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)
-    if config.moe_norm_topk_prob:
-        top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+    if config.moe_score == "sigmoid":
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        choose = probs if bias is None else probs + bias.astype(jnp.float32)
+        top_e = jax.lax.top_k(choose, k)[1]
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+        if config.moe_norm_topk_prob:
+            top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+        top_p = top_p * config.moe_routed_scale
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, k)
+        if config.moe_norm_topk_prob:
+            top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
     # load-balancing loss of topkgating (sharded_moe.py): E/k * <probs_e> . <share_e>
     chosen = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1)  # [t, E]
     w = jnp.ones(probs.shape[0], jnp.float32) if live is None else live.astype(jnp.float32)
@@ -226,7 +239,7 @@ def experts_grouped(config, lp, tokens, logits, live=None, layer=None
     each held expert)."""
     t, h = tokens.shape
     E, k = config.n_experts, config.moe_top_k
-    top_p, top_e, aux = route(config, logits, live)
+    top_p, top_e, aux = route(config, logits, live, lp.get("router_bias"))
 
     tm = row_tile(t * k, tokens.dtype.itemsize)
     m = -(-t * k // tm) * tm

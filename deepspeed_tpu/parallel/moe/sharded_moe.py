@@ -342,6 +342,10 @@ def moe_mlp(config, lp, x: jax.Array, live: Optional[jax.Array] = None, layer=No
 
         out, l_aux, counts = experts_grouped(config, lp, tokens, logits, live, layer)
         return _moe_tail(config, lp, tokens, out).reshape(b, s, h), l_aux, counts
+    if config.moe_score != "softmax":
+        raise NotImplementedError(
+            "a sigmoid-scored router with a selection bias runs the grouped dispatch "
+            "(moe_drop_tokens=False, expert and model axes of 1); topkgating is softmax only")
     if config.router_width != config.n_experts:
         raise NotImplementedError(
             "an expert share (moe_experts_total > n_experts) runs the grouped "
@@ -409,8 +413,11 @@ def _moe_tail(config, lp, tokens, out):
     if config.moe_shared_expert_dim > 0 and "shared_up" in lp:
         # qwen2-moe shared expert: always-on dense expert scaled by a
         # sigmoid gate (HF Qwen2MoeSparseMoeBlock.shared_expert_gate)
-        gate = jax.nn.sigmoid((tokens @ lp["shared_gate_proj"]).astype(jnp.float32))
-        out = out + gate.astype(out.dtype) * _dense_mlp("shared")
+        shared = _dense_mlp("shared")
+        if config.moe_shared_gated:
+            gate = jax.nn.sigmoid((tokens @ lp["shared_gate_proj"]).astype(jnp.float32))
+            shared = gate.astype(out.dtype) * shared
+        out = out + shared
     return out
 
 

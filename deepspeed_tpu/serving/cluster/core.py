@@ -204,6 +204,12 @@ class EngineCore:
             self._inc("moe_experts_hit_total", moe.get("hit", 0))
         # a model with DeltaNet layers: the rows whose states took the update
         self._inc("gdn_decode_rows_total", held("gdn_decode_rows"))
+        # the cache as the step found it, by kind, summed a step; and what a
+        # window layer's decode walks visit (beside paged_live_blocks_total)
+        self._inc("kv_global_blocks_used_total", held("kv_global_blocks"))
+        self._inc("kv_window_blocks_used_total", held("kv_window_blocks"))
+        self._inc("kv_context_tokens_total", held("kv_context_tokens"))
+        self._inc("paged_window_live_blocks_total", held("paged_window_live_blocks"))
 
     # -- admission accounting --------------------------------------------
     def blocks_needed(self, req: Request, prefill_only: bool = False) -> int:

@@ -288,6 +288,8 @@ class ServingDriver:
                 ),
                 # the second kind of cache (0 / 0 without recurrent-state layers)
                 "state_slots_total": self._kv_info.get("state_slots", 0),
+                # ... or the window layers' rings, held by the same slots
+                "window_slots_total": self._kv_info.get("window_slots", 0),
                 "state_slots_in_use": self.core.state_slots()["live"],
                 "kv_host_tier": self._host_tier_health(),
                 "spec": {

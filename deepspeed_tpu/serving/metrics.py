@@ -148,6 +148,14 @@ class ServingMetrics:
             # recurrent state took the one-token update, of one such layer
             # a step
             "gdn_decode_rows_total": 0,
+            # the cache by kind (EngineCore._count_step), summed a step: blocks
+            # of the block pool held, ring blocks of the window pool held (0
+            # without one), tokens of context tracked; and the blocks one
+            # window layer's decode walks visit
+            "kv_global_blocks_used_total": 0,
+            "kv_window_blocks_used_total": 0,
+            "kv_context_tokens_total": 0,
+            "paged_window_live_blocks_total": 0,
             "admission_blocked_total": 0,
             # prefix cache (mirrors of PrefixCache's monotone counters)
             "prefix_queries_total": 0,

@@ -24,14 +24,17 @@ def _programs(kind):
     """(engine, {name: (jitted, args)}) after two same-shape generate()
     passes: pass 1 traces, pass 2 must hit the caches. ``decode_only_step``
     is the split step's shape for a batch with no chunk row."""
-    return dv._engine_v2_programs("bf16", model="gdn") if kind == "gdn" else dv._engine_v2_programs(kind)
+    if kind in ("gdn", "window"):
+        return dv._engine_v2_programs("bf16", model=kind)
+    return dv._engine_v2_programs(kind)
 
 
 # "gdn": a model with Gated DeltaNet layers over a bf16 pool; its pools
 # argument ends with the recurrent-state and conv pools, and it has no verify
-# step (refused at build)
-CASES = [(p, d) for d in ("bf16", "int8", "gdn") for p in PROGRAMS
-         if not (d == "gdn" and p == "verify_step")]
+# step (refused at build). "window": window and global layers in one stack;
+# its pools argument ends with the window pools (k, v), likewise no verify step
+CASES = [(p, d) for d in ("bf16", "int8", "gdn", "window") for p in PROGRAMS
+         if not (d in ("gdn", "window") and p == "verify_step")]
 
 
 @pytest.mark.parametrize("program,kv_dtype", CASES)
@@ -45,7 +48,7 @@ def test_step_programs_alias_every_pool_leaf(program, kv_dtype):
     # the donated buffers ARE the pools' leaves (k, v and, for int8, the two
     # scale planes), and nothing else is donated
     pools = eng._pools()
-    assert len(pools) == (2 if kv_dtype == "bf16" else 4)
+    assert len(pools) == (2 if kv_dtype == "bf16" else 4)  # gdn, window: two more pools
     got = sorted((tuple(b.shape), b.dtype) for b in res.buffers)
     assert got == sorted((tuple(p.shape), str(p.dtype)) for p in pools)
 

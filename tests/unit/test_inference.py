@@ -396,7 +396,8 @@ class TestInferenceV2:
         # ... and its chunk attention: no pool block below the chunk + 2 of
         # its own, of the 8 table slots + 4 chunk blocks a dense walk covers
         assert dataclasses.replace(prefill, moe=None) == StepStats(
-            4 + 64, 20, 20, 0, 4 * 8, chunk_live_blocks=2, chunk_table_slots=8 + 4)
+            4 + 64, 20, 20, 0, 4 * 8, chunk_live_blocks=2, chunk_table_slots=8 + 4,
+            kv_global_blocks=2, kv_context_tokens=20)  # the cache as the step found it
         engine.scheduler.feedback(0, toks[0])
         if entry.startswith("step_tokens"):
             engine.scheduler.feedback(0, engine.step_tokens()[0])
@@ -413,15 +414,18 @@ class TestInferenceV2:
                 moe = {"routed": 2 * 2, "computed": 2 * 2 * tile, "hot": 2, "calls": 2,
                        "hit": 2 * 2}
                 assert prefill.moe["routed"] == 20 * 2 * 2
-            assert engine.last_step == StepStats(4, 1, 0, 2, 4 * 8, moe)
+            assert engine.last_step == StepStats(
+                4, 1, 0, 2, 4 * 8, moe, kv_global_blocks=2, kv_context_tokens=21)
         elif entry == "decode_round":
             assert len(engine.decode_round(3)[0]) == 3
             # a round's 3 kernel calls a layer walk the round-start window
-            assert engine.last_step == StepStats(4 * 3, 3, 0, 3 * 2, 3 * 4 * 8)
+            assert engine.last_step == StepStats(
+                4 * 3, 3, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=23)
         else:
             assert 1 <= len(engine.spec_round(2, drafts={0: [5]})[0]) <= 2
             # the pending token and one draft on a grid of R x (k + 1)
-            assert engine.last_step == StepStats(4 * 3, 2, 0, 3 * 2, 3 * 4 * 8)
+            assert engine.last_step == StepStats(
+                4 * 3, 2, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=22)
         assert engine.last_step is not prefill and prefill.prefill_tokens == 20
         # a step with nothing to schedule starts from zeros again
         engine.scheduler.finish(0)

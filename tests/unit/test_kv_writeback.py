@@ -4,7 +4,10 @@ the same tokens computes, every slot the step did not schedule is
 byte-identical to before the step, and the served tokens equal generate()'s.
 The run mixes decode rows, two prompt chunks in one step, a prefix-cache hit
 and padded (trash) slots; cases: bf16 and int8 pools, an alternating-window
-stack (the unrolled layer loop) and tp=2 on the virtual CPU devices."""
+stack (the unrolled layer loop; its window layer's K/V live in the window pool:
+the block pool is its global layer's, the ring's rows that a later query can see
+are held to the dense K/V after every step, and the prefix cache is off) and
+tp=2 on the virtual CPU devices."""
 
 import numpy as np
 import pytest
@@ -50,8 +53,8 @@ def _prompts(vocab):
 
 
 def _pools(eng):
-    """The pools (and int8 scale planes) on the host, as [L, NBp, bs, nkv(, d)]."""
-    return [np.asarray(p) for p in eng._pools()]
+    """The block pools (and int8 scale planes) on the host, as [L, NBp, bs, nkv(, d)]."""
+    return [np.asarray(p) for p in eng._kv_pool_planes().values()]
 
 
 def _rows(pools, table, n):
@@ -59,6 +62,16 @@ def _rows(pools, table, n):
     pos = np.arange(n)
     blk = np.asarray(table)[pos // BS]
     return [p[:, blk, pos % BS] for p in pools]
+
+
+def _ring_rows(eng, seq):
+    """What a window layer's ring holds of ``seq`` that a later query can see,
+    positions ``p0 .. seen - 1``: (p0, [Lw, seen - p0, nkv, d] of K, of V)."""
+    wb, n = eng._win_blocks, seq.seen_tokens
+    pos = np.arange(max(0, n - eng._mc.sliding_window + 1), n)
+    blk = seq.state_slot * wb + (pos // BS) % wb
+    return (int(pos[0]),) + tuple(
+        np.asarray(p)[:, blk, pos % BS] for p in (eng._wk_cache, eng._wv_cache))
 
 
 def _dense_kv(cfg, params, tokens):
@@ -73,11 +86,13 @@ def _dense_kv(cfg, params, tokens):
 def _serve(eng, prompts):
     """A at step 0; B and C together once A decodes. Checks after every step
     that only scheduled slots (and the trash block) changed. Returns the
-    streams, each sequence's last pool rows, and what the steps mixed."""
+    streams, each sequence's last pool rows, what the steps mixed, the prefix
+    hit, and for a window pool each sequence's ring rows after every step."""
     sm, sched = eng.state_manager, eng.scheduler
     trash = eng.config.kv_cache.num_blocks
     streams = {u: list(p) for u, p in prompts.items()}
     live, rows, mixed, shared = {0}, {}, [], None
+    rings = {u: [] for u in prompts}
     sched.submit(0, prompts[0])
     for _ in range(40):
         if not live:
@@ -112,6 +127,8 @@ def _serve(eng, prompts):
             for old, cur in zip(rows.get(u, []), new):  # older rows never change
                 np.testing.assert_array_equal(old, cur[:, :old.shape[1]])
             rows[u] = new
+            if eng._windowed and seq.seen_tokens:
+                rings[u].append(_ring_rows(eng, seq))
         for u, tok in out.items():
             streams[u].append(int(tok))
             if len(streams[u]) - len(prompts[u]) >= MAX_NEW:
@@ -120,7 +137,7 @@ def _serve(eng, prompts):
             else:
                 sched.feedback(u, int(tok))
     assert not live, f"sequences still running: {live}"
-    return streams, rows, mixed, shared
+    return streams, rows, mixed, shared, rings
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -141,7 +158,7 @@ def test_pool_holds_dense_kv_and_only_scheduled_slots_change(case, devices8):
             assert eng._k_cache.sharding.spec[3] is not None
         if "model" in spec:
             assert isinstance(eng._layer_windows(), list)  # the unrolled loop
-        streams, rows, mixed, shared = _serve(eng, prompts)
+        streams, rows, mixed, shared, rings = _serve(eng, prompts)
         oracle = _engine(cfg, params, kv_dtype, tp).generate(
             [prompts[u] for u in sorted(prompts)], max_new_tokens=MAX_NEW)
     finally:
@@ -150,7 +167,10 @@ def test_pool_holds_dense_kv_and_only_scheduled_slots_change(case, devices8):
     # what the run was meant to mix
     assert any(c >= 2 and d >= 1 for c, d in mixed), mixed  # two chunks beside a decode row
     blocks_a, blocks_b, cached = shared
-    assert blocks_a == blocks_b and cached == 2 * BS  # the prefix-cache hit
+    if eng._windowed:  # a hit would skip the window pool: the cache is off
+        assert eng.prefix_cache is None and cached == 0
+    else:
+        assert blocks_a == blocks_b and cached == 2 * BS  # the prefix-cache hit
     assert any(len(p) % 8 for p in prompts.values())  # a chunk shorter than its bucket
 
     for u, want in zip(sorted(prompts), oracle):
@@ -160,6 +180,17 @@ def test_pool_holds_dense_kv_and_only_scheduled_slots_change(case, devices8):
         n = rows[u][0].shape[1]
         assert n == len(streams[u]) - 1  # all but the pending token are in the pool
         ref_k, ref_v = _dense_kv(cfg, params, streams[u][:n])
+        kinds = T.cache_kinds(cfg)
+        ring = [i for i, kind in enumerate(kinds) if kind == "window"]
+        assert bool(ring) == bool(rings[u]) == (case == "alternating")
+        for p0, got_k, got_v in rings[u]:  # the window pool's layers, after every step
+            m = got_k.shape[1]
+            np.testing.assert_allclose(got_k, ref_k[ring][:, p0:p0 + m], rtol=1e-4, atol=1e-5)
+            np.testing.assert_allclose(got_v, ref_v[ring][:, p0:p0 + m], rtol=1e-4, atol=1e-5)
+        if rings[u]:  # ... through a wrap of the ring and up to the last step
+            assert rings[u][-1][0] + rings[u][-1][1].shape[1] == n > eng._win_blocks * BS
+        held = [i for i, kind in enumerate(kinds) if kind == "full"]
+        ref_k, ref_v = ref_k[held], ref_v[held]  # the block pool's layers
         if kv_dtype == "int8":
             for ref, q, s in ((ref_k, rows[u][0], rows[u][2]), (ref_v, rows[u][1], rows[u][3])):
                 # layer 0 sees no quantized context: its stored bytes are the
