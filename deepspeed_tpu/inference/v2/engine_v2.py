@@ -22,8 +22,12 @@ TPU adaptation:
 
 Every step goes one way: ``_stage_<shape>`` (numpy only: the inputs by name,
 and the step's ``StepStats``) → ``_launch`` (the one call site of a step
-program; the pools go in and come back as one donated argument) →
-``_dispatch_and_collect`` (host copies, wait, materialize: one span bracket).
+program; the pools go in and come back as one donated argument), both under
+``_dispatch``, which returns the ``StepInFlight`` → ``_collect`` (host
+copies, wait, materialize). A served split step is the pair ``launch_step``
+/ ``collect_step``: the serving core launches step n+1 before it collects
+step n, and a decode row of n+1 takes the token step n sampled from the
+device (``last_tokens`` / ``tok_src``), never through the host.
 """
 
 import dataclasses
@@ -128,8 +132,9 @@ class StepStats:
     the step is staged (``moe`` after its wait). The serving core folds it
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
     paged_live_blocks_total / paged_table_slots_total, chunk_live_blocks_total
-    / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total and
-    paged_window_live_blocks_total."""
+    / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total,
+    paged_window_live_blocks_total, steps_ahead_total and
+    ahead_rows_dropped_total."""
 
     grid_slots: int = 0
     scheduled_tokens: int = 0
@@ -158,6 +163,30 @@ class StepStats:
     kv_window_blocks: int = 0
     kv_context_tokens: int = 0
     paged_window_live_blocks: int = 0
+    # one step in flight (the serving core fills both): the step was launched
+    # before its predecessor was collected; rows it computed for a request
+    # that had stopped by the time it was collected
+    ahead: bool = False
+    ahead_rows_dropped: int = 0
+
+
+@dataclasses.dataclass
+class StepInFlight:
+    """A launched step or round, not yet waited for: everything that is the
+    STEP's and not the engine's, so that the next step may be launched before
+    this one is collected. ``waited``: the program's outputs the results come
+    from (never the engine's pools: by collect time those are the next
+    step's); ``finish``: outputs on the host -> the entry point's result;
+    ``stats``: the step's StepStats (``moe`` filled at collect from ``moe``,
+    an expert model's routed rows, still on the device); ``rows``: for a
+    split step, each completed row's uid -> its slot of the program's
+    ``last_tokens`` output (decode slot i, or R + chunk row j)."""
+
+    waited: list
+    finish: object
+    stats: StepStats
+    moe: object = None
+    rows: Dict[int, int] = dataclasses.field(default_factory=dict)
 
 
 class InferenceEngineV2:
@@ -418,9 +447,14 @@ class InferenceEngineV2:
         self._spec_rr = 0  # rotation cursor for budget-capped spec rounds
         self.last_spec = {"drafted": 0, "accepted": 0, "per_uid": {}}
         self.last_step = StepStats()
-        # an expert model's [.., L, E] routed rows of the step just launched,
-        # on the device until _count_moe reduces them into last_step.moe
+        # an expert model's [.., L, E] routed rows of the step being launched:
+        # _dispatch moves them into the step's StepInFlight
         self._moe_pending = None
+        # the last split step's sampled tokens by output slot, on the device:
+        # the next one's ``last_tokens``
+        self._last_tokens = jnp.zeros(
+            self.config.state_manager.max_ragged_sequence_count
+            + self.scheduler.max_prompt_chunks, jnp.int32)
         self.last_capped = set()
         # sampling state: one base key; programs fold in each row's (uid,
         # source position) so a token's key is content-addressed — invariant
@@ -1779,14 +1813,26 @@ class InferenceEngineV2:
         is the R decode slots, the program takes no ``chk_*`` input and
         runs no chunk attention (the fused round's and the verify step's
         grids are R rows wide already). Outputs: (decode logits [R, vocab],
-        chunk logits [Rc, vocab], decode tokens [R], chunk tokens [Rc]); the
-        chunk pair is None at ``tq == 0``, where no row reads it."""
+        chunk logits [Rc, vocab], decode tokens [R], chunk tokens [Rc],
+        ``last_tokens`` [R + Rc]); the chunk pair is None at ``tq == 0``,
+        where no row reads it.
+
+        One step in flight: every shape takes the PREVIOUS split step's
+        ``last_tokens`` (its sampled tokens by output slot: decode slots,
+        then chunk rows, zeros where it had none) and ``tok_src`` [R]: the
+        slot a decode row's token comes from, or -1 for the host's
+        ``tokens[i]``. So a row whose token is still on the device is
+        launched without it, and the program returns its own
+        ``last_tokens`` for the next."""
         R = self.config.state_manager.max_ragged_sequence_count
         Rc = self.scheduler.max_prompt_chunks
 
         def step(params, inputs, rng, temperature, pools):
             tokens, positions = inputs["tokens"], inputs["positions"]
             dec_pos = inputs["dec_pos"]
+            tok_src = inputs["tok_src"]
+            tokens = tokens.at[:R].set(jnp.where(
+                tok_src >= 0, inputs["last_tokens"][jnp.maximum(tok_src, 0)], tokens[:R]))
             x = self._embed(params, tokens, positions)
             pools, second = self._split_pools(pools)
             meta = {
@@ -1831,7 +1877,11 @@ class InferenceEngineV2:
                 chk_at = jnp.clip(inputs["chk_last"], 0, tokens.shape[0] - 1)
                 logits_chk, toks_chk = self._sample_rows(
                     params, x, chk_at, rng, temperature, inputs["chk_uids"], positions[chk_at])
-            return (logits_dec, logits_chk, toks_dec, toks_chk), pools, self._moe_rows(side)
+            last_tokens = jnp.concatenate([
+                toks_dec.astype(jnp.int32),
+                jnp.zeros(Rc, jnp.int32) if toks_chk is None else toks_chk.astype(jnp.int32)])
+            return ((logits_dec, logits_chk, toks_dec, toks_chk, last_tokens), pools,
+                    self._moe_rows(side))
 
         return jax.jit(step, donate_argnums=(4,))
 
@@ -2097,10 +2147,12 @@ class InferenceEngineV2:
     # -- the step protocol: stage -> launch -> collect ----------------------
     def _stage_split(self, total_tokens, dec_rows, chk_rows):
         """The scheduler's batch onto the fixed [R decode slots | Rc chunks x
-        tq] grid: ``dec_rows`` (uid, tokens, start), ``chk_rows`` (uid,
-        tokens, start, chunked). Returns the split step's cache key and its
-        inputs by name: the seven a decode slot needs and, for a batch that
-        holds a chunk, the five ``chk_*``."""
+        tq] grid: ``dec_rows`` (uid, tokens, start, src: the slot of the
+        previous split step's ``last_tokens`` its token is in, -1 for a
+        token the host has), ``chk_rows`` (uid, tokens, start, chunked).
+        Returns the split step's cache key and its inputs by name: the nine a
+        decode slot needs and, for a batch that holds a chunk, the five
+        ``chk_*``."""
         kv = self.config.kv_cache
         R = self.config.state_manager.max_ragged_sequence_count
         Rc = self.scheduler.max_prompt_chunks
@@ -2129,6 +2181,7 @@ class InferenceEngineV2:
         dec_tables = np.full((R, B), trash, np.int32)
         dec_pos = np.full(R, -1, np.int32)  # -1 = inactive slot (masks all)
         dec_uids = np.zeros(R, np.int32)
+        tok_src = np.full(R, -1, np.int32)
         chk_tables = np.full((Rc, B), trash, np.int32)
         chk_pos = np.full((Rc, tq), -1, np.int32)
         chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
@@ -2142,10 +2195,11 @@ class InferenceEngineV2:
         wb = self._win_blocks
         wblk = np.full(T_, spare * wb, np.int32)
 
-        for i, (uid, toks, start) in enumerate(dec_rows):
+        for i, (uid, toks, start, src) in enumerate(dec_rows):
             seq = self.state_manager.get_sequence(uid)
             dec_slots[i] = seq.state_slot
             tokens[i] = toks[0]
+            tok_src[i] = src
             positions[i] = start
             nblk = len(seq.block_table)
             dec_tables[i, :nblk] = seq.block_table
@@ -2188,6 +2242,8 @@ class InferenceEngineV2:
         inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,
             "dec_tables": dec_tables, "dec_pos": dec_pos, "dec_uids": dec_uids,
+            # the previous split step's tokens, where they are: on the device
+            "tok_src": tok_src, "last_tokens": self._last_tokens,
         }
         if tq:  # the decode-only shape takes, and is sent, no chunk input
             inputs.update(
@@ -2292,51 +2348,68 @@ class InferenceEngineV2:
         with tr.span("engine.launch", track=track):
             return self._launch(key, inputs)
 
-    def _dispatch_and_collect(self, dispatch):
-        """The one bracket of a served step, on this replica's engine track.
+    def _dispatch(self, dispatch) -> StepInFlight:
+        """The first half of a served step, on this replica's engine track.
         ``dispatch()`` runs under ``engine.dispatch`` (host-side scheduling,
         staging and the async launch: ``engine.schedule`` / ``engine.stage``
         / ``engine.launch`` nest in it) and returns (the device arrays the
-        results come from, ``finish``, the dispatch span's args). The
-        arrays' host copies are requested between dispatch and the wait, in
-        neither span; ``engine.device_wait`` blocks on them;
-        ``engine.materialize`` reduces an expert model's routed rows and
-        runs ``finish()``, whose value is returned. So host-side queueing
-        and device time separate on the timeline. One path, traced or not:
+        results come from, ``finish``, the dispatch span's args[, the rows'
+        output slots]). What staging and the launch left on ``self`` for the
+        step (its StepStats, an expert model's routed rows) moves into the
+        record, and the arrays' host copies are requested now, queued
+        behind the program: nothing here waits. One path, traced or not:
         the null tracer's spans are a shared no-op."""
         tr = get_tracer()
-        track = getattr(self, "_trace_name", "engine")
-        with tr.span("engine.dispatch", track=track) as sp:
-            waited, finish, span_args = dispatch()
+        with tr.span("engine.dispatch", track=getattr(self, "_trace_name", "engine")) as sp:
+            waited, finish, span_args, *rows = dispatch()
             if tr.enabled:
                 sp.args = span_args
+        moe, self._moe_pending = self._moe_pending, None
         # an expert model's routed rows come from the same program: nothing
         # more to wait for, but for a step that completed no row
-        waited = list(waited) + ([] if self._moe_pending is None else [self._moe_pending])
-        _start_host_copies(waited)
+        flight = StepInFlight(
+            list(waited) + ([] if moe is None else [moe]), finish, self.last_step, moe, *rows)
+        _start_host_copies(flight.waited)
+        return flight
+
+    def _collect(self, flight: StepInFlight):
+        """The second half: ``engine.device_wait`` blocks on the step's OWN
+        outputs; ``engine.materialize`` reduces an expert model's routed
+        rows and runs ``finish()``, whose value is returned. So host-side
+        queueing and device time separate on the timeline.
+        ``engine.last_step`` is the collected step's again."""
+        tr = get_tracer()
+        track = getattr(self, "_trace_name", "engine")
         with tr.span("engine.device_wait", track=track):
             # A step that completed no row (prompt chunks with more to come
-            # and no decode row) has nothing to copy; it is waited for all
-            # the same, on the pool it returns. Left to run ahead, a long
-            # prompt's chunk steps queue on the device and the last one's
-            # wait holds the serving loop for all of them (no admission, no
-            # cancel, for up to max_context / prompt_chunk steps), and a
-            # chunk step costs one thing beside a decode row and another
-            # without: a first-token tail then reads which of the two its
-            # arrivals happened to meet.
-            device_synchronize(waited or [self._k_cache])
+            # and no decode row) is waited for all the same, on the tokens
+            # its program returns. Left to run on, a long prompt's chunk
+            # steps queue on the device and the last one's wait holds the
+            # serving loop for all of them (no admission, no cancel, for up
+            # to max_context / prompt_chunk steps), and a chunk step costs
+            # one thing beside a decode row and another without: a
+            # first-token tail then reads which of the two its arrivals
+            # happened to meet. So at most ONE step is in flight beyond the
+            # one waited for here (EngineCore.step_once).
+            device_synchronize(flight.waited)
         with tr.span("engine.materialize", track=track):
-            self._count_moe()
-            return finish()
+            self.last_step = flight.stats
+            self._count_moe(flight)
+            return flight.finish()
 
-    def _count_moe(self) -> None:
+    def _dispatch_and_collect(self, dispatch):
+        """A step that is waited for where it is launched: the fused round,
+        the verify step, and ``step_tokens``."""
+        return self._collect(self._dispatch(dispatch))
+
+    def _count_moe(self, flight: StepInFlight) -> None:
         """After the wait: reduce the step's routed rows ([L, E], or a fused
-        round's [n_steps, L, E]) to ``last_step.moe``. One layer call a row
+        round's [n_steps, L, E]) to its ``stats.moe``. One layer call a row
         of E: rows routed, rows the dispatch computed (the grouped kernel:
         its tile size for every tile visit; the capacity dispatch: E x
         capacity), the fullest expert's rows and the experts that had a row.
         None stays for a dense model or a step that launched nothing."""
-        pending, self._moe_pending = self._moe_pending, None
+        pending, flight.moe = flight.moe, None
         if pending is None:
             return
         from deepspeed_tpu.parallel.moe import grouped, sharded_moe
@@ -2346,14 +2419,14 @@ class InferenceEngineV2:
         counts = rows.reshape(-1, c.n_experts)
         # tokens of one layer call: the grid, a step of it for a fused round
         steps = rows.shape[0] if rows.ndim == 3 else 1
-        pairs = self.last_step.grid_slots // steps * c.moe_top_k
+        pairs = flight.stats.grid_slots // steps * c.moe_top_k
         if c.moe_drop_tokens:
             computed = counts.shape[0] * c.n_experts * sharded_moe._capacity(
                 pairs, c.n_experts, c.moe_capacity_factor)
         else:
             itemsize = jnp.dtype(T.DTYPES[c.dtype]).itemsize
             computed = grouped.computed_rows(counts, grouped.row_tile(pairs, itemsize))
-        self.last_step.moe = {
+        flight.stats.moe = {
             "routed": int(counts.sum()), "computed": int(computed),
             "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
             "hit": int((counts > 0).sum()),
@@ -2513,39 +2586,57 @@ class InferenceEngineV2:
         (one sync per *phase*, not per step)."""
         return _materialize_rows(self._step_device())
 
-    def step_tokens(self) -> Dict[int, int]:
-        """One engine step returning ``{uid: next-token int}`` for rows that
-        completed a prompt or decode token — the serving driver's step
-        primitive. Takes the IN-PROGRAM sampled token (greedy or sampled per
-        the engine's static sampling config), never a host argmax, so driven
-        serving reproduces ``generate()`` token-for-token. The wait wraps
-        the CALLER of ``_step_device`` (_dispatch_and_collect) — that
-        function itself must stay sync-free so ``generate()``'s prefill
-        pipelining is untouched. Every step is waited for here, one that
-        completed no row too (``{}``): a served step is synchronous."""
+    def launch_step(self) -> StepInFlight:
+        """The first half of a served step: schedule, stage and launch the
+        split step, nothing waited for. The serving core calls this for
+        step n+1 BEFORE ``collect_step`` of step n (one step in flight): the
+        scheduler then hands out step n's rows with their tokens still on
+        the device (``scheduler.expect``), and the program reads them from
+        the previous one's ``last_tokens``. Takes the IN-PROGRAM sampled
+        token (greedy or sampled per the engine's static sampling config),
+        never a host argmax, so driven serving reproduces ``generate()``
+        token-for-token."""
 
         def dispatch():
-            res = self._step_device()
-            # the arrays the tokens come from (rows of one step share them)
-            waited = {id(a): a for a in (_entry_array(e, True)[0] for e in res.values())}
+            _, rows, last = self._launch_batch()
 
             def finish():
-                return {
-                    uid: int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
-                    for uid, tok in _materialize_rows(res, want_tokens=True).items()
-                }
+                host = np.asarray(last) if rows else ()
+                return {uid: int(host[slot]) for uid, slot in rows.items()}
 
-            return waited.values(), finish, {
-                "rows": len(res), "tokens": self.last_step.scheduled_tokens}
+            # a step that launched nothing (no batch) has nothing to wait on
+            return ([] if last is None else [last]), finish, {
+                "rows": len(rows), "tokens": self.last_step.scheduled_tokens}, rows
 
-        return self._dispatch_and_collect(dispatch)
+        return self._dispatch(dispatch)
+
+    def collect_step(self, flight: StepInFlight) -> Dict[int, int]:
+        """The second half: wait for ``flight``'s own outputs and return
+        ``{uid: next-token int}`` for the rows that completed a prompt or a
+        decode token (``{}`` for a step that completed none: waited for all
+        the same)."""
+        return self._collect(flight)
+
+    def step_tokens(self) -> Dict[int, int]:
+        """One served step, waited for where it is launched:
+        ``collect_step(launch_step())``. What ``warm_*``, the probe and
+        ``analysis/verify.py`` drive, and a serving core that cannot run one
+        step ahead."""
+        return self.collect_step(self.launch_step())
 
     def _step_device(self) -> Dict[int, jax.Array]:
-        """The split-phase step: schedule, stage the batch (_stage_split),
-        run ONE compiled program, return {uid: DEVICE logits row} for rows
-        whose prompt (or decode token) completed — no host sync happens
-        here, so consecutive prefill steps are dispatched without one wait
-        between them."""
+        """The split-phase step for ``generate()`` and ``step()``: {uid:
+        DEVICE logits row} for rows whose prompt (or decode token)
+        completed — no host sync happens here, so consecutive prefill steps
+        are dispatched without one wait between them."""
+        return self._launch_batch()[0]
+
+    def _launch_batch(self):
+        """Schedule, stage the batch (_stage_split), run ONE compiled
+        program; sync-free. Returns (``{uid: (logits array, row, token
+        array)}`` for the rows that completed, ``{uid: slot of
+        last_tokens}`` for the same rows, the program's ``last_tokens``);
+        ({}, {}, None) when the scheduler had no batch."""
         tr = get_tracer()
         with tr.span("engine.schedule", track=getattr(self, "_trace_name", "engine")):
             batch = self.scheduler.next_batch()
@@ -2553,11 +2644,12 @@ class InferenceEngineV2:
         self.last_step = StepStats()
         self._moe_pending = None
         if batch is None:
-            return {}
+            return {}, {}, None
         dec_rows = [
-            (uid, toks, start)
-            for uid, toks, start, dec in zip(
-                batch.uids, batch.tokens, batch.start_positions, batch.is_decode
+            (uid, toks, start, src)
+            for uid, toks, start, src, dec in zip(
+                batch.uids, batch.tokens, batch.start_positions, batch.token_src,
+                batch.is_decode,
             )
             if dec
         ]
@@ -2569,23 +2661,27 @@ class InferenceEngineV2:
             )
             if not dec
         ]
-        logits_dec, logits_chk, toks_dec, toks_chk = self._start(
+        logits_dec, logits_chk, toks_dec, toks_chk, self._last_tokens = self._start(
             self._stage_split, batch.total_tokens, dec_rows, chk_rows)
         # rows are referenced as (logits array, row index, token array):
         # slicing logits_dec[i] here would issue one tiny device op per
         # completed row per step. Callers materialize each ARRAY once;
         # generate() keeps only the token arrays alive.
+        R = self.config.state_manager.max_ragged_sequence_count
         results: Dict[int, tuple] = {}
-        for i, (uid, toks, _start) in enumerate(dec_rows):
+        slots: Dict[int, int] = {}
+        for i, (uid, toks, _start, _src) in enumerate(dec_rows):
             seq = self.state_manager.get_sequence(uid)
             seq.seen_tokens += len(toks)
             results[uid] = (logits_dec, i, toks_dec)
+            slots[uid] = i
         for j, (uid, toks, _start, chunked) in enumerate(chk_rows):
             seq = self.state_manager.get_sequence(uid)
             seq.seen_tokens += len(toks)
             if not chunked:  # prompt complete: last-token logits usable
                 results[uid] = (logits_chk, j, toks_chk)
-        return results
+                slots[uid] = R + j
+        return results, slots, self._last_tokens
 
     # -- convenience generation loop (greedy) ---------------------------------
     def generate(self, prompts, max_new_tokens: int = 32, eos_token_id: Optional[int] = None):

@@ -27,6 +27,10 @@ class RaggedBatch:
     start_positions: List[int]  # first position of those tokens in the sequence
     is_prompt_chunk: List[bool]  # True if more of this prompt remains after the step
     is_decode: List[bool] = field(default_factory=list)  # row came from _running
+    # a decode row whose token is still on the device: the slot of the step in
+    # flight's sampled tokens it comes from (its ``tokens`` entry is a 0 the
+    # program never reads); -1 for a token the host holds
+    token_src: List[int] = field(default_factory=list)
 
     @property
     def total_tokens(self):
@@ -53,6 +57,12 @@ class RaggedScheduler:
         self._pending: List[Tuple[int, np.ndarray]] = []  # (uid, remaining prompt)
         self._running: List[int] = []  # uids with a sampled next token to feed
         self._next_token: Dict[int, int] = {}
+        # one step in flight (expect): running uids whose next token the step
+        # in flight samples -> its output slot there; and uids whose row a
+        # LATER batch already took with the token still on the device, so
+        # feedback() owes history the value and nothing else
+        self._in_flight: Dict[int, int] = {}
+        self._owed: set = set()
         # uids force-finished because they hit max_context / max_blocks_per_seq
         # (the decode analogue of a max-length stop); cleared on re-submit
         self.capped: set = set()
@@ -89,6 +99,9 @@ class RaggedScheduler:
         # sampled token (already in seq.tokens via feedback()) into this
         # prompt chunk — otherwise next_batch() would emit a decode row AND a
         # prompt row at the same start position, double-writing the KV cache.
+        if uid in self._in_flight or uid in self._owed:
+            raise RuntimeError(
+                f"submit({uid}): its last token is in flight; collect the step first")
         if uid in self._running:
             self._running.remove(uid)
             pending = self._next_token.pop(uid, None)
@@ -109,12 +122,34 @@ class RaggedScheduler:
         self._pending.append((uid, toks))
 
     def feedback(self, uid: int, sampled_token: int) -> None:
-        """Engine reports the sampled next token for a running sequence."""
+        """Engine reports the sampled next token for a running sequence. A
+        token that was expected (``expect``) lands in history here, in
+        order, and nowhere else if a later batch already took the row."""
         seq = self._mgr.get_sequence(uid)
         if seq is None or seq.finished:
             return
         seq.tokens.append(int(sampled_token))
+        if uid in self._owed:
+            self._owed.discard(uid)
+            return
+        self._in_flight.pop(uid, None)
         self._next_token[uid] = int(sampled_token)
+        if uid not in self._running:
+            self._running.append(uid)
+
+    def expect(self, uid: int, src: int) -> None:
+        """A row of the step in flight will sample this sequence's next
+        token into slot ``src`` of that step's output: run it again in the
+        NEXT batch with the token read on the device. Nothing enters
+        ``seq.tokens`` until ``feedback`` brings the value, so whatever
+        reads history (prefix hashing, adopt, export) never sees a
+        placeholder. The caller collects the step in flight, and feeds every
+        expected token back or finishes its sequence, before it expects
+        again: a slot names the step in flight and no other."""
+        seq = self._mgr.get_sequence(uid)
+        if seq is None or seq.finished:
+            return
+        self._in_flight[uid] = int(src)
         if uid not in self._running:
             self._running.append(uid)
 
@@ -141,6 +176,8 @@ class RaggedScheduler:
         if seq is not None:
             seq.finished = True
         self._next_token.pop(uid, None)
+        self._in_flight.pop(uid, None)
+        self._owed.discard(uid)
         if uid in self._running:
             self._running.remove(uid)
         # Drop unscheduled prompt chunks too (cancel mid-prefill): a stale
@@ -200,14 +237,15 @@ class RaggedScheduler:
     def next_batch(self) -> Optional[RaggedBatch]:
         budget = self._config.max_ragged_batch_size
         max_rows = self._config.max_ragged_sequence_count
-        uids, tokens, starts, chunked, decode = [], [], [], [], []
+        uids, tokens, starts, chunked, decode, srcs = [], [], [], [], [], []
 
         # 1. decode tokens for running sequences (fuse)
         for uid in list(self._running):
             if len(uids) >= max_rows or budget <= 0:
                 break
             seq = self._mgr.get_sequence(uid)
-            tok = self._next_token.get(uid)
+            src = self._in_flight.get(uid, -1)
+            tok = 0 if src >= 0 else self._next_token.get(uid)
             if seq is None or tok is None:
                 continue
             # Permanently unschedulable: context or per-sequence block cap
@@ -227,8 +265,11 @@ class RaggedScheduler:
             starts.append(seq.seen_tokens)
             chunked.append(False)
             decode.append(True)
+            srcs.append(src)
             self._running.remove(uid)
             self._next_token.pop(uid, None)
+            if self._in_flight.pop(uid, None) is not None:
+                self._owed.add(uid)
             budget -= 1
 
         # 2. prompt chunks (split): at most max_prompt_chunks rows of at most
@@ -264,6 +305,7 @@ class RaggedScheduler:
             starts.append(seq.seen_tokens)
             chunked.append(len(rest) > 0)
             decode.append(False)
+            srcs.append(-1)
             budget -= take
             n_chunks += 1
             # the step consuming this batch writes the chunk's KV, so every
@@ -282,5 +324,5 @@ class RaggedScheduler:
             return None
         return RaggedBatch(
             uids=uids, tokens=tokens, start_positions=starts,
-            is_prompt_chunk=chunked, is_decode=decode,
+            is_prompt_chunk=chunked, is_decode=decode, token_src=srcs,
         )

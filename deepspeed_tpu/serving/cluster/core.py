@@ -20,6 +20,13 @@ owner can serve many cores):
   * ``finish_capped(core, req)`` — the scheduler force-finished the
     sequence at its block/context cap (blocks already freed).
 
+One step in flight: a core that can (no speculative controller, no fused
+rounds, not the prefill role, an engine with ``launch_step`` /
+``collect_step``) launches step n+1 and THEN collects and delivers step n,
+so the host's work of a step runs under the chip's; the others collect where
+they launch, through the same two primitives. ``has_work()`` is true while a
+step is in flight, and ``settle()`` collects it without launching another.
+
 Thread safety: each core carries a ``step_lock`` serializing engine
 stepping against cross-engine KV block import/export — both paths
 reassign the donated pool arrays, so an unserialized import racing a step
@@ -71,6 +78,11 @@ class EngineCore:
         self.health = ReplicaHealth(self.name)
         self.step_started_at: Optional[float] = None
         self._step_failed = False
+        # one step in flight: the engine's record of the step launched and
+        # not yet collected, with the requests its rows belonged to at launch
+        # ({uid: Request}); None between steps of a core that collects where
+        # it launches
+        self._flight = None
         # serializes engine stepping against KV import/export (both
         # reassign the donated pool arrays) and scheduler mutation from
         # other threads (admission, cancel cleanup)
@@ -175,18 +187,24 @@ class EngineCore:
         if self.metrics is not None:
             self.metrics.inc(name, delta)
 
-    def _count_step(self) -> None:
+    def _count_step(self, stats=None) -> None:
         """One step or round ran: count it, and beside it what the engine
-        sized it to and what it carried (its ``last_step``, a ``StepStats``
-        filled as the engine stages the step), so that useful slots over
-        computed slots is measured where the work is decided. An engine
-        without one (a compute-free fake) counts zeros."""
-        stats = getattr(self.engine, "last_step", None)
+        sized it to and what it carried (``stats``, the step's ``StepStats``,
+        filled as the engine stages the step; the engine's ``last_step``
+        where the step was waited for where it was launched), so that
+        useful slots over computed slots is measured where the work is
+        decided. An engine without one (a compute-free fake) counts zeros."""
+        if stats is None:
+            stats = getattr(self.engine, "last_step", None)
 
         def held(field):
             return getattr(stats, field, 0)
 
         self._inc("engine_steps_total")
+        # one step in flight: launched before its predecessor was collected;
+        # rows it computed for a request that had stopped meanwhile
+        self._inc("steps_ahead_total", int(held("ahead")))
+        self._inc("ahead_rows_dropped_total", held("ahead_rows_dropped"))
         self._inc("grid_slots_total", held("grid_slots"))
         self._inc("scheduled_tokens_total", held("scheduled_tokens"))
         if held("prefill_tokens"):
@@ -310,8 +328,16 @@ class EngineCore:
             self.metrics.set_gauge(
                 "state_slots_in_use", getattr(self.engine.state_manager, "state_slots_in_use", 0))
 
+    @property
+    def step_in_flight(self) -> bool:
+        """A step was launched and is not collected yet."""
+        return self._flight is not None
+
     def has_work(self) -> bool:
-        return self.engine.scheduler.has_work()
+        """Something to schedule, or a step in flight to collect: the loop
+        that owns this core steps it until both are done, so the last step
+        launched is always collected."""
+        return self._flight is not None or self.engine.scheduler.has_work()
 
     # -- stepping --------------------------------------------------------
     def _reap_capped(self, sink) -> None:
@@ -433,8 +459,13 @@ class EngineCore:
 
     def step_once(self, sink) -> bool:
         """One engine step (or fused decode / speculative verify round).
-        Returns True if any token landed or any prompt advanced by a chunk
-        (progress). Caller holds ``step_lock``.
+        Returns True if any token landed, any prompt advanced by a chunk or
+        a step was launched (progress). Caller holds ``step_lock``.
+
+        One step in flight: a core that can (``_runs_ahead``) LAUNCHES step
+        n+1 and then collects and delivers step n, so the host's work of a
+        step runs under the chip's. ``has_work()`` stays true while a step
+        is in flight: the loop's next call collects it.
 
         Wraps the step in the watchdog window — ``step_started_at`` is
         the monotonic stamp the coordinator's hung-step scan reads
@@ -442,24 +473,101 @@ class EngineCore:
         feeds the health state machine: a clean step resets the error
         streak; the failure handler advances it before telling the
         sink."""
+        return self._watched(sink, launch=True)
+
+    def settle(self, sink) -> None:
+        """Collect and deliver the step in flight, launching nothing: what
+        reads a row's steady state (a checkpoint export, a preemption) calls
+        this first, under ``step_lock``. A no-op with nothing in flight."""
+        if self._flight is not None:
+            self._watched(sink, launch=False)
+
+    def _watched(self, sink, launch: bool) -> bool:
         self._step_failed = False
         self.step_started_at = time.monotonic()
         try:
-            return self._step_locked(sink)
+            return self._step_locked(sink, launch)
         finally:
             self.step_started_at = None
             if not self._step_failed:
                 self.health.note_success()
 
-    def _step_locked(self, sink) -> bool:
+    def _runs_ahead(self) -> bool:
+        """Whether this core launches a step before it collects the one
+        before, from what it is: not with a speculative controller or fused
+        rounds (their programs take a row's token from the host), not in a
+        role that hands K/V off after a step (the export reads what the
+        step's tokens completed), not over an engine that has only
+        ``step_tokens`` (a compute-free fake)."""
+        return (
+            self.spec_ctl is None
+            and self.decode_steps <= 1
+            and self.role != "prefill"
+            and hasattr(self.engine, "launch_step")
+        )
+
+    def _collect_flight(self, sched, flight, reqs) -> Dict[int, int]:
+        """Wait for a launched step and count it. A row whose request
+        stopped while the step was in flight (a stop only its last token
+        showed, a cancel, a timeout) is dropped here: never delivered, never
+        counted as a decode token."""
+        results = self.engine.collect_step(flight)
+        for uid in [u for u in results if self.requests.get(u) is not reqs.get(u)]:
+            del results[uid]
+            flight.stats.ahead_rows_dropped += 1
+            if uid not in self.requests:
+                sched.finish(uid)  # make sure scheduler state is gone
+        self._count_step(flight.stats)
+        return results
+
+    def _split_step(self, sched, launch: bool):
+        """The split step through the engine's two primitives, their order
+        chosen a step at a time: with a step in flight, hand its rows out
+        again with their tokens where they are (on the device), launch, THEN
+        collect it. Returns ({uid: token} of what was collected, whether
+        anything was scheduled or stays in flight)."""
+        if not hasattr(self.engine, "launch_step"):
+            results = self.engine.step_tokens()
+            self._count_step()
+            return results, bool(
+                getattr(getattr(self.engine, "last_step", None), "scheduled_tokens", 0))
+        prev, self._flight = self._flight, None
+        collect = [prev] if prev is not None else []
+        if prev is not None and launch:
+            flight, reqs = prev
+            for uid, slot in flight.rows.items():
+                req = self.requests.get(uid)
+                # not a row whose request is gone, nor one whose token in
+                # flight is its last by length: no row-step is wasted there
+                if req is not None and req is reqs[uid] and req.remaining_tokens > 1:
+                    sched.expect(uid, slot)
+        if launch and sched.has_work():
+            flight = self.engine.launch_step()
+            flight.stats.ahead = prev is not None and bool(flight.waited)
+            cur = (flight, {uid: self.requests.get(uid) for uid in flight.rows})
+            if flight.waited and self._runs_ahead():
+                self._flight = cur
+            else:
+                # nothing launched (no batch: stalled on KV blocks), or a
+                # core that collects where it launches
+                collect.append(cur)
+        results: Dict[int, int] = {}
+        for flight, reqs in collect:
+            results.update(self._collect_flight(sched, flight, reqs))
+        flights = collect + ([self._flight] if self._flight is not None else [])
+        return results, any(f.stats.scheduled_tokens for f, _ in flights)
+
+    def _step_locked(self, sink, launch: bool) -> bool:
         sched = self.engine.scheduler
         use_spec = (
-            self.spec_ctl is not None
+            launch
+            and self.spec_ctl is not None
             and not sched.has_pending()
             and bool(sched.running_uids())
         )
         use_round = (
-            self.decode_steps > 1
+            launch
+            and self.decode_steps > 1
             and hasattr(self.engine, "decode_round")
             and not sched.has_pending()
             and bool(sched.running_uids())
@@ -490,8 +598,7 @@ class EngineCore:
                         })
                     return self._deliver_results(sink, sched, round_res, feedback=False)
             t0 = tr.now() if tr.enabled else 0.0
-            results = self.engine.step_tokens()
-            self._count_step()
+            results, scheduled = self._split_step(sched, launch)
             if tr.enabled:
                 self._trace_round(tr, "step.split", t0, tr.now(), results, {
                     "rows": len(results),
@@ -501,10 +608,12 @@ class EngineCore:
         except Exception as e:
             # engine-level failure: per-request state is unknowable, so the
             # in-flight set fails (or, under a resilience-enabled router,
-            # is recovered by replay) — but the owner survives
+            # is recovered by replay) — but the owner survives. A step in
+            # flight goes with it: its rows' requests have just failed.
             err = f"{type(e).__name__}: {e}"
             logger.warning(f"serving[{self.name}]: engine step failed: {err}")
             self._step_failed = True
+            self._flight = None
             # advance health BEFORE the sink runs so engine_failed sees the
             # post-transition state (quarantine side-effects fire once)
             self.health.note_error(err)
@@ -527,8 +636,7 @@ class EngineCore:
         # A step of prompt chunks with more to come lands no token and is
         # progress all the same: read as none, it sent the serving loop into
         # its stalled-on-KV-blocks poll between two chunks of one prompt.
-        return delivered or bool(
-            getattr(getattr(self.engine, "last_step", None), "scheduled_tokens", 0))
+        return delivered or scheduled
 
     # -- probation probes -------------------------------------------------
     def probe(self, lock_timeout_s: float = 0.5) -> None:

@@ -445,6 +445,9 @@ class RemoteEngineHandle:
         deliberate no-op, not a stub."""
         return False
 
+    def settle(self, sink) -> None:
+        """No step is in flight here: the agent's own core runs them."""
+
     def probe(self, lock_timeout_s: float = 0.5) -> None:
         """Probation probe as a HEALTH RPC with a deadline: the agent runs
         its own ``EngineCore.probe`` (empty step through the fault seam)

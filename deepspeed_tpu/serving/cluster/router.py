@@ -1434,6 +1434,8 @@ class Router:
         if getattr(vcore, "is_remote", False):
             return False  # no checkpoint export across a process boundary
         with vcore.step_lock:
+            # a checkpoint reads a row's steady state: no step in flight
+            vcore.settle(self)
             with self._cond:
                 if victim.is_terminal or self._owner.get(victim.uid) is not vcore:
                     return False
@@ -1857,6 +1859,10 @@ class Router:
         log_event("worker_crash", replica=core.name, error=err, health=state)
         self.metrics.inc("replica_failures_total")
         with core.step_lock:
+            # the checkpoint route reads rows in their steady state: collect
+            # the step in flight first (a step that fails here fails over by
+            # replay, through the step's own handler)
+            core.settle(self)
             with self._cond:
                 self._handoff_out.pop(core.name, None)
                 self._note_quarantine_locked(core)

@@ -25,7 +25,11 @@ Responsibilities (and how each maps to the loop):
 The driver needs only a small engine protocol — ``scheduler`` (the
 ``RaggedScheduler`` API), ``state_manager`` (``free_blocks``), and
 ``step_tokens()`` returning ``{uid: next-token int}`` — so tests drive it
-with a compute-free fake over the REAL scheduler/allocator stack.
+with a compute-free fake over the REAL scheduler/allocator stack. Over an
+engine that also has ``launch_step()`` / ``collect_step()`` the core runs
+one step in flight: it launches step n+1 before it collects step n
+(``EngineCore.step_once``), so the loop keeps stepping while
+``core.has_work()``, which is true until the last step launched is collected.
 
 Since the disaggregated-serving refactor the engine-facing half of the
 loop (admission accounting, stepping, spec rounds, capped reaping) lives
@@ -481,7 +485,7 @@ class ServingDriver:
             role=self.core.role,
         )
         self.metrics.set_gauge("active_requests", len(self._active))
-        if not self._active and not self._queue:
+        if not self._active and not self._queue and not self.core.step_in_flight:
             self._idle.set()
             self._flush_monitor()
 
@@ -495,12 +499,13 @@ class ServingDriver:
             tr = get_tracer()
             with self._cond:
                 while True:
-                    if self._stopping and not self._active and not self._queue:
+                    if (self._stopping and not self._active and not self._queue
+                            and not self.core.step_in_flight):
                         self._idle.set()
                         return
                     work = (
                         bool(self._cancel_uids)
-                        or self.engine.scheduler.has_work()
+                        or self.core.has_work()
                         or (self._queue and self._admissible(self._queue[0]))
                     )
                     now = time.monotonic()
@@ -509,7 +514,7 @@ class ServingDriver:
                         break  # timeouts due
                     if work and not stall_wait:
                         break
-                    if not self._active and not self._queue:
+                    if not self._active and not self._queue and not self.core.step_in_flight:
                         self._idle.set()
                         self._flush_monitor()
                     # sleep until: new submit/cancel (notify), the next
@@ -528,7 +533,7 @@ class ServingDriver:
                     self._expire_locked()
                     self._admit_locked()
             stepped = False
-            if self.engine.scheduler.has_work():
+            if self.core.has_work():
                 stepped = self._step_once()
                 with self._cond:
                     with tr.span("loop.admit", track="driver"):

@@ -156,6 +156,12 @@ class ServingMetrics:
             "kv_window_blocks_used_total": 0,
             "kv_context_tokens_total": 0,
             "paged_window_live_blocks_total": 0,
+            # one step in flight (EngineCore._count_step): steps launched
+            # before their predecessor was collected, and rows such a step
+            # computed for a request that had stopped meanwhile (never
+            # delivered, never a decode token)
+            "steps_ahead_total": 0,
+            "ahead_rows_dropped_total": 0,
             "admission_blocked_total": 0,
             # prefix cache (mirrors of PrefixCache's monotone counters)
             "prefix_queries_total": 0,

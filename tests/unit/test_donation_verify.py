@@ -67,6 +67,25 @@ def test_serving_programs_copy_no_pool(program, kv_dtype):
     assert res.ok, res.detail
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "gdn", "window"])
+@pytest.mark.parametrize("program", ["split_step", "decode_only_step"])
+def test_split_step_takes_the_step_before_its_tokens_and_copies_no_pool(program, kv_dtype):
+    """One step in flight: every shape of the split step takes the previous
+    one's sampled tokens (``last_tokens``, on the device) and each decode
+    slot's source there (``tok_src``), donates the pools alone, and holds no
+    pool-sized copy with the two inputs added."""
+    eng, programs = _programs(kv_dtype)
+    fn, args = programs[program]
+    inputs = args[1]
+    R = eng.config.state_manager.max_ragged_sequence_count
+    assert inputs["last_tokens"].shape == (R + eng.scheduler.max_prompt_chunks,)
+    assert inputs["tok_src"].shape == (R,) and inputs["tok_src"].dtype == jnp.int32
+    res = dv.check_donation(program, fn, args)
+    assert res.ok and len(res.buffers) == len(eng._pools()), res.detail
+    res = dv.check_pool_copies(program, fn, args, eng._pools())
+    assert res.ok, res.detail
+
+
 def test_pool_copy_check_flags_a_scatter_inside_the_layer_loop():
     """The protocol this check exists to keep out: the loop reads the
     step-start pool as an invariant and scatters into the carried pool, so

@@ -361,16 +361,23 @@ class TestInferenceV2:
         batch = sched.next_batch()
         assert all(batch.is_decode) and len(batch.uids) == len(prompts)
         key, inputs = engine._stage_split(
-            batch.total_tokens, list(zip(batch.uids, batch.tokens, batch.start_positions)), [])
-        assert key == ("split", 0)
-        assert sorted(inputs) == ["blk", "dec_pos", "dec_tables", "dec_uids", "positions",
-                                  "row", "tokens"]
+            batch.total_tokens,
+            list(zip(batch.uids, batch.tokens, batch.start_positions, batch.token_src)), [])
+        assert key == ("split", 0) and set(batch.token_src) == {-1}
+        assert sorted(inputs) == ["blk", "dec_pos", "dec_tables", "dec_uids", "last_tokens",
+                                  "positions", "row", "tok_src", "tokens"]
         assert {len(inputs[k]) for k in ("tokens", "positions", "blk", "row", "dec_pos")} == {4}
         # both programs read the pool below the rows' positions and write
         # the same K/V at them: run one after the other on the same pools
-        logits0, no_logits, toks0, no_toks = engine._launch(key, inputs)
-        logits1, _, toks1, _ = engine._launch(("split", tq), _as_chunk_shape(engine, inputs, tq))
+        logits0, no_logits, toks0, no_toks, last0 = engine._launch(key, inputs)
+        logits1, _, toks1, _, last1 = engine._launch(
+            ("split", tq), _as_chunk_shape(engine, inputs, tq))
         assert no_logits is None and no_toks is None
+        # the tokens by output slot, for the next step to read on the device:
+        # decode slots, then chunk rows (zeros in the shape that has none)
+        np.testing.assert_array_equal(np.asarray(last0)[:4], np.asarray(toks0))
+        np.testing.assert_array_equal(np.asarray(last1)[:4], np.asarray(toks1))
+        assert not np.asarray(last0)[4:].any() and last0.shape == last1.shape
         live = slice(0, len(batch.uids))
         np.testing.assert_array_equal(np.asarray(toks0)[live], np.asarray(toks1)[live])
         np.testing.assert_allclose(
@@ -414,8 +421,10 @@ class TestInferenceV2:
                 moe = {"routed": 2 * 2, "computed": 2 * 2 * tile, "hot": 2, "calls": 2,
                        "hit": 2 * 2}
                 assert prefill.moe["routed"] == 20 * 2 * 2
+            # ... waited for where it was launched: not ahead, no row dropped
             assert engine.last_step == StepStats(
-                4, 1, 0, 2, 4 * 8, moe, kv_global_blocks=2, kv_context_tokens=21)
+                4, 1, 0, 2, 4 * 8, moe, kv_global_blocks=2, kv_context_tokens=21,
+                ahead=False, ahead_rows_dropped=0)
         elif entry == "decode_round":
             assert len(engine.decode_round(3)[0]) == 3
             # a round's 3 kernel calls a layer walk the round-start window

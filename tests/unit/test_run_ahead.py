@@ -1,0 +1,330 @@
+"""One step in flight: the serving core launches step n+1 before it collects
+step n, and a decode row of n+1 takes the token step n sampled from the
+device (``last_tokens`` / ``tok_src``), never through the host.
+
+Every case runs on three toys, float32, on the CPU: a dense stack, the hybrid
+(Gated DeltaNet layers: a state slot a sequence beside its K/V blocks) and the
+window stack (a ring of window blocks a sequence). The oracle is a plain loop
+of synchronous ``engine.step_tokens()`` on a second engine of the same toy:
+per request the same token at the same position. The driver is never started:
+the test is its loop, pass by pass, so that what is in flight at each stop,
+cancel or failure is the same in every run."""
+
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
+from deepspeed_tpu.inference.v2 import engine_v2
+from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.serving.cluster.core import EngineCore
+from deepspeed_tpu.serving.driver import ServingDriver
+from deepspeed_tpu.serving.request import RequestState, SamplingParams
+from tests.unit import test_k_exaone_serving as window_toy
+from tests.unit import test_qwen3_next_serving as hybrid_toy
+
+R = 4  # decode slots of a step
+CHUNK = 40
+
+
+def _dense_model():
+    from deepspeed_tpu.models import get_config, init_params
+
+    cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
+    return cfg, init_params(cfg, jax.random.key(0))
+
+
+MODELS = {"dense": _dense_model, "hybrid": hybrid_toy._model, "window": window_toy._model}
+_built = {}
+
+
+@pytest.fixture(params=sorted(MODELS))
+def toy(request):
+    """(name, a maker of engines of that toy): blocks of 8 tokens, chunks of
+    40, R = 4 decode slots and 2 chunk rows a step."""
+    name = request.param
+    if name not in _built:
+        _built[name] = MODELS[name]()
+    cfg, params = _built[name]
+
+    def make(**extra):
+        rc = {
+            "dtype": "float32", "prompt_chunk": CHUNK, "max_prompt_chunks": 2,
+            "kv_cache": {"block_size": 8, "num_blocks": 96, "max_blocks_per_seq": 32},
+            "state_manager": {"max_tracked_sequences": 6, "max_ragged_batch_size": 128,
+                              "max_ragged_sequence_count": R, "max_context": 256},
+            **extra,
+        }
+        return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig.from_dict(rc))
+
+    return name, make
+
+
+def _prompt(i, n, vocab=120):
+    return (np.random.default_rng(100 + i).integers(1, vocab, size=n)).astype(np.int32)
+
+
+# (the pass it arrives at, prompt length, max_new_tokens): chunked prompts (40
+# a chunk), a one-token answer, five requests for four decode slots, and
+# arrivals while others decode
+MIX = [(0, 5, 6), (0, 70, 9), (3, 100, 4), (7, 33, 12), (9, 12, 1)]
+
+
+def _sync_reference(engine, work, stops=None):
+    """The plain loop: one ``step_tokens()`` a pass, every token through the
+    host. ``work``: (arrival pass, prompt, max_new); uid = its index.
+    ``stops``: {uid: token that ends it}."""
+    sched = engine.scheduler
+    out = {uid: [] for uid in range(len(work))}
+    nxt = step = 0
+    while nxt < len(work) or sched.has_work():
+        while nxt < len(work) and work[nxt][0] <= step:
+            sched.submit(nxt, work[nxt][1])
+            nxt += 1
+        for uid, tok in engine.step_tokens().items():
+            out[uid].append(tok)
+            if len(out[uid]) >= work[uid][2] or tok == (stops or {}).get(uid):
+                sched.finish(uid)
+            else:
+                sched.feedback(uid, tok)
+        step += 1
+        assert step < 500
+    return out
+
+
+def _one_pass(driver):
+    """One pass of ``ServingDriver._loop``, by hand."""
+    with driver._cond:
+        driver._expire_locked()
+        driver._admit_locked()
+    stepped = driver.core.has_work() and driver._step_once()
+    with driver._cond:
+        driver._admit_locked()
+        driver._update_metrics_locked()
+    return stepped
+
+
+def _submit(driver, prompt, max_new, **kw):
+    return driver.submit(prompt, params=SamplingParams(
+        max_new_tokens=max_new, ignore_eos=True, **kw.pop("params", {})), **kw)
+
+
+def _serve(driver, work):
+    """The driver's loop over ``work``, arrivals by pass; returns the requests."""
+    reqs = []
+    step = 0
+    while len(reqs) < len(work) or driver.core.has_work() or driver._queue:
+        while len(reqs) < len(work) and work[len(reqs)][0] <= step:
+            uid = len(reqs)
+            reqs.append(_submit(driver, work[uid][1], work[uid][2]))
+            assert reqs[-1].uid == uid
+        _one_pass(driver)
+        step += 1
+        assert step < 500
+    return reqs
+
+
+def _all_free(engine):
+    acct = engine.state_manager.kv_block_accounting()
+    slots = engine.state_manager.state_slot_accounting()
+    return (acct["free"] == acct["total"] and slots["free"] == slots["total"]
+            and acct.get("window_free") == acct.get("window_total"))
+
+
+def _work(mix=MIX):
+    return [(at, _prompt(i, n), new) for i, (at, n, new) in enumerate(mix)]
+
+
+def test_served_tokens_equal_the_synchronous_loop(toy):
+    """(a), (c), (g): a ragged mix with chunked prompts and arrivals
+    mid-stream, token for token; stops by length drop nothing; the last step
+    in flight is collected, and every program compiled once."""
+    _name, make = toy
+    work = _work()
+    want = _sync_reference(make(), work)
+    engine = make()
+    driver = ServingDriver(engine)
+    reqs = _serve(driver, work)
+    for uid, req in enumerate(reqs):
+        assert req.state == RequestState.FINISHED and req.finish_reason == "max_tokens"
+        assert req.generated == want[uid], f"uid {uid}"
+    c = driver.metrics.counters
+    assert c["decode_tokens_total"] == sum(new for _, _, new in MIX)
+    assert c["ahead_rows_dropped_total"] == 0
+    # every step but the first was launched with its predecessor in flight
+    assert c["steps_ahead_total"] == c["engine_steps_total"] - 1 > 10
+    assert not driver.core.has_work() and not driver.core.step_in_flight and _all_free(engine)
+    assert driver._idle.is_set()
+    # one program a key, whatever ``last_tokens`` was (zeros, then an output)
+    assert sorted(engine._programs) == [("split", 0), ("split", CHUNK)]
+    assert all(fn._cache_size() == 1 for fn in engine._programs.values())
+
+
+def _stop_token(tokens, at_least=2):
+    """(index, token) of the first token from ``at_least`` on that did not
+    occur before it: a stop token that ends the stream exactly there."""
+    for i in range(at_least, len(tokens) - 1):
+        if tokens[i] not in tokens[:i]:
+            return i, tokens[i]
+    raise AssertionError(f"no fresh token in {tokens}")
+
+
+def test_a_stop_only_the_token_shows_drops_the_row_in_flight(toy):
+    """(b): the request stops on a token. By then its next row is in flight:
+    that row's token is never delivered nor counted, its blocks, state slot
+    and ring are free at once, and the request that gets them next (its first
+    chunk in the very next launch) serves its reference tokens."""
+    _name, make = toy
+    first = [(0, _prompt(0, 20), 12)]
+    at, stop = _stop_token(_sync_reference(make(), first)[0])
+    work = first + [(0, _prompt(1, 50), 6)]
+    want = _sync_reference(make(), work, stops={0: stop})
+    assert len(want[0]) == at + 1
+
+    engine = make()
+    driver = ServingDriver(engine)
+    a = _submit(driver, work[0][1], 12, params={"stop_token_ids": [stop]})
+    while not a.is_terminal:
+        assert _one_pass(driver)
+    assert a.finish_reason == "stop_token" and a.generated == want[0]
+    # stopped at its collect, with the step after it launched: its row there
+    assert driver.core.step_in_flight and 0 in driver.core._flight[0].rows
+    assert _all_free(engine), "freed at once, the row in flight notwithstanding"
+    b = _submit(driver, work[1][1], 6)
+    _one_pass(driver)  # launches b's first chunk, then collects and drops a's row
+    c = driver.metrics.counters
+    assert c["ahead_rows_dropped_total"] == 1 and c["decode_tokens_total"] == at + 1
+    assert a.generated == want[0]
+    while not b.is_terminal:
+        assert _one_pass(driver)
+    assert b.generated == want[1] and c["ahead_rows_dropped_total"] == 1
+    assert c["decode_tokens_total"] == at + 1 + 6
+    assert not driver.core.has_work() and _all_free(engine)
+
+
+@pytest.mark.parametrize("how", ["cancel", "timeout"])
+def test_cancel_and_timeout_with_a_row_in_flight(toy, how):
+    """(d): the request goes between two passes; its row in flight is
+    computed and dropped, the others' streams are untouched."""
+    _name, make = toy
+    work = [(0, _prompt(0, 30), 30), (0, _prompt(1, 12), 8)]
+    want = _sync_reference(make(), work)
+    engine = make()
+    driver = ServingDriver(engine)
+    a = _submit(driver, work[0][1], 30, **({"timeout_s": 3600.0} if how == "timeout" else {}))
+    b = _submit(driver, work[1][1], 8)
+    while len(a.generated) < 3:
+        assert _one_pass(driver)
+    assert a.uid in driver.core._flight[0].rows
+    if how == "cancel":
+        assert driver.cancel(a.uid)
+    else:
+        a.deadline = time.monotonic() - 1.0
+    while driver.core.has_work():
+        _one_pass(driver)
+    assert a.state == (RequestState.CANCELLED if how == "cancel" else RequestState.TIMED_OUT)
+    assert a.generated == want[0][: len(a.generated)] and 3 <= len(a.generated) < 30
+    assert b.state == RequestState.FINISHED and b.generated == want[1]
+    c = driver.metrics.counters
+    assert c["ahead_rows_dropped_total"] == 1
+    assert c["decode_tokens_total"] == len(a.generated) + 8
+    assert _all_free(engine)
+
+
+@pytest.mark.parametrize("where", ["launch_step", "collect_step"])
+def test_an_engine_failure_with_a_step_in_flight_fails_the_active_set_once(toy, where,
+                                                                           monkeypatch):
+    """(e): the launch of step n+1 or the collect of step n raises. Both
+    active requests fail, once; nothing stays in flight or expected; the
+    next request is served, to its reference."""
+    _name, make = toy
+    engine = make()
+    driver = ServingDriver(engine)
+    reqs = [_submit(driver, _prompt(i, 20), 20) for i in range(2)]
+    while min(len(r.generated) for r in reqs) < 2:
+        assert _one_pass(driver)
+    assert driver.core.step_in_flight
+    real = getattr(engine, where)
+
+    def boom(*args):
+        monkeypatch.setattr(engine, where, real)
+        raise RuntimeError("injected")
+
+    monkeypatch.setattr(engine, where, boom)
+    _one_pass(driver)
+    assert all(r.state == RequestState.FAILED and r.finish_reason == "engine_error" for r in reqs)
+    c = driver.metrics.counters
+    assert c["requests_failed_total"] == 2
+    assert not driver.core.has_work() and not driver.core.step_in_flight
+    sched = engine.scheduler
+    assert not sched._in_flight and not sched._owed and _all_free(engine)
+    work = [(0, _prompt(5, 45), 5)]
+    after = _submit(driver, work[0][1], 5)
+    while not after.is_terminal:
+        assert _one_pass(driver)
+    # sampling is keyed by (uid, position) and greedy here: uid 0 serves the same
+    assert after.state == RequestState.FINISHED and after.generated == _sync_reference(make(), work)[0]
+    assert c["requests_failed_total"] == 2
+
+
+@pytest.mark.parametrize("kind", ["spec_k", "decode_steps", "prefill_role"])
+def test_a_core_that_cannot_run_ahead_never_does(toy, kind):
+    """(f): a speculative controller or fused rounds take a row's token from
+    the host, and a prefill worker hands K/V off after its step: such a core
+    collects where it launches, through the same two primitives."""
+    name, make = toy
+    work = _work([(0, 5, 6), (0, 70, 9), (2, 33, 5)])
+    want = _sync_reference(make(), work)
+    if kind == "prefill_role":
+        core = EngineCore(make(), role="prefill")
+        assert not core._runs_ahead() and EngineCore(make(), role="decode")._runs_ahead()
+        return
+    if kind == "spec_k":
+        if name != "dense":
+            # spec_round is refused for a second kind of cache: the core that
+            # would drive it still collects where it launches
+            assert not EngineCore(make(), spec_k=2)._runs_ahead()
+            return
+        engine, kw = make(spec_k=2), {"spec_k": 2}
+    else:
+        engine, kw = make(decode_steps=3), {"decode_steps": 3}
+    driver = ServingDriver(engine, **kw)
+    reqs = _serve(driver, work)
+    for uid, req in enumerate(reqs):
+        assert req.generated == want[uid], f"uid {uid}"
+    c = driver.metrics.counters
+    assert c["steps_ahead_total"] == 0 and c["ahead_rows_dropped_total"] == 0
+    assert c["engine_steps_total"] > 0 and not driver.core.step_in_flight
+
+
+def test_a_step_that_completed_no_row_is_waited_on_its_own_outputs(toy, monkeypatch):
+    """(h): chunks with more to come complete no row. Each is waited for, one
+    pass after its launch, on the tokens ITS program returned: not on the
+    engine's pools (the next step's by then) nor on the engine's
+    ``_last_tokens`` (the next step's too). So at most one step is in flight
+    beyond the one waited for."""
+    _name, make = toy
+    engine = make()
+    waits = []
+    real_wait = engine_v2.device_synchronize
+    monkeypatch.setattr(engine_v2, "device_synchronize",
+                        lambda tree=None: (waits.append(list(tree)), real_wait(tree))[1])
+    driver = ServingDriver(engine)
+    req = _submit(driver, _prompt(0, 100), 2)  # 40 + 40 + 20
+    flights = []
+    for chunk in range(3):
+        assert _one_pass(driver), f"chunk {chunk} is progress"
+        flights.append(driver.core._flight[0])
+        assert len(waits) == chunk and req.generated == []
+        assert bool(flights[-1].rows) == (chunk == 2)
+    own = {id(a) for f in flights[:2] for a in f.waited}
+    pools = {id(a) for a in jax.tree_util.tree_leaves(engine._pools())}
+    for w, f in zip(waits, flights):
+        assert w == f.waited and w and not ({id(a) for a in w} & pools)
+        assert all(a is not engine._last_tokens for a in w)
+    assert len(own) == sum(len(f.waited) for f in flights[:2])
+    while not req.is_terminal:
+        assert _one_pass(driver)
+    assert len(req.generated) == 2 and not driver.core.has_work()

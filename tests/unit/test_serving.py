@@ -492,12 +492,14 @@ class TestServingRealEngine:
 
     def test_a_step_that_lands_no_token_is_waited_for_and_is_progress(self, tiny_model,
                                                                       monkeypatch):
-        """A step of prompt chunks with more to come completes no row. The
-        engine waits for it all the same, on the pool it returns, and the core
-        reports it as progress: the serving loop neither runs ahead of the
-        device (a chunk step then costs the same with a decode row beside it
-        or none) nor takes it for a stall on KV blocks and polls between two
-        chunks of one prompt. A pass that schedules nothing is still none."""
+        """A step of prompt chunks with more to come completes no row. It is
+        waited for all the same, on its OWN outputs (never the engine's
+        pools, which by then are the next step's), and the core reports it
+        as progress: at most one step is in flight beyond the one waited
+        for, so a long prompt's chunk steps cannot queue on the device, and
+        the loop does not take such a step for a stall on KV blocks and poll
+        between two chunks of one prompt. A pass that schedules nothing is
+        still none."""
         from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
         from deepspeed_tpu.inference.v2 import engine_v2
 
@@ -518,12 +520,25 @@ class TestServingRealEngine:
                             params=SamplingParams(max_new_tokens=2, ignore_eos=True))
         with driver._cond:
             driver._admit_locked()
-        for chunk in range(2):  # 32 + 32 of 80 tokens: more to come
+        flights = []
+        for chunk in range(3):  # 32 + 32 + 16 of 80 tokens
             assert driver._step_once(), f"chunk {chunk} of three is progress"
-            assert req.generated == [] and engine.last_step.prefill_tokens == 32
-            assert len(waits[-1]) == 1 and waits[-1][0] is engine._k_cache
-        assert driver._step_once() and len(req.generated) == 1  # the tail: first token
-        assert waits[-1] and all(w is not engine._k_cache for w in waits[-1])
+            assert req.generated == [] and driver.core.has_work()
+            flights.append(driver.core._flight[0])
+            # launched, not waited for; the step before it was, on its own outputs
+            assert len(waits) == chunk and flights[-1].stats.prefill_tokens == (32, 32, 16)[chunk]
+            assert bool(flights[-1].rows) == (chunk == 2) and flights[-1].stats.ahead == (chunk > 0)
+            if chunk:
+                assert waits[-1] == flights[-2].waited and len(waits[-1]) == 1
+                assert waits[-1][0] is not engine._k_cache
+                assert waits[-1][0] is not engine._last_tokens  # that is THIS step's
+        # the tail's token is taken on the device by the decode step launched
+        # before the tail is collected; the request's last token launches nothing
+        assert driver._step_once() and len(req.generated) == 1 and len(waits) == 3
         assert driver._step_once() and req.state == RequestState.FINISHED
+        assert len(waits) == 4 and not driver.core.has_work()
         assert not driver._step_once(), "a pass that scheduled nothing is no progress"
-        assert engine.last_step.scheduled_tokens == 0
+        assert engine.last_step.scheduled_tokens == 1  # the last step collected: nothing ran since
+        counters = driver.metrics.counters
+        assert counters["engine_steps_total"] == 4 and counters["steps_ahead_total"] == 3
+        assert counters["ahead_rows_dropped_total"] == 0 and counters["decode_tokens_total"] == 2

@@ -107,18 +107,21 @@ class TestServingSpans:
             assert len(parts) == len(dispatches)
             for sp in parts:
                 assert any(_inside(sp, d) for d in dispatches), part
-        # a step on the timeline: dispatch, then the wait, then the tokens come
-        # to the host, all inside the core's step.split; delivery follows it
+        # one step in flight: a pass of the core (step.split) dispatches step
+        # n+1, THEN waits for step n and brings its tokens to the host;
+        # delivery follows it. The first pass only launches, the last only
+        # collects (both requests' last tokens: nothing left to launch).
         steps = [sp for sp in ring if sp.name == "step.split"]
-        assert len(steps) == len(dispatches) == 3
+        assert len(dispatches) == 3 and len(steps) == 4
         for name in ("engine.dispatch", "engine.device_wait", "engine.materialize"):
             for sp in (s for s in ring if s.name == name):
                 assert any(_inside(sp, st) for st in steps), name
         order = [sp.name for sp in sorted(ring, key=lambda s: s.t0)
                  if sp.name in ("engine.dispatch", "engine.device_wait",
                                 "engine.materialize", "step.deliver")]
-        assert order == ["engine.dispatch", "engine.device_wait",
-                         "engine.materialize", "step.deliver"] * 3
+        collect = ["engine.device_wait", "engine.materialize", "step.deliver"]
+        assert order == (["engine.dispatch", "step.deliver"]
+                         + ["engine.dispatch"] + collect + ["engine.dispatch"] + collect + collect)
         # the dispatch span still says what the step carried
         assert [d.args["tokens"] for d in dispatches] == [200, 21, 2]
         assert tracer.stats()["dropped_spans"] == 0
