@@ -1,0 +1,3 @@
+"""Layer: serving programs. chunk_step_time_pct in a cell at saturation, where
+throughput is judged (PERF.md section 2). Should move gen_tok_s."""
+from benchmarks.metrics.chunk_step_time_pct import read  # noqa: F401

@@ -31,6 +31,7 @@ device (``last_tokens`` / ``tok_src``), never through the host.
 """
 
 import dataclasses
+import time
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -45,6 +46,10 @@ from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.observability.tracing import get_tracer
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import device_synchronize
+
+# the host's clock for a step's enqueue and ready stamps: the span tracer's
+# (``time.monotonic``), under a name of its own so that a test can drive it
+_now = time.monotonic
 
 # step program of each cache key's kind: ("split", tq) | ("round", n) | ("verify", k)
 _BUILDERS = {
@@ -108,6 +113,13 @@ def _start_host_copies(arrays) -> None:
             start()
 
 
+def _is_ready(arr) -> bool:
+    """Whether a step's output has been computed, asked without waiting
+    (``jax.Array.is_ready``). Test doubles' numpy arrays always have."""
+    ready = getattr(arr, "is_ready", None)
+    return True if ready is None else bool(ready())
+
+
 def _materialize_rows(res: dict, want_tokens: bool = False) -> dict:
     """{uid: (logits array, row[, token array])} -> {uid: host row}, pulling
     each distinct ARRAY from the device exactly once (rows of one step share
@@ -133,8 +145,16 @@ class StepStats:
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
     paged_live_blocks_total / paged_table_slots_total, chunk_live_blocks_total
     / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total,
-    paged_window_live_blocks_total, steps_ahead_total and
-    ahead_rows_dropped_total."""
+    paged_window_live_blocks_total, steps_ahead_total,
+    ahead_rows_dropped_total, and the step's time on the device by KIND:
+    decode_step_seconds_total / chunk_step_seconds_total with the counts of the
+    steps they hold, and steps_starved_total.
+
+    The kind is ``prefill_tokens``: a split step that carried a prompt chunk
+    is a CHUNK step, every other split step a DECODE step. A fused round and a
+    verify round are timed the same way and counted as decode steps, each of
+    its own program (a round is ``n`` tokens a row in one program: its seconds
+    are a round's, not a token's)."""
 
     grid_slots: int = 0
     scheduled_tokens: int = 0
@@ -168,6 +188,17 @@ class StepStats:
     # that had stopped by the time it was collected
     ahead: bool = False
     ahead_rows_dropped: int = 0
+    # the step on the device, on the host's clock (_collect fills the first
+    # two; no part of what a step WAS, so not compared): ``t_ready``, when the
+    # wait on its outputs returned (None: nothing was launched, nothing timed);
+    # ``device_s``, from the later of the step collected before it turning
+    # ready and its own enqueue to ``t_ready``. ``starved`` (_launch): the step
+    # in flight had ALREADY finished when this one was enqueued, so the chip
+    # ran dry before it: the host was the pace for this step, and the end of
+    # the step before it is seen late (its seconds leak into this one's)
+    t_ready: Optional[float] = dataclasses.field(default=None, compare=False)
+    device_s: float = dataclasses.field(default=0.0, compare=False)
+    starved: bool = dataclasses.field(default=False, compare=False)
 
 
 @dataclasses.dataclass
@@ -180,13 +211,16 @@ class StepInFlight:
     ``stats``: the step's StepStats (``moe`` filled at collect from ``moe``,
     an expert model's routed rows, still on the device); ``rows``: for a
     split step, each completed row's uid -> its slot of the program's
-    ``last_tokens`` output (decode slot i, or R + chunk row j)."""
+    ``last_tokens`` output (decode slot i, or R + chunk row j);
+    ``t_enqueued``: the host's clock just before the jitted call, after the
+    host-to-device transfers (None: nothing was launched)."""
 
     waited: list
     finish: object
     stats: StepStats
     moe: object = None
     rows: Dict[int, int] = dataclasses.field(default_factory=dict)
+    t_enqueued: Optional[float] = None
 
 
 class InferenceEngineV2:
@@ -450,6 +484,14 @@ class InferenceEngineV2:
         # an expert model's [.., L, E] routed rows of the step being launched:
         # _dispatch moves them into the step's StepInFlight
         self._moe_pending = None
+        # the device's step timed where it is collected: the enqueue stamp of
+        # the step being launched (_launch parks it for _dispatch, like the
+        # routed rows), the step launched and not collected yet (what a launch
+        # asks whether the chip ran dry), and when the last collected step was
+        # seen ready (where the next one's time on the device starts)
+        self._enqueued = None
+        self._uncollected = None
+        self._last_ready = 0.0
         # the last split step's sampled tokens by output slot, on the device:
         # the next one's ``last_tokens``
         self._last_tokens = jnp.zeros(
@@ -2317,18 +2359,26 @@ class InferenceEngineV2:
         ``jnp.asarray`` an input, passes the sampling state and the pools,
         stores the pools the program returns and parks an expert model's
         routed rows for _count_moe. Returns the program's outputs: device
-        arrays, nothing waited for."""
+        arrays, nothing waited for.
+
+        Between the transfers and the jitted call it stamps the step's
+        enqueue (``_enqueued``, for _dispatch) and asks, without waiting,
+        whether the step in flight has finished already (``starved``)."""
         fn = self._programs.get(key)
         if fn is None:
             kind, shape = key
             fn = self._programs[key] = getattr(self, _BUILDERS[kind])(shape)
-        outputs, pools, self._moe_pending = fn(
+        args = (
             self.params,
             {name: jnp.asarray(a) for name, a in inputs.items()},
             self._rng,
             jnp.float32(getattr(self.config, "temperature", 1.0) or 1.0),
             self._pools(),
         )
+        prev = self._uncollected
+        self.last_step.starved = prev is not None and _is_ready(prev.waited[0])
+        self._enqueued = _now()
+        outputs, pools, self._moe_pending = fn(*args)
         pools, second = self._split_pools(pools)
         self._k_cache, self._v_cache = pools[:2]
         if self._kv_int8:
@@ -2365,18 +2415,24 @@ class InferenceEngineV2:
             if tr.enabled:
                 sp.args = span_args
         moe, self._moe_pending = self._moe_pending, None
+        enqueued, self._enqueued = self._enqueued, None
         # an expert model's routed rows come from the same program: nothing
         # more to wait for, but for a step that completed no row
         flight = StepInFlight(
-            list(waited) + ([] if moe is None else [moe]), finish, self.last_step, moe, *rows)
+            list(waited) + ([] if moe is None else [moe]), finish, self.last_step, moe, *rows,
+            t_enqueued=enqueued)
         _start_host_copies(flight.waited)
+        if flight.waited:
+            self._uncollected = flight
         return flight
 
     def _collect(self, flight: StepInFlight):
         """The second half: ``engine.device_wait`` blocks on the step's OWN
-        outputs; ``engine.materialize`` reduces an expert model's routed
-        rows and runs ``finish()``, whose value is returned. So host-side
-        queueing and device time separate on the timeline.
+        outputs and, as the wait returns, stamps the step ready and counts
+        its seconds on the device (``StepStats.t_ready`` / ``device_s``: one
+        path, tracing off or on); ``engine.materialize`` reduces an expert
+        model's routed rows and runs ``finish()``, whose value is returned.
+        So host-side queueing and device time separate on the timeline.
         ``engine.last_step`` is the collected step's again."""
         tr = get_tracer()
         track = getattr(self, "_trace_name", "engine")
@@ -2392,6 +2448,16 @@ class InferenceEngineV2:
             # happened to meet. So at most ONE step is in flight beyond the
             # one waited for here (EngineCore.step_once).
             device_synchronize(flight.waited)
+            if flight.waited:
+                # the step's time on the device: it began when the step
+                # collected before it ended (it ran ahead: every step of a
+                # loop under load) or, after an idle chip, at its own enqueue
+                stats = flight.stats
+                stats.t_ready = _now()
+                stats.device_s = stats.t_ready - max(self._last_ready, flight.t_enqueued)
+                self._last_ready = stats.t_ready
+        if self._uncollected is flight:
+            self._uncollected = None
         with tr.span("engine.materialize", track=track):
             self.last_step = flight.stats
             self._count_moe(flight)
@@ -2642,7 +2708,7 @@ class InferenceEngineV2:
             batch = self.scheduler.next_batch()
             self.last_capped |= self.scheduler.drain_capped()
         self.last_step = StepStats()
-        self._moe_pending = None
+        self._moe_pending = self._enqueued = None
         if batch is None:
             return {}, {}, None
         dec_rows = [

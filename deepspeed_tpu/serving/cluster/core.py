@@ -205,6 +205,19 @@ class EngineCore:
         # rows it computed for a request that had stopped meanwhile
         self._inc("steps_ahead_total", int(held("ahead")))
         self._inc("ahead_rows_dropped_total", held("ahead_rows_dropped"))
+        # the step's time on the device, by kind: a split step that carried a
+        # prompt chunk, or any other step or round. Only a step the engine
+        # stamped as it collected it: one that launched nothing, a
+        # compute-free fake's and a remote core's count no second and no step
+        if getattr(stats, "t_ready", None) is not None:
+            if held("prefill_tokens"):
+                self._inc("chunk_step_seconds_total", held("device_s"))
+                self._inc("chunk_steps_timed_total")
+            else:
+                self._inc("decode_step_seconds_total", held("device_s"))
+                self._inc("decode_steps_timed_total")
+        # enqueued after the step in flight had finished: the chip ran dry
+        self._inc("steps_starved_total", int(held("starved")))
         self._inc("grid_slots_total", held("grid_slots"))
         self._inc("scheduled_tokens_total", held("scheduled_tokens"))
         if held("prefill_tokens"):
@@ -394,6 +407,18 @@ class EngineCore:
             if req is not None and req.trace is not None:
                 tr.complete(name, t0, t1, key=uid, parent=req.trace.phase)
 
+    def _trace_step(self, tr, stats, t0: float, t1: float, uids) -> None:
+        """One split step as a completed span named for its kind,
+        ``step.chunk`` (it carried a prompt chunk) or ``step.decode``, over
+        ``[t0, t1]``: the step's own time on the device where the engine
+        stamped it, else the bracket of the call that ran it."""
+        name = "step.chunk" if getattr(stats, "prefill_tokens", 0) else "step.decode"
+        self._trace_round(tr, name, t0, t1, uids, {
+            "rows": len(uids),
+            "tokens": int(getattr(stats, "scheduled_tokens", 0)),
+            "ahead": bool(getattr(stats, "ahead", False)),
+        })
+
     def _spec_step(self, sink, sched) -> bool:
         """One speculative verify round: propose drafts, verify K+1 tokens
         per row in one program, deliver the accepted burst. Returns True
@@ -517,7 +542,12 @@ class EngineCore:
             flight.stats.ahead_rows_dropped += 1
             if uid not in self.requests:
                 sched.finish(uid)  # make sure scheduler state is gone
-        self._count_step(flight.stats)
+        stats = flight.stats
+        self._count_step(stats)
+        tr = get_tracer()
+        if tr.enabled and stats.t_ready is not None:
+            # the interval the counters hold, where they are counted
+            self._trace_step(tr, stats, stats.t_ready - stats.device_s, stats.t_ready, results)
         return results
 
     def _split_step(self, sched, launch: bool):
@@ -527,10 +557,15 @@ class EngineCore:
         collect it. Returns ({uid: token} of what was collected, whether
         anything was scheduled or stays in flight)."""
         if not hasattr(self.engine, "launch_step"):
+            # no stamps from such an engine: the bracket of the call is the step
+            tr = get_tracer()
+            t0 = tr.now() if tr.enabled else 0.0
             results = self.engine.step_tokens()
-            self._count_step()
-            return results, bool(
-                getattr(getattr(self.engine, "last_step", None), "scheduled_tokens", 0))
+            stats = getattr(self.engine, "last_step", None)
+            self._count_step(stats)
+            if tr.enabled:
+                self._trace_step(tr, stats, t0, tr.now(), results)
+            return results, bool(getattr(stats, "scheduled_tokens", 0))
         prev, self._flight = self._flight, None
         collect = [prev] if prev is not None else []
         if prev is not None and launch:
@@ -597,14 +632,7 @@ class EngineCore:
                             "tokens": sum(len(t) for t in round_res.values()),
                         })
                     return self._deliver_results(sink, sched, round_res, feedback=False)
-            t0 = tr.now() if tr.enabled else 0.0
             results, scheduled = self._split_step(sched, launch)
-            if tr.enabled:
-                self._trace_round(tr, "step.split", t0, tr.now(), results, {
-                    "rows": len(results),
-                    "tokens": int(getattr(getattr(self.engine, "last_step", None),
-                                          "scheduled_tokens", 0)),
-                })
         except Exception as e:
             # engine-level failure: per-request state is unknowable, so the
             # in-flight set fails (or, under a resilience-enabled router,

@@ -162,6 +162,19 @@ class ServingMetrics:
             # delivered, never a decode token)
             "steps_ahead_total": 0,
             "ahead_rows_dropped_total": 0,
+            # the device's step timed where it is collected
+            # (EngineCore._count_step), by kind: seconds on the device of the
+            # steps with no prompt chunk (a fused or verify round among them)
+            # and of those that carried one, each beside the count of the
+            # steps its seconds hold (a step that launched nothing, a
+            # compute-free fake's and a remote core's have no stamp); and
+            # steps enqueued after the step in flight had already finished:
+            # the chip ran dry, the host was the pace for that step
+            "decode_step_seconds_total": 0,
+            "decode_steps_timed_total": 0,
+            "chunk_step_seconds_total": 0,
+            "chunk_steps_timed_total": 0,
+            "steps_starved_total": 0,
             "admission_blocked_total": 0,
             # prefix cache (mirrors of PrefixCache's monotone counters)
             "prefix_queries_total": 0,

@@ -19,6 +19,7 @@ import pytest
 from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
 from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.observability import NULL_TRACER, SpanTracer, set_tracer
 from deepspeed_tpu.serving.cluster.core import EngineCore
 from deepspeed_tpu.serving.driver import ServingDriver
 from deepspeed_tpu.serving.request import RequestState, SamplingParams
@@ -328,3 +329,153 @@ def test_a_step_that_completed_no_row_is_waited_on_its_own_outputs(toy, monkeypa
     while not req.is_terminal:
         assert _one_pass(driver)
     assert len(req.generated) == 2 and not driver.core.has_work()
+
+
+# -- the device's step timed where it is collected, by kind ------------------
+TICK, DECODE_S, CHUNK_S, IDLE_S = 0.001, 0.010, 0.030, 5.0
+STEP_SPANS = ("step.decode", "step.chunk")
+
+
+class _Clock:
+    """The engine's clock (``engine_v2._now``) in the test's hands. A read
+    returns ``t`` and moves it one TICK on, so that in a pass the enqueue of
+    step n+1 lies BEFORE the ready stamp of step n; the test adds the rest:
+    a step's length before the pass that collects it, the idle between two
+    bursts."""
+
+    def __init__(self):
+        self.t = 1000.0
+        self.reads = []
+
+    def __call__(self):
+        self.reads.append(self.t)
+        self.t += TICK
+        return self.reads[-1]
+
+
+def _timed_run(make, monkeypatch, tracer):
+    """Two bursts with an idle loop between them: a prompt of 100 (chunks of
+    40, 40, 20) beside one of 12, then one of 50. Before each pass the step
+    in flight is given its length by its kind. Returns (driver, clock,
+    requests, the steps' kinds in order)."""
+    clock = _Clock()
+    monkeypatch.setattr(engine_v2, "_now", clock)
+    # whether a step had finished by the next launch is the machine's, not the run's
+    monkeypatch.setattr(engine_v2, "_is_ready", lambda arr: False)
+    set_tracer(tracer)
+    try:
+        driver = ServingDriver(make())
+        kinds = []
+
+        def burst(*work):
+            reqs = [_submit(driver, _prompt(i, n), new) for i, n, new in work]
+            while driver.core.has_work() or driver._queue:
+                if driver.core.step_in_flight:
+                    chunk = driver.core._flight[0].stats.prefill_tokens > 0
+                    kinds.append("chunk" if chunk else "decode")
+                    clock.t += CHUNK_S if chunk else DECODE_S
+                _one_pass(driver)
+            return reqs
+
+        reqs = burst((0, 100, 5), (1, 12, 3))
+        clock.t += IDLE_S
+        reqs += burst((2, 50, 4))
+    finally:
+        set_tracer(NULL_TRACER)
+    assert all(r.state == RequestState.FINISHED for r in reqs)
+    return driver, clock, reqs, kinds
+
+
+def test_a_steps_seconds_go_to_its_kind_and_sum_to_the_busy_time(toy, monkeypatch):
+    """Every step launched with its predecessor in flight begins where that
+    one was seen ready; the first of a burst at its own enqueue. So each
+    step's seconds are what the test gave it (+ the reads between its bounds:
+    the one it starts at and the next step's enqueue), under its own kind,
+    and all of them together are the span from the first enqueue to the last
+    ready less the idle between the bursts."""
+    _name, make = toy
+    driver, clock, _reqs, kinds = _timed_run(make, monkeypatch, NULL_TRACER)
+    c = driver.metrics.counters
+    n_chunk, n_decode = kinds.count("chunk"), kinds.count("decode")
+    assert n_chunk == 5 and n_decode >= 6
+    assert (c["chunk_steps_timed_total"], c["decode_steps_timed_total"]) == (n_chunk, n_decode)
+    assert c["steps_with_prefill_total"] == n_chunk
+    assert c["engine_steps_total"] == n_chunk + n_decode  # every one launched a program
+    assert c["chunk_step_seconds_total"] == pytest.approx(n_chunk * (CHUNK_S + 2 * TICK))
+    # a burst's last step, a decode step, is collected with no launch before it
+    assert c["decode_step_seconds_total"] == pytest.approx(
+        n_decode * (DECODE_S + 2 * TICK) - 2 * TICK)
+    # the reads are the enqueues and the readies, nothing else; the last
+    # ready of the first burst moved the clock one TICK on before the idle
+    assert len(clock.reads) == 2 * len(kinds) and kinds[-1] == "decode"
+    busy = clock.reads[-1] - clock.reads[0] - (IDLE_S + TICK)
+    assert c["chunk_step_seconds_total"] + c["decode_step_seconds_total"] == pytest.approx(busy)
+    # the first step of each burst followed an idle loop: not ahead, and no
+    # launch found a finished step in flight that the loop had not reached...
+    assert c["steps_ahead_total"] == c["engine_steps_total"] - 2 and c["steps_starved_total"] == 0
+    text = driver.metrics.prometheus_text()
+    assert f"chunk_steps_timed_total {n_chunk}" in text and "decode_step_seconds_total" in text
+
+
+def test_step_counters_are_the_same_traced_or_not_and_the_spans_hold_them(toy, monkeypatch):
+    """One seeded run, tracing off and on: identical counters. Traced, each
+    step is one ``step.decode`` / ``step.chunk`` span on the engine's track
+    over the interval its seconds count, mirrored into the tree of each
+    request it completed a row for, under the phase the request was in."""
+    _name, make = toy
+    off, _, _, _ = _timed_run(make, monkeypatch, NULL_TRACER)
+    tracer = SpanTracer()
+    on, _clock, reqs, kinds = _timed_run(make, monkeypatch, tracer)
+    c = on.metrics.counters
+    assert dict(off.metrics.counters) == dict(c)
+    ring = tracer.ring_spans()
+    assert not [sp.name for sp in ring if sp.name == "step.split"]
+    steps = [sp for sp in ring if sp.name in STEP_SPANS]
+    assert [sp.name for sp in steps] == ["step." + k for k in kinds]
+    assert {sp.track for sp in steps} == {on.core.name}
+    for name, counter in zip(STEP_SPANS, ("decode_step_seconds_total", "chunk_step_seconds_total")):
+        assert sum(sp.t1 - sp.t0 for sp in steps if sp.name == name) == pytest.approx(c[counter])
+    assert [sp.args["ahead"] for sp in steps].count(False) == 2  # the first of each burst
+    for req in reqs:
+        tree = tracer.trace(req.uid)["spans"]
+        phase = {sp.name: sp.span_id for sp in tree if sp.name in ("prefill", "decode")}
+        mine = [sp for sp in tree if sp.name in STEP_SPANS]
+        # the step that completed its prompt, then one a later token
+        assert [sp.name for sp in mine if sp.parent_id == phase["prefill"]] == ["step.chunk"]
+        assert sum(sp.parent_id == phase["decode"] for sp in mine) == len(req.generated) - 1
+        assert len(mine) == len(req.generated)
+
+
+def test_a_launch_after_the_step_in_flight_ended_is_starved_one_after_idle_is_not(
+        toy, monkeypatch):
+    """``steps_starved_total``: at its enqueue a step found the step in
+    flight already finished (asked without waiting), so the chip ran dry in
+    front of it. A launch with nothing in flight is not one, whatever the
+    outputs say: that is the complement of ``steps_ahead_total``."""
+    _name, make = toy
+    driver = ServingDriver(make())
+    c = driver.metrics.counters
+    real, asked = engine_v2._is_ready, []
+    answer = [True]
+    monkeypatch.setattr(engine_v2, "_is_ready", lambda arr: (asked.append(arr), answer[0])[1])
+    a = _submit(driver, _prompt(0, 20), 6)
+    assert _one_pass(driver) and not asked  # after an idle loop: nothing in flight to ask
+    first = driver.core._flight[0]
+    assert _one_pass(driver)  # step 2 is enqueued behind a finished step 1
+    assert asked == [first.waited[0]] and driver.core._flight[0].stats.starved
+    answer[0] = False
+    while not a.is_terminal:
+        assert _one_pass(driver)
+    assert c["steps_starved_total"] == 1 and c["engine_steps_total"] == 6
+    assert not driver.core.has_work()
+    # the real question, of a step this test has waited for itself
+    b = _submit(driver, _prompt(1, 20), 4)
+    assert _one_pass(driver)
+    jax.block_until_ready(driver.core._flight[0].waited)
+    monkeypatch.setattr(engine_v2, "_is_ready", real)
+    assert _one_pass(driver) and driver.core._flight[0].stats.starved
+    monkeypatch.setattr(engine_v2, "_is_ready", lambda arr: False)
+    while not b.is_terminal:
+        assert _one_pass(driver)
+    assert c["steps_starved_total"] == 2 and c["engine_steps_total"] == 10
+    assert c["steps_ahead_total"] == c["engine_steps_total"] - 2

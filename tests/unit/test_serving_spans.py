@@ -18,7 +18,11 @@ from deepspeed_tpu.serving.request import RequestState, SamplingParams
 
 # the contract of names: benchmarks/metrics readers and docs/OBSERVABILITY.md
 STEP_SPANS = {"engine.schedule", "engine.stage", "engine.launch", "engine.dispatch",
-              "engine.device_wait", "engine.materialize", "step.split", "step.deliver"}
+              "engine.device_wait", "engine.materialize", "step.decode", "step.chunk",
+              "step.deliver"}
+# a step's time on the device, recorded after the fact from the step's own
+# stamps (no ``with``, no profiler annotation): it lies across the host's spans
+DEVICE_SPANS = ("step.decode", "step.chunk")
 LOOP_SPANS = {"loop.admit", "loop.bookkeeping", "loop.wait"}
 
 
@@ -100,22 +104,37 @@ class TestServingSpans:
         assert all(sp.t1 is not None for sp in ring)
         names = {sp.name for sp in ring}
         assert STEP_SPANS | LOOP_SPANS <= names, sorted((STEP_SPANS | LOOP_SPANS) - names)
-        _assert_overlap_only_by_nesting(ring)  # the loop is one thread
+        host = [sp for sp in ring if sp.name not in DEVICE_SPANS]
+        _assert_overlap_only_by_nesting(host)  # the loop is one thread
         dispatches = [sp for sp in ring if sp.name == "engine.dispatch"]
         for part in ("engine.schedule", "engine.stage", "engine.launch"):
             parts = [sp for sp in ring if sp.name == part]
             assert len(parts) == len(dispatches)
             for sp in parts:
                 assert any(_inside(sp, d) for d in dispatches), part
-        # one step in flight: a pass of the core (step.split) dispatches step
-        # n+1, THEN waits for step n and brings its tokens to the host;
-        # delivery follows it. The first pass only launches, the last only
-        # collects (both requests' last tokens: nothing left to launch).
-        steps = [sp for sp in ring if sp.name == "step.split"]
-        assert len(dispatches) == 3 and len(steps) == 4
-        for name in ("engine.dispatch", "engine.device_wait", "engine.materialize"):
-            for sp in (s for s in ring if s.name == name):
-                assert any(_inside(sp, st) for st in steps), name
+        # one step in flight: a pass of the core dispatches step n+1, THEN
+        # waits for step n and brings its tokens to the host; delivery
+        # follows it. The first pass only launches, the last only collects
+        # (both requests' last tokens: nothing left to launch).
+        # A step is one span named for its kind, over its time on the
+        # device: [a chunk of 200], [a decode row + a chunk of 20], [two
+        # decode rows]. It starts where the step before it was seen ready
+        # (it was launched ahead) and ends inside its own wait.
+        steps = sorted((sp for sp in ring if sp.name in DEVICE_SPANS), key=lambda s: s.t0)
+        assert len(dispatches) == 3
+        assert [sp.name for sp in steps] == ["step.chunk", "step.chunk", "step.decode"]
+        assert [sp.args for sp in steps] == [
+            {"rows": 1, "tokens": 200, "ahead": False},
+            {"rows": 2, "tokens": 21, "ahead": True},
+            {"rows": 2, "tokens": 2, "ahead": True}]
+        waits = sorted((sp for sp in ring if sp.name == "engine.device_wait"),
+                       key=lambda s: s.t0)
+        launches = sorted((sp for sp in ring if sp.name == "engine.launch"), key=lambda s: s.t0)
+        for i, (st, wait, launch) in enumerate(zip(steps, waits, launches)):
+            assert wait.t0 <= st.t1 <= wait.t1, "seen ready inside its own wait"
+            assert st.t0 >= launch.t0, "never before its own transfers began"
+            assert st.t0 == (steps[i - 1].t1 if i else st.t0)
+            assert st.track == dispatches[0].track
         order = [sp.name for sp in sorted(ring, key=lambda s: s.t0)
                  if sp.name in ("engine.dispatch", "engine.device_wait",
                                 "engine.materialize", "step.deliver")]
@@ -136,9 +155,12 @@ class TestServingSpans:
         for name in ("engine.stage", "engine.launch", "engine.dispatch",
                      "engine.device_wait", "engine.materialize"):
             assert any(_inside(sp, r) for r in rounds for sp in ring if sp.name == name), name
-        assert sum(sp.name == "step.deliver" for sp in ring) == \
-            sum(sp.name in ("round.fused", "step.split") for sp in ring)
-        _assert_overlap_only_by_nesting(ring)
+        # the prompt's step, then the rounds: each is delivered once
+        assert [sp.name for sp in sorted(ring, key=lambda s: s.t0)
+                if sp.name in ("round.fused",) + DEVICE_SPANS] == (
+                    ["step.chunk"] + ["round.fused"] * len(rounds))
+        assert sum(sp.name == "step.deliver" for sp in ring) == 1 + len(rounds)
+        _assert_overlap_only_by_nesting([sp for sp in ring if sp.name not in DEVICE_SPANS])
 
     def test_ring_overflow_is_counted(self):
         tracer = SpanTracer(max_events=256)
@@ -243,7 +265,7 @@ class TestOnePath:
         on = set_tracer(SpanTracer())
         _, reqs = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
         on_names = [sp.name for sp in sorted(on.ring_spans(), key=lambda s: s.span_id)
-                    if sp.name not in ("step.split",)]  # recorded after the fact, both ways
+                    if sp.name not in DEVICE_SPANS]  # recorded after the fact, both ways
         assert [r.generated for r in reqs] == off_tokens
         assert len(waits) == off_waits == 3
 
@@ -251,7 +273,7 @@ class TestOnePath:
             return [n for n in names if n.startswith(("engine.", "step."))]
 
         assert steps_only(off_names) == steps_only(on_names)
-        assert set(off_names) >= (STEP_SPANS - {"step.split"}) | (LOOP_SPANS - {"loop.wait"})
+        assert set(off_names) >= (STEP_SPANS - set(DEVICE_SPANS)) | (LOOP_SPANS - {"loop.wait"})
 
 
 class TestProfilerBridge:

@@ -371,7 +371,7 @@ class TestRouterTracing:
         root = _assert_single_rooted(rec["spans"])
         names = _by_name(rec["spans"])
         for required in ("queued", "placement", "prefill", "handoff.export",
-                        "handoff.import", "decode", "step.split"):
+                        "handoff.import", "decode", "step.decode"):
             assert required in names, f"missing {required} in {sorted(names)}"
         place = names["placement"][0]
         assert "prefill" in place.args and "decode" in place.args
@@ -379,7 +379,7 @@ class TestRouterTracing:
         assert names["handoff.import"][0].args["blocks"] >= 1
         # decode rounds land inside the decode phase
         decode = names["decode"][0]
-        in_decode = [sp for sp in names["step.split"]
+        in_decode = [sp for sp in names["step.decode"]
                      if sp.parent_id == decode.span_id]
         assert in_decode, "no step rounds parented on the decode phase"
         assert root.args["finish_reason"] == "max_tokens"
@@ -623,4 +623,41 @@ class TestTracingOverheadShape:
         assert NULL_TRACER.recent() == []
         assert served["off"] == served["on"]
         names = {sp.name for sp in tracer.ring_spans()}
-        assert {"step.split", "step.deliver", "loop.admit", "loop.bookkeeping"} <= names
+        # an engine that has only step_tokens() stamps nothing: the bracket of
+        # the call is the step, named for its kind (no ``last_step``: decode)
+        assert {"step.decode", "step.deliver", "loop.admit", "loop.bookkeeping"} <= names
+        assert "step.split" not in names
+
+    def test_an_engine_without_stamps_counts_steps_and_no_step_time(self):
+        """The step's seconds on the device come from the engine's own stamps
+        (``StepStats.t_ready`` / ``device_s``). A compute-free fake has none:
+        its steps are counted, its step time and its timed steps stay 0, and
+        no launch of its is starved; a ``last_step`` that shows prefill
+        tokens names the bracket ``step.chunk``."""
+        tracer = set_tracer(SpanTracer())
+        eng = FakeEngine()
+        real_step = eng.step_tokens
+
+        def step_tokens():
+            out = real_step()
+            eng.last_step = SimpleNamespace(
+                scheduled_tokens=len(out), prefill_tokens=5 if eng.steps == 1 else 0)
+            return out
+
+        eng.step_tokens = step_tokens
+        driver = ServingDriver(eng, max_queue=8)
+        driver.start()
+        try:
+            req = driver.submit(np.arange(1, 6, dtype=np.int32), params=_params(3))
+            assert req.wait(30)
+        finally:
+            driver.shutdown(drain=False)
+        c = driver.metrics.counters
+        assert c["engine_steps_total"] >= 3 and c["steps_with_prefill_total"] == 1
+        for name in ("decode_step_seconds_total", "chunk_step_seconds_total",
+                     "decode_steps_timed_total", "chunk_steps_timed_total",
+                     "steps_starved_total", "steps_ahead_total"):
+            assert c[name] == 0, name
+        steps = [sp.name for sp in tracer.ring_spans() if sp.name.startswith("step.")
+                 and sp.name != "step.deliver"]
+        assert steps[0] == "step.chunk" and set(steps[1:]) == {"step.decode"}
