@@ -1300,10 +1300,13 @@ class InferenceEngineV2:
         """Decode attention: one token per row, per-ROW layer-offset tables
         [R, B] into the flat pools, dispatched through ``paged_attention``
         with the impl resolved at engine init — on TPU the Pallas kernel,
-        whose grid is the blocks the rows' contexts cover and one tail a
-        row, read off ``positions`` / ``pool_limit`` / ``window`` (a table
-        slot a row does not hold costs nothing; int8 pools dequantize
-        in-VMEM behind the halved HBM reads), elsewhere the dense XLA
+        whose grid is the blocks the rows' contexts cover, a row's last
+        visit also its finish (it folds this step's K/V and writes the row;
+        a row that holds no block is one program), read off ``positions`` /
+        ``pool_limit`` / ``window`` (a table slot a row does not hold costs
+        nothing; a block is folded in one product batched over the KV
+        heads, in the pool's dtype; int8 pools dequantize in-VMEM behind
+        the halved HBM reads), elsewhere the dense XLA
         gather+einsum over whole tables (``impl="dense"``: GSPMD shards it
         on the kv-head dim without a shard_map island; CPU and tp shapes).
         ``extra_kv``/``pool_limit``: this step's K/V ride alongside and

@@ -20,12 +20,19 @@ Implementations (``paged_attention(impl=...)``; ``auto`` is the kernel on a
 TPU for head sizes 64 / 128 / 256 and the dense form elsewhere):
   * ``kernel`` — the Pallas kernel ``dstpu_paged_decode``. Its grid is the
     list of visits the call's rows need (``_visit_list``): for each query
-    row the table slots its context covers, in order, then one tail program
-    for the extra columns and the finish. A slot a row does not hold is no
-    program, so a call costs what its rows hold, not ``T x B``. The block
-    of a visit comes in through a scalar-prefetched index map; the list is
-    computed from ``q_pos`` / ``pool_limit`` / ``window`` by a few small XLA
-    fusions in front of the call.
+    row the table slots its context covers, in order; the row's LAST visit
+    also folds the extra columns and writes the row out, and a row that
+    holds no block is one program (the extra columns alone; a padded slot
+    writes zeros). A slot a row does not hold is no program, so a call
+    costs what its rows hold, not ``T x B``. A visit takes the
+    ``[bs, nkv, d]`` block as it lies in the pool, swaps it to head-major
+    once and folds it into the row's flash state in ONE product batched
+    over the KV heads: operands in the queries' dtype (the pool's, in every
+    served model; an int8 pool dequantises into it in VMEM), float32
+    scores, softmax state and accumulator; the queries come in scaled. The
+    block of a visit comes in through a scalar-prefetched index map; the
+    list is computed from ``q_pos`` / ``pool_limit`` / ``window`` by a few
+    small XLA fusions in front of the call.
   * ``dense`` (``paged_decode_attention_dense``) — plain XLA: gather every
     slot of every table, then a masked einsum. What runs off the TPU and at
     ``tp_size > 1`` (GSPMD shards it on the kv-head dim); it reads the whole
@@ -108,29 +115,35 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables, q_pos, trash_bl
     return out.astype(q.dtype)
 
 
-def _paged_kernel(
-    *refs, bs, nh, nkv, d, B, E=0, window=0, scale=None, int8=False
-):
+def _paged_kernel(*refs, bs, E=0, window=0, int8=False):
     """One program a VISIT: the grid is the list of (row, table slot) pairs
-    the rows' contexts cover, each row's pool blocks in order and then its
-    tail (the extra columns and the finish), so a table slot a row does not
-    hold costs nothing. ``refs`` layout — scalar prefetch (SMEM): bt [T, B],
-    qpos [T], trash [1], limit [T], vrow [G], vslot [G] (the visit list,
-    ``_visit_list``) — then tensor blocks (VMEM): epos (1, 1, E) if ``E``,
-    q (1, nh, d), k (1, bs, nkv, d), v, ks/vs scale planes (1, bs, nkv) if
-    ``int8``, ke/ve (1, E, nkv, d) if ``E`` — then o (1, nh, d) and the
-    m/l/acc flash scratch.
+    the rows' contexts cover, each row's pool blocks in order, so a table
+    slot a row does not hold costs nothing; a row's LAST visit also folds the
+    extra columns and writes the row out. ``refs`` layout — scalar prefetch
+    (SMEM): bt [T, B], qpos [T], trash [1], limit [T], vrow / vslot / vflag
+    [G] (the visit list, ``_visit_list``) — then tensor blocks (VMEM): epos
+    (1, 1, E) if ``E``, q (1, nkv, group, d) scaled, k (1, bs, nkv, d), v,
+    ks/vs scale planes (1, bs, nkv) if ``int8``, ke/ve (1, E, nkv, d) if
+    ``E`` — then o (1, nkv, group, d) and the m / l / acc flash scratch
+    [nkv, group, .].
+
+    A block is folded as it lies in the pool: swapped to head-major
+    ``[nkv, bs, d]`` once and multiplied in ONE product batched over the KV
+    heads, operands in the queries' dtype (what the pool stores, in every
+    served model), float32 scores, softmax state and accumulator, read and
+    written whole once a visit (an operation a head costs a visit what its
+    head count costs, not what its bytes cost: ledger, PRs 32 and 34).
 
     ``trash`` rides as a prefetch operand (not a static kwarg) because the
     engine's flat multi-layer views use layer-offset trash ids — traced
     values inside the fori_loop layer driver. ``E`` extra columns are this
     step/round's NOT-YET-CACHED K/V (the write-after-read protocol), kept
     in compute dtype — only the pool payload is int8; dequant happens here
-    in fp32 right after the halved-HBM block DMA, so the VPU multiply
-    hides under the transfer (the EQuARX argument applied to HBM)."""
+    right after the halved-HBM block DMA, so the VPU multiply hides under
+    the transfer (the EQuARX argument applied to HBM)."""
     it = iter(refs)
     bt_ref, qpos_ref, trash_ref, limit_ref = next(it), next(it), next(it), next(it)
-    vrow_ref, vslot_ref = next(it), next(it)
+    vrow_ref, vslot_ref, vflag_ref = next(it), next(it), next(it)
     epos_ref = next(it) if E else None
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
     ks_ref = next(it) if int8 else None
@@ -141,47 +154,35 @@ def _paged_kernel(
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
 
     g = pl.program_id(0)
-    t = vrow_ref[g]
-    slot = vslot_ref[g]  # >= B: the row's tail
-    group = nh // nkv
-    scale = scale if scale is not None else d**-0.5
+    t, slot, flag = vrow_ref[g], vslot_ref[g], vflag_ref[g]
     qpos = qpos_ref[t]
 
-    @pl.when((g == 0) | (vrow_ref[jnp.maximum(g - 1, 0)] != t))
+    @pl.when((flag & 2) != 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32) * scale  # [nh, d]
+    q = q_ref[0]  # [nkv, group, d]
 
-    def flash_accum(k, v, valid):
-        """One online-softmax accumulation sweep: k/v [nk, nkv, d] fp32,
-        valid [1, nk] or None for all. Disjoint per-kv-head scratch slices,
-        so reading m/l once up front is safe."""
-        m_prev = m_scr[...]  # [nh, 128] (col 0 meaningful)
-        l_prev = l_scr[...]
-        for n in range(nkv):
-            qn = q[n * group : (n + 1) * group]  # [group, d]
-            kn = k[:, n, :]  # [nk, d]
-            vn = v[:, n, :]
-            s = jax.lax.dot_general(
-                qn, kn, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # [group, nk]
-            if valid is not None:
-                s = jnp.where(valid, s, NEG_INF)
-            m_p = m_prev[n * group : (n + 1) * group, :1]  # [group, 1]
-            m_new = jnp.maximum(m_p, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_p - m_new)
-            p = jnp.exp(s - m_new)  # [group, nk]
-            l_p = l_prev[n * group : (n + 1) * group, :1]
-            l_new = l_p * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc_scr[n * group : (n + 1) * group, :]  # [group, d]
-            acc_scr[n * group : (n + 1) * group, :] = acc * alpha + jax.lax.dot_general(
-                p, vn, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            m_scr[n * group : (n + 1) * group, :1] = m_new
-            l_scr[n * group : (n + 1) * group, :1] = l_new
+    def fold(k, v, valid):
+        """One online-softmax step over keys k / values v [nkv, nk, d] in the
+        queries' dtype; ``valid`` [1, 1, nk], or None where every key is the
+        row's."""
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        )  # [nkv, group, nk]
+        if valid is not None:
+            s = jnp.where(valid, s, NEG_INF)
+        m_p = m_scr[:, :, :1]  # col 0 meaningful
+        m_new = jnp.maximum(m_p, jnp.max(s, axis=2, keepdims=True))
+        alpha = jnp.exp(m_p - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_scr[:, :, :1] = m_new
 
     # the pool holds the row's keys below ``limit`` (the explicit pool window
     # of the write-after-read protocol, else the causal <=; 0 for a padded
@@ -189,54 +190,53 @@ def _paged_kernel(
     # context takes no mask: all but a row's last (under a window, and its
     # first) are such
     limit = limit_ref[t]
-    live = bt_ref[t, jnp.minimum(slot, B - 1)] != trash_ref[0]
+    live = bt_ref[t, slot] != trash_ref[0]
     whole = live & ((slot + 1) * bs <= limit)
     if window:
+        from deepspeed_tpu.ops.attention.core import window_too_far
+
         whole = whole & (qpos - slot * bs < window)
 
     def in_context(kpos):
         ok = (kpos < limit) & live
         if window:
-            from deepspeed_tpu.ops.attention.core import window_too_far
-
             ok = ok & jnp.logical_not(window_too_far(qpos, kpos, window))
         return ok
 
     def pool_block(masked):
-        k = k_ref[0].astype(jnp.float32)  # [bs, nkv, d]
-        v = v_ref[0].astype(jnp.float32)
+        k, v = k_ref[0], v_ref[0]  # [bs, nkv, d], as the pool stores them
         if int8:
-            k = k * ks_ref[0][..., None]
-            v = v * vs_ref[0][..., None]
+            k = k.astype(jnp.float32) * ks_ref[0][..., None]
+            v = v.astype(jnp.float32) * vs_ref[0][..., None]
+        k = jnp.swapaxes(k.astype(q.dtype), 0, 1)  # head-major [nkv, bs, d]
+        v = jnp.swapaxes(v.astype(q.dtype), 0, 1)
         valid = None
         if masked:
             # a masked key's weight is exactly 0, and 0 x NaN is not: what the
             # block holds outside the row's context must not reach the sum
-            krow = slot * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
-            v = jnp.where(in_context(krow), v, 0.0)
-            valid = in_context(slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1))
-        flash_accum(k, v, valid)
+            krow = slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1)
+            v = jnp.where(in_context(krow), v, jnp.zeros_like(v))
+            valid = in_context(slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2))
+        fold(k, v, valid)
 
-    pl.when((slot < B) & whole)(lambda: pool_block(masked=False))
-    pl.when((slot < B) & jnp.logical_not(whole))(lambda: pool_block(masked=True))
+    # a row that holds no block is one program: nothing of the pool to fold
+    holds = (flag & 1) != 0
+    pl.when(holds & whole)(lambda: pool_block(masked=False))
+    pl.when(holds & jnp.logical_not(whole))(lambda: pool_block(masked=True))
 
-    @pl.when(slot >= B)
-    def _tail():
+    @pl.when((flag & 4) != 0)
+    def _finish():
         if E:
-            epos = epos_ref[0]  # [1, E]
+            epos = epos_ref[...]  # [1, 1, E]
             valid = (epos >= 0) & (epos <= qpos)
             if window:
-                from deepspeed_tpu.ops.attention.core import window_too_far
-
                 valid = valid & jnp.logical_not(window_too_far(qpos, epos, window))
-            flash_accum(
-                ke_ref[0].astype(jnp.float32), ve_ref[0].astype(jnp.float32), valid
-            )
-        l = l_scr[:, :1]
+            fold(jnp.swapaxes(ke_ref[0], 0, 1).astype(q.dtype),
+                 jnp.swapaxes(ve_ref[0], 0, 1).astype(q.dtype), valid)
         # fully-masked token (all-trash padding): m never left NEG_INF and
         # every p degenerated to exp(0) — emit 0, matching the reference
-        any_valid = m_scr[:, :1] > NEG_INF * 0.5
-        out = jnp.where(any_valid, acc_scr[...] / jnp.maximum(l, 1e-30), 0.0)
+        any_valid = m_scr[:, :, :1] > NEG_INF * 0.5
+        out = jnp.where(any_valid, acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30), 0.0)
         o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -319,29 +319,29 @@ def paged_attention(
         limit = q_pos + 1  # the causal <=
     else:
         limit = jnp.where(q_pos >= 0, jnp.asarray(pool_limit, jnp.int32).reshape(T), 0)
-    n_visits, vrow, vslot = _visit_list(q_pos, limit, bs, B, int(window))
+    n_visits, vrow, vslot, vflag = _visit_list(q_pos, limit, bs, B, int(window))
+    group = nh // nkv
+    # the queries go in scaled, a KV head's query heads together: the fold
+    # batches over the KV heads and scales nothing a visit
+    qs = (q.astype(jnp.float32) * (scale if scale is not None else d**-0.5)).astype(q.dtype)
 
-    # index maps see (g, bt, qpos, trash, limit, vrow, vslot): a row's operands
-    # follow vrow[g]; the pool's follow the table. A tail's slot is B + the
-    # slot its row fetched last: the same block index as the program before
-    # it, so nothing is fetched for it
+    # index maps see (g, bt, qpos, trash, limit, vrow, vslot, vflag): a row's
+    # operands follow vrow[g]; the pool's follow the table. A row that holds
+    # no block points at a slot of its table all the same (a padded row: the
+    # trash block, which the row before it already fetched if it was padded)
     def per_row(*shape):
         return pl.BlockSpec((1,) + shape, lambda g, *s: (s[4][g],) + (0,) * len(shape))
 
     def per_block(*shape):
-        def index(g, *s):
-            slot = s[5][g]
-            blk = s[0][s[4][g], jnp.where(slot >= B, slot - B, slot)]
-            return (blk,) + (0,) * len(shape)
-
-        return pl.BlockSpec((1,) + shape, index)
+        return pl.BlockSpec(
+            (1,) + shape, lambda g, *s: (s[0][s[4][g], s[5][g]],) + (0,) * len(shape))
 
     in_specs = []
     if E:
         # [T, 1, E]: a (1, E) window of a [T, E] plane is not a legal Mosaic
         # block (last two dims must be (8, 128)-aligned or whole)
         in_specs.append(per_row(1, E))
-    in_specs.append(per_row(nh, d))
+    in_specs.append(per_row(nkv, group, d))
     in_specs.extend([per_block(bs, nkv, d), per_block(bs, nkv, d)])
     if int8_pool:
         in_specs.extend([per_block(bs, nkv), per_block(bs, nkv)])
@@ -349,20 +349,18 @@ def paged_attention(
         in_specs.extend([per_row(E, nkv, d), per_row(E, nkv, d)])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(n_visits,),
         in_specs=in_specs,
-        out_specs=per_row(nh, d),
+        out_specs=per_row(nkv, group, d),
         scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, d), jnp.float32),
+            pltpu.VMEM((nkv, group, 128), jnp.float32),
+            pltpu.VMEM((nkv, group, 128), jnp.float32),
+            pltpu.VMEM((nkv, group, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, bs=bs, nh=nh, nkv=nkv, d=d, B=B, E=E,
-        window=int(window), scale=scale, int8=int8_pool,
-    )
+        _paged_kernel, bs=bs, E=E, window=int(window), int8=int8_pool)
     operands = [
         block_tables.astype(jnp.int32),
         q_pos,
@@ -370,10 +368,11 @@ def paged_attention(
         limit,
         vrow,
         vslot,
+        vflag,
     ]
     if E:
         operands.append(jnp.asarray(extra_kv[2], jnp.int32).reshape(T, 1, E))
-    operands.append(q)
+    operands.append(qs.reshape(T, nkv, group, d))
     operands.extend([k_cache, v_cache])
     if int8_pool:
         operands.extend([k_scale, v_scale])
@@ -382,7 +381,7 @@ def paged_attention(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, nh, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, nkv, group, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # one flat axis of visits: a row's programs follow one another
             # and accumulate into the same scratch
@@ -390,7 +389,7 @@ def paged_attention(
         ),
         interpret=interpret,
         name=PAGED_DECODE,
-    )(*operands)
+    )(*operands).reshape(T, nh, d)
 
 
 def _visit_list(q_pos, limit, bs: int, B: int, window: int):
@@ -398,20 +397,23 @@ def _visit_list(q_pos, limit, bs: int, B: int, window: int):
     ``t``'s context covers table slots ``lo..hi``: ``hi = ceil(limit / bs)``
     and ``lo`` the block of the first key a sliding ``window`` still admits
     (core.window_too_far: ``q_pos - window + 1``), else 0. Its programs are
-    those slots in order and then its tail. Returns (the number of programs,
-    vrow [T * (B + 1)], vslot [T * (B + 1)]): program ``g`` works for row
-    ``vrow[g]`` on table slot ``vslot[g]``, or is the row's tail where
-    ``vslot[g] >= B`` (then ``vslot[g] - B`` is the slot the row fetched
-    last). Entries past the number of programs are never run."""
+    those slots in order, the last of them also its finish; a row that holds
+    no block (a padded slot; a row whose only key is an extra column) is one
+    program that folds nothing of the pool. Returns (the number of programs,
+    then three [T * B] arrays): program ``g`` works for row ``vrow[g]`` on
+    table slot ``vslot[g]`` under ``vflag[g]``: 1 a pool block to fold, 2
+    the row's first program, 4 its last. Entries past the number of programs
+    are never run."""
     T = q_pos.shape[0]
     hi = jnp.clip((limit + bs - 1) // bs, 0, B)
     lo = jnp.maximum(q_pos - window + 1, 0) // bs if window else jnp.zeros_like(hi)
     n = jnp.maximum(hi - lo, 0)
-    n_visits, j, of_row = _programs_of(n + 1, T * (B + 1))
-    n_g, lo_g = of_row(n), of_row(lo)
-    last = jnp.minimum(lo_g + jnp.maximum(n_g - 1, 0), B - 1)
-    vslot = jnp.where(j < n_g, lo_g + j, B + last)
-    return n_visits, of_row(jnp.arange(T, dtype=jnp.int32)), vslot
+    cnt = jnp.maximum(n, 1)
+    n_visits, j, of_row = _programs_of(cnt, T * B)
+    n_g, cnt_g, lo_g = of_row(n), of_row(cnt), of_row(lo)
+    vslot = jnp.clip(lo_g + j, 0, B - 1)
+    flags = (j < n_g) + 2 * (j == 0) + 4 * (j == cnt_g - 1)
+    return n_visits, of_row(jnp.arange(T, dtype=jnp.int32)), vslot, flags.astype(jnp.int32)
 
 
 def _programs_of(cnt, G: int):

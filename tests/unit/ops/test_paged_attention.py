@@ -507,7 +507,7 @@ def test_paged_kernel_window_starts_past_block_zero(form):
     vc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
     bt = jnp.asarray(np.arange(T * B, dtype=np.int32).reshape(T, B))
     qpos = np.array([60, 36, 35, 7], np.int32)  # first block in band: 2, 1, 1, 0
-    _, vrow, vslot = _visit_list(jnp.asarray(qpos), jnp.asarray(qpos + 1), bs, B, window)
+    _, vrow, vslot, _ = _visit_list(jnp.asarray(qpos), jnp.asarray(qpos + 1), bs, B, window)
     first = [int(vslot[np.flatnonzero(np.asarray(vrow) == t)[0]]) for t in range(T)]
     assert first == [2, 1, 1, 0]
     if form == "plain":
@@ -599,21 +599,119 @@ def test_paged_kernel_ignores_what_a_row_does_not_hold(form, row):
 @pytest.mark.parametrize("window", [0, 20])
 def test_visit_list_against_a_hand_count(window):
     """The kernel's programs, from the bounds alone: each row's slots
-    lo..hi in order, then its tail on the slot it fetched last."""
+    lo..hi in order, ``n`` programs for a row that holds ``n`` blocks and one
+    for a row that holds none, flagged 1 where there is a block to fold, 2 on
+    the row's first program and 4 on its last."""
     bs, B = 16, 4
     qpos = np.array([-1, 0, 15, 16, 40, 63, 63], np.int32)
     limit = np.array([0, 1, 16, 17, 40, 64, 0], np.int32)  # the last row: an empty pool window
-    n, vrow, vslot = _visit_list(jnp.asarray(qpos), jnp.asarray(limit), bs, B, window)
+    n, vrow, vslot, vflag = _visit_list(jnp.asarray(qpos), jnp.asarray(limit), bs, B, window)
     want = []
     for t, (p, lim) in enumerate(zip(qpos, limit)):
         hi = min(-(-int(lim) // bs), B)
         lo = max(int(p) - window + 1, 0) // bs if window else 0
         slots = list(range(lo, hi))
-        want += [(t, s) for s in slots] + [(t, B + (slots[-1] if slots else min(lo, B - 1)))]
+        if not slots:  # one program all the same: the extra columns, or zeros
+            want.append((t, min(lo, B - 1), 2 | 4))
+        for i, s in enumerate(slots):
+            want.append((t, s, 1 | (2 if i == 0 else 0) | (4 if i == len(slots) - 1 else 0)))
     assert int(n) == len(want)
-    assert list(zip(np.asarray(vrow)[: len(want)].tolist(),
-                    np.asarray(vslot)[: len(want)].tolist())) == want
-    assert vrow.shape == vslot.shape == (len(qpos) * (B + 1),)
+    got = [np.asarray(a)[: len(want)].tolist() for a in (vrow, vslot, vflag)]
+    assert list(zip(*got)) == want
+    assert vrow.shape == vslot.shape == vflag.shape == (len(qpos) * B,)
+    # by hand, window 0: rows 0 and 6 hold nothing, row 5 its whole table
+    if not window:
+        assert int(n) == 1 + 1 + 1 + 2 + 3 + 4 + 1
+        assert want[:3] == [(0, 0, 6), (1, 0, 7), (2, 0, 7)]
+        assert want[3:5] == [(3, 0, 3), (3, 1, 5)]
+
+
+# the cells' three geometries (Qwen3 / K-EXAONE's KV side, OLMoE, Qwen3-Next)
+# in the dtype they serve: a bf16 pool and bf16 queries against the float32
+# reference on the same (bf16-valued) numbers
+# unit-normal q, k, v: it reads 0.007-0.009 here, of which rounding the output
+# itself to bf16 is 0.0075 (the dense form on the same call)
+BF16_FOLD_ATOL = 2e-2
+
+
+@pytest.mark.parametrize("E", [0, 1, 4])
+@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (16, 2, 256)])
+def test_paged_kernel_bf16_pool_against_float32_reference(nh, nkv, d, E):
+    """Operands enter the products as the pool stores them (bf16), scores,
+    softmax state and accumulator in float32: contexts of nothing, one key,
+    a part block, exactly one block, several, a full table, and a padded
+    slot in one call, with ``E`` extra columns (0: the plain form)."""
+    rng = np.random.default_rng(30)
+    q, (kc, vc), _, bt, ctx, trash = _ragged_call(rng, nh, nkv, d)
+    T = len(ctx)
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    qb, kb, vb = bf(q), bf(kc), bf(vc)
+    if E == 0:
+        qpos = jnp.asarray(ctx - 1)
+        ref = paged_attention_reference(
+            f32(qb), f32(kb), f32(vb), jnp.asarray(np.where(ctx[:, None] > 0, bt, trash)),
+            jnp.maximum(qpos, 0), trash)
+        out = paged_attention(qb, kb, vb, jnp.asarray(bt), qpos, trash,
+                              impl="kernel", interpret=True)
+        dead = ctx <= 0
+    else:
+        # the row's E fresh tokens at ctx .. ctx + E - 1, the query the last
+        ke, ve = bf(rng.normal(size=(T, E, nkv, d))), bf(rng.normal(size=(T, E, nkv, d)))
+        epos = np.where(ctx[:, None] >= 0, ctx[:, None] + np.arange(E)[None], -1).astype(np.int32)
+        qpos = jnp.asarray(np.where(ctx >= 0, ctx + E - 1, -1).astype(np.int32))
+        lim = jnp.asarray(np.maximum(ctx, 0))
+        ref = paged_attention(
+            f32(qb), f32(kb), f32(vb), jnp.asarray(bt), qpos, trash, impl="dense",
+            extra_kv=(f32(ke), f32(ve), jnp.asarray(epos)), pool_limit=lim)
+        out = paged_attention(
+            qb, kb, vb, jnp.asarray(bt), qpos, trash, impl="kernel", interpret=True,
+            extra_kv=(ke, ve, jnp.asarray(epos)), pool_limit=lim)
+        dead = ctx < 0
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
+                               atol=BF16_FOLD_ATOL)
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[dead], 0.0)
+
+
+@pytest.mark.parametrize("case", ["only the extra column", "exactly one block", "all trash"])
+def test_paged_kernel_rows_of_one_program(case):
+    """A row whose first visit is its last. ``only the extra column``: an
+    empty pool and E = 1, the first token after a prompt of a block's
+    multiple was written elsewhere: the output is that column's value.
+    ``exactly one block``: first = last visit, the block masked or whole.
+    ``all trash``: every row padded: one program a row, zeros out."""
+    rng = np.random.default_rng(31)
+    T, nh, nkv, d, bs, B = 3, 8, 4, 64, 16, 3
+    NB = T * B + 1
+    trash = NB - 1
+    q = jnp.asarray(rng.normal(size=(T, nh, d)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
+    ke = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+    ve = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
+    bt = np.full((T, B), trash, np.int32)
+    if case == "only the extra column":
+        ctx = np.array([0, 0, 0], np.int32)
+        bt[:, 0] = np.arange(T) * B  # the block the token will land in: nothing of it is held
+    elif case == "exactly one block":
+        ctx = np.array([bs, 5, 1], np.int32)  # whole; masked; one key
+        bt[:, 0] = np.arange(T) * B
+    else:
+        ctx = np.array([-1, -1, -1], np.int32)
+    kw = dict(extra_kv=(ke, ve, jnp.asarray(ctx[:, None])), pool_limit=jnp.asarray(np.maximum(ctx, 0)))
+    args = (q, kc, vc, jnp.asarray(bt), jnp.asarray(ctx), trash)
+    n, vrow, _, vflag = _visit_list(jnp.asarray(ctx), kw["pool_limit"], bs, B, 0)
+    assert int(n) == T and np.asarray(vrow)[:T].tolist() == [0, 1, 2]
+    assert np.asarray(vflag)[:T].tolist() == [7 if case == "exactly one block" else 6] * T
+    out = np.asarray(paged_attention(*args, impl="kernel", interpret=True, **kw))
+    if case == "all trash":
+        np.testing.assert_array_equal(out, 0.0)
+        return
+    ref = np.asarray(paged_attention(*args, impl="dense", **kw))
+    np.testing.assert_allclose(out, ref, atol=2e-5)
+    if case == "only the extra column":
+        np.testing.assert_allclose(out, np.repeat(np.asarray(ve[:, 0]), nh // nkv, axis=1), atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
