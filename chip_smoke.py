@@ -432,6 +432,79 @@ def kernel_cases(size):
         ring_chunk("dense"), 2e-2,
     ))
 
+    # a LATENT pool at the A.X-K1 geometry (64 heads against blocks of [576,
+    # 128]: the normed 512-wide latent + 64 rotated key dims a token, a block
+    # its tokens on the lanes): the absorbed decode with the row's own vector
+    # beside the pool, prompt chunks over the pool and their own vectors, and
+    # the pool write that merges a step's vectors into the blocks they land in
+    from deepspeed_tpu.ops.attention import latent_pallas as LP
+
+    lnh, lrank, lD, lbs, lB, lP, ltq, lR = (
+        (4, 16, 24, 16, 6, 40, 32, 4) if TINY else (64, 512, 576, 128, 112, 4096, 512, 32))
+    lpool = rnd((lP + 1, lD, lbs))
+    lctx = rs.integers(1, lB * lbs - 1, size=lR)
+    ltab = np.full((lR, lB), lP, np.int32)
+    free = list(rs.permutation(lP))
+    for r in range(lR - 1):   # the last slot is the grid's padding
+        for j in range(-(-int(lctx[r]) // lbs)):
+            ltab[r, j] = free.pop()
+    lpos = jnp.asarray(np.where(np.arange(lR) < lR - 1, lctx, -1), jnp.int32)
+
+    def latent_dec(impl):
+        def run(q, pool, tb, qpos, own):
+            return LP.latent_decode(q, pool, tb, qpos, lP, rank=lrank, scale=lD ** -0.5,
+                                    extra=(own, qpos[:, None]), pool_limit=qpos, impl=impl,
+                                    interpret=interp)
+        return run
+
+    cases.append((
+        "latent decode, absorbed, the row's own vector beside the pool",
+        latent_dec("kernel"), (rnd((lR, lnh, lD)), lpool, jnp.asarray(ltab), lpos, rnd((lR, 1, lD))),
+        latent_dec("dense"), 2e-2,
+    ))
+    cB = lB if TINY else 24   # (the dense oracle's scores are [2, tq, heads, B x bs + tq])
+    cstart = jnp.asarray([min(3 * ltq + 5, (cB - 5) * lbs), 0], jnp.int32)
+    ccpos = jnp.stack([cstart[0] + jnp.arange(ltq), jnp.where(
+        jnp.arange(ltq) < ltq - ltq // 4, jnp.arange(ltq), -1)]).astype(jnp.int32)
+    ctab = np.full((2, cB), lP, np.int32)
+    for r, n in enumerate((-(-(int(cstart[0]) + ltq) // lbs), ltq // lbs)):
+        ctab[r, :n] = [free.pop() for _ in range(n)]   # (no block is two rows': the write below)
+
+    def latent_chk(impl):
+        def run(q, pool, tb, qpos, new, limit):
+            return LP.latent_chunk(q, pool, tb, qpos, lP, new, limit, rank=lrank, scale=lD ** -0.5,
+                                   impl=impl, interpret=interp)
+        return run
+
+    cases.append((
+        "latent chunk, absorbed, the pool below the chunk and its own vectors",
+        latent_chk("kernel"),
+        (rnd((2, ltq, lnh, lD)), lpool, jnp.asarray(ctab), ccpos, rnd((2, ltq, lD)), cstart),
+        latent_chk("dense"), 2e-2,
+    ))
+    # the write: lR decode rows' blocks and a chunk's, two layers of the pool
+    wn = lR + ltq
+    wblk = np.full(wn, lP, np.int32)
+    wrow = np.zeros(wn, np.int32)
+    wblk[: lR - 1], wrow[: lR - 1] = ltab[np.arange(lR - 1), lctx[: lR - 1] // lbs], lctx[: lR - 1] % lbs
+    cp = int(cstart[0]) + np.arange(ltq)
+    wblk[lR:], wrow[lR:] = ctab[0][cp // lbs], cp % lbs
+    visits = tuple(jnp.asarray(v) for v in LP.write_visits(
+        wblk, lP, lR + ltq // lbs + ltq // LP.WRITE_TILE + 3))
+
+    def latent_wr(impl):
+        def run(pool, new, blk, row, vis):
+            out = LP.latent_write(pool, new, blk, row, vis, impl=impl, interpret=interp)
+            return out[:, :lP]   # (the trash block holds whatever the padding left)
+        return run
+
+    cases.append((
+        "latent write, a step's vectors merged into the pool's blocks",
+        latent_wr("kernel"),
+        (jnp.stack([lpool, lpool * 0.5]), rnd((2, wn, lD)), jnp.asarray(wblk), jnp.asarray(wrow), visits),
+        latent_wr("dense"), 0.0,
+    ))
+
     cases.append((
         "gdn decode, the state pool in place",
         gdn("interpret" if interp else "kernel"),

@@ -353,12 +353,16 @@ def engine_config_from_args(args, cfg):
         # capacity multiplier reaches admission
         # (a DeltaNet model's state slots or a mixed stack's window rings,
         # one a tracked sequence and a spare, come out of the same budget first)
-        from deepspeed_tpu.inference.v2.kv_pool import blocks_for_budget, slot_bytes
+        # (a latent model's pool is one plane of one vector a token)
+        from deepspeed_tpu.inference.v2.kv_pool import (
+            blocks_for_budget, pool_geometry, slot_bytes)
 
+        kv_heads, head_dim, planes = pool_geometry(cfg)
         num_blocks = blocks_for_budget(
-            int(args.kv_pool_bytes), args.block_size, cfg.kv_heads,
-            cfg.head_dim, cfg.kv_layers, kv_dtype,
+            int(args.kv_pool_bytes), args.block_size, kv_heads,
+            head_dim, cfg.kv_layers, kv_dtype,
             state_bytes=(args.max_concurrent + 1) * slot_bytes(cfg, args.block_size),
+            planes=planes,
         )
     return RaggedInferenceEngineConfig.from_dict({
         "dtype": args.dtype, "tp_size": args.tp,

@@ -13,7 +13,9 @@ TPU adaptation:
     a stack that mixes window and global layers holds the window layers' K/V
     in a window pool [Lw, slots * wb, block_size, n_kv, d], a ring of wb
     blocks a tracked sequence (kv_pool.py), and the block pool the global
-    layers' alone;
+    layers' alone; a latent-attention model (``kv_lora_rank``) has ONE plane,
+    [L, num_blocks, latent_dim, block_size]: a token's keys and values are
+    one vector every head shares (ops/attention/latent_pallas.py);
   * paged attention = block-table gather → dense attention with a length
     mask, or the Pallas paged kernel underneath (``paged_attention``);
   * a step is one compiled program over a fixed grid (the SplitFuse
@@ -145,7 +147,9 @@ class StepStats:
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
     paged_live_blocks_total / paged_table_slots_total, chunk_live_blocks_total
     / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total,
-    paged_window_live_blocks_total, steps_ahead_total,
+    paged_window_live_blocks_total, latent_decode_rows_total /
+    latent_decode_blocks_total / latent_live_blocks_total,
+    moe_group_hit_tokens_total, steps_ahead_total,
     ahead_rows_dropped_total, and the step's time on the device by KIND:
     decode_step_seconds_total / chunk_step_seconds_total with the counts of the
     steps they hold, and steps_starved_total.
@@ -183,6 +187,13 @@ class StepStats:
     kv_window_blocks: int = 0
     kv_context_tokens: int = 0
     paged_window_live_blocks: int = 0
+    # a latent pool: the decode rows of the step and the pool blocks ONE
+    # layer's absorbed decode walks for them (_count_latent); 0 elsewhere
+    latent_decode_rows: int = 0
+    latent_decode_blocks: int = 0
+    # ... and the pool blocks the tracked sequences' tables hold (kv_global_blocks
+    # also counts what the prefix cache retains until a request needs the room)
+    latent_live_blocks: int = 0
     # one step in flight (the serving core fills both): the step was launched
     # before its predecessor was collected; rows it computed for a request
     # that had stopped by the time it was collected
@@ -254,6 +265,10 @@ class InferenceEngineV2:
                         "window layers keep their K/V in a window pool" if self._windowed else None)
         if self._beside:
             self._refuse_at_build(model_config, quantized, tp)
+        # latent attention: a pool of one plane, one vector a token
+        self._latent = model_config.latent
+        if self._latent:
+            self._refuse_latent_at_build(quantized, tp)
         if model_config.n_experts > 0 and (quantized or tp > 1):
             # the expert layer's grouped matmul reads whole bf16 expert
             # weights on one device; neither variant has a test
@@ -398,7 +413,7 @@ class InferenceEngineV2:
             # tp>1 stays dense: the Pallas kernel is opaque to GSPMD and
             # has no shard_map island; the gather shards on the kv-head dim
             impl = "kernel" if (
-                on_tpu() and c.head_dim in (64, 128, 256) and self._tp == 1
+                on_tpu() and (c.head_dim in (64, 128, 256) or c.latent) and self._tp == 1
             ) else "dense"
         elif impl == "kernel" and self._tp > 1:
             raise NotImplementedError(
@@ -412,7 +427,11 @@ class InferenceEngineV2:
         shape = (c.kv_layers, kv.num_blocks + 1, kv.block_size, c.kv_heads, c.head_dim)
         sshape = shape[:-1]  # fp32 scale planes: one scalar per head vector
         self._ks_cache = self._vs_cache = None
-        if self._tp > 1:
+        if self._latent:
+            # ONE plane, a block latent_dim rows of its tokens (_k_cache names it)
+            self._k_cache = jnp.zeros(shape[:2] + (c.latent_dim, kv.block_size), dtype)
+            self._v_cache = None
+        elif self._tp > 1:
             zeros = jax.jit(lambda: jnp.zeros(shape, pool_dtype), out_shardings=self._kv_sharding)
             self._k_cache = zeros()
             self._v_cache = zeros()
@@ -513,6 +532,7 @@ class InferenceEngineV2:
             + (f", comm_overlap=tiled({self._overlap_tiles})" if self._tp_tiled else "")
             + (", prefix_cache=on" if self.state_manager.prefix_cache is not None else "")
             + (f", host_tier={htb}B" if self._host_tier is not None else "")
+            + (f", latent pool {c.latent_dim} a token a layer" if self._latent else "")
             + (f", state_slots={self._state_slots}" if self._hybrid else "")
             + (f", window_pool={c.window_layers} layers x {self._state_slots} rings of "
                f"{self._win_blocks} blocks" if self._windowed else ""),
@@ -548,6 +568,31 @@ class InferenceEngineV2:
                 ranks=[0])
             self.config.kv_cache = dataclasses.replace(kv, prefix_cache=False)
 
+    def _refuse_latent_at_build(self, quantized: bool, tp: int) -> None:
+        """A latent-attention model caches ONE plane, ``latent_dim`` rows of a
+        block's tokens a layer: what has no form for it yet raises here, with
+        its reason, and never misreads the plane as K and V. (The prefix cache
+        stays on: a hit shares blocks by table, whatever a block holds.)"""
+        kv = self.config.kv_cache
+        what = None
+        if quantized or tp > 1:
+            what = ("quantized weights" if quantized else f"tp_size={tp}") + (
+                ": the latent projections have no int8 or model-sharded form")
+        elif str(getattr(kv, "kv_cache_dtype", "bf16") or "bf16") != "bf16":
+            what = (f"kv_cache_dtype={kv.kv_cache_dtype!r}: the int8 pool's scale planes are a "
+                    "scalar a head vector, and a latent vector is no head's")
+        elif int(getattr(kv, "host_tier_bytes", 0) or 0) > 0:
+            what = ("a host block tier (host_tier_bytes): a spilled block is checked and "
+                    "readmitted as K and V planes")
+        elif int(getattr(self.config, "spec_k", 0) or 0) > 0:
+            what = "speculative decoding (spec_k): the verify step has no absorbed form"
+        elif int(getattr(self.config, "decode_steps", 1) or 1) > 1:
+            what = "decode_steps > 1: the fused decode round has no absorbed form"
+        if what is not None:
+            raise NotImplementedError(
+                "v2 paged engine: a latent-attention model (one latent vector a token in "
+                f"place of per-head keys and values) with {what}")
+
     def _refuse_state_loss(self, what: str) -> None:
         """Raise where an operation moves or rolls back a sequence's cache by
         K/V blocks alone (handoff, recovery, host tier, speculative verify):
@@ -557,6 +602,11 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 f"{what}: this model's {self._beside} "
                 "beside the K/V blocks, and nothing moves or snapshots it yet")
+        if self._latent:
+            raise NotImplementedError(
+                f"{what}: this model's pool is one latent plane [layers, blocks, latent_dim, "
+                "block_size], and what moves blocks between pools carries K and V planes "
+                "[.., kv_heads, head_dim] alone")
 
     @property
     def prefix_cache(self):
@@ -611,12 +661,13 @@ class InferenceEngineV2:
         bytes/block, dtype, capacity multiplier vs bf16 (kv_pool.describe),
         plus the resolved attention impl, and for a model with DeltaNet
         layers the state slots and their bytes."""
-        from deepspeed_tpu.inference.v2.kv_pool import describe
+        from deepspeed_tpu.inference.v2.kv_pool import describe, pool_geometry
 
         c, kv = self._mc, self.config.kv_cache
+        kv_heads, head_dim, planes = pool_geometry(c)
         info = describe(
-            kv.num_blocks, kv.block_size, c.kv_heads, c.head_dim,
-            c.kv_layers, self._kv_dtype,
+            kv.num_blocks, kv.block_size, kv_heads, head_dim,
+            c.kv_layers, self._kv_dtype, planes,
         )
         info["paged_attention_impl"] = self._attn_impl
         if self._windowed:
@@ -663,6 +714,8 @@ class InferenceEngineV2:
         return out
 
     def _kv_pool_planes(self) -> Dict[str, "jnp.ndarray"]:
+        if self._latent:
+            return {"c": self._k_cache}
         planes = {"k": self._k_cache, "v": self._v_cache}
         if self._kv_int8:
             planes["k_scale"] = self._ks_cache
@@ -1173,6 +1226,9 @@ class InferenceEngineV2:
         """What a step's layers read of the caches, by name, for ``meta``:
         the flat views of the block pools (and scale planes) and, for a mixed
         stack, of the window pools ``second`` [Lw * NWp, bs, nkv, d]."""
+        if self._latent:
+            (c_pool,) = pools  # [L, NBp, D, bs] -> flat blocks, layer-offset tables
+            return {"k_pool0": c_pool.reshape((-1,) + c_pool.shape[2:])}
         k_pool0, v_pool0 = self._pool_views(*pools[:2])
         ks_pool0, vs_pool0 = self._scale_views(*pools[2:])
         views = {"k_pool0": k_pool0, "v_pool0": v_pool0,
@@ -1209,7 +1265,9 @@ class InferenceEngineV2:
             return (meta["wk_pool0"], meta["wv_pool0"], o * NWp + meta["win_" + tables],
                     o * NWp + NWp - self._win_blocks)
         NBp = kv.num_blocks + 1
-        return meta["k_pool0"], meta["v_pool0"], o * NBp + meta[tables], o * NBp + kv.num_blocks
+        # (a latent pool is one plane: no v_pool0)
+        return (meta["k_pool0"], meta.get("v_pool0"), o * NBp + meta[tables],
+                o * NBp + kv.num_blocks)
 
     def _side_index(self, li):
         """Where layer ``li``'s new K/V sit in the side buffers: at the
@@ -1235,8 +1293,8 @@ class InferenceEngineV2:
 
     def _pools(self):
         """The pools as the step programs take them and give them back: ONE
-        argument, ``(k, v)``, with an int8 pool ``(k, v, ks, vs)``, with
-        DeltaNet layers ``(k, v, states, conv inputs)``, donated whole —
+        argument, ``(k, v)``, a latent model's ``(c,)``, with an int8 pool
+        ``(k, v, ks, vs)``, with DeltaNet layers ``(k, v, states, conv inputs)``, donated whole —
         whatever else a program takes, and whichever kinds of cache the model
         has, every leaf of it is updated in place."""
         second = ((self._gdn_state, self._gdn_conv) if self._hybrid else
@@ -1384,6 +1442,13 @@ class InferenceEngineV2:
         (``_with_state``): they are never a loop's invariant, every read and
         the in-place update of a layer go through the carried value."""
         c = self._mc
+        if self._latent:
+            # one vector a token: [L, *token_dims, latent_dim] under "k" alone
+            carry = {"k": jnp.zeros((c.kv_layers,) + tuple(token_dims) + (c.latent_dim,),
+                                    T.DTYPES[c.dtype])}
+            if c.n_experts > 0:
+                carry["moe"] = jnp.zeros((c.n_layers, self._moe_width), jnp.int32)
+            return carry
         shape = (c.kv_layers + c.window_layers,) + tuple(token_dims) + (c.kv_heads, c.head_dim)
         side = jnp.zeros(shape, T.DTYPES[c.dtype])
         if self._mesh is not None:
@@ -1398,8 +1463,16 @@ class InferenceEngineV2:
         if c.n_experts > 0:
             # an expert model's loop also carries what each layer routed:
             # [L, E] rows an expert, returned with the step's tokens
-            carry["moe"] = jnp.zeros((c.n_layers, c.n_experts), jnp.int32)
+            carry["moe"] = jnp.zeros((c.n_layers, self._moe_width), jnp.int32)
         return carry
+
+    @property
+    def _moe_width(self) -> int:
+        """Entries of one layer's routed-rows record: a row count an expert
+        held and, for a grouped router, the tokens whose kept groups include
+        a held one (grouped.experts_grouped)."""
+        c = self._mc
+        return c.n_experts + (1 if c.moe_n_group > 1 else 0)
 
     def _with_state(self, carry, second):
         """The carry with a DeltaNet model's (states, conv inputs) in it (a
@@ -1477,15 +1550,31 @@ class InferenceEngineV2:
             for pool, a in zip(caches, new)
         )
 
-    def _write_back(self, pools, second, blk, row, side, wblk=None, x=None):
+    def _write_back(self, pools, second, blk, row, side, wblk=None, x=None, visits=None):
         """Every pool of a step's ``pools`` argument as the program returns
         it: the block pools written from the side buffers (_scatter_kv), then
         a mixed stack's window pools from their layers' part of the same
         buffers at the tokens' ring blocks ``wblk`` [n] (a token that no later
         query can see names the spare ring: no ring slot is written twice),
-        or a DeltaNet model's state pools as the carry holds them. ``x``: the
+        or a DeltaNet model's state pools as the carry holds them; a latent
+        model's one plane through ``latent_write`` (``visits``: its programs,
+        staged on the host). ``x``: the
         stream an UNROLLED stack left (the split step of a mixed stack), which
         orders the write behind the last layer and changes nothing of it."""
+        if self._latent:
+            from deepspeed_tpu.ops.attention.latent_pallas import latent_write
+
+            # the one plane, in place: merged by kernel on the chip under the
+            # host-staged ``visits`` (XLA's scatter of a column copies the pool
+            # twice), scattered elsewhere. An unrolled stack (the dense lead
+            # layer) hands on its last layer's vectors before that layer has
+            # read the pool: the write waits for the stream as below
+            if x is not None:
+                p = x[0, 0, 0]
+                never = (p != p) & (p == p)
+                blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
+                visits = visits and (visits[0], visits[1], jnp.where(never, 0, visits[2]))
+            return (latent_write(pools[0], side["k"], blk, row, visits, impl=self._attn_impl),)
         if not self._windowed:
             return self._scatter_kv(pools, blk, row, (side["k"], side["v"])) + self._state_of(side)
         if x is not None:
@@ -1821,6 +1910,8 @@ class InferenceEngineV2:
                 "live": meta["dec_pos"] >= 0, "slot_live": meta["slot_live"],
                 "chk_slots": meta.get("chk_slots"), "chk_start": meta.get("chk_start"),
                 "chk_pos": meta.get("chk_pos")}, carry)
+        if self._latent:
+            return self._latent_layer(lp, x, li, meta, carry)
         a, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"], w)
         ks_pool, vs_pool = meta["ks_pool0"], meta["vs_pool0"]
         # the pool of the layer's kind at the layer's ordinal in it
@@ -1847,6 +1938,44 @@ class InferenceEngineV2:
             out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, d)], axis=0)
         x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li, a)
         return x, self._record_kv(carry, li, k, v, moe)
+
+    def _latent_layer(self, lp, x, li, meta, carry):
+        """One latent-attention layer of the split step, in the ABSORBED form:
+        ``W_UK`` moved to the query (``q = [q_nope W_UK | q_rope]``, every head
+        against the one cached vector a token) and ``W_UV`` behind the output.
+        Decode rows through ``latent_decode`` (the pool below their position
+        and their own new vector as the extra column), chunk rows through
+        ``latent_chunk`` (the pool below the chunk's start, then the chunk's
+        own vectors, causal); on the chip the kernels ``dstpu_mla_decode`` /
+        ``dstpu_mla_chunk``, elsewhere the dense forms. The layer records its
+        new vectors in ``carry``; the pool is written once, after the loop."""
+        from deepspeed_tpu.ops.attention.latent_pallas import latent_chunk, latent_decode
+
+        c = self._mc
+        R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
+        nh, rank, D = c.n_heads, c.kv_lora_rank, c.latent_dim
+        scale = c.attn_scale if c.attn_scale is not None else c.head_dim ** -0.5
+        lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
+        a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
+        q_nope, q_rope, ckv = T.latent_qkv(c, lp, a[0], meta["positions"], meta["live"])
+        w_uk, w_uv = T.latent_up(c, lp)
+        q = jnp.concatenate([jnp.einsum("thd,chd->thc", q_nope, w_uk), q_rope], axis=-1)
+        pool, _, tables_l, trash_l = self._kv_source(meta, li, "dec_tables")
+        out = latent_decode(
+            q[:R], pool, tables_l, meta["dec_pos"], trash_l, rank=rank, scale=scale,
+            extra=(ckv[:R, None], meta["dec_pos"][:, None]), pool_limit=meta["dec_pos"],
+            impl=self._attn_impl)
+        if tq:
+            _, _, tables_l, trash_l = self._kv_source(meta, li, "chk_tables")
+            out_c = latent_chunk(
+                q[R:].reshape(Rc, tq, nh, D), pool, tables_l, meta["chk_pos"], trash_l,
+                ckv[R:].reshape(Rc, tq, D), meta["chk_start"], rank=rank, scale=scale,
+                impl=self._attn_impl)
+            out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, rank)], axis=0)
+        heads = jnp.einsum("thc,chd->thd", out, w_uv).reshape(x.shape[1], nh * c.v_head_dim)
+        x, moe = self._mlp_tail(lp, x, (heads @ lp["wo"])[None], meta["slot_live"], li)
+        carry = dict(carry, k=jax.lax.dynamic_update_index_in_dim(carry["k"], ckv, li, 0))
+        return x, self._record_moe(carry, li, moe)
 
     def _build_split_step(self, tq: int):
         """ONE compiled step over the split-phase batch: R decode slots +
@@ -1911,8 +2040,11 @@ class InferenceEngineV2:
             x, side = self._drive_layers(
                 layer_fn, params, x,
                 self._with_state(self._side_buffers(tokens.shape[0]), second))
+            visits = tuple(inputs[k] for k in ("lat_vblk", "lat_vtile", "lat_vflag")
+                           ) if "lat_vblk" in inputs else None
             pools = self._write_back(
-                pools, second, inputs["blk"], inputs["row"], side, inputs.get("wblk"), x)
+                pools, second, inputs["blk"], inputs["row"], side, inputs.get("wblk"), x,
+                visits=visits)
             # generate() holds only the token arrays across its prefill
             # phase and drops the logits
             logits_dec, toks_dec = self._sample_rows(
@@ -2284,6 +2416,12 @@ class InferenceEngineV2:
             gdn_decode_rows=len(dec_rows) if self._hybrid else 0,
             **self._count_chunk(chk_rows, tq), **self._count_cache(dec_pos, total_tokens),
         )
+        if self._latent:
+            # what ONE layer's absorbed decode walks: paged_live_blocks again,
+            # under the latent kernel's name, beside the rows it walks them for
+            self.last_step.latent_decode_rows = len(dec_rows)
+            self.last_step.latent_decode_blocks = self.last_step.paged_live_blocks
+            self.last_step.latent_live_blocks = self.state_manager.live_blocks
         inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,
             "dec_tables": dec_tables, "dec_pos": dec_pos, "dec_uids": dec_uids,
@@ -2301,6 +2439,14 @@ class InferenceEngineV2:
                 inputs["chk_slots"] = chk_slots
         if wb:
             inputs["wblk"] = wblk
+        if self._latent and self._attn_impl == "kernel":
+            # the programs of the pool write (dstpu_mla_write), a fixed count a
+            # step shape: a decode row's block, and a chunk's blocks by the
+            # 128-token tiles of the grid they come from
+            from deepspeed_tpu.ops.attention.latent_pallas import WRITE_TILE, write_visits
+
+            inputs["lat_vblk"], inputs["lat_vtile"], inputs["lat_vflag"] = write_visits(
+                blk, trash, R + Rc * (tq // bs + tq // WRITE_TILE + 3) if tq else R)
         return ("split", tq), inputs
 
     def _stage_rows(self, uids, width: int):
@@ -2383,7 +2529,10 @@ class InferenceEngineV2:
         self._enqueued = _now()
         outputs, pools, self._moe_pending = fn(*args)
         pools, second = self._split_pools(pools)
-        self._k_cache, self._v_cache = pools[:2]
+        if self._latent:
+            (self._k_cache,) = pools
+        else:
+            self._k_cache, self._v_cache = pools[:2]
         if self._kv_int8:
             self._ks_cache, self._vs_cache = pools[2:]
         if self._hybrid:
@@ -2485,7 +2634,8 @@ class InferenceEngineV2:
 
         c = self._mc
         rows = np.asarray(pending)[..., c.moe_dense_lead:, :]  # the layers that have experts
-        counts = rows.reshape(-1, c.n_experts)
+        group_hit = rows[..., c.n_experts:].sum() if c.moe_n_group > 1 else None
+        counts = rows[..., : c.n_experts].reshape(-1, c.n_experts)
         # tokens of one layer call: the grid, a step of it for a fused round
         steps = rows.shape[0] if rows.ndim == 3 else 1
         pairs = flight.stats.grid_slots // steps * c.moe_top_k
@@ -2500,6 +2650,11 @@ class InferenceEngineV2:
             "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
             "hit": int((counts > 0).sum()),
         }
+        if group_hit is not None:
+            # a grouped router: tokens whose kept groups include a held one,
+            # summed over the expert layers' calls, beside the tokens routed
+            flight.stats.moe.update(
+                group_hit=int(group_hit), group_tokens=flight.stats.scheduled_tokens * counts.shape[0])
 
     # -- entry points --------------------------------------------------------
     def decode_round(self, n_steps: Optional[int] = None) -> Dict[int, np.ndarray]:

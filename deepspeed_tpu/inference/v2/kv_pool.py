@@ -33,6 +33,13 @@ context: logical block ``b`` of slot ``s`` is ring block ``s * wb + b % wb``.
 It is paid like the state slots, one ring a tracked sequence and a spare
 (``window_slot_bytes``), from the budget first; the block pool then holds the
 global layers alone (``kv_layers``).
+
+A latent-attention model (``TransformerConfig.latent``) has a pool of ONE
+plane, [L, num_blocks+1, latent_dim, block_size]: a token's keys and values
+are one vector every head shares (the normed latent and the rotated key dims:
+512 + 64 for A.X-K1), stored a block as ``latent_dim`` rows of ``block_size``
+tokens (ops/attention/latent_pallas.py says why). ``pool_geometry`` gives what
+the functions below take for it: one "KV head" of ``latent_dim``, one plane.
 """
 
 from typing import Any, Dict, Tuple
@@ -97,17 +104,28 @@ def _check_dtype(kv_dtype: str) -> str:
     return kv_dtype
 
 
+def pool_geometry(config) -> Tuple[int, int, int]:
+    """(kv_heads, head_dim, planes) of a model's block pool, as the byte
+    accounting below takes them (``config``: the TransformerConfig): K and V
+    planes of ``kv_heads x head_dim`` a token, or a latent model's one plane of
+    one ``latent_dim``-wide vector."""
+    if config.latent:
+        return 1, config.latent_dim, 1
+    return config.kv_heads, config.head_dim, 2
+
+
 def bytes_per_block(block_size: int, kv_heads: int, head_dim: int,
-                    n_layers: int, kv_dtype: str = "bf16") -> int:
-    """HBM bytes one logical KV block costs across all layers and both
-    (K and V) pools — payload plus, for int8, the fp32 scale plane."""
+                    n_layers: int, kv_dtype: str = "bf16", planes: int = 2) -> int:
+    """HBM bytes one logical KV block costs across all layers and its
+    ``planes`` pools (K and V; one for a latent pool) — payload plus, for
+    int8, the fp32 scale plane."""
     _check_dtype(kv_dtype)
     vectors = block_size * kv_heads  # head vectors per block per pool
     if kv_dtype == "int8":
         per_pool = vectors * head_dim * 1 + vectors * 4  # int8 payload + fp32 scale
     else:
         per_pool = vectors * head_dim * 2  # bf16 payload
-    return 2 * n_layers * per_pool
+    return planes * n_layers * per_pool
 
 
 def state_slot_bytes(config, conv_itemsize: int = 2) -> int:
@@ -145,14 +163,14 @@ def slot_bytes(config, block_size: int, conv_itemsize: int = 2) -> int:
 
 def blocks_for_budget(budget_bytes: int, block_size: int, kv_heads: int,
                       head_dim: int, n_layers: int,
-                      kv_dtype: str = "bf16", state_bytes: int = 0) -> int:
+                      kv_dtype: str = "bf16", state_bytes: int = 0, planes: int = 2) -> int:
     """How many pool blocks fit a fixed byte budget (the +1 trash block is
     charged too, so the returned count is directly ``num_blocks``).
     ``n_layers``: the layers that keep the whole context's K/V. ``state_bytes``:
     what the slots of the second kind of cache (recurrent states, or the window
     layers' rings: ``slot_bytes`` a tracked sequence and a spare) take from the
     budget first."""
-    per = bytes_per_block(block_size, kv_heads, head_dim, n_layers, kv_dtype)
+    per = bytes_per_block(block_size, kv_heads, head_dim, n_layers, kv_dtype, planes)
     n = (budget_bytes - state_bytes) // per - 1  # -1: the engine allocates num_blocks + 1
     if n < 1:
         raise ValueError(
@@ -164,7 +182,7 @@ def blocks_for_budget(budget_bytes: int, block_size: int, kv_heads: int,
 
 
 def capacity_multiplier(block_size: int, kv_heads: int, head_dim: int,
-                        kv_dtype: str = "bf16") -> float:
+                        kv_dtype: str = "bf16") -> float:  # (the planes cancel)
     """Effective pool-capacity multiplier of ``kv_dtype`` vs the bf16
     baseline at a fixed byte budget (layer count cancels)."""
     base = bytes_per_block(block_size, kv_heads, head_dim, 1, "bf16")
@@ -173,22 +191,22 @@ def capacity_multiplier(block_size: int, kv_heads: int, head_dim: int,
 
 
 def pool_bytes(num_blocks: int, block_size: int, kv_heads: int,
-               head_dim: int, n_layers: int, kv_dtype: str = "bf16") -> int:
+               head_dim: int, n_layers: int, kv_dtype: str = "bf16", planes: int = 2) -> int:
     """Total HBM bytes of the allocated pools (num_blocks + 1 trash)."""
     return (num_blocks + 1) * bytes_per_block(
-        block_size, kv_heads, head_dim, n_layers, kv_dtype
+        block_size, kv_heads, head_dim, n_layers, kv_dtype, planes
     )
 
 
 def describe(num_blocks: int, block_size: int, kv_heads: int, head_dim: int,
-             n_layers: int, kv_dtype: str = "bf16") -> Dict:
+             n_layers: int, kv_dtype: str = "bf16", planes: int = 2) -> Dict:
     """The health()/metrics snapshot: bytes, dtype, capacity multiplier."""
     return {
         "kv_cache_dtype": _check_dtype(kv_dtype),
         "kv_pool_bytes": pool_bytes(
-            num_blocks, block_size, kv_heads, head_dim, n_layers, kv_dtype),
+            num_blocks, block_size, kv_heads, head_dim, n_layers, kv_dtype, planes),
         "kv_bytes_per_block": bytes_per_block(
-            block_size, kv_heads, head_dim, n_layers, kv_dtype),
+            block_size, kv_heads, head_dim, n_layers, kv_dtype, planes),
         "kv_capacity_multiplier": capacity_multiplier(
             block_size, kv_heads, head_dim, kv_dtype),
     }

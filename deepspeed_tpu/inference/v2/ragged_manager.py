@@ -236,6 +236,12 @@ class DSStateManager:
         return out
 
     @property
+    def live_blocks(self) -> int:
+        """Blocks the tracked sequences' tables name (a block two tables share
+        counts twice); what the prefix cache alone retains is not among them."""
+        return sum(len(s.block_table) for s in self._seqs.values())
+
+    @property
     def context_tokens(self) -> int:
         """Tokens of context the tracked sequences hold in the cache."""
         return sum(s.seen_tokens for s in self._seqs.values())

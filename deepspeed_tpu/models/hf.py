@@ -10,7 +10,10 @@ serves through ``deepspeed_tpu.initialize`` / ``init_inference`` unchanged.
 Supported ``model_type``s: llama, mistral, qwen2, qwen2_moe, qwen3, qwen3_next
 (Gated DeltaNet and gated-attention layers, a share of the experts),
 exaone_moe (K-EXAONE: window and full attention layers, output-normed blocks,
-a dense lead layer then sigmoid-routed experts), qwen3_moe (per-head q/k
+a dense lead layer then sigmoid-routed experts), axk1 (A.X-K1: DeepseekV3's
+latent attention, one low-rank vector a token in place of per-head keys and
+values, YaRN rotary on 64 shared dims; a dense lead layer then sigmoid-routed
+experts chosen inside the best groups, a share of them), qwen3_moe (per-head q/k
 RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole projection width; the four MoE types import drop-free: ``moe_drop_tokens``
 false), falcon, phi (incl. qk_layernorm),
 phi3, gpt2, gpt_neo, opt, gemma, bloom, gptj, gpt_neox, internlm, stablelm
@@ -42,6 +45,7 @@ Weight-layout notes (why each mapping is what it is):
 
 import dataclasses
 import json
+import math
 import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -147,6 +151,93 @@ def _llama_like_config(get, **extra) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def _expert_share(get, mt: str, key: str):
+    """(experts held, published count, this chip's share index) from the
+    configuration's ``key`` and ``deployment_share`` (this repo's key: ``key`` is
+    one chip's share of ``deployment_share[key]`` over ``chips_per_layer``)."""
+    held = int(get(key))
+    share = get("deployment_share", None) or {}
+    total = int(share.get(key, held))
+    chips = int(share.get("chips_per_layer", 1))
+    if total != held * chips:
+        raise ValueError(
+            f"{mt}: deployment_share says {chips} chips share {total} experts; "
+            f"{key}={held} is not one chip's share of them")
+    return held, total, int(share.get("share_index", 0))
+
+
+def _router_groups(get, mt: str):
+    """(n_group, topk_group) of a DeepseekV3-style grouped router."""
+    n_group, topk_group = int(get("n_group", 1) or 1), int(get("topk_group", 1) or 1)
+    if n_group < 1 or not 0 < topk_group <= n_group:
+        raise ValueError(f"{mt}: n_group={n_group}, topk_group={topk_group}")
+    return n_group, topk_group
+
+
+def _axk1_config(get) -> TransformerConfig:
+    """A.X-K1 (``axk1``), DeepseekV3's block under its key names: latent
+    attention (``q_lora_rank`` / ``kv_lora_rank``, heads of ``qk_nope_head_dim`` +
+    ``qk_rope_head_dim`` against values of ``v_head_dim``, rotary on the rope dims
+    alone in interleaved pairs, the softmax scale times YaRN's ``mscale``
+    squared), ``first_k_dense_replace`` dense lead layers, then experts routed by
+    sigmoid score inside the ``topk_group`` best of ``n_group`` groups
+    (``topk_method`` "none": no selection bias; "noaux_tc": with one),
+    renormalised, scaled, plus ungated shared experts. ``deployment_share`` as
+    for qwen3_next (``n_routed_experts`` held of the published count)."""
+    import math
+
+    n_layers = int(get("num_hidden_layers"))
+    lead = int(get("first_k_dense_replace", 0) or 0)
+    if not 0 < lead < n_layers or int(get("moe_layer_freq", 1) or 1) != 1:
+        raise ValueError(
+            f"axk1: first_k_dense_replace={lead}, moe_layer_freq={get('moe_layer_freq', 1)}: "
+            "supported are dense lead layers followed by expert layers, some of each")
+    if get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"axk1: scoring_func={get('scoring_func')!r}, expected 'sigmoid'")
+    method = get("topk_method", "noaux_tc")
+    if method not in ("none", "noaux_tc"):
+        raise ValueError(f"axk1: topk_method={method!r}, expected 'none' or 'noaux_tc'")
+    if get("attention_bias", False) or not get("q_lora_rank", None):
+        raise ValueError("axk1: attention_bias, or no q_lora_rank, is not supported")
+    held, total, shard = _expert_share(get, "axk1", "n_routed_experts")
+    n_group, topk_group = _router_groups(get, "axk1")
+    dn, dr = int(get("qk_nope_head_dim")), int(get("qk_rope_head_dim"))
+    # DeepseekV3Attention: the scale of the whole head, times mscale^2 where
+    # the rotary is YaRN-scaled with an mscale_all_dim
+    scale = (dn + dr) ** -0.5
+    scaling = get("rope_scaling", None) or {}
+    if scaling.get("mscale_all_dim") and float(scaling.get("factor", 1.0)) > 1:
+        m = 0.1 * float(scaling["mscale_all_dim"]) * math.log(float(scaling["factor"])) + 1.0
+        scale *= m * m
+    expert_dim = int(get("moe_intermediate_size"))
+    return _llama_like_config(
+        get,
+        head_dim_override=dn + dr,
+        attn_scale=scale,
+        kv_lora_rank=int(get("kv_lora_rank")),
+        q_lora_rank=int(get("q_lora_rank")),
+        qk_nope_dim=dn,
+        qk_rope_dim=dr,
+        v_head_dim=int(get("v_head_dim")),
+        rope_interleave=bool(get("rope_interleave", True)),
+        n_experts=held,
+        moe_experts_total=total if total != held else 0,
+        moe_expert_shard=shard,
+        moe_top_k=get("num_experts_per_tok"),
+        moe_norm_topk_prob=bool(get("norm_topk_prob", True)),
+        moe_drop_tokens=False,  # the published block never drops a token
+        moe_dense_lead=lead,
+        moe_expert_dim=expert_dim,
+        moe_score="sigmoid",
+        moe_router_bias=method == "noaux_tc",
+        moe_n_group=n_group,
+        moe_topk_group=topk_group,
+        moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+        moe_shared_expert_dim=expert_dim * int(get("n_shared_experts", 0) or 0),
+        moe_shared_gated=False,
+    )
+
+
 def _exaone_moe_config(get) -> TransformerConfig:
     """K-EXAONE (``exaone_moe``): window and full attention layers in one stack
     (``layer_types``, window ``sliding_window``), a dense lead MLP then expert
@@ -173,9 +264,7 @@ def _exaone_moe_config(get) -> TransformerConfig:
         raise ValueError(
             f"exaone_moe: mlp_layer_types={mlps!r}: supported are dense lead layers "
             "followed by expert layers, some of each")
-    if int(get("n_group", 1) or 1) != 1 or int(get("topk_group", 1) or 1) != 1:
-        raise ValueError("exaone_moe: n_group / topk_group other than 1 (a grouped top-k) "
-                         "is not supported")
+    n_group, topk_group = _router_groups(get, "exaone_moe")
     if get("scoring_func", "sigmoid") != "sigmoid":
         raise ValueError(f"exaone_moe: scoring_func={get('scoring_func')!r}, expected 'sigmoid'")
     window = int(get("sliding_window", 0) or 0)
@@ -185,14 +274,7 @@ def _exaone_moe_config(get) -> TransformerConfig:
     if not any(local):
         raise ValueError("exaone_moe: no sliding_attention layer: rotary sits on those alone, "
                          "and a stack of none has no position term at all")
-    held = int(get("num_experts"))
-    share = get("deployment_share", None) or {}
-    total = int(share.get("num_experts", held))
-    chips = int(share.get("chips_per_layer", 1))
-    if total != held * chips:
-        raise ValueError(
-            f"exaone_moe: deployment_share says {chips} chips share {total} experts; "
-            f"num_experts={held} is not one chip's share of them")
+    held, total, shard = _expert_share(get, "exaone_moe", "num_experts")
     rope = get("rope_parameters", None) or {}
     if rope.get("rope_type", "default") != "default":
         raise ValueError(f"exaone_moe: rope_type={rope.get('rope_type')!r} is not supported")
@@ -208,13 +290,16 @@ def _exaone_moe_config(get) -> TransformerConfig:
         rope_window_only=True,
         n_experts=held,
         moe_experts_total=total if total != held else 0,
-        moe_expert_shard=int(share.get("share_index", 0)),
+        moe_expert_shard=shard,
         moe_top_k=get("num_experts_per_tok"),
         moe_norm_topk_prob=bool(get("norm_topk_prob", True)),
         moe_drop_tokens=False,  # the published block never drops a token
         moe_dense_lead=lead,
         moe_expert_dim=expert_dim,
         moe_score="sigmoid",
+        # (its router has a selection bias: a group scores its top two summed)
+        moe_n_group=n_group,
+        moe_topk_group=topk_group,
         moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
         moe_shared_expert_dim=expert_dim * int(get("num_shared_experts", 0) or 0),
         moe_shared_gated=False,
@@ -335,6 +420,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         )
     if mt == "exaone_moe":
         return _exaone_moe_config(get)
+    if mt == "axk1":
+        return _axk1_config(get)
     if mt == "qwen2_moe":
         sparse_step = get("decoder_sparse_step", 1)
         mlp_only = get("mlp_only_layers", []) or []
@@ -804,7 +891,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
         "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
-        "qwen3, qwen3_moe, qwen3_next, exaone_moe, megatron_gpt, bert, distilbert, clip_text_model"
+        "qwen3, qwen3_moe, qwen3_next, exaone_moe, axk1, megatron_gpt, bert, distilbert, "
+        "clip_text_model"
     )
 
 
@@ -931,6 +1019,36 @@ def _exaone_moe_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict
     moe = layers["sparse"]
     moe["router"].append(take.linear(f"{p}.mlp.gate.weight"))
     moe["router_bias"].append(take(f"{p}.mlp.gate.e_score_correction_bias"))
+    first = cfg.moe_expert_shard * cfg.n_experts
+    for name, hf in names:
+        moe[name].append(np.stack([
+            take.linear(f"{p}.mlp.experts.{first + e}.{hf}.weight") for e in range(cfg.n_experts)]))
+        moe[f"shared_{name[2:]}"].append(take.linear(f"{p}.mlp.shared_experts.{hf}.weight"))
+
+
+def _axk1_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
+    """One A.X-K1 layer under DeepseekV3's checkpoint names: the latent
+    attention's projections and norms, then a dense MLP (the lead layers, under
+    ``lead``) or DeepseekV3MoE's block (under ``sparse``: the router whole, its
+    selection bias where the router has one, the chip's own experts, the shared
+    experts as one MLP of their summed width)."""
+    i = int(p.rsplit(".", 1)[1])
+    layers["attn_norm"].append(take(f"{p}.input_layernorm.weight"))
+    layers["mlp_norm"].append(take(f"{p}.post_attention_layernorm.weight"))
+    for name, hf in (("wq_a", "q_a_proj"), ("wq_b", "q_b_proj"), ("wkv_a", "kv_a_proj_with_mqa"),
+                     ("wkv_b", "kv_b_proj"), ("wo", "o_proj")):
+        layers[name].append(take.linear(f"{p}.self_attn.{hf}.weight"))
+    layers["q_a_norm"].append(take(f"{p}.self_attn.q_a_layernorm.weight"))
+    layers["kv_a_norm"].append(take(f"{p}.self_attn.kv_a_layernorm.weight"))
+    names = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+    if i < cfg.moe_dense_lead:
+        for name, hf in names:
+            layers["lead"][name].append(take.linear(f"{p}.mlp.{hf}.weight"))
+        return
+    moe = layers["sparse"]
+    moe["router"].append(take.linear(f"{p}.mlp.gate.weight"))
+    if cfg.moe_router_bias:
+        moe["router_bias"].append(take(f"{p}.mlp.gate.e_score_correction_bias"))
     first = cfg.moe_expert_shard * cfg.n_experts
     for name, hf in names:
         moe[name].append(np.stack([
@@ -1313,6 +1431,7 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "qwen3": _llama_layer,
     "qwen3_next": _qwen3_next_layer,
     "exaone_moe": _exaone_moe_layer,
+    "axk1": _axk1_layer,
     "qwen3_moe": _llama_layer,
     "falcon": _falcon_layer,
     "phi": _phi_layer,
@@ -1347,6 +1466,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
     "qwen3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_next": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "exaone_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "axk1": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi": ("model.embed_tokens.weight", "model.final_layernorm", "model.layers", None),
@@ -1386,6 +1506,10 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
 def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
     """Empty stacking lists for exactly the keys this config's params carry."""
     keys = ["attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_up", "w_down"]
+    if cfg.latent:
+        from deepspeed_tpu.models.transformer import LATENT_KEYS
+
+        keys = [k for k in keys if k not in ("wq", "wk", "wv")] + list(LATENT_KEYS)
     if cfg.activation in ("swiglu", "geglu"):
         keys.append("w_gate")
     if cfg.norm == "layernorm":
@@ -1404,7 +1528,7 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
         keys.append("wq_gate")
     if cfg.n_experts > 0:
         keys.append("router")
-        if cfg.moe_score == "sigmoid":
+        if cfg.moe_score == "sigmoid" and cfg.moe_router_bias:
             keys.append("router_bias")
         if cfg.moe_shared_expert_dim > 0:
             keys += ["shared_gate", "shared_up", "shared_down"]

@@ -234,6 +234,35 @@ class TransformerConfig:
     moe_score: str = "softmax"
     moe_routed_scale: float = 1.0
     moe_shared_gated: bool = True
+    # deepseek-v3 / a.x-k1 GROUPED top-k: the router's experts lie in
+    # moe_n_group groups of equal size, a token keeps its moe_topk_group best
+    # groups and takes its top-k inside them (a token's experts then lie on
+    # few chips of a deployment that gives a chip a group). A group's score:
+    # its LARGEST expert score where the router has no selection bias
+    # (DeepSeek-V2's group_limited_greedy), the sum of its two largest
+    # score + bias where it has one (DeepseekV3TopkRouter). moe_router_bias
+    # False: a sigmoid router without the selection bias (a.x-k1's
+    # topk_method "none"); no router_bias parameter then
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_router_bias: bool = True
+    # latent attention (DeepseekV3Attention, a.x-k1): kv_lora_rank > 0. A
+    # token's keys and values are ONE vector shared by every head: the normed
+    # latent [kv_lora_rank] and qk_rope_dim rotated key dims; a head's keys
+    # (qk_nope_dim, beside the shared rotated dims) and values (v_head_dim)
+    # are wkv_b of the latent. Queries go through a low-rank pair with a norm
+    # between (q_lora_rank). head_dim is qk_nope_dim + qk_rope_dim (the
+    # softmax's 1/sqrt), rotary turns the qk_rope_dim dims alone, in
+    # INTERLEAVED pairs (2i, 2i+1) where rope_interleave. Parameters: wq_a,
+    # q_a_norm, wq_b, wkv_a, kv_a_norm, wkv_b in place of wq / wk / wv, wo
+    # [n_heads * v_head_dim, h]. The serving engine caches the one vector a
+    # token (``latent_dim`` wide) and attends in the absorbed form.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False
     # per-layer KIND (qwen3-next): n_layers names, "full" (softmax attention
     # over cached keys and values) or "gdn" (Gated DeltaNet: a recurrent state
     # and a short causal conv, ops/linear_attention). None: every layer "full".
@@ -306,6 +335,28 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_dense_lead={self.moe_dense_lead}: the lead layers are some, not all, of "
                 f"an expert model's {self.n_layers} layers (and compose with no layer_kinds)")
+        if self.moe_n_group > 1 and (
+                self.router_width % self.moe_n_group
+                or not 0 < self.moe_topk_group <= self.moe_n_group
+                or self.moe_topk_group * (self.router_width // self.moe_n_group) < self.moe_top_k
+                or self.moe_score != "sigmoid"):
+            raise ValueError(
+                f"moe_n_group={self.moe_n_group}, moe_topk_group={self.moe_topk_group}: the "
+                f"{self.router_width} experts of a sigmoid router in equal groups, the kept "
+                f"groups holding at least moe_top_k={self.moe_top_k} experts")
+        if self.kv_lora_rank and not (
+                min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim) > 0
+                and self.qk_rope_dim % 2 == 0
+                and self.head_dim_override == self.qk_nope_dim + self.qk_rope_dim
+                and self.position == "rope" and self.norm_scheme == "pre"
+                and not (self.sliding_window or self.layer_kinds or self.qk_norm
+                         or self.attn_qkv_bias or self.attn_out_bias or self.attn_out_gate
+                         or self.parallel_block or self.rope_frac != 1.0)):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, qk_nope_dim, an even "
+                "qk_rope_dim and v_head_dim, head_dim_override = qk_nope_dim + qk_rope_dim, "
+                "rotary positions and pre-norm blocks, and composes with no window, layer "
+                "kinds, q/k norm, bias, output gate or parallel block")
         if self.rope_window_only and (self.attn_layer_pattern is None or self.position != "rope"):
             raise ValueError("rope_window_only needs attn_layer_pattern (which layers have the "
                              "window) and position='rope'")
@@ -435,6 +486,17 @@ class TransformerConfig:
         return self.hidden_size // self.n_heads
 
     @property
+    def latent(self) -> bool:
+        """True where a token's keys and values are one latent vector."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """What a latent model caches a token a layer: the normed latent and
+        the rotated key dims every head shares."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
     def router_width(self) -> int:
         """Experts the router chooses among: the published count."""
         return self.moe_experts_total or self.n_experts
@@ -525,6 +587,9 @@ ATTENTION_KEYS = frozenset({
     "wq", "wk", "wv", "wo", "wq_gate", "wq_b", "wk_b", "wv_b", "wo_b",
     "q_norm", "k_norm", "q_norm_b", "k_norm_b",
 })
+# what a latent-attention layer has in place of wq / wk / wv (and their biases:
+# "wq_b" above is the q BIAS of a qwen2 layer, here the second query projection)
+LATENT_KEYS = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b")
 
 
 def layer_period(c: TransformerConfig) -> Tuple[Tuple[str, ...], int]:
@@ -631,8 +696,34 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     # wrapped or a window not applied 0.9-1.1; at 1 / sqrt(2 layers) 0.27 and
     # 3-5. The benchmark's fixed limit lies between the two at the smaller
     # weight only: PERF.md section 6, PR 31.)
-    into_stream = 1.0 / math.sqrt(2 * L) if c.hybrid else 1.0
-    unit_stream = c.hybrid or c.norm_scheme == "out"
+    # A latent-attention model (a.x-k1) is seeded BY COMPONENT, so that the
+    # comparison tells a fault in what is new (the latent cache, the absorbed
+    # attention) from bf16, which one gain for everything could not (my chip
+    # runs, PR 37: PERF.md section 6 has every reading). (1) Attention has to
+    # ATTEND: at unit gain 64 heads' scores over thousands of keys have std 1.8,
+    # a softmax spread over ~250 of 4,096 keys whose output is 7% of a value's
+    # size, and with every projection into the stream at 1 / (2 layers) rotary
+    # dropped from the cache, a neighbouring head's W_UK, a lost norm or the
+    # wrong softmax scale all read under the limit (0.09-0.16 against bf16's
+    # 0.06-0.09, limit 0.15). So the second query projection is drawn at TWICE
+    # unit gain (~14 keys carry a query, as a trained head's few) and o_proj
+    # at 0.5: a layer's attention adds a fifth of the stream, those faults
+    # read 1.3-3.0 and the cache through float8 0.13-0.22, bf16 0.04-0.05. At
+    # 0.6 bf16 read 0.05-0.11 over eight runs: too near the limit. (2) The
+    # latent's norm weights are drawn in [0.5, 1.5] (at 1 a vector of unit rms
+    # is its own norm, and a cache that stores the latent BEFORE its norm reads
+    # sound). (3) What DECIDES stays small: a router that chooses groups and
+    # then experts turns ~5% of its choices on a bf16 rounding, each turned
+    # choice moves the token by one expert's output, and an expert's down
+    # projection at 1 / (6 layers) keeps that under a third of the limit (the
+    # dense and shared MLPs at 1 / (2 layers)). The price, said in PERF.md: a
+    # router that IGNORES its groups differs from the sound one by an expert or
+    # two a token, which is what bf16 does too, and reads under the limit at
+    # any gain that keeps bf16 under it (the CPU tests hold the groups exactly).
+    latent_gain = {"q": 2.0, "attn": 0.5, "mlp": 1.0 / (2 * L), "experts": 1.0 / (6 * L)}
+    into_stream = (latent_gain["experts"] if c.latent else 1.0 / math.sqrt(2 * L) if c.hybrid
+                   else 1.0)
+    unit_stream = c.hybrid or c.latent or c.norm_scheme == "out"
 
     # rmsnorm_1p's effective scale is (1 + w): identity init is ZEROS there
     norm_one = jnp.zeros if c.norm == "rmsnorm_1p" else jnp.ones
@@ -645,12 +736,28 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     La = c.kind_count("full")
     layers: Dict[str, Any] = {
         "attn_norm": block_norm((L, h), dtype),
-        "wq": dense(next(keys), (La, h, nh * d), h),
-        "wk": dense(next(keys), (La, h, nkv * d), h),
-        "wv": dense(next(keys), (La, h, nkv * d), h),
-        "wo": dense(next(keys), (La, nh * d, h), nh * d, into_stream),
         "mlp_norm": block_norm((L, h), dtype),
     }
+    if c.latent:
+        rank, qr = c.kv_lora_rank, c.q_lora_rank
+        layers.update(
+            wq_a=dense(next(keys), (L, h, qr), h),
+            q_a_norm=jnp.ones((L, qr), dtype),
+            wq_b=dense(next(keys), (L, qr, nh * d), qr, latent_gain["q"]),
+            # the latent and, behind it, the rotary key dims every head shares
+            wkv_a=dense(next(keys), (L, h, c.latent_dim), h),
+            kv_a_norm=jax.random.uniform(next(keys), (L, rank), jnp.float32, 0.5, 1.5).astype(dtype),
+            # per head: qk_nope_dim key columns, then v_head_dim value columns
+            wkv_b=dense(next(keys), (L, rank, nh * (c.qk_nope_dim + c.v_head_dim)), rank),
+            wo=dense(next(keys), (L, nh * c.v_head_dim, h), nh * c.v_head_dim, latent_gain["attn"]),
+        )
+    else:
+        layers.update(
+            wq=dense(next(keys), (La, h, nh * d), h),
+            wk=dense(next(keys), (La, h, nkv * d), h),
+            wv=dense(next(keys), (La, h, nkv * d), h),
+            wo=dense(next(keys), (La, nh * d, h), nh * d, into_stream),
+        )
     if c.attn_out_gate:
         layers["wq_gate"] = dense(next(keys), (La, h, nh * d), h)
     if c.norm == "layernorm":
@@ -704,7 +811,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         }
     def dense_mlp(n):
         out = {"w_up": dense(next(keys), (n, h, ffn), h),
-               "w_down": dense(next(keys), (n, ffn, h), ffn)}
+               "w_down": dense(next(keys), (n, ffn, h), ffn, latent_gain["mlp"] if c.latent else 1.0)}
         if c.activation in ("swiglu", "geglu"):
             out["w_gate"] = dense(next(keys), (n, h, ffn), h)
         return out
@@ -718,7 +825,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         moe["w_down"] = dense(next(keys), (Le, E, ed, h), ed, into_stream)
         if c.activation in ("swiglu", "geglu"):
             moe["w_gate"] = dense(next(keys), (Le, E, h, ed), h)
-        if c.moe_score == "sigmoid":
+        if c.moe_score == "sigmoid" and c.moe_router_bias:
             # the selection bias: a checkpoint's is what balanced its experts'
             # load, a few hundredths of a score
             moe["router_bias"] = (jax.random.normal(
@@ -733,7 +840,8 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         if c.moe_shared_expert_dim > 0:
             sd = c.moe_shared_expert_dim
             moe["shared_up"] = dense(next(keys), (Le, h, sd), h)
-            moe["shared_down"] = dense(next(keys), (Le, sd, h), sd, into_stream)
+            moe["shared_down"] = dense(next(keys), (Le, sd, h), sd,
+                                       latent_gain["mlp"] if c.latent else into_stream)
             if c.activation in ("swiglu", "geglu"):
                 moe["shared_gate"] = dense(next(keys), (Le, h, sd), h)
             if c.moe_shared_gated:
@@ -790,10 +898,10 @@ def param_partition_specs(config: TransformerConfig) -> Dict[str, Any]:
     never sharded. ZeRO later adds the ``data`` axis on free dims
     (runtime/zero/partition.py choose_zero_spec)."""
     c = config
-    if c.hybrid or c.attn_out_gate or c.moe_experts_total:
+    if c.hybrid or c.attn_out_gate or c.moe_experts_total or c.latent:
         raise NotImplementedError(
             "tensor-parallel partition specs for layer_kinds / attn_out_gate / an "
-            "expert share: no sharded form of these has a test yet")
+            "expert share / latent attention: no sharded form of these has a test yet")
     m = MODEL_AXIS
     layers: Dict[str, Any] = {
         "attn_norm": P(None, None),
@@ -1270,9 +1378,78 @@ def _window_bias(c: TransformerConfig, q_glob, k_pos, local_flag):
     return jnp.where(far, jnp.float32(-1e30), jnp.float32(0.0))
 
 
+def _rope_pairs(c: TransformerConfig, x, positions, seq_len=None):
+    """Rotary on the ``qk_rope_dim`` dims of a latent-attention layer: x [t,
+    heads, rot] at ``positions`` [t]. With ``rope_interleave`` dims (2i, 2i + 1)
+    turn together (DeepseekV3's apply_rotary_pos_emb_interleave): they are laid
+    out [evens | odds] first and then rotated in halves, the layout the result
+    keeps: queries and keys get the same, and a dot product does not see it."""
+    if c.rope_interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return _rope(x.transpose(1, 0, 2)[None], positions[None], c, seq_len)[0].transpose(1, 0, 2)
+
+
+def latent_qkv(c: TransformerConfig, lp, a, positions, seq_len=None):
+    """The projections of one latent-attention layer on normed activations
+    ``a`` [t, h] at ``positions`` [t]: (q_nope [t, nh, qk_nope_dim], q_rope
+    [t, nh, qk_rope_dim] rotated, ckv [t, latent_dim]: the normed latent and
+    the rotated key dims every head shares, which is what a cache holds of a
+    token). Shared by the model's forward (expanded form) and the serving
+    steps (absorbed form)."""
+    from deepspeed_tpu.ops.normalization.fused_norm import rms_norm_reference
+
+    t = a.shape[0]
+    nh, rank, dn = c.n_heads, c.kv_lora_rank, c.qk_nope_dim
+    cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], c.norm_eps)
+    q = _proj(c, cq, lp["wq_b"]).reshape(t, nh, c.head_dim)
+    kv = _proj(c, a, lp["wkv_a"])
+    latent = rms_norm_reference(kv[:, :rank], lp["kv_a_norm"], c.norm_eps)
+    q_rope = _rope_pairs(c, q[..., dn:], positions, seq_len)
+    k_rope = _rope_pairs(c, kv[:, None, rank:], positions, seq_len)[:, 0]
+    return q[..., :dn], q_rope, jnp.concatenate([latent, k_rope.astype(latent.dtype)], axis=-1)
+
+
+def latent_up(c: TransformerConfig, lp):
+    """``wkv_b`` split by head: (W_UK [rank, nh, qk_nope_dim], W_UV [rank, nh,
+    v_head_dim]): a head's keys and values of the latent (expanded form), or
+    moved to the query and the output side (absorbed form)."""
+    w = lp["wkv_b"].reshape(c.kv_lora_rank, c.n_heads, c.qk_nope_dim + c.v_head_dim)
+    return w[..., : c.qk_nope_dim], w[..., c.qk_nope_dim:]
+
+
+def _latent_attention_block(c: TransformerConfig, lp, x, positions, segment_ids):
+    """Latent attention in the EXPANDED form (what training and a full
+    forward run): every head's keys and values made of the latent. x: [b, s, h]."""
+    b, s, h = x.shape
+    nh, rank, d, dv = c.n_heads, c.kv_lora_rank, c.head_dim, c.v_head_dim
+    if positions.ndim != 1:
+        raise NotImplementedError("latent attention takes one [s] position vector a batch")
+    w_uk, w_uv = latent_up(c, lp)
+
+    def one(a):
+        q_nope, q_rope, ckv = latent_qkv(c, lp, a, positions, s)
+        k_nope = jnp.einsum("tc,chd->thd", ckv[:, :rank], w_uk)
+        v = jnp.einsum("tc,chd->thd", ckv[:, :rank], w_uv)
+        k_rope = jnp.broadcast_to(ckv[:, None, rank:], (s, nh, c.qk_rope_dim))
+        return (jnp.concatenate([q_nope, q_rope], -1), jnp.concatenate([k_nope, k_rope], -1),
+                # values ride the kernels at the keys' width; the pad is cut below
+                jnp.pad(v, ((0, 0), (0, 0), (0, d - dv))))
+
+    q, k, v = (t.transpose(0, 2, 1, 3) for t in jax.vmap(one)(x))
+    out = attention_op(q, k, v, causal=True, segment_ids=segment_ids, scale=c.attn_scale,
+                       impl=c.attention_impl)
+    out = out[..., :dv].transpose(0, 2, 1, 3).reshape(b, s, nh * dv)
+    return _proj(c, out, lp["wo"]), None
+
+
 def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cache=None,
                      local_flag=None):
     """Self-attention for one layer. x: [b, s, h]."""
+    if c.latent:
+        if kv_cache is not None:
+            raise NotImplementedError(
+                "latent attention has no v1 decode step: serve it through InferenceEngineV2")
+        return _latent_attention_block(c, lp, x, positions, segment_ids)
     b, s, h = x.shape
     nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
     q = _proj(c, x, lp["wq"])

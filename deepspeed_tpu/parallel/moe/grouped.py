@@ -197,18 +197,44 @@ def grouped_matmul(lhs, rhs, group_sizes, tm: int, impl: Optional[str] = None, l
     return _gmm_kernel_vjp(lhs, rhs, group_sizes, jnp.asarray(layer, jnp.int32), tm, impl == "interpret")
 
 
+def kept_groups(config, choose, biased: bool):
+    """The group step of a grouped router (``moe_n_group`` > 1): ``choose`` [t,
+    E] are the scores a token's experts are chosen on, E experts in
+    ``moe_n_group`` groups of consecutive numbers. A group's score is its
+    LARGEST entry where the router has no selection bias (DeepSeek-V2's
+    group_limited_greedy, vLLM's grouped_topk) and the sum of its two largest
+    where it has one (DeepseekV3TopkRouter, on score + bias). Returns [t,
+    moe_n_group] bool: the ``moe_topk_group`` best groups of each token."""
+    t, E = choose.shape
+    G = config.moe_n_group
+    by_group = choose.reshape(t, G, E // G)
+    score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1) if biased else jnp.max(by_group, axis=-1)
+    best = jax.lax.top_k(score, config.moe_topk_group)[1]
+    return jnp.sum(jax.nn.one_hot(best, G, dtype=jnp.int32), axis=1) > 0
+
+
 def route(config, logits, live=None, bias=None):
     """Router as published. logits ``[t, E]`` float32 over every expert of the
     layer, held here or not. ``moe_score`` "softmax": the k most probable,
-    renormalised where the model says so. "sigmoid" (DeepseekV3TopkRouter with
-    one group): scores ``sigmoid(logits)``, the k CHOSEN on ``score + bias``
-    (``bias [E]``: the checkpoint's e_score_correction_bias) and weighted by
-    the score alone, renormalised, times ``moe_routed_scale``. Returns (gate
-    values ``[t, k]`` float32, expert ids ``[t, k]``, aux loss)."""
+    renormalised where the model says so. "sigmoid" (DeepseekV3TopkRouter):
+    scores ``sigmoid(logits)``, the k CHOSEN on ``score + bias`` (``bias
+    [E]``: the checkpoint's e_score_correction_bias; None for a router without
+    one) and weighted by the score alone, renormalised, times
+    ``moe_routed_scale``; with ``moe_n_group`` > 1 the k are taken inside the
+    token's ``moe_topk_group`` best groups (``kept_groups``; an expert outside
+    them is out of the choice whatever its score: -inf where the published
+    code writes 0.0, which differs only if fewer than k kept scores are
+    positive). Returns (gate values ``[t, k]`` float32, expert ids ``[t, k]``,
+    aux loss, the kept groups ``[t, moe_n_group]`` bool: None for a router
+    without groups)."""
     E, k = logits.shape[-1], config.moe_top_k
+    kept = None
     if config.moe_score == "sigmoid":
         probs = jax.nn.sigmoid(logits.astype(jnp.float32))
         choose = probs if bias is None else probs + bias.astype(jnp.float32)
+        if config.moe_n_group > 1:
+            kept = kept_groups(config, choose, bias is not None)
+            choose = jnp.where(jnp.repeat(kept, E // config.moe_n_group, axis=1), choose, -jnp.inf)
         top_e = jax.lax.top_k(choose, k)[1]
         top_p = jnp.take_along_axis(probs, top_e, axis=-1)
         if config.moe_norm_topk_prob:
@@ -225,7 +251,7 @@ def route(config, logits, live=None, bias=None):
     n = jnp.maximum(jnp.sum(w), 1.0)
     me = jnp.sum(probs * w[:, None], axis=0) / n
     ce = jnp.sum(chosen * w[:, None], axis=0) / n
-    return top_p, top_e, jnp.sum(me * ce) * E / k
+    return top_p, top_e, jnp.sum(me * ce) * E / k, kept
 
 
 def experts_grouped(config, lp, tokens, logits, live=None, layer=None
@@ -236,10 +262,11 @@ def experts_grouped(config, lp, tokens, logits, live=None, layer=None
     stacks ``[L, E, ...]`` (see ``grouped_matmul``). ``E`` is the experts HELD
     here; the logits span more under an expert share. Returns (out ``[t, h]``:
     the held experts' part of the sum, aux loss, ``[E]`` int32 rows routed to
-    each held expert)."""
+    each held expert; a grouped router appends one entry: the live tokens
+    whose kept groups include one this share holds an expert of)."""
     t, h = tokens.shape
     E, k = config.n_experts, config.moe_top_k
-    top_p, top_e, aux = route(config, logits, live, lp.get("router_bias"))
+    top_p, top_e, aux, kept = route(config, logits, live, lp.get("router_bias"))
 
     tm = row_tile(t * k, tokens.dtype.itemsize)
     m = -(-t * k // tm) * tm
@@ -268,4 +295,12 @@ def experts_grouped(config, lp, tokens, logits, live=None, layer=None
     y = jnp.where(routed[:, None], y.astype(jnp.float32) * p_sorted[:, None], 0.0)
     back = jnp.argsort(order)[: t * k]  # where each (token, choice) pair went
     out = jnp.sum(y[back].reshape(t, k, h), axis=1)
+    if kept is not None:
+        # the groups this share's experts lie in (one, where a chip holds a group)
+        per = config.router_width // config.moe_n_group
+        first = config.moe_expert_shard * E
+        hit = jnp.any(kept[:, first // per: (first + E - 1) // per + 1], axis=1)
+        if live is not None:
+            hit = hit & live
+        counts = jnp.concatenate([counts, jnp.sum(hit, dtype=jnp.int32)[None]])
     return out.astype(tokens.dtype), aux, counts

@@ -233,6 +233,10 @@ class EngineCore:
             self._inc("moe_hot_expert_rows_total", moe["hot"])
             self._inc("moe_layer_calls_total", moe["calls"])
             self._inc("moe_experts_hit_total", moe.get("hit", 0))
+            # a grouped router: (token, layer call) pairs routed, and those
+            # whose kept groups include one this chip holds
+            self._inc("moe_group_tokens_total", moe.get("group_tokens", 0))
+            self._inc("moe_group_hit_tokens_total", moe.get("group_hit", 0))
         # a model with DeltaNet layers: the rows whose states took the update
         self._inc("gdn_decode_rows_total", held("gdn_decode_rows"))
         # the cache as the step found it, by kind, summed a step; and what a
@@ -241,6 +245,10 @@ class EngineCore:
         self._inc("kv_window_blocks_used_total", held("kv_window_blocks"))
         self._inc("kv_context_tokens_total", held("kv_context_tokens"))
         self._inc("paged_window_live_blocks_total", held("paged_window_live_blocks"))
+        # a latent pool: the decode rows and the blocks one layer walks for them
+        self._inc("latent_decode_rows_total", held("latent_decode_rows"))
+        self._inc("latent_decode_blocks_total", held("latent_decode_blocks"))
+        self._inc("latent_live_blocks_total", held("latent_live_blocks"))
 
     # -- admission accounting --------------------------------------------
     def blocks_needed(self, req: Request, prefill_only: bool = False) -> int:

@@ -156,6 +156,16 @@ class ServingMetrics:
             "kv_window_blocks_used_total": 0,
             "kv_context_tokens_total": 0,
             "paged_window_live_blocks_total": 0,
+            # a latent pool (EngineCore._count_step): decode rows, and the
+            # pool blocks ONE layer's absorbed decode walks for them, and the
+            # blocks the tracked sequences' tables hold, summed a step; a
+            # grouped router: (token, expert-layer call) pairs routed, and
+            # those whose kept groups include one this chip holds
+            "latent_decode_rows_total": 0,
+            "latent_decode_blocks_total": 0,
+            "latent_live_blocks_total": 0,
+            "moe_group_tokens_total": 0,
+            "moe_group_hit_tokens_total": 0,
             # one step in flight (EngineCore._count_step): steps launched
             # before their predecessor was collected, and rows such a step
             # computed for a request that had stopped meanwhile (never

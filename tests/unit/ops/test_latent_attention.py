@@ -1,0 +1,270 @@
+"""The latent pool's three kernels (ops/attention/latent_pallas.py) against their
+dense forms, interpreted on the CPU at a small size, and compiled for a
+described v5e at A.X-K1's widths (64 heads against blocks of [576, 128])."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops.attention import latent_pallas as LP
+
+NH, RANK, ROPE, BS, B, P = 4, 16, 8, 16, 5, 23
+D = RANK + ROPE
+TRASH = P - 1
+
+
+def _pool(rng):
+    return jnp.asarray(rng.normal(size=(P, D, BS)), jnp.float32)
+
+
+def _tables(rng, tokens, width=B):
+    """A table a row over distinct blocks: ``ceil(tokens / BS)`` each."""
+    perm, k = rng.permutation(P - 1), 0
+    out = np.full((len(tokens), width), TRASH, np.int32)
+    for r, n in enumerate(tokens):
+        nb = -(-max(int(n), 0) // BS)
+        out[r, :nb] = perm[k: k + nb]
+        k += nb
+    return out
+
+
+@pytest.mark.parametrize("own", [False, True], ids=["pool_only", "own_vector_beside_the_pool"])
+def test_decode_kernel_equals_the_dense_form(own):
+    """Rows at a block's first key, inside one, on an edge, across several; a
+    padded slot emits 0. With ``own`` the row's new vector rides as a column
+    and the pool is read below the row's position."""
+    rng = np.random.default_rng(0)
+    qpos = np.array([0, 5, 16, 37, -1, 79], np.int32)
+    tables = _tables(rng, np.where(qpos >= 0, qpos + 1, 0))
+    q = jnp.asarray(rng.normal(size=(len(qpos), NH, D)), jnp.float32)
+    extra = limit = None
+    if own:
+        extra = (jnp.asarray(rng.normal(size=(len(qpos), 1, D)), jnp.float32), jnp.asarray(qpos[:, None]))
+        limit = jnp.asarray(np.maximum(qpos, 0))
+    kw = dict(rank=RANK, scale=0.3, extra=extra, pool_limit=limit)
+    args = (q, _pool(rng), jnp.asarray(tables), jnp.asarray(qpos), TRASH)
+    dense = LP.latent_decode(*args, impl="dense", **kw)
+    kernel = LP.latent_decode(*args, impl="kernel", **kw)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense), atol=2e-6)
+    assert not np.asarray(dense[4]).any() and float(jnp.abs(dense[3]).max()) > 0.1
+
+
+def test_decode_keeps_what_a_block_holds_outside_the_context_out_of_the_sum():
+    """A NaN behind a row's last key (what a freed block may hold) reaches
+    neither form's output."""
+    rng = np.random.default_rng(1)
+    qpos = np.array([20], np.int32)
+    tables = _tables(rng, [21])
+    pool = np.array(_pool(rng))
+    pool[tables[0, 1], :, 6:] = np.nan   # positions 22.. of the row's second block
+    q = jnp.asarray(rng.normal(size=(1, NH, D)), jnp.float32)
+    for impl in ("dense", "kernel"):
+        out = LP.latent_decode(q, jnp.asarray(pool), jnp.asarray(tables), jnp.asarray(qpos), TRASH,
+                               rank=RANK, scale=0.3, impl=impl)
+        assert np.isfinite(np.asarray(out)).all(), impl
+
+
+@pytest.mark.parametrize("tile", [8, 16, 32])
+def test_chunk_kernel_equals_the_dense_form(tile):
+    """A chunk that starts inside a block with a padded tail, and a first chunk
+    (nothing below it), at tiles under, at and over the block size."""
+    rng = np.random.default_rng(2)
+    Rc, tq = 2, 64
+    starts, ns = np.array([21, 0]), np.array([50, 64])
+    q_pos = np.full((Rc, tq), -1, np.int32)
+    for r in range(Rc):
+        q_pos[r, : ns[r]] = starts[r] + np.arange(ns[r])
+    tables = _tables(rng, starts + ns, width=B + 3)
+    q = jnp.asarray(rng.normal(size=(Rc, tq, NH, D)), jnp.float32)
+    new = jnp.asarray(rng.normal(size=(Rc, tq, D)), jnp.float32)
+    args = (q, _pool(rng), jnp.asarray(tables), jnp.asarray(q_pos), TRASH, new, jnp.asarray(starts))
+    dense = LP.latent_chunk(*args, rank=RANK, scale=0.3, impl="dense")
+    kernel = LP.latent_chunk(*args, rank=RANK, scale=0.3, impl="kernel", tile=tile)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense), atol=3e-6)
+    assert not np.asarray(dense[0, 50:]).any()   # the padded tail
+
+
+def test_write_kernel_equals_the_scatter():
+    """Decode rows one token a block, two of them in the same block, and a
+    chunk that starts inside a block and whose tokens come from two 128-token
+    tiles of the step's grid; the padding names the trash block and lands
+    nowhere."""
+    rng = np.random.default_rng(3)
+    L, NBp, n = 3, 9, 150
+    pool = jnp.asarray(rng.normal(size=(L, NBp, D, BS)), jnp.float32)
+    blk, row = np.full(n, NBp - 1, np.int32), np.zeros(n, np.int32)
+    blk[:4], row[:4] = [2, 5, 5, 0], [3, 0, 7, 15]
+    pos = 10 + np.arange(40)
+    blk[100:140], row[100:140] = np.asarray([1, 3, 4, 6])[pos // BS], pos % BS
+    new = jnp.asarray(rng.normal(size=(L, n, D)), jnp.float32)
+    visits = LP.write_visits(blk, NBp - 1, 16)
+    assert visits[2][:9].tolist() == [3, 3, 3, 3, 3, 1, 3, 3, 0]   # block 4 from two tiles
+    dense = LP.latent_write(pool, new, jnp.asarray(blk), jnp.asarray(row), impl="dense")
+    kernel = LP.latent_write(pool, new, jnp.asarray(blk), jnp.asarray(row), visits, impl="kernel")
+    np.testing.assert_array_equal(np.asarray(kernel[:, :-1]), np.asarray(dense[:, :-1]))
+    np.testing.assert_array_equal(np.asarray(kernel[:, 7]), np.asarray(pool[:, 7]))  # untouched
+    assert float(jnp.abs(dense - pool)[:, :-1].max()) > 1.0
+    # a step that writes nothing is one program: the trash block onto itself
+    none = LP.write_visits(np.full(n, NBp - 1), NBp - 1, 4)
+    same = LP.latent_write(pool, new, jnp.full(n, NBp - 1), jnp.zeros(n, jnp.int32), none, impl="kernel")
+    np.testing.assert_array_equal(np.asarray(same), np.asarray(pool))
+    with pytest.raises(RuntimeError, match="sized for 2"):
+        LP.write_visits(blk, NBp - 1, 2)
+
+
+@pytest.mark.parametrize("impl", ["decode", "chunk", "write"])
+def test_an_unknown_impl_raises(impl):
+    rng = np.random.default_rng(4)
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="unknown impl"):
+        if impl == "decode":
+            LP.latent_decode(z((1, NH, D)), _pool(rng), z((1, B), jnp.int32), z(1, jnp.int32), TRASH,
+                             rank=RANK, scale=1.0, impl="auto")
+        elif impl == "chunk":
+            LP.latent_chunk(z((1, BS, NH, D)), _pool(rng), z((1, B), jnp.int32), z((1, BS), jnp.int32),
+                            TRASH, z((1, BS, D)), z(1, jnp.int32), rank=RANK, scale=1.0, impl="auto")
+        else:
+            LP.latent_write(z((1, 2, D, BS)), z((1, 4, D)), z(4, jnp.int32), z(4, jnp.int32), impl="auto")
+
+
+# -- compiled for the chip, at A.X-K1's widths (no chip: a described v5e) -----------
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_the_chip(monkeypatch):
+    """The kernels ask ``on_tpu()`` whether to interpret; the compile answers."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(LP, "on_tpu", lambda: True)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("what", ["decode", "chunk_128", "chunk_512", "write"])
+def test_the_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, on_the_chip, what):
+    """Mosaic takes each kernel at 64 heads, blocks of [576, 128] and the
+    cell's pool, reads the pool in place (no pool-sized copy in front of the
+    call) and, for the write, updates it in place."""
+    L, NBp, Dm, bs, rank, nh, Bt, R, Rc = 5, 2712, 576, 128, 512, 64, 112, 32, 2
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    pool = S((L, NBp, Dm, bs))
+    if what == "decode":
+        def f(q, pool, tab, pos, ke):
+            return LP.latent_decode(q, pool.reshape(L * NBp, Dm, bs), tab, pos, NBp - 1, rank=rank,
+                                    scale=0.1, extra=(ke, pos[:, None]), pool_limit=pos, impl="kernel")
+        comp = jax.jit(f).lower(S((R, nh, Dm)), pool, S((R, Bt), i32), S((R,), i32),
+                                S((R, 1, Dm))).compile()
+    elif what.startswith("chunk"):
+        tq = int(what.split("_")[1])
+
+        def f(q, pool, tab, pos, new, start):
+            return LP.latent_chunk(q, pool.reshape(L * NBp, Dm, bs), tab, pos, NBp - 1, new, start,
+                                   rank=rank, scale=0.1, impl="kernel")
+        comp = jax.jit(f).lower(S((Rc, tq, nh, Dm)), pool, S((Rc, Bt), i32), S((Rc, tq), i32),
+                                S((Rc, tq, Dm)), S((Rc,), i32)).compile()
+    else:
+        n, G = R + Rc * 512, 64
+
+        def f(pool, new, blk, row, a, b, c):
+            return LP.latent_write(pool, new, blk, row, (a, b, c), impl="kernel")
+        comp = jax.jit(f, donate_argnums=0).lower(
+            pool, S((L, n, Dm)), S((n,), i32), S((n,), i32), S((G,), i32), S((G,), i32),
+            S((G,), i32)).compile()
+        assert comp.memory_analysis().temp_size_in_bytes < 64 << 20   # no second pool
+    text = comp.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and (f"[{L * NBp}," in ln.split(" copy(")[0]
+                                       or f"[{L},{NBp}," in ln.split(" copy(")[0])]
+
+
+@pytest.mark.parametrize("tq", [0, 128], ids=["decode_only", "with_chunks"])
+def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chip, on_the_chip,
+                                                                         monkeypatch, tq):
+    """The whole served step of ``a.x-k1.serve-doc-long-closed64`` at its
+    sizes: 11.2 GB of weights and the 2 GB pool as arguments, the pool aliased
+    to the output, the three latent kernels and the grouped expert matmul in
+    it, and no copy the size of the pool (``dstpu lint --verify``'s question,
+    asked of the chip's compiler: off the chip the pool is written by XLA's
+    scatter, which transposes it)."""
+    import dataclasses
+    import json
+    import re
+    import sys
+
+    import deepspeed_tpu.accelerator.device as device
+    from deepspeed_tpu.inference.cli import engine_config_from_args, serve_parse_args
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+    from deepspeed_tpu.models import init_params
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    for name, mod in list(sys.modules.items()):   # every "am I on a TPU?" says yes
+        if name.startswith("deepspeed_tpu") and getattr(mod, "on_tpu", None) is not None:
+            monkeypatch.setattr(mod, "on_tpu", lambda: True)
+    assert device.on_tpu()
+    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    hf = json.load(open(os.path.join(here, "benchmarks", "configs", "a.x-k1.json")))
+    cell = json.load(open(os.path.join(
+        here, "benchmarks", "cells", "a.x-k1.serve-doc-long-closed64.json")))["serve_args"]
+    cfg = dataclasses.replace(config_from_hf(hf), dtype="bfloat16")
+    argv = ["--model", "", "--port", "0"]
+    for flag, value in cell.items():
+        argv += [flag, str(value)]
+    rc = engine_config_from_args(serve_parse_args(argv), cfg)
+    rc.kv_cache = dataclasses.replace(rc.kv_cache, prefix_cache=False)
+    shapes = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.key(0))
+    eng = InferenceEngineV2(cfg, jax.tree.map(lambda s: jnp.zeros((), s.dtype), shapes), rc)
+    assert eng._attn_impl == "kernel"
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, Rc = rc.state_manager.max_ragged_sequence_count, eng.scheduler.max_prompt_chunks
+    _, inputs = eng._stage_split(0, [], [])
+    if tq:
+        T_, B = R + Rc * tq, rc.kv_cache.max_blocks_per_seq
+        grid = {"tokens": T_, "positions": T_, "blk": T_, "row": T_, "chk_tables": (Rc, B),
+                "chk_pos": (Rc, tq), "chk_start": Rc, "chk_last": Rc, "chk_uids": Rc}
+        inputs = {**inputs, **{k: np.zeros(v, np.int32) for k, v in grid.items()}}
+        G = R + Rc * (tq // 128 + tq // LP.WRITE_TILE + 3)
+        inputs.update({k: np.zeros(G, np.int32) for k in ("lat_vblk", "lat_vtile", "lat_vflag")})
+    pools = tuple(S(p.shape, p.dtype) for p in eng._pools())
+    comp = eng._build_split_step(tq).lower(
+        jax.tree.map(lambda s: S(s.shape, jnp.bfloat16), shapes),
+        {k: S(np.shape(v), np.asarray(v).dtype) for k, v in inputs.items()},
+        jax.eval_shape(lambda: jax.random.key(0)), S((), jnp.float32), pools).compile()
+    ma, text = comp.memory_analysis(), comp.as_text()
+    assert ma.alias_size_in_bytes >= 1_999_000_000          # the pool, in place
+    assert ma.argument_size_in_bytes < 13_300_000_000 and ma.temp_size_in_bytes < 1_000_000_000
+    want = {"dstpu_mla_decode", "dstpu_mla_write", "dstpu_moe_gmm"} | ({"dstpu_mla_chunk"} if tq else set())
+    assert want <= set(re.findall(r"dstpu_[a-z_]+", text))
+    pool_elems = int(np.prod(pools[0].shape))
+    big = [ln for ln in text.splitlines() if " copy(" in ln and any(
+        int(np.prod([int(x) for x in dims.split(",")])) >= pool_elems // 2
+        for dims in re.findall(r"\[([0-9,]+)\]", ln.split(" copy(")[0])[:1])]
+    assert not big, big[:2]

@@ -251,7 +251,7 @@ def test_router_bias_moves_the_choice_and_not_the_weight():
 
     cfg, _ = _model({**HF, "num_experts": 16, "deployment_share": None})
     logits = jax.random.normal(jax.random.key(3), (32, 16))
-    w0, e0, _ = route(cfg, logits, bias=jnp.zeros(16))
+    w0, e0, *_ = route(cfg, logits, bias=jnp.zeros(16))
     np.testing.assert_allclose(np.asarray(w0.sum(-1)), 2.5, rtol=1e-6)  # renormalised, x 2.5
     scores = np.asarray(jax.nn.sigmoid(logits))
     np.testing.assert_allclose(
@@ -260,7 +260,7 @@ def test_router_bias_moves_the_choice_and_not_the_weight():
     # a bias that lifts expert 5 over everything: chosen by every token, and
     # weighted by its sigmoid alone
     bias = jnp.zeros(16).at[5].set(10.0)
-    w1, e1, _ = route(cfg, logits, bias=bias)
+    w1, e1, *_ = route(cfg, logits, bias=bias)
     assert bool(jnp.all(jnp.any(e1 == 5, axis=-1)))
     assert not bool(jnp.all(jnp.any(e0 == 5, axis=-1)))
     np.testing.assert_allclose(np.asarray(w1.sum(-1)), 2.5, rtol=1e-6)
@@ -289,7 +289,7 @@ def test_config_from_hf_on_the_published_keys():
 
 
 @pytest.mark.parametrize("change,match", [
-    ({"n_group": 2}, "n_group"),
+    ({"n_group": 2, "topk_group": 3}, "n_group"),   # (groups themselves are served: PR 37)
     ({"scoring_func": "softmax"}, "scoring_func"),
     ({"mlp_layer_types": ["sparse"] * 8}, "mlp_layer_types"),
     ({"mlp_layer_types": ["dense", "sparse", "dense"] + ["sparse"] * 5}, "mlp_layer_types"),
