@@ -310,37 +310,45 @@ def _capture_builder(obj, attr: str, store: dict, key: str):
     setattr(obj, attr, build)
 
 
+def _tiny_model_config(model: str = "dense"):
+    """A tiny model of each kind of cache the serving engine keeps."""
+    from deepspeed_tpu.models import get_config
+
+    if model == "gdn":
+        # the second kind of cache: two periods of one Gated DeltaNet layer
+        # and one attention layer, so that the loop over periods is a loop
+        return get_config(
+            "tiny", n_layers=4, dtype="float32", max_seq_len=512,
+            layer_kinds=("gdn", "full") * 2, gdn_key_heads=2, gdn_value_heads=4,
+            gdn_key_dim=16, gdn_value_dim=16)
+    if model == "window":
+        # ... or a window pool: window and global layers in one stack
+        return get_config(
+            "tiny", n_layers=4, dtype="float32", max_seq_len=512,
+            sliding_window=6, attn_layer_pattern=(1, 1, 1, 0))
+    if model == "latent":
+        # ... or a latent pool of one plane, behind a dense lead layer (an
+        # unrolled stack) and grouped experts
+        return get_config(
+            "tiny", n_layers=3, dtype="float32", max_seq_len=512, head_dim_override=24,
+            kv_lora_rank=16, q_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+            rope_interleave=True, n_experts=8, moe_top_k=2, moe_drop_tokens=False,
+            moe_dense_lead=1, moe_score="sigmoid", moe_router_bias=False, moe_n_group=4,
+            moe_topk_group=2)
+    return get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
+
+
 def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
                     kv_extra: Optional[dict] = None, model: str = "dense"):
     import jax
 
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
     from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
-    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models import init_params
 
-    cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
-    if model == "gdn":
-        # the second kind of cache: two periods of one Gated DeltaNet layer
-        # and one attention layer, so that the loop over periods is a loop
-        cfg = get_config(
-            "tiny", n_layers=4, dtype="float32", max_seq_len=512,
-            layer_kinds=("gdn", "full") * 2, gdn_key_heads=2, gdn_value_heads=4,
-            gdn_key_dim=16, gdn_value_dim=16)
-    if model == "window":
-        # ... or a window pool: window and global layers in one stack
-        cfg = get_config(
-            "tiny", n_layers=4, dtype="float32", max_seq_len=512,
-            sliding_window=6, attn_layer_pattern=(1, 1, 1, 0))
+    cfg = _tiny_model_config(model)
     if model == "latent":
-        # ... or a latent pool of one plane, behind a dense lead layer (an
-        # unrolled stack) and grouped experts; it has no fused round
-        decode_steps = 1
-        cfg = get_config(
-            "tiny", n_layers=3, dtype="float32", max_seq_len=512, head_dim_override=24,
-            kv_lora_rank=16, q_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
-            rope_interleave=True, n_experts=8, moe_top_k=2, moe_drop_tokens=False,
-            moe_dense_lead=1, moe_score="sigmoid", moe_router_bias=False, moe_n_group=4,
-            moe_topk_group=2)
+        decode_steps = 1  # a latent pool has no fused round
     params = init_params(cfg, jax.random.key(0))
     kv = {"block_size": 4, "num_blocks": 128, "max_blocks_per_seq": 32,
           "kv_cache_dtype": kv_dtype}
@@ -348,6 +356,9 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
     rc = RaggedInferenceEngineConfig.from_dict({
         "dtype": "float32",
         "decode_steps": decode_steps,
+        # two chunk rows a step at most, so the split step has a one-row shape
+        # and a two-row shape beside its decode-only one
+        "prompt_chunk": 128, "max_prompt_chunks": 2,
         "kv_cache": kv,
         "state_manager": {"max_tracked_sequences": 16,
                           "max_ragged_batch_size": 256,
@@ -358,12 +369,14 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
 
 def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     """The v2 serving programs of a tiny engine with a ``kv_dtype`` pool:
-    (engine, {name: (jitted, args)}). The split step (at a chunk bucket) and
-    the fused decode round are captured from two same-shape ``generate()``
-    passes (pass 1 traces, pass 2 must hit the caches); the split step's
-    decode-only shape and the verify step are lowered directly with the
-    inputs of an empty step (lowering reads shapes only, so passing the live
-    pools is safe). Every program takes ``(params, inputs, rng, temperature,
+    (engine, {name: (jitted, args)}). The split step (two chunk rows: the
+    passes' two prompts go in one step) and the fused decode round are
+    captured from two same-shape ``generate()`` passes (pass 1 traces, pass 2
+    must hit the caches); the split step's decode-only shape and the verify
+    step are lowered directly with the inputs of an empty step, its one-row
+    shape with those of a step that holds one prompt (lowering reads shapes
+    only, so passing the live pools is safe). Every program takes
+    ``(params, inputs, rng, temperature,
     pools)`` and donates ``pools`` whole: int8 adds the scale planes as two
     more leaves of it, ``model="gdn"`` (a model with Gated DeltaNet layers)
     the recurrent-state and conv pools, ``model="window"`` (window and global
@@ -395,8 +408,16 @@ def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     # a served request runs at decode_steps 1): the generate() passes above
     # decode in fused rounds, so it is staged here, with no row (the class's
     # builder: the instance's is shadowed by the capture above)
-    (_, tq), inputs = eng._stage_split(0, [], [])
-    programs["decode_only_step"] = staged(type(eng)._build_split_step(eng, tq), inputs)
+    (_, shape), inputs = eng._stage_split(0, [], [])
+    programs["decode_only_step"] = staged(type(eng)._build_split_step(eng, shape), inputs)
+    # ... and of a batch with ONE chunk row where the scheduler could have cut
+    # two: the grid follows the batch, so this is a program of its own
+    eng.scheduler.submit(0, np.arange(1, 13, dtype=np.int32))
+    batch = eng.scheduler.next_batch()
+    (_, shape), inputs = eng._stage_split(batch.total_tokens, [], [
+        (batch.uids[0], batch.tokens[0], batch.start_positions[0], batch.is_prompt_chunk[0])])
+    eng.scheduler.finish(0)
+    programs["one_row_step"] = staged(type(eng)._build_split_step(eng, shape), inputs)
     # speculative verify step (serving/spec): the K+1-token draft-and-verify
     # program declares the pools donated — without aliasing, every spec
     # round would copy the whole paged pool, erasing the subsystem's win.
@@ -415,7 +436,8 @@ def _engine_v2_pass(kv_dtype: str, model: str = "dense") -> List[CheckResult]:
     tag = "".join(f"[{t}]" for t in (kv_dtype, model) if t not in ("bf16", "dense"))
     results: List[CheckResult] = []
     eng, programs = _engine_v2_programs(kv_dtype, model)
-    for key in ("split_step", "decode_only_step", "multistep_decode", "verify_step"):
+    for key in ("split_step", "decode_only_step", "one_row_step", "multistep_decode",
+                "verify_step"):
         if key == "verify_step" and eng._beside:
             continue  # refused at build: a rejected draft would need the second cache rolled back
         if key in ("verify_step", "multistep_decode") and eng._latent:
@@ -1079,8 +1101,8 @@ def verify_elastic() -> List[CheckResult]:
     results: List[CheckResult] = []
 
     # -- warm spare: serving-shaped traffic after warm_trace is compile-free
-    # tables of 64 blocks: a prompt can pass 128 tokens, so the spare's
-    # baseline holds all three split shapes (decode-only, 128, prompt_chunk)
+    # the spare's baseline holds every split shape: decode-only, and one
+    # chunk row and two of them at its one bucket (prompt_chunk = 128)
     pool = WarmSparePool(
         factory=lambda: _tiny_v2_engine(
             decode_steps=2, kv_extra={"max_blocks_per_seq": 64})[1],
@@ -1140,10 +1162,10 @@ def verify_elastic() -> List[CheckResult]:
         results.append(CheckResult(label, "recompile", False, str(e)))
     sched.finish(uid)
 
-    # the warmed split program itself must be single-trace per bucket
-    for (kind, tq), fn in eng._programs.items():
+    # the warmed split program itself must be single-trace per shape
+    for (kind, shape), fn in eng._programs.items():
         if kind == "split":
-            results.append(check_recompile(f"elastic.split_step[tq={tq}]", fn))
+            results.append(check_recompile(f"elastic.split_step[rows, tq={shape}]", fn))
     return results
 
 

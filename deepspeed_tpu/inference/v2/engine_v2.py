@@ -53,7 +53,7 @@ from deepspeed_tpu.utils.timer import device_synchronize
 # (``time.monotonic``), under a name of its own so that a test can drive it
 _now = time.monotonic
 
-# step program of each cache key's kind: ("split", tq) | ("round", n) | ("verify", k)
+# step program of each cache key's kind: ("split", (rc, tq)) | ("round", n) | ("verify", k)
 _BUILDERS = {
     "split": "_build_split_step",
     "round": "_build_multistep_decode",
@@ -1072,17 +1072,21 @@ class InferenceEngineV2:
 
     def warm_split_shapes(self, uid: int = (1 << 30) + 7, while_running=None) -> None:
         """Build every shape of the split step before a request is admitted,
-        whatever clients send first: a short throwaway prompt traces the 128
-        bucket, its first decode step the decode-only shape (``tq == 0``),
-        and the longest prompt one chunk can hold the ``prompt_chunk``
-        bucket. The serving entry points call this on every engine they
-        build (``inference/cli.py``), so the first prompt with a short tail
-        compiles nothing in the middle of its TTFT. ``while_running(uid)``
-        runs once, while the first sequence is running with a token fed
-        back (warm_trace's rounds). The throwaway sequences are finished
-        and scrubbed from the prefix trie afterwards, and sampling keys are
-        content-addressed — warming never perturbs later streams. Call
-        AFTER the final ``set_sampling`` (it invalidates the programs)."""
+        whatever clients send first. A shape is (chunk rows, bucket): for
+        each count of rows the scheduler can cut, 1 .. ``max_prompt_chunks``,
+        that many throwaway prompts submitted TOGETHER make one step of as
+        many chunk rows, in each bucket that count has (_chunk_bucket): short
+        ones trace the 128 bucket, and with the longest prompt one chunk can
+        hold as the first of them the ``prompt_chunk`` bucket; the first short
+        prompt's first decode step traces the decode-only shape (no chunk
+        row). The serving entry points call this on every engine they build
+        (``inference/cli.py``), so no prompt, alone or beside another,
+        compiles anything in the middle of its TTFT. ``while_running(uid)``
+        runs once, while the first sequence is running with a token fed back
+        (warm_trace's rounds). The throwaway sequences are finished and
+        scrubbed from the prefix trie afterwards, and sampling keys are
+        content-addressed — warming never perturbs later streams. Call AFTER
+        the final ``set_sampling`` (it invalidates the programs)."""
         sched = self.scheduler
         kv = self.config.kv_cache
         vocab = int(getattr(self._mc, "vocab_size", 0) or 2)
@@ -1095,44 +1099,56 @@ class InferenceEngineV2:
         # it lands in the prompt_chunk bucket
         longest = min(pc, int(self.config.state_manager.max_context) - 1,
                       min(kv.max_blocks_per_seq, kv.num_blocks) * kv.block_size)
-        lens = [8, longest] if longest > 128 else [8]
+        # a chunk row is a tracked sequence of its own
+        most_rows = min(sched.max_prompt_chunks,
+                        int(self.config.state_manager.max_tracked_sequences))
 
-        def next_token(wuid, length):
-            for _ in range(8 + length // max(1, pc)):
-                out = self.step_tokens()
-                if wuid in out:
-                    return out[wuid]
+        def first_tokens(wuids, length):
+            got = {}
+            for _ in range(8 + length // max(1, pc) + len(wuids)):
+                got.update(self.step_tokens())
+                if all(w in got for w in wuids):
+                    return got
             raise RuntimeError(
                 f"warm_split_shapes: a prompt of {length} tokens never produced a token")
 
         try:
-            for i, length in enumerate(lens):
-                wuid = uid + i
-                # no shared first token: a prefix hit would shorten the chunk
-                toks = ((np.arange(length, dtype=np.int32) + i) % max(1, vocab - 1)) + 1
-                sched.submit(wuid, toks)
-                try:
-                    sched.feedback(wuid, next_token(wuid, length))
-                    if i == 0:
-                        # the short prompt's first decode step has no chunk beside it
-                        sched.feedback(wuid, next_token(wuid, length))
-                        if while_running is not None:
-                            while_running(wuid)
-                finally:
-                    sched.finish(wuid)
+            for rows in range(1, most_rows + 1):
+                # a prompt length for each bucket this count of rows has
+                lens = {self._chunk_bucket(rows, n): n for n in (longest, 8)}
+                for length in sorted(lens.values()):
+                    wuids = [uid + k for k in range(rows)]
+                    try:
+                        for k, wuid in enumerate(wuids):
+                            # the bucket is the longest row's; no shared first
+                            # token: a prefix hit would shorten a chunk
+                            toks = np.arange(length if k == 0 else 8, dtype=np.int32) + k
+                            sched.submit(wuid, toks % max(1, vocab - 1) + 1)
+                        got = first_tokens(wuids, length)
+                        if rows == 1 and length == 8:
+                            # the short prompt's first decode step has no chunk beside it
+                            sched.feedback(uid, got[uid])
+                            sched.feedback(uid, first_tokens([uid], 1)[uid])
+                            if while_running is not None:
+                                while_running(uid)
+                    finally:
+                        for wuid in wuids:
+                            sched.finish(wuid)
+                        if cache is not None:
+                            # warm prefixes must never serve a hit, the next
+                            # round's prompts neither
+                            cache.clear()
         finally:
             if cache is not None:
-                try:
-                    cache.clear()  # warm prefixes must never serve a hit
-                finally:
-                    cache.spill_fn = spill
+                cache.spill_fn = spill
 
     def warm_trace(self, decode_steps: int = 1, spec_k: int = 0,
                    uid: int = (1 << 30) + 7) -> Dict[str, int]:
         """Pre-trace every step program the serving loop will drive, so a
         warm-spare engine admits requests with ZERO admission-time
-        compiles: the split-phase step at its three shapes (decode-only,
-        128 and ``prompt_chunk``: warm_split_shapes), the fused decode round
+        compiles: the split-phase step at every shape (decode-only, one
+        chunk row in the 128 bucket, and each count of chunk rows in the
+        ``prompt_chunk`` bucket: warm_split_shapes), the fused decode round
         at ``decode_steps``, the speculative verify step at ``spec_k``, and
         the fixed-window chunked re-import scatter (preemption resume /
         host-tier readmit). Returns the post-warm ``trace_signature`` (the
@@ -1417,18 +1433,31 @@ class InferenceEngineV2:
                        paged_window_live_blocks=calls * int(walks.sum()))
         return out
 
+    def _chunk_bucket(self, rows: int, longest: int) -> int:
+        """The slots a chunk row has on the grid of a step with ``rows`` chunk
+        rows, the longest of ``longest`` tokens. Two buckets keep a short
+        prompt or a short tail off the full ``prompt_chunk`` pad without a
+        program per ragged length: 128 for ONE row of at most 128 tokens,
+        ``prompt_chunk`` for everything else. Rows that arrive together share
+        the one bucket: a shape is a program, traced and lowered before a
+        request is admitted at ~1.5 s of set-up each on the serving host
+        (PERF.md, PR 38), and two short chunks in one step are rare."""
+        pc = self.scheduler.prompt_chunk
+        return min(128, pc) if rows == 1 and longest <= 128 else pc
+
     def _count_chunk(self, chk_rows, tq: int):
         """What one layer's chunk attention had to read against what the
         dense form walks, as ``StepStats``' chunk_live_blocks and
         chunk_table_slots: a chunk row (uid, tokens, start, chunked) holds
         ``ceil(start / bs)`` pool blocks below it and ``ceil(n / bs)`` key
-        blocks of its own; the grid's ``Rc`` rows have ``B`` table slots and
-        ``tq / bs`` chunk blocks each. Zeros for a step with no chunk."""
+        blocks of its own; the grid's rows, one a chunk row of the batch, have
+        ``B`` table slots and ``tq / bs`` chunk blocks each. Zeros for a step
+        with no chunk."""
         kv = self.config.kv_cache
         bs = kv.block_size
         live = sum(-(-start // bs) + -(-len(toks) // bs) for _, toks, start, _ in chk_rows)
-        slots = self.scheduler.max_prompt_chunks * (kv.max_blocks_per_seq + -(-tq // bs))
-        return {"chunk_live_blocks": live, "chunk_table_slots": slots if tq else 0}
+        slots = len(chk_rows) * (kv.max_blocks_per_seq + -(-tq // bs))
+        return {"chunk_live_blocks": live, "chunk_table_slots": slots}
 
     def _side_buffers(self, *token_dims):
         """What a step's layer loop carries, by name: ``k`` / ``v``, a zeroed
@@ -1977,29 +2006,31 @@ class InferenceEngineV2:
         carry = dict(carry, k=jax.lax.dynamic_update_index_in_dim(carry["k"], ckv, li, 0))
         return x, self._record_moe(carry, li, moe)
 
-    def _build_split_step(self, tq: int):
+    def _build_split_step(self, shape):
         """ONE compiled step over the split-phase batch: R decode slots +
-        Rc prompt chunks of tq tokens (the static-shape SplitFuse). blk/row/
-        positions come pre-staged from the host (_stage_split) —
-        data-dependent anyway. Three shapes of the one program, by what the
-        batch holds: ``tq`` 128 or ``prompt_chunk`` (the chunk-length
-        buckets), and ``tq == 0`` for a batch with no chunk row: the grid
-        is the R decode slots, the program takes no ``chk_*`` input and
-        runs no chunk attention (the fused round's and the verify step's
+        Rc prompt chunks of tq tokens (the static-shape SplitFuse), ``shape``
+        = (Rc, tq). blk/row/positions come pre-staged from the host
+        (_stage_split) — data-dependent anyway. The shapes of the one
+        program are what the batch holds: ``Rc`` the chunk rows the
+        scheduler cut (1 .. ``max_prompt_chunks``: a row nobody cut is not
+        on the grid), ``tq`` 128 or ``prompt_chunk`` (the chunk-length
+        buckets: _chunk_bucket), and (0, 0) for a batch with no chunk row:
+        the grid is the R decode slots, the program takes no ``chk_*`` input
+        and runs no chunk attention (the fused round's and the verify step's
         grids are R rows wide already). Outputs: (decode logits [R, vocab],
         chunk logits [Rc, vocab], decode tokens [R], chunk tokens [Rc],
-        ``last_tokens`` [R + Rc]); the chunk pair is None at ``tq == 0``,
-        where no row reads it.
+        ``last_tokens`` [R + max_prompt_chunks]); the chunk pair is None at
+        ``tq == 0``, where no row reads it.
 
         One step in flight: every shape takes the PREVIOUS split step's
-        ``last_tokens`` (its sampled tokens by output slot: decode slots,
-        then chunk rows, zeros where it had none) and ``tok_src`` [R]: the
-        slot a decode row's token comes from, or -1 for the host's
-        ``tokens[i]``. So a row whose token is still on the device is
-        launched without it, and the program returns its own
-        ``last_tokens`` for the next."""
+        ``last_tokens`` (its sampled tokens by output slot, the same length
+        whatever shape that step had: decode slots, then chunk rows, zeros
+        where it had none) and ``tok_src`` [R]: the slot a decode row's
+        token comes from, or -1 for the host's ``tokens[i]``. So a row whose
+        token is still on the device is launched without it, and the program
+        returns its own ``last_tokens`` for the next."""
         R = self.config.state_manager.max_ragged_sequence_count
-        Rc = self.scheduler.max_prompt_chunks
+        Rc, tq = shape
 
         def step(params, inputs, rng, temperature, pools):
             tokens, positions = inputs["tokens"], inputs["positions"]
@@ -2054,9 +2085,12 @@ class InferenceEngineV2:
                 chk_at = jnp.clip(inputs["chk_last"], 0, tokens.shape[0] - 1)
                 logits_chk, toks_chk = self._sample_rows(
                     params, x, chk_at, rng, temperature, inputs["chk_uids"], positions[chk_at])
+            # by output slot, the same length in every shape: the rows this
+            # shape has, zeros behind them
             last_tokens = jnp.concatenate([
                 toks_dec.astype(jnp.int32),
-                jnp.zeros(Rc, jnp.int32) if toks_chk is None else toks_chk.astype(jnp.int32)])
+                *([toks_chk.astype(jnp.int32)] if tq else []),
+                jnp.zeros(self.scheduler.max_prompt_chunks - Rc, jnp.int32)])
             return ((logits_dec, logits_chk, toks_dec, toks_chk, last_tokens), pools,
                     self._moe_rows(side))
 
@@ -2323,32 +2357,28 @@ class InferenceEngineV2:
 
     # -- the step protocol: stage -> launch -> collect ----------------------
     def _stage_split(self, total_tokens, dec_rows, chk_rows):
-        """The scheduler's batch onto the fixed [R decode slots | Rc chunks x
-        tq] grid: ``dec_rows`` (uid, tokens, start, src: the slot of the
-        previous split step's ``last_tokens`` its token is in, -1 for a
-        token the host has), ``chk_rows`` (uid, tokens, start, chunked).
-        Returns the split step's cache key and its inputs by name: the nine a
-        decode slot needs and, for a batch that holds a chunk, the five
-        ``chk_*``."""
+        """The scheduler's batch onto the [R decode slots | Rc chunks x tq]
+        grid, ``Rc`` the chunk rows it holds: ``dec_rows`` (uid, tokens,
+        start, src: the slot of the previous split step's ``last_tokens`` its
+        token is in, -1 for a token the host has), ``chk_rows`` (uid, tokens,
+        start, chunked). Returns the split step's cache key, ("split", (Rc,
+        tq)), and its inputs by name: the nine a decode slot needs and, for a
+        batch that holds a chunk, the five ``chk_*``."""
         kv = self.config.kv_cache
         R = self.config.state_manager.max_ragged_sequence_count
-        Rc = self.scheduler.max_prompt_chunks
+        Rc = len(chk_rows)
         B = kv.max_blocks_per_seq
         bs = kv.block_size
         trash = kv.num_blocks
-        if len(dec_rows) > R or len(chk_rows) > Rc:
+        if len(dec_rows) > R or Rc > self.scheduler.max_prompt_chunks:
             raise RuntimeError(
                 f"split-phase batch overflow: {len(dec_rows)} decode rows "
-                f"(cap {R}), {len(chk_rows)} prompt chunks (cap {Rc})"
+                f"(cap {R}), {Rc} prompt chunks (cap {self.scheduler.max_prompt_chunks})"
             )
-        # chunk-length buckets: two shapes keep short prompts off the full
-        # prompt_chunk pad without a compile per ragged length; a batch
-        # with no chunk takes the grid of its decode slots alone
-        tq = 0
-        if chk_rows:
-            max_chunk = max(len(t) for _, t, _, _ in chk_rows)
-            tq = min(128 if max_chunk <= 128 else self.scheduler.prompt_chunk,
-                     self.scheduler.prompt_chunk)
+        # the grid has a row a chunk the scheduler cut, as long as the bucket
+        # of the longest; a batch with no chunk takes the grid of its decode
+        # slots alone
+        tq = self._chunk_bucket(Rc, max(len(t) for _, t, _, _ in chk_rows)) if Rc else 0
         T_ = R + Rc * tq
 
         tokens = np.zeros(T_, np.int32)
@@ -2361,7 +2391,7 @@ class InferenceEngineV2:
         tok_src = np.full(R, -1, np.int32)
         chk_tables = np.full((Rc, B), trash, np.int32)
         chk_pos = np.full((Rc, tq), -1, np.int32)
-        chk_start = np.zeros(Rc, np.int32)  # 0 = inactive (empty pool window)
+        chk_start = np.zeros(Rc, np.int32)
         chk_last = np.zeros(Rc, np.int32)
         chk_uids = np.zeros(Rc, np.int32)
         # a second kind of cache: each row's slot; padding points at the spare
@@ -2446,8 +2476,8 @@ class InferenceEngineV2:
             from deepspeed_tpu.ops.attention.latent_pallas import WRITE_TILE, write_visits
 
             inputs["lat_vblk"], inputs["lat_vtile"], inputs["lat_vflag"] = write_visits(
-                blk, trash, R + Rc * (tq // bs + tq // WRITE_TILE + 3) if tq else R)
-        return ("split", tq), inputs
+                blk, trash, R + Rc * (tq // bs + tq // WRITE_TILE + 3))
+        return ("split", (Rc, tq)), inputs
 
     def _stage_rows(self, uids, width: int):
         """What the fused round and the verify step share: one row a running
@@ -2830,7 +2860,8 @@ class InferenceEngineV2:
 
             # a step that launched nothing (no batch) has nothing to wait on
             return ([] if last is None else [last]), finish, {
-                "rows": len(rows), "tokens": self.last_step.scheduled_tokens}, rows
+                "rows": len(rows), "tokens": self.last_step.scheduled_tokens,
+                "grid_slots": self.last_step.grid_slots}, rows
 
         return self._dispatch(dispatch)
 

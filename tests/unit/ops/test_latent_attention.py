@@ -203,9 +203,10 @@ def test_the_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, on_the_
                                        or f"[{L},{NBp}," in ln.split(" copy(")[0])]
 
 
-@pytest.mark.parametrize("tq", [0, 128], ids=["decode_only", "with_chunks"])
+@pytest.mark.parametrize("Rc,tq", [(0, 0), (1, 128), (2, 128)],
+                         ids=["decode_only", "one_chunk_row", "two_chunk_rows"])
 def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chip, on_the_chip,
-                                                                         monkeypatch, tq):
+                                                                         monkeypatch, Rc, tq):
     """The whole served step of ``a.x-k1.serve-doc-long-closed64`` at its
     sizes: 11.2 GB of weights and the 2 GB pool as arguments, the pool aliased
     to the output, the three latent kernels and the grouped expert matmul in
@@ -244,7 +245,7 @@ def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chi
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    R, Rc = rc.state_manager.max_ragged_sequence_count, eng.scheduler.max_prompt_chunks
+    R = rc.state_manager.max_ragged_sequence_count
     _, inputs = eng._stage_split(0, [], [])
     if tq:
         T_, B = R + Rc * tq, rc.kv_cache.max_blocks_per_seq
@@ -254,7 +255,7 @@ def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chi
         G = R + Rc * (tq // 128 + tq // LP.WRITE_TILE + 3)
         inputs.update({k: np.zeros(G, np.int32) for k in ("lat_vblk", "lat_vtile", "lat_vflag")})
     pools = tuple(S(p.shape, p.dtype) for p in eng._pools())
-    comp = eng._build_split_step(tq).lower(
+    comp = eng._build_split_step((Rc, tq)).lower(
         jax.tree.map(lambda s: S(s.shape, jnp.bfloat16), shapes),
         {k: S(np.shape(v), np.asarray(v).dtype) for k, v in inputs.items()},
         jax.eval_shape(lambda: jax.random.key(0)), S((), jnp.float32), pools).compile()

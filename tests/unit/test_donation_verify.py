@@ -16,14 +16,16 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.analysis import verify as dv
 
-PROGRAMS = ["split_step", "decode_only_step", "multistep_decode", "verify_step"]
+PROGRAMS = ["split_step", "decode_only_step", "one_row_step", "multistep_decode", "verify_step"]
 
 
 @functools.lru_cache(maxsize=None)
 def _programs(kind):
     """(engine, {name: (jitted, args)}) after two same-shape generate()
-    passes: pass 1 traces, pass 2 must hit the caches. ``decode_only_step``
-    is the split step's shape for a batch with no chunk row."""
+    passes: pass 1 traces, pass 2 must hit the caches. ``split_step`` holds
+    the passes' two prompts as two chunk rows; ``decode_only_step`` is the
+    split step's shape for a batch with no chunk row, ``one_row_step`` for a
+    batch with one."""
     if kind in ("gdn", "window"):
         return dv._engine_v2_programs("bf16", model=kind)
     return dv._engine_v2_programs(kind)
@@ -68,7 +70,7 @@ def test_serving_programs_copy_no_pool(program, kv_dtype):
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "gdn", "window"])
-@pytest.mark.parametrize("program", ["split_step", "decode_only_step"])
+@pytest.mark.parametrize("program", ["split_step", "decode_only_step", "one_row_step"])
 def test_split_step_takes_the_step_before_its_tokens_and_copies_no_pool(program, kv_dtype):
     """One step in flight: every shape of the split step takes the previous
     one's sampled tokens (``last_tokens``, on the device) and each decode

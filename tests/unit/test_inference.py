@@ -66,25 +66,61 @@ def _geometry(name):
     return cfg, params, prompts, n_new, [_greedy_reference(cfg, params, p, n_new) for p in prompts]
 
 
-def _as_chunk_shape(engine, inputs, tq):
-    """The inputs of a decode-only split step as the split step took them
-    before it had that shape: the same decode rows on the grid of
-    ``R + Rc x tq`` slots, every chunk row empty."""
+def _with_empty_chunk_rows(engine, inputs, tq, rows=1):
+    """A split step's inputs on a grid of ``rows`` more chunk rows of ``tq``
+    slots, each EMPTY (what the split step padded to before its grid followed
+    the batch): a decode-only step's inputs as a chunk shape takes them, or a
+    one-row step's as the two-row shape does."""
     kv = engine.config.kv_cache
-    Rc = engine.scheduler.max_prompt_chunks
+    B, trash = kv.max_blocks_per_seq, kv.num_blocks
+    spare = engine._state_slots - 1
 
-    def grid(a, fill):
-        return np.concatenate([a, np.full(Rc * tq, fill, np.int32)])
+    def more(name, shape, fill):
+        pad = np.full(shape, fill, np.int32)
+        return np.concatenate([inputs[name], pad]) if name in inputs else pad
 
-    return {
+    out = {
         **inputs,
-        "tokens": grid(inputs["tokens"], 0), "positions": grid(inputs["positions"], 0),
-        "blk": grid(inputs["blk"], kv.num_blocks), "row": grid(inputs["row"], 0),
-        "chk_tables": np.full((Rc, kv.max_blocks_per_seq), kv.num_blocks, np.int32),
-        "chk_pos": np.full((Rc, tq), -1, np.int32),
-        "chk_start": np.zeros(Rc, np.int32), "chk_last": np.zeros(Rc, np.int32),
-        "chk_uids": np.zeros(Rc, np.int32),
+        "tokens": more("tokens", rows * tq, 0), "positions": more("positions", rows * tq, 0),
+        "blk": more("blk", rows * tq, trash), "row": more("row", rows * tq, 0),
+        "chk_tables": more("chk_tables", (rows, B), trash),
+        "chk_pos": more("chk_pos", (rows, tq), -1),
+        "chk_start": more("chk_start", rows, 0), "chk_last": more("chk_last", rows, 0),
+        "chk_uids": more("chk_uids", rows, 0),
     }
+    if engine._beside:  # a second kind of cache: padding points at the spare slot
+        out["chk_slots"] = more("chk_slots", rows, spare)
+    if "wblk" in inputs:
+        out["wblk"] = more("wblk", rows * tq, spare * engine._win_blocks)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _family(name):
+    """(cfg, params) of a tiny model of each family the split step serves:
+    ``_geometry``'s three, ``gdn`` (DeltaNet layers + their state pools),
+    ``window`` (window rings beside the block pool), ``latent`` (a pool of one
+    plane behind a dense lead layer and grouped experts)."""
+    from deepspeed_tpu.analysis.verify import _tiny_model_config
+
+    if name in ("mha", "gqa_4_2", "experts"):
+        cfg = dataclasses.replace(_geometry(name)[0], max_seq_len=512)
+    else:
+        cfg = _tiny_model_config(name)  # ``dstpu lint --verify``'s model of that kind
+    return cfg, init_params(cfg, jax.random.key(0))
+
+
+def _two_row_engine(cfg, params, chunk=160):
+    """An engine whose scheduler may cut TWO prompt chunks a step, of up to
+    ``chunk`` tokens: R = 4 decode rows, tables of 32 blocks of 16."""
+    return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig.from_dict({
+        "dtype": "float32", "prompt_chunk": chunk, "max_prompt_chunks": 2,
+        "kv_cache": {"block_size": 16, "num_blocks": 96, "max_blocks_per_seq": 32},
+        "state_manager": {"max_ragged_batch_size": 2 * chunk + 4,
+                          "max_ragged_sequence_count": 4, "max_context": 512}}))
+
+
+_POOLS = ("_k_cache", "_v_cache", "_gdn_state", "_gdn_conv", "_wk_cache", "_wv_cache")
 
 
 class TestInferenceV1:
@@ -347,7 +383,7 @@ class TestInferenceV2:
         tq = min(128, engine.scheduler.prompt_chunk)
         for out, ref in zip(engine.generate(prompts, max_new_tokens=n_new), refs):
             np.testing.assert_array_equal(out, ref)
-        assert sorted(engine._programs) == [("split", 0), ("split", tq)]
+        assert sorted(engine._programs) == [("split", (0, 0)), ("split", (1, tq))]
 
         # the prompts again, up to a batch of three decode rows and no chunk
         sched = engine.scheduler
@@ -363,7 +399,7 @@ class TestInferenceV2:
         key, inputs = engine._stage_split(
             batch.total_tokens,
             list(zip(batch.uids, batch.tokens, batch.start_positions, batch.token_src)), [])
-        assert key == ("split", 0) and set(batch.token_src) == {-1}
+        assert key == ("split", (0, 0)) and set(batch.token_src) == {-1}
         assert sorted(inputs) == ["blk", "dec_pos", "dec_tables", "dec_uids", "last_tokens",
                                   "positions", "row", "tok_src", "tokens"]
         assert {len(inputs[k]) for k in ("tokens", "positions", "blk", "row", "dec_pos")} == {4}
@@ -371,7 +407,7 @@ class TestInferenceV2:
         # the same K/V at them: run one after the other on the same pools
         logits0, no_logits, toks0, no_toks, last0 = engine._launch(key, inputs)
         logits1, _, toks1, _, last1 = engine._launch(
-            ("split", tq), _as_chunk_shape(engine, inputs, tq))
+            ("split", (1, tq)), _with_empty_chunk_rows(engine, inputs, tq))
         assert no_logits is None and no_toks is None
         # the tokens by output slot, for the next step to read on the device:
         # decode slots, then chunk rows (zeros in the shape that has none)
@@ -384,6 +420,79 @@ class TestInferenceV2:
             np.asarray(logits0)[live], np.asarray(logits1)[live], atol=2e-5, rtol=2e-5)
         for i, uid in enumerate(batch.uids):
             assert int(toks0[i]) == refs[uid][len(prompts[uid]) + 1]
+
+    @pytest.mark.parametrize("tail", [40, 140])
+    @pytest.mark.parametrize("family", ["mha", "gqa_4_2", "experts", "gdn", "window", "latent"])
+    def test_one_row_shape_equals_the_two_row_shape(self, family, tail):
+        """A batch with ONE chunk row runs the split step on a grid of one
+        chunk row, though the scheduler could have cut two. Its tokens and
+        logits are those of the two-row shape fed the same rows beside an
+        EMPTY second row (what such a step ran before), in both buckets (a
+        chunk of 40 tokens: 128 slots, a bucket the engine gives one row alone
+        and the builder any count; of 140: ``prompt_chunk`` = 160) and for
+        every kind of cache: the chunk is a prompt's SECOND, over 160 tokens
+        of pool, state or ring, beside a decode row."""
+        cfg, params = _family(family)
+        engine = _two_row_engine(cfg, params)
+        sched, R = engine.scheduler, 4
+        tq = 128 if tail <= 128 else sched.prompt_chunk
+        sched.submit(0, np.arange(1, 21, dtype=np.int32))
+        sched.feedback(0, engine.step_tokens()[0])
+        sched.submit(1, (np.arange(160 + tail, dtype=np.int32) * 7) % (cfg.vocab_size - 1) + 1)
+        sched.feedback(0, engine.step_tokens()[0])  # the prompt's first 160 beside the decode row
+        batch = sched.next_batch()
+        assert batch.is_decode == [True, False] and len(batch.tokens[1]) == tail
+        dec, chk = (
+            [(u, t, s, x)] for u, t, s, x in zip(
+                batch.uids, batch.tokens, batch.start_positions,
+                [batch.token_src[0], batch.is_prompt_chunk[1]]))
+        key, inputs = engine._stage_split(batch.total_tokens, dec, chk)
+        assert key == ("split", (1, tq)) and chk[0][2] == 160
+        assert engine.last_step.grid_slots == R + tq == len(inputs["tokens"])
+        # both programs start from the same pools: a DeltaNet layer's state
+        # is read AND written by the step
+        before = {n: jnp.copy(getattr(engine, n)) for n in _POOLS
+                  if getattr(engine, n, None) is not None}
+        logits1, chk_logits1, toks1, chk_toks1, last1 = engine._launch(key, inputs)
+        for n, pool in before.items():
+            setattr(engine, n, pool)
+        logits2, chk_logits2, toks2, chk_toks2, last2 = engine._launch(
+            ("split", (2, tq)), _with_empty_chunk_rows(engine, inputs, tq))
+        assert chk_logits1.shape[0] == 1 and chk_logits2.shape[0] == 2
+        np.testing.assert_allclose(
+            np.asarray(logits1)[0], np.asarray(logits2)[0], atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(chk_logits1)[0], np.asarray(chk_logits2)[0], atol=2e-5, rtol=2e-5)
+        assert int(toks1[0]) == int(toks2[0]) and int(chk_toks1[0]) == int(chk_toks2[0])
+        # the tokens by output slot keep their length whatever the shape: R
+        # decode slots, the chunk row at R, zeros where the shape has no row
+        assert last1.shape == last2.shape == (R + 2,)
+        np.testing.assert_array_equal(np.asarray(last1)[: R + 1], np.asarray(last2)[: R + 1])
+        assert int(last1[R]) == int(chk_toks1[0]) and int(last1[R + 1]) == 0
+
+    @pytest.mark.parametrize("rows", [0, 1, 2])
+    def test_grid_follows_the_chunk_rows(self, tiny_model, rows):
+        """``StepStats.grid_slots`` is R + rc x tq for the ``rc`` chunk rows
+        the scheduler cut, and ``chunk_table_slots`` what a dense walk of
+        those rows covers: rc x (32 table slots + 160 / 16 chunk blocks), by
+        hand for no row, one of two and both, beside one decode row."""
+        cfg, params = tiny_model
+        engine = _two_row_engine(cfg, params)
+        sched = engine.scheduler
+        sched.submit(0, np.arange(1, 21, dtype=np.int32))
+        sched.feedback(0, engine.step_tokens()[0])
+        lengths = [140, 30][:rows]
+        for uid, n in enumerate(lengths, start=1):
+            sched.submit(uid, np.arange(n, dtype=np.int32) + 10 * uid)
+        assert 0 in engine.step_tokens()
+        step, tq = engine.last_step, 160 if rows else 0
+        assert ("split", (rows, tq)) in engine._programs
+        assert (step.grid_slots, step.scheduled_tokens, step.prefill_tokens) == (
+            4 + rows * tq, 1 + sum(lengths), sum(lengths))
+        # 140 tokens are 9 key blocks of 16 and 30 are 2, nothing below either
+        assert step.chunk_live_blocks == [0, 9, 11][rows]
+        assert step.chunk_table_slots == rows * (32 + 10)
+        assert (step.paged_live_blocks, step.paged_table_slots) == (2, 4 * 32)
 
     @pytest.mark.parametrize("entry", ["step_tokens", "step_tokens_experts", "decode_round",
                                        "spec_round"])

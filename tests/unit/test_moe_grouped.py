@@ -189,8 +189,9 @@ def test_the_tiling_rule_and_the_rows_it_covers():
 
 def test_serving_counters_equal_a_count_by_hand():
     """One prompt of 13 tokens through an engine with chunks of 8 and a grid
-    of 4 decode slots + 2 x 8 chunk slots (the 4 decode slots alone for the
-    step with no chunk): the step programs return what every layer routed,
+    of 4 decode slots + 8 chunk slots (ONE chunk row of the two the scheduler
+    may cut; the 4 decode slots alone for the step with no chunk): the step
+    programs return what every layer routed,
     padding excluded, and the core folds it into the counters."""
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
     from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
@@ -212,9 +213,9 @@ def test_serving_counters_equal_a_count_by_hand():
     for real in (8, 5, 1):                       # chunk, chunk, one decode row
         toks = eng.step_tokens()
         core._count_step()
-        # a step with a chunk is the whole grid, the decode-only step its 4 slots
+        # a step with a chunk has the chunk's row, the decode-only step its 4 slots
         assert eng.last_step.scheduled_tokens == real
-        assert eng.last_step.grid_slots == (4 + 2 * 8 if real > 1 else 4)
+        assert eng.last_step.grid_slots == (4 + 8 if real > 1 else 4)
         assert eng.last_step.moe["routed"] == real * k * L and eng.last_step.moe["calls"] == L
         # the fullest expert holds at least the mean and at most every token
         assert real * k * L / 8 <= eng.last_step.moe["hot"] <= real * L

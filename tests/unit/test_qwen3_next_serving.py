@@ -107,8 +107,10 @@ def test_engine_equals_the_reference_on_logits_float32(gdn_impl):
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in PROMPT_LENS]
     with jax.default_matmul_precision("highest"):
         served = _serve_logits(eng, prompts, n_new=5)
-        # all three shapes of the split step served it
-        assert set(eng._programs) == {("split", 0), ("split", 128), ("split", 160)}
+        # every shape of the split step served it: no chunk row, one in
+        # either bucket, two (which share the one bucket)
+        assert set(eng._programs) == {
+            ("split", shape) for shape in [(0, 0), (1, 128), (1, 160), (2, 160)]}
         for uid, p in enumerate(prompts):
             want = _reference_logits(params, HF, p, served[uid])
             np.testing.assert_allclose(served[uid], want, atol=5e-5, rtol=0)

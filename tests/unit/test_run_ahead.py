@@ -159,7 +159,7 @@ def test_served_tokens_equal_the_synchronous_loop(toy):
     assert not driver.core.has_work() and not driver.core.step_in_flight and _all_free(engine)
     assert driver._idle.is_set()
     # one program a key, whatever ``last_tokens`` was (zeros, then an output)
-    assert sorted(engine._programs) == [("split", 0), ("split", CHUNK)]
+    assert sorted(engine._programs) == [("split", (0, 0)), ("split", (1, CHUNK)), ("split", (2, CHUNK))]
     assert all(fn._cache_size() == 1 for fn in engine._programs.values())
 
 

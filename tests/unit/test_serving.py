@@ -451,12 +451,15 @@ class TestServingRealEngine:
             assert all(0 <= t < cfg.vocab_size for t in r.generated)
         assert engine.state_manager.free_blocks == 64
 
-    @pytest.mark.parametrize("arrivals", ["alone", "overlapping"])
+    @pytest.mark.parametrize("arrivals", ["alone", "overlapping", "together"])
     def test_serve_entry_point_builds_every_split_shape_first(self, arrivals):
-        """The stack as ``dstpu serve`` builds it has all three shapes of the
-        split step (decode-only, the 128 bucket, ``prompt_chunk``) before the
-        first request is admitted: prompts whose chunks and tails land in
-        every bucket, one at a time or all at once, trace nothing."""
+        """The stack as ``dstpu serve`` builds it has every shape of the
+        split step (decode-only; one chunk row in the 128 bucket and in
+        ``prompt_chunk``; two chunk rows, which share the one bucket) before
+        the first request is admitted: prompts whose chunks and tails land in
+        every bucket, one at a time, all at once, or two SUBMITTED TOGETHER
+        before the loop's first step (so that one step carries two chunk rows,
+        short ones and long ones), trace nothing."""
         import jax
 
         from deepspeed_tpu.inference.cli import build_serving_stack, serve_parse_args
@@ -473,13 +476,23 @@ class TestServingRealEngine:
         engine = driver.engine
         pc = engine.scheduler.prompt_chunk
         baseline = engine.trace_signature()
-        assert sorted(baseline) == sorted(f"split[{tq}]" for tq in (0, 128, pc)) and pc > 128
+        shapes = [(0, 0), (1, 128), (1, pc), (2, pc)]
+        assert sorted(baseline) == sorted(f"split[{shape}]" for shape in shapes) and pc > 128
         assert all(n == 1 for n in baseline.values())
         rng = np.random.default_rng(0)
         lengths = [1, 128, 129, pc, pc + 100, 2 * pc + 300]
+        launched, launch = set(), engine._launch
+        engine._launch = lambda key, inputs: (launched.add(key), launch(key, inputs))[1]
+        reqs = []
+        if arrivals == "together":
+            # the oldest and the shortest share the first step, the two
+            # that are left the second
+            lengths = [10, 20, pc, 200]
+            reqs = [driver.submit(
+                rng.integers(1, cfg.vocab_size, size=n, dtype=np.int32),
+                params=SamplingParams(max_new_tokens=3, ignore_eos=True)) for n in lengths]
         with driver:
-            reqs = []
-            for n in lengths:
+            for n in lengths[len(reqs):]:
                 reqs.append(driver.submit(
                     rng.integers(1, cfg.vocab_size, size=n, dtype=np.int32),
                     params=SamplingParams(max_new_tokens=3, ignore_eos=True)))
@@ -489,6 +502,9 @@ class TestServingRealEngine:
                 assert r.wait(300), f"a prompt of {n} tokens did not finish"
                 assert r.state == RequestState.FINISHED and len(r.generated) == 3
         assert_no_new_traces(engine, baseline, label=f"served {arrivals}")
+        assert launched <= {("split", shape) for shape in shapes}
+        if arrivals == "together":
+            assert ("split", (2, pc)) in launched
 
     def test_a_step_that_lands_no_token_is_waited_for_and_is_progress(self, tiny_model,
                                                                       monkeypatch):

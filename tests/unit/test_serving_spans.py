@@ -122,6 +122,12 @@ class TestServingSpans:
         # (it was launched ahead) and ends inside its own wait.
         steps = sorted((sp for sp in ring if sp.name in DEVICE_SPANS), key=lambda s: s.t0)
         assert len(dispatches) == 3
+        # ... and a dispatch says the grid its step was sized to: R + rc x tq
+        # for the rc chunk rows of the batch in the bucket tq
+        assert [sp.args for sp in sorted(dispatches, key=lambda s: s.t0)] == [
+            {"rows": 1, "tokens": 200, "grid_slots": 4 + 512},
+            {"rows": 2, "tokens": 21, "grid_slots": 4 + 128},
+            {"rows": 2, "tokens": 2, "grid_slots": 4}]
         assert [sp.name for sp in steps] == ["step.chunk", "step.chunk", "step.decode"]
         assert [sp.args for sp in steps] == [
             {"rows": 1, "tokens": 200, "ahead": False},
