@@ -61,6 +61,10 @@ _BUILDERS = {
     "verify": "_build_verify_step",
 }
 
+# a layer's attention projections, which an unrolled stack never slices out of
+# their stack (_static_layer)
+_READ_IN_PLACE = ("wq", "wk", "wv", "wo")
+
 
 def serving_benchmark(eng, n_seq=32, max_new=64, repeats=2, prompt_min=64,
                       prompt_max=512, seed=0):
@@ -1689,7 +1693,15 @@ class InferenceEngineV2:
         depth (a stack of a single period is that body alone). A layer's
         parameters are what every layer has at ``li`` and its kind's stack
         at the layer's ordinal among its kind (``T.kind_ordinals``); the
-        body knows its kind from the keys it is handed. ``carry`` holds the
+        body knows its kind from the keys it is handed. Two sets of keys
+        STAY IN THEIR STACK, because a slice in front of a product is a copy a
+        step to XLA on the TPU: an expert model's per-expert weights in every
+        shape of the loop (the grouped kernel indexes its layer's blocks),
+        and, in the unrolled shape, the attention projections ``wq`` / ``wk``
+        / ``wv`` / ``wo`` of the common stack and of a kind's own alike
+        (``_static_layer``: ``Stacked``, read in place by ``stack_dot``). The
+        two looped shapes index their stacks with a traced layer, which
+        ``stack_matmul`` does not take. ``carry`` holds the
         step's side buffers, an expert model's routed rows and a DeltaNet
         model's state pools, and never a K/V pool: those are invariants of
         every loop."""
@@ -1745,20 +1757,34 @@ class InferenceEngineV2:
         # at its index in them
         common = {k: v for k, v in sliced.items() if not isinstance(v, dict)}
         for li, w in enumerate(windows):
-            lp = {**jax.tree.map(lambda a: a[li], common), **whole}
+            lp = {**self._static_layer(common, li), **whole}
             for name, ki in T.layer_stacks(c, li):
                 own = dict(params["layers"][name])
                 # (_mlp_tail knows an expert layer's index in the stacks from li)
                 lp.update({k: own.pop(k) for k in whole_keys if name == "sparse" and k in own})
-                if name in ("full", "window"):
-                    # a kind's projections stay in their stack as well: a static
-                    # index is a slice, which XLA copies out of the stack (and
-                    # transposes again) every step; stack_dot reads it in place
-                    lp.update({k: Stacked(own.pop(k), ki) for k in ("wq", "wk", "wv", "wo")
-                               if isinstance(own.get(k), jax.Array)})
-                lp.update(jax.tree.map(lambda a: a[ki], own))
+                lp.update(self._static_layer(own, ki))
             x, carry = layer_fn(lp, x, li, carry, window=w)
         return x, carry
+
+    def _static_layer(self, stacks, i):
+        """Layer ``i`` (static) of a dict of parameters stacked ``[layers,
+        ...]``, for an unrolled stack. The attention projections ``wq`` /
+        ``wk`` / ``wv`` / ``wo`` are NOT sliced: they go on as ``Stacked(stack,
+        i)`` and ``stack_dot`` multiplies with them in place. What decides is
+        what the code can see: a plain array (a quantized leaf widens a layer
+        at a time, from its slice), more than one layer in the stack (``a[0]``
+        of a stack of one is a bitcast), one device (``_tp_row_matmul`` and
+        GSPMD take arrays), and a layer whose products are ``stack_dot``'s
+        (``_layer_qkv`` / ``_layer_tail`` / ``T.kind_qkv``; ``_latent_layer``
+        multiplies its ``wo`` itself)."""
+        in_place = self._mesh is None and not self._latent
+
+        def take(k, a):
+            if in_place and k in _READ_IN_PLACE and isinstance(a, jax.Array) and a.shape[0] > 1:
+                return Stacked(a, i)
+            return jax.tree.map(lambda b: b[i], a)
+
+        return {k: take(k, a) for k, a in stacks.items()}
 
     def _layer_qkv(self, lp, x, positions, live, window=None):
         """Shared per-layer prologue for the serving step bodies: pre-norm →
@@ -1777,7 +1803,7 @@ class InferenceEngineV2:
         if c.attn_by_kind:
             # the layer's own KV heads, value width, rotary base, value scale
             return (a,) + T.kind_qkv(c, lp, a[0], positions, "window" if window else "full", live)
-        q, k, v = a[0] @ lp["wq"], a[0] @ lp["wk"], a[0] @ lp["wv"]
+        q, k, v = (stack_dot(a[0], lp[n]) for n in ("wq", "wk", "wv"))
         if c.attn_qkv_bias:
             q, k, v = q + lp["wq_b"], k + lp["wk_b"], v + lp["wv_b"]
         if T.qk_norm_full(c):

@@ -136,6 +136,100 @@ def test_engine_equals_the_reference_through_the_interpreted_kernels():
     assert _gap(params, hf, prompts, got, toks) < 5 * TOL  # longer sums
 
 
+@pytest.mark.parametrize("lens,n_new", [((5,), 30), ((5, 70, 100, 33), 12)],
+                         ids=["decode_only_steps", "chunk_steps_beside_decode_rows"])
+def test_projections_read_in_place_give_the_sliced_forms_logits(monkeypatch, lens, n_new):
+    """The four attention projections reach ``stack_dot`` unsliced
+    (``_static_layer``); with ``stack_matmul``'s kernel interpreted every
+    served logit is what ``x @ stack[index]`` gives (the CPU's default): a
+    short prompt and then steps of decode rows alone, and prompts of several
+    chunks whose steps carry decode rows beside them."""
+    from deepspeed_tpu.ops import stack_matmul as SM
+
+    cfg, params = _model()
+    prompts = _prompts(lens)
+    sliced, toks = _serve_logits(_engine(cfg, params), prompts, n_new)
+    calls, kernel = [], SM.stack_matmul
+
+    def interpreted(x, stack, index, impl=None):
+        calls.append((stack.shape, index))
+        return kernel(x, stack, index, impl="interpret")
+
+    monkeypatch.setattr(SM, "stack_matmul", interpreted)
+    in_place, toks2 = _serve_logits(_engine(cfg, params), prompts, n_new)
+    assert toks2 == toks
+    for u in sliced:
+        np.testing.assert_allclose(in_place[u], sliced[u], atol=TOL, rtol=0)
+    # every layer's wq, wk, wv and wo, in every program the run compiled
+    assert calls and len(calls) % (4 * cfg.n_layers) == 0
+    assert {i for _, i in calls} == set(range(cfg.n_layers))
+
+
+def _handed_to_the_layers(eng):
+    """What ``_drive_layers`` hands each layer: {layer: {key: type name}}."""
+    seen = {}
+
+    def spy(lp, x, li, carry, window=None):
+        seen[li] = {k: type(v).__name__ for k, v in lp.items()}
+        return x, carry
+
+    eng._drive_layers(spy, eng.params, jnp.zeros((1, 1, eng._mc.hidden_size)), {})
+    return seen
+
+
+@pytest.mark.parametrize("case", ["one_device", "a_mesh", "a_stack_of_one", "a_quantized_leaf",
+                                  "a_dense_alternating_stack"])
+def test_which_projections_stay_in_their_stack(monkeypatch, case):
+    """One rule for the common stack and a kind's own (``_static_layer``): a
+    plain array of more than one layer on one device goes on as ``Stacked``,
+    and nothing else does. A mesh cannot reach the unrolled loop through a
+    build today (a window pool and an expert model are both refused at
+    ``tp_size`` > 1, as are quantized weights: the last case holds the dense
+    alternating stack to that), so the guards are driven here."""
+    from deepspeed_tpu.inference.quantization.quantize import QuantizedWeight, quantize_inference_params
+    from deepspeed_tpu.ops.stack_matmul import Stacked
+
+    cfg, params = _model()
+    eng = _engine(cfg, params)
+    four = ("wq", "wk", "wv", "wo")
+    if case == "one_device":
+        seen = _handed_to_the_layers(eng)
+        assert sorted(seen) == list(range(cfg.n_layers))
+        for li, types in seen.items():
+            stacked = {k for k, t in types.items() if t == "Stacked"}
+            assert stacked == set(four), (li, types)
+        assert "w_up" in seen[0] and "router" in seen[1]     # the lead layer's MLP, an expert layer's block
+    elif case == "a_mesh":
+        monkeypatch.setattr(eng, "_mesh", object())
+        assert not any(t == "Stacked" for types in _handed_to_the_layers(eng).values() for t in types.values())
+    elif case == "a_stack_of_one":
+        one = {k: params["layers"][k][:1] for k in four}
+        lp = eng._static_layer(one, 0)
+        assert all(isinstance(lp[k], jax.Array) and lp[k].shape == one[k].shape[1:] for k in four)
+        np.testing.assert_array_equal(lp["wq"], params["layers"]["wq"][0])
+        many = eng._static_layer({k: params["layers"][k][:2] for k in four}, 1)
+        assert all(isinstance(many[k], Stacked) and many[k].index == 1 for k in four)
+    elif case == "a_dense_alternating_stack":
+        # gemma-2's shape: no experts, no lead layer, window and global layers in turn
+        from deepspeed_tpu.models import get_config
+
+        dense = get_config("tiny", n_layers=4, dtype="float32", max_seq_len=512,
+                           sliding_window=16, attn_layer_pattern=(1, 0, 1, 0))
+        weights = T.init_params(dense, jax.random.key(0))
+        seen = _handed_to_the_layers(_engine(dense, weights))
+        assert sorted(seen) == [0, 1, 2, 3]
+        assert all({k for k, t in types.items() if t == "Stacked"} == set(four) for types in seen.values())
+        with pytest.raises(NotImplementedError, match="window layers keep their K/V"):
+            _engine(dense, weights, tp_size=2)
+    else:
+        q = quantize_inference_params({k: params["layers"][k] for k in four}, bits=8, group_size=32)
+        assert all(isinstance(q[k], QuantizedWeight) for k in four)
+        lp = eng._static_layer(q, 3)
+        assert all(isinstance(lp[k], QuantizedWeight) for k in four)
+        np.testing.assert_array_equal(
+            T._dequant_tree(lp, jnp.float32)["wq"], T._dequant_tree(q, jnp.float32)["wq"][3])
+
+
 def test_fused_rounds_and_generate_go_through_both_pools():
     """``generate()`` with ``decode_steps`` 4: fused rounds read the pools as
     the round found them and write their tokens' ring blocks after it."""
