@@ -1809,6 +1809,7 @@ class InferenceEngineV2:
         if T.qk_norm_full(c):
             q = T.qk_norm_apply(c, q, lp["q_norm"], head_axis=-1)
             k = T.qk_norm_apply(c, k, lp["k_norm"], head_axis=-1)
+        q, k = T.as_written((q, k))
         q = q.reshape(t, nh, d)
         k = k.reshape(t, nkv, d)
         v = v.reshape(t, nkv, d)

@@ -1417,6 +1417,18 @@ def _proj(c: TransformerConfig, x, w):
     return qmatmul(x, w.stack[w.index] if isinstance(w, Stacked) else w, c.matmul_precision)
 
 
+def as_written(y):
+    """A product's output (or a tuple of them) that is about to be split by
+    heads, pinned as the ``[rows, n]`` matrix the product wrote. Unpinned, the
+    TPU's compiler carries the heads-major layout back through the product,
+    batches it over heads and writes the layer's weight out of its looped stack
+    transposed, every layer of every step; pinned, it reads the stack in place
+    as every other projection's product does. An identity, differentiable. Held
+    by the ``qwen3_*`` and ``a_x_k1_*`` cases of tests/unit/ops/
+    test_latent_attention.py::test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy."""
+    return jax.lax.optimization_barrier(y)
+
+
 def qk_norm_apply(c: TransformerConfig, x, w, head_axis: int, b=None):
     """THE q/k-norm application, shared by the training/decode attention
     block and both v2 paged layer bodies. x: [..., d] with a head axis at
@@ -1487,7 +1499,7 @@ def latent_qkv(c: TransformerConfig, lp, a, positions, seq_len=None):
     t = a.shape[0]
     nh, rank, dn = c.n_heads, c.kv_lora_rank, c.qk_nope_dim
     cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], c.norm_eps)
-    q = _proj(c, cq, lp["wq_b"]).reshape(t, nh, c.head_dim)
+    q = as_written(_proj(c, cq, lp["wq_b"])).reshape(t, nh, c.head_dim)
     kv = _proj(c, a, lp["wkv_a"])
     latent = rms_norm_reference(kv[:, :rank], lp["kv_a_norm"], c.norm_eps)
     q_rope = _rope_pairs(c, q[..., dn:], positions, seq_len)
@@ -1752,7 +1764,7 @@ def gdn_project(c: TransformerConfig, lp, a):
     from deepspeed_tpu.ops.linear_attention import gdn_gates
 
     nv = c.gdn_value_heads
-    z = _proj(c, a, lp["gdn_z"])
+    z = as_written(_proj(c, a, lp["gdn_z"]))
     ba = a @ lp["gdn_ba"]
     g, beta = gdn_gates(ba[..., :nv], ba[..., nv:], lp["gdn_a_log"], lp["gdn_dt_bias"])
     return _proj(c, a, lp["gdn_qkv"]), z.reshape(z.shape[:-1] + (nv, c.gdn_value_dim)), g, beta

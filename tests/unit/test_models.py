@@ -235,6 +235,52 @@ def test_remat_policy_knob():
         remat_policy("bogus")
 
 
+@pytest.mark.parametrize("through", ["itself", "gdn_project", "latent_qkv"])
+def test_as_written_is_an_identity_and_has_the_identitys_gradient(monkeypatch, through):
+    """``as_written`` pins a layout for the TPU's compiler and changes no value:
+    under ``jit`` it hands back its argument bit for bit, and the gradient of
+    the two model functions that call it (a training forward's too) is the one
+    they have with the identity in its place."""
+    from deepspeed_tpu.models import transformer as T
+
+    f32 = jnp.float32
+    if through == "itself":
+        y = (jax.random.normal(jax.random.key(0), (4, 64), jnp.bfloat16), jax.random.normal(jax.random.key(1), (4, 32)))
+        for got, want in zip(jax.jit(T.as_written)(y), y):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got.astype(f32)), np.asarray(want.astype(f32)))
+        return
+    if through == "gdn_project":
+        from tests.unit.test_qwen3_next_serving import _model
+
+        cfg, params = _model()
+        names, stack = ("gdn_z", "gdn_ba", "gdn_a_log", "gdn_dt_bias", "gdn_qkv"), params["layers"]["gdn"]
+        a = jax.random.normal(jax.random.key(2), (2, 5, cfg.hidden_size), f32)
+
+        def outputs(lp, a):
+            return T.gdn_project(cfg, lp, a)
+    else:
+        from tests.unit.test_axk1_serving import _model
+
+        cfg, params = _model()
+        names, stack = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm"), params["layers"]
+        a = jax.random.normal(jax.random.key(2), (5, cfg.hidden_size), f32)
+
+        def outputs(lp, a):
+            return T.latent_qkv(cfg, lp, a, jnp.arange(5))
+
+    def loss(lp, a):   # every output, each element weighted differently
+        return sum(jnp.sum(jnp.sin(o.astype(f32)) * jnp.cos(jnp.arange(o.size, dtype=f32)).reshape(o.shape))
+                   for o in outputs(lp, a))
+
+    lp = {k: stack[k][0] for k in names}
+    pinned = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, a)
+    monkeypatch.setattr(T, "as_written", lambda y: y)
+    plain = jax.jit(jax.grad(loss, argnums=(0, 1)))(lp, a)
+    assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(plain))
+    jax.tree.map(lambda g, w: np.testing.assert_array_equal(np.asarray(g), np.asarray(w)), pinned, plain)
+
+
 class TestResidualMoE:
     """Residual-MoE (reference moe/layer.py:29,47 use_residual) + qwen2-moe
     shared expert + TP↔EP mappings (reference moe/mappings.py)."""
