@@ -7,7 +7,7 @@ full width and depth of the ``bench-767m`` preset with seeded random weights:
   kernels  every Pallas entry point a TPU default selects, compiled by Mosaic
            at the preset's head geometry and checked against its jnp reference
   train    ``deepspeed_tpu.initialize`` -> ``engine.train_batch`` (ZeRO-3, bf16,
-           AdamW, batch 6 x seq 2048, the bench.py configuration) for a few
+           AdamW, batch 6 x seq 2048, int8 forward projections) for a few
            steps on a repeated batch: losses finite and falling
   serve    ``serve_parse_args`` -> ``build_serving_stack`` -> ``driver.start``
            -> ``start_server``; POST /generate bodies of mixed length, one of
@@ -564,8 +564,8 @@ def phase_kernels(size):
 def train_config(size):
     from deepspeed_tpu.models.transformer import get_config
 
-    # bench.py's flagship: bf16, remat_policy=flash (in the preset), int8
-    # forward projections
+    # ``bench-767m`` as it is trained: bf16, remat_policy=flash (in the
+    # preset), int8 forward projections
     return get_config(size, dtype="bfloat16", matmul_precision="int8")
 
 

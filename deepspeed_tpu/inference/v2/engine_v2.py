@@ -66,37 +66,6 @@ _BUILDERS = {
 _READ_IN_PLACE = ("wq", "wk", "wv", "wo")
 
 
-def serving_benchmark(eng, n_seq=32, max_new=64, repeats=2, prompt_min=64,
-                      prompt_max=512, seed=0):
-    """The canonical serving-throughput workload (FastGen-analogue: n_seq
-    concurrent sequences, mixed prompt lengths, max_new generated tokens).
-    ONE definition shared by bench.py's serving bench and the autotuner's
-    serving experiments so their numbers stay comparable. Returns best
-    generated tok/s over ``repeats`` measured passes (first pass warms every
-    compiled program)."""
-    import time as _time
-
-    rng = np.random.default_rng(seed)
-    vocab = eng._mc.vocab_size
-
-    def batch():
-        return [
-            rng.integers(0, vocab, size=(int(l),)).astype(np.int32)
-            for l in rng.integers(prompt_min, prompt_max, size=n_seq)
-        ]
-
-    eng.generate(batch(), max_new_tokens=max_new)  # warm
-    best = 0.0
-    for _ in range(repeats):
-        prompts = batch()
-        t0 = _time.perf_counter()
-        outs = eng.generate(prompts, max_new_tokens=max_new)
-        dt = _time.perf_counter() - t0
-        gen = sum(len(o) - len(p) for o, p in zip(outs, prompts))
-        best = max(best, gen / dt)
-    return best
-
-
 def _entry_array(entry, want_tokens: bool):
     """(device array, row index or None) of one step-result entry: the
     in-program token array instead of logits when ``want_tokens`` and

@@ -613,8 +613,8 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         vocab_size=32000, hidden_size=2048, n_layers=16, n_heads=16, n_kv_heads=8,
         max_seq_len=4096, ffn_hidden_size=5632,
     ),
-    # the round-3 bench flagship: best measured MFU shape on one v5e chip
-    # (55.4% — PERF.md width sweep); d=128 heads, 3:1 GQA, 3x ffn
+    # what chip_smoke.py trains and serves at start-up: a width one v5e holds
+    # under ZeRO-3; d=128 heads, 3:1 GQA, 3x ffn
     "bench-767m": dict(
         vocab_size=32000, hidden_size=2304, n_layers=10, n_heads=18,
         n_kv_heads=6, ffn_hidden_size=6912, max_seq_len=2048,
@@ -2312,5 +2312,8 @@ def flops_per_token(config: TransformerConfig, seq_len: Optional[int] = None) ->
         + c.n_heads * c.head_dim * c.hidden_size  # out proj
         + c.hidden_size * c.ffn_dim * (3 if c.activation in ("swiglu", "geglu") else 2)
     ) * c.n_layers + c.vocab_size * c.hidden_size
-    attn = 2 * c.n_layers * s * c.hidden_size
+    # causal attention by the heads' width (n_heads * head_dim, which is not
+    # hidden_size where the head size is decoupled, as in Qwen3-0.6B): the
+    # count of benchmarks/harness/flops.py, held to it by tests/unit/test_models.py
+    attn = 2 * c.n_layers * s * c.n_heads * c.head_dim
     return 6.0 * (n_dense + attn / 2)

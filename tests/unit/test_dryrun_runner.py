@@ -1,6 +1,6 @@
 """Leg-isolation runner for the multichip dryrun gate.
 
-Round-4 lesson (MULTICHIP_r04 rc=134): one process running every jit-heavy
+Round-4 lesson (an abort, rc=134): one process running every jit-heavy
 leg with unbounded thread pools starves XLA's 40s collective-rendezvous
 timer under host load. The orchestrator in ``__graft_entry__`` must
 (a) cap per-leg thread pools, (b) isolate each leg in a subprocess, and

@@ -221,6 +221,23 @@ class TestUtilities:
 
         assert flops_per_token(cfg, 128) > 6 * n * 0.5
 
+    @pytest.mark.parametrize("name, pinned", [
+        ("qwen3-0.6b", 4.985192448e9), ("qwen3-1.7b", 11.731992576e9)])
+    def test_flops_per_token_is_the_benchmarks_count(self, name, pinned):
+        # the two train configurations at their published sizes and the cells'
+        # 4,096 tokens: the values benchmarks/tests/test_harness.py pins, so
+        # that the autotuner's mfu_pct and the ledger's are one number. Qwen3-0.6B
+        # is the case that tells: 16 heads of 128 against a hidden size of 1,024
+        from benchmarks.harness import flops
+        from benchmarks.harness.common import Catalog
+        from deepspeed_tpu.models import flops_per_token
+        from deepspeed_tpu.models.hf import config_from_hf
+
+        hf = Catalog().config(name)
+        ours = flops_per_token(config_from_hf(hf), 4096)
+        assert ours == pytest.approx(pinned, rel=1e-12)
+        assert ours == flops.train_flops_per_token(hf, 4096)
+
 
 def test_remat_policy_knob():
     """remat_policy is config-selectable (VERDICT perf item); bad names fail fast."""

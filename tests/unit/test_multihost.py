@@ -363,6 +363,13 @@ class TestInProcessContract:
             assert ep.address == ("198.51.100.7", ep.bind_address[1])
             assert agent._bootstrap_meta()["kv_endpoint"][0] == "198.51.100.7"
             _wait_joined(router, "adv0")
+            # the router counts a replica connected from the moment it
+            # attaches the events channel, one META ack BEFORE the agent's own
+            # connect() returns; nothing else in this test takes time, so
+            # without this wait the shutdown below can land inside that dial,
+            # which then finds the listener gone and raises out of run()
+            _wait_for(lambda: agent._rpc_thread is not None,
+                      msg="the agent's own connect() to return")
             health = router.health()
             assert (health["control_plane"]["remote_replicas"]["adv0"]
                     ["kv_endpoint"][0]) == "198.51.100.7"

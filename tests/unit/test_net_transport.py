@@ -274,8 +274,12 @@ class TestEndpoint:
             assert stats["leaked_credits"] == 0
             assert stats["max_inflight_windows"] == 2  # double-buffered
             deadline = time.monotonic() + 5
-            while ep.staged_count() and time.monotonic() < deadline:
-                time.sleep(0.005)  # DONE releases the stage asynchronously
+            # DONE releases the stage asynchronously, and the sender counts
+            # the transfer served only after its last chunk has left: the
+            # importer can be through both before that thread runs again
+            while ((ep.staged_count() or not ep.stats()["served"])
+                   and time.monotonic() < deadline):
+                time.sleep(0.005)
             assert ep.staged_count() == 0
             assert ep.stats()["served"] == 1
         finally:
