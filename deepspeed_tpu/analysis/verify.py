@@ -345,6 +345,16 @@ def _tiny_model_config(model: str = "dense"):
             rope_interleave=True, n_experts=8, moe_top_k=2, moe_drop_tokens=False,
             moe_dense_lead=1, moe_score="sigmoid", moe_router_bias=False, moe_n_group=4,
             moe_topk_group=2)
+    if model == "planes":
+        # ... or two planes a layer: a layer of two latent-attention sub-blocks
+        # with the expert block on a shortcut across them (longcat_flash), a
+        # looped stack whose sub-block stacks are indexed at 2 li + i
+        return get_config(
+            "tiny", n_layers=2, dtype="float32", max_seq_len=512, head_dim_override=24,
+            kv_lora_rank=16, q_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+            rope_interleave=True, latent_q_scale=2.0, latent_kv_scale=2.8, moe_shortcut=True,
+            n_experts=4, moe_experts_total=8, moe_zero_experts=4, moe_top_k=3,
+            moe_drop_tokens=False, moe_norm_topk_prob=False, moe_routed_scale=6.0)
     return get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
 
 
@@ -357,7 +367,7 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
     from deepspeed_tpu.models import init_params
 
     cfg = _tiny_model_config(model)
-    if model == "latent":
+    if model in ("latent", "planes"):
         decode_steps = 1  # a latent pool has no fused round
     params = init_params(cfg, jax.random.key(0))
     kv = {"block_size": 4, "num_blocks": 128, "max_blocks_per_seq": 32,
@@ -392,7 +402,8 @@ def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     the recurrent-state and conv pools, ``model="window"`` (window and global
     layers in one stack) the window pools; such models have no verify step
     (the engine refuses it); ``model="latent"`` (latent attention) has one
-    plane, and neither a verify step nor a fused round."""
+    plane, ``model="planes"`` two a layer, and neither a verify step nor a
+    fused round."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -477,10 +488,10 @@ def verify_engine_v2() -> List[CheckResult]:
     # ... a model with DeltaNet layers the state pools, and one that mixes
     # window and global layers the window pools
     # (and one whose two pools differ in KV heads and whose planes in width)
-    # ... and a latent-attention model its pool of one plane
+    # ... and a latent-attention model its pool of one plane, or of two a layer
     return (_engine_v2_pass("bf16") + _engine_v2_pass("int8") + _engine_v2_pass("bf16", "gdn")
             + _engine_v2_pass("bf16", "window") + _engine_v2_pass("bf16", "geometry")
-            + _engine_v2_pass("bf16", "latent"))
+            + _engine_v2_pass("bf16", "latent") + _engine_v2_pass("bf16", "planes"))
 
 
 def verify_streamed_adam() -> List[CheckResult]:

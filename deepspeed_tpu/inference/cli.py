@@ -353,7 +353,8 @@ def engine_config_from_args(args, cfg):
         # capacity multiplier reaches admission
         # (a DeltaNet model's state slots or a mixed stack's window rings,
         # one a tracked sequence and a spare, come out of the same budget first)
-        # (a latent model's pool is one plane of one vector a token; a pool's
+        # (a latent model's pool is one plane of one vector a token, kv_layers
+        # deep: two planes a layer where a layer holds two attentions; a pool's
         # geometry is its own: the block pool's KV heads and plane widths here,
         # the window pool's inside slot_bytes)
         from deepspeed_tpu.inference.v2.kv_pool import (

@@ -15,7 +15,9 @@ TPU adaptation:
     blocks a tracked sequence (kv_pool.py), and the block pool the global
     layers' alone; a latent-attention model (``kv_lora_rank``) has ONE plane,
     [L, num_blocks, latent_dim, block_size]: a token's keys and values are
-    one vector every head shares (ops/attention/latent_pallas.py);
+    one vector every head shares (ops/attention/latent_pallas.py), and where
+    a layer holds two latent attentions (``moe_shortcut``) L counts the
+    attentions: plane 2 l + i is sub-block i of layer l's;
   * paged attention = block-table gather → dense attention with a length
     mask, or the Pallas paged kernel underneath (``paged_attention``);
   * a step is one compiled program over a fixed grid (the SplitFuse
@@ -162,7 +164,8 @@ class StepStats:
     kv_context_tokens: int = 0
     paged_window_live_blocks: int = 0
     # a latent pool: the decode rows of the step and the pool blocks ONE
-    # layer's absorbed decode walks for them (_count_latent); 0 elsewhere
+    # PLANE's absorbed decode walks for them (a layer's, where a layer has one
+    # latent attention; a step walks kv_layers planes); 0 elsewhere
     latent_decode_rows: int = 0
     latent_decode_blocks: int = 0
     # ... and the pool blocks the tracked sequences' tables hold (kv_global_blocks
@@ -1512,9 +1515,10 @@ class InferenceEngineV2:
     def _moe_width(self) -> int:
         """Entries of one layer's routed-rows record: a row count an expert
         held and, for a grouped router, the tokens whose kept groups include
-        a held one (grouped.experts_grouped)."""
+        a held one, for one with identity experts the pairs that chose one
+        (grouped.experts_grouped)."""
         c = self._mc
-        return c.n_experts + (1 if c.moe_n_group > 1 else 0)
+        return c.n_experts + (1 if c.moe_n_group > 1 or c.moe_zero_experts else 0)
 
     def _with_state(self, carry, second):
         """The carry with a DeltaNet model's (states, conv inputs) in it (a
@@ -1687,6 +1691,9 @@ class InferenceEngineV2:
             whole_keys = EXPERT_STACKS
             whole = {k: v for k, v in params["layers"].items() if k in whole_keys}
         sliced = {k: v for k, v in params["layers"].items() if k not in whole}
+        # a layer of two sub-blocks (moe_shortcut): their stacks, [2 L, ...],
+        # are indexed by sub-block, 2 li + i, as the cache's planes are
+        subs = sliced.pop("sub", None)
 
         def traced(a, i):
             return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
@@ -1716,7 +1723,11 @@ class InferenceEngineV2:
         if not isinstance(windows, list):
             def body(li, st):
                 x, carry = st
-                return layer_fn(layer_params(lambda a: traced(a, li)), x, li, carry, window=windows)
+                lp = layer_params(lambda a: traced(a, li))
+                if subs is not None:
+                    lp["sub"] = tuple(jax.tree.map(lambda a: traced(a, 2 * li + i), subs)
+                                      for i in range(2))
+                return layer_fn(lp, x, li, carry, window=windows)
 
             x, carry = jax.lax.fori_loop(0, L, body, (x, carry))
             return x, carry
@@ -1982,6 +1993,8 @@ class InferenceEngineV2:
                 "live": meta["dec_pos"] >= 0, "slot_live": meta["slot_live"],
                 "chk_slots": meta.get("chk_slots"), "chk_start": meta.get("chk_start"),
                 "chk_pos": meta.get("chk_pos")}, carry)
+        if "sub" in lp:  # a layer of two sub-blocks (moe_shortcut): its own keys say so
+            return self._shortcut_layer(lp, x, li, meta, carry)
         if self._latent:
             return self._latent_layer(lp, x, li, meta, carry)
         a, q, k, v = self._layer_qkv(lp, x, meta["positions"], meta["live"], w)
@@ -2014,43 +2027,77 @@ class InferenceEngineV2:
         x, moe = self._layer_tail(lp, x, out, meta["slot_live"], li, a)
         return x, self._record_kv(carry, li, k, v, moe)
 
-    def _latent_layer(self, lp, x, li, meta, carry):
-        """One latent-attention layer of the split step, in the ABSORBED form:
-        ``W_UK`` moved to the query (``q = [q_nope W_UK | q_rope]``, every head
-        against the one cached vector a token) and ``W_UV`` behind the output.
-        Decode rows through ``latent_decode`` (the pool below their position
-        and their own new vector as the extra column), chunk rows through
-        ``latent_chunk`` (the pool below the chunk's start, then the chunk's
-        own vectors, causal); on the chip the kernels ``dstpu_mla_decode`` /
-        ``dstpu_mla_chunk``, elsewhere the dense forms. The layer records its
-        new vectors in ``carry``; the pool is written once, after the loop."""
+    def _latent_attention(self, lp, x, plane, meta):
+        """Latent attention of the split step in the ABSORBED form, on the
+        cache's plane ``plane``: ``W_UK`` moved to the query (``q = [q_nope W_UK
+        | q_rope]``, every head against the one cached vector a token) and
+        ``W_UV`` behind the output. Decode rows through ``latent_decode`` (the
+        pool below their position and their own new vector as the extra
+        column), chunk rows through ``latent_chunk`` (the pool below the chunk's
+        start, then the chunk's own vectors, causal); on the chip the kernels
+        ``dstpu_mla_decode`` / ``dstpu_mla_chunk``, elsewhere the dense forms.
+        Returns (the block's output [1, t, h], the new vectors [t, latent_dim])."""
         from deepspeed_tpu.ops.attention.latent_pallas import latent_chunk, latent_decode
 
         c = self._mc
         R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
         nh, rank, D = c.n_heads, c.kv_lora_rank, c.latent_dim
         scale = c.attn_scale if c.attn_scale is not None else c.head_dim ** -0.5
-        lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
         a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
         q_nope, q_rope, ckv = T.latent_qkv(c, lp, a[0], meta["positions"], meta["live"])
         w_uk, w_uv = T.latent_up(c, lp)
         q = jnp.concatenate([jnp.einsum("thd,chd->thc", q_nope, w_uk), q_rope], axis=-1)
-        pool, _, tables_l, trash_l = self._kv_source(meta, li, "dec_tables")
+        pool, _, tables_l, trash_l = self._kv_source(meta, plane, "dec_tables")
         out = latent_decode(
             q[:R], pool, tables_l, meta["dec_pos"], trash_l, rank=rank, scale=scale,
             extra=(ckv[:R, None], meta["dec_pos"][:, None]), pool_limit=meta["dec_pos"],
             impl=self._attn_impl)
         if tq:
-            _, _, tables_l, trash_l = self._kv_source(meta, li, "chk_tables")
+            _, _, tables_l, trash_l = self._kv_source(meta, plane, "chk_tables")
             out_c = latent_chunk(
                 q[R:].reshape(Rc, tq, nh, D), pool, tables_l, meta["chk_pos"], trash_l,
                 ckv[R:].reshape(Rc, tq, D), meta["chk_start"], rank=rank, scale=scale,
                 impl=self._attn_impl)
             out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, rank)], axis=0)
         heads = jnp.einsum("thc,chd->thd", out, w_uv).reshape(x.shape[1], nh * c.v_head_dim)
-        x, moe = self._mlp_tail(lp, x, (heads @ lp["wo"])[None], meta["slot_live"], li)
+        return (heads @ lp["wo"])[None], ckv
+
+    def _latent_layer(self, lp, x, li, meta, carry):
+        """One latent-attention layer of the split step: ``_latent_attention``
+        on plane ``li``, then the MLP or the expert block. The layer records its
+        new vectors in ``carry``; the pool is written once, after the loop."""
+        lp = T._dequant_tree(lp, T.DTYPES[self._mc.dtype])
+        attn_out, ckv = self._latent_attention(lp, x, li, meta)
+        x, moe = self._mlp_tail(lp, x, attn_out, meta["slot_live"], li)
         carry = dict(carry, k=jax.lax.dynamic_update_index_in_dim(carry["k"], ckv, li, 0))
         return x, self._record_moe(carry, li, moe)
+
+    def _shortcut_layer(self, lp, x, li, meta, carry):
+        """One ``moe_shortcut`` layer of the split step (longcat_flash): two
+        sub-blocks, each ``_latent_attention`` on its own plane (2 li + i) and a
+        dense MLP on the stream, and the expert block, which reads the FIRST
+        sub-block's normed MLP input and joins the stream behind the SECOND
+        sub-block's MLP. Nothing but that sum orders the expert block against
+        the second attention: across chips its exchange would hide behind it.
+        ``lp``: the expert block's parameters (its weights the whole stacks,
+        read at ``li``) and under "sub" the two sub-blocks'. Each sub-block
+        records its new vectors at its plane, the layer what it routed."""
+        from deepspeed_tpu.parallel.moe import moe_mlp
+
+        c = self._mc
+        shortcut = moe = None
+        for i, sp in enumerate(lp["sub"]):
+            with jax.named_scope(f"sub_block_{i}"):
+                plane = 2 * li + i
+                attn_out, ckv = self._latent_attention(sp, x, plane, meta)
+                carry = dict(carry, k=jax.lax.dynamic_update_index_in_dim(carry["k"], ckv, plane, 0))
+                x = x + attn_out
+                m = T._norm(x, sp["mlp_norm"], None, c.norm, c.norm_eps)
+                if i == 0:
+                    with jax.named_scope("shortcut_experts"):
+                        shortcut, _, moe = moe_mlp(c, lp, m, live=meta["slot_live"][None], layer=li)
+                x = x + T._mlp_block(c, sp, m)[0]
+        return x + shortcut, self._record_moe(carry, li, moe)
 
     def _build_split_step(self, shape):
         """ONE compiled step over the split-phase batch: R decode slots +
@@ -2711,7 +2758,8 @@ class InferenceEngineV2:
 
         c = self._mc
         rows = np.asarray(pending)[..., c.moe_dense_lead:, :]  # the layers that have experts
-        group_hit = rows[..., c.n_experts:].sum() if c.moe_n_group > 1 else None
+        beside = rows[..., c.n_experts:].sum()  # the entry behind the experts', where there is one
+        group_hit = beside if c.moe_n_group > 1 else None
         counts = rows[..., : c.n_experts].reshape(-1, c.n_experts)
         # tokens of one layer call: the grid, a step of it for a fused round
         steps = rows.shape[0] if rows.ndim == 3 else 1
@@ -2727,6 +2775,12 @@ class InferenceEngineV2:
             "hot": int(counts.max(axis=-1).sum()), "calls": int(counts.shape[0]),
             "hit": int((counts > 0).sum()),
         }
+        if c.moe_zero_experts:
+            # a router with identity experts: every (token, choice) pair of the
+            # layer calls, held here or not, and those that chose an identity one
+            flight.stats.moe.update(
+                pairs=flight.stats.scheduled_tokens * counts.shape[0] * c.moe_top_k,
+                zero_pairs=int(beside))
         if group_hit is not None:
             # a grouped router: tokens whose kept groups include a held one,
             # summed over the expert layers' calls, beside the tokens routed

@@ -16,7 +16,10 @@ values, YaRN rotary on 64 shared dims; a dense lead layer then sigmoid-routed
 experts chosen inside the best groups, a share of them), mimo_v2_flash
 (MiMo-V2-Flash: window layers of 8 KV heads with a learned sink beside full
 layers of 4, keys of 192 against values of 128, a rotary base by layer kind, a
-dense lead layer then sigmoid-routed experts with no shared one), qwen3_moe (per-head q/k
+dense lead layer then sigmoid-routed experts with no shared one), longcat_flash
+(LongCat-Flash: a layer of two latent-attention sub-blocks with dense MLPs and
+one expert block on a shortcut across them, a softmax router whose last ids
+are identity experts), qwen3_moe (per-head q/k
 RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole projection width; the four MoE types import drop-free: ``moe_drop_tokens``
 false), falcon, phi (incl. qk_layernorm),
 phi3, gpt2, gpt_neo, opt, gemma, bloom, gptj, gpt_neox, internlm, stablelm
@@ -238,6 +241,61 @@ def _axk1_config(get) -> TransformerConfig:
         moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
         moe_shared_expert_dim=expert_dim * int(get("n_shared_experts", 0) or 0),
         moe_shared_gated=False,
+    )
+
+
+def _longcat_flash_config(get) -> TransformerConfig:
+    """LongCat-Flash (``longcat_flash``) under its own key names: ``num_layers``
+    layers of TWO sub-blocks each (latent attention as DeepseekV3's, with
+    ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: queries times sqrt(hidden /
+    q_lora_rank), the normed latent times sqrt(hidden / kv_lora_rank); a dense
+    SwiGLU of ``ffn_hidden_size``) and ONE expert block on a shortcut across them
+    (``moe_shortcut``): a softmax router over ``n_routed_experts`` +
+    ``zero_expert_num`` ids, ``moe_topk`` CHOSEN on probability +
+    ``e_score_correction_bias`` and weighted by the probability alone, not
+    renormalised, times ``routed_scaling_factor``; experts of
+    ``expert_ffn_hidden_size``, the ids behind them the identity. No shared
+    expert, no dense lead layer, an untied head. ``deployment_share`` as for
+    qwen3_next (``n_routed_experts`` held of the published count; the identity
+    experts are every chip's)."""
+    import math
+
+    mt = "longcat_flash"
+    if get("attention_method", "MLA") != "MLA" or get("attention_bias", False) or not get(
+            "q_lora_rank", None):
+        raise ValueError(f"{mt}: attention_method MLA with a q_lora_rank and no bias is supported")
+    zero = int(get("zero_expert_num", 0) or 0)
+    if zero and get("zero_expert_type", "identity") != "identity":
+        raise ValueError(f"{mt}: zero_expert_type={get('zero_expert_type')!r}, expected 'identity'")
+    held, total, shard = _expert_share(get, mt, "n_routed_experts")
+    h = int(get("hidden_size"))
+    dn, dr = int(get("qk_nope_head_dim")), int(get("qk_rope_head_dim"))
+    qr, rank = int(get("q_lora_rank")), int(get("kv_lora_rank"))
+    return _llama_like_config(
+        get,
+        n_layers=int(get("num_layers")),
+        ffn_hidden_size=get("ffn_hidden_size"),
+        head_dim_override=dn + dr,
+        kv_lora_rank=rank,
+        q_lora_rank=qr,
+        qk_nope_dim=dn,
+        qk_rope_dim=dr,
+        v_head_dim=int(get("v_head_dim")),
+        rope_interleave=bool(get("rope_interleave", True)),
+        latent_norm_eps=1e-6,  # LongcatFlashMLA's inner norms: its RMSNorm's default, not rms_norm_eps
+        latent_q_scale=math.sqrt(h / qr) if get("mla_scale_q_lora", False) else 1.0,
+        latent_kv_scale=math.sqrt(h / rank) if get("mla_scale_kv_lora", False) else 1.0,
+        moe_shortcut=True,
+        n_experts=held,
+        moe_experts_total=total if total != held else 0,
+        moe_expert_shard=shard,
+        moe_zero_experts=zero,
+        moe_top_k=int(get("moe_topk")),
+        moe_norm_topk_prob=bool(get("norm_topk_prob", False)),
+        moe_drop_tokens=False,  # the published block never drops a token
+        moe_expert_dim=int(get("expert_ffn_hidden_size")),
+        moe_score="softmax",
+        moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
     )
 
 
@@ -497,6 +555,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         return _axk1_config(get)
     if mt == "mimo_v2_flash":
         return _mimo_v2_flash_config(get)
+    if mt == "longcat_flash":
+        return _longcat_flash_config(get)
     if mt == "qwen2_moe":
         sparse_step = get("decoder_sparse_step", 1)
         mlp_only = get("mlp_only_layers", []) or []
@@ -966,7 +1026,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
         "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
-        "qwen3, qwen3_moe, qwen3_next, exaone_moe, axk1, mimo_v2_flash, megatron_gpt, bert, "
+        "qwen3, qwen3_moe, qwen3_next, exaone_moe, axk1, mimo_v2_flash, longcat_flash, "
+        "megatron_gpt, bert, "
         "distilbert, "
         "clip_text_model"
     )
@@ -1130,6 +1191,32 @@ def _axk1_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, 
         moe[name].append(np.stack([
             take.linear(f"{p}.mlp.experts.{first + e}.{hf}.weight") for e in range(cfg.n_experts)]))
         moe[f"shared_{name[2:]}"].append(take.linear(f"{p}.mlp.shared_experts.{hf}.weight"))
+
+
+def _longcat_flash_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
+    """One LongCat-Flash layer: its two sub-blocks (``input_layernorm.i``,
+    ``self_attn.i.*`` under DeepseekV3's projection names,
+    ``post_attention_layernorm.i``, the dense ``mlps.i.*``) appended in order to
+    the ``sub`` stacks, and the expert block ``mlp``: the router's classifier
+    whole (identity ids too), its selection bias, the chip's own experts."""
+    sub = layers["sub"]
+    names = (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+    for i in range(2):
+        sub["attn_norm"].append(take(f"{p}.input_layernorm.{i}.weight"))
+        sub["mlp_norm"].append(take(f"{p}.post_attention_layernorm.{i}.weight"))
+        for name, hf in (("wq_a", "q_a_proj"), ("wq_b", "q_b_proj"), ("wkv_a", "kv_a_proj_with_mqa"),
+                         ("wkv_b", "kv_b_proj"), ("wo", "o_proj")):
+            sub[name].append(take.linear(f"{p}.self_attn.{i}.{hf}.weight"))
+        sub["q_a_norm"].append(take(f"{p}.self_attn.{i}.q_a_layernorm.weight"))
+        sub["kv_a_norm"].append(take(f"{p}.self_attn.{i}.kv_a_layernorm.weight"))
+        for name, hf in names:
+            sub[name].append(take.linear(f"{p}.mlps.{i}.{hf}.weight"))
+    layers["router"].append(take.linear(f"{p}.mlp.router.classifier.weight"))
+    layers["router_bias"].append(take(f"{p}.mlp.router.e_score_correction_bias"))
+    first = cfg.moe_expert_shard * cfg.n_experts
+    for name, hf in names:
+        layers[name].append(np.stack([
+            take.linear(f"{p}.mlp.experts.{first + e}.{hf}.weight") for e in range(cfg.n_experts)]))
 
 
 def _mimo_v2_flash_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
@@ -1539,6 +1626,7 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "exaone_moe": _exaone_moe_layer,
     "axk1": _axk1_layer,
     "mimo_v2_flash": _mimo_v2_flash_layer,
+    "longcat_flash": _longcat_flash_layer,
     "qwen3_moe": _llama_layer,
     "falcon": _falcon_layer,
     "phi": _phi_layer,
@@ -1575,6 +1663,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
     "exaone_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "axk1": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "mimo_v2_flash": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "longcat_flash": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "phi": ("model.embed_tokens.weight", "model.final_layernorm", "model.layers", None),
@@ -1636,12 +1725,16 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
         keys.append("wq_gate")
     if cfg.n_experts > 0:
         keys.append("router")
-        if cfg.moe_score == "sigmoid" and cfg.moe_router_bias:
+        if cfg.router_has_bias:
             keys.append("router_bias")
         if cfg.moe_shared_expert_dim > 0:
             keys += ["shared_gate", "shared_up", "shared_down"]
             if cfg.moe_shared_gated:
                 keys.append("shared_gate_proj")
+    if cfg.moe_shortcut:  # the expert block's keys, and a sub-block's (a dense MLP of its own) apart
+        mlp = ("w_up", "w_down", "w_gate")
+        return {**{k: [] for k in keys if k.startswith("router") or k in mlp},
+                "sub": {k: [] for k in keys if not k.startswith("router")}}
     if cfg.moe_dense_lead:  # the MLPs stacked apart (transformer.init_params)
         mlp = ("w_up", "w_down", "w_gate")
         out = {k: [] for k in keys if k not in mlp and not k.startswith(("router", "shared_"))}

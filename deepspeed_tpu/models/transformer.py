@@ -263,6 +263,35 @@ class TransformerConfig:
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = False
+    # longcat_flash (LongcatFlashMLA's mla_scale_q_lora / mla_scale_kv_lora):
+    # the heads' queries (behind wq_b, nope and rope dims alike) are multiplied
+    # by latent_q_scale = sqrt(hidden / q_lora_rank) and the normed latent by
+    # latent_kv_scale = sqrt(hidden / kv_lora_rank); the shared rotary key dims
+    # are not. The cache holds the latent AFTER its scale. 1.0: none
+    latent_q_scale: float = 1.0
+    latent_kv_scale: float = 1.0
+    # the eps of the two norms INSIDE a latent attention (q_a_norm, kv_a_norm)
+    # where it is not norm_eps: LongcatFlashMLA builds them with its RMSNorm's
+    # default, 1e-6, beside layer norms of rms_norm_eps 1e-5. None: norm_eps
+    latent_norm_eps: Optional[float] = None
+    # longcat_flash's layer (shortcut-connected experts): TWO sub-blocks, each a
+    # latent attention and a dense MLP of ffn_dim on the stream, and ONE expert
+    # block that reads the first sub-block's normed MLP input and joins the
+    # stream behind the SECOND sub-block's MLP:
+    #   h = x + A0(N(x)); m = N(h); s = E(m); h = h + D0(m)
+    #   h = h + A1(N(h)); x' = h + D1(N(h)) + s
+    # n_layers counts such layers. Parameters: the expert block on [n_layers]
+    # as every expert model's (router, router_bias, w_up / w_gate / w_down
+    # [n_layers, E, ...]), and what a sub-block has (attn_norm, mlp_norm, the
+    # latent keys, wo, the dense MLP's w_up / w_gate / w_down) under
+    # params["layers"]["sub"] on [2 * n_layers], sub-block i of layer l at
+    # 2 l + i; the cache is as deep (``kv_layers``), plane 2 l + i its vector
+    moe_shortcut: bool = False
+    # longcat_flash's zero-computation experts: the router is moe_zero_experts
+    # wider than the experts there are, and a chosen id behind them is the
+    # IDENTITY: it adds gate x the expert block's input, has no weight, no row
+    # in the grouped matmul and no chip (every chip adds it for its own tokens)
+    moe_zero_experts: int = 0
     # mimo_v2_flash: a mixed stack (attn_layer_pattern with both flags) whose
     # window layers differ from its global ones in more than the window.
     # window_kv_heads > 0: the window layers have that many KV heads, the
@@ -375,6 +404,19 @@ class TransformerConfig:
                 "qk_rope_dim and v_head_dim, head_dim_override = qk_nope_dim + qk_rope_dim, "
                 "rotary positions and pre-norm blocks, and composes with no window, layer "
                 "kinds, q/k norm, bias, output gate or parallel block")
+        if self.moe_shortcut and not (
+                self.latent and self.n_experts > 0 and not self.moe_dense_lead
+                and not self.moe_shared_expert_dim and not self.moe_residual
+                and not self.moe_drop_tokens):
+            raise ValueError(
+                "moe_shortcut: a layer of two latent-attention sub-blocks with dense MLPs and "
+                "one drop-free expert block across them; composes with no dense lead layer, "
+                "shared or residual expert")
+        if self.moe_zero_experts and (self.moe_drop_tokens or self.moe_n_group > 1
+                                      or self.n_experts < 1):
+            raise ValueError(
+                f"moe_zero_experts={self.moe_zero_experts}: identity experts behind those of a "
+                "drop-free router without groups (the grouped dispatch adds them)")
         if self.attn_by_kind and not (
                 self.window_layers > 0 and self.n_heads % self.window_kv_heads == 0
                 and self.layer_kinds is None and not self.latent
@@ -549,9 +591,28 @@ class TransformerConfig:
         return self.kv_lora_rank + self.qk_rope_dim
 
     @property
-    def router_width(self) -> int:
-        """Experts the router chooses among: the published count."""
+    def routed_experts(self) -> int:
+        """Experts there are, held here or elsewhere: the published count."""
         return self.moe_experts_total or self.n_experts
+
+    @property
+    def router_width(self) -> int:
+        """Ids the router chooses among: the published experts, then the
+        identity ones (``moe_zero_experts``)."""
+        return self.routed_experts + self.moe_zero_experts
+
+    @property
+    def router_has_bias(self) -> bool:
+        """True where the router CHOOSES on score + ``router_bias`` (a sigmoid
+        router unless ``moe_router_bias`` is off; a softmax router with identity
+        experts, whose share of the choices the bias steers)."""
+        return self.n_experts > 0 and self.moe_router_bias and (
+            self.moe_score == "sigmoid" or self.moe_zero_experts > 0)
+
+    @property
+    def sub_blocks(self) -> int:
+        """Attention sub-blocks a layer has: 2 under ``moe_shortcut``."""
+        return 2 if self.moe_shortcut else 1
 
     @property
     def hybrid(self) -> bool:
@@ -567,8 +628,9 @@ class TransformerConfig:
     def kv_layers(self) -> int:
         """Layers that cache the whole context's keys and values: the block
         pool's leading dimension (a mixed stack's window layers have a pool of
-        their own, ``window_layers`` deep)."""
-        return self.kind_count("full") - self.window_layers
+        their own, ``window_layers`` deep). A ``moe_shortcut`` layer caches a
+        plane for each of its two sub-blocks."""
+        return (self.kind_count("full") - self.window_layers) * self.sub_blocks
 
     @property
     def window_layers(self) -> int:
@@ -784,8 +846,32 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     # router decides. Its sinks are drawn where they MATTER: at q gain 2 a
     # window row's 128 scores have std 2 and their log-sum-exp is ~6.9, so a
     # logit in [4.5, 6.5] takes a tenth to two fifths of the row's mass.
+    # A layer of two sub-blocks and an expert block on a shortcut (longcat_flash,
+    # ``moe_shortcut``) is seeded by component too, at gains of its own, because
+    # its published scales and its router's law change what a unit gain means
+    # (PERF.md section 6, PR 45, has the chip readings). (1) Its queries are
+    # multiplied by latent_q_scale (2) and its latent by latent_kv_scale (3.46)
+    # behind norms that leave unit rms, so at unit gain a score has std 6: the
+    # second query projection is drawn at 0.6 (std 3.6, A.X-K1's with its YaRN
+    # factor) and o_proj at 0.5 / latent_kv_scale (the values are as much
+    # larger): an attention adds a fifth of the stream. (2) Its gates are
+    # 6 x softmax over 768, not renormalised: ~0.01 each, a twentieth of
+    # A.X-K1's, so the experts keep unit gain, and the router is drawn at a
+    # quarter of unit gain: a turned top-12 choice moves a token by one gate
+    # times the expert's output or, for an identity expert, times the block's
+    # input itself, ~1% of the stream. The eight dense MLPs add 1 / (2 x 8).
+    # (3) Four double layers add to a bf16 stream twenty times, A.X-K1's five
+    # layers ten: the rounding of the stream itself, which no block's gain
+    # changes, read 0.047-0.091 under the reference's best logit at a head of
+    # unit gain (limit 0.15; A.X-K1 0.04-0.05). A shortfall scales with the
+    # logits, so the head is drawn at 0.8: noise and faults both read a fifth
+    # lower, and the limit sits between them with the same room on either side.
     by_component = c.latent or c.attn_by_kind
-    latent_gain = {"q": 2.0, "attn": 0.5, "mlp": 1.0 / (2 * L), "experts": 1.0 / (6 * L)}
+    latent_gain = {"q": 2.0, "attn": 0.5, "mlp": 1.0 / (2 * L), "experts": 1.0 / (6 * L),
+                   "router": 1.0}
+    if c.moe_shortcut:
+        latent_gain = {"q": 0.6, "attn": 0.5 / c.latent_kv_scale, "mlp": 1.0 / (4 * L),
+                       "experts": 1.0, "router": 0.25, "head": 0.8}
     into_stream = (latent_gain["experts"] if by_component else 1.0 / math.sqrt(2 * L) if c.hybrid
                    else 1.0)
     unit_stream = c.hybrid or by_component or c.norm_scheme == "out"
@@ -799,22 +885,23 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     # attention weights live on the layers that attend: all of them, or with
     # layer_kinds the "full" ones (stacked apart, under layers["full"])
     La = c.kind_count("full")
+    Ls = L * c.sub_blocks  # norms and latent attention: a set a sub-block
     layers: Dict[str, Any] = {
-        "attn_norm": block_norm((L, h), dtype),
-        "mlp_norm": block_norm((L, h), dtype),
+        "attn_norm": block_norm((Ls, h), dtype),
+        "mlp_norm": block_norm((Ls, h), dtype),
     }
     if c.latent:
         rank, qr = c.kv_lora_rank, c.q_lora_rank
         layers.update(
-            wq_a=dense(next(keys), (L, h, qr), h),
-            q_a_norm=jnp.ones((L, qr), dtype),
-            wq_b=dense(next(keys), (L, qr, nh * d), qr, latent_gain["q"]),
+            wq_a=dense(next(keys), (Ls, h, qr), h),
+            q_a_norm=jnp.ones((Ls, qr), dtype),
+            wq_b=dense(next(keys), (Ls, qr, nh * d), qr, latent_gain["q"]),
             # the latent and, behind it, the rotary key dims every head shares
-            wkv_a=dense(next(keys), (L, h, c.latent_dim), h),
-            kv_a_norm=jax.random.uniform(next(keys), (L, rank), jnp.float32, 0.5, 1.5).astype(dtype),
+            wkv_a=dense(next(keys), (Ls, h, c.latent_dim), h),
+            kv_a_norm=jax.random.uniform(next(keys), (Ls, rank), jnp.float32, 0.5, 1.5).astype(dtype),
             # per head: qk_nope_dim key columns, then v_head_dim value columns
-            wkv_b=dense(next(keys), (L, rank, nh * (c.qk_nope_dim + c.v_head_dim)), rank),
-            wo=dense(next(keys), (L, nh * c.v_head_dim, h), nh * c.v_head_dim, latent_gain["attn"]),
+            wkv_b=dense(next(keys), (Ls, rank, nh * (c.qk_nope_dim + c.v_head_dim)), rank),
+            wo=dense(next(keys), (Ls, nh * c.v_head_dim, h), nh * c.v_head_dim, latent_gain["attn"]),
         )
     def attention(n, nkv):
         """Per-head attention's parameters on ``n`` layers of ``nkv`` KV heads."""
@@ -896,20 +983,28 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             out["w_gate"] = dense(next(keys), (n, h, ffn), h)
         return out
 
+    if c.moe_shortcut:
+        # what a sub-block has (its dense MLP too) under "sub" on [2 L]; the
+        # expert block beside it on [L]
+        layers.update(dense_mlp(Ls))
+        layers = {"sub": layers}
     if c.n_experts > 0:
         E, ed = c.n_experts, c.expert_dim
         Le = L - c.moe_dense_lead  # the layers that have experts
         moe: Dict[str, Any] = {}
-        moe["router"] = dense(next(keys), (Le, h, c.router_width), h)
+        moe["router"] = dense(next(keys), (Le, h, c.router_width), h, latent_gain["router"])
         moe["w_up"] = dense(next(keys), (Le, E, h, ed), h)
         moe["w_down"] = dense(next(keys), (Le, E, ed, h), ed, into_stream)
         if c.activation in ("swiglu", "geglu"):
             moe["w_gate"] = dense(next(keys), (Le, E, h, ed), h)
-        if c.moe_score == "sigmoid" and c.moe_router_bias:
+        if c.router_has_bias:
             # the selection bias: a checkpoint's is what balanced its experts'
-            # load, a few hundredths of a score
+            # load, a few hundredths of a sigmoid's score; beside a softmax
+            # over router_width, as large as the spread of its probabilities
+            # (std ~0.25 / width at the router's quarter gain)
+            std = 0.02 if c.moe_score == "sigmoid" else 0.25 / c.router_width
             moe["router_bias"] = (jax.random.normal(
-                next(keys), (Le, c.router_width), jnp.float32) * 0.02).astype(dtype)
+                next(keys), (Le, c.router_width), jnp.float32) * std).astype(dtype)
         if c.moe_residual:
             # dense residual expert + 2-way mixing coefficient (layer.py:47)
             moe["res_up"] = dense(next(keys), (Le, h, ffn), h)
@@ -966,7 +1061,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         params["mlm_norm_b"] = jnp.zeros((h,), dtype)
         params["mlm_bias"] = jnp.zeros((c.vocab_size,), dtype)
     if not c.tie_embeddings:
-        params["lm_head"] = dense(next(keys), (h, c.vocab_size), h)
+        params["lm_head"] = dense(next(keys), (h, c.vocab_size), h, latent_gain.get("head", 1.0))
         if c.lm_head_bias:
             params["lm_head_b"] = jnp.zeros((c.vocab_size,), dtype)
     return params
@@ -978,7 +1073,8 @@ def param_partition_specs(config: TransformerConfig) -> Dict[str, Any]:
     never sharded. ZeRO later adds the ``data`` axis on free dims
     (runtime/zero/partition.py choose_zero_spec)."""
     c = config
-    if c.hybrid or c.attn_out_gate or c.moe_experts_total or c.latent or c.attn_by_kind:
+    if (c.hybrid or c.attn_out_gate or c.moe_experts_total or c.latent or c.attn_by_kind
+            or c.moe_zero_experts):
         raise NotImplementedError(
             "tensor-parallel partition specs for layer_kinds / attn_out_gate / an "
             "expert share / latent attention / attention stacked by kind: no sharded form "
@@ -1498,10 +1594,15 @@ def latent_qkv(c: TransformerConfig, lp, a, positions, seq_len=None):
 
     t = a.shape[0]
     nh, rank, dn = c.n_heads, c.kv_lora_rank, c.qk_nope_dim
-    cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], c.norm_eps)
+    eps = c.norm_eps if c.latent_norm_eps is None else c.latent_norm_eps
+    cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], eps)
     q = as_written(_proj(c, cq, lp["wq_b"])).reshape(t, nh, c.head_dim)
     kv = _proj(c, a, lp["wkv_a"])
-    latent = rms_norm_reference(kv[:, :rank], lp["kv_a_norm"], c.norm_eps)
+    latent = rms_norm_reference(kv[:, :rank], lp["kv_a_norm"], eps)
+    if c.latent_q_scale != 1.0:  # longcat_flash: behind wq_b, nope and rope dims alike
+        q = (q.astype(jnp.float32) * c.latent_q_scale).astype(q.dtype)
+    if c.latent_kv_scale != 1.0:  # ... and the normed latent; the rotary key dims are not
+        latent = (latent.astype(jnp.float32) * c.latent_kv_scale).astype(latent.dtype)
     q_rope = _rope_pairs(c, q[..., dn:], positions, seq_len)
     k_rope = _rope_pairs(c, kv[:, None, rank:], positions, seq_len)[:, 0]
     return q[..., :dn], q_rope, jnp.concatenate([latent, k_rope.astype(latent.dtype)], axis=-1)
@@ -1851,6 +1952,26 @@ def _dequant_tree(lp, dtype):
     )
 
 
+def _shortcut_layer(c: TransformerConfig, lp, x, positions, segment_ids):
+    """One ``moe_shortcut`` layer (longcat_flash) as published: two sub-blocks
+    of latent attention and dense MLP on the stream, and the expert block, which
+    reads the FIRST sub-block's normed MLP input and joins the stream behind
+    the SECOND sub-block's MLP. ``lp``: the expert block's parameters and
+    under "sub" the sub-blocks' stacked [2, ...]."""
+    shortcut = aux_loss = None
+    for i in range(2):
+        sp = jax.tree.map(lambda a: a[i], lp["sub"])
+        with jax.named_scope(f"sub_block_{i}"):
+            a = _norm(x, sp["attn_norm"], None, c.norm, c.norm_eps)
+            x = _act_constraint(x + _attention_block(c, sp, a, positions, segment_ids)[0])
+            m = _norm(x, sp["mlp_norm"], None, c.norm, c.norm_eps)
+            if i == 0:
+                with jax.named_scope("shortcut_experts"):
+                    shortcut, aux_loss = _mlp_block(c, lp, m)
+            x = x + _mlp_block(c, sp, m)[0]
+    return _act_constraint(x + shortcut), aux_loss
+
+
 def _layer(c: TransformerConfig, lp, x, positions, segment_ids, local_flag=None):
     lp = _dequant_tree(lp, DTYPES[c.dtype])
     # Autocast: run the layer at the model's configured compute dtype even
@@ -1864,6 +1985,8 @@ def _layer(c: TransformerConfig, lp, x, positions, segment_ids, local_flag=None)
         else w,
         lp,
     )
+    if "sub" in lp:  # a layer of two sub-blocks (moe_shortcut): its own keys say so
+        return _shortcut_layer(c, lp, x, positions, segment_ids)
     if c.norm_scheme == "post":
         # BERT: norm AFTER each residual add; attention reads the raw stream
         attn_out, _ = _attention_block(c, lp, x, positions, segment_ids, local_flag=local_flag)
@@ -2008,6 +2131,9 @@ def forward_hidden(
             return layer_fn(lp, x, positions, segment_ids, flag)
     else:
         xs = params["layers"]
+        if c.moe_shortcut:  # a layer's two sub-blocks: [2 L, ...] as [L, 2, ...]
+            xs = dict(xs, sub=jax.tree.map(
+                lambda a: a.reshape((c.n_layers, 2) + a.shape[1:]), xs["sub"]))
 
         def call_layer(xs_i, x):
             return layer_fn(xs_i, x, positions, segment_ids)
@@ -2112,10 +2238,11 @@ def decode_step(params, tokens, config, kv_caches, positions):
             "decode_step: bidirectional encoder models (attn_causal=False) "
             "do not autoregressively decode — call forward() instead"
         )
-    if c.norm_scheme == "out" or c.moe_dense_lead:
+    if c.norm_scheme == "out" or c.moe_dense_lead or c.moe_shortcut:
         raise NotImplementedError(
             "decode_step: output-normed blocks / a dense lead layer before the experts "
-            "(exaone_moe) decode through the v2 paged engine only")
+            "(exaone_moe) / a layer of two sub-blocks (longcat_flash) decode through the v2 "
+            "paged engine only")
     b, t = tokens.shape
     stream = _stream_active(c)
     embed = _maybe_stage(params["embed"]) if stream else params["embed"]
@@ -2311,9 +2438,9 @@ def flops_per_token(config: TransformerConfig, seq_len: Optional[int] = None) ->
         c.hidden_size * (c.n_heads + 2 * c.kv_heads) * c.head_dim  # qkv
         + c.n_heads * c.head_dim * c.hidden_size  # out proj
         + c.hidden_size * c.ffn_dim * (3 if c.activation in ("swiglu", "geglu") else 2)
-    ) * c.n_layers + c.vocab_size * c.hidden_size
+    ) * c.n_layers * c.sub_blocks + c.vocab_size * c.hidden_size  # (a moe_shortcut layer: two)
     # causal attention by the heads' width (n_heads * head_dim, which is not
     # hidden_size where the head size is decoupled, as in Qwen3-0.6B): the
     # count of benchmarks/harness/flops.py, held to it by tests/unit/test_models.py
-    attn = 2 * c.n_layers * s * c.n_heads * c.head_dim
+    attn = 2 * c.n_layers * c.sub_blocks * s * c.n_heads * c.head_dim
     return 6.0 * (n_dense + attn / 2)

@@ -28,6 +28,14 @@ with the padding, so what comes back is this share's PART of the sum. On one
 chip the layer runs without its exchange, and nothing stands in for the chips
 that are not there.
 
+Identity experts (``moe_zero_experts``, longcat_flash's zero-computation
+experts): the router is that much wider than the experts there are, and a
+chosen id behind them adds ``gate x the layer's input``. Such a pair has no
+weight, so no row of the grouped matmul, no entry in the sort and no tile: a
+token's identity pairs are ONE fused multiply-add over ``[t, h]`` with the sum
+of their gates. It needs no dispatch either, so under an expert share every
+chip adds it for its own tokens, like a shared expert.
+
 ``grouped_matmul`` is the one new operation. On a TPU it is the Pallas kernel
 ``dstpu_moe_gmm`` (the shape of ``jax.experimental.pallas.ops.tpu.megablox``: row
 tiles aligned to group boundaries through scalar prefetch, a tile that straddles
@@ -216,7 +224,10 @@ def kept_groups(config, choose, biased: bool):
 def route(config, logits, live=None, bias=None):
     """Router as published. logits ``[t, E]`` float32 over every expert of the
     layer, held here or not. ``moe_score`` "softmax": the k most probable,
-    renormalised where the model says so. "sigmoid" (DeepseekV3TopkRouter):
+    renormalised where the model says so; with a ``bias`` (LongcatFlashTopkRouter)
+    the k CHOSEN on ``probability + bias`` and weighted by the probability
+    alone, and times ``moe_routed_scale`` where that is not 1. "sigmoid"
+    (DeepseekV3TopkRouter):
     scores ``sigmoid(logits)``, the k CHOSEN on ``score + bias`` (``bias
     [E]``: the checkpoint's e_score_correction_bias; None for a router without
     one) and weighted by the score alone, renormalised, times
@@ -242,9 +253,15 @@ def route(config, logits, live=None, bias=None):
         top_p = top_p * config.moe_routed_scale
     else:
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, k)
+        if bias is None:
+            top_p, top_e = jax.lax.top_k(probs, k)
+        else:
+            top_e = jax.lax.top_k(probs + bias.astype(jnp.float32), k)[1]
+            top_p = jnp.take_along_axis(probs, top_e, axis=-1)
         if config.moe_norm_topk_prob:
             top_p = top_p / jnp.maximum(jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
+        if config.moe_routed_scale != 1.0:
+            top_p = top_p * config.moe_routed_scale
     # load-balancing loss of topkgating (sharded_moe.py): E/k * <probs_e> . <share_e>
     chosen = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1)  # [t, E]
     w = jnp.ones(probs.shape[0], jnp.float32) if live is None else live.astype(jnp.float32)
@@ -263,7 +280,8 @@ def experts_grouped(config, lp, tokens, logits, live=None, layer=None
     here; the logits span more under an expert share. Returns (out ``[t, h]``:
     the held experts' part of the sum, aux loss, ``[E]`` int32 rows routed to
     each held expert; a grouped router appends one entry: the live tokens
-    whose kept groups include one this share holds an expert of)."""
+    whose kept groups include one this share holds an expert of; a router with
+    identity experts appends one: the live tokens' identity pairs)."""
     t, h = tokens.shape
     E, k = config.n_experts, config.moe_top_k
     top_p, top_e, aux, kept = route(config, logits, live, lp.get("router_bias"))
@@ -295,6 +313,13 @@ def experts_grouped(config, lp, tokens, logits, live=None, layer=None
     y = jnp.where(routed[:, None], y.astype(jnp.float32) * p_sorted[:, None], 0.0)
     back = jnp.argsort(order)[: t * k]  # where each (token, choice) pair went
     out = jnp.sum(y[back].reshape(t, k, h), axis=1)
+    if config.moe_zero_experts:
+        # the identity pairs: the sum of their gates times the input, on every chip
+        zero = top_e >= config.routed_experts
+        if live is not None:
+            zero = zero & live[:, None]
+        out = out + jnp.sum(jnp.where(zero, top_p, 0.0), axis=1, keepdims=True) * tokens.astype(jnp.float32)
+        counts = jnp.concatenate([counts, jnp.sum(zero, dtype=jnp.int32)[None]])
     if kept is not None:
         # the groups this share's experts lie in (one, where a chip holds a group)
         per = config.router_width // config.moe_n_group

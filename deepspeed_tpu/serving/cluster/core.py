@@ -233,6 +233,14 @@ class EngineCore:
             self._inc("moe_hot_expert_rows_total", moe["hot"])
             self._inc("moe_layer_calls_total", moe["calls"])
             self._inc("moe_experts_hit_total", moe.get("hit", 0))
+            # a router with identity experts: every (token, choice) pair,
+            # those of an expert held here (the routed rows again, under the
+            # pairs' name) and those of an identity expert, which no chip
+            # holds and every chip adds
+            if "pairs" in moe:
+                self._inc("moe_pairs_total", moe["pairs"])
+                self._inc("moe_held_pairs_total", moe["routed"])
+                self._inc("moe_zero_pairs_total", moe["zero_pairs"])
             # a grouped router: (token, layer call) pairs routed, and those
             # whose kept groups include one this chip holds
             self._inc("moe_group_tokens_total", moe.get("group_tokens", 0))

@@ -166,6 +166,13 @@ class ServingMetrics:
             "latent_live_blocks_total": 0,
             "moe_group_tokens_total": 0,
             "moe_group_hit_tokens_total": 0,
+            # a router with identity experts (moe_zero_experts; 0 for every
+            # other): every (token, choice) pair of the expert layers' calls,
+            # those whose expert this chip holds and those that chose an
+            # identity expert (no chip's, added by every chip)
+            "moe_pairs_total": 0,
+            "moe_held_pairs_total": 0,
+            "moe_zero_pairs_total": 0,
             # one step in flight (EngineCore._count_step): steps launched
             # before their predecessor was collected, and rows such a step
             # computed for a request that had stopped meanwhile (never

