@@ -121,7 +121,8 @@ class StepStats:
     """What one step or round was sized to and what it carried, filled where
     the step is staged (``moe`` after its wait). The serving core folds it
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
-    paged_live_blocks_total / paged_table_slots_total, chunk_live_blocks_total
+    paged_live_blocks_total / paged_table_slots_total / paged_programs_total,
+    chunk_live_blocks_total
     / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total,
     paged_window_live_blocks_total, latent_decode_rows_total /
     latent_decode_blocks_total / latent_live_blocks_total,
@@ -163,6 +164,9 @@ class StepStats:
     kv_window_blocks: int = 0
     kv_context_tokens: int = 0
     paged_window_live_blocks: int = 0
+    # ... and the programs of dstpu_paged_decode that read paged_live_blocks:
+    # a program reads blocks_a_program of a row's blocks (_count_paged)
+    paged_programs: int = 0
     # a latent pool: the decode rows of the step and the pool blocks ONE
     # PLANE's absorbed decode walks for them (a layer's, where a layer has one
     # latent attention; a step walks kv_layers planes); 0 elsewhere
@@ -387,7 +391,8 @@ class InferenceEngineV2:
                 "path — the seam that kept the kernel unreachable)"
             )
         from deepspeed_tpu.inference.v2.kv_pool import plane_widths, pool_geometry
-        from deepspeed_tpu.ops.attention.paged_pallas import kernels_take, keys_flat
+        from deepspeed_tpu.ops.attention.paged_pallas import (
+            blocks_a_program, kernels_take, keys_flat)
 
         # each pool's (kv_heads, key width, value width): the block pool is the
         # global layers', a mixed stack's window pool its window layers'
@@ -396,6 +401,12 @@ class InferenceEngineV2:
             heads, dim, planes = pool_geometry(c, pool)
             widths = plane_widths(dim, planes)
             self._geom[pool] = (heads, widths[0], widths[-1])
+        # pool blocks a program of dstpu_paged_decode reads off the block pool
+        # (_count_paged): the kernel's own rule on the bytes of a block of K
+        # and V; 0 for a latent pool, which that kernel never walks
+        nkv, dk, dv = self._geom["block"]
+        self._blocks_a_program = 0 if c.latent else blocks_a_program(
+            kv.block_size * nkv * (dk + dv) * (1 if self._kv_int8 else jnp.dtype(dtype).itemsize))
         if impl == "auto":
             # tp>1 stays dense: the Pallas kernel is opaque to GSPMD and
             # has no shard_map island; the gather shards on the kv-head dim.
@@ -1380,12 +1391,13 @@ class InferenceEngineV2:
         """Decode attention: one token per row, per-ROW layer-offset tables
         [R, B] into the flat pools, dispatched through ``paged_attention``
         with the impl resolved at engine init — on TPU the Pallas kernel,
-        whose grid is the blocks the rows' contexts cover, a row's last
-        visit also its finish (it folds this step's K/V and writes the row;
-        a row that holds no block is one program), read off ``positions`` /
-        ``pool_limit`` / ``window`` (a table slot a row does not hold costs
-        nothing; a block is folded in one product batched over the KV
-        heads, in the pool's dtype; int8 pools dequantize in-VMEM behind
+        whose grid is the blocks the rows' contexts cover, as many to a
+        program as make a megabyte (``paged_pallas.blocks_a_program``), a
+        row's last program also its finish (it folds this step's K/V and
+        writes the row; a row that holds no block is one program), read off
+        ``positions`` / ``pool_limit`` / ``window`` (a table slot a row does
+        not hold costs nothing; a program's blocks are folded in one product
+        batched over the KV heads, in the pool's dtype; int8 pools dequantize in-VMEM behind
         the halved HBM reads), elsewhere the dense XLA
         gather+einsum over whole tables (``impl="dense"``: GSPMD shards it
         on the kv-head dim without a shard_map island; CPU and tp shapes).
@@ -1408,16 +1420,23 @@ class InferenceEngineV2:
 
     def _count_paged(self, pool_tokens, calls: int = 1):
         """What one layer's decode attention had to read against what it was
-        handed, as ``StepStats``' (paged_live_blocks, paged_table_slots):
-        ``pool_tokens`` [R] are the tokens each row's pool window holds
-        (<= 0: an inactive slot), a row's live blocks are
-        ``ceil(tokens / bs)`` and its table has B slots; ``calls`` kernel
-        calls a layer walk the same rows (a fused round's steps, a verify
-        round's K1 queries a row)."""
+        handed, as ``StepStats``' paged_live_blocks, paged_table_slots and
+        paged_programs: ``pool_tokens`` [R] are the tokens each row's pool
+        window holds (<= 0: an inactive slot), a row's live blocks are
+        ``ceil(tokens / bs)``, its table has B slots, and the programs of
+        ``dstpu_paged_decode`` that read its blocks are ``ceil(blocks / G)``,
+        ``G`` the kernel's ``blocks_a_program`` of the block pool (a row that
+        holds nothing is a program that reads nothing, and is not counted:
+        live blocks over programs is 1 where ``G`` is, and ``G`` where every
+        group is full); ``calls`` kernel calls a layer walk the same rows (a
+        fused round's steps, a verify round's K1 queries a row)."""
         kv = self.config.kv_cache
         held = np.maximum(np.asarray(pool_tokens, np.int64), 0)
-        return (calls * int((-(-held // kv.block_size)).sum()),
-                calls * len(held) * kv.max_blocks_per_seq)
+        blocks = -(-held // kv.block_size)
+        G = self._blocks_a_program
+        return {"paged_live_blocks": calls * int(blocks.sum()),
+                "paged_table_slots": calls * len(held) * kv.max_blocks_per_seq,
+                "paged_programs": calls * int((-(-blocks // G)).sum()) if G else 0}
 
     def _count_cache(self, dec_pos, new_tokens: int, calls: int = 1):
         """The cache as the step finds it, as ``StepStats``' kv_global_blocks
@@ -2536,7 +2555,7 @@ class InferenceEngineV2:
                     pos >= start + n - wb * bs, seq.state_slot * wb + (pos // bs) % wb, spare * wb)
         prefill = sum(len(t) for _, t, _, _ in chk_rows)
         self.last_step = StepStats(
-            T_, total_tokens, prefill, *self._count_paged(dec_pos),
+            T_, total_tokens, prefill, **self._count_paged(dec_pos),
             gdn_decode_rows=len(dec_rows) if self._hybrid else 0,
             **self._count_chunk(chk_rows, tq), **self._count_cache(dec_pos, total_tokens),
         )
@@ -2605,7 +2624,7 @@ class InferenceEngineV2:
         R, inputs = self._stage_rows(uids, 1)
         inputs["tokens"] = inputs["tokens"][:, 0]
         self.last_step = StepStats(
-            R * n, len(uids) * n, 0, *self._count_paged(inputs["positions"], calls=n),
+            R * n, len(uids) * n, 0, **self._count_paged(inputs["positions"], calls=n),
             gdn_decode_rows=len(uids) * n if self._hybrid else 0,
             **self._count_cache(
                 np.where(inputs["active"], inputs["positions"], -1), len(uids) * n, calls=n))
@@ -2621,7 +2640,7 @@ class InferenceEngineV2:
             inputs["n_input"][i] = 1 + len(d)
         self.last_step = StepStats(
             R * (k + 1), len(uids) + sum(len(d) for d in row_drafts), 0,
-            *self._count_paged(inputs["positions"], calls=k + 1),
+            **self._count_paged(inputs["positions"], calls=k + 1),
             **self._count_cache((), len(uids) + sum(len(d) for d in row_drafts)),
         )
         return ("verify", k), inputs

@@ -213,7 +213,7 @@ def latent_decode(q, pool, tables, q_pos, trash_block, *, rank: int, scale: floa
     limit = q_pos + 1 if pool_limit is None else jnp.where(
         q_pos >= 0, jnp.asarray(pool_limit, jnp.int32).reshape(T), 0)
     kb = VISIT_BLOCKS
-    n_visits, vrow, vslot, vflag = _visit_list(q_pos, limit, bs * kb, -(-B // kb), 0)
+    n_visits, vrow, vslot, vflag, _ = _visit_list(q_pos, limit, bs * kb, -(-B // kb), 0)
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     def per_row(*shape):

@@ -19,20 +19,24 @@ Layout:
 Implementations (``paged_attention(impl=...)``; ``auto`` is the kernel on a
 TPU for head sizes 64 / 128 / 256 and the dense form elsewhere):
   * ``kernel`` — the Pallas kernel ``dstpu_paged_decode``. Its grid is the
-    list of visits the call's rows need (``_visit_list``): for each query
-    row the table slots its context covers, in order; the row's LAST visit
-    also folds the extra columns and writes the row out, and a row that
-    holds no block is one program (the extra columns alone; a padded slot
-    writes zeros). A slot a row does not hold is no program, so a call
-    costs what its rows hold, not ``T x B``. A visit takes the
-    ``[bs, nkv, d]`` block as it lies in the pool, swaps it to head-major
-    once and folds it into the row's flash state in ONE product batched
-    over the KV heads: operands in the queries' dtype (the pool's, in every
-    served model; an int8 pool dequantises into it in VMEM), float32
+    list of programs the call's rows need (``_visit_list``): for each query
+    row the table slots its context covers, in order, ``G`` consecutive slots
+    to a program, ``G`` as many pool blocks as make about a megabyte
+    (``blocks_a_program``: the geometry's alone, fixed when the call is
+    built); the row's LAST program also folds the extra columns and writes
+    the row out, and a row that holds no block is one program (the extra
+    columns alone; a padded slot writes zeros). A slot a row does not hold is
+    neither fetched nor folded, so a call costs what its rows hold, not
+    ``T x B``. A program takes its ``[bs, nkv, d]`` blocks as they lie in the
+    pool, joins them, swaps them to head-major once and folds them into the
+    row's flash state in ONE online-softmax step (``_fold``), one product
+    batched over the KV heads: operands in the queries' dtype (the pool's, in
+    every served model; an int8 pool dequantises into it in VMEM), float32
     scores, softmax state and accumulator; the queries come in scaled. The
-    block of a visit comes in through a scalar-prefetched index map; the
-    list is computed from ``q_pos`` / ``pool_limit`` / ``window`` by a few
-    small XLA fusions in front of the call.
+    blocks of a program come in through scalar-prefetched index maps, the
+    pool given once a block of the group; the list is computed from ``q_pos``
+    / ``pool_limit`` / ``window`` by a few small XLA fusions in front of the
+    call.
   * ``dense`` (``paged_decode_attention_dense``) — plain XLA: gather every
     slot of every table, then a masked einsum. What runs off the TPU and at
     ``tp_size > 1`` (GSPMD shards it on the kv-head dim); it reads the whole
@@ -70,6 +74,27 @@ NEG_INF = -1e30
 # custom call); metadata only. benchmarks/metrics readers find them by these.
 PAGED_DECODE = "dstpu_paged_decode"
 PAGED_CHUNK = "dstpu_paged_chunk"
+# A program of ``dstpu_paged_decode`` reads pool blocks until they make this
+# many bytes (``blocks_a_program``), four at most: the one threshold, set from
+# the kernel alone on a v5e at the cells' geometries and rows (us a live block
+# of 128 tokens at 1 / 2 / 4 blocks a program; my chip run, PR 46):
+#   16 KV heads of 128, 1 MiB (OLMoE)           1.51 / 1.49 / 1.60  -> 1
+#   8 x 128, 512 KiB (Qwen3, 5 blocks a row)    0.98 / 0.85 / 0.88  -> 2
+#   8 x 128 (K-EXAONE, 24 blocks a row)         0.90 / 0.75 / 0.72  -> 2
+#   8 x (192 + 128), 640 KiB (MiMo's rings)     1.28 / 1.04 / 1.11  -> 2
+#   4 x (192 + 128), 320 KiB (MiMo's full)      0.75 / 0.59 / 0.50  -> 4
+#   2 x 256, 256 KiB (Qwen3-Next)               1.00 / 0.77 / 0.77  -> 4
+# A program costs ~0.35 us whatever it reads, beside 0.64 us for the bytes of a
+# 512 KiB block. Four such blocks a program gain 4% over two on rows of 24
+# blocks and lose 3% on rows of 5 (more index maps and a longer join cost what
+# the fewer programs save), and a block that is a megabyte keeps the program
+# it has: the chip reads such a program at 85% of its bytes, 8 x 128 at two
+# blocks a program at 75-85% (65-71% at one).
+PROGRAM_BYTES = 1 << 20
+# ``_visit_list``'s flags: the group's first, second, .. slot holds a block of
+# the row (2: the row's first program, 4: its last)
+_GROUP_BITS = (1, 8, 16, 32)
+MAX_BLOCKS_A_PROGRAM = len(_GROUP_BITS)
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables, q_pos, trash_block,
@@ -186,26 +211,67 @@ def _fold_sink(m, l, acc, sink):
     return l * alpha + jnp.exp(sink - m_new), acc * alpha
 
 
-def _paged_kernel(*refs, bs, nkv, d, E=0, window=0, int8=False, sink=False):
-    """One program a VISIT: the grid is the list of (row, table slot) pairs
-    the rows' contexts cover, each row's pool blocks in order, so a table
-    slot a row does not hold costs nothing; a row's LAST visit also folds the
-    extra columns and writes the row out. ``refs`` layout — scalar prefetch
-    (SMEM): bt [T, B], qpos [T], trash [1], limit [T], vrow / vslot / vflag
-    [G] (the visit list, ``_visit_list``) — then tensor blocks (VMEM): epos
-    (1, 1, E) if ``E``, q (1, nkv, group, d) scaled, k (1, bs, nkv, d), v,
-    ks/vs scale planes (1, bs, nkv) if ``int8``, ke/ve (1, E, nkv, d) if
-    ``E``, the sinks (nkv, group, 128) float32 if ``sink`` (a head's logit on
-    every lane; folded into a row's state at its finish) — then o (1, nkv,
-    group, dv) and the m / l / acc flash scratch [nkv, group, .]. The values'
-    width ``dv`` is the V block's own: a key of 192 beside a value of 128.
+def blocks_a_program(block_bytes: int) -> int:
+    """How many consecutive table slots of its row a program of
+    ``dstpu_paged_decode`` reads, from the bytes of ONE pool block of K and V as
+    the pool stores them (``bs x (K row + V row) x itemsize``, an int8 pool's
+    scale planes left out): the smallest power of two that brings a program to
+    ``PROGRAM_BYTES``, at most ``MAX_BLOCKS_A_PROGRAM``. The engine's counter of
+    programs (``_count_paged``) asks the same function."""
+    g = 1
+    while g < MAX_BLOCKS_A_PROGRAM and g * block_bytes < PROGRAM_BYTES:
+        g *= 2
+    return g
 
-    A block is folded as it lies in the pool: swapped to head-major
-    ``[nkv, bs, d]`` once and multiplied in ONE product batched over the KV
-    heads, operands in the queries' dtype (what the pool stores, in every
-    served model), float32 scores, softmax state and accumulator, read and
-    written whole once a visit (an operation a head costs a visit what its
-    head count costs, not what its bytes cost: ledger, PRs 32 and 34).
+
+def _fold(q, k, v, valid, m_scr, l_scr, acc_scr):
+    """One online-softmax step of a flash state held in scratch (``m_scr`` /
+    ``l_scr`` [nkv, M, 128], column 0 meaningful, ``acc_scr`` [nkv, M, dv]):
+    queries ``q`` [nkv, M, d] against keys ``k`` / values ``v`` [nkv, nk, .],
+    every KV head in ONE batched product, operands as they come (the queries'
+    dtype), float32 scores, state and accumulator, read and written once.
+    ``valid`` [.., nk] broadcastable to the scores, or None where no key is
+    masked; a masked key's VALUE is the caller's to zero (its weight is
+    exactly 0, and 0 x NaN is not)."""
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    )  # [nkv, M, nk]
+    if valid is not None:
+        s = jnp.where(valid, s, NEG_INF)
+    m_p = m_scr[:, :, :1]  # col 0 meaningful
+    m_new = jnp.maximum(m_p, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_p - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    m_scr[:, :, :1] = m_new
+
+
+def _paged_kernel(*refs, bs, nkv, d, G=1, E=0, window=0, int8=False, sink=False):
+    """One program a GROUP of ``G`` consecutive table slots of one row: the
+    grid is the list of groups the rows' contexts cover (``_visit_list``), each
+    row's in order, so a table slot a row does not hold costs nothing; a row's
+    LAST program also folds the extra columns and writes the row out. ``refs``
+    layout — scalar prefetch (SMEM): bt [T, B], qpos [T], trash [1], limit
+    [T], vrow / vslot / vflag [P] (the program's row, its first slot and its
+    flags), then ``frow`` / ``fslot`` [P] for each block of the group after the
+    first (where its index map points: read by the maps alone) — then tensor
+    blocks (VMEM): epos (1, 1, E) if ``E``, q (1, nkv, group, d) scaled, G
+    blocks of k (1, bs, nkv, d), G of v, G + G scale planes ks / vs (1, bs,
+    nkv) if ``int8``, ke/ve (1, E, nkv, d) if ``E``, the sinks (nkv, group,
+    128) float32 if ``sink`` (a head's logit on every lane; folded into a
+    row's state at its finish) — then o (1, nkv, group, dv) and the m / l /
+    acc flash scratch [nkv, group, .]. The values' width ``dv`` is the V
+    block's own: a key of 192 beside a value of 128.
+
+    The program's live blocks are folded as they lie in the pool, TOGETHER:
+    joined to ``[n x bs, nkv, d]``, swapped to head-major once and folded in
+    ONE online-softmax step (``_fold``), so the state is read and written once
+    a program, not once a block. A block of the group that the row does not
+    hold (its last program, where the blocks do not fill it) is neither
+    fetched (its index repeats what that operand fetched last) nor folded.
 
     ``trash`` rides as a prefetch operand (not a static kwarg) because the
     engine's flat multi-layer views use layer-offset trash ids — traced
@@ -217,19 +283,24 @@ def _paged_kernel(*refs, bs, nkv, d, E=0, window=0, int8=False, sink=False):
     it = iter(refs)
     bt_ref, qpos_ref, trash_ref, limit_ref = next(it), next(it), next(it), next(it)
     vrow_ref, vslot_ref, vflag_ref = next(it), next(it), next(it)
+    for _ in range(2 * (G - 1)):
+        next(it)  # frow / fslot: the index maps' alone
     epos_ref = next(it) if E else None
-    q_ref, k_ref, v_ref = next(it), next(it), next(it)
-    ks_ref = next(it) if int8 else None
-    vs_ref = next(it) if int8 else None
+    q_ref = next(it)
+    k_refs, v_refs = [next(it) for _ in range(G)], [next(it) for _ in range(G)]
+    ks_refs = [next(it) for _ in range(G)] if int8 else None
+    vs_refs = [next(it) for _ in range(G)] if int8 else None
     ke_ref = next(it) if E else None
     ve_ref = next(it) if E else None
     sink_ref = next(it) if sink else None
     o_ref = next(it)
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
+    state = (m_scr, l_scr, acc_scr)
 
     g = pl.program_id(0)
     t, slot, flag = vrow_ref[g], vslot_ref[g], vflag_ref[g]
     qpos = qpos_ref[t]
+    B = bt_ref.shape[1]
 
     @pl.when((flag & 2) != 0)
     def _init():
@@ -239,64 +310,62 @@ def _paged_kernel(*refs, bs, nkv, d, E=0, window=0, int8=False, sink=False):
 
     q = q_ref[0]  # [nkv, group, d]
 
-    def fold(k, v, valid):
-        """One online-softmax step over keys k / values v [nkv, nk, d] in the
-        queries' dtype; ``valid`` [1, 1, nk], or None where every key is the
-        row's."""
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        )  # [nkv, group, nk]
-        if valid is not None:
-            s = jnp.where(valid, s, NEG_INF)
-        m_p = m_scr[:, :, :1]  # col 0 meaningful
-        m_new = jnp.maximum(m_p, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_p - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[:, :, :1] = m_new
-
     # the pool holds the row's keys below ``limit`` (the explicit pool window
     # of the write-after-read protocol, else the causal <=; 0 for a padded
-    # query slot, which must see nothing). A block wholly inside the row's
+    # query slot, which must see nothing). A group wholly inside the row's
     # context takes no mask: all but a row's last (under a window, and its
     # first) are such
     limit = limit_ref[t]
-    live = bt_ref[t, slot] != trash_ref[0]
-    whole = live & ((slot + 1) * bs <= limit)
+    slots = [slot] + [jnp.minimum(slot + i, B - 1) for i in range(1, G)]
+    live = [bt_ref[t, s] != trash_ref[0] for s in slots]
+    whole = functools.reduce(jnp.logical_and, live) & ((slot + G) * bs <= limit)
     if window:
         from deepspeed_tpu.ops.attention.core import window_too_far
 
         whole = whole & (qpos - slot * bs < window)
 
-    def in_context(kpos):
-        ok = (kpos < limit) & live
+    def in_context(kpos, n):
+        """Which of the keys at ``kpos`` (the first ``n`` blocks' of the
+        group) are the row's."""
+        held = live[0]
+        for i in range(1, n):  # (Mosaic selects no booleans)
+            past = kpos >= (slot + i) * bs
+            held = (past & live[i]) | (jnp.logical_not(past) & held)
+        ok = (kpos < limit) & held
         if window:
             ok = ok & jnp.logical_not(window_too_far(qpos, kpos, window))
         return ok
 
-    def pool_block(masked):
-        k, v = k_ref[0], v_ref[0]  # [bs, nkv, d], as the pool stores them
-        if int8:
-            k = k.astype(jnp.float32) * ks_ref[0][..., None]
-            v = v.astype(jnp.float32) * vs_ref[0][..., None]
-        k = _head_major(k.astype(q.dtype), nkv, d)  # [nkv, bs, d]
-        v = jnp.swapaxes(v.astype(q.dtype), 0, 1)
+    def pool_blocks(n, masked):
+        """Fold the group's first ``n`` blocks, as the pool stores them."""
+        def joined(blocks, scales):
+            x = [r[0] for r in blocks[:n]]  # [bs, nkv, d] each
+            if int8:
+                x = [a.astype(jnp.float32) * s[0][..., None] for a, s in zip(x, scales)]
+            return (x[0] if n == 1 else jnp.concatenate(x, axis=0)).astype(q.dtype)
+
+        k, v = joined(k_refs, ks_refs), joined(v_refs, vs_refs)
+        k = _head_major(k, nkv, d)  # [nkv, n x bs, d]
+        v = jnp.swapaxes(v, 0, 1)
         valid = None
         if masked:
             # a masked key's weight is exactly 0, and 0 x NaN is not: what the
-            # block holds outside the row's context must not reach the sum
-            krow = slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs, 1), 1)
-            v = jnp.where(in_context(krow), v, jnp.zeros_like(v))
-            valid = in_context(slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bs), 2))
-        fold(k, v, valid)
+            # blocks hold outside the row's context must not reach the sum
+            krow = slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, n * bs, 1), 1)
+            v = jnp.where(in_context(krow, n), v, jnp.zeros_like(v))
+            valid = in_context(
+                slot * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, n * bs), 2), n)
+        _fold(q, k, v, valid, *state)
 
-    # a row that holds no block is one program: nothing of the pool to fold
-    holds = (flag & 1) != 0
-    pl.when(holds & whole)(lambda: pool_block(masked=False))
-    pl.when(holds & jnp.logical_not(whole))(lambda: pool_block(masked=True))
+    # a row that holds no block is one program: nothing of the pool to fold.
+    # Flag 1: the group's first block is the row's; 8, 16, ..: its second,
+    # third, .. are. A group that is not whole folds as many as it carries
+    has = [(flag & b) != 0 for b in _GROUP_BITS[:G]]
+    pl.when(has[0] & whole)(lambda: pool_blocks(G, masked=False))
+    ragged = jnp.logical_not(whole)
+    for n in range(1, G + 1):
+        carries = has[n - 1] if n == G else has[n - 1] & jnp.logical_not(has[n])
+        pl.when(carries & ragged)(functools.partial(pool_blocks, n, masked=True))
 
     @pl.when((flag & 4) != 0)
     def _finish():
@@ -305,8 +374,8 @@ def _paged_kernel(*refs, bs, nkv, d, E=0, window=0, int8=False, sink=False):
             valid = (epos >= 0) & (epos <= qpos)
             if window:
                 valid = valid & jnp.logical_not(window_too_far(qpos, epos, window))
-            fold(_head_major(ke_ref[0], nkv, d).astype(q.dtype),
-                 jnp.swapaxes(ve_ref[0], 0, 1).astype(q.dtype), valid)
+            _fold(q, _head_major(ke_ref[0], nkv, d).astype(q.dtype),
+                  jnp.swapaxes(ve_ref[0], 0, 1).astype(q.dtype), valid, *state)
         # fully-masked token (all-trash padding): m never left NEG_INF and
         # every p degenerated to exp(0) — emit 0, matching the reference
         m, l, acc = m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[...]
@@ -395,19 +464,37 @@ def paged_attention(
             f"(expected one of {PAGED_ATTENTION_IMPLS})"
         )
 
-    # kernel path; off-TPU it only runs interpreted (CPU tests)
+    return _paged_kernel_call(
+        q, k_cache, v_cache, block_tables, q_pos, trash_block, window=int(window), scale=scale,
+        k_scale=k_scale, v_scale=v_scale, extra_kv=extra_kv, pool_limit=pool_limit, sinks=sinks,
+        interpret=interpret)
+
+
+def _paged_kernel_call(q, k_cache, v_cache, block_tables, q_pos, trash_block, *, window=0,
+                       scale=None, k_scale=None, v_scale=None, extra_kv=None, pool_limit=None,
+                       sinks=None, interpret=False, blocks: Optional[int] = None):
+    """``paged_attention`` through ``dstpu_paged_decode`` (arguments as there,
+    already checked). ``blocks``: how many table slots a program reads; None,
+    what every caller but a test passes, is ``blocks_a_program`` of the pool's
+    own block."""
+    T, nh, d = q.shape
+    NB, bs, nkv, dv = v_cache.shape
+    flat = k_cache.ndim == 3  # keys a token a row (keys_flat)
+    int8_pool = k_cache.dtype == jnp.int8
+    # off the TPU the kernel only runs interpreted (CPU tests)
     interpret = bool(interpret) or not on_tpu()
     B = block_tables.shape[1]
     E = 0 if extra_kv is None else int(extra_kv[0].shape[1])
+    G = int(blocks) if blocks else blocks_a_program(bs * nkv * (d + dv) * k_cache.dtype.itemsize)
     q_pos = q_pos.astype(jnp.int32)
     if pool_limit is None:
         limit = q_pos + 1  # the causal <=
     else:
         limit = jnp.where(q_pos >= 0, jnp.asarray(pool_limit, jnp.int32).reshape(T), 0)
-    n_visits, vrow, vslot, vflag = _visit_list(q_pos, limit, bs, B, int(window))
+    n_programs, vrow, vslot, vflag, fetch = _visit_list(q_pos, limit, bs, B, int(window), G)
     group = nh // nkv
     # the queries go in scaled, a KV head's query heads together: the fold
-    # batches over the KV heads and scales nothing a visit
+    # batches over the KV heads and scales nothing a program
     qs = (q.astype(jnp.float32) * (scale if scale is not None else d**-0.5)).astype(q.dtype)
     qs = qs.reshape(T, nkv, group, d)
     k_row = (nkv, d)
@@ -415,16 +502,21 @@ def paged_attention(
         # a head's queries at its offset in its window of the row (_key_windows)
         qs, k_row = _queries_to_windows(qs, nkv, d), (nkv * d,)
 
-    # index maps see (g, bt, qpos, trash, limit, vrow, vslot, vflag): a row's
-    # operands follow vrow[g]; the pool's follow the table. A row that holds
-    # no block points at a slot of its table all the same (a padded row: the
-    # trash block, which the row before it already fetched if it was padded)
+    # index maps see (g, bt, qpos, trash, limit, vrow, vslot, vflag, then frow,
+    # fslot of the group's second block, its third, ..): a row's operands
+    # follow vrow[g]; the pool is given once a block of the group, block i
+    # through the table at (frow, fslot)[i], the first at (vrow, vslot). A row
+    # that holds no block points at a slot of its table all the same (a padded
+    # row: the trash block, which the row before it already fetched if it was
+    # padded)
     def per_row(*shape):
         return pl.BlockSpec((1,) + shape, lambda g, *s: (s[4][g],) + (0,) * len(shape))
 
     def per_block(*shape):
-        return pl.BlockSpec(
-            (1,) + shape, lambda g, *s: (s[0][s[4][g], s[5][g]],) + (0,) * len(shape))
+        at = [(4, 5)] + [(5 + 2 * i, 6 + 2 * i) for i in range(1, G)]  # (vrow, vslot), (frow, fslot)..
+        return [pl.BlockSpec(
+            (1,) + shape, lambda g, *s, r=r, c=c: (s[0][s[r][g], s[c][g]],) + (0,) * len(shape))
+            for r, c in at]
 
     in_specs = []
     if E:
@@ -432,9 +524,9 @@ def paged_attention(
         # block (last two dims must be (8, 128)-aligned or whole)
         in_specs.append(per_row(1, E))
     in_specs.append(per_row(*qs.shape[1:]))
-    in_specs.extend([per_block(bs, *k_row), per_block(bs, nkv, dv)])
+    in_specs.extend(per_block(bs, *k_row) + per_block(bs, nkv, dv))
     if int8_pool:
-        in_specs.extend([per_block(bs, nkv), per_block(bs, nkv)])
+        in_specs.extend(per_block(bs, nkv) + per_block(bs, nkv))
     if E:
         in_specs.extend([per_row(E, *k_row), per_row(E, nkv, dv)])
     if sinks is not None:
@@ -442,8 +534,8 @@ def paged_attention(
         in_specs.append(pl.BlockSpec((nkv, group, 128), lambda g, *s: (0, 0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
-        grid=(n_visits,),
+        num_scalar_prefetch=7 + len(fetch),
+        grid=(n_programs,),
         in_specs=in_specs,
         out_specs=per_row(nkv, group, dv),
         scratch_shapes=[
@@ -453,7 +545,7 @@ def paged_attention(
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, bs=bs, nkv=nkv, d=d, E=E, window=int(window), int8=int8_pool,
+        _paged_kernel, bs=bs, nkv=nkv, d=d, G=G, E=E, window=int(window), int8=int8_pool,
         sink=sinks is not None)
     operands = [
         block_tables.astype(jnp.int32),
@@ -463,13 +555,14 @@ def paged_attention(
         vrow,
         vslot,
         vflag,
+        *fetch,
     ]
     if E:
         operands.append(jnp.asarray(extra_kv[2], jnp.int32).reshape(T, 1, E))
     operands.append(qs)
-    operands.extend([k_cache, v_cache])
+    operands.extend([k_cache] * G + [v_cache] * G)
     if int8_pool:
-        operands.extend([k_scale, v_scale])
+        operands.extend([k_scale] * G + [v_scale] * G)
     if E:
         operands.extend([extra_kv[0].reshape((T, E) + k_row), extra_kv[1]])
     if sinks is not None:
@@ -479,8 +572,8 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, nkv, group, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            # one flat axis of visits: a row's programs follow one another
-            # and accumulate into the same scratch
+            # one flat axis of programs: a row's follow one another and
+            # accumulate into the same scratch
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
@@ -509,28 +602,48 @@ def kernels_take(kv_heads: int, head_dim: int, v_head_dim: Optional[int] = None)
             and (kv_heads in (2, 4) or kv_heads % 8 == 0))
 
 
-def _visit_list(q_pos, limit, bs: int, B: int, window: int):
+def _visit_list(q_pos, limit, bs: int, B: int, window: int, G: int = 1):
     """The programs of one kernel call, from what the call is given. Row
     ``t``'s context covers table slots ``lo..hi``: ``hi = ceil(limit / bs)``
     and ``lo`` the block of the first key a sliding ``window`` still admits
     (core.window_too_far: ``q_pos - window + 1``), else 0. Its programs are
-    those slots in order, the last of them also its finish; a row that holds
-    no block (a padded slot; a row whose only key is an extra column) is one
-    program that folds nothing of the pool. Returns (the number of programs,
-    then three [T * B] arrays): program ``g`` works for row ``vrow[g]`` on
-    table slot ``vslot[g]`` under ``vflag[g]``: 1 a pool block to fold, 2
-    the row's first program, 4 its last. Entries past the number of programs
-    are never run."""
+    those ``n`` slots in order, ``G`` to a program: ``ceil(n / G)`` of them,
+    the last also its finish, and the last alone may carry fewer than ``G``;
+    a row that holds no block (a padded slot; a row whose only key is an
+    extra column) is one program that folds nothing of the pool. Returns (the
+    number of programs, three [P] arrays, ``P = T x ceil(B / G)``, and a list
+    of ``2 (G - 1)`` more): program ``g`` works for row ``vrow[g]`` from table
+    slot ``vslot[g]`` on under ``vflag[g]``: 1 the slot holds a block to fold,
+    2 the row's first program, 4 its last, then 8, 16, .. where the second,
+    third, .. slot of the group holds one too. The list is ``frow, fslot`` of
+    the group's second block, then of its third, ..: the entry of the table
+    that block's index map reads. Where the row holds the block that is its
+    own slot; where it does not, the entry that block of a group pointed at
+    LAST (the row's program before, or an earlier row's), so the pipeline
+    finds the index unchanged and fetches nothing. Entries past the number of
+    programs are never run."""
     T = q_pos.shape[0]
     hi = jnp.clip((limit + bs - 1) // bs, 0, B)
     lo = jnp.maximum(q_pos - window + 1, 0) // bs if window else jnp.zeros_like(hi)
     n = jnp.maximum(hi - lo, 0)
-    cnt = jnp.maximum(n, 1)
-    n_visits, j, of_row = _programs_of(cnt, T * B)
+    cnt = jnp.maximum(-(-n // G), 1)
+    n_programs, j, of_row = _programs_of(cnt, T * -(-B // G))
     n_g, cnt_g, lo_g = of_row(n), of_row(cnt), of_row(lo)
-    vslot = jnp.clip(lo_g + j, 0, B - 1)
-    flags = (j < n_g) + 2 * (j == 0) + 4 * (j == cnt_g - 1)
-    return n_visits, of_row(jnp.arange(T, dtype=jnp.int32)), vslot, flags.astype(jnp.int32)
+    row = of_row(jnp.arange(T, dtype=jnp.int32))
+    first = lo_g + j * G
+    flags = (j * G < n_g) + 2 * (j == 0) + 4 * (j == cnt_g - 1)
+    fetch = []
+    for i in range(1, G):
+        held = j * G + i < n_g
+        flags = flags + _GROUP_BITS[i] * held
+        # the entry block i pointed at last, a row: its last slot of ordinal i
+        # in a group, in the latest row down to this one that has such a slot
+        # (row x B + slot grows with the row: a running maximum finds it)
+        last = jnp.arange(T, dtype=jnp.int32) * B + lo + (n - 1 - i) // G * G + i
+        last = jnp.maximum(jax.lax.cummax(jnp.where(n > i, last, -1)), 0)
+        last_g = of_row(last)
+        fetch += [jnp.where(held, row, last_g // B), jnp.where(held, first + i, last_g % B)]
+    return n_programs, row, jnp.clip(first, 0, B - 1), flags.astype(jnp.int32), fetch
 
 
 def _programs_of(cnt, G: int):

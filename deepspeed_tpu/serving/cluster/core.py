@@ -224,6 +224,7 @@ class EngineCore:
             self._inc("steps_with_prefill_total")
         self._inc("paged_live_blocks_total", held("paged_live_blocks"))
         self._inc("paged_table_slots_total", held("paged_table_slots"))
+        self._inc("paged_programs_total", held("paged_programs"))
         self._inc("chunk_live_blocks_total", held("chunk_live_blocks"))
         self._inc("chunk_table_slots_total", held("chunk_table_slots"))
         moe = getattr(stats, "moe", None)

@@ -126,9 +126,12 @@ class ServingMetrics:
             "steps_with_prefill_total": 0,
             # decode attention (EngineCore._count_step): blocks the decode
             # rows' contexts cover against the slots of their block tables,
-            # one layer's kernel calls of every step or round
+            # one layer's kernel calls of every step or round, and the
+            # kernel's programs that read those blocks (live blocks over
+            # programs: how many blocks a program read)
             "paged_live_blocks_total": 0,
             "paged_table_slots_total": 0,
+            "paged_programs_total": 0,
             # chunk attention (EngineCore._count_step): key blocks the chunk
             # rows hold (pool blocks below a chunk + the chunk's own) against
             # the slots of whole tables and whole chunks, one layer a step

@@ -318,11 +318,42 @@ def test_the_paged_kernels_compile_for_a_v5e_at_a_key_of_192_and_a_value_of_128(
 
     pool_bytes = L * (NB + 1) * bs * nkv * d * 2
     assert PP.keys_flat(d) and PP.kernels_take(nkv, d, dv)
+    # the decode kernel is given each pool as often as a program reads blocks:
+    # four of the full layers' 320 KiB, two of the window layers' 640 KiB
+    assert PP.blocks_a_program(bs * nkv * (d + dv) * 2) == {4: 4, 8: 2}[nkv]
     for comp in compiled((nkv * d,)):       # as the engine stores them
         assert comp.as_text().count("tpu_custom_call") == 1
         assert comp.memory_analysis().temp_size_in_bytes < pool_bytes // 8
     # the control: a row a head, and the K pool is copied (and padded to 256 lanes) a call
     assert compiled((nkv, d))[0].memory_analysis().temp_size_in_bytes > pool_bytes
+
+
+def test_the_paged_decode_kernel_compiles_for_a_v5e_with_the_pool_given_twice(
+        one_chip, on_the_chip, monkeypatch):
+    """``dstpu_paged_decode`` at Qwen3-1.7B's 16 heads over 8 of 128, the cell's
+    32 rows of 32 slots: a program reads TWO blocks of 512 KiB, so the call is
+    given the K pool and the V pool twice each, with two index maps over the
+    one table. One Mosaic call, and no pool is copied for being given twice."""
+    from deepspeed_tpu.ops.attention import paged_pallas as PP
+
+    monkeypatch.setattr(PP, "on_tpu", lambda: True)
+    nh, nkv, d, bs, L, NB, R, B = 16, 8, 128, 128, 28, 300, 32, 32
+    assert PP.blocks_a_program(bs * nkv * 2 * d * 2) == 2
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(q, kc, vc, tab, pos, ke, ve):
+        flat = lambda pool: pool.reshape((-1,) + pool.shape[2:])
+        return PP.paged_attention(q, flat(kc), flat(vc), tab, pos, NB, impl="kernel",
+                                  extra_kv=(ke, ve, pos[:, None]), pool_limit=pos)
+
+    pool = S((L, NB + 1, bs, nkv, d))
+    comp = jax.jit(decode).lower(
+        S((R, nh, d)), pool, pool, S((R, B), jnp.int32), S((R,), jnp.int32),
+        S((R, 1, nkv, d)), S((R, 1, nkv, d))).compile()
+    assert comp.as_text().count("tpu_custom_call") == 1
+    assert comp.memory_analysis().temp_size_in_bytes < L * (NB + 1) * bs * nkv * d * 2 // 8
 
 
 # (configuration, cell, the kernels its decode-only step calls, the projections

@@ -376,8 +376,9 @@ def test_paged_kernel_scale_override():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("G", [None, 1, 2])  # (None: what the pool's block gives, 4 here)
 @pytest.mark.parametrize("int8", [False, True])
-def test_paged_kernel_extra_kv_matches_dense(int8):
+def test_paged_kernel_extra_kv_matches_dense(int8, G):
     """The kernel's extras grid step (write-after-read decode form: pre-write
     pool + per-row extra tokens + pool_limit cap) must match the dense path,
     whose own correctness vs the post-write oracle is pinned above."""
@@ -403,10 +404,16 @@ def test_paged_kernel_extra_kv_matches_dense(int8):
     ref = paged_attention(
         q, pk, pv, jnp.asarray(bt), jnp.asarray(qpos), trash, impl="dense", **kw
     )
-    out = paged_attention(
-        q, pk, pv, jnp.asarray(bt), jnp.asarray(qpos), trash,
-        impl="kernel", interpret=True, **kw,
-    )
+    if G is None:
+        from deepspeed_tpu.ops.attention.paged_pallas import blocks_a_program
+
+        assert blocks_a_program(bs * nkv * 2 * d * pk.dtype.itemsize) == 4
+        out = paged_attention(
+            q, pk, pv, jnp.asarray(bt), jnp.asarray(qpos), trash,
+            impl="kernel", interpret=True, **kw,
+        )
+    else:
+        out = _kernel(q, pk, pv, jnp.asarray(bt), jnp.asarray(qpos), trash, G, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -431,12 +438,24 @@ def test_paged_attention_impl_and_scale_validation():
 # the kernel visits only the blocks a row's context covers: ragged rows in
 # one call, the bounds at a block's edges, and what lies outside a context
 # ---------------------------------------------------------------------------
-from deepspeed_tpu.ops.attention.paged_pallas import _visit_list
+from deepspeed_tpu.ops.attention.paged_pallas import _paged_kernel_call, _visit_list
 
 RAGGED_BS, RAGGED_B = 16, 4
 # tokens the pool holds for each row: nothing, one, a block less one, a whole
-# block, a block and one, a full table; the last row is an inactive slot
-RAGGED_CONTEXTS = (0, 1, RAGGED_BS - 1, RAGGED_BS, RAGGED_BS + 1, RAGGED_B * RAGGED_BS)
+# block, a block and one, two blocks and a part, a full table (0, 1, 1, 1, 2, 3
+# and 4 blocks: every residue of a program's 2 and of its 4); the last row is
+# an inactive slot
+RAGGED_CONTEXTS = (0, 1, RAGGED_BS - 1, RAGGED_BS, RAGGED_BS + 1, 2 * RAGGED_BS + 5,
+                   RAGGED_B * RAGGED_BS)
+# blocks a program of dstpu_paged_decode reads (paged_pallas.blocks_a_program
+# gives a pool's; a test's small blocks would all read 4)
+PROGRAM_BLOCKS = (1, 2, 4)
+
+
+def _kernel(q, kc, vc, bt, qpos, trash, G, **kw):
+    """``paged_attention(impl="kernel", interpret=True)`` with ``G`` blocks a
+    program: the kernel call's private argument."""
+    return _paged_kernel_call(q, kc, vc, bt, qpos, trash, interpret=True, blocks=G, **kw)
 
 
 def _ragged_call(rng, nh, nkv, d, int8=False):
@@ -458,12 +477,13 @@ def _ragged_call(rng, nh, nkv, d, int8=False):
     return q, pools, kw, bt, ctx, trash
 
 
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("form", ["plain", "split", "split-int8"])
 # (16, 2, 256): Qwen3-Next's full-attention layers, 8 query heads a KV head
 @pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (4, 1, 64), (16, 2, 256)])
-def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form):
-    """Contexts 0, 1, bs-1, bs, bs+1 and a full table beside an inactive slot
-    in ONE call. ``plain``: the query is the context's last token, against
+def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form, G):
+    """Contexts 0, 1, bs-1, bs, bs+1, 2 bs + 5 and a full table beside an
+    inactive slot in ONE call, ``G`` blocks a program. ``plain``: the query is the context's last token, against
     the per-token reference. ``split``: the engine's split-step form (the pool
     holds the context, the query's own K/V rides as the extra column),
     against the dense form; ``split-int8`` the same over an int8 pool."""
@@ -475,8 +495,7 @@ def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form):
         ref = paged_attention_reference(
             q, pk, pv, jnp.asarray(np.where(ctx[:, None] > 0, bt, trash)),
             jnp.maximum(qpos, 0), trash)
-        out = paged_attention(q, pk, pv, jnp.asarray(bt), qpos, trash,
-                              impl="kernel", interpret=True)
+        out = _kernel(q, pk, pv, jnp.asarray(bt), qpos, trash, G)
         for t in np.flatnonzero(ctx <= 0):
             np.testing.assert_array_equal(np.asarray(out[t]), 0.0)
     else:
@@ -486,8 +505,7 @@ def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form):
                   pool_limit=jnp.asarray(np.maximum(ctx, 0)))
         ref = paged_attention(q, pk, pv, jnp.asarray(bt), jnp.asarray(ctx), trash,
                               impl="dense", **kw)
-        out = paged_attention(q, pk, pv, jnp.asarray(bt), jnp.asarray(ctx), trash,
-                              impl="kernel", interpret=True, **kw)
+        out = _kernel(q, pk, pv, jnp.asarray(bt), jnp.asarray(ctx), trash, G, **kw)
         # context 0 sees its own token alone; the inactive slot sees nothing
         np.testing.assert_allclose(
             np.asarray(out[0]), np.repeat(np.asarray(ve[0, 0]), nh // nkv, axis=0), atol=1e-6)
@@ -495,38 +513,40 @@ def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("form", ["plain", "split"])
-def test_paged_kernel_window_starts_past_block_zero(form):
+def test_paged_kernel_window_starts_past_block_zero(form, G):
     """A window whose first block in band is not block 0: the kernel starts
-    its walk there, and a row still inside the window starts at 0."""
+    its walk there, whatever ``G`` (a group starts at the row's first slot,
+    not at a multiple of ``G``), and a row still inside the window starts at
+    0. Rows of 2, 2, 2, 1 and 3 blocks in band."""
     rng = np.random.default_rng(21)
-    T, nh, nkv, d, bs, NB, B, window = 4, 4, 2, 64, 16, 17, 4, 20
+    T, nh, nkv, d, bs, NB, B, window = 5, 4, 2, 64, 16, 26, 5, 20
     trash = NB - 1
     q = jnp.asarray(rng.normal(size=(T, nh, d)), jnp.float32)
     kc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
     vc = jnp.asarray(rng.normal(size=(NB, bs, nkv, d)), jnp.float32)
     bt = jnp.asarray(np.arange(T * B, dtype=np.int32).reshape(T, B))
-    qpos = np.array([60, 36, 35, 7], np.int32)  # first block in band: 2, 1, 1, 0
-    _, vrow, vslot, _ = _visit_list(jnp.asarray(qpos), jnp.asarray(qpos + 1), bs, B, window)
+    qpos = np.array([60, 36, 35, 7, 66], np.int32)  # first block in band: 2, 1, 1, 0, 2
+    _, vrow, vslot, _, _ = _visit_list(jnp.asarray(qpos), jnp.asarray(qpos + 1), bs, B, window, G)
     first = [int(vslot[np.flatnonzero(np.asarray(vrow) == t)[0]]) for t in range(T)]
-    assert first == [2, 1, 1, 0]
+    assert first == [2, 1, 1, 0, 2]
     if form == "plain":
         ref = paged_attention_reference(q, kc, vc, bt, jnp.asarray(qpos), trash, window=window)
-        out = paged_attention(q, kc, vc, bt, jnp.asarray(qpos), trash, impl="kernel",
-                              interpret=True, window=window)
+        out = _kernel(q, kc, vc, bt, jnp.asarray(qpos), trash, G, window=window)
     else:
         ke = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
         ve = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
         kw = dict(extra_kv=(ke, ve, jnp.asarray(qpos[:, None])), pool_limit=jnp.asarray(qpos),
                   window=window)
         ref = paged_attention(q, kc, vc, bt, jnp.asarray(qpos), trash, impl="dense", **kw)
-        out = paged_attention(q, kc, vc, bt, jnp.asarray(qpos), trash, impl="kernel",
-                              interpret=True, **kw)
+        out = _kernel(q, kc, vc, bt, jnp.asarray(qpos), trash, G, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("int8", [False, True])
-def test_paged_kernel_verify_round_per_token_form(int8):
+def test_paged_kernel_verify_round_per_token_form(int8, G):
     """The speculative verify round's call: T = R x K1 queries, each carrying
     its ROW's table and pool window, the row's K1 fresh K/V as shared extra
     columns whose mask is the in-chunk causal one; padded slots (q_pos -1)
@@ -552,7 +572,7 @@ def test_paged_kernel_verify_round_per_token_form(int8):
               pool_limit=rep(pos0))
     args = (q, pk, pv, rep(tables), jnp.asarray(qpos.reshape(-1).astype(np.int32)), trash)
     ref = paged_attention(*args, impl="dense", **kw)
-    out = paged_attention(*args, impl="kernel", interpret=True, **kw)
+    out = _kernel(*args, G, **kw)
     # the dense form has no padded-slot convention of its own (the engine's
     # alternative there is paged_chunk_attention): compare the live slots
     live = qpos.reshape(-1) >= 0
@@ -560,12 +580,16 @@ def test_paged_kernel_verify_round_per_token_form(int8):
     np.testing.assert_array_equal(np.asarray(out)[~live], 0.0)
 
 
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("row", range(len(RAGGED_CONTEXTS) + 1))
 @pytest.mark.parametrize("form", ["plain", "split"])
-def test_paged_kernel_ignores_what_a_row_does_not_hold(form, row):
-    """NaN in every pool block row ``row`` does not hold, in the trash block,
+def test_paged_kernel_ignores_what_a_row_does_not_hold(form, row, G):
+    """NaN in every pool block row ``row`` does not hold (the absent partners
+    of its last group among them: the blocks its table names past its
+    context, and whatever those operands fetched last), in the trash block,
     and in the rows of its last block at or beyond its context: the row's
-    output is finite and equal to the clean pool's, bit for bit."""
+    output is finite, equal to the clean pool's bit for bit and to the
+    reference's."""
     rng = np.random.default_rng(23)
     nh, nkv, d, bs = 8, 4, 64, RAGGED_BS
     q, (kc, vc), _, bt, ctx, trash = _ragged_call(rng, nh, nkv, d)
@@ -587,43 +611,98 @@ def test_paged_kernel_ignores_what_a_row_does_not_hold(form, row):
         kw = dict(extra_kv=(ke, ve, jnp.asarray(ctx[:, None])),
                   pool_limit=jnp.asarray(np.maximum(ctx, 0)))
         qpos = jnp.asarray(ctx)
-    run = lambda k, v: np.asarray(paged_attention(
-        q, k, v, jnp.asarray(bt), qpos, trash, impl="kernel", interpret=True, **kw))[row]
+    run = lambda k, v: np.asarray(_kernel(q, k, v, jnp.asarray(bt), qpos, trash, G, **kw))[row]
     clean, dirty = run(kc, vc), run(poisoned(kc), poisoned(vc))
     assert np.isfinite(dirty).all()
     np.testing.assert_array_equal(dirty, clean)
     if form == "plain" and c == 0:
         np.testing.assert_array_equal(dirty, 0.0)
+    elif form == "split":  # (the plain reference has no query for an empty context)
+        ref = paged_attention(q, kc, vc, jnp.asarray(bt), qpos, trash, impl="dense", **kw)
+        np.testing.assert_allclose(dirty, np.asarray(ref)[row], atol=2e-5)
+    else:
+        ref = paged_attention_reference(q, kc, vc, jnp.asarray(bt), qpos, trash)
+        np.testing.assert_allclose(dirty, np.asarray(ref)[row], atol=2e-5)
 
 
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("window", [0, 20])
-def test_visit_list_against_a_hand_count(window):
+def test_visit_list_against_a_hand_count(window, G):
     """The kernel's programs, from the bounds alone: each row's slots
-    lo..hi in order, ``n`` programs for a row that holds ``n`` blocks and one
-    for a row that holds none, flagged 1 where there is a block to fold, 2 on
-    the row's first program and 4 on its last."""
-    bs, B = 16, 4
-    qpos = np.array([-1, 0, 15, 16, 40, 63, 63], np.int32)
-    limit = np.array([0, 1, 16, 17, 40, 64, 0], np.int32)  # the last row: an empty pool window
-    n, vrow, vslot, vflag = _visit_list(jnp.asarray(qpos), jnp.asarray(limit), bs, B, window)
-    want = []
+    lo..hi in order, ``G`` to a program: ``ceil(n / G)`` programs for a row
+    that holds ``n`` blocks and one for a row that holds none, each from its
+    first slot on, flagged 1 / 8 / 16 / 32 where the group's first / second /
+    third / fourth slot holds a block to fold (so the count of live blocks it
+    carries), 2 on the row's first program and 4 on its last; and where each
+    block of a group after the first points: its own slot where the row holds
+    it, else where that block pointed last."""
+    from deepspeed_tpu.ops.attention.paged_pallas import _GROUP_BITS
+
+    bs, B = 16, 5
+    qpos = np.array([-1, 0, 15, 16, 40, 79, 63, 70], np.int32)
+    limit = np.array([0, 1, 16, 17, 40, 80, 0, 70], np.int32)  # row 6: an empty pool window
+    n, vrow, vslot, vflag, fetch = _visit_list(jnp.asarray(qpos), jnp.asarray(limit), bs, B, window, G)
+    assert len(fetch) == 2 * (G - 1)
+    want, points = [], []
+    last = [(0, 0)] * G  # where block i of a group pointed last: (row, slot)
     for t, (p, lim) in enumerate(zip(qpos, limit)):
         hi = min(-(-int(lim) // bs), B)
         lo = max(int(p) - window + 1, 0) // bs if window else 0
         slots = list(range(lo, hi))
-        if not slots:  # one program all the same: the extra columns, or zeros
-            want.append((t, min(lo, B - 1), 2 | 4))
-        for i, s in enumerate(slots):
-            want.append((t, s, 1 | (2 if i == 0 else 0) | (4 if i == len(slots) - 1 else 0)))
+        groups = [slots[i:i + G] for i in range(0, len(slots), G)] or [[]]
+        for j, grp in enumerate(groups):
+            flag = (2 if j == 0 else 0) | (4 if j == len(groups) - 1 else 0)
+            for i, s_ in enumerate(grp):
+                flag |= _GROUP_BITS[i]
+                last[i] = (t, s_)
+            # one program all the same for a row of nothing: the extra columns, or zeros
+            want.append((t, grp[0] if grp else min(lo, B - 1), flag))
+            points.append(list(last[1:]))
     assert int(n) == len(want)
     got = [np.asarray(a)[: len(want)].tolist() for a in (vrow, vslot, vflag)]
     assert list(zip(*got)) == want
-    assert vrow.shape == vslot.shape == vflag.shape == (len(qpos) * B,)
-    # by hand, window 0: rows 0 and 6 hold nothing, row 5 its whole table
-    if not window:
-        assert int(n) == 1 + 1 + 1 + 2 + 3 + 4 + 1
+    for i in range(1, G):
+        got = list(zip(np.asarray(fetch[2 * i - 2])[: len(want)].tolist(),
+                       np.asarray(fetch[2 * i - 1])[: len(want)].tolist()))
+        assert got == [pt[i - 1] for pt in points], i
+    programs = len(qpos) * -(-B // G)
+    assert all(a.shape == (programs,) for a in (vrow, vslot, vflag, *fetch))
+    live = [bin(f >> 3).count("1") + (f & 1) for _, _, f in want]
+    assert all(1 <= c <= G for c, (_, _, f) in zip(live, want) if f & 1)
+    # by hand, window 0: rows 0 and 6 hold nothing, row 5 its whole table of 5
+    if not window and G == 1:
+        assert int(n) == 1 + 1 + 1 + 2 + 3 + 5 + 1 + 5
         assert want[:3] == [(0, 0, 6), (1, 0, 7), (2, 0, 7)]
         assert want[3:5] == [(3, 0, 3), (3, 1, 5)]
+    if not window and G == 2:
+        # rows of 0, 1, 1, 2, 3, 5, 0, 5 blocks: 1, 1, 1, 1, 2, 3, 1, 3 programs
+        assert int(n) == 13 and live == [0, 1, 1, 2, 2, 1, 2, 2, 1, 0, 2, 2, 1]
+        assert want[3] == (3, 0, 1 | 8 | 2 | 4)                        # two blocks, first and last
+        assert want[4:6] == [(4, 0, 1 | 8 | 2), (4, 2, 1 | 4)]        # three: a pair, then one
+        assert want[6:9] == [(5, 0, 11), (5, 2, 9), (5, 4, 5)]
+        # the second block of a group: nothing to point at until row 3 holds one (0, 0);
+        # row 4's lone third block leaves it at (4, 1), row 6's nothing at row 5's (5, 3)
+        assert [pt[0] for pt in points] == [
+            (0, 0), (0, 0), (0, 0), (3, 1), (4, 1), (4, 1), (5, 1), (5, 3), (5, 3), (5, 3),
+            (7, 1), (7, 3), (7, 3)]
+
+
+@pytest.mark.parametrize("nkv,dk,dv,itemsize,want", [
+    (16, 128, 128, 2, 1),   # OLMoE: a block of K and V is a megabyte already
+    (8, 128, 128, 2, 2),    # Qwen3, K-EXAONE's both kinds: 512 KiB
+    (8, 192, 128, 2, 2),    # MiMo-V2-Flash's window layers: 640 KiB
+    (4, 192, 128, 2, 4),    # ... and its full layers: 320 KiB
+    (2, 256, 256, 2, 4),    # Qwen3-Next's full layers: 256 KiB
+    (8, 128, 128, 1, 4),    # an int8 pool of Qwen3's: its payload alone, 256 KiB
+    (32, 128, 128, 2, 1),   # past a megabyte: one
+], ids=["olmoe", "qwen3", "mimo_window", "mimo_full", "qwen3_next", "qwen3_int8", "two_mib"])
+def test_blocks_a_program_at_the_cells_geometries(nkv, dk, dv, itemsize, want):
+    """As many 128-token pool blocks as make a megabyte, four at most: the
+    rule's one constant is set from kernel-alone times at these geometries
+    (paged_pallas.PROGRAM_BYTES; PERF.md section 6, PR 46)."""
+    from deepspeed_tpu.ops.attention.paged_pallas import blocks_a_program
+
+    assert blocks_a_program(128 * nkv * (dk + dv) * itemsize) == want
 
 
 # the cells' three geometries (Qwen3 / K-EXAONE's KV side, OLMoE, Qwen3-Next)
@@ -674,8 +753,9 @@ def test_paged_kernel_bf16_pool_against_float32_reference(nh, nkv, d, E):
     np.testing.assert_array_equal(np.asarray(out, np.float32)[dead], 0.0)
 
 
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("case", ["only the extra column", "exactly one block", "all trash"])
-def test_paged_kernel_rows_of_one_program(case):
+def test_paged_kernel_rows_of_one_program(case, G):
     """A row whose first visit is its last. ``only the extra column``: an
     empty pool and E = 1, the first token after a prompt of a block's
     multiple was written elsewhere: the output is that column's value.
@@ -701,10 +781,10 @@ def test_paged_kernel_rows_of_one_program(case):
         ctx = np.array([-1, -1, -1], np.int32)
     kw = dict(extra_kv=(ke, ve, jnp.asarray(ctx[:, None])), pool_limit=jnp.asarray(np.maximum(ctx, 0)))
     args = (q, kc, vc, jnp.asarray(bt), jnp.asarray(ctx), trash)
-    n, vrow, _, vflag = _visit_list(jnp.asarray(ctx), kw["pool_limit"], bs, B, 0)
+    n, vrow, _, vflag, _ = _visit_list(jnp.asarray(ctx), kw["pool_limit"], bs, B, 0, G)
     assert int(n) == T and np.asarray(vrow)[:T].tolist() == [0, 1, 2]
     assert np.asarray(vflag)[:T].tolist() == [7 if case == "exactly one block" else 6] * T
-    out = np.asarray(paged_attention(*args, impl="kernel", interpret=True, **kw))
+    out = np.asarray(_kernel(*args, G, **kw))
     if case == "all trash":
         np.testing.assert_array_equal(out, 0.0)
         return
@@ -947,8 +1027,9 @@ def _geometry_case(nkv, sink, flat, window, seed=0):
 
 @pytest.mark.parametrize("flat", [False, True], ids=["row_a_head", "token_a_row"])
 @pytest.mark.parametrize("sink", [False, True], ids=["no_sink", "sink"])
+@pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("nkv,window", [(4, 0), (8, 16)], ids=["4_heads_full", "8_heads_window"])
-def test_paged_kernels_at_a_key_of_192_and_a_value_of_128(nkv, window, sink, flat):
+def test_paged_kernels_at_a_key_of_192_and_a_value_of_128(nkv, window, sink, flat, G):
     """``dstpu_paged_decode`` and ``dstpu_paged_chunk`` (interpreted) and their
     dense forms against ``paged_attention_reference`` at keys of 192 beside
     values of 128, 4 and 8 KV heads, with and without the heads' sinks in the
@@ -961,20 +1042,21 @@ def test_paged_kernels_at_a_key_of_192_and_a_value_of_128(nkv, window, sink, fla
     dv, bs = vc.shape[-1], vc.shape[1]
     ref = paged_attention_reference(q, kc, vc, tb, pos, NB, window=window, sinks=sinks)
     assert ref.shape == (T, nh, dv)
-    for impl in ("dense", "kernel"):
-        out = paged_attention(q, kc, vc, tb, pos, NB, impl=impl, interpret=True, window=window,
-                              sinks=sinks)
+    for out in (paged_attention(q, kc, vc, tb, pos, NB, impl="dense", window=window, sinks=sinks),
+                _kernel(q, kc, vc, tb, pos, NB, G, window=window, sinks=sinks)):
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
     if sink:  # the sink takes mass: the same call without it reads otherwise
-        bare = paged_attention(q, kc, vc, tb, pos, NB, impl="kernel", interpret=True, window=window)
+        bare = _kernel(q, kc, vc, tb, pos, NB, G, window=window)
         assert float(jnp.abs(bare - ref).max()) > 1e-2
     rng = np.random.default_rng(1)
     ke = jnp.asarray(rng.normal(size=(T, 1, nkv, d)), jnp.float32)
     ve = jnp.asarray(rng.normal(size=(T, 1, nkv, dv)), jnp.float32)
     kw = dict(window=window, sinks=sinks, extra_kv=(ke, ve, pos[:, None]), pool_limit=pos)
     np.testing.assert_allclose(
-        np.asarray(paged_attention(q, kc, vc, tb, pos, NB, impl="kernel", interpret=True, **kw)),
+        np.asarray(_kernel(q, kc, vc, tb, pos, NB, G, **kw)),
         np.asarray(paged_attention(q, kc, vc, tb, pos, NB, impl="dense", **kw)), atol=2e-5)
+    if G != PROGRAM_BLOCKS[0]:
+        return  # (the chunk kernel has no such parameter: once)
     # prompt chunks: two rows of two blocks, one below a pool context, one from position 0
     Rc, tq = 2, 2 * bs
     qc = jnp.asarray(rng.normal(size=(Rc, tq, nh, d)), jnp.float32)

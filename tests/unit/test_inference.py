@@ -494,6 +494,29 @@ class TestInferenceV2:
         assert step.chunk_table_slots == rows * (32 + 10)
         assert (step.paged_live_blocks, step.paged_table_slots) == (2, 4 * 32)
 
+    @pytest.mark.parametrize("G", [1, 2, 4])
+    def test_paged_programs_are_the_rows_blocks_by_groups(self, tiny_model, G):
+        """``_count_paged``: a row of ``n`` blocks is ``ceil(n / G)`` programs
+        of the decode kernel, ``G`` what the kernel's own rule gives the
+        engine's block pool: an inactive slot and a row of nothing count none,
+        rows of 1 and of G blocks one, a row of G + 1 two; ``calls`` walks of
+        the rows multiply all three numbers. Blocks of 16 tokens, tables of 8."""
+        from deepspeed_tpu.ops.attention.paged_pallas import blocks_a_program
+
+        cfg, params = tiny_model
+        engine = self._engine(cfg, params)
+        # 16 tokens x 2 KV heads x (16 + 16) wide x 4 bytes: far below a megabyte
+        assert engine._blocks_a_program == blocks_a_program(16 * 2 * 32 * 4) == 4
+        engine._blocks_a_program = G
+        tokens = np.array([-1, 0, 1, 16 * G, 16 * G + 1])
+        blocks = 0 + 0 + 1 + G + (G + 1)
+        assert engine._count_paged(tokens) == {
+            "paged_live_blocks": blocks, "paged_table_slots": 5 * 8, "paged_programs": 1 + 1 + 2}
+        assert engine._count_paged(tokens, calls=3) == {
+            "paged_live_blocks": 3 * blocks, "paged_table_slots": 3 * 5 * 8, "paged_programs": 3 * 4}
+        engine._blocks_a_program = 0  # a latent pool: another kernel's walks
+        assert engine._count_paged(tokens)["paged_programs"] == 0
+
     @pytest.mark.parametrize("entry", ["step_tokens", "step_tokens_experts", "decode_round",
                                        "spec_round"])
     def test_step_stats_filled_by(self, tiny_model, entry):
@@ -533,17 +556,20 @@ class TestInferenceV2:
             # ... waited for where it was launched: not ahead, no row dropped
             assert engine.last_step == StepStats(
                 4, 1, 0, 2, 4 * 8, moe, kv_global_blocks=2, kv_context_tokens=21,
+                paged_programs=1,  # (blocks this small: four to a program)
                 ahead=False, ahead_rows_dropped=0)
         elif entry == "decode_round":
             assert len(engine.decode_round(3)[0]) == 3
             # a round's 3 kernel calls a layer walk the round-start window
             assert engine.last_step == StepStats(
-                4 * 3, 3, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=23)
+                4 * 3, 3, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=23,
+                paged_programs=3)
         else:
             assert 1 <= len(engine.spec_round(2, drafts={0: [5]})[0]) <= 2
             # the pending token and one draft on a grid of R x (k + 1)
             assert engine.last_step == StepStats(
-                4 * 3, 2, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=22)
+                4 * 3, 2, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=22,
+                paged_programs=3)
         assert engine.last_step is not prefill and prefill.prefill_tokens == 20
         # a step with nothing to schedule starts from zeros again
         engine.scheduler.finish(0)

@@ -207,26 +207,30 @@ class TestGridCounters:
         assert c["steps_with_prefill_total"] == 1
 
 
-    @pytest.mark.parametrize("decode_steps,work,live,slots", [
+    @pytest.mark.parametrize("decode_steps,work,live,slots,programs", [
         # the three steps of the first test. Decode rows: none; one whose pool
         # holds 200 tokens = 13 blocks of 16; that row at 201 (13) and one at
-        # 20 (2). Every step's tables have R x B = 4 x 32 slots
-        (1, [(200, 3), (20, 2)], 0 + 13 + (13 + 2), 3 * 128),
+        # 20 (2). Every step's tables have R x B = 4 x 32 slots. Blocks this
+        # small go four to a program: 13 blocks are 4 programs, 2 are one
+        (1, [(200, 3), (20, 2)], 0 + 13 + (13 + 2), 3 * 128, 0 + 4 + (4 + 1)),
         # a prefill step, then two fused rounds of 4 steps: the row's pool
         # window stays at the round's start (20, then 24 tokens: 2 blocks),
         # and each of a round's 4 kernel calls a layer walks it again
-        (4, [(20, 9)], 0 + 4 * 2 + 4 * 2, 128 + 2 * 4 * 128),
+        (4, [(20, 9)], 0 + 4 * 2 + 4 * 2, 128 + 2 * 4 * 128, 0 + 4 * 1 + 4 * 1),
     ])
     def test_paged_counters_are_live_blocks_over_table_slots(
-            self, tiny_model, decode_steps, work, live, slots):
+            self, tiny_model, decode_steps, work, live, slots, programs):
         """``ceil(pool tokens / block size)`` summed over the decode rows
-        against ``R x B``, for one layer's decode attention calls."""
+        against ``R x B``, for one layer's decode attention calls, and the
+        kernel's programs that read those blocks."""
         driver, _ = _serve(_engine(tiny_model, decode_steps=decode_steps), work,
                            **({"decode_steps": decode_steps} if decode_steps > 1 else {}))
         c = driver.metrics.counters
         assert c["paged_live_blocks_total"] == live
         assert c["paged_table_slots_total"] == slots
+        assert c["paged_programs_total"] == programs
         assert f"paged_table_slots_total {slots}" in driver.metrics.prometheus_text()
+        assert f"paged_programs_total {programs}" in driver.metrics.prometheus_text()
 
 
     def test_chunk_counters_are_held_blocks_over_whole_walks(self, tiny_model):
