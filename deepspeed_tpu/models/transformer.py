@@ -20,9 +20,11 @@ TPU-first design decisions:
   * Activations carry ``with_sharding_constraint`` on [batch, seq, hidden]:
     batch over data/expert, seq over sequence (Ulysses), hidden replicated.
   * Attention dispatches to the Pallas flash kernel (ops/attention) on TPU.
-  * ``remat``: per-layer ``jax.checkpoint`` with a dots-saveable policy —
-    the activation-checkpointing analogue (runtime/activation_checkpointing/
-    checkpointing.py:488) without RNG state juggling (jax threads RNG keys).
+  * ``remat``: per-layer ``jax.checkpoint``; the default policy keeps the
+    outputs of the layer's matrix products and the attention kernel's output
+    and log-sum-exp (``remat_policy``) — the activation-checkpointing
+    analogue (runtime/activation_checkpointing/checkpointing.py:488) without
+    RNG state juggling (jax threads RNG keys).
   * Sequence parallelism: when the mesh's ``sequence`` axis > 1 the attention
     runs under Ulysses all-to-all (parallel/sequence/ulysses.py), scattering
     heads and gathering sequence exactly like the reference
@@ -1179,27 +1181,42 @@ def param_partition_specs(config: TransformerConfig) -> Dict[str, Any]:
 # forward
 # ---------------------------------------------------------------------------
 def remat_policy(name: str):
-    """Map a config name to a jax.checkpoint policy. Memory/recompute trade,
-    cheapest-memory first: nothing < dots_with_no_batch_dims < dots <
-    everything (no recompute; remat becomes a no-op barrier)."""
+    """Map a config name to a ``jax.checkpoint`` policy: what a remat'd layer
+    keeps for its backward. The four ``jax.checkpoint`` sites that take a
+    policy (two in ``forward_hidden``, two in ``runtime.pipe.pipeline``) all
+    take it from here. Least memory (most recomputed) first:
+
+    * ``nothing``: the layer's input alone; the backward runs the whole layer
+      again, the attention kernel's forward included.
+    * ``flash``: only the attention kernel's output and log-sum-exp (tagged
+      ``flash_out`` / ``flash_lse`` in ``ops.attention.flash_pallas._flash_fwd``,
+      ``sharded`` and ``splash_pallas``): ``b·nh·s·(d·2 + 4)`` bytes a layer in
+      bf16 (16 MiB + 256 KiB a 4,096-token sequence at 16 heads of 128). The
+      backward re-runs every matrix product but never the kernel's forward.
+    * ``flash_qkv``: also the rope'd q / k / v that feed the kernel, so the
+      backward skips the three projections and the rope too.
+    * ``dots_with_no_batch_dims`` (the default) and ``dots``: the output of
+      every matrix product of the layer (q, k, v, ``wo``, gate, up, down: ~10
+      activations of q's width) AND the kernel's ``flash_out`` / ``flash_lse``.
+      The kernel is a ``pallas_call``, not a product: a policy of products
+      alone drops its result and the backward of every layer runs the flash
+      forward a second time to rebuild it. Keeping it costs one more
+      activation of q's width a layer; a layer whose attention is plain XLA
+      carries no tag and keeps what it kept.
+    * ``everything``: no recompute; remat becomes a no-op barrier.
+
+    A job that no longer fits takes ``flash``, ``nothing`` or a smaller
+    micro-batch."""
+    cp = jax.checkpoint_policies
+    flash = cp.save_only_these_names("flash_out", "flash_lse")
     policies = {
-        "nothing": jax.checkpoint_policies.nothing_saveable,
-        # "flash": save ONLY the attention output + LSE (tagged in
-        # ops.attention.flash_pallas._flash_fwd) — backward recomputes the
-        # cheap elementwise work but never re-runs the flash forward kernel.
-        # Costs b·h·s·(d·2+4) bytes/layer (~37 MB at the bench config).
-        "flash": jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse"
-        ),
-        # "flash_qkv" additionally saves the rope'd q/k/v feeding the kernel,
-        # so the backward skips the qkv projections + rope recompute too
-        # (+74 MB/layer at the bench config on top of "flash").
-        "flash_qkv": jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse", "flash_qkv"
-        ),
-        "dots_with_no_batch_dims": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        "dots": jax.checkpoint_policies.dots_saveable,
-        "everything": jax.checkpoint_policies.everything_saveable,
+        "nothing": cp.nothing_saveable,
+        "flash": flash,
+        "flash_qkv": cp.save_only_these_names("flash_out", "flash_lse", "flash_qkv"),
+        "dots_with_no_batch_dims": cp.save_from_both_policies(
+            cp.dots_with_no_batch_dims_saveable, flash),
+        "dots": cp.save_from_both_policies(cp.dots_saveable, flash),
+        "everything": cp.everything_saveable,
     }
     if name not in policies:
         raise ValueError(f"remat_policy must be one of {sorted(policies)}, got {name!r}")
@@ -2037,8 +2054,10 @@ def forward_hidden(
     """Body forward: tokens [b, s] → (final-norm'd hidden [b, s, h], aux_loss).
 
     Layers run under ``lax.scan`` over the stacked layer pytree; with
-    ``config.remat`` each layer is rematerialized (dots saveable) so
-    activation memory is O(1) in depth.
+    ``config.remat`` each layer is rematerialized and keeps only what
+    ``remat_policy(config.remat_policy)`` names (by default its products'
+    outputs and the attention kernel's result), so the backward recomputes
+    the elementwise work and no product or kernel.
     """
     c = config
     b, s = tokens.shape

@@ -514,8 +514,9 @@ def _flash_fwd(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interp
     out, lse = _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret)
     # Residual LSE is narrowed to one lane (it is lane-broadcast) so saving it
     # costs b·h·s·4 bytes, not ×LANES; the backward re-broadcasts. The names
-    # feed the "flash" remat policy (models.transformer.remat_policy): saving
-    # out+lse means a remat'd layer skips re-running the attention forward.
+    # feed the remat policies (models.transformer.remat_policy: every one but
+    # "nothing" keeps them): saving out+lse means a remat'd layer skips
+    # re-running the attention forward.
     from jax.ad_checkpoint import checkpoint_name
 
     out = checkpoint_name(out, "flash_out")

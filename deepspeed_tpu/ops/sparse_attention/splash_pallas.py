@@ -382,7 +382,9 @@ def _splash_vjp_fwd(q, k, v, seg, kvi, kind, kvi_t, kind_t, base, params):
     from jax.ad_checkpoint import checkpoint_name
 
     out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    # one lane of the lane-broadcast plane, as flash_pallas._flash_fwd keeps it:
+    # a remat policy that saves the tag pays b·h·s·4 bytes, not x LANES
+    lse = checkpoint_name(lse[..., :1], "flash_lse")
     q = checkpoint_name(q, "flash_qkv")
     k = checkpoint_name(k, "flash_qkv")
     v = checkpoint_name(v, "flash_qkv")
@@ -391,6 +393,7 @@ def _splash_vjp_fwd(q, k, v, seg, kvi, kind, kvi_t, kind_t, base, params):
 
 def _splash_vjp_bwd(params: _SplashParams, res, g):
     q, k, v, seg, kvi, kind, kvi_t, kind_t, base, out, lse = res
+    lse = jnp.broadcast_to(lse, lse.shape[:-1] + (LANES,))
     b, h, sq, d = q.shape
     h_kv, sk = k.shape[1], k.shape[2]
     group = h // h_kv

@@ -178,9 +178,7 @@ def make_pipelined_loss_fn(
         x, aux = state
         layer = functools.partial(T._layer, c)
         if c.remat:
-            layer = jax.checkpoint(
-                layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            )
+            layer = jax.checkpoint(layer, policy=T.remat_policy(c.remat_policy))
 
         def body(carry, lp):
             h, a = carry
@@ -388,9 +386,7 @@ class Pipelined1F1BLoss:
         def run_stage(sp, state, seg, pos):
             layer = functools.partial(T._layer, c)
             if c.remat:
-                layer = jax.checkpoint(
-                    layer, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                )
+                layer = jax.checkpoint(layer, policy=T.remat_policy(c.remat_policy))
 
             def body(carry, lp):
                 h, a = carry
