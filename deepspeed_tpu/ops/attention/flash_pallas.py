@@ -21,11 +21,30 @@ resident, lifting the former ~8k dense cap):
     ``preferred_element_type=jnp.float32``.
   * LSE is stored lane-broadcast as [b, h, s, LANES] to satisfy the TPU
     (8, 128) tiling rule for output blocks.
-  * Backward: flash recompute — per-block p = exp(qk·scale − lse). dq
-    streams kv blocks (grid (b, h, nq, nk)); dk/dv streams q/do/o/lse
-    blocks (grid (b, h, nk, nq)); both carry fp32 scratch accumulators.
-    delta = Σ do·o is computed in-kernel from the saved output.
+  * Backward: flash recompute — per-block p = exp(qk·scale − lse), ONE
+    kernel (``dstpu_flash_bwd_fused``: the dk/dv kernel's body with dq beside
+    it, grid (b, h, nk, nq), q/do/o/lse streamed): a block pair's scores,
+    masks, p, dp and ds are built once and feed all three sums, five MXU
+    products a pair. dk/dv accumulate in
+    [bk, d] fp32 scratch over the q blocks; the head's whole dq accumulates
+    in [s, d] fp32 scratch that stays in VMEM across the kv blocks and
+    leaves as q.dtype once. delta = Σ do·o is computed in-kernel from the
+    saved output, once a pair.
+  * The residency budget: that form runs where s·d·4 bytes fit
+    ``DQ_RESIDENT_BYTES`` (2 MiB: s ≤ 4096 at d = 128). A longer sequence
+    takes the two streaming kernels it fuses, which also serve the ring:
+    dq (``dstpu_flash_bwd_dq``, grid (b, h, nq, nk), kv streamed) and dk/dv
+    (``dstpu_flash_bwd_dkv``, grid (b, h, nk, nq)); each rebuilds the pair's
+    scores and dp, seven products a pair, and nothing sequence-sized is
+    resident. Which one is read off the shape, and the sums are the same in
+    the same order: dq[i] over kv blocks ascending, dk[j]/dv[j] over q blocks
+    ascending, so the three gradients are bit-identical either way (and to
+    the ring's chunked stream).
   * GQA: kv-head index map h → h // (nh/nkv); no head replication in HBM.
+    The backward writes dk/dv per QUERY head and sums the group outside the
+    kernel (cast to the input dtype, then an fp32 sum): the ring's hand-over
+    (``flash_dkv_finalize``) sums its per-head partials in that order, and
+    its bitwise parity with one call rests on it.
 
 Numerics validated against ops.attention.mha_reference in
 tests/unit/ops/test_flash_attention.py (interpret mode on CPU), including a
@@ -47,6 +66,7 @@ LANES = 128
 FLASH_FWD = "dstpu_flash_fwd"
 FLASH_BWD_DQ = "dstpu_flash_bwd_dq"
 FLASH_BWD_DKV = "dstpu_flash_bwd_dkv"
+FLASH_BWD_FUSED = "dstpu_flash_bwd_fused"
 FLASH_FWD_CHUNK = "dstpu_flash_fwd_chunk"
 FLASH_BWD_DQ_CHUNK = "dstpu_flash_bwd_dq_chunk"
 FLASH_BWD_DKV_CHUNK = "dstpu_flash_bwd_dkv_chunk"
@@ -74,10 +94,54 @@ def _apply_window(logits, window, wflag_ref, q_pos, k_pos):
     return jnp.where(far, NEG_INF, logits)
 
 
+def _pair_logits(q, k, qi, ki, scale, causal, bq, bk, window, seg_q_ref=None,
+                 seg_k_ref=None, alibi_ref=None, kpos_ref=None, wflag_ref=None):
+    """Masked logits [bq, bk] f32 of one block pair (q block ``qi``, kv block
+    ``ki``): scale * q k^T, the ALiBi term, then the causal, window and
+    segment masks. The forward and every backward kernel build the pair's
+    scores through this one body; the optional refs are a kernel's
+    ``**mask_refs``."""
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    if alibi_ref is not None:
+        logits = logits + _alibi_term(alibi_ref, kpos_ref)
+    if causal:
+        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+        if window:
+            logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
+    if seg_q_ref is not None:
+        logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
+    return logits
+
+
+def _kv_block_active(qi, ki, causal, bq, bk, window, mask_refs):
+    """Does q block ``qi`` see kv block ``ki``? The pairs a q-major grid
+    (forward, dq) computes; the rest cost grid overhead only."""
+    hi = (qi * bq + bq - 1) // bk  # last kv block a causal q block touches
+    active = (ki <= hi) if causal else (ki >= 0)
+    if window and mask_refs.get("wflag_ref") is None:
+        # static window (every layer banded): prune kv blocks fully behind it
+        active = jnp.logical_and(active, ki >= jnp.maximum(0, qi * bq - window + 1) // bk)
+    return active
+
+
+def _q_block_active(ki, qj, causal, bq, bk, window, mask_refs):
+    """The same set of pairs, asked from the kv block's side (the kv-major
+    grids: dk/dv and the fused backward)."""
+    lo = (ki * bk) // bq  # first q block that sees this kv block
+    active = (qj >= lo) if causal else (qj >= 0)
+    if window and mask_refs.get("wflag_ref") is None:
+        # last q block inside the band for this kv block
+        active = jnp.logical_and(active, qj <= (ki * bk + bk - 1 + window - 1) // bq)
+    return active
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                scale, causal, bq, bk, nk, window=0, seg_q_ref=None,
-                seg_k_ref=None, alibi_ref=None, kpos_ref=None, wflag_ref=None,
-                m_in_ref=None, l_in_ref=None, acc_in_ref=None, l_out_ref=None):
+                scale, causal, bq, bk, nk, window=0, m_in_ref=None,
+                l_in_ref=None, acc_in_ref=None, l_out_ref=None, **mask_refs):
     # q_ref: [bq, d]; k_ref/v_ref: [bk, d] (one streamed block);
     # o_ref: [bq, d]; lse_ref: [bq, LANES]; scratch m/l: [bq, LANES] f32,
     # acc: [bq, d] f32 — carried across the minor (kv) grid dimension.
@@ -102,30 +166,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
             l_ref[:] = l_in_ref[:]
             acc_ref[:] = acc_in_ref[:].astype(jnp.float32)
 
-    hi = (qi * bq + bq - 1) // bk  # last kv block a causal q block touches
-    active = (ki <= hi) if causal else (ki >= 0)
-    if window and wflag_ref is None:
-        # static window (every layer banded): prune kv blocks fully behind it
-        active = jnp.logical_and(active, ki >= jnp.maximum(0, qi * bq - window + 1) // bk)
-
-    @pl.when(active)
+    @pl.when(_kv_block_active(qi, ki, causal, bq, bk, window, mask_refs))
     def _step():
         q = q_ref[:]
         k = k_ref[:]
         v = v_ref[:]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk] fp32
-        if alibi_ref is not None:
-            logits = logits + _alibi_term(alibi_ref, kpos_ref)
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-            if window:
-                logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
-        if seg_q_ref is not None:
-            logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
+        logits = _pair_logits(q, k, qi, ki, scale, causal, bq, bk, window, **mask_refs)
         m = m_ref[:, 0]
         l = l_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
@@ -157,9 +203,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
                    delta_ref, dq_acc_ref, *, scale, causal, bq, bk, nk,
-                   window=0, seg_q_ref=None, seg_k_ref=None, alibi_ref=None,
-                   kpos_ref=None, wflag_ref=None, dq_in_ref=None,
-                   raw_out=False):
+                   window=0, dq_in_ref=None, raw_out=False, **mask_refs):
     # Carry mode (ring bwd): ``dq_in_ref`` seeds the accumulator from the
     # previous chunk's partial and ``raw_out`` flushes it unscaled in f32 —
     # the ring applies `* scale` once after the last chunk, exactly like the
@@ -178,31 +222,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
         )
         delta_ref[:] = jnp.broadcast_to(delta[:, None], delta_ref.shape)
 
-    hi = (qi * bq + bq - 1) // bk
-    active = (ki <= hi) if causal else (ki >= 0)
-    if window and wflag_ref is None:
-        active = jnp.logical_and(active, ki >= jnp.maximum(0, qi * bq - window + 1) // bk)
-
-    @pl.when(active)
+    @pl.when(_kv_block_active(qi, ki, causal, bq, bk, window, mask_refs))
     def _step():
         q = q_ref[:]
         k = k_ref[:]
         v = v_ref[:]
         do = do_ref[:]
         lse = lse_ref[:, 0]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        if alibi_ref is not None:
-            logits = logits + _alibi_term(alibi_ref, kpos_ref)
-        if causal:
-            q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-            if window:
-                logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
-        if seg_q_ref is not None:
-            logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
+        logits = _pair_logits(q, k, qi, ki, scale, causal, bq, bk, window, **mask_refs)
         p = jnp.exp(logits - lse[:, None])  # [bq, bk]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -223,11 +250,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
                     dv_ref, dk_acc_ref, dv_acc_ref, *, scale, causal, bq, bk,
-                    nq, window=0, seg_q_ref=None, seg_k_ref=None,
-                    alibi_ref=None, kpos_ref=None, wflag_ref=None,
-                    dk_in_ref=None, dv_in_ref=None, raw_out=False):
+                    nq, window=0, dk_in_ref=None, dv_in_ref=None, raw_out=False,
+                    dq_ref=None, dq_acc_ref=None, nk=None, **mask_refs):
     # Carry mode mirrors _bwd_dq_kernel: seed accumulators from the previous
     # chunk's partials, flush raw f32 when ``raw_out``.
+    #
+    # Fused mode (``dq_ref``: the head's [s, d] output block; ``dq_acc_ref``:
+    # [nq, bq, d] f32 scratch that stays in VMEM across the kv blocks): the
+    # pair's scores, masks, p, dp and ds, built ONCE, feed dq too, five
+    # products a pair where this kernel and the dq kernel do seven. Block
+    # ``qj`` of the accumulator sums over the kv blocks ascending, exactly as
+    # the dq kernel's does.
     ki = pl.program_id(2)
     qj = pl.program_id(3)
 
@@ -240,13 +273,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
             dk_acc_ref[:] = dk_in_ref[:]
             dv_acc_ref[:] = dv_in_ref[:]
 
-    lo = (ki * bk) // bq  # first q block that sees this kv block
-    active = (qj >= lo) if causal else (qj >= 0)
-    if window and wflag_ref is None:
-        # last q block inside the band for this kv block
-        active = jnp.logical_and(active, qj <= (ki * bk + bk - 1 + window - 1) // bq)
+    if dq_ref is not None:
+        @pl.when(ki == 0)
+        def _init_dq():
+            dq_acc_ref[qj] = jnp.zeros(dq_acc_ref.shape[1:], dq_acc_ref.dtype)
 
-    @pl.when(active)
+    @pl.when(_q_block_active(ki, qj, causal, bq, bk, window, mask_refs))
     def _step():
         k = k_ref[:]
         v = v_ref[:]
@@ -260,19 +292,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
         delta = jnp.sum(
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
         )  # [bq]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
-        if alibi_ref is not None:
-            logits = logits + _alibi_term(alibi_ref, kpos_ref)
-        if causal:
-            q_pos = qj * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-            if window:
-                logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
-        if seg_q_ref is not None:
-            logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
+        logits = _pair_logits(q, k, qj, ki, scale, causal, bq, bk, window, **mask_refs)
         p = jnp.exp(logits - lse[:, None])
         dv_acc_ref[:] = dv_acc_ref[:] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -286,6 +306,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if dq_ref is not None:
+            dq_acc_ref[qj] = dq_acc_ref[qj] + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [bq, d]
 
     @pl.when(qj == nq - 1)
     def _flush():
@@ -296,6 +321,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
             # scale moved onto the logits, so dk picks it up (dlogits/dk = scale*q)
             dk_ref[:] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
             dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+    if dq_ref is not None:
+        @pl.when(ki == nk - 1)
+        def _flush_dq():
+            # the last kv block has passed: q block qj's sum is complete
+            rows = pl.ds(pl.multiple_of(qj * bq, bq), bq)
+            dq_ref[rows, :] = (dq_acc_ref[qj] * scale).astype(dq_ref.dtype)
+
+
+# The fused backward (``_bwd_dkv_kernel`` with ``dq_ref``) keeps a head's whole
+# dq accumulator ([s, d] f32) in VMEM. It runs where that fits this many bytes
+# (s <= 4096 at d = 128, 8192 at 64); a longer sequence takes the dq kernel and
+# the dk/dv kernel without it, which hold one block each.
+# At that budget the kernel fits the 16 MiB scoped-VMEM default with every mask
+# operand present (the described v5e compiles it inside 12 MiB at s = 4096,
+# d = 128, blocks of 1024), so the call raises no limit.
+DQ_RESIDENT_BYTES = 2 * 1024 * 1024
 
 
 def _pick_block(s, target=None):
@@ -447,6 +489,23 @@ def _wflag_specs(wflag):
     return [wflag], [pl.BlockSpec((1, LANES), lambda b_, h_, i, j: (0, 0))]
 
 
+def _pop_mask_refs(rest, seg_ops, alibi_ops, wf_ops=()):
+    """Split a kernel entry's trailing refs into the kernel's ``**mask_refs``
+    (present in the order the ``*_specs`` helpers above append their operands)
+    and what follows them: the call's outputs and scratch."""
+    rest = list(rest)
+    kw = {}
+    if seg_ops:
+        kw["seg_q_ref"] = rest.pop(0).at[0]
+        kw["seg_k_ref"] = rest.pop(0).at[0]
+    if alibi_ops:
+        kw["alibi_ref"] = rest.pop(0)
+        kw["kpos_ref"] = rest.pop(0).at[0]
+    if wf_ops:
+        kw["wflag_ref"] = rest.pop(0)
+    return kw, rest
+
+
 def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret):
     b, h, s, d = q.shape
     h_kv = k.shape[1]
@@ -466,17 +525,8 @@ def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, inter
     wf_ops, wf_specs = _wflag_specs(wflag)
 
     def entry(qr, kr, vr, *rest):
-        rest = list(rest)
-        kw = {}
-        if seg_ops:
-            kw["seg_q_ref"] = rest.pop(0).at[0]
-            kw["seg_k_ref"] = rest.pop(0).at[0]
-        if alibi_ops:
-            kw["alibi_ref"] = rest.pop(0)
-            kw["kpos_ref"] = rest.pop(0).at[0]
-        if wf_ops:
-            kw["wflag_ref"] = rest.pop(0)
-        orf, lr, mref, lref, aref = rest
+        kw, (orf, lr, mref, lref, aref) = _pop_mask_refs(
+            rest, seg_ops, alibi_ops, wf_ops)
         kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
                lr.at[0, 0], mref, lref, aref, **kw)
 
@@ -536,51 +586,60 @@ def _flash_bwd(causal, scale, window, interpret, res, g):
     h_kv = k.shape[1]
     group = h // h_kv
     scale_v = scale if scale is not None else d ** -0.5
+    args = (q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale_v,
+            window, interpret)
+    # Which kernels is read off the shape: one pass where a head's dq
+    # accumulator may stay in VMEM, the dq and dk/dv kernels above that.
+    # Same sums in the same order either way.
+    if s * d * 4 <= DQ_RESIDENT_BYTES:
+        dk_h, dv_h, dq = _bwd_dkv_call(*args, with_dq=True)
+    else:
+        dq = _bwd_dq_call(*args)
+        dk_h, dv_h = _bwd_dkv_call(*args, with_dq=False)
+    # dk/dv come per q-head and are reduced over the GQA group here, outside
+    # the kernel: the ring's hand-over (flash_dkv_finalize) sums the same way.
+    if group > 1:
+        dk = jnp.sum(dk_h.reshape(b, h_kv, group, s, d).astype(jnp.float32), axis=2).astype(k.dtype)
+        dv = jnp.sum(dv_h.reshape(b, h_kv, group, s, d).astype(jnp.float32), axis=2).astype(v.dtype)
+    else:
+        dk, dv = dk_h, dv_h
+    return dq, dk, dv, None, None, None  # no cotangent for segment_ids / alibi / wflag
+
+
+def _bwd_dq_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
+                 window, interpret):
+    """dq from the dq kernel: q-major, kv blocks streamed."""
+    b, h, s, d = q.shape
+    group = h // k.shape[1]
     bq = _pick_block(s)
     bk = _pick_block(s)
     nq, nk = s // bq, s // bk
-    static_w = wflag is None
-    jc = _kv_clamp(causal, bq, bk, window, static_window=static_w)
-    qc = _q_clamp(causal, bq, bk, window, static_window=static_w, nq=nq)
+    jc = _kv_clamp(causal, bq, bk, window, static_window=wflag is None)
 
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale_v, causal=causal, bq=bq, bk=bk, nk=nk,
+    kernel = functools.partial(
+        _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk,
         window=window,
     )
-
     seg_ops, seg_specs = _seg_specs(segment_ids, bq, lambda i, j: i, bk, jc)
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, jc)
     wf_ops, wf_specs = _wflag_specs(wflag)
 
-    def dq_entry(qr, kr, vr, orf, dor, lr, *rest):
-        rest = list(rest)
-        kw = {}
-        if seg_ops:
-            kw["seg_q_ref"] = rest.pop(0).at[0]
-            kw["seg_k_ref"] = rest.pop(0).at[0]
-        if alibi_ops:
-            kw["alibi_ref"] = rest.pop(0)
-            kw["kpos_ref"] = rest.pop(0).at[0]
-        if wf_ops:
-            kw["wflag_ref"] = rest.pop(0)
-        dqr, dref, aref = rest
-        dq_kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
-                  dor.at[0, 0], lr.at[0, 0], dqr.at[0, 0], dref, aref, **kw)
+    def entry(qr, kr, vr, orf, dor, lr, *rest):
+        kw, (dqr, dref, aref) = _pop_mask_refs(rest, seg_ops, alibi_ops, wf_ops)
+        kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
+               dor.at[0, 0], lr.at[0, 0], dqr.at[0, 0], dref, aref, **kw)
 
-    dq = pl.pallas_call(
-        dq_entry,
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, d),
+                           lambda b_, h_, i, j: (b_, h_ // group, jc(i, j), 0))
+    return pl.pallas_call(
+        entry,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // group, jc(i, j), 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // group, jc(i, j), 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            q_spec, kv_spec, kv_spec, q_spec, q_spec,
             pl.BlockSpec((1, 1, bq, LANES), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ] + seg_specs + alibi_specs + wf_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),  # delta
@@ -590,66 +649,63 @@ def _flash_bwd(causal, scale, window, interpret, res, g):
         name=FLASH_BWD_DQ,
     )(q, k, v, out, g, lse, *seg_ops, *alibi_ops, *wf_ops)
 
-    # dk/dv computed per q-head (reduced over the GQA group after), with the
-    # q/do/o/lse stream minor so one [bk, d] kv block stays resident.
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale_v, causal=causal, bq=bq, bk=bk, nq=nq,
-        window=window,
+
+def _bwd_dkv_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
+                  window, interpret, with_dq):
+    """(per-q-head dk, per-q-head dv) from the dk/dv kernel: kv-major, with
+    the q/do/o/lse stream minor so one [bk, d] kv block stays resident.
+    ``with_dq``: the fused backward, (dk, dv, dq) from that one pass, the
+    head's whole dq accumulated in VMEM beside them."""
+    b, h, s, d = q.shape
+    group = h // k.shape[1]
+    bq = _pick_block(s)
+    bk = _pick_block(s)
+    nq, nk = s // bq, s // bk
+    qc = _q_clamp(causal, bq, bk, window, static_window=wflag is None, nq=nq)
+
+    kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nq=nq,
+        nk=nk, window=window,
     )
-    dkv_seg_ops, dkv_seg_specs = _seg_specs(segment_ids, bq, qc, bk, lambda i, j: i)
-    # dk/dv grid is kv-major: the key-position block follows the kv index i
-    dkv_alibi_ops, dkv_alibi_specs = _alibi_specs(alibi, bk, lambda i, j: i)
+    seg_ops, seg_specs = _seg_specs(segment_ids, bq, qc, bk, lambda i, j: i)
+    # the grid is kv-major: the key-position block follows the kv index i
+    alibi_ops, alibi_specs = _alibi_specs(alibi, bk, lambda i, j: i)
+    wf_ops, wf_specs = _wflag_specs(wflag)
 
-    def dkv_entry(qr, kr, vr, orf, dor, lr, *rest):
-        rest = list(rest)
-        kw = {}
-        if dkv_seg_ops:
-            kw["seg_q_ref"] = rest.pop(0).at[0]
-            kw["seg_k_ref"] = rest.pop(0).at[0]
-        if dkv_alibi_ops:
-            kw["alibi_ref"] = rest.pop(0)
-            kw["kpos_ref"] = rest.pop(0).at[0]
-        if wf_ops:
-            kw["wflag_ref"] = rest.pop(0)
-        dkr, dvr, dka, dva = rest
-        dkv_kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
-                   dor.at[0, 0], lr.at[0, 0], dkr.at[0, 0], dvr.at[0, 0],
-                   dka, dva, **kw)
+    def entry(qr, kr, vr, orf, dor, lr, *rest):
+        kw, rest = _pop_mask_refs(rest, seg_ops, alibi_ops, wf_ops)
+        if with_dq:
+            dkr, dvr, dqr, dka, dva, dqa = rest
+            kw.update(dq_ref=dqr.at[0, 0], dq_acc_ref=dqa)
+        else:
+            dkr, dvr, dka, dva = rest
+        kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
+               dor.at[0, 0], lr.at[0, 0], dkr.at[0, 0], dvr.at[0, 0],
+               dka, dva, **kw)
 
-    dk_h, dv_h = pl.pallas_call(
-        dkv_entry,
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, qc(i, j), 0))
+    kv_in_spec = pl.BlockSpec((1, 1, bk, d),
+                              lambda b_, h_, i, j: (b_, h_ // group, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+    # the head's whole dq: written back once, when the head changes
+    dq_spec = pl.BlockSpec((1, 1, s, d), lambda b_, h_, i, j: (b_, h_, 0, 0))
+    return pl.pallas_call(
+        entry,
         grid=(b, h, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, qc(i, j), 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_ // group, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_ // group, i, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, qc(i, j), 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, qc(i, j), 0)),
+            q_spec, kv_in_spec, kv_in_spec, q_spec, q_spec,
             pl.BlockSpec((1, 1, bq, LANES),
                          lambda b_, h_, i, j: (b_, h_, qc(i, j), 0)),
-        ] + dkv_seg_specs + dkv_alibi_specs + wf_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        ],
+        ] + seg_specs + alibi_specs + wf_specs,
+        out_specs=[kv_spec, kv_spec] + [dq_spec] * with_dq,
+        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)] * (2 + with_dq),
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),  # dk accumulator
             pltpu.VMEM((bk, d), jnp.float32),  # dv accumulator
-        ],
+        ] + [pltpu.VMEM((nq, bq, d), jnp.float32)] * with_dq,  # the head's dq
         interpret=interpret,
-        name=FLASH_BWD_DKV,
-    )(q, k, v, out, g, lse, *dkv_seg_ops, *dkv_alibi_ops, *wf_ops)
-
-    if group > 1:
-        dk = jnp.sum(dk_h.reshape(b, h_kv, group, s, d).astype(jnp.float32), axis=2).astype(k.dtype)
-        dv = jnp.sum(dv_h.reshape(b, h_kv, group, s, d).astype(jnp.float32), axis=2).astype(v.dtype)
-    else:
-        dk, dv = dk_h, dv_h
-    return dq, dk, dv, None, None, None  # no cotangent for segment_ids / alibi / wflag
+        name=FLASH_BWD_FUSED if with_dq else FLASH_BWD_DKV,
+    )(q, k, v, out, g, lse, *seg_ops, *alibi_ops, *wf_ops)
 
 
 _flash_core.defvjp(_flash_fwd, _flash_bwd)
@@ -740,21 +796,12 @@ def flash_fwd_chunk(q, k, v, carry, segment_ids=None, alibi=None,
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, jc)
 
     def entry(qr, kr, vr, mir, lir, air, *rest):
-        rest = list(rest)
-        kw = {
-            "m_in_ref": mir.at[0, 0],
-            "l_in_ref": lir.at[0, 0],
-            "acc_in_ref": air.at[0, 0],
-        }
-        if seg_ops:
-            kw["seg_q_ref"] = rest.pop(0).at[0]
-            kw["seg_k_ref"] = rest.pop(0).at[0]
-        if alibi_ops:
-            kw["alibi_ref"] = rest.pop(0)
-            kw["kpos_ref"] = rest.pop(0).at[0]
-        aor, mor, lor, mref, lref, aref = rest
+        kw, (aor, mor, lor, mref, lref, aref) = _pop_mask_refs(
+            rest, seg_ops, alibi_ops)
         kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], aor.at[0, 0],
-               mor.at[0, 0], mref, lref, aref, l_out_ref=lor.at[0, 0], **kw)
+               mor.at[0, 0], mref, lref, aref, m_in_ref=mir.at[0, 0],
+               l_in_ref=lir.at[0, 0], acc_in_ref=air.at[0, 0],
+               l_out_ref=lor.at[0, 0], **kw)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
     lane_spec = pl.BlockSpec((1, 1, bq, LANES), lambda b_, h_, i, j: (b_, h_, i, 0))
@@ -817,17 +864,10 @@ def flash_dq_chunk(q, k, v, out, do, lse, dq_acc, segment_ids=None,
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, jc)
 
     def entry(qr, kr, vr, orf, dor, lr, dqi, *rest):
-        rest = list(rest)
-        kw = {"dq_in_ref": dqi.at[0, 0]}
-        if seg_ops:
-            kw["seg_q_ref"] = rest.pop(0).at[0]
-            kw["seg_k_ref"] = rest.pop(0).at[0]
-        if alibi_ops:
-            kw["alibi_ref"] = rest.pop(0)
-            kw["kpos_ref"] = rest.pop(0).at[0]
-        dqr, dref, aref = rest
+        kw, (dqr, dref, aref) = _pop_mask_refs(rest, seg_ops, alibi_ops)
         kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
-               dor.at[0, 0], lr.at[0, 0], dqr.at[0, 0], dref, aref, **kw)
+               dor.at[0, 0], lr.at[0, 0], dqr.at[0, 0], dref, aref,
+               dq_in_ref=dqi.at[0, 0], **kw)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bk, d),
@@ -877,18 +917,10 @@ def flash_dkv_chunk(q, k, v, out, do, lse, dk_acc, dv_acc, segment_ids=None,
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, lambda i, j: i)
 
     def entry(qr, kr, vr, orf, dor, lr, dki, dvi, *rest):
-        rest = list(rest)
-        kw = {"dk_in_ref": dki.at[0, 0], "dv_in_ref": dvi.at[0, 0]}
-        if seg_ops:
-            kw["seg_q_ref"] = rest.pop(0).at[0]
-            kw["seg_k_ref"] = rest.pop(0).at[0]
-        if alibi_ops:
-            kw["alibi_ref"] = rest.pop(0)
-            kw["kpos_ref"] = rest.pop(0).at[0]
-        dkr, dvr, dka, dva = rest
+        kw, (dkr, dvr, dka, dva) = _pop_mask_refs(rest, seg_ops, alibi_ops)
         kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
                dor.at[0, 0], lr.at[0, 0], dkr.at[0, 0], dvr.at[0, 0],
-               dka, dva, **kw)
+               dka, dva, dk_in_ref=dki.at[0, 0], dv_in_ref=dvi.at[0, 0], **kw)
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, qc(i, j), 0))
     kv_in_spec = pl.BlockSpec((1, 1, bk, d),

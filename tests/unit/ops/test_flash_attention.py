@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.ops.attention import mha_reference
+from deepspeed_tpu.ops.attention import flash_pallas, mha_reference
 from deepspeed_tpu.ops.attention.flash_pallas import flash_attention
 
 
@@ -402,3 +402,79 @@ def test_window_gqa_segments_combo(monkeypatch):
     out = flash_attention(q, k, v, True, seg, None, True, window=64)
     ref = mha_reference(q, k, v, causal=True, segment_ids=seg, window=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the backward: one kernel where a head's dq accumulator may stay in VMEM,
+# the dq and dk/dv kernels above that; same sums in the same order
+# ---------------------------------------------------------------------------
+_BWD_VARIANTS = {
+    "plain": {},
+    "gqa": {"h_kv": 2},
+    "segments": {"segments": True},
+    "alibi": {"alibi": True},
+    "window": {"window": 160},           # straddles the 128 blocks: pruning + masks
+    "window_flag0": {"window": 160, "window_flag": 0},
+    "window_flag1": {"window": 160, "window_flag": 1},
+}
+
+
+@pytest.mark.parametrize("causal, variant", [
+    (causal, name) for causal in (True, False) for name in _BWD_VARIANTS
+    if causal or "window" not in name])     # a window needs causal
+def test_fused_backward_equals_the_two_kernels_bitwise(monkeypatch, causal, variant):
+    """dq, dk, dv of ``dstpu_flash_bwd_fused`` against those of
+    ``dstpu_flash_bwd_dq`` + ``dstpu_flash_bwd_dkv`` at one block size (128 at
+    s = 512: a 4 x 4 grid, so every accumulator sums over several blocks):
+    equal to the last bit, whatever masks the pair carries."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", "128")
+    spec = dict(_BWD_VARIANTS[variant])
+    q, k, v = _qkv(b=1, h=4, h_kv=spec.pop("h_kv", None), s=512, d=64)
+    g = jax.random.normal(jax.random.key(11), q.shape, q.dtype)
+    kw = {}
+    if spec.pop("segments", False):
+        kw["segment_ids"] = _packed_segments(1, 512, n_seg=3)
+    if spec.pop("alibi", False):
+        kw["alibi_slopes"] = jnp.asarray(alibi_slopes(4))
+    if "window_flag" in spec:
+        spec["window_flag"] = jnp.int32(spec["window_flag"])
+    kw.update(spec)
+
+    def grads(budget):
+        monkeypatch.setattr(flash_pallas, "DQ_RESIDENT_BYTES", budget)
+        run = lambda q, k, v: flash_attention(q, k, v, causal=causal, interpret=True, **kw)
+        text = str(jax.make_jaxpr(lambda q, k, v: jax.vjp(run, q, k, v)[1](g))(q, k, v))
+        return jax.vjp(run, q, k, v)[1](g), text
+
+    fused, fused_text = grads(flash_pallas.DQ_RESIDENT_BYTES)
+    two, two_text = grads(0)
+    assert flash_pallas.FLASH_BWD_FUSED in fused_text and flash_pallas.FLASH_BWD_DQ not in fused_text
+    assert flash_pallas.FLASH_BWD_DKV in two_text and flash_pallas.FLASH_BWD_FUSED not in two_text
+    for a, b_, name in zip(fused, two, "qkv"):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_), err_msg=f"d{name}")
+    assert all(np.abs(np.asarray(a)).max() > 0 for a in fused)
+
+
+@pytest.mark.parametrize("s, d, kernels", [
+    (4096, 128, {flash_pallas.FLASH_BWD_FUSED}),                       # 2 MiB: at the budget
+    (8192, 64, {flash_pallas.FLASH_BWD_FUSED}),
+    (8192, 128, {flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV}),   # 4 MiB: above it
+    (16384, 64, {flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV}),
+])
+def test_the_backward_kernels_are_chosen_by_the_shape(s, d, kernels):
+    """Which backward kernels the traced gradient holds is read off the shape:
+    the fused one where a head's [s, d] f32 dq accumulator fits
+    ``DQ_RESIDENT_BYTES``, the two streaming kernels above it. Traced only."""
+    import re
+
+    q = jax.ShapeDtypeStruct((1, 2, s, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1, s, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
+    assert (s * d * 4 <= flash_pallas.DQ_RESIDENT_BYTES) == (kernels == {flash_pallas.FLASH_BWD_FUSED})
+    assert set(re.findall(r"dstpu_flash_bwd\w*", text)) == kernels

@@ -203,6 +203,30 @@ def test_the_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, on_the_
                                        or f"[{L},{NBp}," in ln.split(" copy(")[0])]
 
 
+def test_the_flash_backward_compiles_for_a_v5e_at_the_train_cells_shape(one_chip, on_the_chip):
+    """The gradient of ``flash_attention`` as both train cells run it in every
+    layer (16 query heads over 8 KV heads of 128, a causal 4,096-token
+    sequence, bf16, blocks of 1,024): Mosaic takes the one backward kernel with
+    a head's 2 MiB dq accumulator resident, inside the scoped-VMEM default, and
+    neither streaming kernel is in the program. It stands in this file because
+    one process loads the TPU's library: every described compile shares the
+    ``topo`` fixture above."""
+    from deepspeed_tpu.ops.attention import flash_pallas as FP
+
+    def S(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return FP.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        S((1, 16, 4096, 128)), S((1, 8, 4096, 128)), S((1, 8, 4096, 128))).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2     # the forward kernel and ONE backward kernel
+    assert sum(FP.FLASH_BWD_FUSED in ln for ln in calls) == 1
+    assert FP.FLASH_BWD_DQ not in text and FP.FLASH_BWD_DKV not in text
+
+
 @pytest.mark.parametrize("Rc,tq", [(0, 0), (1, 128), (2, 128)],
                          ids=["decode_only", "one_chunk_row", "two_chunk_rows"])
 def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chip, on_the_chip,

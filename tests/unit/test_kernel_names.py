@@ -28,8 +28,13 @@ def _s(shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def test_flash_forward_and_backward_are_named():
-    q, kv = _s((1, 4, 256, 128)), _s((1, 2, 256, 128))
+@pytest.mark.parametrize("s, backward", [
+    (256, {flash_pallas.FLASH_BWD_FUSED}),
+    # a head's dq accumulator above DQ_RESIDENT_BYTES: the two streaming kernels
+    (8192, {flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV}),
+], ids=["dq_resident", "above_the_budget"])
+def test_flash_forward_and_backward_are_named(s, backward):
+    q, kv = _s((1, 4, s, 128)), _s((1, 2, s, 128))
 
     def loss(q, k, v):
         return flash_pallas.flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
@@ -37,11 +42,12 @@ def test_flash_forward_and_backward_are_named():
     fwd = _kernel_names(_tpu_text(lambda q, k, v: flash_pallas.flash_attention(q, k, v), q, kv, kv))
     assert fwd == {flash_pallas.FLASH_FWD}
     both = _kernel_names(_tpu_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv))
-    assert both == {flash_pallas.FLASH_FWD, flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV}
+    assert both == {flash_pallas.FLASH_FWD} | backward
     # the readers tell forward from backward by these prefixes
     assert flash_pallas.FLASH_FWD.startswith("dstpu_flash_fwd")
     assert all(n.startswith("dstpu_flash_bwd")
-               for n in (flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV))
+               for n in (flash_pallas.FLASH_BWD_DQ, flash_pallas.FLASH_BWD_DKV,
+                         flash_pallas.FLASH_BWD_FUSED))
 
 
 def test_flash_ring_chunks_are_named():
@@ -92,8 +98,8 @@ def test_fused_adam_is_named():
 
 
 @pytest.mark.parametrize("module, constants", [
-    (flash_pallas, ("FLASH_FWD", "FLASH_BWD_DQ", "FLASH_BWD_DKV", "FLASH_FWD_CHUNK",
-                    "FLASH_BWD_DQ_CHUNK", "FLASH_BWD_DKV_CHUNK")),
+    (flash_pallas, ("FLASH_FWD", "FLASH_BWD_DQ", "FLASH_BWD_DKV", "FLASH_BWD_FUSED",
+                    "FLASH_FWD_CHUNK", "FLASH_BWD_DQ_CHUNK", "FLASH_BWD_DKV_CHUNK")),
     (paged_pallas, ("PAGED_DECODE",)),
 ])
 def test_names_are_distinct_trace_safe_identifiers(module, constants):

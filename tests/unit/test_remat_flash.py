@@ -1,10 +1,12 @@
 """What a remat'd layer keeps of its attention kernel. A policy that keeps the
 layer's matrix products (``dots_with_no_batch_dims``, the default, and ``dots``)
 keeps the flash kernel's output and log-sum-exp too: the backward of a layer
-then runs ``dstpu_flash_bwd_*`` alone and never ``dstpu_flash_fwd`` a second
-time. Counted in the traced program at every ``jax.checkpoint`` site that takes
-``remat_policy()``, and held to the gradients of the un-remat'd model with the
-kernel interpreted.
+then runs the backward kernel alone (``dstpu_flash_bwd_fused`` at these sizes,
+one call a layer; ``dstpu_flash_bwd_dq`` + ``dstpu_flash_bwd_dkv`` only where a
+head's dq accumulator outgrows ``flash_pallas.DQ_RESIDENT_BYTES``) and never
+``dstpu_flash_fwd`` a second time. Counted in the traced program at every
+``jax.checkpoint`` site that takes ``remat_policy()``, and held to the gradients
+of the un-remat'd model with the kernel interpreted.
 """
 
 import dataclasses
@@ -92,8 +94,8 @@ def kernel_runs(jaxpr, name, times=1):
 def _forward_and_backward_runs(loss, params, batch):
     fwd = kernel_runs(jax.make_jaxpr(loss)(params, batch).jaxpr, flash_pallas.FLASH_FWD)
     both = jax.make_jaxpr(jax.grad(loss))(params, batch).jaxpr
-    assert kernel_runs(both, flash_pallas.FLASH_BWD_DQ) == fwd
-    assert kernel_runs(both, flash_pallas.FLASH_BWD_DKV) == fwd
+    assert kernel_runs(both, flash_pallas.FLASH_BWD_FUSED) == fwd
+    assert kernel_runs(both, flash_pallas.FLASH_BWD_DQ) == kernel_runs(both, flash_pallas.FLASH_BWD_DKV) == 0
     return fwd, kernel_runs(both, flash_pallas.FLASH_FWD) - fwd
 
 
