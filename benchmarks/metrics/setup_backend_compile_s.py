@@ -1,0 +1,10 @@
+"""Layer: entry points. Source: the program's set-up record
+(``deepspeed_tpu.observability.setup_report``, clipped to the run's set-up:
+setup_outside_s.report). Self seconds of ``compile.backend`` under
+the program's spans: XLA's compile, or the persistent cache's retrieval
+(setup_cache_hit_pct says which). Should move setup_s."""
+from benchmarks.metrics.setup_outside_s import phase
+
+
+def read(rec):
+    return phase(rec, "backend_compile")

@@ -1,0 +1,9 @@
+"""Layer: entry points. Source: the program's set-up record
+(``deepspeed_tpu.observability.setup_report``, clipped to the run's set-up:
+setup_outside_s.report). Self seconds of ``compile.lower`` under the
+program's spans: jaxpr to MLIR. Should move setup_s."""
+from benchmarks.metrics.setup_outside_s import phase
+
+
+def read(rec):
+    return phase(rec, "lower")

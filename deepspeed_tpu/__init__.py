@@ -7,6 +7,10 @@ Public API mirrors the reference ``deepspeed/__init__.py``:
 ``add_config_arguments`` (:279), ``zero``, ``comm``.
 """
 
+import time
+
+_T_IMPORT0 = time.monotonic()  # ``setup.import``: this file's first line to its last
+
 from typing import Any, Callable, Optional, Union
 
 from deepspeed_tpu.version import __version__
@@ -18,9 +22,15 @@ from deepspeed_tpu.comm.comm import init_distributed
 from deepspeed_tpu.parallel.topology import Topology, get_topology, set_topology
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+from deepspeed_tpu.observability.setup_record import (
+    get_setup_record,
+    install_compile_listeners,
+    setup_span,
+)
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 
+@setup_span("setup.initialize")
 def initialize(
     args=None,
     model: Optional[Callable] = None,
@@ -188,3 +198,8 @@ def _add_core_arguments(parser):
     parser = add_config_arguments(parser)
     parser = add_tuning_arguments(parser)
     return parser
+
+
+# every compile of the process from here on is a span of the set-up record
+install_compile_listeners()
+get_setup_record().add("setup.import", _T_IMPORT0)

@@ -26,6 +26,8 @@ import sys
 
 import numpy as np
 
+from deepspeed_tpu.observability.setup_record import get_setup_record, setup_span
+
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(
@@ -417,6 +419,7 @@ def _serving_engine(cfg, params, rc):
     return engine
 
 
+@setup_span("setup.build_stack")
 def build_serving_stack(args, cfg=None, params=None, tok=None):
     """Engine(s) + driver from parsed serve args (split out so tests can
     build the stack without a socket). Pass cfg/params/tok to skip
@@ -439,7 +442,8 @@ def build_serving_stack(args, cfg=None, params=None, tok=None):
     if cfg is None or params is None:
         from deepspeed_tpu.models import load_hf_model
 
-        cfg, params = load_hf_model(args.model, dtype=args.dtype)
+        with get_setup_record().span("setup.load_weights"):
+            cfg, params = load_hf_model(args.model, dtype=args.dtype)
     if tok is None and args.model:
         from deepspeed_tpu.tokenizer import load_tokenizer
 
@@ -564,6 +568,7 @@ def serve_main(argv=None) -> int:
     return 0
 
 
+@setup_span("setup.build_stack")
 def build_agent_core(args, cfg=None, params=None, tok=None):
     """One decode ``EngineCore`` for ``dstpu serve-agent`` (split out so
     tests can build an agent without a checkpoint). The engine comes from
@@ -576,7 +581,8 @@ def build_agent_core(args, cfg=None, params=None, tok=None):
     if cfg is None or params is None:
         from deepspeed_tpu.models import load_hf_model
 
-        cfg, params = load_hf_model(args.model, dtype=args.dtype)
+        with get_setup_record().span("setup.load_weights"):
+            cfg, params = load_hf_model(args.model, dtype=args.dtype)
     if tok is None and args.model:
         from deepspeed_tpu.tokenizer import load_tokenizer
 

@@ -47,6 +47,7 @@ from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
 from deepspeed_tpu.inference.v2.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.scheduler import RaggedBatch, RaggedScheduler
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.observability.setup_record import get_setup_record, setup_span
 from deepspeed_tpu.observability.tracing import get_tracer
 from deepspeed_tpu.ops.stack_matmul import Stacked, stack_dot
 from deepspeed_tpu.utils.logging import log_dist
@@ -216,6 +217,7 @@ class StepInFlight:
 
 
 class InferenceEngineV2:
+    @setup_span("setup.build_engine")
     def __init__(self, model_config: T.TransformerConfig, params, config: Optional[RaggedInferenceEngineConfig] = None):
         setup_compile_cache()
         self.config = config or RaggedInferenceEngineConfig()
@@ -1127,12 +1129,15 @@ class InferenceEngineV2:
         most_rows = min(sched.max_prompt_chunks,
                         int(self.config.state_manager.max_tracked_sequences))
 
-        def first_tokens(wuids, length):
+        def first_tokens(wuids, length, shape):
+            # under the first-call span of the shape these steps build: _launch's
+            # own (builder to enqueue) nests in it, the wait for the tokens is its
             got = {}
-            for _ in range(8 + length // max(1, pc) + len(wuids)):
-                got.update(self.step_tokens())
-                if all(w in got for w in wuids):
-                    return got
+            with get_setup_record().span("program.first_call", key=f"split[{shape}]"):
+                for _ in range(8 + length // max(1, pc) + len(wuids)):
+                    got.update(self.step_tokens())
+                    if all(w in got for w in wuids):
+                        return got
             raise RuntimeError(
                 f"warm_split_shapes: a prompt of {length} tokens never produced a token")
 
@@ -1148,11 +1153,11 @@ class InferenceEngineV2:
                             # token: a prefix hit would shorten a chunk
                             toks = np.arange(length if k == 0 else 8, dtype=np.int32) + k
                             sched.submit(wuid, toks % max(1, vocab - 1) + 1)
-                        got = first_tokens(wuids, length)
+                        got = first_tokens(wuids, length, (rows, self._chunk_bucket(rows, length)))
                         if rows == 1 and length == 8:
                             # the short prompt's first decode step has no chunk beside it
                             sched.feedback(uid, got[uid])
-                            sched.feedback(uid, first_tokens([uid], 1)[uid])
+                            sched.feedback(uid, first_tokens([uid], 1, (0, 0))[uid])
                             if while_running is not None:
                                 while_running(uid)
                     finally:
@@ -2657,8 +2662,13 @@ class InferenceEngineV2:
         enqueue (``_enqueued``, for _dispatch) and asks, without waiting,
         whether the step in flight has finished already (``starved``)."""
         fn = self._programs.get(key)
+        first_call = None
         if fn is None:
+            # the program's first call, builder to the jitted call's return:
+            # its trace, lower and compile (or the cache's answer) nest in it
             kind, shape = key
+            first_call = get_setup_record().span("program.first_call", key=f"{kind}[{shape}]")
+            first_call.__enter__()
             fn = self._programs[key] = getattr(self, _BUILDERS[kind])(shape)
         args = (
             self.params,
@@ -2670,7 +2680,11 @@ class InferenceEngineV2:
         prev = self._uncollected
         self.last_step.starved = prev is not None and _is_ready(prev.waited[0])
         self._enqueued = _now()
-        outputs, pools, self._moe_pending = fn(*args)
+        try:
+            outputs, pools, self._moe_pending = fn(*args)
+        finally:
+            if first_call is not None:
+                first_call.__exit__(None, None, None)
         pools, second = self._split_pools(pools)
         if self._latent:
             (self._k_cache,) = pools

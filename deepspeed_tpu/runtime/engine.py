@@ -42,6 +42,11 @@ from deepspeed_tpu.parallel.topology import (
     get_topology,
     set_topology,
 )
+from deepspeed_tpu.observability.setup_record import (
+    get_setup_record,
+    setup_line,
+    setup_report,
+)
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.fp16 import loss_scaler as ls
 from deepspeed_tpu.runtime.lr_schedules import get_lr_scheduler
@@ -310,85 +315,89 @@ class DeepSpeedEngine:
                     is_leaf=lambda x: isinstance(x, _NS),
                 ),
             )
-        init_shardings = (
-            self.plan.param_shardings
-            if self._offload_native
-            else self.plan.device_shardings(self.plan.param_shardings)
-        )
-        if deferred_init is not None:
-            dtype = self.compute_dtype
-            params = jax.jit(
-                lambda: _tree_cast(deferred_init(), dtype), out_shardings=init_shardings
-            )()
-        elif not dont_change_device:
-            params = jax.device_put(params, init_shardings)
-        self.params = params
-
-        # optimizer (+ fp32 master, sharded per plan)
-        self.optimizer = self._configure_optimizer(optimizer, config)
-        if self._weight_stream:
-            if self.optimizer.name not in ("adam", "adamw"):
-                raise NotImplementedError(
-                    f"weight_stream supports Adam/AdamW only (got {self.optimizer.name}): "
-                    "the chunk-streamed host-state update is AdamW-specific "
-                    "(runtime/streamed_adam.py)"
-                )
-            from deepspeed_tpu.runtime.streamed_adam import StreamedAdamW
-
-            d = self.optimizer.defaults
-            if self.optimizer.name == "adam" and d.get("weight_decay", 0.0):
-                raise NotImplementedError(
-                    "weight_stream implements decoupled (AdamW) weight decay "
-                    "only; use AdamW or weight_decay=0"
-                )
-            self.optimizer = StreamedAdamW(
-                lr=d.get("lr", 1e-3),
-                betas=tuple(d.get("betas", (0.9, 0.999))),
-                eps=d.get("eps", 1e-8),
-                weight_decay=d.get("weight_decay", 0.0),
-                # int8 moment streaming: the tier is PCIe-wire-limited and
-                # bytes are the lever (PERF.md streamed-7B roofline)
-                quant_bits=int(getattr(
-                    config.zero_optimization.offload_optimizer,
-                    "stream_quant_bits", 0,
-                ) or 0),
-                # double-buffered state-window streaming rides the same
-                # escape hatch as the collective overlap scheduler
-                overlap=config.zero_optimization.overlap_enabled,
+        # the placement and sharding of the parameters and the optimizer
+        # state's creation: what a set-up pays for the weights, less the
+        # compiles inside it
+        with get_setup_record().span("setup.state"):
+            init_shardings = (
+                self.plan.param_shardings
+                if self._offload_native
+                else self.plan.device_shardings(self.plan.param_shardings)
             )
-        self._host_opt = None
-        self._host_step_jit = None
-        if self._host_opt_requested:
-            # state never materializes in device/host jax memory at all —
-            # it is seeded straight to NVMe files (ZeRO-Infinity semantics)
-            self._init_host_optimizer(zcfg)
-            self._state_shardings = {}
-            self.opt_state = {}
-        else:
-            state_shapes = jax.eval_shape(self.optimizer.init, self.params)
-            if getattr(self.optimizer, "state_partition_specs", None) is not None:
-                # collective optimizers (1-bit Adam) own their state layout:
-                # per-worker error buffers shard over data, moments replicate
-                from jax.sharding import NamedSharding, PartitionSpec
+            if deferred_init is not None:
+                dtype = self.compute_dtype
+                params = jax.jit(
+                    lambda: _tree_cast(deferred_init(), dtype), out_shardings=init_shardings
+                )()
+            elif not dont_change_device:
+                params = jax.device_put(params, init_shardings)
+            self.params = params
 
-                specs = self.optimizer.state_partition_specs(state_shapes)
-                self._state_shardings = jax.tree.map(
-                    lambda s: NamedSharding(self.topo.mesh, s),
-                    specs,
-                    is_leaf=lambda x: isinstance(x, PartitionSpec),
-                )
-            else:
-                self._state_shardings = self.plan.state_shardings(state_shapes)
+            # optimizer (+ fp32 master, sharded per plan)
+            self.optimizer = self._configure_optimizer(optimizer, config)
             if self._weight_stream:
-                self.opt_state = self._streamed_opt_init(state_shapes)
+                if self.optimizer.name not in ("adam", "adamw"):
+                    raise NotImplementedError(
+                        f"weight_stream supports Adam/AdamW only (got {self.optimizer.name}): "
+                        "the chunk-streamed host-state update is AdamW-specific "
+                        "(runtime/streamed_adam.py)"
+                    )
+                from deepspeed_tpu.runtime.streamed_adam import StreamedAdamW
+
+                d = self.optimizer.defaults
+                if self.optimizer.name == "adam" and d.get("weight_decay", 0.0):
+                    raise NotImplementedError(
+                        "weight_stream implements decoupled (AdamW) weight decay "
+                        "only; use AdamW or weight_decay=0"
+                    )
+                self.optimizer = StreamedAdamW(
+                    lr=d.get("lr", 1e-3),
+                    betas=tuple(d.get("betas", (0.9, 0.999))),
+                    eps=d.get("eps", 1e-8),
+                    weight_decay=d.get("weight_decay", 0.0),
+                    # int8 moment streaming: the tier is PCIe-wire-limited and
+                    # bytes are the lever (PERF.md streamed-7B roofline)
+                    quant_bits=int(getattr(
+                        config.zero_optimization.offload_optimizer,
+                        "stream_quant_bits", 0,
+                    ) or 0),
+                    # double-buffered state-window streaming rides the same
+                    # escape hatch as the collective overlap scheduler
+                    overlap=config.zero_optimization.overlap_enabled,
+                )
+            self._host_opt = None
+            self._host_step_jit = None
+            if self._host_opt_requested:
+                # state never materializes in device/host jax memory at all —
+                # it is seeded straight to NVMe files (ZeRO-Infinity semantics)
+                self._init_host_optimizer(zcfg)
+                self._state_shardings = {}
+                self.opt_state = {}
             else:
-                self.opt_state = jax.jit(
-                    self.optimizer.init,
-                    out_shardings=self.plan.device_shardings(self._state_shardings),
-                )(self.params)
-                if self.plan.offload_optimizer:
-                    self.opt_state = jax.device_put(self.opt_state, self._state_shardings)
-        self.params = self._park_params(self.params)
+                state_shapes = jax.eval_shape(self.optimizer.init, self.params)
+                if getattr(self.optimizer, "state_partition_specs", None) is not None:
+                    # collective optimizers (1-bit Adam) own their state layout:
+                    # per-worker error buffers shard over data, moments replicate
+                    from jax.sharding import NamedSharding, PartitionSpec
+
+                    specs = self.optimizer.state_partition_specs(state_shapes)
+                    self._state_shardings = jax.tree.map(
+                        lambda s: NamedSharding(self.topo.mesh, s),
+                        specs,
+                        is_leaf=lambda x: isinstance(x, PartitionSpec),
+                    )
+                else:
+                    self._state_shardings = self.plan.state_shardings(state_shapes)
+                if self._weight_stream:
+                    self.opt_state = self._streamed_opt_init(state_shapes)
+                else:
+                    self.opt_state = jax.jit(
+                        self.optimizer.init,
+                        out_shardings=self.plan.device_shardings(self._state_shardings),
+                    )(self.params)
+                    if self.plan.offload_optimizer:
+                        self.opt_state = jax.device_put(self.opt_state, self._state_shardings)
+            self.params = self._park_params(self.params)
 
         # Bucketed comm/compute overlap (runtime/zero/overlap.py): resolve
         # the overlap_comm knob once and size the transformer scan-chunk for
@@ -1779,9 +1788,28 @@ class DeepSpeedEngine:
         stacked = self._stack_batch(data_iter if data_iter is not None else batch)
         stacked = self._apply_curriculum(stacked)
         if self._host_opt is not None:
-            return self._train_batch_hostopt(stacked)
-        if self._weight_stream:
-            return self._train_batch_streamed(stacked)
+            step, program = self._train_batch_hostopt, self._host_step_jit
+        elif self._weight_stream:
+            step, program = self._train_batch_streamed, getattr(self, "_stream_grads_jit", None)
+        else:
+            step, program = self._train_batch_fused, self._train_step_jit
+        if program is not None:
+            return step(stacked)
+        # the step program's first call, builder to the step's return: its
+        # trace, lower and compile (or the cache's answer) nest in it
+        with get_setup_record().span("program.first_call", key="train_step"):
+            loss = step(stacked)
+        log_dist(setup_line(self.setup_report()), ranks=[0])
+        return loss
+
+    @staticmethod
+    def setup_report():
+        """What this process spent before its first step, by phase and by
+        program (observability/setup_record.py, docs/OBSERVABILITY.md)."""
+        return setup_report()
+
+    def _train_batch_fused(self, stacked):
+        """train_batch where the whole step is one program on the chip."""
         if self._train_step_jit is None:
             self._train_step_jit = self._build_train_step()
         lr = self._lr_for_step()
@@ -1991,17 +2019,21 @@ class DeepSpeedEngine:
             self.timers.log([FORWARD_GLOBAL_TIMER, BACKWARD_GLOBAL_TIMER, STEP_GLOBAL_TIMER])
 
     def eval_batch(self, batch):
-        if self._eval_jit is None:
+        first = self._eval_jit is None
+        # (no frame of its own around the first call: every frame above a
+        # program's trace is paid again in its lowering, PERF.md section 6, PR 51)
+        with get_setup_record().span("program.first_call", key="eval") if first else contextlib.nullcontext():
+            if first:
 
-            def eval_fn(params, batch):
-                params = self._stage_params(params)
-                loss, aux = self._call_loss(params, batch, None if not self._loss_fn_takes_rng else self._rng_key)
-                return loss
+                def eval_fn(params, batch):
+                    params = self._stage_params(params)
+                    loss, aux = self._call_loss(params, batch, None if not self._loss_fn_takes_rng else self._rng_key)
+                    return loss
 
-            self._eval_jit = jax.jit(eval_fn)
-        self._unpark_params()
-        batch = jax.device_put(batch, self._batch_shardings(batch))
-        return self._eval_jit(self.params, batch)
+                self._eval_jit = jax.jit(eval_fn)
+            self._unpark_params()
+            batch = jax.device_put(batch, self._batch_shardings(batch))
+            return self._eval_jit(self.params, batch)
 
     # ------------------------------------------------------------------
     # dataloader (reference deepspeed_io, engine.py:2005)

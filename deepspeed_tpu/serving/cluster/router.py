@@ -37,6 +37,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from deepspeed_tpu.observability.events import get_event_log, log_event
+from deepspeed_tpu.observability.setup_record import get_setup_record
 from deepspeed_tpu.observability.tracing import (
     begin_request_trace,
     finish_request_trace,
@@ -558,6 +559,7 @@ class Router:
                         snap.get("peer_pull_retries_total", 0)),
                 },
                 "events": get_event_log().stats(),
+                "setup": get_setup_record().health(),
             }
 
     def _host_tier_health_locked(self) -> Dict:

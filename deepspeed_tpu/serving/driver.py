@@ -46,6 +46,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from deepspeed_tpu.observability.events import get_event_log
+from deepspeed_tpu.observability.setup_record import get_setup_record
 from deepspeed_tpu.observability.tracing import (
     begin_request_trace,
     finish_request_trace,
@@ -311,6 +312,7 @@ class ServingDriver:
                     "acceptance_rate": snap["spec_acceptance_rate"],
                 },
                 "events": get_event_log().stats(),
+                "setup": get_setup_record().health(),
             }
 
     def _host_tier_health(self) -> Dict:

@@ -17,6 +17,7 @@ from deepspeed_tpu.monitor.monitor import (
     prometheus_metric_name,
     render_prometheus_text,
 )
+from deepspeed_tpu.observability.setup_record import get_setup_record
 
 # Latency buckets in seconds (log-ish spacing from 1 ms to 2 min): one set
 # serves TTFT, TPOT, and e2e — cross-metric comparability beats per-metric
@@ -572,6 +573,8 @@ class ServingMetrics:
                 ("kv_handoff_seconds", self.handoff_seconds),
             ):
                 samples.extend(hist.prom_samples(f"{p}_{hname}"))
+        # the process's set-up and compiles (observability/setup_record.py)
+        samples.extend(get_setup_record().prometheus_samples())
         return render_prometheus_text(samples)
 
     def to_events(self, step: Optional[int] = None) -> List[Tuple]:
