@@ -13,10 +13,24 @@ resident, lifting the former ~8k dense cap):
     current block's MXU work. Softmax state (m, l) and the output
     accumulator live in VMEM scratch carried across the kv iterations; the
     output block is written once on the last iteration.
-  * Causal pruning: masked (q, kv) grid points clamp their kv index map to
-    the last active block — Pallas elides the copy when the block index is
-    unchanged — and skip compute under ``pl.when``. Cost of a pruned point
-    is grid overhead only, preserving the ~2× causal win.
+  * Causal pruning and classes. A (q, kv) grid point ABOVE the diagonal is
+    pruned: it clamps its kv index map to the last active block — Pallas
+    elides the copy when the block index is unchanged — and skips compute
+    under ``pl.when``; it costs grid overhead only. The forward computes every
+    other pair whole under the causal mask. The BACKWARD kernels class them by
+    where the diagonal passes (``causal_pair_classes``), one ``pl.when`` body a
+    class. Wholly UNDER it: the whole pair with no causal mask (no iotas, no
+    compare, no select; segment and ALiBi terms stay). ON it: the pair goes by
+    key-column strips of a quarter block (256 at 1,024) against the q rows that
+    see them, unrolled, each under a compare of the strip, and what lies wholly
+    above the diagonal is never computed: 10 tiles of 16, so a 4,096-token
+    sequence at blocks of 1,024 costs the backward 8.5 pairs of products where
+    its 10 active pairs held 10 (and 16 without pruning). A window (static or
+    by ``wflag``), blocks that differ and a block too small to hold two
+    128-wide strips keep every pair whole under the position masks. A dropped
+    term is an exact zero, ``exp(NEG_INF - lse)``. The forward takes neither:
+    measured on the v5e, every cut of a forward pair is slower than the pair,
+    and the unmasked body gains it nothing (PERF.md, PR 52).
   * fp32 accumulators; the MXU sees bf16 inputs with
     ``preferred_element_type=jnp.float32``.
   * LSE is stored lane-broadcast as [b, h, s, LANES] to satisfy the TPU
@@ -29,7 +43,7 @@ resident, lifting the former ~8k dense cap):
     [bk, d] fp32 scratch over the q blocks; the head's whole dq accumulates
     in [s, d] fp32 scratch that stays in VMEM across the kv blocks and
     leaves as q.dtype once. delta = Σ do·o is computed in-kernel from the
-    saved output, once a pair.
+    saved output, once a pair (once a strip of a cut pair).
   * The residency budget: that form runs where s·d·4 bytes fit
     ``DQ_RESIDENT_BYTES`` (2 MiB: s ≤ 4096 at d = 128). A longer sequence
     takes the two streaming kernels it fuses, which also serve the ring:
@@ -38,8 +52,9 @@ resident, lifting the former ~8k dense cap):
     scores and dp, seven products a pair, and nothing sequence-sized is
     resident. Which one is read off the shape, and the sums are the same in
     the same order: dq[i] over kv blocks ascending, dk[j]/dv[j] over q blocks
-    ascending, so the three gradients are bit-identical either way (and to
-    the ring's chunked stream).
+    ascending, a cut pair's strips ascending inside it through one shared
+    body, so the three gradients are bit-identical either way (and to the
+    ring's chunked stream).
   * GQA: kv-head index map h → h // (nh/nkv); no head replication in HBM.
     The backward writes dk/dv per QUERY head and sums the group outside the
     kernel (cast to the input dtype, then an fp32 sum): the ring's hand-over
@@ -52,7 +67,7 @@ tests/unit/ops/test_flash_attention.py (interpret mode on CPU), including a
 """
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,12 +87,13 @@ FLASH_BWD_DQ_CHUNK = "dstpu_flash_bwd_dq_chunk"
 FLASH_BWD_DKV_CHUNK = "dstpu_flash_bwd_dkv_chunk"
 
 
-def _alibi_term(alibi_ref, kpos_ref):
+def _alibi_term(alibi_ref, kpos_ref, cols):
     """ALiBi additive logits term for one block: ``slope_h * key_position``
     (HF bloom's absolute-position convention — softmax-equivalent to the
     relative form under causal masking). alibi_ref: [1, 1, LANES] slope plane
-    for this head; kpos_ref: [1, bk] int32 key positions."""
-    return alibi_ref[0, 0, 0] * kpos_ref[:].astype(jnp.float32)
+    for this head; kpos_ref: [1, bk] int32 key positions, of which a strip
+    of a pair takes its ``cols``."""
+    return alibi_ref[0, 0, 0] * kpos_ref[:, cols].astype(jnp.float32)
 
 
 def _apply_window(logits, window, wflag_ref, q_pos, k_pos):
@@ -94,27 +110,117 @@ def _apply_window(logits, window, wflag_ref, q_pos, k_pos):
     return jnp.where(far, NEG_INF, logits)
 
 
-def _pair_logits(q, k, qi, ki, scale, causal, bq, bk, window, seg_q_ref=None,
-                 seg_k_ref=None, alibi_ref=None, kpos_ref=None, wflag_ref=None):
-    """Masked logits [bq, bk] f32 of one block pair (q block ``qi``, kv block
-    ``ki``): scale * q k^T, the ALiBi term, then the causal, window and
-    segment masks. The forward and every backward kernel build the pair's
-    scores through this one body; the optional refs are a kernel's
-    ``**mask_refs``."""
+class PairClasses(NamedTuple):
+    """What a causal sequence's block pairs cost (:func:`causal_pair_classes`)."""
+
+    pruned: int      # wholly above the diagonal: grid overhead only
+    under: int       # wholly under it: computed whole, no causal mask
+    diagonal: int    # the diagonal passes through them
+    strip: int       # side of the tiles a diagonal pair is cut into; 0: run whole, masked
+    live_tiles: int  # tiles of the diagonal pairs that are computed ...
+    tiles: int       # ... of those they hold (a pair run whole is one tile of one)
+
+    @property
+    def pairs_of_products(self) -> float:
+        """MXU work in whole pairs: ``under`` + the diagonal pairs' live share."""
+        return self.under + self.diagonal * self.live_tiles / max(self.tiles, 1)
+
+
+def causal_pair_classes(s, bq, bk) -> PairClasses:
+    """Class every (q block, kv block) pair of a causal ``s``-token sequence by
+    where the diagonal passes: the count of what the backward kernels below
+    compute (the forward runs ``under + diagonal`` pairs whole). A pair on the
+    diagonal goes by strips of ``strip`` (a quarter of the block, at least a
+    128-lane tile, at least two a block) and computes only the tiles on or
+    under the diagonal; where the blocks differ or are too small to cut,
+    ``strip`` is 0 and the pair runs whole under the mask. 4,096 at 1,024:
+    6 / 6 / 4, strips of 256, 40 of 64 tiles, 8.5 pairs of products for 10."""
+    strip = max(bq // 4, LANES) if bq == bk else 0
+    if strip and (bq < 2 * strip or bq % strip):
+        strip = 0
+    pruned = under = diagonal = 0
+    for qi in range(s // bq):
+        for ki in range(s // bk):
+            if ki * bk > qi * bq + bq - 1:
+                pruned += 1
+            elif qi * bq >= ki * bk + bk - 1:
+                under += 1
+            else:
+                diagonal += 1
+    n = bq // strip if strip else 1
+    return PairClasses(pruned, under, diagonal, strip,
+                       diagonal * n * (n + 1) // 2, diagonal * n * n)
+
+
+def _strip_for(causal, window, s, bq, bk):
+    """A backward kernel's static ``strip``. None: the pairs it computes are all
+    of one class (not causal: no causal mask; a window, static or by ``wflag``:
+    every pair whole under the position masks). Else three classes, the
+    diagonal pairs by strips of that side, or whole where it is 0."""
+    if not causal or window:
+        return None
+    return causal_pair_classes(s, bq, bk).strip
+
+
+def _pair_bodies(active, qi, ki, causal, bq, bk, strip):
+    """[(condition, class)]: the bodies a backward kernel builds under ``pl.when``
+    for the pairs it computes. The class is None (no causal mask), "masked" (the
+    whole pair under the position masks, window included) or "cut" (a pair on
+    the diagonal, by strips: :func:`_pair_strips`)."""
+    if strip is None:
+        return [(active, "masked" if causal else None)]
+    under = qi * bq >= ki * bk + bk - 1  # implies active
+    on = jnp.logical_and(active, jnp.logical_not(under))
+    return [(under, None), (on, "cut" if strip else "masked")]
+
+
+def _pair_strips(cls, strip, bq, bk):
+    """[(q rows, key columns, masked)]: the rectangles a backward kernel computes
+    a pair by, each through the same body. A "cut" pair (bq == bk) goes by
+    key-column strips of ``strip`` against the q rows that see them, and so
+    leaves out what lies wholly above the diagonal; dv, dk and dq take their
+    products from the same strip's p and ds. The compare covers the strip:
+    cutting the diagonal tile out to compare it alone read slower on the v5e.
+    Any other pair is one rectangle, the pair."""
+    if cls != "cut":
+        return [(slice(0, bq), slice(0, bk), cls == "masked")]
+    return [(slice(e, bq), slice(e, e + strip), True) for e in range(0, bq, strip)]
+
+
+def _pair_logits(q, k, qi, ki, scale, bq, bk, window, rows, cols, masked,
+                 seg_q_ref=None, seg_k_ref=None, alibi_ref=None, kpos_ref=None,
+                 wflag_ref=None):
+    """Masked logits f32 of one rectangle of a block pair (q block ``qi``, kv
+    block ``ki``; ``q`` its ``rows``, ``k`` its ``cols``: :func:`_pair_strips`):
+    scale * q k^T, the ALiBi term, then, where the rectangle is ``masked``, the
+    causal and window masks by position, then the segment mask. The forward
+    (always the whole pair) and every backward kernel build a pair's scores
+    through this one body; the optional refs are a kernel's ``**mask_refs``."""
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     if alibi_ref is not None:
-        logits = logits + _alibi_term(alibi_ref, kpos_ref)
-    if causal:
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        logits = logits + _alibi_term(alibi_ref, kpos_ref, cols)
+    if masked:
+        q_pos = qi * bq + rows.start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+        k_pos = ki * bk + cols.start + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
         if window:
             logits = _apply_window(logits, window, wflag_ref, q_pos, k_pos)
     if seg_q_ref is not None:
-        logits = jnp.where(seg_q_ref[:, :1] == seg_k_ref[:], logits, NEG_INF)
+        logits = jnp.where(seg_q_ref[rows, :1] == seg_k_ref[:, cols], logits, NEG_INF)
     return logits
+
+
+def _pair_p_ds(q, k, v, do, lse, delta, *logits_args, **mask_refs):
+    """(p, ds) f32 of one rectangle of a pair, the flash recompute every backward
+    kernel shares: p = exp(logits - lse), ds = p * (do v^T - delta)."""
+    logits = _pair_logits(q, k, *logits_args, **mask_refs)
+    p = jnp.exp(logits - lse[:, None])
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    return p, p * (dp - delta[:, None])
 
 
 def _kv_block_active(qi, ki, causal, bq, bk, window, mask_refs):
@@ -171,7 +277,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
         q = q_ref[:]
         k = k_ref[:]
         v = v_ref[:]
-        logits = _pair_logits(q, k, qi, ki, scale, causal, bq, bk, window, **mask_refs)
+        # every pair whole, a causal one under the position masks: ONE body
+        # (on the v5e a second, unmasked one for the pairs under the diagonal
+        # reads no shorter, 717 against 716 us a layer, and a cut pair longer)
+        logits = _pair_logits(q, k, qi, ki, scale, bq, bk, window, slice(0, bq),
+                              slice(0, bk), causal, **mask_refs)
         m = m_ref[:, 0]
         l = l_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
@@ -203,7 +313,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
                    delta_ref, dq_acc_ref, *, scale, causal, bq, bk, nk,
-                   window=0, dq_in_ref=None, raw_out=False, **mask_refs):
+                   window=0, strip=None, dq_in_ref=None, raw_out=False, **mask_refs):
     # Carry mode (ring bwd): ``dq_in_ref`` seeds the accumulator from the
     # previous chunk's partial and ``raw_out`` flushes it unscaled in f32 —
     # the ring applies `* scale` once after the last chunk, exactly like the
@@ -222,23 +332,21 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
         )
         delta_ref[:] = jnp.broadcast_to(delta[:, None], delta_ref.shape)
 
-    @pl.when(_kv_block_active(qi, ki, causal, bq, bk, window, mask_refs))
-    def _step():
-        q = q_ref[:]
-        k = k_ref[:]
-        v = v_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:, 0]
-        logits = _pair_logits(q, k, qi, ki, scale, causal, bq, bk, window, **mask_refs)
-        p = jnp.exp(logits - lse[:, None])  # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[:, 0][:, None])  # [bq, bk]
-        dq_acc_ref[:] = dq_acc_ref[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def _step(cls):
+        for rows, cols, masked in _pair_strips(cls, strip, bq, bk):
+            k = k_ref[cols, :]
+            _, ds = _pair_p_ds(
+                q_ref[rows, :], k, v_ref[cols, :], do_ref[rows, :], lse_ref[rows, 0],
+                delta_ref[rows, 0], qi, ki, scale, bq, bk, window, rows, cols, masked,
+                **mask_refs)
+            dq_acc_ref[rows, :] = dq_acc_ref[rows, :] + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+    active = _kv_block_active(qi, ki, causal, bq, bk, window, mask_refs)
+    for when, cls in _pair_bodies(active, qi, ki, causal, bq, bk, strip):
+        pl.when(when)(functools.partial(_step, cls))
 
     @pl.when(ki == nk - 1)
     def _flush():
@@ -250,8 +358,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
                     dv_ref, dk_acc_ref, dv_acc_ref, *, scale, causal, bq, bk,
-                    nq, window=0, dk_in_ref=None, dv_in_ref=None, raw_out=False,
-                    dq_ref=None, dq_acc_ref=None, nk=None, **mask_refs):
+                    nq, window=0, strip=None, dk_in_ref=None, dv_in_ref=None,
+                    raw_out=False, dq_ref=None, dq_acc_ref=None, nk=None, **mask_refs):
     # Carry mode mirrors _bwd_dq_kernel: seed accumulators from the previous
     # chunk's partials, flush raw f32 when ``raw_out``.
     #
@@ -278,39 +386,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
         def _init_dq():
             dq_acc_ref[qj] = jnp.zeros(dq_acc_ref.shape[1:], dq_acc_ref.dtype)
 
-    @pl.when(_q_block_active(ki, qj, causal, bq, bk, window, mask_refs))
-    def _step():
-        k = k_ref[:]
-        v = v_ref[:]
-        q = q_ref[:]
-        do = do_ref[:]
-        o = o_ref[:]
-        lse = lse_ref[:, 0]
-        # delta is recomputed per (kv, q) grid point: one [bq, d] VPU reduce
-        # (~0.05% of the two MXU matmuls below) — cheaper than a separate
-        # preprocess kernel or an HBM round-trip for [b, h, s] deltas.
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1
-        )  # [bq]
-        logits = _pair_logits(q, k, qj, ki, scale, causal, bq, bk, window, **mask_refs)
-        p = jnp.exp(logits - lse[:, None])
-        dv_acc_ref[:] = dv_acc_ref[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta[:, None])
-        dk_acc_ref[:] = dk_acc_ref[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dq_ref is not None:
-            dq_acc_ref[qj] = dq_acc_ref[qj] + jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+    def _step(cls):
+        for rows, cols, masked in _pair_strips(cls, strip, bq, bk):
+            k = k_ref[cols, :]
+            q = q_ref[rows, :]
+            do = do_ref[rows, :]
+            # delta is recomputed per rectangle of a (kv, q) grid point: one
+            # [rows, d] VPU reduce (~0.05% of the MXU products below) — cheaper
+            # than a separate preprocess kernel or an HBM round-trip for
+            # [b, h, s] deltas.
+            delta = jnp.sum(
+                do.astype(jnp.float32) * o_ref[rows, :].astype(jnp.float32), axis=-1
+            )
+            p, ds = _pair_p_ds(
+                q, k, v_ref[cols, :], do, lse_ref[rows, 0], delta, qj, ki, scale,
+                bq, bk, window, rows, cols, masked, **mask_refs)
+            dv_acc_ref[cols, :] = dv_acc_ref[cols, :] + jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )  # [bq, d]
+            )  # [cols, d]
+            dk_acc_ref[cols, :] = dk_acc_ref[cols, :] + jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            if dq_ref is not None:
+                dq_acc_ref[qj, rows, :] = dq_acc_ref[qj, rows, :] + jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [rows, d]
+
+    active = _q_block_active(ki, qj, causal, bq, bk, window, mask_refs)
+    for when, cls in _pair_bodies(active, qj, ki, causal, bq, bk, strip):
+        pl.when(when)(functools.partial(_step, cls))
 
     @pl.when(qj == nq - 1)
     def _flush():
@@ -344,7 +451,11 @@ def _pick_block(s, target=None):
     """Largest power-of-two block ≤ target dividing s. The default block is
     env-tunable (DSTPU_FLASH_BLOCK) for per-generation retuning; with the
     kv-pipelined kernel 1024 measured best on v5e at s=2048 (fwd+bwd 5.75 ms
-    vs 6.93 at 512, 10.7 at 256; 2048 exceeds the 16M scoped-vmem limit)."""
+    vs 6.93 at 512, 10.7 at 256; 2048 exceeds the 16M scoped-vmem limit).
+    The block also sets the strip a backward kernel cuts a diagonal pair by
+    (``causal_pair_classes``: a quarter of it, 128 at least; 256 measured best
+    at 1024: 1.078 ms a layer against 1.110 at 512, 1.142 at 128, 1.223
+    uncut), so a block under 256 is not cut."""
     if target is None:
         import os
 
@@ -618,7 +729,7 @@ def _bwd_dq_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
 
     kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk,
-        window=window,
+        window=window, strip=_strip_for(causal, window, s, bq, bk),
     )
     seg_ops, seg_specs = _seg_specs(segment_ids, bq, lambda i, j: i, bk, jc)
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, jc)
@@ -662,10 +773,19 @@ def _bwd_dkv_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale
     bk = _pick_block(s)
     nq, nk = s // bq, s // bk
     qc = _q_clamp(causal, bq, bk, window, static_window=wflag is None, nq=nq)
+    strip = _strip_for(causal, window, s, bq, bk)
+    if strip is not None:
+        # once a traced backward: what its pairs cost, by class
+        from deepspeed_tpu.observability.tracing import get_tracer
+
+        classes = causal_pair_classes(s, bq, bk)
+        get_tracer().instant("flash.causal_pairs", track="trace", args={
+            "s": s, "block": bq, **classes._asdict(),
+            "pairs_of_products": classes.pairs_of_products})
 
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nq=nq,
-        nk=nk, window=window,
+        nk=nk, window=window, strip=strip,
     )
     seg_ops, seg_specs = _seg_specs(segment_ids, bq, qc, bk, lambda i, j: i)
     # the grid is kv-major: the key-position block follows the kv index i
@@ -858,7 +978,7 @@ def flash_dq_chunk(q, k, v, out, do, lse, dq_acc, segment_ids=None,
 
     kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk,
-        raw_out=True,
+        strip=_strip_for(causal, 0, sq, bq, bk), raw_out=True,
     )
     seg_ops, seg_specs = _seg_specs(segment_ids, bq, lambda i, j: i, bk, jc)
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, jc)
@@ -911,7 +1031,7 @@ def flash_dkv_chunk(q, k, v, out, do, lse, dk_acc, dv_acc, segment_ids=None,
 
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nq=nq,
-        raw_out=True,
+        strip=_strip_for(causal, 0, sq, bq, bk), raw_out=True,
     )
     seg_ops, seg_specs = _seg_specs(segment_ids, bq, qc, bk, lambda i, j: i)
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, lambda i, j: i)

@@ -478,3 +478,175 @@ def test_the_backward_kernels_are_chosen_by_the_shape(s, d, kernels):
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
     assert (s * d * 4 <= flash_pallas.DQ_RESIDENT_BYTES) == (kernels == {flash_pallas.FLASH_BWD_FUSED})
     assert set(re.findall(r"dstpu_flash_bwd\w*", text)) == kernels
+
+
+# -- the three classes of a causal block pair (PR 52) -------------------------------------------
+# Blocks of 256 are cut into strips of 128 (two tiles a side) and blocks of 512
+# into four: at 3 x 3 and 2 x 2 blocks a sequence has pairs above, under and on
+# the diagonal. The backward kernels class and cut; the forward runs every pair
+# whole under the mask, as before.
+
+@pytest.mark.parametrize("s, bq, bk, want", [
+    # the train cells: 6 / 6 / 4, strips of 256, 40 of 64 tiles, 8.5 of 10 pairs' products
+    (4096, 1024, 1024, (6, 6, 4, 256, 40, 64, 8.5)),
+    (768, 256, 256, (3, 3, 3, 128, 9, 12, 5.25)),
+    (1024, 512, 512, (1, 1, 2, 128, 20, 32, 2.25)),
+    # blocks that differ are not cut: the pairs the diagonal crosses run whole
+    (4096, 1024, 512, (12, 12, 8, 0, 8, 8, 20.0)),
+    # a block too small to hold two 128-lane tiles runs whole too
+    (512, 128, 128, (6, 6, 4, 0, 4, 4, 10.0)),
+    (384, 384, 384, (0, 0, 1, 128, 6, 9, 6 / 9)),   # not a power of two: three strips
+    (320, 320, 320, (0, 0, 1, 0, 1, 1, 1.0)),       # 128 does not divide it
+])
+def test_causal_pair_classes(s, bq, bk, want):
+    got = flash_pallas.causal_pair_classes(s, bq, bk)
+    assert tuple(got) + (got.pairs_of_products,) == want
+    assert got.pruned + got.under + got.diagonal == (s // bq) * (s // bk)
+
+
+def _classes_traced(monkeypatch):
+    """Record, while the kernels are traced, the class of every body they build
+    and the number of rectangles it is computed by."""
+    seen = []
+    inner = flash_pallas._pair_strips
+
+    def spy(cls, *a, **kw):
+        strips = inner(cls, *a, **kw)
+        seen.append((cls, len(strips)))
+        return strips
+
+    monkeypatch.setattr(flash_pallas, "_pair_strips", spy)
+    return seen
+
+
+_CLASS_VARIANTS = {
+    "causal": {},
+    "segments": {"segments": True},
+    "alibi": {"alibi": True},
+    "gqa": {"h_kv": 2},
+    "gqa_segments_alibi": {"h_kv": 2, "segments": True, "alibi": True},
+}
+
+
+def _class_case(variant, s):
+    from deepspeed_tpu.models.transformer import alibi_slopes
+
+    spec = dict(_CLASS_VARIANTS[variant])
+    q, k, v = _qkv(b=1, h=4, h_kv=spec.pop("h_kv", None), s=s, d=64, seed=3)
+    kw = {}
+    if spec.pop("segments", False):
+        kw["segment_ids"] = _packed_segments(1, s, n_seg=3)
+    if spec.pop("alibi", False):
+        kw["alibi_slopes"] = jnp.asarray(alibi_slopes(4))
+    return q, k, v, kw
+
+
+@pytest.mark.parametrize("block, s", [(256, 768), (512, 1024)])
+@pytest.mark.parametrize("variant", list(_CLASS_VARIANTS))
+def test_causal_classes_match_reference(monkeypatch, variant, block, s):
+    """Forward and the three gradients against the float32 reference where a
+    sequence has pairs of all three classes and a diagonal pair is cut into
+    strips by the backward kernels: what a strip leaves out is an exact zero."""
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", str(block))
+    classes = flash_pallas.causal_pair_classes(s, block, block)
+    assert min(classes.pruned, classes.under, classes.diagonal) >= 1
+    assert classes.strip and block // classes.strip >= 2
+    seen = _classes_traced(monkeypatch)
+    q, k, v, kw = _class_case(variant, s)
+
+    def loss_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=True, **kw)
+        return jnp.sum(jnp.square(out)), out
+
+    def loss_ref(q, k, v):
+        out = mha_reference(q, k, v, causal=True, **kw)
+        return jnp.sum(jnp.square(out)), out
+
+    gf, out = jax.grad(loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    gr, ref = jax.grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+    for a, b_, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=1e-3, atol=1e-3, err_msg=f"d{name}")
+    # the backward built two bodies: no causal mask under the diagonal, strips on
+    # it (the forward classes nothing: every pair whole, in one body)
+    assert set(seen) == {(None, 1), ("cut", block // classes.strip)}
+
+
+@pytest.mark.parametrize("window_flag", [None, 0, 1], ids=["static", "wflag0", "wflag1"])
+def test_a_window_keeps_the_whole_pair_path(monkeypatch, window_flag):
+    """A window, static or toggled by ``wflag``, needs the positions in every
+    pair: blocks that would be cut run whole under the masks, as before, and
+    still match the reference."""
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", "256")
+    s, window = 768, 200
+    assert flash_pallas.causal_pair_classes(s, 256, 256).strip == 128
+    seen = _classes_traced(monkeypatch)
+    q, k, v = _qkv(b=1, h=2, s=s, d=64, seed=5)
+    kw = {} if window_flag is None else {"window_flag": jnp.int32(window_flag)}
+    ref_window = 0 if window_flag == 0 else window
+
+    def loss_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window, interpret=True, **kw)
+        return jnp.sum(jnp.square(out)), out
+
+    def loss_ref(q, k, v):
+        out = mha_reference(q, k, v, causal=True, window=ref_window)
+        return jnp.sum(jnp.square(out)), out
+
+    gf, out = jax.grad(loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    gr, ref = jax.grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
+    for a, b_, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=1e-3, atol=1e-3, err_msg=f"d{name}")
+    assert set(seen) == {("masked", 1)}
+
+
+@pytest.mark.parametrize("variant", list(_CLASS_VARIANTS))
+def test_the_backward_kernels_share_the_strips_bitwise(monkeypatch, variant):
+    """The bitwise test above runs blocks of 128, which are not cut. Here the
+    diagonal pairs go by strips (blocks of 256 at s = 768): the fused kernel
+    against the dq and dk/dv kernels, which take their products from the same
+    strips in the same order, to the last bit."""
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", "256")
+    q, k, v, kw = _class_case(variant, 768)
+    g = jax.random.normal(jax.random.key(11), q.shape, q.dtype)
+    run = lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=True, **kw)
+
+    def grads(budget):
+        monkeypatch.setattr(flash_pallas, "DQ_RESIDENT_BYTES", budget)
+        return jax.vjp(run, q, k, v)[1](g)
+
+    fused = grads(flash_pallas.DQ_RESIDENT_BYTES)
+    two = grads(0)
+    for a, b_, name in zip(fused, two, "qkv"):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_), err_msg=f"d{name}")
+    assert all(np.abs(np.asarray(a)).max() > 0 for a in fused)
+
+
+def test_a_traced_backward_records_its_pair_classes(monkeypatch):
+    """Once a traced causal backward the tracer gets ``flash.causal_pairs`` with
+    the call's count; a forward alone classes nothing, and a windowed call,
+    whose pairs all run whole, records none."""
+    from deepspeed_tpu.observability import tracing
+
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", "256")
+    old = tracing.get_tracer()
+    tracer = tracing.set_tracer(tracing.SpanTracer())
+    try:
+        q = jax.ShapeDtypeStruct((1, 2, 768, 64), jnp.float32)
+
+        def loss(q, k, v, **kw):
+            return flash_attention(q, k, v, causal=True, interpret=True, **kw).sum()
+
+        jax.eval_shape(loss, q, q, q)
+        jax.eval_shape(jax.grad(functools.partial(loss, window=200)), q, q, q)
+        assert not tracer.ring_spans()
+        jax.eval_shape(jax.grad(loss), q, q, q)
+        spans = [sp for sp in tracer.ring_spans() if sp.name == "flash.causal_pairs"]
+    finally:
+        tracing.set_tracer(old)
+    assert [sp.args for sp in spans] == [{
+        "s": 768, "block": 256, "pruned": 3, "under": 3, "diagonal": 3, "strip": 128,
+        "live_tiles": 9, "tiles": 12, "pairs_of_products": 5.25}]

@@ -227,6 +227,32 @@ def test_the_flash_backward_compiles_for_a_v5e_at_the_train_cells_shape(one_chip
     assert FP.FLASH_BWD_DQ not in text and FP.FLASH_BWD_DKV not in text
 
 
+def test_the_flash_kernels_compile_for_a_v5e_with_every_mask_operand(one_chip, on_the_chip):
+    """The same shape with segment ids, ALiBi slopes and positions all present
+    (PR 52: each kernel now holds two bodies, the pairs under the diagonal
+    without the causal mask and, in the backward, the pairs on it unrolled
+    into four strips): forward and fused backward still fit the 16 MiB
+    scoped-VMEM default, which the call does not raise."""
+    from deepspeed_tpu.ops.attention import flash_pallas as FP
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert FP.causal_pair_classes(4096, 1024, 1024) == (6, 6, 4, 256, 40, 64)
+
+    def grads(q, k, v, seg, slopes, pos, do):
+        out, vjp = jax.vjp(lambda q, k, v: FP.flash_attention(
+            q, k, v, causal=True, segment_ids=seg, alibi_slopes=slopes, alibi_positions=pos), q, k, v)
+        return (out,) + tuple(vjp(do))
+
+    q, kv = S((1, 16, 4096, 128)), S((1, 8, 4096, 128))
+    text = jax.jit(grads).lower(q, kv, kv, S((1, 4096), jnp.int32), S((16,), jnp.float32),
+                                S((1, 4096), jnp.int32), q).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert sum(FP.FLASH_FWD in ln for ln in calls) == 1 and sum(FP.FLASH_BWD_FUSED in ln for ln in calls) == 1
+
+
 @pytest.mark.parametrize("Rc,tq", [(0, 0), (1, 128), (2, 128)],
                          ids=["decode_only", "one_chunk_row", "two_chunk_rows"])
 def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chip, on_the_chip,

@@ -141,6 +141,26 @@ class TestRingBitwise:
                 np.asarray(r), np.asarray(a), err_msg=name
             )
 
+    def test_fwd_bwd_bitwise_where_diagonal_pairs_go_by_strips(self, cp_topo, monkeypatch):
+        """Blocks of 256 are cut into strips of 128 on the diagonal (PR 52):
+        a shard of 512 holds pairs under and on it, and the ring's diagonal
+        chunk classes them as the one kernel does, so the bits still agree."""
+        monkeypatch.setenv("DSTPU_FLASH_BLOCK", "256")
+        assert fp.causal_pair_classes(512, 256, 256)[:4] == (1, 1, 2, 128)
+        b, s = 2, 512 * get_topology().sizes["context"]
+        q, k, v, g = _qkv(b=b, s=s, hk=2, seed=2)
+        seg = jnp.broadcast_to((jnp.arange(s)[None, :] // 200).astype(jnp.int32), (b, s))
+        ref = _vjp_all(
+            lambda q, k, v: fp.flash_attention(q, k, v, causal=True, segment_ids=seg,
+                                               interpret=True),
+            q, k, v, g)
+        ring = _vjp_all(
+            lambda q, k, v: ring_flash_attention(q, k, v, causal=True, segment_ids=seg,
+                                                 interpret=True),
+            q, k, v, g)
+        for r, a, name in zip(ref, ring, ("out", "dq", "dk", "dv")):
+            np.testing.assert_array_equal(np.asarray(r), np.asarray(a), err_msg=name)
+
     def test_matches_reference_numerics(self, cp_topo):
         """Anchor the whole stack to the jnp einsum (not just the kernel)."""
         q, k, v, _ = _qkv(seed=2)
