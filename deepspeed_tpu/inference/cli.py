@@ -353,7 +353,7 @@ def engine_config_from_args(args, cfg):
         # size the pool from a byte budget: under int8 the same budget
         # holds ~2x blocks (kv_pool.bytes_per_block) — this is where the
         # capacity multiplier reaches admission
-        # (a DeltaNet model's state slots or a mixed stack's window rings,
+        # (a recurrent model's state slots, DeltaNet's or Mamba's, or a mixed stack's window rings,
         # one a tracked sequence and a spare, come out of the same budget first)
         # (a latent model's pool is one plane of one vector a token, kv_layers
         # deep: two planes a layer where a layer holds two attentions; a pool's

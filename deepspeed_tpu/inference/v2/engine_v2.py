@@ -7,9 +7,10 @@ blocked KV cache and returns next-token logits per sequence.
 TPU adaptation:
   * the paged KV cache is [L, num_blocks, block_size, n_kv, d] per k/v, L the
     layers that attend over keys and values (``kv_layers``); a model with
-    Gated DeltaNet layers (``layer_kinds``) has a second kind of cache beside
-    it, one fixed-size slot a tracked sequence: the layers' recurrent states
-    [Lg * slots, nv, dk, dv] float32 and conv inputs [Lg * slots, (K - 1) * C];
+    recurrent layers (``layer_kinds``: Gated DeltaNet or Mamba) has a second
+    kind of cache beside it, one fixed-size slot a tracked sequence: the
+    layers' states [Lr * slots, ...] float32 (DeltaNet [nv, dk, dv], Mamba
+    [N, d / 128, 128]) and conv inputs [Lr * slots, (K - 1) * C];
     a stack that mixes window and global layers holds the window layers' K/V
     in a window pool [Lw, slots * wb, block_size, n_kv, d], a ring of wb
     blocks a tracked sequence (kv_pool.py), and the block pool the global
@@ -117,6 +118,11 @@ def _materialize_rows(res: dict, want_tokens: bool = False) -> dict:
     return out
 
 
+# layers of one kind in a row that a hybrid stack's step programs loop over
+# and do not unroll (_drive_layers)
+RUN_LOOP = 5
+
+
 @dataclasses.dataclass
 class StepStats:
     """What one step or round was sized to and what it carried, filled where
@@ -124,7 +130,7 @@ class StepStats:
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
     paged_live_blocks_total / paged_table_slots_total / paged_programs_total,
     chunk_live_blocks_total
-    / chunk_table_slots_total, moe_*_total, gdn_*_total, kv_*_total,
+    / chunk_table_slots_total, moe_*_total, gdn_*_total, mamba_*_total, kv_*_total,
     paged_window_live_blocks_total, latent_decode_rows_total /
     latent_decode_blocks_total / latent_live_blocks_total,
     moe_group_hit_tokens_total, steps_ahead_total,
@@ -152,6 +158,9 @@ class StepStats:
     # DeltaNet layers: rows whose state took the one-token update, of ONE
     # layer (every such layer sees the same); 0 for a model without them
     gdn_decode_rows: int = 0
+    # Mamba layers: the same, and the prompt tokens the chunked scan walked
+    mamba_decode_rows: int = 0
+    mamba_chunk_tokens: int = 0
     # chunk attention: key blocks the chunk rows hold (pool blocks below the
     # chunk's start + the chunk's own) against the slots a walk of whole
     # tables and whole chunks covers, of one layer (_count_chunk)
@@ -244,7 +253,9 @@ class InferenceEngineV2:
         # the block pool, a ring a tracked sequence
         self._windowed = model_config.window_layers > 0
         # what a sequence holds beside its K/V blocks, for the refusals' words
-        self._beside = ("Gated DeltaNet layers keep a recurrent state" if self._hybrid else
+        # the stack's recurrent layer kind (models.transformer.RECURRENT), or None
+        self._rec = T.RECURRENT.get(model_config.recurrent_kind)
+        self._beside = (self._rec.words if self._hybrid else
                         "window layers keep their K/V in a window pool" if self._windowed else None)
         if self._beside:
             self._refuse_at_build(model_config, quantized, tp)
@@ -275,7 +286,7 @@ class InferenceEngineV2:
             )
         self.params = params
         kv = self.config.kv_cache
-        # a DeltaNet model, or one with a window pool: one slot a tracked
+        # a model with recurrent layers, or one with a window pool: one slot a tracked
         # sequence, and one spare that the padding of a step's grid points at
         self._state_slots = (
             self.config.state_manager.max_tracked_sequences + 1 if self._beside else 0)
@@ -457,12 +468,12 @@ class InferenceEngineV2:
             if self._kv_int8:
                 self._ks_cache = jnp.zeros(sshape, jnp.float32)
                 self._vs_cache = jnp.zeros(sshape, jnp.float32)
-        # the second kind of cache, flat over the DeltaNet layers so that the
+        # the second kind of cache, flat over the recurrent layers so that the
         # decode kernel indexes [layer's ordinal * slots + slot] in place:
         # recurrent states float32 (as transformers keeps them) and the conv's
         # last K - 1 inputs in the compute dtype
-        self._gdn_state = self._gdn_conv = None
-        self._gdn_impl = None  # gdn_decode's pick by platform; tests name one
+        self._rec_state = self._rec_conv = None
+        self._rec_impl = None  # the decode rule's pick by platform; tests name one
         self._cache_kinds = T.cache_kinds(c)
         self._ordinals = np.asarray(T.cache_ordinals(c), np.int32)
         # the window pool: the spare slot's ring is its trash
@@ -474,12 +485,12 @@ class InferenceEngineV2:
             self._wk_cache = jnp.zeros(self._k_shape(lead, nkv, dk), dtype)
             self._wv_cache = jnp.zeros(lead + (nkv, dv), dtype)
         if self._hybrid:
-            n = c.kind_count("gdn") * self._state_slots
-            self._gdn_state = jnp.zeros(
-                (n, c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim), jnp.float32)
+            n = c.kind_count(c.recurrent_kind) * self._state_slots
+            self._rec_state = jnp.zeros((n,) + self._rec.state_shape(c), jnp.float32)
             # (a slot's K - 1 inputs flat in one row: a [.., 3, C] pool takes a
             # padded tiling and a layout copy a step on either side of the loop)
-            self._gdn_conv = jnp.zeros((n, (c.gdn_conv_kernel - 1) * c.gdn_conv_dim), dtype)
+            self._rec_conv = jnp.zeros(
+                (n, (self._rec.kernel(c) - 1) * self._rec.channels(c)), dtype)
         self._programs = {}  # (kind, shape) -> compiled step program (_launch)
         self._kv_scatter_jit = None  # handoff import: donated pool scatter
         # chunked re-import: ONE fixed window shape (tail padded into the
@@ -561,11 +572,12 @@ class InferenceEngineV2:
         return tuple(lead) + ((nkv * dk,) if keys_flat(dk) else (nkv, dk))
 
     def _refuse_at_build(self, c, quantized: bool, tp: int) -> None:
-        """A model with a second kind of cache (DeltaNet layers' recurrent
-        state, or window layers' window pool): what cannot carry it yet
-        raises here or is switched off with one log line, and never drops it.
-        A cache hit, a spilled block, an exported block or a rejected draft
-        names K/V blocks alone; the state a sequence's DeltaNet layers hold
+        """A model with a second kind of cache (recurrent layers' states,
+        DeltaNet's or Mamba's, or window layers' window pool): what cannot
+        carry it yet raises here or is switched off with one log line, and
+        never drops it. A cache hit, a spilled block, an exported block or a
+        rejected draft names K/V blocks alone; the state a sequence's
+        recurrent layers hold
         after the same tokens, or the window layers' keys and values of
         them, would be lost, stale or misread."""
         kv = self.config.kv_cache
@@ -617,7 +629,7 @@ class InferenceEngineV2:
     def _refuse_state_loss(self, what: str) -> None:
         """Raise where an operation moves or rolls back a sequence's cache by
         K/V blocks alone (handoff, recovery, host tier, speculative verify):
-        with DeltaNet layers the recurrent state would be dropped, with a
+        with recurrent layers their states would be dropped, with a
         window pool the window layers' keys and values."""
         if self._beside:
             raise NotImplementedError(
@@ -680,7 +692,7 @@ class InferenceEngineV2:
     def kv_pool_info(self) -> Dict:
         """Byte-accounting snapshot for health()/metrics: pool bytes,
         bytes/block, dtype, capacity multiplier vs bf16 (kv_pool.describe),
-        plus the resolved attention impl, and for a model with DeltaNet
+        plus the resolved attention impl, and for a model with recurrent
         layers the state slots and their bytes."""
         from deepspeed_tpu.inference.v2.kv_pool import describe, pool_geometry
 
@@ -713,8 +725,9 @@ class InferenceEngineV2:
             # the second kind of cache: one slot a tracked sequence + a spare
             from deepspeed_tpu.inference.v2.kv_pool import state_slot_bytes
 
-            per_slot = state_slot_bytes(c, self._gdn_conv.dtype.itemsize)
+            per_slot = state_slot_bytes(c, self._rec_conv.dtype.itemsize)
             info.update(
+                state_kind=c.recurrent_kind,
                 state_slots=self._state_slots,
                 state_slots_in_use=self.state_manager.state_slot_accounting()["live"],
                 state_bytes_per_slot=per_slot,
@@ -1332,16 +1345,16 @@ class InferenceEngineV2:
     def _pools(self):
         """The pools as the step programs take them and give them back: ONE
         argument, ``(k, v)``, a latent model's ``(c,)``, with an int8 pool
-        ``(k, v, ks, vs)``, with DeltaNet layers ``(k, v, states, conv inputs)``, donated whole —
+        ``(k, v, ks, vs)``, with recurrent layers ``(k, v, states, conv inputs)``, donated whole —
         whatever else a program takes, and whichever kinds of cache the model
         has, every leaf of it is updated in place."""
-        second = ((self._gdn_state, self._gdn_conv) if self._hybrid else
+        second = ((self._rec_state, self._rec_conv) if self._hybrid else
                   (self._wk_cache, self._wv_cache) if self._windowed else ())
         return tuple(self._kv_pool_planes().values()) + second
 
     def _split_pools(self, pools):
         """(the K/V planes, the second kind of cache or ()) of a program's
-        ``pools``: a DeltaNet model's state pools, which ride the layer
+        ``pools``: a recurrent kind's state pools, which ride the layer
         loop's carry, or a mixed stack's window pools (k, v), which are its
         invariants like the block pools."""
         n = len(pools) - (2 if self._beside else 0)
@@ -1545,16 +1558,16 @@ class InferenceEngineV2:
         return c.n_experts + (1 if c.moe_n_group > 1 or c.moe_zero_experts else 0)
 
     def _with_state(self, carry, second):
-        """The carry with a DeltaNet model's (states, conv inputs) in it (a
+        """The carry with a recurrent kind's (states, conv inputs) in it (a
         window pool is no part of a carry: the layers read it as it was)."""
         if self._hybrid:
-            carry = dict(carry, gdn_state=second[0], gdn_conv=second[1])
+            carry = dict(carry, rec_state=second[0], rec_conv=second[1])
         return carry
 
     @staticmethod
     def _state_of(carry):
         """The state pools a layer loop gives back: the tail of ``_pools()``."""
-        return (carry["gdn_state"], carry["gdn_conv"]) if "gdn_state" in carry else ()
+        return (carry["rec_state"], carry["rec_conv"]) if "rec_state" in carry else ()
 
     @staticmethod
     def _record_moe(carry, li, moe_counts):
@@ -1726,14 +1739,38 @@ class InferenceEngineV2:
             period, n = T.layer_period(c)
             P = len(period)
             ords = T.kind_ordinals(c)[:P]
-            common = {k: v for k, v in sliced.items() if k not in ("full", "gdn")}
+            common = {k: v for k, v in sliced.items() if not isinstance(v, dict)}
+
+            # a period's layers in RUNS of one kind. A short run is unrolled
+            # (Qwen3-Next's three DeltaNet layers and one attention layer, as
+            # ever); a run of RUN_LOOP layers or more (Jamba's seven and six
+            # Mamba layers either side of its attention layer) is a loop with
+            # ONE traced body: fourteen unrolled layers a program cost 147 s
+            # of tracing in four programs (my chip run, PR 53)
+            runs, j = [], 0
+            while j < P:
+                k = j
+                while k < P and period[k] == period[j]:
+                    k += 1
+                runs.append((j, k))
+                j = k
 
             def run_period(pi, x, carry, take):
-                for j, kind in enumerate(period):
-                    li, ki = pi * P + j, pi * period.count(kind) + ords[j]
+                def layer(j, j0, x, carry, take):
+                    # layer j of the period, in the run that starts at j0
+                    kind = period[j0]
+                    li, ki = pi * P + j, pi * period.count(kind) + ords[j0] + (j - j0)
                     lp = {**jax.tree.map(lambda a: take(a, li), common),
                           **jax.tree.map(lambda a: take(a, ki), sliced[kind]), **whole}
-                    x, carry = layer_fn(lp, x, li, carry, window=windows)
+                    return layer_fn(lp, x, li, carry, window=windows)
+
+                for j0, j1 in runs:
+                    if j1 - j0 >= RUN_LOOP:
+                        x, carry = jax.lax.fori_loop(
+                            j0, j1, lambda j, st, j0=j0: layer(j, j0, *st, traced), (x, carry))
+                    else:
+                        for j in range(j0, j1):
+                            x, carry = layer(j, j0, x, carry, take)
                 return x, carry
 
             if n == 1:
@@ -1937,58 +1974,53 @@ class InferenceEngineV2:
         x = x + attn_out + mlp_out if c.parallel_block else x + mlp_out
         return x, counts
 
-    def _gdn_layer(self, lp, x, li, rows, carry):
-        """One Gated DeltaNet layer of a step, on the carried state pools.
-        ``rows`` describes the step's grid: its first ``R`` slots are decode
-        rows, one token each, at ``slots`` [R] with ``live`` [R]; a split
-        step with chunks has ``Rc`` rows of ``tq`` tokens behind them
-        (``chk_slots`` / ``chk_start`` [Rc], ``chk_pos`` [Rc, tq], -1 where
-        a slot holds no token). Decode rows take the one-token update IN the
-        pool (``gdn_decode``: on a TPU the kernel, one read and one write of
-        a row's state); chunk rows run the chunked rule from the slot's
-        state to the slot's state, a chunk at position 0 from zero whatever
-        the slot holds. A slot of the grid that is not live has ``g = beta =
-        0`` and takes no conv input, so state and conv state stay as they
-        were; padding points at the spare slot. Returns (x, carry, the
-        layer's routed rows)."""
-        from deepspeed_tpu.ops.linear_attention import causal_conv, gdn_chunked, gdn_decode
-
-        c = self._mc
+    def _recurrent_layer(self, lp, x, li, rows, carry):
+        """One recurrent layer of a step (Gated DeltaNet or Mamba:
+        ``T.RECURRENT`` gives the kind's projections and its two rules), on the
+        carried state pools. ``rows`` describes the step's grid: its first
+        ``R`` slots are decode rows, one token each, at ``slots`` [R] with
+        ``live`` [R]; a split step with chunks has ``Rc`` rows of ``tq``
+        tokens behind them (``chk_slots`` / ``chk_start`` [Rc], ``chk_pos``
+        [Rc, tq], -1 where a slot holds no token). Decode rows take the
+        one-token update IN the pool (the kind's ``decode``: on a TPU a
+        kernel, one read and one write of a row's state); chunk rows run the
+        kind's ``chunk`` rule from the slot's state to the slot's state, a
+        chunk at position 0 from zero whatever the slot holds. A slot of the
+        grid that is not live takes no conv input and leaves its state as it
+        was (the kind's rules see to that); padding points at the spare slot.
+        Returns (x, carry, the layer's routed rows)."""
+        c, kind = self._mc, self._rec
         R = rows["R"]
-        nv = c.gdn_value_heads
         base = self._ordinal(li) * self._state_slots
-        state, conv = carry["gdn_state"], carry["gdn_conv"]
+        state, conv = carry["rec_state"], carry["rec_conv"]
         a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
-        qkv, z, g, beta = T.gdn_project(c, lp, a[0])
+        y_in, extras = kind.project(c, lp, a[0])
 
-        taps = (c.gdn_conv_kernel - 1, c.gdn_conv_dim)  # a slot's row of the conv pool
+        taps = (kind.kernel(c) - 1, kind.channels(c))  # a slot's row of the conv pool
         slots, live = base + rows["slots"], rows["live"]
-        y, new = causal_conv(qkv[:R, None], lp["gdn_conv"], conv[slots].reshape((R,) + taps),
-                             n=live.astype(jnp.int32))
+        y, new = T.recurrent_conv(kind, lp, y_in[:R, None], conv[slots].reshape((R,) + taps),
+                                  n=live.astype(jnp.int32))
         conv = conv.at[slots].set(new.reshape(R, -1))
-        q, k, v = T.gdn_heads(c, y[:, 0])
-        o, state = gdn_decode(
-            q, k, v, jnp.where(live[:, None], g[:R], 0.0), jnp.where(live[:, None], beta[:R], 0.0),
-            state, slots, impl=self._gdn_impl)
+        o, state = kind.decode(c, lp, y[:, 0], tuple(e[:R] for e in extras), live, state, slots,
+                               self._rec_impl)
         if rows.get("tq"):
             Rc, tq = rows["Rc"], rows["tq"]
             slots = base + rows["chk_slots"]
             tok_live = rows["chk_pos"] >= 0                              # [Rc, tq]
             fresh = rows["chk_start"] == 0
             conv0 = jnp.where(fresh[:, None, None], 0, conv[slots].reshape((Rc,) + taps))
-            y, new = causal_conv(qkv[R:].reshape(Rc, tq, -1), lp["gdn_conv"], conv0,
-                                 n=jnp.sum(tok_live, axis=1, dtype=jnp.int32))
+            y, new = T.recurrent_conv(kind, lp, y_in[R:].reshape(Rc, tq, -1), conv0,
+                                      n=jnp.sum(tok_live, axis=1, dtype=jnp.int32))
             conv = conv.at[slots].set(new.reshape(Rc, -1))
-            q, k, v = T.gdn_heads(c, y)
-            o_c, new = gdn_chunked(
-                q, k, v, jnp.where(tok_live[..., None], g[R:].reshape(Rc, tq, nv), 0.0),
-                jnp.where(tok_live[..., None], beta[R:].reshape(Rc, tq, nv), 0.0),
-                jnp.where(fresh[:, None, None, None], 0.0, state[slots]))
+            old = state[slots]
+            o_c, new = kind.chunk(
+                c, lp, y, tuple(e[R:].reshape((Rc, tq) + e.shape[1:]) for e in extras), tok_live,
+                jnp.where(fresh.reshape((Rc,) + (1,) * (old.ndim - 1)), 0.0, old), self._rec_impl)
             state = state.at[slots].set(new)
             o = jnp.concatenate([o, o_c.reshape((Rc * tq,) + o_c.shape[2:])], axis=0)
-        out = T.gdn_output(c, lp, o, z, x.dtype)[None]
+        out = kind.output(c, lp, o, extras, x.dtype)[None]
         x, moe = self._mlp_tail(lp, x, out, rows["slot_live"], li)
-        return x, self._record_moe(dict(carry, gdn_state=state, gdn_conv=conv), li, moe)
+        return x, self._record_moe(dict(carry, rec_state=state, rec_conv=conv), li, moe)
 
     # ------------------------------------------------------------------
     def _split_layer(self, lp, x, li, meta, carry, window=None):
@@ -2004,15 +2036,15 @@ class InferenceEngineV2:
         the R decode slots alone and the chunk half is absent, not empty:
         no chunk attention is traced. No read needs this step's K/V from the pool, so the
         layer only records them in ``carry`` (the side buffers) and the
-        pool is written once, after the loop. A DeltaNet layer of the same
-        step (``layer_kinds``) goes through ``_gdn_layer`` instead."""
+        pool is written once, after the loop. A recurrent layer of the same
+        step (``layer_kinds``) goes through ``_recurrent_layer`` instead."""
         c = self._mc
         w = c.sliding_window if window is None else window
         nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
         R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        if "gdn_qkv" in lp:  # a DeltaNet layer: no K/V, the state pools instead
-            return self._gdn_layer(lp, x, li, {
+        if self._hybrid and "wq" not in lp:  # a recurrent layer: no K/V, the state pools instead
+            return self._recurrent_layer(lp, x, li, {
                 "R": R, "Rc": Rc, "tq": tq, "slots": meta.get("dec_slots"),
                 "live": meta["dec_pos"] >= 0, "slot_live": meta["slot_live"],
                 "chk_slots": meta.get("chk_slots"), "chk_start": meta.get("chk_start"),
@@ -2223,8 +2255,8 @@ class InferenceEngineV2:
         c = self._mc
         w = c.sliding_window if window is None else window
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        if "gdn_qkv" in lp:  # a DeltaNet layer: the carried state, updated in place
-            return self._gdn_layer(lp, x, li, {
+        if self._hybrid and "wq" not in lp:  # a recurrent layer: the carried state, updated in place
+            return self._recurrent_layer(lp, x, li, {
                 "R": x.shape[1], "slots": meta["slots"], "live": meta["active"],
                 "slot_live": meta["active"]}, carry)
         kn, vn, li_kv = self._side_names(li)
@@ -2561,7 +2593,7 @@ class InferenceEngineV2:
         prefill = sum(len(t) for _, t, _, _ in chk_rows)
         self.last_step = StepStats(
             T_, total_tokens, prefill, **self._count_paged(dec_pos),
-            gdn_decode_rows=len(dec_rows) if self._hybrid else 0,
+            **self._count_recurrent(len(dec_rows), prefill),
             **self._count_chunk(chk_rows, tq), **self._count_cache(dec_pos, total_tokens),
         )
         if self._latent:
@@ -2623,6 +2655,15 @@ class InferenceEngineV2:
             inputs["active"][i] = True
         return R, inputs
 
+    def _count_recurrent(self, decode_rows: int, chunk_tokens: int):
+        """StepStats' fields of the stack's recurrent kind: the rows whose
+        states took the one-token update and, for a kind that counts them, the
+        prompt tokens its chunk rule walked, of ONE such layer."""
+        kind = self._mc.recurrent_kind
+        if kind == "mamba":
+            return {"mamba_decode_rows": decode_rows, "mamba_chunk_tokens": chunk_tokens}
+        return {"gdn_decode_rows": decode_rows} if kind else {}
+
     def _stage_round(self, uids, n: int):
         """A fused decode round of ``n`` steps over ``uids``: cache key and
         inputs by name."""
@@ -2630,7 +2671,7 @@ class InferenceEngineV2:
         inputs["tokens"] = inputs["tokens"][:, 0]
         self.last_step = StepStats(
             R * n, len(uids) * n, 0, **self._count_paged(inputs["positions"], calls=n),
-            gdn_decode_rows=len(uids) * n if self._hybrid else 0,
+            **self._count_recurrent(len(uids) * n, 0),
             **self._count_cache(
                 np.where(inputs["active"], inputs["positions"], -1), len(uids) * n, calls=n))
         return ("round", n), inputs
@@ -2693,7 +2734,7 @@ class InferenceEngineV2:
         if self._kv_int8:
             self._ks_cache, self._vs_cache = pools[2:]
         if self._hybrid:
-            self._gdn_state, self._gdn_conv = second
+            self._rec_state, self._rec_conv = second
         elif self._windowed:
             self._wk_cache, self._wv_cache = second
         return outputs

@@ -17,10 +17,11 @@ all L layers and BOTH pools, i.e. the unit ``free_blocks`` admission counts.
 At head_dim=128 the int8 ratio is 2*128/(128+4) ≈ 1.94x — the ≥1.9x
 capacity bar the acceptance tests pin.
 
-A model with Gated DeltaNet layers also keeps, for every tracked sequence and
-whatever its length, one STATE SLOT: a layer's recurrent state [value heads,
-key dim, value dim] float32 and its conv's last K - 1 inputs, over the Lg such
-layers (``state_slot_bytes``). ``blocks_for_budget`` takes the slots, one a
+A model with recurrent layers (Gated DeltaNet, Mamba) also keeps, for every
+tracked sequence and whatever its length, one STATE SLOT: a layer's recurrent
+state in float32 (DeltaNet [value heads, key dim, value dim], Mamba [state
+size, channels]) and its conv's last K - 1 inputs, over the such layers
+(``state_slot_bytes``). ``blocks_for_budget`` takes the slots, one a
 tracked sequence and the spare, from the budget first and sizes the K/V
 blocks from what is left.
 
@@ -52,6 +53,7 @@ head's keys are wider than the 128 lanes and do not fill them whole
 or is laid out tokens-minor and copied whole in front of every kernel call).
 """
 
+import math
 from typing import Any, Dict, Tuple
 
 import numpy as np
@@ -151,13 +153,18 @@ def bytes_per_block(block_size: int, kv_heads: int, head_dim,
 
 
 def state_slot_bytes(config, conv_itemsize: int = 2) -> int:
-    """HBM bytes of one sequence's state slot over a model's DeltaNet layers
-    (``config``: the TransformerConfig): float32 states and the conv inputs in
-    the compute dtype. 0 for a model without such layers."""
-    layers = config.kind_count("gdn")
-    state = config.gdn_value_heads * config.gdn_key_dim * config.gdn_value_dim * 4
-    conv = (config.gdn_conv_kernel - 1) * config.gdn_conv_dim * conv_itemsize
-    return layers * (state + conv) if layers else 0
+    """HBM bytes of one sequence's state slot over a model's recurrent layers
+    (``config``: the TransformerConfig; ``models.transformer.RECURRENT``
+    describes the kind's slot): float32 states and the conv inputs in the
+    compute dtype. 0 for a model without such layers."""
+    from deepspeed_tpu.models.transformer import RECURRENT
+
+    if config.recurrent_kind is None:
+        return 0
+    kind = RECURRENT[config.recurrent_kind]
+    state = math.prod(kind.state_shape(config)) * 4
+    conv = (kind.kernel(config) - 1) * kind.channels(config) * conv_itemsize
+    return config.kind_count(config.recurrent_kind) * (state + conv)
 
 
 def window_blocks(window: int, block_size: int) -> int:

@@ -11,7 +11,7 @@ stack's window pool may have other KV heads than its block pool, a value
 another width than a key) and each sequence owns an ordered list of block ids; token t of a sequence
 lives in block ``table[t // block_size]`` at row ``t % block_size``.
 
-A model with recurrent-state layers (Gated DeltaNet) has a second kind of
+A model with recurrent-state layers (Gated DeltaNet, Mamba) has a second kind of
 cache: ONE fixed-size state slot a tracked sequence, whatever its length,
 taken when the sequence is created (admission) and given back when it is
 flushed (finish, cancel, expiry: every one of them ends in

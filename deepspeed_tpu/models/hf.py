@@ -8,7 +8,9 @@ a (:class:`TransformerConfig`, stacked-params pytree) pair that trains or
 serves through ``deepspeed_tpu.initialize`` / ``init_inference`` unchanged.
 
 Supported ``model_type``s: llama, mistral, qwen2, qwen2_moe, qwen3, qwen3_next
-(Gated DeltaNet and gated-attention layers, a share of the experts),
+(Gated DeltaNet and gated-attention layers, a share of the experts), jamba
+(Mamba-1 layers with normed dt / B / C beside attention layers without
+positions; the dense form, ``num_experts`` 1),
 exaone_moe (K-EXAONE: window and full attention layers, output-normed blocks,
 a dense lead layer then sigmoid-routed experts), axk1 (A.X-K1: DeepseekV3's
 latent attention, one low-rank vector a token in place of per-head keys and
@@ -178,6 +180,45 @@ def _router_groups(get, mt: str):
     if n_group < 1 or not 0 < topk_group <= n_group:
         raise ValueError(f"{mt}: n_group={n_group}, topk_group={topk_group}")
     return n_group, topk_group
+
+
+def _jamba_config(get) -> TransformerConfig:
+    """Jamba (``jamba``): Mamba-1 layers with RMSNorms on dt, B and C, and every
+    ``attn_layer_period``-th layer (from ``attn_layer_offset``) grouped softmax
+    attention with NO position term (the state-space layers carry the order);
+    the layer order is ``JambaConfig.layers_block_type``'s. Every layer's
+    feed-forward is a gated MLP: a stack with experts (``num_experts > 1``)
+    is refused, since an expert layer every ``expert_layer_period`` among dense
+    ones is a third stacking of the MLP's parameters that nothing here has."""
+    experts = int(get("num_experts", 1) or 1)
+    if experts > 1:
+        raise ValueError(
+            f"jamba: num_experts={experts}: only the dense form (num_experts 1, every "
+            "feed-forward a gated MLP) is supported; expert layers alternating with dense "
+            "ones by expert_layer_period have no stacking here")
+    n_layers = int(get("num_hidden_layers"))
+    period, offset = int(get("attn_layer_period", 8)), int(get("attn_layer_offset", 4))
+    kinds = tuple("full" if i % period == offset else "mamba" for i in range(n_layers))
+    if "mamba" not in kinds or "full" not in kinds:
+        raise ValueError(
+            f"jamba: attn_layer_period={period}, attn_layer_offset={offset} over {n_layers} "
+            "layers leaves one kind of layer alone; a stack needs both")
+    if not bool(get("mamba_conv_bias", True)) or bool(get("mamba_proj_bias", False)):
+        raise ValueError(
+            "jamba: mamba_conv_bias false or mamba_proj_bias true: only the published form "
+            "(a conv with bias, projections without) is supported")
+    hidden = int(get("hidden_size"))
+    rank = get("mamba_dt_rank", "auto")
+    return _llama_like_config(
+        get,
+        position="none",
+        layer_kinds=kinds,
+        mamba_d_inner=int(get("mamba_expand", 2)) * hidden,
+        mamba_d_state=int(get("mamba_d_state", 16)),
+        mamba_dt_rank=-(-hidden // 16) if rank == "auto" else int(rank),
+        mamba_conv_kernel=int(get("mamba_d_conv", 4)),
+        norm_eps=float(get("rms_norm_eps", 1e-6)),
+    )
 
 
 def _axk1_config(get) -> TransformerConfig:
@@ -549,6 +590,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
             moe_shared_expert_dim=get("shared_expert_intermediate_size", 0) or 0,
             moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)),
         )
+    if mt == "jamba":
+        return _jamba_config(get)
     if mt == "exaone_moe":
         return _exaone_moe_config(get)
     if mt == "axk1":
@@ -1026,7 +1069,7 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
         "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
-        "qwen3, qwen3_moe, qwen3_next, exaone_moe, axk1, mimo_v2_flash, longcat_flash, "
+        "qwen3, qwen3_moe, qwen3_next, jamba, exaone_moe, axk1, mimo_v2_flash, longcat_flash, "
         "megatron_gpt, bert, "
         "distilbert, "
         "clip_text_model"
@@ -1134,6 +1177,33 @@ def _qwen3_next_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict
     layers["shared_up"].append(take.linear(f"{p}.mlp.shared_expert.up_proj.weight"))
     layers["shared_down"].append(take.linear(f"{p}.mlp.shared_expert.down_proj.weight"))
     layers["shared_gate_proj"].append(take.linear(f"{p}.mlp.shared_expert_gate.weight"))
+
+
+def _jamba_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
+    """One Jamba layer, by its kind: ``mamba.*`` (the conv's weight [d, 1, K]
+    -> [K, d], ``A_log`` [d, N] -> [N, d]: ops/state_space keeps the channels
+    on the lanes) or ``self_attn.*``; then the gated MLP every layer has."""
+    i = int(p.rsplit(".", 1)[1])
+    layers["attn_norm"].append(take(f"{p}.input_layernorm.weight"))
+    layers["mlp_norm"].append(take(f"{p}.pre_ff_layernorm.weight"))
+    if cfg.layer_kinds[i] == "full":
+        for name in ("q", "k", "v", "o"):
+            layers["full"][f"w{name}"].append(take.linear(f"{p}.self_attn.{name}_proj.weight"))
+    else:
+        m = layers["mamba"]
+        m["mamba_in"].append(take.linear(f"{p}.mamba.in_proj.weight"))
+        m["mamba_conv"].append(take(f"{p}.mamba.conv1d.weight")[:, 0, :].T)
+        m["mamba_conv_b"].append(take(f"{p}.mamba.conv1d.bias"))
+        m["mamba_x"].append(take.linear(f"{p}.mamba.x_proj.weight"))
+        m["mamba_dt"].append(take.linear(f"{p}.mamba.dt_proj.weight"))
+        m["mamba_dt_b"].append(take(f"{p}.mamba.dt_proj.bias"))
+        m["mamba_a_log"].append(take(f"{p}.mamba.A_log").T)
+        m["mamba_d"].append(take(f"{p}.mamba.D"))
+        m["mamba_out"].append(take.linear(f"{p}.mamba.out_proj.weight"))
+        for name in ("dt", "b", "c"):
+            m[f"mamba_{name}_norm"].append(take(f"{p}.mamba.{name}_layernorm.weight"))
+    for name, hf in (("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj")):
+        layers[name].append(take.linear(f"{p}.feed_forward.{hf}.weight"))
 
 
 def _exaone_moe_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
@@ -1623,6 +1693,7 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "qwen2_moe": _llama_layer,
     "qwen3": _llama_layer,
     "qwen3_next": _qwen3_next_layer,
+    "jamba": _jamba_layer,
     "exaone_moe": _exaone_moe_layer,
     "axk1": _axk1_layer,
     "mimo_v2_flash": _mimo_v2_flash_layer,
@@ -1660,6 +1731,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
     "qwen2_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_next": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "jamba": ("model.embed_tokens.weight", "model.final_layernorm", "model.layers", None),
     "exaone_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "axk1": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "mimo_v2_flash": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
@@ -1752,8 +1824,12 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
 
         out: Dict[str, Any] = {k: [] for k in keys if k not in ATTENTION_KEYS}
         out["full"] = {k: [] for k in keys if k in ATTENTION_KEYS}
-        out["gdn"] = {k: [] for k in ("gdn_qkv", "gdn_z", "gdn_ba", "gdn_conv", "gdn_dt_bias",
-                                      "gdn_a_log", "gdn_norm", "gdn_out")}
+        own = {"gdn": ("gdn_qkv", "gdn_z", "gdn_ba", "gdn_conv", "gdn_dt_bias", "gdn_a_log",
+                       "gdn_norm", "gdn_out"),
+               "mamba": ("mamba_in", "mamba_conv", "mamba_conv_b", "mamba_x", "mamba_dt_norm",
+                         "mamba_b_norm", "mamba_c_norm", "mamba_dt", "mamba_dt_b", "mamba_a_log",
+                         "mamba_d", "mamba_out")}[cfg.recurrent_kind]
+        out[cfg.recurrent_kind] = {k: [] for k in own}
         return out
     return {k: [] for k in keys}
 

@@ -36,7 +36,7 @@ import functools
 import math
 from contextlib import contextmanager
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -312,18 +312,26 @@ class TransformerConfig:
     attn_sink_window: bool = False
     window_rope_theta: float = 0.0
     attn_value_scale: float = 1.0
-    # per-layer KIND (qwen3-next): n_layers names, "full" (softmax attention
-    # over cached keys and values) or "gdn" (Gated DeltaNet: a recurrent state
-    # and a short causal conv, ops/linear_attention). None: every layer "full".
-    # Parameters are stacked by kind: what every layer has (norms, MLP or
-    # experts) on [n_layers], attention under params["layers"]["full"] on
-    # [number of full layers], DeltaNet under params["layers"]["gdn"].
+    # per-layer KIND (qwen3-next, jamba): n_layers names, "full" (softmax
+    # attention over cached keys and values) or ONE recurrent kind
+    # (``RECURRENT``): "gdn" (Gated DeltaNet, ops/linear_attention) or "mamba"
+    # (a selective state-space layer, ops/state_space), each a recurrent state
+    # and a short causal conv. None: every layer "full". Parameters are stacked
+    # by kind: what every layer has (norms, MLP or experts) on [n_layers],
+    # attention under params["layers"]["full"] on [number of full layers], the
+    # recurrent kind's under params["layers"]["gdn"] / ["mamba"].
     layer_kinds: Optional[Tuple[str, ...]] = None
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
     gdn_conv_kernel: int = 4
+    # a "mamba" layer (Mamba-1 as Jamba writes it): channels (expand x hidden),
+    # numbers of state a channel, the rank of the step size's projection
+    mamba_d_inner: int = 0
+    mamba_d_state: int = 0
+    mamba_dt_rank: int = 0
+    mamba_conv_kernel: int = 4
     # qwen3-next gated attention output: q_proj is twice as wide, per head a
     # query and a gate, and the heads' output is multiplied by sigmoid(gate)
     # in front of wo (stored apart as wq_gate [h, n_heads * head_dim])
@@ -508,12 +516,17 @@ class TransformerConfig:
                     f"entries for {self.n_layers} layers"
                 )
         if self.layer_kinds is not None:
-            bad = set(self.layer_kinds) - {"full", "gdn"}
-            if bad or len(self.layer_kinds) != self.n_layers:
+            bad = set(self.layer_kinds) - {"full", "gdn", "mamba"}
+            if (bad or len(self.layer_kinds) != self.n_layers
+                    or {"gdn", "mamba"} <= set(self.layer_kinds)):
                 raise ValueError(
                     f"layer_kinds={self.layer_kinds!r}: expected {self.n_layers} "
-                    "names, each 'full' or 'gdn'"
+                    "names, each 'full' or ONE of 'gdn' and 'mamba'"
                 )
+            if "mamba" in self.layer_kinds and min(
+                    self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank) < 1:
+                raise ValueError(
+                    "a 'mamba' layer needs mamba_d_inner / mamba_d_state / mamba_dt_rank")
             if "gdn" in self.layer_kinds and (
                 min(self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
                     self.gdn_value_dim) < 1
@@ -619,7 +632,16 @@ class TransformerConfig:
     @property
     def hybrid(self) -> bool:
         """True where the layers are of more than one kind (``layer_kinds``)."""
-        return self.layer_kinds is not None and "gdn" in self.layer_kinds
+        return self.recurrent_kind is not None
+
+    @property
+    def recurrent_kind(self) -> Optional[str]:
+        """The stack's recurrent layer kind ("gdn" or "mamba": ``RECURRENT``
+        describes it), None for a stack of attention layers alone."""
+        for kind in ("gdn", "mamba"):
+            if self.layer_kinds is not None and kind in self.layer_kinds:
+                return kind
+        return None
 
     def kind_count(self, kind: str) -> int:
         if self.layer_kinds is None:
@@ -877,6 +899,26 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     into_stream = (latent_gain["experts"] if by_component else 1.0 / math.sqrt(2 * L) if c.hybrid
                    else 1.0)
     unit_stream = c.hybrid or by_component or c.norm_scheme == "out"
+    # A TIED head reads the embedding back. At unit rms a logit would be a sum
+    # of h products of unit size (std 51 at 2,560: a bf16 rounding of it as
+    # large as the benchmark's whole limit on a served token's shortfall), and
+    # the token's OWN row, still a unit-rms part of the stream the blocks added
+    # fractions to, would score 47 where every other row scores a unit normal:
+    # the model would repeat its input whatever the layers compute, and no
+    # fault in them could turn a served token. So a hybrid model with a tied
+    # head (jamba) draws its embedding near h ** -0.5: logits of unit scale, the
+    # token's own row a few units at most, and a stream that the blocks'
+    # outputs make up, each still 1 / sqrt(2 layers) of unit gain. (It has no
+    # router: nothing DECIDES on a rounding, which is what the unit stream is for.)
+    # ... at 0.35 of h ** -0.5, the head's gain: a shortfall scales with the
+    # logits, and 26 Mamba layers' worth of bf16 roundings read 0.10-0.13 under
+    # the float32 reference's best logit at unit logits (limit 0.15) where a
+    # norm, the conv's bias, D or a state lost read 5-7; at 0.4 bf16 read
+    # 0.04-0.065 and rotary in the two attention layers 0.21-0.26 (my chip
+    # runs, PR 53: PERF.md section 6): the limit sits between them at 0.35
+    embed_std = 1.0 if unit_stream else 0.02
+    if c.hybrid and c.tie_embeddings:
+        embed_std = 0.35 * h ** -0.5
 
     # rmsnorm_1p's effective scale is (1 + w): identity init is ZEROS there
     norm_one = jnp.zeros if c.norm == "rmsnorm_1p" else jnp.ones
@@ -909,7 +951,12 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
         """Per-head attention's parameters on ``n`` layers of ``nkv`` KV heads."""
         dv = c.value_dim
         out = dict(
-            wq=dense(next(keys), (n, h, nh * d), h, latent_gain["q"] if c.attn_by_kind else 1.0),
+            # (a mamba stack's two attention layers have to ATTEND, as (1) above
+            # says of a.x-k1's: at unit gain over thousands of keys the softmax is
+            # near uniform and rotary applied where the model has no positions read
+            # 0.17 against bf16's own 0.10: my chip run, PR 53)
+            wq=dense(next(keys), (n, h, nh * d), h,
+                     latent_gain["q"] if c.attn_by_kind or c.recurrent_kind == "mamba" else 1.0),
             wk=dense(next(keys), (n, h, nkv * d), h),
             wv=dense(next(keys), (n, h, nkv * dv), h),
             wo=dense(next(keys), (n, nh * dv, h), nh * dv,
@@ -955,6 +1002,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     if c.hybrid:
         layers = {k: v for k, v in layers.items() if k not in ATTENTION_KEYS} | {
             "full": {k: v for k, v in layers.items() if k in ATTENTION_KEYS}}
+    if c.recurrent_kind == "gdn":
         Lg, nv = c.kind_count("gdn"), c.gdn_value_heads
         vd = nv * c.gdn_value_dim
         # the gates as such layers are TRAINED from (the flash-linear-attention
@@ -977,10 +1025,40 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             "gdn_norm": jnp.ones((Lg, c.gdn_value_dim), dtype),
             "gdn_out": dense(next(keys), (Lg, vd, h), vd, into_stream),
         }
+    if c.recurrent_kind == "mamba":
+        Lm, di, N, R = c.kind_count("mamba"), c.mamba_d_inner, c.mamba_d_state, c.mamba_dt_rank
+        # as such layers are TRAINED from (the Mamba code and ``transformers``'
+        # JambaMambaMixer): A = -(1 .. N) a channel, D = 1, a step dt
+        # log-uniform in [1e-3, 1e-1] with the step's bias its inverse softplus
+        # and its projection at the std of uniform(+-rank ** -0.5), the conv's
+        # weight and bias as torch draws a Conv1d's, uniform(+-K ** -0.5)
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (Lm, di), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        K = c.mamba_conv_kernel
+        layers["mamba"] = {
+            "mamba_in": dense(next(keys), (Lm, h, 2 * di), h),  # u | z
+            "mamba_conv": jax.random.uniform(
+                next(keys), (Lm, K, di), jnp.float32, -(K ** -0.5), K ** -0.5).astype(dtype),
+            "mamba_conv_b": jax.random.uniform(
+                next(keys), (Lm, di), jnp.float32, -(K ** -0.5), K ** -0.5).astype(dtype),
+            "mamba_x": dense(next(keys), (Lm, di, R + 2 * N), di),  # dt | B | C
+            "mamba_dt_norm": jnp.ones((Lm, R), dtype),
+            "mamba_b_norm": jnp.ones((Lm, N), dtype),
+            "mamba_c_norm": jnp.ones((Lm, N), dtype),
+            "mamba_dt": dense(next(keys), (Lm, R, di), R, 3 ** -0.5),
+            "mamba_dt_b": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            # [N, d]: a state's numbers by rows, the channels on the lanes
+            # (ops/state_space; a checkpoint's A_log is [d, N])
+            "mamba_a_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None], (Lm, N, di)
+            ).astype(dtype),
+            "mamba_d": jnp.ones((Lm, di), dtype),
+            "mamba_out": dense(next(keys), (Lm, di, h), di, into_stream),
+        }
     def dense_mlp(n):
         out = {"w_up": dense(next(keys), (n, h, ffn), h),
                "w_down": dense(next(keys), (n, ffn, h), ffn,
-                               latent_gain["mlp"] if by_component else 1.0)}
+                               latent_gain["mlp"] if by_component else into_stream)}
         if c.activation in ("swiglu", "geglu"):
             out["w_gate"] = dense(next(keys), (n, h, ffn), h)
         return out
@@ -1038,7 +1116,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
 
     params: Dict[str, Any] = {
         "embed": (jax.random.normal(next(keys), (c.vocab_size, h), jnp.float32)
-                  * (1.0 if unit_stream else 0.02)).astype(dtype),
+                  * embed_std).astype(dtype),
         "layers": layers,
     }
     if c.final_norm:
@@ -1909,18 +1987,141 @@ def gdn_output(c: TransformerConfig, lp, o, z, dtype):
     return _proj(c, y.reshape(y.shape[:-2] + (-1,)), lp["gdn_out"])
 
 
-def _gdn_block(c: TransformerConfig, lp, x):
-    """Gated DeltaNet for one layer with no cache: conv and state start from
-    zero. x: [b, s, h] (normed)."""
-    from deepspeed_tpu.ops.linear_attention import causal_conv, gdn_chunked
+def mamba_select(c: TransformerConfig, lp, u):
+    """What a Mamba layer reads off its conv's output ``u [..., d]`` (float32):
+    (the step size delta ``[..., d]`` float32, B and C ``[..., N]``), each of dt,
+    B and C through its RMSNorm (Jamba's addition to Mamba-1)."""
+    R, N = c.mamba_dt_rank, c.mamba_d_state
+    dtype = DTYPES[c.dtype]
+    x = _proj(c, u.astype(dtype), lp["mamba_x"])
+    dt, B, C = (_norm(a, lp[k], None, "rmsnorm", c.norm_eps) for a, k in (
+        (x[..., :R], "mamba_dt_norm"), (x[..., R : R + N], "mamba_b_norm"),
+        (x[..., R + N :], "mamba_c_norm")))
+    delta = jax.nn.softplus(
+        (dt @ lp["mamba_dt"]).astype(jnp.float32) + lp["mamba_dt_b"].astype(jnp.float32))
+    return delta, B, C
 
-    b = x.shape[0]
-    qkv, z, g, beta = gdn_project(c, lp, x)
-    conv0 = jnp.zeros((b, c.gdn_conv_kernel - 1, c.gdn_conv_dim), x.dtype)
-    q, k, v = gdn_heads(c, causal_conv(qkv, lp["gdn_conv"], conv0)[0])
-    state0 = jnp.zeros((b, c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim), jnp.float32)
-    o, _ = gdn_chunked(q, k, v, g, beta, state0)
-    return gdn_output(c, lp, o, z, x.dtype)
+
+class RecurrentKind(NamedTuple):
+    """A recurrent layer kind: what a sequence keeps for one such layer (a
+    float32 state and the last ``kernel - 1`` inputs of a short causal conv
+    over ``channels``, in the compute dtype) and its two rules. ``models.forward``
+    and the served step (``engine_v2._recurrent_layer``, which owns the slots)
+    read a kind through this description alone.
+
+      project(c, lp, a [t, h]) -> (the conv's input [t, channels], extras: a
+          tuple of arrays [t, ...] the rules and ``output`` take back)
+      decode(c, lp, y [R, channels], extras, live [R], pool, slots, impl) ->
+          (o [R, ...], pool): one token a row on the rows' states IN the pool; a
+          row that is not live leaves its state as it was
+      chunk(c, lp, y [r, t, channels], extras, live [r, t], state [r, ...], impl) ->
+          (o [r, t, ...], the states after the rows' live tokens)
+      output(c, lp, o [t, ...], extras, dtype) -> [t, h]
+    """
+    words: str                      # the refusals' words for what a sequence holds
+    state_shape: Callable           # c -> a layer's state a sequence
+    channels: Callable              # c -> the conv's channels
+    kernel: Callable                # c -> the conv's taps
+    conv_keys: Tuple[str, Optional[str]]  # the conv's weight and bias in ``lp``
+    project: Callable
+    decode: Callable
+    chunk: Callable
+    output: Callable
+
+
+def _gdn_project(c, lp, a):
+    qkv, z, g, beta = gdn_project(c, lp, a)
+    return qkv, (z, g, beta)
+
+
+def _gdn_decode(c, lp, y, extras, live, pool, slots, impl):
+    from deepspeed_tpu.ops.linear_attention import gdn_decode
+
+    _, g, beta = extras
+    q, k, v = gdn_heads(c, y)
+    return gdn_decode(q, k, v, jnp.where(live[:, None], g, 0.0),
+                      jnp.where(live[:, None], beta, 0.0), pool, slots, impl=impl)
+
+
+def _gdn_chunk(c, lp, y, extras, live, state, impl=None):
+    from deepspeed_tpu.ops.linear_attention import gdn_chunked
+
+    _, g, beta = extras
+    q, k, v = gdn_heads(c, y)
+    return gdn_chunked(q, k, v, jnp.where(live[..., None], g, 0.0),
+                       jnp.where(live[..., None], beta, 0.0), state)
+
+
+def _mamba_project(c, lp, a):
+    uz = _proj(c, a, lp["mamba_in"])
+    return uz[..., : c.mamba_d_inner], (uz[..., c.mamba_d_inner :],)
+
+
+def _mamba_rule(c, lp, u, live):
+    """(delta, zero where a token is not live; B; C; A = -exp(A_log); D)."""
+    delta, B, C = mamba_select(c, lp, u)
+    return (jnp.where(live[..., None], delta, 0.0), B, C,
+            -jnp.exp(lp["mamba_a_log"].astype(jnp.float32)), lp["mamba_d"])
+
+
+def _mamba_decode(c, lp, y, extras, live, pool, slots, impl):
+    from deepspeed_tpu.ops.state_space import mamba_decode
+
+    delta, B, C, A, D = _mamba_rule(c, lp, y, live)
+    return mamba_decode(y, delta, B, C, extras[0], A, D, pool, slots, impl=impl)
+
+
+def _mamba_chunk(c, lp, y, extras, live, state, impl=None):
+    from deepspeed_tpu.ops.state_space import mamba_scan
+
+    delta, B, C, A, D = _mamba_rule(c, lp, y, live)
+    return mamba_scan(y, delta, B, C, extras[0], A, D, state, impl=impl)
+
+
+def _mamba_state_shape(c):
+    from deepspeed_tpu.ops.state_space import state_shape
+
+    return state_shape(c.mamba_d_inner, c.mamba_d_state)
+
+
+RECURRENT: Dict[str, RecurrentKind] = {
+    "gdn": RecurrentKind(
+        words="Gated DeltaNet layers keep a recurrent state",
+        state_shape=lambda c: (c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim),
+        channels=lambda c: c.gdn_conv_dim, kernel=lambda c: c.gdn_conv_kernel,
+        conv_keys=("gdn_conv", None),
+        project=_gdn_project, decode=_gdn_decode, chunk=_gdn_chunk,
+        output=lambda c, lp, o, extras, dtype: gdn_output(c, lp, o, extras[0], dtype)),
+    "mamba": RecurrentKind(
+        words="Mamba layers keep a selective state-space state",
+        state_shape=_mamba_state_shape,
+        channels=lambda c: c.mamba_d_inner, kernel=lambda c: c.mamba_conv_kernel,
+        conv_keys=("mamba_conv", "mamba_conv_b"),
+        project=_mamba_project, decode=_mamba_decode, chunk=_mamba_chunk,
+        # (the scan gates its output by silu(z) itself)
+        output=lambda c, lp, o, extras, dtype: _proj(c, o.astype(dtype), lp["mamba_out"])),
+}
+
+
+def recurrent_conv(kind: RecurrentKind, lp, x, state, n=None):
+    """The kind's short causal conv (``causal_conv``) with the layer's weight
+    and, where the kind has one, bias."""
+    from deepspeed_tpu.ops.linear_attention import causal_conv
+
+    w, b = kind.conv_keys
+    return causal_conv(x, lp[w], state, n=n, bias=lp[b] if b else None)
+
+
+def _recurrent_block(c: TransformerConfig, lp, x):
+    """A recurrent layer (``RECURRENT``) with no cache: conv and state start
+    from zero. x: [b, s, h] (normed)."""
+    kind = RECURRENT[c.recurrent_kind]
+    b, s = x.shape[:2]
+    y, extras = kind.project(c, lp, x)
+    y, _ = recurrent_conv(kind, lp, y, jnp.zeros((b, kind.kernel(c) - 1, kind.channels(c)), x.dtype))
+    o, _ = kind.chunk(c, lp, y, extras, jnp.ones((b, s), bool),
+                      jnp.zeros((b,) + kind.state_shape(c), jnp.float32))
+    return kind.output(c, lp, o, extras, x.dtype)
 
 
 def _mlp_block(c: TransformerConfig, lp, x):
@@ -2021,11 +2222,11 @@ def _layer(c: TransformerConfig, lp, x, positions, segment_ids, local_flag=None)
         x = x + _norm(mlp_out, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
         return _act_constraint(x), aux_loss
     a = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
-    if "gdn_qkv" in lp:  # a DeltaNet layer (layer_kinds): its own keys say so
+    if c.hybrid and "wq" not in lp:  # a recurrent layer (layer_kinds): no attention's keys
         if segment_ids is not None:
-            raise NotImplementedError("packed sequences through a 'gdn' layer: the "
+            raise NotImplementedError("packed sequences through a recurrent layer: the "
                                       "state would have to reset at each boundary")
-        attn_out = _gdn_block(c, lp, a)
+        attn_out = _recurrent_block(c, lp, a)
     else:
         attn_out, _ = _attention_block(c, lp, a, positions, segment_ids, local_flag=local_flag)
     if c.parallel_block:
@@ -2099,7 +2300,7 @@ def forward_hidden(
             for kind in set(period)}
         common = jax.tree.map(
             lambda a: a.reshape((n, len(period)) + a.shape[1:]),
-            {k: v for k, v in pl_.items() if k not in ("full", "gdn")})
+            {k: v for k, v in pl_.items() if not isinstance(v, dict)})
 
         def period_body(x, xs_p):
             common_p, kinds_p = xs_p

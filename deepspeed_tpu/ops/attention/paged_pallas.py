@@ -193,6 +193,27 @@ def _head_major(k, nkv: int, d: int):
     return jnp.stack([k[:, w:w + W] for w in starts], axis=0)
 
 
+def _values_head_major(v):
+    """A block of values head-major ``[nkv, nk, dv]``, inside a kernel: ``[nk,
+    nkv, dv]`` swapped once, or ONE head's ``[nk, dv]`` as it lies (``one_head``)."""
+    return v[None] if v.ndim == 2 else jnp.swapaxes(v, 0, 1)
+
+
+def one_head(k_cache, v_cache):
+    """The pools as the kernels read ONE KV head (20 query heads on it, say):
+    a token's head is its row, ``[NB, bs, d]``, which is what the bytes already
+    are. Read a row a head, ``[bs, 1, d]`` a block, the chip's compiler wants
+    the pool tiled two tokens a tile and copies it whole in front of every call
+    (described v5e, PR 53: two copies of 173 M elements a decode step); a
+    reshape that drops the axis of one is no copy. K then takes the kernels'
+    keys-a-token-a-row form (``keys_flat``), V the same through
+    ``_values_head_major``. Returns (k, v) unchanged for any other pool (an
+    int8 pool's scale planes have no such form)."""
+    if v_cache.shape[2] != 1 or v_cache.dtype == jnp.int8:
+        return k_cache, v_cache
+    return (k_cache.reshape(k_cache.shape[:2] + (-1,)), v_cache.reshape(v_cache.shape[:2] + (-1,)))
+
+
 def _softmax_with_sink(scores, sink):
     """Softmax over the last axis of ``scores [..., nkv, group, S]`` with a
     head's learned ``sink [nkv, group]`` (or None) as one more logit in the
@@ -346,7 +367,7 @@ def _paged_kernel(*refs, bs, nkv, d, G=1, E=0, window=0, int8=False, sink=False)
 
         k, v = joined(k_refs, ks_refs), joined(v_refs, vs_refs)
         k = _head_major(k, nkv, d)  # [nkv, n x bs, d]
-        v = jnp.swapaxes(v, 0, 1)
+        v = _values_head_major(v)
         valid = None
         if masked:
             # a masked key's weight is exactly 0, and 0 x NaN is not: what the
@@ -375,7 +396,7 @@ def _paged_kernel(*refs, bs, nkv, d, G=1, E=0, window=0, int8=False, sink=False)
             if window:
                 valid = valid & jnp.logical_not(window_too_far(qpos, epos, window))
             _fold(q, _head_major(ke_ref[0], nkv, d).astype(q.dtype),
-                  jnp.swapaxes(ve_ref[0], 0, 1).astype(q.dtype), valid, *state)
+                  _values_head_major(ve_ref[0]).astype(q.dtype), valid, *state)
         # fully-masked token (all-trash padding): m never left NEG_INF and
         # every p degenerated to exp(0) — emit 0, matching the reference
         m, l, acc = m_scr[:, :, :1], l_scr[:, :, :1], acc_scr[...]
@@ -479,7 +500,9 @@ def _paged_kernel_call(q, k_cache, v_cache, block_tables, q_pos, trash_block, *,
     own block."""
     T, nh, d = q.shape
     NB, bs, nkv, dv = v_cache.shape
-    flat = k_cache.ndim == 3  # keys a token a row (keys_flat)
+    k_cache, v_cache = one_head(k_cache, v_cache)
+    flat = k_cache.ndim == 3  # keys a token a row (keys_flat; one_head)
+    v_row = (nkv, dv) if v_cache.ndim == 4 else (dv,)
     int8_pool = k_cache.dtype == jnp.int8
     # off the TPU the kernel only runs interpreted (CPU tests)
     interpret = bool(interpret) or not on_tpu()
@@ -524,11 +547,11 @@ def _paged_kernel_call(q, k_cache, v_cache, block_tables, q_pos, trash_block, *,
         # block (last two dims must be (8, 128)-aligned or whole)
         in_specs.append(per_row(1, E))
     in_specs.append(per_row(*qs.shape[1:]))
-    in_specs.extend(per_block(bs, *k_row) + per_block(bs, nkv, dv))
+    in_specs.extend(per_block(bs, *k_row) + per_block(bs, *v_row))
     if int8_pool:
         in_specs.extend(per_block(bs, nkv) + per_block(bs, nkv))
     if E:
-        in_specs.extend([per_row(E, *k_row), per_row(E, nkv, dv)])
+        in_specs.extend([per_row(E, *k_row), per_row(E, *v_row)])
     if sinks is not None:
         # whole, the same for every program: fetched once
         in_specs.append(pl.BlockSpec((nkv, group, 128), lambda g, *s: (0, 0, 0)))
@@ -564,7 +587,7 @@ def _paged_kernel_call(q, k_cache, v_cache, block_tables, q_pos, trash_block, *,
     if int8_pool:
         operands.extend([k_scale] * G + [v_scale] * G)
     if E:
-        operands.extend([extra_kv[0].reshape((T, E) + k_row), extra_kv[1]])
+        operands.extend([extra_kv[0].reshape((T, E) + k_row), extra_kv[1].reshape((T, E) + v_row)])
     if sinks is not None:
         operands.append(_sink_lanes(sinks, nkv, group))
     return pl.pallas_call(
@@ -592,14 +615,15 @@ def kernels_take(kv_heads: int, head_dim: int, v_head_dim: Optional[int] = None)
     """Whether a pool of this geometry goes to the paged kernels on the chip:
     THE predicate of the engine's ``auto`` gate, of ``paged_attention``'s and
     of ``chunk_kernel_takes``, so that both kernels or neither serve a pool.
-    A key of 128, 192 or 256, a value of 128 or 256, and 2, 4 or a multiple
-    of 8 KV heads: where the compiler for the chip reads a ``[bs, nkv, d]``
-    block of the pool in place. At 6 heads, at one and at a head of 64 it
-    copies the whole pool into another layout in front of every call
-    (described v5e, PRs 30 and 35): such a pool takes the dense form."""
+    A key of 128, 192 or 256, a value of 128 or 256, and 1, 2, 4 or a
+    multiple of 8 KV heads: where the compiler for the chip reads a ``[bs,
+    nkv, d]`` block of the pool in place (ONE head as the row it is:
+    ``one_head``). At 6 heads and at a head of 64 it copies the whole pool into
+    another layout in front of every call (described v5e, PRs 30 and 35): such
+    a pool takes the dense form."""
     dv = v_head_dim or head_dim
     return (head_dim in (128, 192, 256) and dv in (128, 256)
-            and (kv_heads in (2, 4) or kv_heads % 8 == 0))
+            and (kv_heads in (1, 2, 4) or kv_heads % 8 == 0))
 
 
 def _visit_list(q_pos, limit, bs: int, B: int, window: int, G: int = 1):
@@ -889,7 +913,7 @@ def _chunk_kernel(*refs, bs, tile, nh, nkv, d, dv, window, sink):
         qa = q_ref[0].reshape(nkv, M, q_ref.shape[-1])
         # [bs, nkv, d] from where the keys live, then head-major [nkv, bs, d]
         ka = _head_major(jnp.where(is_pool, k_ref[0], ke_ref[0]), nkv, d).astype(qa.dtype)
-        va = jnp.swapaxes(jnp.where(is_pool, v_ref[0], ve_ref[0]), 0, 1).astype(qa.dtype)
+        va = _values_head_major(jnp.where(is_pool, v_ref[0], ve_ref[0])).astype(qa.dtype)
         if masked:
             q_pos = q0 + i0 + (jax.lax.broadcasted_iota(jnp.int32, (1, M, bs), 1) & (tile - 1))
             k_pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, M, bs), 2)
@@ -943,7 +967,9 @@ def _paged_chunk_kernel_call(q, k_cache, v_cache, row_tables, q_pos, trash_block
     transposes XLA fuses into their neighbours."""
     Rc, tq, nh, d = q.shape
     NB, bs, nkv, dv = v_cache.shape
-    flat = k_cache.ndim == 3  # keys a token a row (keys_flat)
+    k_cache, v_cache = one_head(k_cache, v_cache)
+    flat = k_cache.ndim == 3  # keys a token a row (keys_flat; one_head)
+    v_row = (nkv, dv) if v_cache.ndim == 4 else (dv,)
     B = row_tables.shape[1]
     tile = int(tile) if tile else _chunk_tile(tq, bs, nh, d)
     if tq % tile or tile % bs or tile & (tile - 1):
@@ -979,10 +1005,10 @@ def _paged_chunk_kernel_call(q, k_cache, v_cache, row_tables, q_pos, trash_block
 
     group = nh // nkv
     M = group * tile
-    in_specs = [rows_spec(qs.shape[-1]), pool_spec(*k_row), pool_spec(nkv, dv),
-                side_spec(*k_row), side_spec(nkv, dv)]
+    in_specs = [rows_spec(qs.shape[-1]), pool_spec(*k_row), pool_spec(*v_row),
+                side_spec(*k_row), side_spec(*v_row)]
     operands = [qs, k_cache, v_cache, ke.reshape((Rc * tq // bs, bs) + k_row),
-                ve.reshape(Rc * tq // bs, bs, nkv, dv)]
+                ve.reshape((Rc * tq // bs, bs) + v_row)]
     if sinks is not None:
         in_specs.append(pl.BlockSpec((nkv, group, 128), lambda g, *s: (0, 0, 0)))
         operands.append(_sink_lanes(sinks, nkv, group))

@@ -72,8 +72,9 @@ def gated_rms_norm(o, z, w, eps: float):
     return w.astype(f32) * o * jax.nn.silu(z.astype(f32))
 
 
-def causal_conv(x, w, state, n=None):
-    """Depthwise causal convolution, no bias, then SiLU, with carried inputs.
+def causal_conv(x, w, state, n=None, bias=None):
+    """Depthwise causal convolution (``bias [C]`` added where given), then
+    SiLU, with carried inputs.
 
     x ``[r, t, C]``; w ``[K, C]`` (``w[j]`` multiplies the input ``K - 1 - j``
     tokens back); state ``[r, K - 1, C]``: the inputs before ``x[:, 0]``.
@@ -86,6 +87,8 @@ def causal_conv(x, w, state, n=None):
     ext = jnp.concatenate([state.astype(jnp.float32), x.astype(jnp.float32)], axis=1)
     wf = w.astype(jnp.float32)
     out = sum(ext[:, j : j + t] * wf[j] for j in range(K))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     if n is None:
         new = ext[:, t:]
     else:

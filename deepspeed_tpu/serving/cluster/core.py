@@ -248,6 +248,9 @@ class EngineCore:
             self._inc("moe_group_hit_tokens_total", moe.get("group_hit", 0))
         # a model with DeltaNet layers: the rows whose states took the update
         self._inc("gdn_decode_rows_total", held("gdn_decode_rows"))
+        # one with Mamba layers: the same, and the tokens the chunked scan walked
+        self._inc("mamba_decode_rows_total", held("mamba_decode_rows"))
+        self._inc("mamba_chunk_tokens_total", held("mamba_chunk_tokens"))
         # the cache as the step found it, by kind, summed a step; and what a
         # window layer's decode walks visit (beside paged_live_blocks_total)
         self._inc("kv_global_blocks_used_total", held("kv_global_blocks"))

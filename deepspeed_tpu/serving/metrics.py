@@ -152,6 +152,12 @@ class ServingMetrics:
             # recurrent state took the one-token update, of one such layer
             # a step
             "gdn_decode_rows_total": 0,
+            # Mamba layers: the same, and the prompt tokens one such layer's
+            # chunked scan walked
+            "mamba_decode_rows_total": 0,
+            "mamba_chunk_tokens_total": 0,
+            # a state slot's bytes (update_kv_pool_info: set once, no total)
+            "state_slot_bytes": 0,
             # the cache by kind (EngineCore._count_step), summed a step: blocks
             # of the block pool held, ring blocks of the window pool held (0
             # without one), tokens of context tracked; and the blocks one
@@ -335,6 +341,11 @@ class ServingMetrics:
             )
             self.gauges["kv_pool_bytes"] = info.get("kv_pool_bytes", 0)
             self.gauges["state_slots_total"] = info.get("state_slots", 0)
+            # what a tracked sequence's state slot costs over the recurrent
+            # layers (DeltaNet's or Mamba's), whatever its length: beside
+            # the K/V bytes a token it is what the best batch size turns on.
+            # Among the counters so that a snapshot of them carries it
+            self.counters["state_slot_bytes"] = info.get("state_bytes_per_slot", 0)
             self.gauges["kv_capacity_multiplier"] = info.get(
                 "kv_capacity_multiplier", 1.0
             )

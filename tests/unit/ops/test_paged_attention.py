@@ -480,7 +480,9 @@ def _ragged_call(rng, nh, nkv, d, int8=False):
 @pytest.mark.parametrize("G", PROGRAM_BLOCKS)
 @pytest.mark.parametrize("form", ["plain", "split", "split-int8"])
 # (16, 2, 256): Qwen3-Next's full-attention layers, 8 query heads a KV head
-@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (4, 1, 64), (16, 2, 256)])
+# (20, 1, 128): Jamba2-3B's attention layers, 20 query heads on ONE KV head
+@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (4, 1, 64), (16, 2, 256),
+                                      (20, 1, 128)])
 def test_paged_kernel_ragged_rows_in_one_call(nh, nkv, d, form, G):
     """Contexts 0, 1, bs-1, bs, bs+1, 2 bs + 5 and a full table beside an
     inactive slot in ONE call, ``G`` blocks a program. ``plain``: the query is the context's last token, against
@@ -695,7 +697,9 @@ def test_visit_list_against_a_hand_count(window, G):
     (2, 256, 256, 2, 4),    # Qwen3-Next's full layers: 256 KiB
     (8, 128, 128, 1, 4),    # an int8 pool of Qwen3's: its payload alone, 256 KiB
     (32, 128, 128, 2, 1),   # past a megabyte: one
-], ids=["olmoe", "qwen3", "mimo_window", "mimo_full", "qwen3_next", "qwen3_int8", "two_mib"])
+    (1, 128, 128, 2, 4),    # Jamba2-3B's one KV head: 64 KiB, four at most
+], ids=["olmoe", "qwen3", "mimo_window", "mimo_full", "qwen3_next", "qwen3_int8", "two_mib",
+        "jamba"])
 def test_blocks_a_program_at_the_cells_geometries(nkv, dk, dv, itemsize, want):
     """As many 128-token pool blocks as make a megabyte, four at most: the
     rule's one constant is set from kernel-alone times at these geometries
@@ -836,8 +840,8 @@ def _chunk_rows(tq):
     return [(0, tq), (5, tq - 3), (3 * CHUNK_BS, tq - CHUNK_BS // 2), (S - tq, tq), (0, 0)]
 
 
-# the serving cells' geometries: Qwen3, OLMoE, Qwen3-Next's attention layers
-@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (16, 2, 256)])
+# the serving cells' geometries: Qwen3, OLMoE, Qwen3-Next's and Jamba's attention layers
+@pytest.mark.parametrize("nh,nkv,d", [(16, 8, 128), (16, 16, 128), (16, 2, 256), (20, 1, 128)])
 @pytest.mark.parametrize("tq,tile", [(16, 16), (64, 16), (64, 32)])  # one query tile, four, two
 def test_chunk_kernel_matches_dense(nh, nkv, d, tq, tile):
     rng = np.random.default_rng(30)
@@ -899,7 +903,7 @@ def test_chunk_kernel_leaves_to_the_dense_form(case):
     (16, 8, 128, 128, True), (16, 16, 128, 128, True), (16, 2, 256, 128, True),  # the cells'
     (8, 4, 128, 128, True),
     (18, 6, 128, 128, False),   # 6 KV heads in a tile of 8: the pool is copied
-    (8, 1, 128, 128, False),    # one head of a bf16 pair: the pool is copied
+    (20, 1, 128, 128, True),    # ONE head, read as the row it is (paged_pallas.one_head, PR 53)
     (16, 8, 64, 128, False),    # a head of 64, half a lane tile: the pool is copied
     (16, 8, 128, 16, False),    # 16 keys a block
 ])

@@ -413,6 +413,33 @@ def tiny_qwen3_next(tmp_path_factory):
     return model, str(path)
 
 
+JAMBA_TINY = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=96, num_hidden_layers=8,
+    num_attention_heads=4, num_key_value_heads=1, attn_layer_period=4, attn_layer_offset=2,
+    num_experts=1, num_experts_per_tok=1, mamba_d_state=16, mamba_dt_rank=8, mamba_expand=2,
+    mamba_d_conv=4, use_mamba_kernels=False, tie_word_embeddings=True, rms_norm_eps=1e-6,
+    max_position_embeddings=128)
+
+
+@pytest.fixture(scope="module")
+def tiny_jamba(tmp_path_factory):
+    # two periods of (mamba, mamba, attention, mamba), 4 query heads on ONE
+    # key head, no positions, dense MLPs, a tied head; transformers' slow
+    # path (use_mamba_kernels=False). The norms' weights (dt / B / C norms
+    # among them), the conv's bias, A_log and D are moved off their initial
+    # values: a norm or a term left out would not show at ones and zeros.
+    torch.manual_seed(0)
+    cfg = transformers.JambaConfig(**JAMBA_TINY)
+    model = transformers.JambaForCausalLM(cfg).eval()
+    with torch.no_grad():
+        for name, prm in model.named_parameters():
+            if "layernorm" in name or name.endswith(("conv1d.bias", "A_log", "mamba.D")):
+                prm.add_(0.2 * torch.randn_like(prm))
+    path = tmp_path_factory.mktemp("hf_jamba")
+    model.save_pretrained(path)
+    return model, str(path)
+
+
 @pytest.fixture(scope="module")
 def tiny_bert(tmp_path_factory):
     # post-LN bidirectional encoder + token types + masked-LM head
@@ -531,6 +558,7 @@ _FIXTURES = {
     "qwen3_moe": "tiny_qwen3_moe",
     "olmoe": "tiny_olmoe",
     "qwen3_next": "tiny_qwen3_next",
+    "jamba": "tiny_jamba",
 }
 
 # gpt_neo's attn_scale=1.0 skips the 1/sqrt(d) shrink and bert's post-LN
@@ -888,7 +916,7 @@ def test_logits_parity(arch, request):
 @pytest.mark.parametrize(
     "arch",
     ["qwen2_moe", "falcon", "phi", "gemma", "bloom", "gptj", "gptneox", "mixtral", "stablelm",
-     "olmoe", "qwen3_next"],
+     "olmoe", "qwen3_next", "jamba"],
 )
 def test_greedy_decode_parity(arch, request):
     hf_model, path = request.getfixturevalue(_FIXTURES[arch])
@@ -1128,3 +1156,48 @@ def test_qwen3_next_expert_share_loads_its_own_experts(tiny_qwen3_next, tmp_path
         np.asarray(params["layers"]["w_down"][2, 1]), mlp.experts[3].down_proj.weight.detach().numpy().T)
     with pytest.raises(ValueError, match="not one chip's share"):
         config_from_hf({**hf, "num_experts": 3})
+
+
+def test_jamba_layers_by_kind_and_the_reference_agree(tiny_jamba):
+    """The loader stacks a Jamba checkpoint by kind (the conv's weight [d, 1,
+    K] -> [K, d], A_log [d, N] -> [N, d]), ``forward()`` and the benchmark's
+    plain reference (benchmarks/reference/jamba.py) both equal transformers'
+    ``JambaForCausalLM`` (slow path) on logits, over more tokens than a chunk
+    of the scan kernel holds."""
+    import importlib
+    import json
+    import os
+
+    ref = importlib.import_module("benchmarks.reference.jamba")
+    hf_model, path = tiny_jamba
+    cfg, params = load_hf_model(path, dtype="float32")
+    assert cfg.layer_kinds == ("mamba", "mamba", "full", "mamba") * 2
+    assert cfg.position == "none" and cfg.kv_heads == 1 and cfg.tie_embeddings
+    assert (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank) == (128, 16, 8)
+    mixer = hf_model.model.layers[1].mamba
+    m = {n: np.asarray(w[1]) for n, w in params["layers"]["mamba"].items()}
+    np.testing.assert_array_equal(m["mamba_conv"].T, mixer.conv1d.weight[:, 0].detach().numpy())
+    np.testing.assert_array_equal(m["mamba_a_log"].T, mixer.A_log.detach().numpy())
+    np.testing.assert_array_equal(m["mamba_in"].T, mixer.in_proj.weight.detach().numpy())
+    assert params["layers"]["full"]["wk"].shape == (2, 64, 16)
+    tokens = np.random.default_rng(3).integers(0, 256, size=(2, 70)).astype(np.int32)
+    with torch.no_grad():
+        want = hf_model(torch.tensor(tokens, dtype=torch.long)).logits.numpy()
+    with jax.default_matmul_precision("highest"):
+        ours, _ = forward(params, jnp.asarray(tokens), cfg)
+    # float32 on both sides: measured 7e-7 on logits of scale 0.8
+    np.testing.assert_allclose(np.asarray(ours), want, atol=2e-5, rtol=0)
+    hf = json.load(open(os.path.join(path, "config.json")))
+    got = np.stack([np.asarray(ref.logits(params, row, hf)) for row in tokens])
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(ref.logits(params, tokens[0], hf, rows=[3, 69]), got[0][[3, 69]], atol=1e-6)
+
+
+def test_jamba_with_experts_is_refused_with_its_reason():
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    hf = transformers.JambaConfig(**{**JAMBA_TINY, "num_experts": 4, "num_experts_per_tok": 2}).to_dict()
+    with pytest.raises(ValueError, match="num_experts=4"):
+        config_from_hf(hf)
+    with pytest.raises(ValueError, match="leaves one kind"):
+        config_from_hf({**transformers.JambaConfig(**JAMBA_TINY).to_dict(), "attn_layer_offset": 9})

@@ -120,7 +120,7 @@ def _two_row_engine(cfg, params, chunk=160):
                           "max_ragged_sequence_count": 4, "max_context": 512}}))
 
 
-_POOLS = ("_k_cache", "_v_cache", "_gdn_state", "_gdn_conv", "_wk_cache", "_wv_cache")
+_POOLS = ("_k_cache", "_v_cache", "_rec_state", "_rec_conv", "_wk_cache", "_wv_cache")
 
 
 class TestInferenceV1:

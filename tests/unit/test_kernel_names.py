@@ -125,3 +125,28 @@ def test_gdn_decode_is_named_and_updates_the_pool_in_place():
         _s((R, nv), f32), _s((slots, nv, dk, dv), f32), _s((R,), jnp.int32))
     assert _kernel_names(text) == {gated_delta.GDN_DECODE} == {"dstpu_gdn_decode"}
     assert "output_operand_aliases" in text or "operand_index = 6" in text
+
+
+def test_the_state_space_kernels_are_named_and_the_pool_is_updated_in_place():
+    """The chunked scan and the one-token update at Jamba2-3B's widths: the
+    custom calls carry the names the benchmark's readers look for, and the
+    decode kernel's state pool is aliased to its output."""
+    from deepspeed_tpu.ops.state_space import mamba
+
+    d, n, R, slots = 5120, 16, 32, 26 * 33
+    f32 = jnp.float32
+    row = (_s((R, d), f32), _s((R, d), f32), _s((R, n), f32), _s((R, n), f32), _s((R, d), f32))
+    text = _tpu_text(
+        lambda u, dt, B, C, z, A, D, pool, s: mamba.mamba_decode(u, dt, B, C, z, A, D, pool, s, impl="kernel"),
+        *row, _s((n, d), f32), _s((d,), f32), _s((slots,) + mamba.state_shape(d, n), f32),
+        _s((R,), jnp.int32))
+    assert _kernel_names(text) == {mamba.MAMBA_DECODE} == {"dstpu_mamba_decode"}
+    assert "output_operand_aliases" in text or "operand_index = 8" in text
+    chunk = (_s((2, 512, d), f32), _s((2, 512, d), f32), _s((2, 512, n), f32), _s((2, 512, n), f32),
+             _s((2, 512, d), f32))
+    text = _tpu_text(
+        lambda u, dt, B, C, z, A, D, S: mamba.mamba_scan(u, dt, B, C, z, A, D, S, impl="kernel"),
+        *chunk, _s((n, d), f32), _s((d,), f32), _s((2,) + mamba.state_shape(d, n), f32))
+    assert _kernel_names(text) == {mamba.MAMBA_SCAN} == {"dstpu_mamba_scan"}
+    # never the [tokens, d, N] tensor of the published loop
+    assert f"{512}x{d}x{n}" not in text and f"{d}x{n}x512" not in text

@@ -460,14 +460,16 @@ def test_what_cannot_carry_the_window_pool_is_refused_at_build(what, kw):
 
 def test_one_predicate_sends_a_geometry_to_the_kernels_or_to_the_dense_form(monkeypatch):
     """``kernels_take`` is the engine's ``auto`` gate and the chunk kernel's:
-    6 KV heads, one, a head of 64 resolve to ``dense`` in both places; this
-    model's two pools (192 | 128 at 4 and 8 heads) to the kernels."""
+    6 KV heads, a head of 64 resolve to ``dense`` in both places; this
+    model's two pools (192 | 128 at 4 and 8 heads) to the kernels, and since
+    PR 53 ONE head too (read as the row it is: ``paged_pallas.one_head``)."""
     import deepspeed_tpu.inference.v2.engine_v2 as E
     from deepspeed_tpu.ops.attention import paged_pallas as pp
 
     assert pp.kernels_take(4, 192, 128) and pp.kernels_take(8, 192, 128)
     assert pp.kernels_take(8, 128) and pp.kernels_take(2, 256) and pp.kernels_take(16, 128)
-    assert not (pp.kernels_take(6, 128) or pp.kernels_take(1, 128) or pp.kernels_take(8, 64)
+    assert pp.kernels_take(1, 128)
+    assert not (pp.kernels_take(6, 128) or pp.kernels_take(1, 64) or pp.kernels_take(8, 64)
                 or pp.kernels_take(4, 192, 96))
     assert pp.keys_flat(192) and not (pp.keys_flat(128) or pp.keys_flat(256) or pp.keys_flat(64))
     pool = (9, 128, 6, 128)

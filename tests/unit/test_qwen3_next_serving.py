@@ -102,7 +102,7 @@ def test_engine_equals_the_reference_on_logits_float32(gdn_impl):
     Pallas kernel ``dstpu_gdn_decode`` itself on the state pool."""
     cfg, params = _model()
     eng = _engine(cfg, params)
-    eng._gdn_impl = gdn_impl
+    eng._rec_impl = gdn_impl
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in PROMPT_LENS]
     with jax.default_matmul_precision("highest"):
@@ -149,7 +149,7 @@ def test_engine_in_bf16_equals_the_no_cache_forward_in_bf16():
     hf = {**HF, "num_hidden_layers": 4, "num_experts_per_tok": 4, "deployment_share": None}
     cfg, params = _model(hf, dtype="bfloat16")
     eng = _engine(cfg, params, dtype="bfloat16")
-    assert eng._gdn_state.dtype == jnp.float32 and eng._gdn_conv.dtype == jnp.bfloat16
+    assert eng._rec_state.dtype == jnp.float32 and eng._rec_conv.dtype == jnp.bfloat16
     rng = np.random.default_rng(1)
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in (5, 200)]
     served = _serve_logits(eng, prompts, n_new=6)
@@ -166,7 +166,7 @@ def test_a_bf16_state_pool_fails_the_float32_comparison():
     times the limit (measured 5.5e-3 against 5e-5; the sound engine 2e-6)."""
     cfg, params = _model()
     eng = _engine(cfg, params)
-    eng._gdn_state = eng._gdn_state.astype(jnp.bfloat16)
+    eng._rec_state = eng._rec_state.astype(jnp.bfloat16)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in (70, 330)]
     with jax.default_matmul_precision("highest"):
@@ -194,7 +194,7 @@ def test_a_state_lost_between_two_steps_fails_the_comparison():
         eng.scheduler.submit(0, prompt)
         first = np.asarray(eng.step()[0], np.float32)
         eng.scheduler.feedback(0, int(np.argmax(first)))
-        eng._gdn_state = jnp.zeros_like(eng._gdn_state)
+        eng._rec_state = jnp.zeros_like(eng._rec_state)
         second = np.asarray(eng.step()[0], np.float32)
         eng.scheduler.finish(0)
         want = _reference_logits(params, HF, prompt, np.stack([first, second]))
@@ -233,13 +233,13 @@ def test_a_reused_slot_poisoned_with_nan_starts_from_zero():
         clean = _engine(cfg, params).generate(prompts, max_new_tokens=5)
         eng = _engine(cfg, params)
         spare = np.arange(cfg.kind_count("gdn")) * eng._state_slots + eng._state_slots - 1
-        keep = jnp.zeros(eng._gdn_state.shape[0], bool).at[spare].set(True)
-        eng._gdn_state = jnp.where(keep[:, None, None, None], eng._gdn_state, jnp.nan)
-        eng._gdn_conv = jnp.where(keep[:, None], eng._gdn_conv, jnp.nan)
+        keep = jnp.zeros(eng._rec_state.shape[0], bool).at[spare].set(True)
+        eng._rec_state = jnp.where(keep[:, None, None, None], eng._rec_state, jnp.nan)
+        eng._rec_conv = jnp.where(keep[:, None], eng._rec_conv, jnp.nan)
         poisoned = eng.generate(prompts, max_new_tokens=5)
     for a, b in zip(clean, poisoned):
         np.testing.assert_array_equal(a, b)
-    assert bool(jnp.isfinite(eng._gdn_state[spare]).all())
+    assert bool(jnp.isfinite(eng._rec_state[spare]).all())
 
 
 def test_the_shares_parts_add_up_to_the_uncut_layer():
