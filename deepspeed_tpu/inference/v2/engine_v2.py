@@ -67,7 +67,7 @@ _BUILDERS = {
 
 # a layer's attention projections, which an unrolled stack never slices out of
 # their stack (_static_layer)
-_READ_IN_PLACE = ("wq", "wk", "wv", "wo")
+_READ_IN_PLACE = ("wq", "wk", "wv", "wo", "kda_qkv", "kda_out")
 
 
 def _entry_array(entry, want_tokens: bool):
@@ -161,6 +161,9 @@ class StepStats:
     # Mamba layers: the same, and the prompt tokens the chunked scan walked
     mamba_decode_rows: int = 0
     mamba_chunk_tokens: int = 0
+    # Kimi Delta Attention layers: the same pair
+    kda_decode_rows: int = 0
+    kda_chunk_tokens: int = 0
     # chunk attention: key blocks the chunk rows hold (pool blocks below the
     # chunk's start + the chunk's own) against the slots a walk of whole
     # tables and whole chunks covers, of one layer (_count_chunk)
@@ -1656,7 +1659,8 @@ class InferenceEngineV2:
                 never = (p != p) & (p == p)
                 blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
                 visits = visits and (visits[0], visits[1], jnp.where(never, 0, visits[2]))
-            return (latent_write(pools[0], side["k"], blk, row, visits, impl=self._attn_impl),)
+            return (latent_write(pools[0], side["k"], blk, row, visits, impl=self._attn_impl),
+                    ) + self._state_of(side)
         if not self._windowed:
             return self._scatter_kv(pools, blk, row, (side["k"], side["v"])) + self._state_of(side)
         if x is not None:
@@ -1700,7 +1704,9 @@ class InferenceEngineV2:
         with static indices; layers of two KINDS (``layer_kinds``) a
         fori_loop over the PERIODS of the pattern with one period's layers
         unrolled in its body, so there is one traced body a kind whatever the
-        depth (a stack of a single period is that body alone). A layer's
+        depth (a stack of a single period is that body alone; a stack of two
+        kinds behind a dense LEAD layer, whose MLP is of another shape, is
+        unrolled whole like the per-layer windows). A layer's
         parameters are what every layer has at ``li`` and its kind's stack
         at the layer's ordinal among its kind (``T.kind_ordinals``); the
         body knows its kind from the keys it is handed. Two sets of keys
@@ -1735,7 +1741,7 @@ class InferenceEngineV2:
         def traced(a, i):
             return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
 
-        if self._hybrid:
+        if self._hybrid and not c.moe_dense_lead:
             period, n = T.layer_period(c)
             P = len(period)
             ords = T.kind_ordinals(c)[:P]
@@ -1816,12 +1822,14 @@ class InferenceEngineV2:
         at a time, from its slice), more than one layer in the stack (``a[0]``
         of a stack of one is a bitcast), one device (``_tp_row_matmul`` and
         GSPMD take arrays), and a layer whose products are ``stack_dot``'s
-        (``_layer_qkv`` / ``_layer_tail`` / ``T.kind_qkv``; ``_latent_layer``
-        multiplies its ``wo`` itself)."""
-        in_place = self._mesh is None and not self._latent
+        (``_layer_qkv`` / ``_layer_tail`` / ``T.kind_qkv``, a KDA layer's two wide
+        projections through ``T._proj``; ``_latent_layer`` multiplies its ``wo``
+        itself, and its ``wq`` stays sliced beside it)."""
+        in_place = self._mesh is None
 
         def take(k, a):
-            if in_place and k in _READ_IN_PLACE and isinstance(a, jax.Array) and a.shape[0] > 1:
+            if (in_place and k in _READ_IN_PLACE and not (self._latent and k in ("wq", "wo"))
+                    and isinstance(a, jax.Array) and a.shape[0] > 1):
                 return Stacked(a, i)
             return jax.tree.map(lambda b: b[i], a)
 
@@ -2043,7 +2051,7 @@ class InferenceEngineV2:
         nh, nkv, d = c.n_heads, c.kv_heads, c.head_dim
         R, Rc, tq = meta["R"], meta["Rc"], meta["tq"]
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        if self._hybrid and "wq" not in lp:  # a recurrent layer: no K/V, the state pools instead
+        if self._hybrid and "wo" not in lp:  # a recurrent layer: no K/V, the state pools instead
             return self._recurrent_layer(lp, x, li, {
                 "R": R, "Rc": Rc, "tq": tq, "slots": meta.get("dec_slots"),
                 "live": meta["dec_pos"] >= 0, "slot_live": meta["slot_live"],
@@ -2085,7 +2093,9 @@ class InferenceEngineV2:
 
     def _latent_attention(self, lp, x, plane, meta):
         """Latent attention of the split step in the ABSORBED form, on the
-        cache's plane ``plane``: ``W_UK`` moved to the query (``q = [q_nope W_UK
+        cache's plane ``plane`` (as ``_kv_source`` takes a layer: the plane
+        itself, or in a stack with recurrent layers the LAYER, whose ordinal
+        among the latent ones is its plane): ``W_UK`` moved to the query (``q = [q_nope W_UK
         | q_rope]``, every head against the one cached vector a token) and
         ``W_UV`` behind the output. Decode rows through ``latent_decode`` (the
         pool below their position and their own new vector as the extra
@@ -2120,12 +2130,15 @@ class InferenceEngineV2:
 
     def _latent_layer(self, lp, x, li, meta, carry):
         """One latent-attention layer of the split step: ``_latent_attention``
-        on plane ``li``, then the MLP or the expert block. The layer records its
-        new vectors in ``carry``; the pool is written once, after the loop."""
+        on the layer's plane (``li``, or in a stack with recurrent layers beside
+        it the layer's ordinal among the latent ones), then the MLP or the
+        expert block. The layer records its new vectors in ``carry``; the pool
+        is written once, after the loop."""
         lp = T._dequant_tree(lp, T.DTYPES[self._mc.dtype])
         attn_out, ckv = self._latent_attention(lp, x, li, meta)
         x, moe = self._mlp_tail(lp, x, attn_out, meta["slot_live"], li)
-        carry = dict(carry, k=jax.lax.dynamic_update_index_in_dim(carry["k"], ckv, li, 0))
+        carry = dict(carry, k=jax.lax.dynamic_update_index_in_dim(
+            carry["k"], ckv, self._ordinal(li), 0))
         return x, self._record_moe(carry, li, moe)
 
     def _shortcut_layer(self, lp, x, li, meta, carry):
@@ -2255,7 +2268,7 @@ class InferenceEngineV2:
         c = self._mc
         w = c.sliding_window if window is None else window
         lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        if self._hybrid and "wq" not in lp:  # a recurrent layer: the carried state, updated in place
+        if self._hybrid and "wo" not in lp:  # a recurrent layer: the carried state, updated in place
             return self._recurrent_layer(lp, x, li, {
                 "R": x.shape[1], "slots": meta["slots"], "live": meta["active"],
                 "slot_live": meta["active"]}, carry)
@@ -2660,8 +2673,8 @@ class InferenceEngineV2:
         states took the one-token update and, for a kind that counts them, the
         prompt tokens its chunk rule walked, of ONE such layer."""
         kind = self._mc.recurrent_kind
-        if kind == "mamba":
-            return {"mamba_decode_rows": decode_rows, "mamba_chunk_tokens": chunk_tokens}
+        if kind in ("mamba", "kda"):
+            return {f"{kind}_decode_rows": decode_rows, f"{kind}_chunk_tokens": chunk_tokens}
         return {"gdn_decode_rows": decode_rows} if kind else {}
 
     def _stage_round(self, uids, n: int):

@@ -21,7 +21,10 @@ layers of 4, keys of 192 against values of 128, a rotary base by layer kind, a
 dense lead layer then sigmoid-routed experts with no shared one), longcat_flash
 (LongCat-Flash: a layer of two latent-attention sub-blocks with dense MLPs and
 one expert block on a shortcut across them, a softmax router whose last ids
-are identity experts), qwen3_moe (per-head q/k
+are identity experts), kimi_linear (Kimi Linear: Kimi Delta Attention layers, the
+delta rule with a decay a key channel, beside latent-attention layers with one
+query projection and no positions; a dense lead layer then sigmoid-routed
+experts with a selection bias and a shared one), qwen3_moe (per-head q/k
 RMSNorm), mixtral, olmoe (q/k RMSNorm over the whole projection width; the four MoE types import drop-free: ``moe_drop_tokens``
 false), falcon, phi (incl. qk_layernorm),
 phi3, gpt2, gpt_neo, opt, gemma, bloom, gptj, gpt_neox, internlm, stablelm
@@ -281,6 +284,76 @@ def _axk1_config(get) -> TransformerConfig:
         moe_topk_group=topk_group,
         moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
         moe_shared_expert_dim=expert_dim * int(get("n_shared_experts", 0) or 0),
+        moe_shared_gated=False,
+    )
+
+
+def _kimi_linear_config(get) -> TransformerConfig:
+    """Kimi Linear (``kimi_linear``): ``linear_attn_config`` lists, numbered
+    from 1, the layers that are Kimi Delta Attention (``kda_layers``: the delta
+    rule with a decay a key channel, ``num_heads`` heads of ``head_dim``, a conv
+    of ``short_conv_kernel_size`` taps) and those that are latent attention
+    (``full_attn_layers``: DeepseekV3's, with ONE query projection where
+    ``q_lora_rank`` is null and NO rotary where ``mla_use_nope``);
+    ``first_k_dense_replace`` dense lead layers, then ``num_experts`` experts
+    routed by sigmoid score + a selection bias in one group, renormalised,
+    scaled, plus ungated shared experts. ``deployment_share`` as for qwen3_next
+    (``num_experts`` held of the published count)."""
+    n_layers = int(get("num_hidden_layers"))
+    lin = get("linear_attn_config", None) or {}
+    kda, full = (sorted(int(i) for i in lin.get(k, ())) for k in ("kda_layers", "full_attn_layers"))
+    if sorted(kda + full) != list(range(1, n_layers + 1)) or not kda or not full:
+        raise ValueError(
+            f"kimi_linear: linear_attn_config.kda_layers={kda} and full_attn_layers={full} do "
+            f"not partition layers 1..{n_layers} into some of each")
+    lead = int(get("first_k_dense_replace", 0) or 0)
+    if not 0 < lead < n_layers or int(get("moe_layer_freq", 1) or 1) != 1:
+        raise ValueError(
+            f"kimi_linear: first_k_dense_replace={lead}, moe_layer_freq={get('moe_layer_freq', 1)}: "
+            "supported are dense lead layers followed by expert layers, some of each")
+    if get("moe_router_activation_func", "sigmoid") != "sigmoid":
+        raise ValueError(f"kimi_linear: moe_router_activation_func="
+                         f"{get('moe_router_activation_func')!r}, expected 'sigmoid'")
+    if int(get("num_nextn_predict_layers", 0) or 0) > 0:
+        raise ValueError(
+            "kimi_linear: num_nextn_predict_layers > 0: a multi-token-prediction layer is a "
+            "second head on the stream, and nothing here runs or loads one")
+    if int(get("num_expert_group", 1) or 1) > 1:
+        raise ValueError(
+            "kimi_linear: num_expert_group > 1: how KimiMoEGate scores a group (its largest "
+            "score, or its two largest with the bias) could not be read here; one group is "
+            "what the published model has")
+    if get("q_lora_rank", None) or not bool(get("mla_use_nope", False)):
+        raise ValueError(
+            "kimi_linear: a q_lora_rank, or mla_use_nope false: supported is the published "
+            "form, one query projection and no rotary in the latent layers")
+    held, total, shard = _expert_share(get, "kimi_linear", "num_experts")
+    dn, dr = int(get("qk_nope_head_dim")), int(get("qk_rope_head_dim"))
+    expert_dim = int(get("moe_intermediate_size"))
+    return _llama_like_config(
+        get,
+        position="none",
+        max_seq_len=int(get("model_max_length", None) or get("max_position_embeddings", 2048)),
+        layer_kinds=tuple("kda" if i + 1 in kda else "full" for i in range(n_layers)),
+        kda_heads=int(lin["num_heads"]),
+        kda_head_dim=int(lin["head_dim"]),
+        kda_conv_kernel=int(lin.get("short_conv_kernel_size", 4)),
+        head_dim_override=dn + dr,
+        kv_lora_rank=int(get("kv_lora_rank")),
+        qk_nope_dim=dn,
+        qk_rope_dim=dr,
+        v_head_dim=int(get("v_head_dim")),
+        n_experts=held,
+        moe_experts_total=total if total != held else 0,
+        moe_expert_shard=shard,
+        moe_top_k=get("num_experts_per_token"),
+        moe_norm_topk_prob=bool(get("moe_renormalize", True)),
+        moe_drop_tokens=False,  # the published block never drops a token
+        moe_dense_lead=lead,
+        moe_expert_dim=expert_dim,
+        moe_score="sigmoid",
+        moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+        moe_shared_expert_dim=expert_dim * int(get("num_shared_experts", 0) or 0),
         moe_shared_gated=False,
     )
 
@@ -596,6 +669,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         return _exaone_moe_config(get)
     if mt == "axk1":
         return _axk1_config(get)
+    if mt == "kimi_linear":
+        return _kimi_linear_config(get)
     if mt == "mimo_v2_flash":
         return _mimo_v2_flash_config(get)
     if mt == "longcat_flash":
@@ -1069,7 +1144,8 @@ def config_from_hf(hf_cfg) -> TransformerConfig:
         f"unsupported model_type {mt!r}; supported: llama, mistral, qwen2, "
         "qwen2_moe, mixtral, olmoe, falcon, phi, phi3, gpt2, gpt_neo, opt, gemma, "
         "bloom, gptj, gpt_neox, internlm, stablelm, starcoder2, "
-        "qwen3, qwen3_moe, qwen3_next, jamba, exaone_moe, axk1, mimo_v2_flash, longcat_flash, "
+        "qwen3, qwen3_moe, qwen3_next, jamba, exaone_moe, axk1, kimi_linear, mimo_v2_flash, "
+        "longcat_flash, "
         "megatron_gpt, bert, "
         "distilbert, "
         "clip_text_model"
@@ -1261,6 +1337,52 @@ def _axk1_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, 
         moe[name].append(np.stack([
             take.linear(f"{p}.mlp.experts.{first + e}.{hf}.weight") for e in range(cfg.n_experts)]))
         moe[f"shared_{name[2:]}"].append(take.linear(f"{p}.mlp.shared_experts.{hf}.weight"))
+
+
+def _kimi_linear_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
+    """One Kimi Linear layer, by its kind: a KDA layer's ``self_attn.*`` (q, k
+    and v projections side by side as the conv's channels, the three convs'
+    weights [C, 1, K] -> one [K, 3 C], the decay's and the gate's low-rank pairs)
+    or a latent layer's (DeepseekV3's names, one ``q_proj``); then a dense MLP
+    (the lead layers, under ``lead``) or the expert block (under ``sparse``: the
+    router whole, its selection bias, the chip's own experts ``w1`` / ``w3`` /
+    ``w2`` = gate / up / down, the shared experts as one MLP). The names are the
+    published modeling code's as remembered: no network here to read the
+    checkpoint's index."""
+    i = int(p.rsplit(".", 1)[1])
+    layers["attn_norm"].append(take(f"{p}.input_layernorm.weight"))
+    layers["mlp_norm"].append(take(f"{p}.post_attention_layernorm.weight"))
+    a = f"{p}.self_attn"
+    if cfg.layer_kinds[i] == "full":
+        for name, hf in (("wq", "q_proj"), ("wkv_a", "kv_a_proj_with_mqa"), ("wkv_b", "kv_b_proj"),
+                         ("wo", "o_proj")):
+            layers["full"][name].append(take.linear(f"{a}.{hf}.weight"))
+        layers["full"]["kv_a_norm"].append(take(f"{a}.kv_a_layernorm.weight"))
+    else:
+        kda = layers["kda"]
+        kda["kda_qkv"].append(np.concatenate(
+            [take.linear(f"{a}.{n}_proj.weight") for n in "qkv"], axis=-1))
+        kda["kda_conv"].append(np.concatenate(
+            [take(f"{a}.{n}_conv1d.weight")[:, 0, :].T for n in "qkv"], axis=-1))
+        kda["kda_a_log"].append(take(f"{a}.A_log").reshape(-1))
+        kda["kda_dt_bias"].append(take(f"{a}.dt_bias").reshape(-1))
+        for name, hf in (("kda_f_a", "f_a_proj"), ("kda_f_b", "f_b_proj"), ("kda_b", "b_proj"),
+                         ("kda_g_a", "g_a_proj"), ("kda_g_b", "g_b_proj"), ("kda_out", "o_proj")):
+            kda[name].append(take.linear(f"{a}.{hf}.weight"))
+        kda["kda_norm"].append(take(f"{a}.o_norm.weight"))
+    names = (("w_gate", "gate_proj", "w1"), ("w_up", "up_proj", "w3"), ("w_down", "down_proj", "w2"))
+    if i < cfg.moe_dense_lead:
+        for name, hf, _ in names:
+            layers["lead"][name].append(take.linear(f"{p}.mlp.{hf}.weight"))
+        return
+    moe, m = layers["sparse"], f"{p}.block_sparse_moe"
+    moe["router"].append(take.linear(f"{m}.gate.weight"))
+    moe["router_bias"].append(take(f"{m}.gate.e_score_correction_bias"))
+    first = cfg.moe_expert_shard * cfg.n_experts
+    for name, hf, w in names:
+        moe[name].append(np.stack([
+            take.linear(f"{m}.experts.{first + e}.{w}.weight") for e in range(cfg.n_experts)]))
+        moe[f"shared_{name[2:]}"].append(take.linear(f"{m}.shared_experts.{hf}.weight"))
 
 
 def _longcat_flash_layer(take: _Taker, cfg: TransformerConfig, p: str, layers: Dict[str, list]):
@@ -1696,6 +1818,7 @@ _LAYER_EXTRACTORS: Dict[str, Callable] = {
     "jamba": _jamba_layer,
     "exaone_moe": _exaone_moe_layer,
     "axk1": _axk1_layer,
+    "kimi_linear": _kimi_linear_layer,
     "mimo_v2_flash": _mimo_v2_flash_layer,
     "longcat_flash": _longcat_flash_layer,
     "qwen3_moe": _llama_layer,
@@ -1734,6 +1857,7 @@ _TOPLEVEL_KEYS: Dict[str, Tuple[str, str, str, Optional[str]]] = {
     "jamba": ("model.embed_tokens.weight", "model.final_layernorm", "model.layers", None),
     "exaone_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "axk1": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
+    "kimi_linear": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "mimo_v2_flash": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "longcat_flash": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
     "qwen3_moe": ("model.embed_tokens.weight", "model.norm", "model.layers", None),
@@ -1776,9 +1900,9 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
     """Empty stacking lists for exactly the keys this config's params carry."""
     keys = ["attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_up", "w_down"]
     if cfg.latent:
-        from deepspeed_tpu.models.transformer import LATENT_KEYS
+        from deepspeed_tpu.models.transformer import latent_keys
 
-        keys = [k for k in keys if k not in ("wq", "wk", "wv")] + list(LATENT_KEYS)
+        keys = [k for k in keys if k not in ("wq", "wk", "wv")] + list(latent_keys(cfg))
     if cfg.activation in ("swiglu", "geglu"):
         keys.append("w_gate")
     if cfg.norm == "layernorm":
@@ -1818,20 +1942,22 @@ def _expected_layer_keys(cfg: TransformerConfig) -> Dict[str, list]:
             attn = [k for k in out if k in ATTENTION_KEYS]
             out["full"] = {k: out.pop(k) for k in attn}
             out["window"] = {k: [] for k in attn + (["sink"] if cfg.attn_sink_window else [])}
-        return out
+    else:
+        out = {k: [] for k in keys}
     if cfg.hybrid:  # stacked by kind (transformer.init_params)
-        from deepspeed_tpu.models.transformer import ATTENTION_KEYS
+        from deepspeed_tpu.models.transformer import ATTENTION_KEYS, LATENT_KEYS
 
-        out: Dict[str, Any] = {k: [] for k in keys if k not in ATTENTION_KEYS}
-        out["full"] = {k: [] for k in keys if k in ATTENTION_KEYS}
+        attn = [k for k in out if k in ATTENTION_KEYS or k in LATENT_KEYS]
+        out["full"] = {k: out.pop(k) for k in attn}
         own = {"gdn": ("gdn_qkv", "gdn_z", "gdn_ba", "gdn_conv", "gdn_dt_bias", "gdn_a_log",
                        "gdn_norm", "gdn_out"),
                "mamba": ("mamba_in", "mamba_conv", "mamba_conv_b", "mamba_x", "mamba_dt_norm",
                          "mamba_b_norm", "mamba_c_norm", "mamba_dt", "mamba_dt_b", "mamba_a_log",
-                         "mamba_d", "mamba_out")}[cfg.recurrent_kind]
+                         "mamba_d", "mamba_out"),
+               "kda": ("kda_qkv", "kda_conv", "kda_a_log", "kda_dt_bias", "kda_f_a", "kda_f_b",
+                       "kda_b", "kda_g_a", "kda_g_b", "kda_norm", "kda_out")}[cfg.recurrent_kind]
         out[cfg.recurrent_kind] = {k: [] for k in own}
-        return out
-    return {k: [] for k in keys}
+    return out
 
 
 def _load_encoder(mt: str, cfg: TransformerConfig, take: _Taker, state: Dict[str, Any]):
@@ -1950,7 +2076,7 @@ def load_hf_model(
     # (transformers ignores mtp.* on load), and an expert share leaves the
     # other chips' experts where they are
     leftover = [k for k in state if not k.endswith("rotary_emb.inv_freq")
-                and not k.startswith(("mtp.", "model.mtp")) and not (cfg.moe_experts_total and ".mlp.experts." in k)]
+                and not k.startswith(("mtp.", "model.mtp")) and not (cfg.moe_experts_total and ".experts." in k)]
     if leftover:
         logger.warning(f"unmapped HF weights ignored: {leftover[:8]}{'...' if len(leftover) > 8 else ''}")
     return cfg, params

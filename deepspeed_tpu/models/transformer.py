@@ -253,12 +253,15 @@ class TransformerConfig:
     # latent [kv_lora_rank] and qk_rope_dim rotated key dims; a head's keys
     # (qk_nope_dim, beside the shared rotated dims) and values (v_head_dim)
     # are wkv_b of the latent. Queries go through a low-rank pair with a norm
-    # between (q_lora_rank). head_dim is qk_nope_dim + qk_rope_dim (the
+    # between (q_lora_rank), or with q_lora_rank 0 through ONE projection wq
+    # (kimi_linear). head_dim is qk_nope_dim + qk_rope_dim (the
     # softmax's 1/sqrt), rotary turns the qk_rope_dim dims alone, in
-    # INTERLEAVED pairs (2i, 2i+1) where rope_interleave. Parameters: wq_a,
-    # q_a_norm, wq_b, wkv_a, kv_a_norm, wkv_b in place of wq / wk / wv, wo
-    # [n_heads * v_head_dim, h]. The serving engine caches the one vector a
-    # token (``latent_dim`` wide) and attends in the absorbed form.
+    # INTERLEAVED pairs (2i, 2i+1) where rope_interleave; with position "none"
+    # nothing turns and the "rope" dims are plain dims every head shares.
+    # Parameters: wq_a, q_a_norm, wq_b (or wq), wkv_a, kv_a_norm, wkv_b in place
+    # of wq / wk / wv, wo [n_heads * v_head_dim, h]. The serving engine caches
+    # the one vector a token (``latent_dim`` wide) and attends in the absorbed
+    # form. In a hybrid stack (layer_kinds) the "full" layers are the latent ones.
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -312,14 +315,16 @@ class TransformerConfig:
     attn_sink_window: bool = False
     window_rope_theta: float = 0.0
     attn_value_scale: float = 1.0
-    # per-layer KIND (qwen3-next, jamba): n_layers names, "full" (softmax
-    # attention over cached keys and values) or ONE recurrent kind
-    # (``RECURRENT``): "gdn" (Gated DeltaNet, ops/linear_attention) or "mamba"
-    # (a selective state-space layer, ops/state_space), each a recurrent state
-    # and a short causal conv. None: every layer "full". Parameters are stacked
-    # by kind: what every layer has (norms, MLP or experts) on [n_layers],
+    # per-layer KIND (qwen3-next, jamba, kimi_linear): n_layers names, "full"
+    # (softmax attention over cached keys and values) or ONE recurrent kind
+    # (``RECURRENT``): "gdn" (Gated DeltaNet, ops/linear_attention), "mamba"
+    # (a selective state-space layer, ops/state_space) or "kda" (Kimi Delta
+    # Attention: the delta rule with a decay a key channel), each a recurrent
+    # state and a short causal conv. None: every layer "full". Parameters are
+    # stacked by kind: what every layer has (norms, MLP or experts) on
+    # [n_layers] (under moe_dense_lead the MLPs under "lead" / "sparse"),
     # attention under params["layers"]["full"] on [number of full layers], the
-    # recurrent kind's under params["layers"]["gdn"] / ["mamba"].
+    # recurrent kind's under params["layers"]["gdn"] / ["mamba"] / ["kda"].
     layer_kinds: Optional[Tuple[str, ...]] = None
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
@@ -332,6 +337,11 @@ class TransformerConfig:
     mamba_d_state: int = 0
     mamba_dt_rank: int = 0
     mamba_conv_kernel: int = 4
+    # a "kda" layer: heads of kda_head_dim keys and as many values; the decay's
+    # and the output gate's low-rank pairs go through kda_head_dim too
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
     # qwen3-next gated attention output: q_proj is twice as wide, per head a
     # query and a gate, and the heads' output is multiplied by sigmoid(gate)
     # in front of wo (stored apart as wq_gate [h, n_heads * head_dim])
@@ -387,11 +397,10 @@ class TransformerConfig:
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_score={self.moe_score!r}: expected 'softmax' or 'sigmoid'")
         if self.moe_dense_lead and not (
-                0 < self.moe_dense_lead < self.n_layers and self.n_experts > 0
-                and self.layer_kinds is None):
+                0 < self.moe_dense_lead < self.n_layers and self.n_experts > 0):
             raise ValueError(
                 f"moe_dense_lead={self.moe_dense_lead}: the lead layers are some, not all, of "
-                f"an expert model's {self.n_layers} layers (and compose with no layer_kinds)")
+                f"an expert model's {self.n_layers} layers")
         if self.moe_n_group > 1 and (
                 self.router_width % self.moe_n_group
                 or not 0 < self.moe_topk_group <= self.moe_n_group
@@ -402,18 +411,20 @@ class TransformerConfig:
                 f"{self.router_width} experts of a sigmoid router in equal groups, the kept "
                 f"groups holding at least moe_top_k={self.moe_top_k} experts")
         if self.kv_lora_rank and not (
-                min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim) > 0
-                and self.qk_rope_dim % 2 == 0
+                min(self.qk_nope_dim, self.qk_rope_dim, self.v_head_dim) > 0
+                and self.q_lora_rank >= 0 and self.qk_rope_dim % 2 == 0
                 and self.head_dim_override == self.qk_nope_dim + self.qk_rope_dim
-                and self.position == "rope" and self.norm_scheme == "pre"
-                and not (self.sliding_window or self.layer_kinds or self.qk_norm
+                and self.position in ("rope", "none") and self.norm_scheme == "pre"
+                and not (self.sliding_window or self.qk_norm
                          or self.attn_qkv_bias or self.attn_out_bias or self.attn_out_gate
-                         or self.parallel_block or self.rope_frac != 1.0)):
+                         or self.parallel_block or self.rope_frac != 1.0)
+                and not (self.layer_kinds and self.moe_shortcut)):
             raise ValueError(
-                "latent attention (kv_lora_rank > 0) needs q_lora_rank, qk_nope_dim, an even "
-                "qk_rope_dim and v_head_dim, head_dim_override = qk_nope_dim + qk_rope_dim, "
-                "rotary positions and pre-norm blocks, and composes with no window, layer "
-                "kinds, q/k norm, bias, output gate or parallel block")
+                "latent attention (kv_lora_rank > 0) needs qk_nope_dim, an even qk_rope_dim "
+                "and v_head_dim (q_lora_rank 0: one query projection), head_dim_override = "
+                "qk_nope_dim + qk_rope_dim, rotary or no positions and pre-norm blocks, and "
+                "composes with no window, q/k norm, bias, output gate or parallel block, nor "
+                "as layer kinds with a layer of two sub-blocks (moe_shortcut)")
         if self.moe_shortcut and not (
                 self.latent and self.n_experts > 0 and not self.moe_dense_lead
                 and not self.moe_shared_expert_dim and not self.moe_residual
@@ -516,13 +527,15 @@ class TransformerConfig:
                     f"entries for {self.n_layers} layers"
                 )
         if self.layer_kinds is not None:
-            bad = set(self.layer_kinds) - {"full", "gdn", "mamba"}
-            if (bad or len(self.layer_kinds) != self.n_layers
-                    or {"gdn", "mamba"} <= set(self.layer_kinds)):
+            recurrent = set(self.layer_kinds) - {"full"}
+            if (recurrent - set(RECURRENT) or len(self.layer_kinds) != self.n_layers
+                    or len(recurrent) > 1):
                 raise ValueError(
                     f"layer_kinds={self.layer_kinds!r}: expected {self.n_layers} "
-                    "names, each 'full' or ONE of 'gdn' and 'mamba'"
+                    "names, each 'full' or ONE of 'gdn', 'mamba' and 'kda'"
                 )
+            if "kda" in self.layer_kinds and min(self.kda_heads, self.kda_head_dim) < 1:
+                raise ValueError("a 'kda' layer needs kda_heads / kda_head_dim")
             if "mamba" in self.layer_kinds and min(
                     self.mamba_d_inner, self.mamba_d_state, self.mamba_dt_rank) < 1:
                 raise ValueError(
@@ -636,9 +649,9 @@ class TransformerConfig:
 
     @property
     def recurrent_kind(self) -> Optional[str]:
-        """The stack's recurrent layer kind ("gdn" or "mamba": ``RECURRENT``
-        describes it), None for a stack of attention layers alone."""
-        for kind in ("gdn", "mamba"):
+        """The stack's recurrent layer kind ("gdn", "mamba" or "kda":
+        ``RECURRENT`` describes it), None for a stack of attention layers alone."""
+        for kind in RECURRENT:
             if self.layer_kinds is not None and kind in self.layer_kinds:
                 return kind
         return None
@@ -730,6 +743,12 @@ ATTENTION_KEYS = frozenset({
 LATENT_KEYS = ("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm", "wkv_b")
 
 
+def latent_keys(c: TransformerConfig) -> Tuple[str, ...]:
+    """``LATENT_KEYS`` as this model has them: with ``q_lora_rank`` 0 the
+    queries are ONE projection, ``wq``, in place of the low-rank pair."""
+    return LATENT_KEYS if c.q_lora_rank else ("wq",) + LATENT_KEYS[3:]
+
+
 def layer_period(c: TransformerConfig) -> Tuple[Tuple[str, ...], int]:
     """(the kinds of one period, how many periods) of ``layer_kinds``: the
     shortest pattern the stack repeats, so a loop over periods with the
@@ -754,11 +773,11 @@ def layer_stacks(c: TransformerConfig, li: int) -> Tuple[Tuple[str, int], ...]:
     beside the common keys, each with the layer's index in it: its kind's stack
     under ``layer_kinds``; "lead" / "sparse" under ``moe_dense_lead`` (its MLP)
     and "full" / "window" under ``attn_by_kind`` (its attention), a layer of
-    such a model having one of each; none where every layer is alike. ``li``
+    a model with two of these having one of each; none where every layer is alike. ``li``
     is static."""
-    if c.hybrid:
-        return ((c.layer_kinds[li], kind_ordinals(c)[li]),)
     out = []
+    if c.hybrid:
+        out.append((c.layer_kinds[li], kind_ordinals(c)[li]))
     if c.attn_by_kind:
         out.append((cache_kinds(c)[li], cache_ordinals(c)[li]))
     if c.moe_dense_lead:
@@ -936,16 +955,21 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     }
     if c.latent:
         rank, qr = c.kv_lora_rank, c.q_lora_rank
+        Ll = La * c.sub_blocks  # the latent layers: every sub-block, or a hybrid stack's "full" ones
+        if qr:
+            layers.update(
+                wq_a=dense(next(keys), (Ll, h, qr), h),
+                q_a_norm=jnp.ones((Ll, qr), dtype),
+                wq_b=dense(next(keys), (Ll, qr, nh * d), qr, latent_gain["q"]))
+        else:  # ONE query projection (kimi_linear), at the gain of the pair's second
+            layers["wq"] = dense(next(keys), (Ll, h, nh * d), h, latent_gain["q"])
         layers.update(
-            wq_a=dense(next(keys), (Ls, h, qr), h),
-            q_a_norm=jnp.ones((Ls, qr), dtype),
-            wq_b=dense(next(keys), (Ls, qr, nh * d), qr, latent_gain["q"]),
             # the latent and, behind it, the rotary key dims every head shares
-            wkv_a=dense(next(keys), (Ls, h, c.latent_dim), h),
-            kv_a_norm=jax.random.uniform(next(keys), (Ls, rank), jnp.float32, 0.5, 1.5).astype(dtype),
+            wkv_a=dense(next(keys), (Ll, h, c.latent_dim), h),
+            kv_a_norm=jax.random.uniform(next(keys), (Ll, rank), jnp.float32, 0.5, 1.5).astype(dtype),
             # per head: qk_nope_dim key columns, then v_head_dim value columns
-            wkv_b=dense(next(keys), (Ls, rank, nh * (c.qk_nope_dim + c.v_head_dim)), rank),
-            wo=dense(next(keys), (Ls, nh * c.v_head_dim, h), nh * c.v_head_dim, latent_gain["attn"]),
+            wkv_b=dense(next(keys), (Ll, rank, nh * (c.qk_nope_dim + c.v_head_dim)), rank),
+            wo=dense(next(keys), (Ll, nh * c.v_head_dim, h), nh * c.v_head_dim, latent_gain["attn"]),
         )
     def attention(n, nkv):
         """Per-head attention's parameters on ``n`` layers of ``nkv`` KV heads."""
@@ -1000,8 +1024,9 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
     elif not c.latent:
         layers.update(attention(La, nkv))
     if c.hybrid:
-        layers = {k: v for k, v in layers.items() if k not in ATTENTION_KEYS} | {
-            "full": {k: v for k, v in layers.items() if k in ATTENTION_KEYS}}
+        own = ATTENTION_KEYS | set(LATENT_KEYS)
+        layers = {k: v for k, v in layers.items() if k not in own} | {
+            "full": {k: v for k, v in layers.items() if k in own}}
     if c.recurrent_kind == "gdn":
         Lg, nv = c.kind_count("gdn"), c.gdn_value_heads
         vd = nv * c.gdn_value_dim
@@ -1054,6 +1079,38 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
             ).astype(dtype),
             "mamba_d": jnp.ones((Lm, di), dtype),
             "mamba_out": dense(next(keys), (Lm, di, h), di, into_stream),
+        }
+    if c.recurrent_kind == "kda":
+        Lk, H, dh = c.kind_count("kda"), c.kda_heads, c.kda_head_dim
+        # (keys of their own: the 48 above are what every other model is seeded from)
+        kk = iter(jax.random.split(jax.random.fold_in(key, 0x6B6461), 12))
+        # THE DECAYS. A token multiplies channel c of head h by exp(g), g =
+        # -exp(A_log[h]) * softplus(f(x)[h, c] + dt_bias[h, c]). A = exp(A_log)
+        # uniform in (1, 16) a head, as the flash-linear-attention library draws
+        # a delta rule's; dt_bias a CHANNEL the inverse softplus of 1 / (A tau),
+        # with the channel's memory tau log-uniform in [10, 5,000] tokens: at
+        # f = 0 channel c forgets over tau_c tokens, so every head has channels
+        # that forget within ten tokens and channels that remember thousands
+        # (a step log-uniform in [1e-3, 1e-1] under A up to 16, GDN's draw, lets
+        # every channel forget within tens of tokens). f's low-rank pair at unit
+        # gain moves a channel's tau by a factor of e either way with the input.
+        A = jax.random.uniform(next(kk), (Lk, H), jnp.float32, 1.0, 16.0)
+        tau = jnp.exp(jax.random.uniform(
+            next(kk), (Lk, H, dh), jnp.float32, math.log(10.0), math.log(5000.0)))
+        dt = 1.0 / (A[..., None] * tau)
+        layers["kda"] = {
+            # q | k | v, each [heads * head_dim]: the conv's channels
+            "kda_qkv": dense(next(kk), (Lk, h, 3 * H * dh), h),
+            "kda_conv": dense(next(kk), (Lk, c.kda_conv_kernel, 3 * H * dh), c.kda_conv_kernel),
+            "kda_a_log": jnp.log(A).astype(dtype),
+            "kda_dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).reshape(Lk, H * dh).astype(dtype),
+            "kda_f_a": dense(next(kk), (Lk, h, dh), h),
+            "kda_f_b": dense(next(kk), (Lk, dh, H * dh), dh),
+            "kda_b": dense(next(kk), (Lk, h, H), h),
+            "kda_g_a": dense(next(kk), (Lk, h, dh), h),
+            "kda_g_b": dense(next(kk), (Lk, dh, H * dh), dh),
+            "kda_norm": jnp.ones((Lk, dh), dtype),
+            "kda_out": dense(next(kk), (Lk, H * dh, h), H * dh, latent_gain["attn"]),
         }
     def dense_mlp(n):
         out = {"w_up": dense(next(keys), (n, h, ffn), h),
@@ -1683,23 +1740,29 @@ def latent_qkv(c: TransformerConfig, lp, a, positions, seq_len=None):
     ``a`` [t, h] at ``positions`` [t]: (q_nope [t, nh, qk_nope_dim], q_rope
     [t, nh, qk_rope_dim] rotated, ckv [t, latent_dim]: the normed latent and
     the rotated key dims every head shares, which is what a cache holds of a
-    token). Shared by the model's forward (expanded form) and the serving
-    steps (absorbed form)."""
+    token; under ``position="none"`` neither is rotated). Shared by the model's
+    forward (expanded form) and the serving steps (absorbed form)."""
     from deepspeed_tpu.ops.normalization.fused_norm import rms_norm_reference
 
     t = a.shape[0]
     nh, rank, dn = c.n_heads, c.kv_lora_rank, c.qk_nope_dim
     eps = c.norm_eps if c.latent_norm_eps is None else c.latent_norm_eps
-    cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], eps)
-    q = as_written(_proj(c, cq, lp["wq_b"])).reshape(t, nh, c.head_dim)
+    if "wq" in lp:  # q_lora_rank 0: one projection
+        q = as_written(_proj(c, a, lp["wq"])).reshape(t, nh, c.head_dim)
+    else:
+        cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], eps)
+        q = as_written(_proj(c, cq, lp["wq_b"])).reshape(t, nh, c.head_dim)
     kv = _proj(c, a, lp["wkv_a"])
     latent = rms_norm_reference(kv[:, :rank], lp["kv_a_norm"], eps)
     if c.latent_q_scale != 1.0:  # longcat_flash: behind wq_b, nope and rope dims alike
         q = (q.astype(jnp.float32) * c.latent_q_scale).astype(q.dtype)
     if c.latent_kv_scale != 1.0:  # ... and the normed latent; the rotary key dims are not
         latent = (latent.astype(jnp.float32) * c.latent_kv_scale).astype(latent.dtype)
-    q_rope = _rope_pairs(c, q[..., dn:], positions, seq_len)
-    k_rope = _rope_pairs(c, kv[:, None, rank:], positions, seq_len)[:, 0]
+    if c.position == "rope":
+        q_rope = _rope_pairs(c, q[..., dn:], positions, seq_len)
+        k_rope = _rope_pairs(c, kv[:, None, rank:], positions, seq_len)[:, 0]
+    else:  # "none" (kimi_linear's mla_use_nope): nothing turns
+        q_rope, k_rope = q[..., dn:], kv[:, rank:]
     return q[..., :dn], q_rope, jnp.concatenate([latent, k_rope.astype(latent.dtype)], axis=-1)
 
 
@@ -2078,6 +2141,55 @@ def _mamba_chunk(c, lp, y, extras, live, state, impl=None):
     return mamba_scan(y, delta, B, C, extras[0], A, D, state, impl=impl)
 
 
+def _kda_project(c, lp, a):
+    """A KDA layer's projections of the normed input ``a [..., h]``: (the conv's
+    input ``[..., 3 H d]``: q | k | v, (the output gate's input ``[..., H, d]``, g
+    ``[..., H, d]`` float32: a decay a key channel, beta ``[..., H]`` float32))."""
+    f32 = jnp.float32
+    H, d = c.kda_heads, c.kda_head_dim
+    heads = lambda x: x.reshape(x.shape[:-1] + (H, d))  # noqa: E731
+    f = heads(((a @ lp["kda_f_a"]) @ lp["kda_f_b"]).astype(f32) + lp["kda_dt_bias"].astype(f32))
+    g = -jnp.exp(lp["kda_a_log"].astype(f32))[:, None] * jax.nn.softplus(f)
+    beta = jax.nn.sigmoid((a @ lp["kda_b"]).astype(f32))
+    z = heads(as_written((a @ lp["kda_g_a"]) @ lp["kda_g_b"]))
+    return _proj(c, a, lp["kda_qkv"]), (z, g, beta)
+
+
+def _kda_heads(c, y):
+    """The conv's output ``[..., 3 H d]`` as the rule takes it: q, k ``[..., H,
+    d]`` L2-normalised (q scaled), v ``[..., H, d]``, float32."""
+    from deepspeed_tpu.ops.linear_attention.gated_delta import qk_heads
+
+    q, k, v = (x.reshape(x.shape[:-1] + (c.kda_heads, c.kda_head_dim))
+               for x in jnp.split(y.astype(jnp.float32), 3, axis=-1))
+    return qk_heads(q, k) + (v,)
+
+
+def _kda_decode(c, lp, y, extras, live, pool, slots, impl):
+    from deepspeed_tpu.ops.linear_attention import kda_decode
+
+    _, g, beta = extras
+    return kda_decode(*_kda_heads(c, y), jnp.where(live[:, None, None], g, 0.0),
+                      jnp.where(live[:, None], beta, 0.0), pool, slots, impl=impl)
+
+
+def _kda_chunk(c, lp, y, extras, live, state, impl=None):
+    from deepspeed_tpu.ops.linear_attention import kda_chunked
+
+    _, g, beta = extras
+    return kda_chunked(*_kda_heads(c, y), jnp.where(live[..., None, None], g, 0.0),
+                       jnp.where(live[..., None], beta, 0.0), state)
+
+
+def _kda_output(c, lp, o, extras, dtype):
+    """The rule's output through the head's RMSNorm under the SIGMOID gate, and
+    the output projection."""
+    from deepspeed_tpu.ops.linear_attention import gated_rms_norm
+
+    y = gated_rms_norm(o, extras[0], lp["kda_norm"], c.norm_eps, jax.nn.sigmoid).astype(dtype)
+    return _proj(c, y.reshape(y.shape[:-2] + (-1,)), lp["kda_out"])
+
+
 def _mamba_state_shape(c):
     from deepspeed_tpu.ops.state_space import state_shape
 
@@ -2100,6 +2212,12 @@ RECURRENT: Dict[str, RecurrentKind] = {
         project=_mamba_project, decode=_mamba_decode, chunk=_mamba_chunk,
         # (the scan gates its output by silu(z) itself)
         output=lambda c, lp, o, extras, dtype: _proj(c, o.astype(dtype), lp["mamba_out"])),
+    "kda": RecurrentKind(
+        words="Kimi Delta Attention layers keep a recurrent state",
+        state_shape=lambda c: (c.kda_heads, c.kda_head_dim, c.kda_head_dim),
+        channels=lambda c: 3 * c.kda_heads * c.kda_head_dim, kernel=lambda c: c.kda_conv_kernel,
+        conv_keys=("kda_conv", None),
+        project=_kda_project, decode=_kda_decode, chunk=_kda_chunk, output=_kda_output),
 }
 
 
@@ -2222,7 +2340,7 @@ def _layer(c: TransformerConfig, lp, x, positions, segment_ids, local_flag=None)
         x = x + _norm(mlp_out, lp["mlp_norm"], lp.get("mlp_norm_b"), c.norm, c.norm_eps)
         return _act_constraint(x), aux_loss
     a = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
-    if c.hybrid and "wq" not in lp:  # a recurrent layer (layer_kinds): no attention's keys
+    if c.hybrid and "wo" not in lp:  # a recurrent layer (layer_kinds): no attention's keys
         if segment_ids is not None:
             raise NotImplementedError("packed sequences through a recurrent layer: the "
                                       "state would have to reset at each boundary")
@@ -2288,7 +2406,16 @@ def forward_hidden(
     if c.remat:
         layer_fn = jax.checkpoint(layer_fn, policy=remat_policy(c.remat_policy))
 
-    if c.hybrid:
+    if c.hybrid and c.moe_dense_lead:
+        # kinds of layer AND lead layers whose MLP is of another shape: every
+        # layer unrolled, its parameters out of its own sub-stacks (layer_stacks)
+        auxs = []
+        for li in range(c.n_layers):
+            x, aux = layer_fn(take_layer(params["layers"], c, li, lambda a, i: a[i]),
+                              x, positions, segment_ids)
+            auxs.append(aux)
+        aux_losses, xs = jnp.stack(auxs), None
+    elif c.hybrid:
         # two kinds of layer: one scan step a PERIOD of the pattern, the
         # period's layers unrolled in it, each kind's stack split by period
         period, n = layer_period(c)

@@ -40,6 +40,8 @@ from deepspeed_tpu.accelerator.device import on_tpu
 
 # the kernel's name in a device trace, beside the other ``dstpu_*`` names
 GDN_DECODE = "dstpu_gdn_decode"
+# the same kernel with a decay a key channel (ops/linear_attention/kda.py)
+KDA_DECODE = "dstpu_kda_decode"
 CHUNK = 64
 _HI = jax.lax.Precision.HIGHEST
 
@@ -63,13 +65,13 @@ def gdn_gates(b, a, a_log, dt_bias):
     return g, jax.nn.sigmoid(b.astype(f32))
 
 
-def gated_rms_norm(o, z, w, eps: float):
-    """``w * rms(o) * silu(z)`` over the last axis, in float32 (the weight is
-    plain ``w``, not ``1 + w``)."""
+def gated_rms_norm(o, z, w, eps: float, gate=jax.nn.silu):
+    """``w * rms(o) * gate(z)`` over the last axis, in float32 (the weight is
+    plain ``w``, not ``1 + w``; ``gate``: SiLU, a KDA layer's the sigmoid)."""
     f32 = jnp.float32
     o = o.astype(f32)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
-    return w.astype(f32) * o * jax.nn.silu(z.astype(f32))
+    return w.astype(f32) * o * gate(z.astype(f32))
 
 
 def causal_conv(x, w, state, n=None, bias=None):
@@ -169,11 +171,14 @@ def gdn_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     return jnp.moveaxis(o, 1, 2)[:, :t], state
 
 
-def _decode_kernel(slots, q_ref, k_ref, v_ref, d_ref, b_ref, s_ref, o_ref, s_out_ref, *, hb, rep):
+def _decode_kernel(slots, q_ref, k_ref, v_ref, d_ref, b_ref, s_ref, o_ref, s_out_ref, *, hb, rep,
+                   by_channel):
     del slots  # the state's index maps read it
     for h in range(hb):
         kc = k_ref[:, h // rep : h // rep + 1]                       # [dk, 1]
-        S = s_ref[h] * d_ref[h : h + 1, :]
+        # the decay: a head's ONE number along a [1, dv] row (Gated DeltaNet), or
+        # a number a key channel as a [dk, 1] column, laid out as k is (KDA)
+        S = s_ref[h] * (d_ref[:, h : h + 1] if by_channel else d_ref[h : h + 1, :])
         mem = jnp.sum(S * kc, axis=0, keepdims=True)                 # [1, dv]
         delta = (v_ref[h : h + 1, :] - mem) * b_ref[h : h + 1, :]
         S = S + kc * delta
@@ -188,25 +193,31 @@ def _head_block(nv: int, rep: int) -> int:
 
 
 def _decode_pallas(q, k, v, g, beta, pool, slots, interpret: bool):
+    """The one-token kernel. ``g [R, nv]``: a decay a head, under the name
+    ``dstpu_gdn_decode``; ``g [R, nv, dk]``: a decay a key channel (KDA), the
+    same body under ``dstpu_kda_decode``."""
     R, nk, dk = q.shape
     nv, dv = v.shape[-2:]
     rep = nv // nk
     hb = _head_block(nv, rep)
     J, kb = nv // hb, hb // rep
+    by_channel = g.ndim == 3
 
-    def columns(a):  # [R, nk, dk] -> [R, J, dk, kb]: a key head a lane
-        return jnp.swapaxes(a.reshape(R, J, kb, dk), -1, -2)
+    def columns(a, n):  # [R, J * n, dk] -> [R, J, dk, n]: a head a lane
+        return jnp.swapaxes(a.reshape(R, J, n, dk), -1, -2)
 
     rows = lambda a: jnp.broadcast_to(a[..., None], (R, nv, dv))  # noqa: E731
     head_rows = pl.BlockSpec((None, hb, dv), lambda r, j, s: (r, j, 0))
     key_cols = pl.BlockSpec((None, None, dk, kb), lambda r, j, s: (r, j, 0, 0))
+    head_cols = pl.BlockSpec((None, None, dk, hb), lambda r, j, s: (r, j, 0, 0))
     state = pl.BlockSpec((None, hb, dk, dv), lambda r, j, s: (s[r], j, 0, 0))
     o, pool = pl.pallas_call(
-        functools.partial(_decode_kernel, hb=hb, rep=rep),
+        functools.partial(_decode_kernel, hb=hb, rep=rep, by_channel=by_channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(R, J),
-            in_specs=[key_cols, key_cols, head_rows, head_rows, head_rows, state],
+            in_specs=[key_cols, key_cols, head_rows, head_cols if by_channel else head_rows,
+                      head_rows, state],
             out_specs=[head_rows, state],
         ),
         out_shape=[jax.ShapeDtypeStruct((R, nv, dv), jnp.float32),
@@ -215,8 +226,9 @@ def _decode_pallas(q, k, v, g, beta, pool, slots, interpret: bool):
         input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name=GDN_DECODE,
-    )(slots.astype(jnp.int32), columns(q), columns(k), v, rows(jnp.exp(g)), rows(beta), pool)
+        name=KDA_DECODE if by_channel else GDN_DECODE,
+    )(slots.astype(jnp.int32), columns(q, kb), columns(k, kb), v,
+      columns(jnp.exp(g), hb) if by_channel else rows(jnp.exp(g)), rows(beta), pool)
     return o, pool
 
 

@@ -251,6 +251,9 @@ class EngineCore:
         # one with Mamba layers: the same, and the tokens the chunked scan walked
         self._inc("mamba_decode_rows_total", held("mamba_decode_rows"))
         self._inc("mamba_chunk_tokens_total", held("mamba_chunk_tokens"))
+        # ... with Kimi Delta Attention layers: the same pair
+        self._inc("kda_decode_rows_total", held("kda_decode_rows"))
+        self._inc("kda_chunk_tokens_total", held("kda_chunk_tokens"))
         # the cache as the step found it, by kind, summed a step; and what a
         # window layer's decode walks visit (beside paged_live_blocks_total)
         self._inc("kv_global_blocks_used_total", held("kv_global_blocks"))

@@ -156,6 +156,9 @@ class ServingMetrics:
             # chunked scan walked
             "mamba_decode_rows_total": 0,
             "mamba_chunk_tokens_total": 0,
+            # Kimi Delta Attention layers: the same pair
+            "kda_decode_rows_total": 0,
+            "kda_chunk_tokens_total": 0,
             # a state slot's bytes (update_kv_pool_info: set once, no total)
             "state_slot_bytes": 0,
             # the cache by kind (EngineCore._count_step), summed a step: blocks

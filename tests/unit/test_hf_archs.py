@@ -1201,3 +1201,175 @@ def test_jamba_with_experts_is_refused_with_its_reason():
         config_from_hf(hf)
     with pytest.raises(ValueError, match="leaves one kind"):
         config_from_hf({**transformers.JambaConfig(**JAMBA_TINY).to_dict(), "attn_layer_offset": 9})
+
+
+# --- kimi_linear: no published code in transformers 4.57; the configuration's
+# mapping, the three lifted refusals and the checkpoint names' round trip -------
+KIMI_TINY = dict(
+    model_type="kimi_linear", vocab_size=256, hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=32, num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=4,
+    num_experts=2, num_experts_per_token=2, num_shared_experts=1, first_k_dense_replace=1,
+    moe_layer_freq=1, moe_renormalize=True, moe_router_activation_func="sigmoid",
+    num_expert_group=1, topk_group=1, use_grouped_topk=True, routed_scaling_factor=2.446,
+    kv_lora_rank=32, q_lora_rank=None, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    mla_use_nope=True, rms_norm_eps=1e-5, rope_theta=10000, rope_scaling=None,
+    tie_word_embeddings=False, hidden_act="silu", head_dim=16, model_max_length=512,
+    num_nextn_predict_layers=0,
+    linear_attn_config=dict(full_attn_layers=[2, 5], kda_layers=[1, 3, 4], head_dim=16,
+                            num_heads=4, short_conv_kernel_size=4),
+    deployment_share=dict(num_experts=6, chips_per_layer=3, share_index=1),
+)
+
+
+def test_kimi_linear_config_of_the_published_row_and_of_the_benchmarks_file():
+    """The catalog row's ``config`` gives 20 KDA and 7 latent layers, a lead
+    layer, 256 sigmoid experts top 8 with a bias and a shared expert, one query
+    projection and no positions; the benchmark's file the 12-layer share."""
+    import json
+    import os
+
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    file = json.load(open(os.path.join(root, "benchmarks", "configs", "kimi-linear-48b-a3b.json")))
+    row = {k: v for k, v in file.items() if k not in ("deployment_share",)}
+    row.update(file["published"])
+    row["linear_attn_config"] = {**file["linear_attn_config"], **file["published"]["linear_attn_config"]}
+    c = config_from_hf(row)
+    assert c.n_layers == 27 and c.layer_kinds.count("kda") == 20 and c.layer_kinds.count("full") == 7
+    assert [i + 1 for i, k in enumerate(c.layer_kinds) if k == "full"] == [4, 8, 12, 16, 20, 24, 27]
+    assert c.moe_dense_lead == 1 and (c.n_experts, c.moe_experts_total, c.moe_top_k) == (256, 0, 8)
+    assert c.moe_score == "sigmoid" and c.router_has_bias and c.moe_n_group == 1
+    assert c.moe_shared_expert_dim == 1024 and not c.moe_shared_gated and c.moe_routed_scale == 2.446
+    assert c.latent and c.q_lora_rank == 0 and c.position == "none" and c.kv_layers == 7
+    assert (c.kv_lora_rank, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim, c.head_dim) == (512, 128, 64, 128, 192)
+    assert (c.kda_heads, c.kda_head_dim, c.kda_conv_kernel) == (32, 128, 4) and c.recurrent_kind == "kda"
+    assert c.vocab_size == 163840 and c.ffn_dim == 9216 and c.expert_dim == 1024 and not c.tie_embeddings
+    share = config_from_hf(file)
+    assert share.layer_kinds == ("kda", "kda", "kda", "full") * 3 and share.kv_layers == 3
+    assert (share.n_experts, share.moe_experts_total, share.moe_expert_shard) == (32, 256, 0)
+    assert share.router_width == 256 and share.vocab_size == 20480 and share.moe_dense_lead == 1
+
+
+@pytest.mark.parametrize("change,match", [
+    ({"linear_attn_config": {**KIMI_TINY["linear_attn_config"], "kda_layers": [1, 3]}}, "do not partition"),
+    ({"linear_attn_config": {**KIMI_TINY["linear_attn_config"], "full_attn_layers": [2, 4, 5]}}, "do not partition"),
+    ({"num_nextn_predict_layers": 1}, "multi-token-prediction"),
+    ({"num_expert_group": 2}, "num_expert_group > 1"),
+    ({"q_lora_rank": 16}, "one query projection and no rotary"),
+    ({"mla_use_nope": False}, "one query projection and no rotary"),
+    ({"first_k_dense_replace": 0}, "dense lead layers followed by expert layers"),
+    ({"moe_router_activation_func": "softmax"}, "expected 'sigmoid'"),
+    ({"num_experts": 4}, "is not one chip's share"),
+])
+def test_kimi_linear_refuses_what_it_cannot_map(change, match):
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    with pytest.raises(ValueError, match=match):
+        config_from_hf({**KIMI_TINY, **change})
+
+
+def _latent_hybrid(**change):
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    base = dict(
+        vocab_size=64, hidden_size=32, n_layers=4, n_heads=2, head_dim_override=24, kv_lora_rank=16,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, position="none",
+        layer_kinds=("kda", "full", "kda", "full"), kda_heads=2, kda_head_dim=16,
+        n_experts=2, moe_top_k=1, moe_dense_lead=1, moe_drop_tokens=False)
+    return TransformerConfig(**{**base, **change})
+
+
+@pytest.mark.parametrize("change,match", [
+    # latent attention as the "full" kind of a hybrid stack: lifted; a layer of
+    # two sub-blocks in such a stack is still refused
+    ({"moe_shortcut": True, "moe_dense_lead": 0}, "nor\\s+as layer kinds with a layer of two sub-blocks"),
+    # one query projection (q_lora_rank 0): lifted; a negative rank is still refused
+    ({"q_lora_rank": -1}, "q_lora_rank 0: one query projection"),
+    # no positions: lifted; learned positions are still refused
+    ({"position": "learned"}, "rotary or no positions"),
+    # a dense lead layer in a hybrid stack: lifted; as many lead layers as layers is still refused
+    ({"moe_dense_lead": 4}, "some, not all"),
+    ({"moe_dense_lead": 1, "n_experts": 0}, "some, not all"),
+    # a third recurrent kind: one kind a stack still
+    ({"layer_kinds": ("kda", "full", "gdn", "full")}, "ONE of 'gdn', 'mamba' and 'kda'"),
+    ({"kda_heads": 0}, "needs kda_heads / kda_head_dim"),
+])
+def test_the_lifted_refusals_still_refuse_what_does_not_compose(change, match):
+    assert _latent_hybrid().latent and _latent_hybrid().hybrid   # what is lifted builds
+    assert _latent_hybrid(q_lora_rank=8, position="rope").recurrent_kind == "kda"
+    with pytest.raises(ValueError, match=match):
+        _latent_hybrid(**change)
+
+
+def test_kimi_linear_checkpoint_names_round_trip(tmp_path):
+    """A checkpoint written under the names ``_kimi_linear_layer`` reads (all 6
+    experts; the three convs and projections of a KDA layer apart) comes back as
+    the seeded tree: by kind, the lead layer's MLP and the expert blocks apart,
+    this chip's experts (2-3), the router whole. ``forward()`` on it equals the
+    benchmark's plain reference."""
+    import dataclasses
+    import importlib
+    import json
+
+    from safetensors.torch import save_file
+
+    from deepspeed_tpu.models.hf import config_from_hf
+    from deepspeed_tpu.models.transformer import init_params
+
+    cfg = dataclasses.replace(config_from_hf(KIMI_TINY), dtype="float32")
+    params = init_params(cfg, jax.random.key(0))
+    other = init_params(cfg, jax.random.key(1))["layers"]["sparse"]   # the other chips' experts
+    L = params["layers"]
+    state = {"model.embed_tokens.weight": params["embed"], "model.norm.weight": params["final_norm"],
+             "lm_head.weight": params["lm_head"].T}
+    mlp = (("w_gate", "gate_proj", "w1"), ("w_up", "up_proj", "w3"), ("w_down", "down_proj", "w2"))
+    C = cfg.kda_heads * cfg.kda_head_dim
+    for i, kind in enumerate(cfg.layer_kinds):
+        p, a, k = f"model.layers.{i}", f"model.layers.{i}.self_attn", cfg.layer_kinds[:i].count(kind)
+        state[f"{p}.input_layernorm.weight"] = L["attn_norm"][i]
+        state[f"{p}.post_attention_layernorm.weight"] = L["mlp_norm"][i]
+        if kind == "full":
+            for name, hf in (("wq", "q_proj"), ("wkv_a", "kv_a_proj_with_mqa"), ("wkv_b", "kv_b_proj"),
+                             ("wo", "o_proj")):
+                state[f"{a}.{hf}.weight"] = L["full"][name][k].T
+            state[f"{a}.kv_a_layernorm.weight"] = L["full"]["kv_a_norm"][k]
+        else:
+            kda = {n: w[k] for n, w in L["kda"].items()}
+            for j, n in enumerate("qkv"):
+                state[f"{a}.{n}_proj.weight"] = kda["kda_qkv"][:, j * C: (j + 1) * C].T
+                state[f"{a}.{n}_conv1d.weight"] = kda["kda_conv"][:, j * C: (j + 1) * C].T[:, None, :]
+            state[f"{a}.A_log"] = kda["kda_a_log"].reshape(1, 1, -1, 1)
+            state[f"{a}.dt_bias"] = kda["kda_dt_bias"]
+            for name, hf in (("kda_f_a", "f_a_proj"), ("kda_f_b", "f_b_proj"), ("kda_b", "b_proj"),
+                             ("kda_g_a", "g_a_proj"), ("kda_g_b", "g_b_proj"), ("kda_out", "o_proj")):
+                state[f"{a}.{hf}.weight"] = kda[name].T
+            state[f"{a}.o_norm.weight"] = kda["kda_norm"]
+        if i == 0:
+            for name, hf, _ in mlp:
+                state[f"{p}.mlp.{hf}.weight"] = L["lead"][name][0].T
+            continue
+        m, s = f"{p}.block_sparse_moe", i - 1
+        state[f"{m}.gate.weight"] = L["sparse"]["router"][s].T
+        state[f"{m}.gate.e_score_correction_bias"] = L["sparse"]["router_bias"][s]
+        for name, hf, w in mlp:
+            state[f"{m}.shared_experts.{hf}.weight"] = L["sparse"][f"shared_{name[2:]}"][s].T
+            for e in range(6):
+                mine = L["sparse"][name][s][e - 2] if 2 <= e < 4 else other[name][s][e % 2]
+                state[f"{m}.experts.{e}.{w}.weight"] = mine.T
+    save_file({k: torch.tensor(np.ascontiguousarray(np.asarray(v, np.float32)))
+               for k, v in state.items()}, str(tmp_path / "model.safetensors"))
+    json.dump(KIMI_TINY, open(tmp_path / "config.json", "w"))
+    got_cfg, got = load_hf_model(str(tmp_path), dtype="float32")
+    assert got_cfg == cfg and got_cfg.moe_expert_shard == 1
+    flat_want = jax.tree_util.tree_leaves_with_path(params)
+    flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
+    assert set(flat_got) == {k for k, _ in flat_want}
+    for k, v in flat_want:
+        np.testing.assert_array_equal(np.asarray(flat_got[k]), np.asarray(v), err_msg=str(k))
+    ref = importlib.import_module("benchmarks.reference.kimi_linear")
+    tokens = np.random.default_rng(3).integers(0, 256, size=(1, 40)).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        ours, _ = jax.jit(lambda p, t: forward(p, t, got_cfg))(got, jnp.asarray(tokens))
+        np.testing.assert_allclose(np.asarray(ours[0]), np.asarray(ref.logits(got, tokens[0], KIMI_TINY)),
+                                   atol=2e-5, rtol=0)

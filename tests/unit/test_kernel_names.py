@@ -127,6 +127,23 @@ def test_gdn_decode_is_named_and_updates_the_pool_in_place():
     assert "output_operand_aliases" in text or "operand_index = 6" in text
 
 
+def test_kda_decode_is_named_apart_from_gdn_decode():
+    """The same kernel body with a decay a key channel (``g [R, H, dk]``) at Kimi
+    Linear's widths: its custom call carries a name of its own, which a trace
+    tells from Gated DeltaNet's, and the state pool is aliased to its output."""
+    from deepspeed_tpu.ops.linear_attention import gated_delta, kda
+
+    R, H, d, slots = 32, 32, 128, 9 * 33
+    f32 = jnp.float32
+    text = _tpu_text(
+        lambda q, k, v, g, b, pool, s: kda.kda_decode(q, k, v, g, b, pool, s, impl="kernel"),
+        _s((R, H, d), f32), _s((R, H, d), f32), _s((R, H, d), f32), _s((R, H, d), f32),
+        _s((R, H), f32), _s((slots, H, d, d), f32), _s((R,), jnp.int32))
+    assert _kernel_names(text) == {gated_delta.KDA_DECODE} == {"dstpu_kda_decode"}
+    assert gated_delta.KDA_DECODE != gated_delta.GDN_DECODE
+    assert "output_operand_aliases" in text or "operand_index = 6" in text
+
+
 def test_the_state_space_kernels_are_named_and_the_pool_is_updated_in_place():
     """The chunked scan and the one-token update at Jamba2-3B's widths: the
     custom calls carry the names the benchmark's readers look for, and the
