@@ -155,9 +155,11 @@ class StepStats:
     # layer calls, experts with a row) of the step's expert layers
     # (_count_moe); None for a dense model
     moe: Optional[dict] = None
-    # DeltaNet layers: rows whose state took the one-token update, of ONE
-    # layer (every such layer sees the same); 0 for a model without them
+    # DeltaNet layers: rows whose state took the one-token update and the
+    # prompt tokens the chunk rule walked, of ONE layer (every such layer sees
+    # the same); 0 for a model without them
     gdn_decode_rows: int = 0
+    gdn_chunk_tokens: int = 0
     # Mamba layers: the same, and the prompt tokens the chunked scan walked
     mamba_decode_rows: int = 0
     mamba_chunk_tokens: int = 0
@@ -2670,12 +2672,12 @@ class InferenceEngineV2:
 
     def _count_recurrent(self, decode_rows: int, chunk_tokens: int):
         """StepStats' fields of the stack's recurrent kind: the rows whose
-        states took the one-token update and, for a kind that counts them, the
-        prompt tokens its chunk rule walked, of ONE such layer."""
+        states took the one-token update and the prompt tokens its chunk rule
+        walked, of ONE such layer."""
         kind = self._mc.recurrent_kind
-        if kind in ("mamba", "kda"):
-            return {f"{kind}_decode_rows": decode_rows, f"{kind}_chunk_tokens": chunk_tokens}
-        return {"gdn_decode_rows": decode_rows} if kind else {}
+        if not kind:
+            return {}
+        return {f"{kind}_decode_rows": decode_rows, f"{kind}_chunk_tokens": chunk_tokens}
 
     def _stage_round(self, uids, n: int):
         """A fused decode round of ``n`` steps over ``uids``: cache key and

@@ -2112,7 +2112,7 @@ def _gdn_chunk(c, lp, y, extras, live, state, impl=None):
     _, g, beta = extras
     q, k, v = gdn_heads(c, y)
     return gdn_chunked(q, k, v, jnp.where(live[..., None], g, 0.0),
-                       jnp.where(live[..., None], beta, 0.0), state)
+                       jnp.where(live[..., None], beta, 0.0), state, impl=impl)
 
 
 def _mamba_project(c, lp, a):
@@ -2178,7 +2178,7 @@ def _kda_chunk(c, lp, y, extras, live, state, impl=None):
 
     _, g, beta = extras
     return kda_chunked(*_kda_heads(c, y), jnp.where(live[..., None, None], g, 0.0),
-                       jnp.where(live[..., None], beta, 0.0), state)
+                       jnp.where(live[..., None], beta, 0.0), state, impl=impl)
 
 
 def _kda_output(c, lp, o, extras, dtype):
@@ -2232,13 +2232,17 @@ def recurrent_conv(kind: RecurrentKind, lp, x, state, n=None):
 
 def _recurrent_block(c: TransformerConfig, lp, x):
     """A recurrent layer (``RECURRENT``) with no cache: conv and state start
-    from zero. x: [b, s, h] (normed)."""
+    from zero. x: [b, s, h] (normed). The two delta rules run their ``"jnp"``
+    bodies here whatever the platform: training differentiates this path, XLA's
+    autodiff goes through the chunked form and a ``pallas_call`` has no gradient
+    (the served step names no ``impl`` and takes the kernels on a TPU)."""
     kind = RECURRENT[c.recurrent_kind]
     b, s = x.shape[:2]
     y, extras = kind.project(c, lp, x)
     y, _ = recurrent_conv(kind, lp, y, jnp.zeros((b, kind.kernel(c) - 1, kind.channels(c)), x.dtype))
     o, _ = kind.chunk(c, lp, y, extras, jnp.ones((b, s), bool),
-                      jnp.zeros((b,) + kind.state_shape(c), jnp.float32))
+                      jnp.zeros((b,) + kind.state_shape(c), jnp.float32),
+                      "jnp" if c.recurrent_kind in ("gdn", "kda") else None)
     return kind.output(c, lp, o, extras, x.dtype)
 
 

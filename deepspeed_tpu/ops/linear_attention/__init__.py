@@ -4,8 +4,10 @@ One token costs the same whatever the context's length: a sequence's cache is
 a fixed ``[value heads, key dim, value dim]`` float32 state and the last
 ``conv width - 1`` inputs of a short causal convolution. ``kda.py``: the same
 rule with a decay for every key channel (Kimi Delta Attention).
+``delta_chunk.py``: a prompt chunk of either rule as one kernel.
 """
 
+from deepspeed_tpu.ops.linear_attention.delta_chunk import GDN_CHUNK, KDA_CHUNK
 from deepspeed_tpu.ops.linear_attention.gated_delta import (
     GDN_DECODE,
     KDA_DECODE,
@@ -20,7 +22,9 @@ from deepspeed_tpu.ops.linear_attention.gated_delta import (
 from deepspeed_tpu.ops.linear_attention.kda import kda_chunked, kda_decode, kda_recurrent
 
 __all__ = [
+    "GDN_CHUNK",
     "GDN_DECODE",
+    "KDA_CHUNK",
     "KDA_DECODE",
     "causal_conv",
     "gated_rms_norm",

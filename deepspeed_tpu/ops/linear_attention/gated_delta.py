@@ -19,6 +19,9 @@ padding of a serving step's grid is kept out of it.
   * ``gdn_chunked``: chunks of 64 tokens, the in-chunk part as matmuls (the
     WY form of the published chunked rule), the state carried from chunk to
     chunk: what a prompt chunk runs, from the slot's state to the slot's state.
+    On a TPU the Pallas kernel ``dstpu_gdn_chunk`` (``delta_chunk.py``: a head's
+    state stays in VMEM across a row's chunks); elsewhere, as the kernel's
+    second oracle and where a gradient is taken, plain XLA.
   * ``gdn_decode``: one token a row over a POOL of states in place. On a TPU
     the Pallas kernel ``dstpu_gdn_decode`` (the rows' slot ids by scalar
     prefetch, the pool aliased to the output: one read and one write of a
@@ -123,15 +126,22 @@ def gdn_recurrent(q, k, v, g, beta, state):
     return jnp.moveaxis(o, 0, 1), state
 
 
-def gdn_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
+def gdn_chunked(q, k, v, g, beta, state, chunk: int = CHUNK, impl: Optional[str] = None):
     """``gdn_recurrent``'s result in chunks of ``chunk`` tokens: inside a chunk
     the delta rule's triangular system is solved as ``(I - A)^-1 = prod_j (I +
     A^(2^j))`` (A strictly lower, so nilpotent), the state goes from chunk to
-    chunk. ``t`` is padded to a whole number of chunks with ``g = beta = 0``."""
+    chunk. ``t`` is padded to a whole number of chunks with ``g = beta = 0``.
+    ``impl``: ``"kernel"`` (on a TPU), ``"interpret"`` (the kernel interpreted,
+    for tests on the CPU) or ``"jnp"`` (this body: the one a gradient goes
+    through); None picks by the platform."""
+    impl = impl or ("kernel" if on_tpu() else "jnp")
+    if impl != "jnp":
+        from deepspeed_tpu.ops.linear_attention.delta_chunk import delta_chunk
+
+        return delta_chunk(q, k, v, g, beta, state, impl == "interpret", chunk)
     f32 = jnp.float32
     r, t, nv, dv = v.shape
     q, k = _to_value_heads(q.astype(f32), k.astype(f32), nv)
-    dk = q.shape[-1]
     pad = -t % chunk
     N = (t + pad) // chunk
 

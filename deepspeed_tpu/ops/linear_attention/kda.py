@@ -14,7 +14,10 @@ a head's channels given one decay this IS ``gdn_recurrent``. A token with
 
   * ``kda_recurrent``: token by token (``lax.scan``): the oracle.
   * ``kda_chunked``: chunks of 64 tokens, the in-chunk part as matrix products,
-    the state carried chunk to chunk. NO EXPONENTIAL OF A POSITIVE NUMBER is
+    the state carried chunk to chunk: what a prompt chunk runs. On a TPU the
+    Pallas kernel ``dstpu_kda_chunk`` (``delta_chunk.py``: a head's state stays
+    in VMEM across a row's chunks); elsewhere, as the kernel's second oracle and
+    where a gradient is taken, plain XLA. NO EXPONENTIAL OF A POSITIVE NUMBER is
     taken anywhere: with ``G`` the in-chunk cumulative sum of ``g`` a channel,
     the pair matrix ``M_ij = sum_c a_ic k_jc exp(G_ic - G_jc)`` (j <= i) cannot
     be split as ``(a exp(G)) (k exp(-G))^T``: ``A_log`` up to ln 16 under a
@@ -88,11 +91,18 @@ def _pair_matrices(rows, k, G, sub):
     return jnp.concatenate(out, axis=-2)
 
 
-def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK, sub: int = SUB):
+def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK, sub: int = SUB,
+                impl: Optional[str] = None):
     """``kda_recurrent``'s result in chunks of ``chunk`` tokens (sub-blocks of
     ``sub``): the delta rule's triangular system solved as ``gdn_chunked`` solves
     it, the state from chunk to chunk. ``t`` is padded to whole chunks with
-    ``g = beta = 0``."""
+    ``g = beta = 0``. ``impl`` as ``gdn_chunked``'s (the kernel sets its own
+    sub-blocks)."""
+    impl = impl or ("kernel" if on_tpu() else "jnp")
+    if impl != "jnp":
+        from deepspeed_tpu.ops.linear_attention.delta_chunk import delta_chunk
+
+        return delta_chunk(q, k, v, g, beta, state, impl == "interpret", chunk)
     f32 = jnp.float32
     r, t, H, dv = v.shape
     pad = -t % chunk

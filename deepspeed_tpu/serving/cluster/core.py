@@ -246,9 +246,11 @@ class EngineCore:
             # whose kept groups include one this chip holds
             self._inc("moe_group_tokens_total", moe.get("group_tokens", 0))
             self._inc("moe_group_hit_tokens_total", moe.get("group_hit", 0))
-        # a model with DeltaNet layers: the rows whose states took the update
+        # a model with DeltaNet layers: the rows whose states took the update,
+        # and the prompt tokens the chunk rule walked
         self._inc("gdn_decode_rows_total", held("gdn_decode_rows"))
-        # one with Mamba layers: the same, and the tokens the chunked scan walked
+        self._inc("gdn_chunk_tokens_total", held("gdn_chunk_tokens"))
+        # one with Mamba layers: the same pair (the chunked scan's tokens)
         self._inc("mamba_decode_rows_total", held("mamba_decode_rows"))
         self._inc("mamba_chunk_tokens_total", held("mamba_chunk_tokens"))
         # ... with Kimi Delta Attention layers: the same pair

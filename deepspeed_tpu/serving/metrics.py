@@ -150,10 +150,10 @@ class ServingMetrics:
             "moe_experts_hit_total": 0,
             # Gated DeltaNet layers (EngineCore._count_step): rows whose
             # recurrent state took the one-token update, of one such layer
-            # a step
+            # a step, and the prompt tokens one such layer's chunk rule walked
             "gdn_decode_rows_total": 0,
-            # Mamba layers: the same, and the prompt tokens one such layer's
-            # chunked scan walked
+            "gdn_chunk_tokens_total": 0,
+            # Mamba layers: the same pair (the chunked scan's tokens)
             "mamba_decode_rows_total": 0,
             "mamba_chunk_tokens_total": 0,
             # Kimi Delta Attention layers: the same pair

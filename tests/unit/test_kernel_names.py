@@ -5,8 +5,11 @@ what the benchmark's kernel-share readers look for. Lowering only: the Mosaic
 lowering for the TPU platform needs no chip and compiles nothing.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from deepspeed_tpu.ops import fused_ce
@@ -142,6 +145,61 @@ def test_kda_decode_is_named_apart_from_gdn_decode():
     assert _kernel_names(text) == {gated_delta.KDA_DECODE} == {"dstpu_kda_decode"}
     assert gated_delta.KDA_DECODE != gated_delta.GDN_DECODE
     assert "output_operand_aliases" in text or "operand_index = 6" in text
+
+
+@pytest.mark.parametrize("rule, name", [("kda", "dstpu_kda_chunk"), ("gdn", "dstpu_gdn_chunk")])
+def test_the_chunk_rules_are_named_apart(rule, name):
+    """A prompt chunk's delta rule at the published widths (Kimi Linear: 32
+    heads; Qwen3-Next: 16 key heads serving 32 value heads), two rows of 512
+    tokens: ONE kernel body under two names, by the decay's shape, so that a
+    trace tells the two models apart; no XLA body beside it."""
+    from deepspeed_tpu.ops import linear_attention as L
+
+    r, t, nv, d, f32 = 2, 512, 32, 128, jnp.float32
+    nk, fn, g = (nv, L.kda_chunked, (r, t, nv, d)) if rule == "kda" else (16, L.gdn_chunked, (r, t, nv))
+    text = _tpu_text(
+        lambda q, k, v, g, b, S: fn(q, k, v, g, b, S, impl="kernel"),
+        _s((r, t, nk, d), f32), _s((r, t, nk, d), f32), _s((r, t, nv, d), f32), _s(g, f32),
+        _s((r, t, nv), f32), _s((r, nv, d, d), f32))
+    assert _kernel_names(text) == {getattr(L, f"{rule.upper()}_CHUNK")} == {name}
+    assert "while" not in text  # the scan over chunks went into the kernel's grid
+    assert len({L.KDA_CHUNK, L.GDN_CHUNK, L.KDA_DECODE, L.GDN_DECODE}) == 4
+
+
+@pytest.mark.parametrize("kind", ["kda", "gdn"])
+def test_forward_of_a_delta_stack_differentiates_with_impl_left_alone(kind, monkeypatch):
+    """Training goes through ``models.forward``, whose cacheless recurrent block
+    names the XLA body for the two delta rules: ``jax.grad`` of a toy stack is
+    finite even where the platform would pick the kernel (a ``pallas_call`` has
+    no gradient), and no kernel is in the lowered program."""
+    import dataclasses
+
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.hf import config_from_hf
+    from deepspeed_tpu.ops.linear_attention import gated_delta, kda
+
+    # the serving tests' toys, cut to one recurrent layer and one attention layer / one period
+    if kind == "kda":
+        hf = importlib.import_module("tests.unit.test_kimi_linear_serving").HF
+        hf = {**hf, "num_hidden_layers": 2,
+              "linear_attn_config": {**hf["linear_attn_config"], "kda_layers": [1], "full_attn_layers": [2]}}
+    else:
+        hf = {**importlib.import_module("tests.unit.test_qwen3_next_serving").HF, "num_hidden_layers": 4}
+    cfg = dataclasses.replace(config_from_hf(hf), dtype="float32", remat=False)
+    assert cfg.recurrent_kind == kind
+    params = T.init_params(cfg, jax.random.key(0))
+    toks = jnp.asarray(np.random.default_rng(0).integers(1, 256, size=(1, 70)), jnp.int32)
+    # as on a TPU: a rule left to pick would pick its kernel
+    monkeypatch.setattr(gated_delta, "on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+
+    def loss(p):
+        return jnp.mean(T.forward(p, toks, cfg)[0].astype(jnp.float32) ** 2)
+
+    grads = jax.jit(jax.grad(loss))(params)
+    leaves = jax.tree.leaves(grads)
+    assert all(bool(jnp.isfinite(g).all()) for g in leaves)
+    assert any(float(jnp.abs(g).max()) > 0 for g in leaves)
 
 
 def test_the_state_space_kernels_are_named_and_the_pool_is_updated_in_place():

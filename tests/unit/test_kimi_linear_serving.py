@@ -82,14 +82,23 @@ def engine(model):
     return _engine(*model)
 
 
-def _serve_logits(eng, prompts, n_new):
+def _serve_logits(eng, prompts, n_new, late=(), steps=None):
     """Each prompt's logits at its last prompt token and at ``n_new - 1``
-    greedy tokens after it, as the engine's steps return them."""
+    greedy tokens after it, as the engine's steps return them. The prompts
+    numbered in ``late`` are submitted after the first step; ``steps`` collects
+    every step's ``StepStats``."""
     for uid, p in enumerate(prompts):
-        eng.scheduler.submit(uid, p)
+        if uid not in late:
+            eng.scheduler.submit(uid, p)
     got = {uid: [] for uid in range(len(prompts))}
-    for _ in range(60):
-        for uid, lg in eng.step().items():
+    for i in range(60):
+        if i == 1:
+            for uid in late:
+                eng.scheduler.submit(uid, prompts[uid])
+        out = eng.step()
+        if steps is not None:
+            steps.append(eng.last_step)
+        for uid, lg in out.items():
             got[uid].append(np.asarray(lg, np.float32))
             if len(got[uid]) < n_new:
                 eng.scheduler.feedback(uid, int(np.argmax(lg)))
@@ -148,16 +157,39 @@ def test_engine_equals_the_reference_on_logits_float32(model, engine):
     assert last.kda_decode_rows >= 0 and last.gdn_decode_rows == last.mamba_decode_rows == 0
 
 
-def test_the_interpreted_kernels_serve_the_same(model):
+def test_the_interpreted_kernels_serve_the_same(model, monkeypatch):
     """Every kernel of the served path interpreted in ONE engine:
-    ``dstpu_kda_decode`` (``_rec_impl``) and, under ``paged_attention_impl:
-    kernel``, ``dstpu_mla_decode`` / ``dstpu_mla_chunk`` / the pool write at 4
-    heads with unrotated shared dims."""
+    ``dstpu_kda_decode`` and ``dstpu_kda_chunk`` (``_rec_impl``) and, under
+    ``paged_attention_impl: kernel``, ``dstpu_mla_decode`` / ``dstpu_mla_chunk``
+    / the pool write at 4 heads with unrotated shared dims. The second prompt
+    arrives after the first step, so the second step's TWO chunk rows are the
+    first prompt's tail, continued from its slot's state, and a fresh row (one
+    chunk program for both steps); a step's ``kda_chunk_tokens`` are the live
+    prompt tokens its chunk rows carried."""
+    from deepspeed_tpu.ops.linear_attention import delta_chunk
+
     cfg, params = model
     eng = _engine(cfg, params, paged_attention_impl="kernel",
                   kv_cache={"block_size": 128, "num_blocks": 12, "max_blocks_per_seq": 4})
     eng._rec_impl = "interpret"
-    assert _worst(eng, params, lens=(40, 200), seed=8) < ATOL
+    traced, kernel = [], delta_chunk.delta_chunk
+    monkeypatch.setattr(delta_chunk, "delta_chunk",
+                        lambda q, *a, **kw: traced.append(q.shape[:2]) or kernel(q, *a, **kw))
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in (200, 40, 30)]
+    steps = []
+    with jax.default_matmul_precision("highest"):
+        served = _serve_logits(eng, prompts, n_new=3, late=(1,), steps=steps)
+        for uid, p in enumerate(prompts):
+            np.testing.assert_allclose(
+                served[uid], _reference_logits(params, p, served[uid]), atol=ATOL, rtol=0)
+    # 160 + 30, both fresh | 40 continued + 40 fresh | decode steps
+    assert [st.prefill_tokens for st in steps[:3]] == [190, 80, 0]
+    assert steps[1].grid_slots == 4 + 2 * 160
+    assert traced == [(2, 160)] * cfg.kind_count("kda")              # (chunk rows, tq) a KDA layer
+    for st in steps:
+        assert st.kda_chunk_tokens == st.prefill_tokens
+        assert st.gdn_chunk_tokens == st.mamba_chunk_tokens == 0
 
 
 def test_a_reused_slot_poisoned_with_nan_starts_from_zero(model, engine):

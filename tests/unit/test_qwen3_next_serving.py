@@ -68,14 +68,23 @@ def _engine(cfg, params, dtype="float32", decode_steps=1, **extra):
     return InferenceEngineV2(cfg, params, RaggedInferenceEngineConfig.from_dict(rc))
 
 
-def _serve_logits(eng, prompts, n_new):
+def _serve_logits(eng, prompts, n_new, late=(), steps=None):
     """Each prompt's logits at its last prompt token and at ``n_new - 1``
-    greedy tokens after it, as the engine's steps return them."""
+    greedy tokens after it, as the engine's steps return them. The prompts
+    numbered in ``late`` are submitted after the first step; ``steps`` collects
+    every step's ``StepStats``."""
     for uid, p in enumerate(prompts):
-        eng.scheduler.submit(uid, p)
+        if uid not in late:
+            eng.scheduler.submit(uid, p)
     got = {uid: [] for uid in range(len(prompts))}
-    for _ in range(40):
-        for uid, lg in eng.step().items():
+    for i in range(40):
+        if i == 1:
+            for uid in late:
+                eng.scheduler.submit(uid, prompts[uid])
+        out = eng.step()
+        if steps is not None:
+            steps.append(eng.last_step)
+        for uid, lg in out.items():
             got[uid].append(np.asarray(lg, np.float32))
             if len(got[uid]) < n_new:
                 eng.scheduler.feedback(uid, int(np.argmax(lg)))
@@ -93,20 +102,43 @@ def _reference_logits(params, hf, prompt, served):
 
 
 @pytest.mark.parametrize("gdn_impl", ["jnp", "interpret"])
-def test_engine_equals_the_reference_on_logits_float32(gdn_impl):
+def test_engine_equals_the_reference_on_logits_float32(gdn_impl, monkeypatch):
     """float32 weights and compute. The engine and the reference differ by the
     order of float32 sums alone (chunks of 64 against token by token, paged
     attention against dense, sorted experts against masked ones): logits of
     scale 1 within 5e-5 (measured 2e-6), where a wrong state, conv input,
     position or expert moves them by tenths to whole units. ``interpret`` runs the
-    Pallas kernel ``dstpu_gdn_decode`` itself on the state pool."""
+    Pallas kernels ``dstpu_gdn_decode`` and ``dstpu_gdn_chunk`` themselves on
+    the state pool. Then two more prompts, the second arriving after the first
+    step: that step's TWO chunk rows are the first prompt's tail, continued from
+    its slot's state, and a fresh row; a step's ``gdn_chunk_tokens`` are the
+    live prompt tokens its chunk rows carried."""
+    from deepspeed_tpu.ops.linear_attention import delta_chunk
+
     cfg, params = _model()
     eng = _engine(cfg, params)
     eng._rec_impl = gdn_impl
+    traced, kernel = set(), delta_chunk.delta_chunk
+    monkeypatch.setattr(delta_chunk, "delta_chunk",
+                        lambda q, *a, **kw: traced.add(q.shape[:2]) or kernel(q, *a, **kw))
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in PROMPT_LENS]
+    more = [rng.integers(1, 256, size=n).astype(np.int32) for n in (200, 40)]
+    steps = []
     with jax.default_matmul_precision("highest"):
-        served = _serve_logits(eng, prompts, n_new=5)
+        served = _serve_logits(eng, prompts, n_new=5, steps=steps)
+        later = _serve_logits(eng, more, n_new=3, late=(1,), steps=steps)
+        # 160 fresh | 40 continued + 40 fresh | decode steps
+        assert [st.prefill_tokens for st in steps[-4:-1]] == [160, 80, 0]
+        assert steps[-3].grid_slots == 4 + 2 * 160
+        # (chunk rows, tq) of the programs that went through the chunk kernel
+        assert traced == ({(1, 128), (1, 160), (2, 160)} if gdn_impl == "interpret" else set())
+        for st in steps:
+            assert st.gdn_chunk_tokens == st.prefill_tokens
+            assert st.kda_chunk_tokens == st.mamba_chunk_tokens == 0
+        for uid, p in enumerate(more):
+            np.testing.assert_allclose(
+                later[uid], _reference_logits(params, HF, p, later[uid]), atol=5e-5, rtol=0)
         # every shape of the split step served it: no chunk row, one in
         # either bucket, two (which share the one bucket)
         assert set(eng._programs) == {
@@ -304,6 +336,9 @@ def test_state_slots_follow_admit_finish_cancel_and_expiry():
         assert driver.health()["state_slots_in_use"] == 0
         c = driver.metrics.counters
         assert c["gdn_decode_rows_total"] > 0
+        # the prompts' tokens, through ONE layer's chunk rule (the late one may never be admitted)
+        assert 8 + 20 <= c["gdn_chunk_tokens_total"] <= 8 + 20 + 10
+        assert c["kda_chunk_tokens_total"] == c["mamba_chunk_tokens_total"] == 0
         assert driver.metrics.gauges["state_slots_in_use"] == 0
         # this chip holds 4 of the layer's experts: fewer pairs than tokens x top-k x layers
         assert 0 < c["moe_routed_rows_total"] < c["scheduled_tokens_total"] * 3 * 8
