@@ -15,7 +15,7 @@ Aliasing is necessary, not sufficient: a step whose layer loop both reads
 the step-start pool and scatters into the carried pool aliases every donated
 buffer and still copies each pool twice (into a second buffer before the
 loop, back after it). ``check_pool_copies`` reads the COMPILED module of
-the split step, the fused decode round and the verify step and fails on any
+the split step and the verify step and fails on any
 ``copy`` the size of a KV pool or a scale plane.
 
 It also counts retraces: a fixed-shape entry point that traces more than
@@ -23,8 +23,8 @@ once across representative same-shape calls is quietly recompiling on the
 hot path (weak-typed scalars, python-hash-unstable statics, ...).
 
 Entry points covered (the compiled hot paths every perf PR leans on):
-  * ``engine_v2`` split step (a chunk bucket and the decode-only shape), fused
-    multistep decode, speculative verify step
+  * ``engine_v2`` split step (a chunk bucket and the decode-only shape),
+    speculative verify step
   * ``runtime.engine`` fused ZeRO-3 train step (bucketed-collective overlap)
   * ``runtime.streamed_adam`` per-leaf donated update
   * quantized-collective variants: TP decode through the int8 psum islands,
@@ -293,16 +293,19 @@ def check_recompile(name: str, jitted, max_traces: int = 1) -> CheckResult:
 # ---------------------------------------------------------------------------
 # entry-point harnesses (tiny models, CPU)
 # ---------------------------------------------------------------------------
-def _capture_builder(obj, attr: str, store: dict, key: str):
+def _capture_builder(obj, attr: str, store: dict, key):
     """Shadow a lazy jit-builder method on one instance so the first real
-    call records (compiled_fn, concrete_args) without changing behavior."""
+    call records (compiled_fn, concrete_args) without changing behavior.
+    ``key``: the name to record under, or a function of the builder's
+    arguments that gives it (one builder, a program a shape)."""
     orig = getattr(obj, attr)
 
     def build(*bargs, **bkw):
         fn = orig(*bargs, **bkw)
+        name = key(*bargs, **bkw) if callable(key) else key
 
         def call(*args):
-            store.setdefault(key, (fn, args))
+            store.setdefault(name, (fn, args))
             return fn(*args)
 
         return call
@@ -358,8 +361,14 @@ def _tiny_model_config(model: str = "dense"):
     return get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
 
 
-def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
-                    kv_extra: Optional[dict] = None, model: str = "dense"):
+def _split_program(shape) -> str:
+    """The name of the split step's program of ``shape`` in a capture: its
+    decode-only shape apart from those that carry a prompt chunk."""
+    return "decode_only_step" if shape == (0, 0) else "split_step"
+
+
+def _tiny_v2_engine(kv_dtype: str = "bf16", kv_extra: Optional[dict] = None,
+                    model: str = "dense"):
     import jax
 
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
@@ -367,15 +376,12 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
     from deepspeed_tpu.models import init_params
 
     cfg = _tiny_model_config(model)
-    if model in ("latent", "planes"):
-        decode_steps = 1  # a latent pool has no fused round
     params = init_params(cfg, jax.random.key(0))
     kv = {"block_size": 4, "num_blocks": 128, "max_blocks_per_seq": 32,
           "kv_cache_dtype": kv_dtype}
     kv.update(kv_extra or {})
     rc = RaggedInferenceEngineConfig.from_dict({
         "dtype": "float32",
-        "decode_steps": decode_steps,
         # two chunk rows a step at most, so the split step has a one-row shape
         # and a two-row shape beside its decode-only one
         "prompt_chunk": 128, "max_prompt_chunks": 2,
@@ -389,11 +395,11 @@ def _tiny_v2_engine(decode_steps: int = 2, kv_dtype: str = "bf16",
 
 def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     """The v2 serving programs of a tiny engine with a ``kv_dtype`` pool:
-    (engine, {name: (jitted, args)}). The split step (two chunk rows: the
-    passes' two prompts go in one step) and the fused decode round are
+    (engine, {name: (jitted, args)}). The split step's two-row shape (the
+    passes' two prompts go in one step) and its decode-only shape are
     captured from two same-shape ``generate()`` passes (pass 1 traces, pass 2
-    must hit the caches); the split step's decode-only shape and the verify
-    step are lowered directly with the inputs of an empty step, its one-row
+    must hit the caches); the verify step is lowered directly with the
+    inputs of an empty step, the split step's one-row
     shape with those of a step that holds one prompt (lowering reads shapes
     only, so passing the live pools is safe). Every program takes
     ``(params, inputs, rng, temperature,
@@ -402,15 +408,13 @@ def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
     the recurrent-state and conv pools, ``model="window"`` (window and global
     layers in one stack) the window pools; such models have no verify step
     (the engine refuses it); ``model="latent"`` (latent attention) has one
-    plane, ``model="planes"`` two a layer, and neither a verify step nor a
-    fused round."""
+    plane, ``model="planes"`` two a layer, and no verify step."""
     import jax.numpy as jnp
     import numpy as np
 
     cfg, eng = _tiny_v2_engine(kv_dtype=kv_dtype, model=model)
     programs: dict = {}
-    _capture_builder(eng, "_build_split_step", programs, "split_step")
-    _capture_builder(eng, "_build_multistep_decode", programs, "multistep_decode")
+    _capture_builder(eng, "_build_split_step", programs, _split_program)
     for seed in (0, 1):
         rng = np.random.default_rng(seed)
         eng.generate([rng.integers(1, cfg.vocab_size, size=(12,)).astype(np.int32)
@@ -425,14 +429,9 @@ def _engine_v2_programs(kv_dtype: str, model: str = "dense"):
             eng._pools(),
         )
 
-    # the split step of a batch with no chunk row (what every decode step of
-    # a served request runs at decode_steps 1): the generate() passes above
-    # decode in fused rounds, so it is staged here, with no row (the class's
-    # builder: the instance's is shadowed by the capture above)
-    (_, shape), inputs = eng._stage_split(0, [], [])
-    programs["decode_only_step"] = staged(type(eng)._build_split_step(eng, shape), inputs)
-    # ... and of a batch with ONE chunk row where the scheduler could have cut
-    # two: the grid follows the batch, so this is a program of its own
+    # the split step of a batch with ONE chunk row where the scheduler could
+    # have cut two: the grid follows the batch, so this is a program of its own
+    # (the class's builder: the instance's is shadowed by the capture above)
     eng.scheduler.submit(0, np.arange(1, 13, dtype=np.int32))
     batch = eng.scheduler.next_batch()
     (_, shape), inputs = eng._stage_split(batch.total_tokens, [], [
@@ -457,12 +456,11 @@ def _engine_v2_pass(kv_dtype: str, model: str = "dense") -> List[CheckResult]:
     tag = "".join(f"[{t}]" for t in (kv_dtype, model) if t not in ("bf16", "dense"))
     results: List[CheckResult] = []
     eng, programs = _engine_v2_programs(kv_dtype, model)
-    for key in ("split_step", "decode_only_step", "one_row_step", "multistep_decode",
-                "verify_step"):
+    for key in ("split_step", "decode_only_step", "one_row_step", "verify_step"):
         if key == "verify_step" and eng._beside:
             continue  # refused at build: a rejected draft would need the second cache rolled back
-        if key in ("verify_step", "multistep_decode") and eng._latent:
-            continue  # refused at build: neither has an absorbed form
+        if key == "verify_step" and eng._latent:
+            continue  # refused at build: it has no absorbed form
         label = f"engine_v2.{key}{tag}"
         if key not in programs:
             results.append(CheckResult(label, "donation", False,
@@ -477,14 +475,14 @@ def _engine_v2_pass(kv_dtype: str, model: str = "dense") -> List[CheckResult]:
             # finding and no news: the program compiled for a described v5e is
             # held to 0 pool-sized copies in tests/unit/ops/test_latent_attention.py)
             results.append(check_pool_copies(label, fn, args, eng._pools(), lowered=lowered))
-        if key in ("split_step", "multistep_decode"):  # the captured, live jits
+        if key in ("split_step", "decode_only_step"):  # the captured, live jits
             results.append(check_recompile(label, fn))
     return results
 
 
 def verify_engine_v2() -> List[CheckResult]:
     # both pool payload dtypes: int8 adds donated scale-plane leaves to
-    # every serving program (split, multistep, verify)
+    # every serving program (split, verify)
     # ... a model with DeltaNet layers the state pools, and one that mixes
     # window and global layers the window pools
     # (and one whose two pools differ in KV heads and whose planes in width)
@@ -690,7 +688,6 @@ def verify_quantized_comm() -> List[CheckResult]:
             "dtype": "float32",
             "tp_size": 2,
             "comm_quant": "int8",
-            "decode_steps": 2,
             "kv_cache": {"block_size": 4, "num_blocks": 128,
                          "max_blocks_per_seq": 32},
             "state_manager": {"max_tracked_sequences": 16,
@@ -700,9 +697,7 @@ def verify_quantized_comm() -> List[CheckResult]:
         })
         eng = InferenceEngineV2(cfg, params, rc)
         captured: dict = {}
-        _capture_builder(eng, "_build_split_step", captured, "split_step")
-        _capture_builder(eng, "_build_multistep_decode", captured,
-                         "multistep_decode")
+        _capture_builder(eng, "_build_split_step", captured, _split_program)
 
         def prompts(seed):
             rng = np.random.default_rng(seed)
@@ -719,7 +714,7 @@ def verify_quantized_comm() -> List[CheckResult]:
         eng.generate(prompts(2), max_new_tokens=6)
         for key, label in (
             ("split_step", "engine_v2.split_step[tp2+commq8]"),
-            ("multistep_decode", "engine_v2.multistep_decode[tp2+commq8]"),
+            ("decode_only_step", "engine_v2.decode_only_step[tp2+commq8]"),
         ):
             if key not in captured:
                 results.append(CheckResult(
@@ -825,7 +820,6 @@ def verify_tiled_overlap() -> List[CheckResult]:
             "tp_size": 2,
             "comm_overlap": "tiled",
             "tp_overlap_tiles": 2,
-            "decode_steps": 2,
             "kv_cache": {"block_size": 4, "num_blocks": 128,
                          "max_blocks_per_seq": 32},
             "state_manager": {"max_tracked_sequences": 16,
@@ -835,9 +829,7 @@ def verify_tiled_overlap() -> List[CheckResult]:
         })
         eng = InferenceEngineV2(cfg, params, rc)
         captured: dict = {}
-        _capture_builder(eng, "_build_split_step", captured, "split_step")
-        _capture_builder(eng, "_build_multistep_decode", captured,
-                         "multistep_decode")
+        _capture_builder(eng, "_build_split_step", captured, _split_program)
 
         def prompts(seed):
             rng = np.random.default_rng(seed)
@@ -848,7 +840,7 @@ def verify_tiled_overlap() -> List[CheckResult]:
         eng.generate(prompts(1), max_new_tokens=6)
         for key, label in (
             ("split_step", "engine_v2.split_step[tp2+tiled]"),
-            ("multistep_decode", "engine_v2.multistep_decode[tp2+tiled]"),
+            ("decode_only_step", "engine_v2.decode_only_step[tp2+tiled]"),
         ):
             if key not in captured:
                 results.append(CheckResult(
@@ -911,7 +903,7 @@ def verify_tiled_overlap() -> List[CheckResult]:
 def verify_disagg() -> List[CheckResult]:
     """Disaggregated serving: the Router's extracted scheduling loop must
     leave each engine's donated step programs intact. The prefill worker's
-    split step and the decode replicas' fused decode rounds both consume
+    split step and the decode replicas' decode steps both consume
     and reassign the donated KV pools, and the KV-handoff import path
     reassigns them too (``import_kv_blocks`` scatter) — a broken donation
     here would copy a full paged pool every step on every replica."""
@@ -921,14 +913,13 @@ def verify_disagg() -> List[CheckResult]:
     from deepspeed_tpu.serving.request import SamplingParams
 
     results: List[CheckResult] = []
-    engines = [_tiny_v2_engine(decode_steps=2)[1] for _ in range(3)]
+    engines = [_tiny_v2_engine()[1] for _ in range(3)]
     captured: dict = {}
     _capture_builder(engines[0], "_build_split_step", captured, "split")
     for eng in engines[1:]:
         # both replicas store under one key; setdefault keeps the first
-        _capture_builder(eng, "_build_multistep_decode", captured, "multistep")
-    router = Router(engines=engines, num_prefill_workers=1,
-                    decode_steps=2).start()
+        _capture_builder(eng, "_build_split_step", captured, "decode")
+    router = Router(engines=engines, num_prefill_workers=1).start()
     try:
         reqs = [
             router.submit(
@@ -943,7 +934,7 @@ def verify_disagg() -> List[CheckResult]:
     finally:
         router.shutdown()
     for key, label in (("split", "disagg.prefill_split_step"),
-                       ("multistep", "disagg.decode_multistep")):
+                       ("decode", "disagg.decode_step")):
         if key not in captured:
             results.append(CheckResult(label, "donation", False,
                                        "entry point never executed under the router"))
@@ -1068,7 +1059,6 @@ def verify_kv_transport() -> List[CheckResult]:
             rc = RaggedInferenceEngineConfig.from_dict({
                 "dtype": "float32",
                 "tp_size": 2,
-                "decode_steps": 2,
                 "kv_cache": {"block_size": 4, "num_blocks": 128,
                              "max_blocks_per_seq": 32,
                              "kv_cache_dtype": kv_dtype},
@@ -1107,7 +1097,7 @@ def verify_kv_transport() -> List[CheckResult]:
 def verify_elastic() -> List[CheckResult]:
     """Elastic serving: a warm spare's ``warm_trace`` must cover EVERY step
     program the serving loop drives, so post-warm serving traffic — prefill,
-    fused decode rounds, and the preempt-checkpoint resume import — runs
+    decode steps, and the preempt-checkpoint resume import — runs
     entirely inside the jit caches (zero admission-time compiles), and a
     preempted-then-resumed greedy stream must replay bit-identically to the
     uninterrupted one (content-addressed sampling + exact KV cursor
@@ -1127,10 +1117,9 @@ def verify_elastic() -> List[CheckResult]:
     # the spare's baseline holds every split shape: decode-only, and one
     # chunk row and two of them at its one bucket (prompt_chunk = 128)
     pool = WarmSparePool(
-        factory=lambda: _tiny_v2_engine(
-            decode_steps=2, kv_extra={"max_blocks_per_seq": 64})[1],
+        factory=lambda: _tiny_v2_engine(kv_extra={"max_blocks_per_seq": 64})[1],
         count=1,
-        warm_kw={"decode_steps": 2, "spec_k": 0},
+        warm_kw={"spec_k": 0},
     )
     eng, baseline = pool.acquire()
     sched = eng.scheduler
@@ -1142,9 +1131,12 @@ def verify_elastic() -> List[CheckResult]:
         if uid in out:
             tok = out[uid]
             break
+    def decode(n):
+        for _ in range(n):
+            sched.feedback(uid, eng.step_tokens()[uid])
+
     sched.feedback(uid, tok)
-    for _ in range(3):
-        eng.decode_round(2)
+    decode(6)
     label = "elastic.warm_spare"
     try:
         assert_no_new_traces(eng, baseline, label=label)
@@ -1173,8 +1165,7 @@ def verify_elastic() -> List[CheckResult]:
         else f"history/cursor drifted: {len(seq2.tokens)} tokens, "
              f"cursor {seq2.seen_tokens} (want {len(pre_tokens)} / "
              f"{len(pre_tokens) - 1})"))
-    for _ in range(2):
-        eng.decode_round(2)
+    decode(4)
     label = "elastic.resume_no_retrace"
     try:
         assert_no_new_traces(eng, baseline, label=label)

@@ -359,7 +359,7 @@ def tune_serving(max_experiments: int = 8, metric: str = "gen_tok_s",
     (reference ``autotuning_metric`` throughput mode, autotuner.py:42,
     applied to FastGen). Reuses the training tuner's subprocess scheduler —
     every candidate runs isolated so an OOM/compile crash is a data point,
-    not a tuner death. Space: fused-round length x prompt-chunk grid x
+    not a tuner death. Space: prompt-chunk grid x token budget x
     KV block geometry, seeded with the hand-picked bench config first
     (the tuner must FIND at least that).
 
@@ -369,18 +369,15 @@ def tune_serving(max_experiments: int = 8, metric: str = "gen_tok_s",
 
     default_space = [
         # hand-picked bench config first (PERF.md round-5 serving sweep)
-        {"decode_steps": 64, "prompt_chunk": 512, "max_prompt_chunks": 2},
-        {"decode_steps": 32, "prompt_chunk": 512, "max_prompt_chunks": 2},
-        {"decode_steps": 64, "prompt_chunk": 256, "max_prompt_chunks": 4},
-        {"decode_steps": 64, "prompt_chunk": 512, "max_prompt_chunks": 2,
-         "token_budget": 2048},
-        {"decode_steps": 64, "prompt_chunk": 512, "max_prompt_chunks": 2,
+        {"prompt_chunk": 512, "max_prompt_chunks": 2},
+        {"prompt_chunk": 256, "max_prompt_chunks": 4},
+        {"prompt_chunk": 512, "max_prompt_chunks": 2, "token_budget": 2048},
+        {"prompt_chunk": 512, "max_prompt_chunks": 2,
          "block_size": 256, "num_blocks": 256, "max_blocks_per_seq": 4},
-        {"decode_steps": 128, "prompt_chunk": 512, "max_prompt_chunks": 2,
-         "max_new": 128},
+        {"prompt_chunk": 512, "max_prompt_chunks": 2, "max_new": 128},
         # right-sized block table: the decode gather reads the WHOLE table,
         # so slots beyond the workload's max context are wasted HBM traffic
-        {"decode_steps": 64, "prompt_chunk": 256, "max_prompt_chunks": 4,
+        {"prompt_chunk": 256, "max_prompt_chunks": 4,
          "max_blocks_per_seq": 5, "max_context": 640},
     ]
     if space is None:

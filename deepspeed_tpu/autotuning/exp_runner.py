@@ -80,7 +80,6 @@ def run_serving(exp: dict) -> dict:
     params = init_params(cfg, jax.random.key(0))
     rc = RaggedInferenceEngineConfig.from_dict({
         "dtype": cfg.dtype,
-        "decode_steps": int(exp.get("decode_steps", 64)),
         "prompt_chunk": int(exp.get("prompt_chunk", 0)),
         "max_prompt_chunks": int(exp.get("max_prompt_chunks", 0)),
         "kv_cache": {

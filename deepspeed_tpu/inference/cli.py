@@ -108,7 +108,6 @@ def generate_main(argv=None) -> int:
             "comm_quant": getattr(args, "comm_quant", "none"),
         "comm_overlap": getattr(args, "comm_overlap", "none"),
         "tp_overlap_tiles": getattr(args, "tp_overlap_tiles", 4),
-            "decode_steps": min(32, args.max_new_tokens),
             "greedy": not args.sample, "temperature": args.temperature,
             "top_k": args.top_k, "top_p": args.top_p, "seed": args.seed,
             "kv_cache": {
@@ -219,8 +218,6 @@ def _serve_parser(prog, description):
                    help="fraction of KV blocks kept free at admission")
     p.add_argument("--timeout", type=float, default=None,
                    help="default per-request timeout in seconds")
-    p.add_argument("--decode-steps", type=int, default=1,
-                   help="fuse this many decode iterations per device call")
     p.add_argument("--spec-k", type=int, default=0,
                    help="speculative decoding: verify up to this many "
                    "n-gram-drafted tokens per sequence per step (0 = off; "
@@ -374,7 +371,6 @@ def engine_config_from_args(args, cfg):
         "comm_quant": getattr(args, "comm_quant", "none"),
         "comm_overlap": getattr(args, "comm_overlap", "none"),
         "tp_overlap_tiles": getattr(args, "tp_overlap_tiles", 4),
-        "decode_steps": args.decode_steps,
         "greedy": not args.sample, "temperature": args.temperature,
         "top_k": args.top_k, "top_p": args.top_p, "seed": args.seed,
         "spec_k": getattr(args, "spec_k", 0),
@@ -493,7 +489,6 @@ def build_serving_stack(args, cfg=None, params=None, tok=None):
             max_queue=args.max_queue,
             kv_headroom=args.kv_headroom,
             default_timeout_s=args.timeout,
-            decode_steps=args.decode_steps,
             spec_ngram=getattr(args, "spec_ngram", 3),
         )
         return driver, tok
@@ -509,8 +504,7 @@ def build_serving_stack(args, cfg=None, params=None, tok=None):
         spare_pool = WarmSparePool(
             factory=lambda: InferenceEngineV2(cfg, params, rc),
             count=max(0, elastic_cfg.max_decode_replicas - n_decode),
-            warm_kw={"decode_steps": args.decode_steps,
-                     "spec_k": int(getattr(args, "spec_k", 0) or 0)},
+            warm_kw={"spec_k": int(getattr(args, "spec_k", 0) or 0)},
         )
     router = Router(
         engines=engines,
@@ -519,7 +513,6 @@ def build_serving_stack(args, cfg=None, params=None, tok=None):
         max_queue=args.max_queue,
         kv_headroom=args.kv_headroom,
         default_timeout_s=args.timeout,
-        decode_steps=args.decode_steps,
         spec_ngram=getattr(args, "spec_ngram", 3),
         placement=getattr(args, "placement", "slo"),
         kv_transport=getattr(args, "kv_transport", "host"),
@@ -595,7 +588,7 @@ def build_agent_core(args, cfg=None, params=None, tok=None):
     engine = _serving_engine(cfg, params, rc)
     core = EngineCore(
         engine, name=args.name or "agent", role="decode",
-        decode_steps=args.decode_steps, kv_headroom=args.kv_headroom,
+        kv_headroom=args.kv_headroom,
         spec_k=int(getattr(args, "spec_k", 0) or 0),
         spec_ngram=getattr(args, "spec_ngram", 3),
         metrics=ServingMetrics(),

@@ -45,8 +45,9 @@ class DeepSpeedInferenceConfig(DSConfigModel):
     top_k: int = 0
     greedy: bool = True
     # > 1: generate() fuses this many decode iterations into one device
-    # program (sampled token fed back in-device) — same knob/rationale as
-    # the v2 engine's decode_steps; output-identical to per-step decoding
+    # program (sampled token fed back in-device), so the host round trip
+    # between two programs is paid once per decode_steps tokens;
+    # output-identical to per-step decoding
     decode_steps: int = 1
 
     @classmethod
@@ -112,13 +113,6 @@ class RaggedInferenceEngineConfig(DSConfigModel):
 
     dtype: str = "bfloat16"
     tp_size: int = 1
-    # > 1: generate() fuses this many greedy decode iterations into ONE
-    # device program (argmax fed back in-device) once all prompts are
-    # prefilled — the per-token host round trip (~120 ms on r05's host,
-    # ~0.6 ms on the v5e today per chip_smoke.py, PR 21; still the classic
-    # serving bottleneck) is paid once per decode_steps tokens. Trade-off:
-    # EOS hits mid-round waste the remaining iterations for that row.
-    decode_steps: int = 1
     # split-phase step grid (0 = derive from the token budget): each engine
     # step serves <= max_prompt_chunks prompt chunks of <= prompt_chunk
     # tokens alongside the full decode row set — the static-shape re-think

@@ -58,8 +58,8 @@ def row_keys(rng, uids, positions):
     """Content-addressed per-row sampling keys: fold each row's sequence
     uid and the GLOBAL position of its logits source into the base key.
     A token's key then depends only on (seed, uid, position) — never on
-    how the scheduler packed the batch, how a prompt was chunked, the
-    decode_steps partitioning, or whether a prefix-cache hit skipped part
+    how the scheduler packed the batch, how a prompt was chunked, which
+    step program sampled it, or whether a prefix-cache hit skipped part
     of prefill — so sampled streams are bit-identical across all of those
     execution choices."""
     with _partitionable_bits():

@@ -23,16 +23,17 @@ TPU adaptation:
     mask, or the Pallas paged kernel underneath (``paged_attention``);
   * a step is one compiled program over a fixed grid (the SplitFuse
     "fixed-shape friendly" re-think for compiled step functions): the split
-    step, the fused decode round or the speculative verify step.
+    step or the speculative verify step.
 
 Every step goes one way: ``_stage_<shape>`` (numpy only: the inputs by name,
 and the step's ``StepStats``) → ``_launch`` (the one call site of a step
 program; the pools go in and come back as one donated argument), both under
 ``_dispatch``, which returns the ``StepInFlight`` → ``_collect`` (host
 copies, wait, materialize). A served split step is the pair ``launch_step``
-/ ``collect_step``: the serving core launches step n+1 before it collects
-step n, and a decode row of n+1 takes the token step n sampled from the
-device (``last_tokens`` / ``tok_src``), never through the host.
+/ ``collect_step``: the serving core and ``generate()`` launch step n+1
+before they collect step n (``launch_ahead``), and a decode row of n+1 takes
+the token step n sampled from the device (``last_tokens`` / ``tok_src``),
+never through the host.
 """
 
 import dataclasses
@@ -58,25 +59,15 @@ from deepspeed_tpu.utils.timer import device_synchronize
 # (``time.monotonic``), under a name of its own so that a test can drive it
 _now = time.monotonic
 
-# step program of each cache key's kind: ("split", (rc, tq)) | ("round", n) | ("verify", k)
+# step program of each cache key's kind: ("split", (rc, tq)) | ("verify", k)
 _BUILDERS = {
     "split": "_build_split_step",
-    "round": "_build_multistep_decode",
     "verify": "_build_verify_step",
 }
 
 # a layer's attention projections, which an unrolled stack never slices out of
 # their stack (_static_layer)
 _READ_IN_PLACE = ("wq", "wk", "wv", "wo", "kda_qkv", "kda_out")
-
-
-def _entry_array(entry, want_tokens: bool):
-    """(device array, row index or None) of one step-result entry: the
-    in-program token array instead of logits when ``want_tokens`` and
-    present; plain arrays (test doubles) have no row."""
-    if isinstance(entry, tuple):
-        return (entry[2] if (want_tokens and len(entry) > 2) else entry[0]), entry[1]
-    return entry, None
 
 
 def _start_host_copies(arrays) -> None:
@@ -100,21 +91,18 @@ def _is_ready(arr) -> bool:
     return True if ready is None else bool(ready())
 
 
-def _materialize_rows(res: dict, want_tokens: bool = False) -> dict:
-    """{uid: (logits array, row[, token array])} -> {uid: host row}, pulling
-    each distinct ARRAY from the device exactly once (rows of one step share
-    their array). ``want_tokens``: take the in-program greedy-token array
-    instead of logits when present. Plain arrays (row=None) pass through for
-    test doubles."""
+def _materialize_rows(res: dict) -> dict:
+    """{uid: (logits array, row)} -> {uid: host row}, pulling each distinct
+    ARRAY from the device exactly once (rows of one step share their
+    array)."""
     hosts = {}
     out = {}
-    for uid, entry in res.items():
-        arr, idx = _entry_array(entry, want_tokens)
+    for uid, (arr, idx) in res.items():
         key = id(arr)
         if key not in hosts:
             # memoized by id(): each distinct device array transfers once
             hosts[key] = np.asarray(arr)  # dstpu: noqa[host-sync-in-loop]
-        out[uid] = hosts[key] if idx is None else hosts[key][idx]
+        out[uid] = hosts[key][idx]
     return out
 
 
@@ -125,12 +113,12 @@ RUN_LOOP = 5
 
 @dataclasses.dataclass
 class StepStats:
-    """What one step or round was sized to and what it carried, filled where
+    """What one step was sized to and what it carried, filled where
     the step is staged (``moe`` after its wait). The serving core folds it
     into grid_slots_total / scheduled_tokens_total / steps_with_prefill_total,
     paged_live_blocks_total / paged_table_slots_total / paged_programs_total,
     chunk_live_blocks_total
-    / chunk_table_slots_total, moe_*_total, gdn_*_total, mamba_*_total, kv_*_total,
+    / chunk_table_slots_total, moe_*_total, the recurrent kind's <kind>_*_total, kv_*_total,
     paged_window_live_blocks_total, latent_decode_rows_total /
     latent_decode_blocks_total / latent_live_blocks_total,
     moe_group_hit_tokens_total, steps_ahead_total,
@@ -139,10 +127,9 @@ class StepStats:
     steps they hold, and steps_starved_total.
 
     The kind is ``prefill_tokens``: a split step that carried a prompt chunk
-    is a CHUNK step, every other split step a DECODE step. A fused round and a
-    verify round are timed the same way and counted as decode steps, each of
-    its own program (a round is ``n`` tokens a row in one program: its seconds
-    are a round's, not a token's)."""
+    is a CHUNK step, every other split step a DECODE step. A verify step is
+    timed the same way and counted as a decode step (up to ``k + 1`` tokens a
+    row in one program: its seconds are a step's, not a token's)."""
 
     grid_slots: int = 0
     scheduled_tokens: int = 0
@@ -155,17 +142,12 @@ class StepStats:
     # layer calls, experts with a row) of the step's expert layers
     # (_count_moe); None for a dense model
     moe: Optional[dict] = None
-    # DeltaNet layers: rows whose state took the one-token update and the
-    # prompt tokens the chunk rule walked, of ONE layer (every such layer sees
-    # the same); 0 for a model without them
-    gdn_decode_rows: int = 0
-    gdn_chunk_tokens: int = 0
-    # Mamba layers: the same, and the prompt tokens the chunked scan walked
-    mamba_decode_rows: int = 0
-    mamba_chunk_tokens: int = 0
-    # Kimi Delta Attention layers: the same pair
-    kda_decode_rows: int = 0
-    kda_chunk_tokens: int = 0
+    # recurrent layers (the stack's ``recurrent_kind``, a key of T.RECURRENT):
+    # rows whose state took the one-token update and the prompt tokens the
+    # chunk rule walked, of ONE layer (every such layer sees the same); 0 for
+    # a model without them
+    recurrent_decode_rows: int = 0
+    recurrent_chunk_tokens: int = 0
     # chunk attention: key blocks the chunk rows hold (pool blocks below the
     # chunk's start + the chunk's own) against the slots a walk of whole
     # tables and whole chunks covers, of one layer (_count_chunk)
@@ -210,7 +192,7 @@ class StepStats:
 
 @dataclasses.dataclass
 class StepInFlight:
-    """A launched step or round, not yet waited for: everything that is the
+    """A launched step, not yet waited for: everything that is the
     STEP's and not the engine's, so that the next step may be launched before
     this one is collected. ``waited``: the program's outputs the results come
     from (never the engine's pools: by collect time those are the next
@@ -546,7 +528,7 @@ class InferenceEngineV2:
         self.last_capped = set()
         # sampling state: one base key; programs fold in each row's (uid,
         # source position) so a token's key is content-addressed — invariant
-        # to batch packing, prompt chunking, fused-round partitioning, and
+        # to batch packing, prompt chunking and
         # prefix-cache hits (sampling.row_keys)
         self._rng = jax.random.key(int(getattr(self.config, "seed", 0) or 0))
         self.last_logprobs: Dict[int, np.ndarray] = {}
@@ -624,8 +606,6 @@ class InferenceEngineV2:
                     "readmitted as K and V planes")
         elif int(getattr(self.config, "spec_k", 0) or 0) > 0:
             what = "speculative decoding (spec_k): the verify step has no absorbed form"
-        elif int(getattr(self.config, "decode_steps", 1) or 1) > 1:
-            what = "decode_steps > 1: the fused decode round has no absorbed form"
         if what is not None:
             raise NotImplementedError(
                 "v2 paged engine: a latent-attention model (one latent vector a token in "
@@ -1127,7 +1107,7 @@ class InferenceEngineV2:
         (``inference/cli.py``), so no prompt, alone or beside another,
         compiles anything in the middle of its TTFT. ``while_running(uid)``
         runs once, while the first sequence is running with a token fed back
-        (warm_trace's rounds). The throwaway sequences are finished and
+        (warm_trace's verify step). The throwaway sequences are finished and
         scrubbed from the prefix trie afterwards, and sampling keys are
         content-addressed — warming never perturbs later streams. Call AFTER
         the final ``set_sampling`` (it invalidates the programs)."""
@@ -1189,27 +1169,24 @@ class InferenceEngineV2:
             if cache is not None:
                 cache.spill_fn = spill
 
-    def warm_trace(self, decode_steps: int = 1, spec_k: int = 0,
-                   uid: int = (1 << 30) + 7) -> Dict[str, int]:
+    def warm_trace(self, spec_k: int = 0, uid: int = (1 << 30) + 7) -> Dict[str, int]:
         """Pre-trace every step program the serving loop will drive, so a
         warm-spare engine admits requests with ZERO admission-time
         compiles: the split-phase step at every shape (decode-only, one
         chunk row in the 128 bucket, and each count of chunk rows in the
-        ``prompt_chunk`` bucket: warm_split_shapes), the fused decode round
-        at ``decode_steps``, the speculative verify step at ``spec_k``, and
+        ``prompt_chunk`` bucket: warm_split_shapes), the speculative verify
+        step at ``spec_k``, and
         the fixed-window chunked re-import scatter (preemption resume /
         host-tier readmit). Returns the post-warm ``trace_signature`` (the
         baseline scale-up asserts against). Call BEFORE serving and AFTER
         the final ``set_sampling`` (sampling knobs shape the programs and
         invalidate these caches)."""
 
-        def rounds(wuid):
-            if decode_steps > 1:
-                self.decode_round(int(decode_steps))
+        def verify(wuid):
             if spec_k > 0:
                 self.spec_round(int(spec_k), drafts={wuid: [1] * int(spec_k)})
 
-        self.warm_split_shapes(uid, while_running=rounds)
+        self.warm_split_shapes(uid, while_running=verify)
         # the fixed-window re-import scatter (resume/readmit path): one
         # chunk+1-block round trip traces the padded-tail window shape
         kv = self.config.kv_cache
@@ -1306,12 +1283,6 @@ class InferenceEngineV2:
         B, wb = self.config.kv_cache.max_blocks_per_seq, self._win_blocks
         return slots[:, None] * wb + (jnp.arange(B, dtype=jnp.int32) % wb)[None]
 
-    def _ring_blocks(self, slots, positions, keep):
-        """Ring block of each token at ``positions`` of rows at ``slots`` (same
-        shape), the spare slot's where ``keep`` is false."""
-        bs, wb = self.config.kv_cache.block_size, self._win_blocks
-        return jnp.where(keep, slots * wb + (positions // bs) % wb, (self._state_slots - 1) * wb)
-
     def _kv_source(self, meta, li, tables: str):
         """(K view, V view, layer-offset tables, layer-offset trash block) of
         what layer ``li``'s attention reads: the block pool under
@@ -1394,8 +1365,7 @@ class InferenceEngineV2:
         IN-program (sampled or greedy per the static config knobs). Keys are
         per-row, content-addressed on (uid, logits-source position)
         (sampling.row_keys): the token for a given position is the same
-        whether the split step, a fused round under any ``decode_steps`` or
-        a verify step produced it, whatever the batch packing, prompt
+        whether the split step or a verify step produced it, whatever the batch packing, prompt
         chunking and prefix-cache state. Returns (fp32 logits [n, vocab],
         what ``sample_tokens`` returns: tokens, or (tokens, logprobs))."""
         from deepspeed_tpu.inference.sampling import row_keys, sample_tokens
@@ -1452,7 +1422,7 @@ class InferenceEngineV2:
         holds nothing is a program that reads nothing, and is not counted:
         live blocks over programs is 1 where ``G`` is, and ``G`` where every
         group is full); ``calls`` kernel calls a layer walk the same rows (a
-        fused round's steps, a verify round's K1 queries a row)."""
+        verify step's K1 queries a row)."""
         kv = self.config.kv_cache
         held = np.maximum(np.asarray(pool_tokens, np.int64), 0)
         blocks = -(-held // kv.block_size)
@@ -1461,14 +1431,14 @@ class InferenceEngineV2:
                 "paged_table_slots": calls * len(held) * kv.max_blocks_per_seq,
                 "paged_programs": calls * int((-(-blocks // G)).sum()) if G else 0}
 
-    def _count_cache(self, dec_pos, new_tokens: int, calls: int = 1):
+    def _count_cache(self, dec_pos, new_tokens: int):
         """The cache as the step finds it, as ``StepStats``' kv_global_blocks
         (blocks of the block pool held), kv_window_blocks (ring blocks held: a
         tracked sequence's ``wb``), kv_context_tokens (tokens the tracked
         sequences hold, this step's ``new_tokens`` included) and paged_window_live_blocks:
         the blocks ONE window layer's decode walks visit, for a row at
         position p those of keys p - window + 1 .. p - 1 (``dec_pos`` [R], < 0
-        an inactive slot; ``calls`` walks a row). Host arithmetic over the
+        an inactive slot). Host arithmetic over the
         tracked sequences, inside ``engine.stage``."""
         sm, kv = self.state_manager, self.config.kv_cache
         bs = kv.block_size
@@ -1479,7 +1449,7 @@ class InferenceEngineV2:
             lo = np.maximum(p - self._mc.sliding_window + 1, 0) // bs
             walks = np.where(p > 0, (p - 1) // bs - lo + 1, 0)
             out.update(kv_window_blocks=sm.state_slots_in_use * self._win_blocks,
-                       paged_window_live_blocks=calls * int(walks.sum()))
+                       paged_window_live_blocks=int(walks.sum()))
         return out
 
     def _chunk_bucket(self, rows: int, longest: int) -> int:
@@ -1508,9 +1478,9 @@ class InferenceEngineV2:
         slots = len(chk_rows) * (kv.max_blocks_per_seq + -(-tq // bs))
         return {"chunk_live_blocks": live, "chunk_table_slots": slots}
 
-    def _side_buffers(self, *token_dims):
+    def _side_buffers(self, n_tokens: int):
         """What a step's layer loop carries, by name: ``k`` / ``v``, a zeroed
-        pair [L, *token_dims, nkv, d] in compute dtype in place of the K/V
+        pair [L, n_tokens, nkv, d] in compute dtype in place of the K/V
         pools (L the block pool's layers), and for a mixed stack ``wk`` / ``wv``
         for its window pool's layers, each pair at ITS pool's geometry (KV
         heads; a value as wide as the V plane). Each such layer records its new
@@ -1523,15 +1493,14 @@ class InferenceEngineV2:
         the in-place update of a layer go through the carried value."""
         c = self._mc
         if self._latent:
-            # one vector a token: [L, *token_dims, latent_dim] under "k" alone
-            carry = {"k": jnp.zeros((c.kv_layers,) + tuple(token_dims) + (c.latent_dim,),
-                                    T.DTYPES[c.dtype])}
+            # one vector a token: [L, n_tokens, latent_dim] under "k" alone
+            carry = {"k": jnp.zeros((c.kv_layers, n_tokens, c.latent_dim), T.DTYPES[c.dtype])}
             if c.n_experts > 0:
                 carry["moe"] = jnp.zeros((c.n_layers, self._moe_width), jnp.int32)
             return carry
         dt = T.DTYPES[c.dtype]
         nkv, dk, dv = self._geom["block"]
-        shape = (c.kv_layers,) + tuple(token_dims) + (nkv, dk)
+        shape = (c.kv_layers, n_tokens, nkv, dk)
         side = jnp.zeros(shape, dt)
         if self._mesh is not None:
             from jax.sharding import NamedSharding
@@ -1539,13 +1508,13 @@ class InferenceEngineV2:
 
             from deepspeed_tpu.parallel.topology import MODEL_AXIS
 
-            spec = P(*([None] * (len(shape) - 2)), MODEL_AXIS, None)
+            spec = P(None, None, MODEL_AXIS, None)
             side = jax.lax.with_sharding_constraint(side, NamedSharding(self._mesh, spec))
         carry = {"k": side, "v": side if dv == dk else jnp.zeros(shape[:-1] + (dv,), dt)}
         if self._windowed:
             # the window pool's layers, at its geometry
             nkv, dk, dv = self._geom["window"]
-            lead = (c.window_layers,) + tuple(token_dims)
+            lead = (c.window_layers, n_tokens)
             carry.update(wk=jnp.zeros(lead + (nkv, dk), dt), wv=jnp.zeros(lead + (nkv, dv), dt))
         if c.n_experts > 0:
             # an expert model's loop also carries what each layer routed:
@@ -1636,7 +1605,7 @@ class InferenceEngineV2:
             for pool, a in zip(caches, new)
         )
 
-    def _write_back(self, pools, second, blk, row, side, wblk=None, x=None, visits=None):
+    def _write_back(self, pools, second, blk, row, side, wblk, x, visits=None):
         """Every pool of a step's ``pools`` argument as the program returns
         it: the block pools written from the side buffers (_scatter_kv), then
         a mixed stack's window pools from their layers' part of the same
@@ -1646,8 +1615,9 @@ class InferenceEngineV2:
         or a DeltaNet model's state pools as the carry holds them; a latent
         model's one plane through ``latent_write`` (``visits``: its programs,
         staged on the host). ``x``: the
-        stream an UNROLLED stack left (the split step of a mixed stack), which
-        orders the write behind the last layer and changes nothing of it."""
+        stream the stack left, which orders a window pool's and a latent
+        plane's write behind an UNROLLED stack's last layer and changes nothing
+        of it."""
         if self._latent:
             from deepspeed_tpu.ops.attention.latent_pallas import latent_write
 
@@ -1656,31 +1626,29 @@ class InferenceEngineV2:
             # twice), scattered elsewhere. An unrolled stack (the dense lead
             # layer) hands on its last layer's vectors before that layer has
             # read the pool: the write waits for the stream as below
-            if x is not None:
-                p = x[0, 0, 0]
-                never = (p != p) & (p == p)
-                blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
-                visits = visits and (visits[0], visits[1], jnp.where(never, 0, visits[2]))
+            p = x[0, 0, 0]
+            never = (p != p) & (p == p)
+            blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
+            visits = visits and (visits[0], visits[1], jnp.where(never, 0, visits[2]))
             return (latent_write(pools[0], side["k"], blk, row, visits, impl=self._attn_impl),
                     ) + self._state_of(side)
         if not self._windowed:
             return self._scatter_kv(pools, blk, row, (side["k"], side["v"])) + self._state_of(side)
-        if x is not None:
-            # A loop ends before the pool write that follows it. An unrolled
-            # stack's last layer hands on its new K/V before its attention has
-            # read the pool, so the write may come first, and XLA keeps the
-            # two apart by copying every pool that layer reads, twice a step
-            # (check_pool_copies; an optimization_barrier does not survive to
-            # where that is decided, and a loop of one pass hoists every
-            # layer's weight slices out as copies). So the write's indices are
-            # made to wait for the stream the last layer left, through a
-            # predicate that is False whatever the stream holds (a NaN is
-            # unequal to itself, and then not equal either): every token's
-            # K/V lands where it belongs, and the compiler cannot know it.
-            p = x[0, 0, 0]
-            never = (p != p) & (p == p)
-            blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
-            wblk = jnp.where(never, (self._state_slots - 1) * self._win_blocks, wblk)
+        # A loop ends before the pool write that follows it. An unrolled
+        # stack's last layer hands on its new K/V before its attention has
+        # read the pool, so the write may come first, and XLA keeps the
+        # two apart by copying every pool that layer reads, twice a step
+        # (check_pool_copies; an optimization_barrier does not survive to
+        # where that is decided, and a loop of one pass hoists every
+        # layer's weight slices out as copies). So the write's indices are
+        # made to wait for the stream the last layer left, through a
+        # predicate that is False whatever the stream holds (a NaN is
+        # unequal to itself, and then not equal either): every token's
+        # K/V lands where it belongs, and the compiler cannot know it.
+        p = x[0, 0, 0]
+        never = (p != p) & (p == p)
+        blk = jnp.where(never, self.config.kv_cache.num_blocks, blk)
+        wblk = jnp.where(never, (self._state_slots - 1) * self._win_blocks, wblk)
         return (self._scatter_kv(pools, blk, row, (side["k"], side["v"]))
                 + self._scatter_kv(second, wblk, row, (side["wk"], side["wv"])))
 
@@ -1840,7 +1808,7 @@ class InferenceEngineV2:
     def _layer_qkv(self, lp, x, positions, live, window=None):
         """Shared per-layer prologue for the serving step bodies: pre-norm →
         QKV projections (+ biases) → qk-norm → rope. One definition so the
-        split step and the fused round cannot drift on arch features
+        split step and the verify step cannot drift on arch features
         (qk_layernorm, biases, rope scaling). lp must be pre-dequantized.
         ``window``: the layer's (static) window, which says whether a
         ``rope_window_only`` model's layer rotates at all. Returns (a, q, k,
@@ -2013,7 +1981,7 @@ class InferenceEngineV2:
         conv = conv.at[slots].set(new.reshape(R, -1))
         o, state = kind.decode(c, lp, y[:, 0], tuple(e[:R] for e in extras), live, state, slots,
                                self._rec_impl)
-        if rows.get("tq"):
+        if rows["tq"]:
             Rc, tq = rows["Rc"], rows["tq"]
             slots = base + rows["chk_slots"]
             tok_live = rows["chk_pos"] >= 0                              # [Rc, tq]
@@ -2180,8 +2148,8 @@ class InferenceEngineV2:
         on the grid), ``tq`` 128 or ``prompt_chunk`` (the chunk-length
         buckets: _chunk_bucket), and (0, 0) for a batch with no chunk row:
         the grid is the R decode slots, the program takes no ``chk_*`` input
-        and runs no chunk attention (the fused round's and the verify step's
-        grids are R rows wide already). Outputs: (decode logits [R, vocab],
+        and runs no chunk attention (the verify step's grid is R rows wide
+        already). Outputs: (decode logits [R, vocab],
         chunk logits [Rc, vocab], decode tokens [R], chunk tokens [Rc],
         ``last_tokens`` [R + max_prompt_chunks]); the chunk pair is None at
         ``tq == 0``, where no row reads it.
@@ -2240,8 +2208,6 @@ class InferenceEngineV2:
             pools = self._write_back(
                 pools, second, inputs["blk"], inputs["row"], side, inputs.get("wblk"), x,
                 visits=visits)
-            # generate() holds only the token arrays across its prefill
-            # phase and drops the logits
             logits_dec, toks_dec = self._sample_rows(
                 params, x, slice(0, R), rng, temperature, inputs["dec_uids"], dec_pos)
             logits_chk = toks_chk = None
@@ -2259,140 +2225,6 @@ class InferenceEngineV2:
                     self._moe_rows(side))
 
         return jax.jit(step, donate_argnums=(4,))
-
-    def _round_layer(self, lp, x, li, meta, carry, window=None):
-        """One layer of one step of a fused decode ROUND: queries are the
-        round's step-``s`` tokens (one per row); context = the ROUND-START
-        pool (read-only all round) + the round's earlier tokens from the
-        carried side buffers [L, R, n, nkv, d]. The side buffers are the
-        round's only read-write surface; the pool is written from them
-        once, after the last step."""
-        c = self._mc
-        w = c.sliding_window if window is None else window
-        lp = T._dequant_tree(lp, T.DTYPES[c.dtype])
-        if self._hybrid and "wo" not in lp:  # a recurrent layer: the carried state, updated in place
-            return self._recurrent_layer(lp, x, li, {
-                "R": x.shape[1], "slots": meta["slots"], "live": meta["active"],
-                "slot_live": meta["active"]}, carry)
-        kn, vn, li_kv = self._side_names(li)
-        side_k, side_v = carry[kn], carry[vn]
-        a, q, k, v = self._layer_qkv(lp, x, meta["pos"], meta["live"], w)
-        # record this step's K/V in the side buffer BEFORE attention (the
-        # query sees itself through the extra columns)
-        side_k = jax.lax.dynamic_update_slice(
-            side_k, k[None, :, None], (li_kv, 0, meta["s"], 0, 0)
-        )
-        side_v = jax.lax.dynamic_update_slice(
-            side_v, v[None, :, None], (li_kv, 0, meta["s"], 0, 0)
-        )
-        sk = jax.lax.dynamic_index_in_dim(side_k, li_kv, 0, keepdims=False)
-        sv = jax.lax.dynamic_index_in_dim(side_v, li_kv, 0, keepdims=False)
-        k_pool, v_pool, tables_l, trash_l = self._kv_source(meta, li, "tables")
-        out = self._attn_decode(
-            q, k_pool, v_pool, tables_l, meta["pos"], w, trash_l,
-            extra_kv=(sk, sv, meta["epos"]),
-            pool_limit=meta["pos0"],
-            k_scale=meta["ks_pool0"], v_scale=meta["vs_pool0"],
-            sinks=lp.get("sink"),
-        )
-        x, moe = self._layer_tail(lp, x, out, meta["active"], li, a)
-        return x, self._record_moe(dict(carry, **{kn: side_k, vn: side_v}), li, moe)
-
-    def _build_multistep_decode(self, n_steps: int):
-        """``n_steps`` decode iterations in ONE device program, each token
-        fed back in-device (reference FastGen keeps sampling on-device for
-        the same reason): what the host spends between two programs (the
-        launch 4.2 ms, the whole gap 5.7-7.4 ms a step: ledger, PR 26) is
-        paid once per ``n_steps`` tokens.
-
-        Every row is one running sequence (R = max_ragged_sequence_count;
-        inactive rows carry an all-trash block table and position 0, so
-        their context masks to nothing and their tokens freeze). Block
-        capacity for ``n_steps`` tokens per row must be allocated by the
-        caller BEFORE the call (decode_round does). Context protocol: the
-        pool is read at its ROUND-START state and is no part of any loop's
-        carry; the round's own tokens ride in side buffers (see
-        _round_layer), and one write-back after the last step puts all
-        ``n_steps`` tokens of every layer into the donated pools. Outputs:
-        (tokens [n_steps, R], logprobs [n_steps, R]); an expert model's
-        routed rows are [n_steps, L, E]. A DeltaNet model's recurrent state
-        needs no side buffer: it is the scan's carry."""
-        kv = self.config.kv_cache
-        bs = kv.block_size
-        B = kv.max_blocks_per_seq
-        trash = kv.num_blocks
-        R = self.config.state_manager.max_ragged_sequence_count
-        if self._windowed and n_steps > bs:
-            raise ValueError(
-                f"decode_steps={n_steps} over a window pool of {bs}-token blocks: a round writes "
-                "its tokens after its last step, at most a block of them a row")
-
-        def fused(params, inputs, rng, temperature, pools):
-            tokens, uids, active = inputs["tokens"], inputs["uids"], inputs["active"]
-            tok_tables = jnp.where(active[:, None], inputs["tables"], trash)
-            pos0 = inputs["positions"]  # round-start positions (pool validity limit)
-            j_idx = jnp.arange(n_steps, dtype=jnp.int32)
-            # round-start pool views: read-only for the whole round (the
-            # in-round tokens come from the side buffers); a DeltaNet
-            # model's state pools ride the scan's carry, each step's
-            # update made in place
-            pools, second = self._split_pools(pools)
-            views = self._cache_views(pools, second)
-            if self._windowed:
-                views["win_tables"] = self._ring_tables(inputs["slots"])
-
-            def one_token(params, toks, pos, s, side):
-                x = self._embed(params, toks, pos)
-                # side slots 0..s are valid for active rows; -1 masks the rest
-                epos = jnp.where(
-                    (j_idx[None] <= s) & active[:, None],
-                    pos0[:, None] + j_idx[None], -1,
-                )
-                meta = {
-                    "tables": tok_tables, "pos": pos, "active": active,
-                    "slots": inputs.get("slots"),
-                    # inactive rows: pos0 == 0 -> pool masks to nothing
-                    "pos0": jnp.where(active, pos0, 0),
-                    "s": s, "epos": epos, **views,
-                    # inactive rows carry position 0: exclude them from the
-                    # rope live-length switch
-                    "live": jnp.max(jnp.where(active, pos, 0)) + 1,
-                }
-
-                def layer_fn(lp, x, li, carry, window=None):
-                    return self._round_layer(lp, x, li, meta, carry, window=window)
-
-                x, side = self._drive_layers(layer_fn, params, x, side)
-                _, (nxt, logp) = self._sample_rows(
-                    params, x, slice(None), rng, temperature, uids,
-                    jnp.where(active, pos, -1), return_logprobs=True)
-                return nxt, logp, side
-
-            def step_fn(carry, s):
-                toks, pos, side = carry
-                nxt, logp, side = one_token(params, toks, pos, s, side)
-                nxt = jnp.where(active, nxt, toks)  # inactive rows freeze
-                return (nxt, pos + active.astype(jnp.int32), side), (nxt, logp, self._moe_rows(side))
-
-            (_, _, side), (toks_out, logps_out, moe) = jax.lax.scan(
-                step_fn,
-                (tokens, pos0, self._with_state(self._side_buffers(R, n_steps), second)),
-                j_idx,
-            )
-            # the round's write-back: step s of row r sits at position
-            # pos0 + s (inactive rows never advance and name the trash block)
-            pos_all = pos0[:, None] + j_idx[None] * active[:, None]  # [R, n_steps]
-            blk = jnp.take_along_axis(tok_tables, jnp.clip(pos_all // bs, 0, B - 1), axis=1)
-            wblk = None
-            if self._windowed:  # n_steps <= a ring's tokens: no ring slot twice
-                wblk = self._ring_blocks(
-                    inputs["slots"][:, None], pos_all, active[:, None]).reshape(R * n_steps)
-            pools = self._write_back(
-                pools, second, blk.reshape(R * n_steps), (pos_all % bs).reshape(R * n_steps),
-                side, wblk)
-            return (toks_out, logps_out), pools, moe
-
-        return jax.jit(fused, donate_argnums=(4,))
 
     # ------------------------------------------------------------------
     def _build_verify_step(self, k: int):
@@ -2608,7 +2440,10 @@ class InferenceEngineV2:
         prefill = sum(len(t) for _, t, _, _ in chk_rows)
         self.last_step = StepStats(
             T_, total_tokens, prefill, **self._count_paged(dec_pos),
-            **self._count_recurrent(len(dec_rows), prefill),
+            # of ONE recurrent layer: the rows its one-token update took, the
+            # prompt tokens its chunk rule walked
+            **({"recurrent_decode_rows": len(dec_rows), "recurrent_chunk_tokens": prefill}
+               if self._hybrid else {}),
             **self._count_chunk(chk_rows, tq), **self._count_cache(dec_pos, total_tokens),
         )
         if self._latent:
@@ -2644,61 +2479,30 @@ class InferenceEngineV2:
                 blk, trash, R + Rc * (tq // bs + tq // WRITE_TILE + 3))
         return ("split", (Rc, tq)), inputs
 
-    def _stage_rows(self, uids, width: int):
-        """What the fused round and the verify step share: one row a running
-        sequence of ``uids``, [R]-shaped (tokens [R, width]: a row's pending
-        token first), inactive rows all-trash at position 0."""
+    def _stage_verify(self, uids, row_drafts, k: int):
+        """A verify step of up to ``k`` drafts a row (``row_drafts`` beside
+        ``uids``): cache key and inputs by name, one row a running sequence
+        of ``uids``, [R]-shaped (tokens [R, k + 1]: a row's pending token, then
+        its drafts), inactive rows all-trash at position 0."""
         kv = self.config.kv_cache
         R = self.config.state_manager.max_ragged_sequence_count
         inputs = {
-            "tokens": np.zeros((R, width), np.int32),
+            "tokens": np.zeros((R, k + 1), np.int32),
             "positions": np.zeros(R, np.int32),
             "tables": np.full((R, kv.max_blocks_per_seq), kv.num_blocks, np.int32),
             "uids": np.zeros(R, np.int32),
             "active": np.zeros(R, bool),
+            "n_input": np.ones(R, np.int32),
         }
-        if self._beside:
-            inputs["slots"] = np.full(R, self._state_slots - 1, np.int32)
-        for i, uid in enumerate(uids):
+        for i, (uid, d) in enumerate(zip(uids, row_drafts)):
             seq = self.state_manager.get_sequence(uid)
-            if self._beside:
-                inputs["slots"][i] = seq.state_slot
             inputs["tokens"][i, 0] = self.scheduler.peek_next_token(uid)
+            inputs["tokens"][i, 1 : 1 + len(d)] = d
+            inputs["n_input"][i] = 1 + len(d)
             inputs["positions"][i] = seq.seen_tokens
             inputs["tables"][i, : len(seq.block_table)] = seq.block_table
             inputs["uids"][i] = uid
             inputs["active"][i] = True
-        return R, inputs
-
-    def _count_recurrent(self, decode_rows: int, chunk_tokens: int):
-        """StepStats' fields of the stack's recurrent kind: the rows whose
-        states took the one-token update and the prompt tokens its chunk rule
-        walked, of ONE such layer."""
-        kind = self._mc.recurrent_kind
-        if not kind:
-            return {}
-        return {f"{kind}_decode_rows": decode_rows, f"{kind}_chunk_tokens": chunk_tokens}
-
-    def _stage_round(self, uids, n: int):
-        """A fused decode round of ``n`` steps over ``uids``: cache key and
-        inputs by name."""
-        R, inputs = self._stage_rows(uids, 1)
-        inputs["tokens"] = inputs["tokens"][:, 0]
-        self.last_step = StepStats(
-            R * n, len(uids) * n, 0, **self._count_paged(inputs["positions"], calls=n),
-            **self._count_recurrent(len(uids) * n, 0),
-            **self._count_cache(
-                np.where(inputs["active"], inputs["positions"], -1), len(uids) * n, calls=n))
-        return ("round", n), inputs
-
-    def _stage_verify(self, uids, row_drafts, k: int):
-        """A verify step of up to ``k`` drafts a row (``row_drafts`` beside
-        ``uids``): cache key and inputs by name."""
-        R, inputs = self._stage_rows(uids, k + 1)
-        inputs["n_input"] = np.ones(R, np.int32)
-        for i, d in enumerate(row_drafts):
-            inputs["tokens"][i, 1 : 1 + len(d)] = d
-            inputs["n_input"][i] = 1 + len(d)
         self.last_step = StepStats(
             R * (k + 1), len(uids) + sum(len(d) for d in row_drafts), 0,
             **self._count_paged(inputs["positions"], calls=k + 1),
@@ -2828,14 +2632,9 @@ class InferenceEngineV2:
             self._count_moe(flight)
             return flight.finish()
 
-    def _dispatch_and_collect(self, dispatch):
-        """A step that is waited for where it is launched: the fused round,
-        the verify step, and ``step_tokens``."""
-        return self._collect(self._dispatch(dispatch))
-
     def _count_moe(self, flight: StepInFlight) -> None:
-        """After the wait: reduce the step's routed rows ([L, E], or a fused
-        round's [n_steps, L, E]) to its ``stats.moe``. One layer call a row
+        """After the wait: reduce the step's routed rows [L, E] to its
+        ``stats.moe``. One layer call a row
         of E: rows routed, rows the dispatch computed (the grouped kernel:
         its tile size for every tile visit; the capacity dispatch: E x
         capacity), the fullest expert's rows and the experts that had a row.
@@ -2846,13 +2645,12 @@ class InferenceEngineV2:
         from deepspeed_tpu.parallel.moe import grouped, sharded_moe
 
         c = self._mc
-        rows = np.asarray(pending)[..., c.moe_dense_lead:, :]  # the layers that have experts
-        beside = rows[..., c.n_experts:].sum()  # the entry behind the experts', where there is one
+        rows = np.asarray(pending)[c.moe_dense_lead:]  # the layers that have experts
+        beside = rows[:, c.n_experts:].sum()  # the entry behind the experts', where there is one
         group_hit = beside if c.moe_n_group > 1 else None
-        counts = rows[..., : c.n_experts].reshape(-1, c.n_experts)
-        # tokens of one layer call: the grid, a step of it for a fused round
-        steps = rows.shape[0] if rows.ndim == 3 else 1
-        pairs = flight.stats.grid_slots // steps * c.moe_top_k
+        counts = rows[:, : c.n_experts]
+        # tokens of one layer call: the grid
+        pairs = flight.stats.grid_slots * c.moe_top_k
         if c.moe_drop_tokens:
             computed = counts.shape[0] * c.n_experts * sharded_moe._capacity(
                 pairs, c.n_experts, c.moe_capacity_factor)
@@ -2877,60 +2675,6 @@ class InferenceEngineV2:
                 group_hit=int(group_hit), group_tokens=flight.stats.scheduled_tokens * counts.shape[0])
 
     # -- entry points --------------------------------------------------------
-    def decode_round(self, n_steps: Optional[int] = None) -> Dict[int, np.ndarray]:
-        """One fused decode round: ``n_steps`` tokens for every eligible
-        RUNNING sequence in a single device call. Only legal when no
-        prompt chunks are pending (prefill through step()/put() first).
-        Returns {uid: [n_steps] generated tokens}; the caller truncates at
-        EOS and calls scheduler.finish for completed sequences.
-
-        Sequences that cannot take a FULL round — within ``n_steps`` of
-        max_context or the per-sequence block cap, or whose block extension
-        fails because the pool is momentarily exhausted — are simply left
-        untouched (still running): capping, max-context stops, and
-        memory-pressure waiting all stay the per-step scheduler's job
-        (generate() falls back to step() when a round serves nobody)."""
-        n = int(n_steps or self.config.decode_steps)
-        sched = self.scheduler
-        if sched.has_pending():
-            raise RuntimeError(
-                "decode_round: prompt chunks are still pending — drive step() "
-                "until prefill completes before fused decode"
-            )
-        max_context = self.config.state_manager.max_context
-        R = self.config.state_manager.max_ragged_sequence_count
-        uids = []
-        for uid in sched.running_uids():
-            if len(uids) >= R:
-                break
-            seq = self.state_manager.get_sequence(uid)
-            if seq.seen_tokens + n > max_context:
-                continue  # near the context limit: per-step path stops it
-            if self.state_manager.seq_capped(seq, n):
-                continue  # near the block cap: per-step path caps it
-            if not self.state_manager.extend(seq, n):
-                continue  # pool momentarily exhausted: sequence waits
-            uids.append(uid)
-        if not uids:
-            return {}
-
-        def dispatch():
-            toks_out, logps_out = self._start(self._stage_round, uids, n)
-
-            def finish():
-                toks, logps = np.asarray(toks_out), np.asarray(logps_out)  # [n, R]
-                results: Dict[int, np.ndarray] = {}
-                self.last_logprobs = {}
-                for i, uid in enumerate(uids):
-                    sched.apply_decode_round(uid, toks[:, i])
-                    results[uid] = toks[:, i]
-                    self.last_logprobs[uid] = logps[:, i]
-                return results
-
-            return (toks_out, logps_out), finish, {"rows": len(uids), "steps": n}
-
-        return self._dispatch_and_collect(dispatch)
-
     def spec_round(self, k: Optional[int] = None, drafts=None) -> Dict[int, np.ndarray]:
         """One speculative draft-and-verify round over eligible RUNNING
         rows. ``drafts``: {uid: proposed next tokens (≤ k)}; rows without an
@@ -2940,8 +2684,8 @@ class InferenceEngineV2:
         decode stream)}; per-round draft/accept counts land in
         ``self.last_spec`` for the driver's metrics and adaptive-K control.
 
-        Eligibility mirrors ``decode_round`` (rows near max_context / the
-        block cap / out of pool blocks fall back to the per-step path), with
+        Rows near max_context / the block cap / out of pool blocks are left
+        to the split step, which caps, stops and waits, with
         each row extended by only the blocks ITS draft needs; rejected
         drafts' blocks are rolled back via ``scheduler.apply_spec_round``.
         Rows are capped so rows x (k+1) fits the step token budget, with a
@@ -3013,7 +2757,7 @@ class InferenceEngineV2:
 
             return outputs, finish, {"rows": len(uids), "k": k}
 
-        return self._dispatch_and_collect(dispatch)
+        return self._collect(self._dispatch(dispatch))
 
     def put(self, batch_uids, batch_tokens) -> Dict[int, np.ndarray]:
         """Submit new sequences (reference put :107) and run ONE engine step.
@@ -3026,20 +2770,15 @@ class InferenceEngineV2:
     def step(self) -> Dict[int, np.ndarray]:
         """One engine step: the scheduler's packed batch advances in a single
         device call (multi-sequence decode + prompt chunks fused). Returns
-        host logits; generate() uses ``_step_device`` to keep them on device
-        (one sync per *phase*, not per step)."""
-        return _materialize_rows(self._step_device())
+        host logits (the reference's ``put`` contract); the caller feeds a
+        token of its own choosing back (``scheduler.feedback``)."""
+        return _materialize_rows(self._launch_batch()[0])
 
     def launch_step(self) -> StepInFlight:
         """The first half of a served step: schedule, stage and launch the
-        split step, nothing waited for. The serving core calls this for
-        step n+1 BEFORE ``collect_step`` of step n (one step in flight): the
-        scheduler then hands out step n's rows with their tokens still on
-        the device (``scheduler.expect``), and the program reads them from
-        the previous one's ``last_tokens``. Takes the IN-PROGRAM sampled
-        token (greedy or sampled per the engine's static sampling config),
-        never a host argmax, so driven serving reproduces ``generate()``
-        token-for-token."""
+        split step, nothing waited for. Takes the IN-PROGRAM sampled token
+        (greedy or sampled per the engine's static sampling config), never a
+        host argmax."""
 
         def dispatch():
             _, rows, last = self._launch_batch()
@@ -3055,6 +2794,29 @@ class InferenceEngineV2:
 
         return self._dispatch(dispatch)
 
+    def launch_ahead(self, prev: Optional[StepInFlight], wants) -> Optional[StepInFlight]:
+        """ONE STEP IN FLIGHT, the order ``generate()`` and the serving core
+        both keep: launch step n+1 while step n (``prev``; None after an idle
+        loop) is still uncollected, THEN ``collect_step(prev)``. Every row of
+        ``prev`` whose sequence ``wants(uid)`` another token is handed on with
+        its token where it is, on the device (``scheduler.expect``: the
+        program reads it from the previous one's ``last_tokens``); a row whose
+        token in flight is its last by length, or whose owner is gone, is not
+        (no row-step is wasted there). Returns the step launched, or None
+        where the scheduler then holds no work. The caller collects ``prev``
+        next and feeds every token back or finishes its sequence; a row that
+        stops on a token only the collect shows (EOS, a cancel) has its
+        successor in flight already, and the caller drops that one's token."""
+        if prev is not None:
+            for uid, slot in prev.rows.items():
+                if wants(uid):
+                    self.scheduler.expect(uid, slot)
+        if not self.scheduler.has_work():
+            return None
+        flight = self.launch_step()
+        flight.stats.ahead = prev is not None and bool(flight.waited)
+        return flight
+
     def collect_step(self, flight: StepInFlight) -> Dict[int, int]:
         """The second half: wait for ``flight``'s own outputs and return
         ``{uid: next-token int}`` for the rows that completed a prompt or a
@@ -3069,19 +2831,12 @@ class InferenceEngineV2:
         step ahead."""
         return self.collect_step(self.launch_step())
 
-    def _step_device(self) -> Dict[int, jax.Array]:
-        """The split-phase step for ``generate()`` and ``step()``: {uid:
-        DEVICE logits row} for rows whose prompt (or decode token)
-        completed — no host sync happens here, so consecutive prefill steps
-        are dispatched without one wait between them."""
-        return self._launch_batch()[0]
-
     def _launch_batch(self):
         """Schedule, stage the batch (_stage_split), run ONE compiled
-        program; sync-free. Returns (``{uid: (logits array, row, token
-        array)}`` for the rows that completed, ``{uid: slot of
-        last_tokens}`` for the same rows, the program's ``last_tokens``);
-        ({}, {}, None) when the scheduler had no batch."""
+        program; sync-free. Returns (``{uid: (logits array, row)}`` for the
+        rows that completed, ``{uid: slot of last_tokens}`` for the same
+        rows, the program's ``last_tokens``); ({}, {}, None) when the
+        scheduler had no batch."""
         tr = get_tracer()
         with tr.span("engine.schedule", track=getattr(self, "_trace_name", "engine")):
             batch = self.scheduler.next_batch()
@@ -3106,149 +2861,67 @@ class InferenceEngineV2:
             )
             if not dec
         ]
-        logits_dec, logits_chk, toks_dec, toks_chk, self._last_tokens = self._start(
+        logits_dec, logits_chk, _, _, self._last_tokens = self._start(
             self._stage_split, batch.total_tokens, dec_rows, chk_rows)
-        # rows are referenced as (logits array, row index, token array):
-        # slicing logits_dec[i] here would issue one tiny device op per
-        # completed row per step. Callers materialize each ARRAY once;
-        # generate() keeps only the token arrays alive.
+        # rows are referenced as (logits array, row index): slicing
+        # logits_dec[i] here would issue one tiny device op per completed row
+        # per step. step() materializes each ARRAY once.
         R = self.config.state_manager.max_ragged_sequence_count
         results: Dict[int, tuple] = {}
         slots: Dict[int, int] = {}
         for i, (uid, toks, _start, _src) in enumerate(dec_rows):
             seq = self.state_manager.get_sequence(uid)
             seq.seen_tokens += len(toks)
-            results[uid] = (logits_dec, i, toks_dec)
+            results[uid] = (logits_dec, i)
             slots[uid] = i
         for j, (uid, toks, _start, chunked) in enumerate(chk_rows):
             seq = self.state_manager.get_sequence(uid)
             seq.seen_tokens += len(toks)
             if not chunked:  # prompt complete: last-token logits usable
-                results[uid] = (logits_chk, j, toks_chk)
+                results[uid] = (logits_chk, j)
                 slots[uid] = R + j
         return results, slots, self._last_tokens
 
-    # -- convenience generation loop (greedy) ---------------------------------
     def generate(self, prompts, max_new_tokens: int = 32, eos_token_id: Optional[int] = None):
-        """Drive submit/step/feedback to completion for a list of prompts.
-        Returns list of np arrays (prompt + generated).
-
-        Two-phase flow: (1) prefill — split-phase steps dispatched WITHOUT
-        reading logits back (device arrays held), so consecutive steps
-        pipeline behind the host→device round-trip; one sync at the end
-        feeds every completed prompt's argmax back. (2) decode — fused
-        multi-token rounds. The old interleaved loop remains underneath as
-        the fallback for caps/memory-pressure cases."""
+        """Serve a list of prompts to completion through the served step,
+        one step in flight (``launch_ahead`` then ``collect_step``, as the
+        serving core drives it), the tokens the programs sample (greedy or
+        sampled per the static config). Returns a list of np arrays (prompt +
+        generated), a row cut at ``max_new_tokens``, after ``eos_token_id``,
+        or where the scheduler capped it (``last_capped``)."""
+        sched = self.scheduler
         uids = list(range(len(prompts)))
         for uid, p in zip(uids, prompts):
-            self.scheduler.submit(uid, p)
+            sched.submit(uid, p)
+        # tokens a row may still take; 0 once it has stopped
         remaining = {uid: max_new_tokens for uid in uids}
         outputs = {uid: list(np.asarray(p, np.int32).reshape(-1)) for uid, p in zip(uids, prompts)}
         self.last_capped = set()
-        ds = int(getattr(self.config, "decode_steps", 1) or 1)
-
-        # ---- phase 1: prefill without per-step syncs ----
-        # Completed rows' next tokens accumulate ON DEVICE in one rolling
-        # DONATED buffer; the host holds only {uid: slot} ints, and no step
-        # output outlives the next call. (The rule dates from a host on
-        # which a retained output array stalled the next dispatch; whether
-        # it still buys anything is not measured on today's: no cell of the
-        # benchmark runs generate().)
-        held: Dict[int, tuple] = {}
-        slots: Dict[int, int] = {}
-        cap = self.config.state_manager.max_tracked_sequences
-        tok_acc = jnp.zeros(cap, jnp.int32)
-        if not hasattr(self, "_acc_scatter"):
-            self._acc_scatter = jax.jit(
-                lambda acc, arr, idx, dst: acc.at[dst].set(arr[idx]),
-                donate_argnums=0,
-            )
-        next_slot = 0
-        while self.scheduler.has_pending():
-            res = self._step_device()
-            if self.last_step.scheduled_tokens == 0:
-                break  # pool pressure: the interleaved loop below owns waiting
-            groups: Dict[int, list] = {}
-            for u, e in res.items():
-                if not (isinstance(e, tuple) and len(e) > 2):
-                    held[u] = e  # test doubles: plain logits arrays
-                    continue
-                groups.setdefault(id(e[2]), [e[2], [], []])
-                # slot supply cannot run out: submit() caps tracked
-                # sequences at max_tracked_sequences and nothing finishes
-                # during phase 1, so completions per phase <= cap
-                if next_slot >= cap:
-                    raise RuntimeError(
-                        "prefill-phase completions exceed slot capacity "
-                        f"({next_slot} >= {cap})"
-                    )
-                g = groups[id(e[2])]
-                g[1].append(e[1])
-                g[2].append(next_slot)
-                slots[u] = next_slot
-                next_slot += 1
-            for arr, idxs, dsts in groups.values():
-                tok_acc = self._acc_scatter(
-                    tok_acc, arr, jnp.asarray(idxs, jnp.int32),
-                    jnp.asarray(dsts, jnp.int32),
-                )
-        if slots:
-            buf = np.asarray(tok_acc)  # ONE sync for the whole phase
-            for uid, sl in slots.items():
-                held[uid] = np.int32(buf[sl])
-        for uid, lg in _materialize_rows(held).items():
-            nxt = int(lg) if np.ndim(lg) == 0 else int(np.argmax(lg))
-            outputs[uid].append(nxt)
-            remaining[uid] -= 1
-            if remaining[uid] <= 0 or (eos_token_id is not None and nxt == eos_token_id):
-                self.scheduler.finish(uid)
-            else:
-                self.scheduler.feedback(uid, nxt)
-
-        # ---- phase 2: fused decode rounds + interleaved fallback ----
-        while self.scheduler.has_work():
-            if ds > 1 and not self.scheduler._pending and self.scheduler._running:
-                # fused multi-token decode: full ds-rounds for every eligible
-                # sequence; a sequence that needs fewer tokens overshoots by
-                # < one round and the extras are truncated (its state is
-                # discarded at finish). Sequences decode_round skips (near a
-                # cap / max_context, or waiting on KV blocks) fall through to
-                # the per-step scheduler below, which owns stop/cap/wait
-                # policy, once no sequence is round-eligible.
-                res = self.decode_round(ds)
-                if res:
-                    for uid, gen in res.items():
-                        take = [int(t) for t in gen]
-                        if eos_token_id is not None and eos_token_id in take:
-                            take = take[: take.index(eos_token_id) + 1]
-                        take = take[: remaining[uid]]
-                        outputs[uid].extend(take)
-                        remaining[uid] -= len(take)
-                        if remaining[uid] <= 0 or (
-                            eos_token_id is not None and take and take[-1] == eos_token_id
-                        ):
-                            self.scheduler.finish(uid)
-                    continue
-            res = self._step_device()
-            # Liveness: if nothing was scheduled and work remains, no call we
-            # make below can change scheduler state — fail loudly instead of
-            # busy-looping (e.g. KV pool too fragmented for any pending
-            # prompt with no running sequence left to free blocks).
-            if self.last_step.scheduled_tokens == 0 and self.scheduler.has_work():
+        prev = None
+        while prev is not None or sched.has_work():
+            flight = self.launch_ahead(prev, lambda uid: remaining[uid] > 1)
+            if flight is not None and not flight.waited:
+                flight = None  # no batch: rows wait on KV blocks
+            if prev is None and flight is None and sched.has_work():
+                # Liveness: nothing is in flight and nothing was scheduled, so
+                # no call made here can change scheduler state — fail loudly
+                # instead of busy-looping (e.g. KV pool too fragmented for any
+                # pending prompt with no running sequence left to free blocks).
                 raise RuntimeError(
                     "scheduler deadlock: work pending but nothing schedulable "
                     f"(free KV blocks={self.state_manager.free_blocks}); "
                     "increase kv_cache.num_blocks or reduce concurrency"
                 )
-            # the in-program next tokens (sampled or greedy per config) —
-            # argmax-of-logits here would silently mix greedy tokens into a
-            # sampled stream (round-5 review finding)
-            for uid, tok in _materialize_rows(res, want_tokens=True).items():
-                nxt = int(tok) if np.ndim(tok) == 0 else int(np.argmax(tok))
-                outputs[uid].append(nxt)
+            for uid, tok in (self.collect_step(prev) if prev is not None else {}).items():
+                if remaining[uid] <= 0:
+                    continue  # stopped on EOS while this step was in flight
+                outputs[uid].append(tok)
                 remaining[uid] -= 1
-                if remaining[uid] <= 0 or (eos_token_id is not None and nxt == eos_token_id):
-                    self.scheduler.finish(uid)
+                if eos_token_id is not None and tok == eos_token_id:
+                    remaining[uid] = 0
+                if remaining[uid] <= 0:
+                    sched.finish(uid)
                 else:
-                    self.scheduler.feedback(uid, nxt)
+                    sched.feedback(uid, tok)
+            prev = flight
         return [np.asarray(outputs[uid], np.int32) for uid in uids]

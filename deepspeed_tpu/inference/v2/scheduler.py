@@ -197,8 +197,8 @@ class RaggedScheduler:
     def has_work(self) -> bool:
         return bool(self._pending or self._running)
 
-    # -- engine-facing accessors (the decode round's bookkeeping runs through
-    # these instead of reaching into privates — round-4 advisor finding) ----
+    # -- engine-facing accessors (the verify step's bookkeeping runs through
+    # these instead of reaching into privates) ----
     def has_pending(self) -> bool:
         return bool(self._pending)
 
@@ -208,30 +208,21 @@ class RaggedScheduler:
     def peek_next_token(self, uid: int) -> Optional[int]:
         return self._next_token.get(uid)
 
-    def apply_decode_round(self, uid: int, gen_tokens) -> None:
-        """Record ``gen_tokens`` greedy tokens produced for a RUNNING uid by
-        a fused decode round: history, seen-token count, and the pending
-        next-token all advance together."""
+    def apply_spec_round(self, uid: int, gen_tokens, pre_blocks: int) -> None:
+        """Record a speculative verify step's ACCEPTED tokens for a RUNNING
+        uid and roll its KV write cursor back past the rejected draft:
+        history, seen-token count and the pending next-token all advance
+        together by the emitted tokens, then table blocks the step allocated
+        beyond the new cursor are truncated and returned to the pool.
+        ``pre_blocks`` is the row's table length BEFORE the step's extend —
+        the truncation floor that keeps prefix-cache-shared (and any other
+        earlier) blocks out of the drop set."""
         seq = self._mgr.get_sequence(uid)
         if seq is None or seq.finished:
             return
         seq.tokens.extend(int(t) for t in gen_tokens)
         seq.seen_tokens += len(gen_tokens)
         self._next_token[uid] = int(gen_tokens[-1])
-
-    def apply_spec_round(self, uid: int, gen_tokens, pre_blocks: int) -> None:
-        """Record a speculative verify round's ACCEPTED tokens for a RUNNING
-        uid and roll its KV write cursor back past the rejected draft:
-        history/seen/pending advance by the emitted tokens exactly as in a
-        fused decode round, then table blocks the round allocated beyond the
-        new cursor are truncated and returned to the pool. ``pre_blocks`` is
-        the row's table length BEFORE the round's extend — the truncation
-        floor that keeps prefix-cache-shared (and any other pre-round)
-        blocks out of the drop set."""
-        seq = self._mgr.get_sequence(uid)
-        if seq is None or seq.finished:
-            return
-        self.apply_decode_round(uid, gen_tokens)
         self._mgr.truncate_blocks(seq, seq.seen_tokens, min_keep_blocks=pre_blocks)
 
     def next_batch(self) -> Optional[RaggedBatch]:

@@ -925,19 +925,7 @@ def _chunk_kernel(*refs, bs, tile, nh, nkv, d, dv, window, sink):
                 # the tile's first query reaches furthest back
                 key_ok = key_ok & jnp.logical_not(window_too_far(first_pos, key_pos, window))
             va = jnp.where(key_ok, va, jnp.zeros_like(va))
-        s = jax.lax.dot_general(
-            qa, ka, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32)  # [nkv, M, bs]
-        if masked:
-            s = jnp.where(valid, s, NEG_INF)
-        m_p = m_scr[:, :, :1]
-        m_new = jnp.maximum(m_p, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_p - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[:, :, :1] = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(qa.dtype), va, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[:, :, :1] = m_new
+        _fold(qa, ka, va, valid if masked else None, m_scr, l_scr, acc_scr)
 
     # a tile with no live query is one program: nothing to fold, zeros out
     pl.when((rows > 0) & whole)(lambda: visit(masked=False))

@@ -163,7 +163,6 @@ class ReplicaAgent:
             "name": self.name,
             "pid": os.getpid(),
             "tp_shards": core.tp_shards(),
-            "decode_steps": core.decode_steps,
             "kv_headroom": core.kv_headroom,
             "kv": {
                 "num_blocks": core.kv_total,

@@ -2,7 +2,7 @@
 
 This is the single-engine ``ServingDriver`` loop body factored out of its
 one-engine assumption: everything that talks to the ENGINE — KV-aware
-admissibility, scheduler submission, fused/speculative/plain stepping,
+admissibility, scheduler submission, speculative/plain stepping,
 capped-sequence reaping — lives here, keyed by a core instance, while
 everything that talks to the REQUEST (token delivery, terminal
 transitions, metrics) is delegated to an owner-provided *sink*. One
@@ -20,9 +20,9 @@ owner can serve many cores):
   * ``finish_capped(core, req)`` — the scheduler force-finished the
     sequence at its block/context cap (blocks already freed).
 
-One step in flight: a core that can (no speculative controller, no fused
-rounds, not the prefill role, an engine with ``launch_step`` /
-``collect_step``) launches step n+1 and THEN collects and delivers step n,
+One step in flight: a core that can (no speculative controller, not the
+prefill role, an engine with ``launch_ahead`` / ``collect_step``) launches
+step n+1 and THEN collects and delivers step n,
 so the host's work of a step runs under the chip's; the others collect where
 they launch, through the same two primitives. ``has_work()`` is true while a
 step is in flight, and ``settle()`` collects it without launching another.
@@ -53,7 +53,6 @@ class EngineCore:
         engine,
         name: str = "replica0",
         role: str = "both",  # "prefill" | "decode" | "both" (colocated)
-        decode_steps: int = 1,
         kv_headroom: float = 0.0,
         spec_k: Optional[int] = None,
         spec_ngram: int = 3,
@@ -63,7 +62,6 @@ class EngineCore:
         self.engine = engine
         self.name = str(name)
         self.role = role
-        self.decode_steps = int(decode_steps)
         self.kv_headroom = float(kv_headroom)
         self.metrics = metrics
         self.requests: Dict[int, Request] = {}  # uid -> Request resident here
@@ -106,6 +104,10 @@ class EngineCore:
         if spec_k is None:
             spec_k = int(getattr(getattr(engine, "config", None), "spec_k", 0) or 0)
         self.spec_k = int(spec_k)
+        # the kind of the engine's recurrent layers (a key of
+        # models.transformer.RECURRENT; "" for a model without them): names
+        # the pair of counters its steps' rows and chunk tokens go to
+        self._recurrent_kind = getattr(getattr(engine, "_mc", None), "recurrent_kind", "") or ""
         self.spec_ctl = None
         self.proposer = proposer
         if self.spec_k > 0 and role != "prefill" and hasattr(engine, "spec_round"):
@@ -188,7 +190,7 @@ class EngineCore:
             self.metrics.inc(name, delta)
 
     def _count_step(self, stats=None) -> None:
-        """One step or round ran: count it, and beside it what the engine
+        """One step ran: count it, and beside it what the engine
         sized it to and what it carried (``stats``, the step's ``StepStats``,
         filled as the engine stages the step; the engine's ``last_step``
         where the step was waited for where it was launched), so that
@@ -206,7 +208,7 @@ class EngineCore:
         self._inc("steps_ahead_total", int(held("ahead")))
         self._inc("ahead_rows_dropped_total", held("ahead_rows_dropped"))
         # the step's time on the device, by kind: a split step that carried a
-        # prompt chunk, or any other step or round. Only a step the engine
+        # prompt chunk, or any other step. Only a step the engine
         # stamped as it collected it: one that launched nothing, a
         # compute-free fake's and a remote core's count no second and no step
         if getattr(stats, "t_ready", None) is not None:
@@ -246,16 +248,12 @@ class EngineCore:
             # whose kept groups include one this chip holds
             self._inc("moe_group_tokens_total", moe.get("group_tokens", 0))
             self._inc("moe_group_hit_tokens_total", moe.get("group_hit", 0))
-        # a model with DeltaNet layers: the rows whose states took the update,
-        # and the prompt tokens the chunk rule walked
-        self._inc("gdn_decode_rows_total", held("gdn_decode_rows"))
-        self._inc("gdn_chunk_tokens_total", held("gdn_chunk_tokens"))
-        # one with Mamba layers: the same pair (the chunked scan's tokens)
-        self._inc("mamba_decode_rows_total", held("mamba_decode_rows"))
-        self._inc("mamba_chunk_tokens_total", held("mamba_chunk_tokens"))
-        # ... with Kimi Delta Attention layers: the same pair
-        self._inc("kda_decode_rows_total", held("kda_decode_rows"))
-        self._inc("kda_chunk_tokens_total", held("kda_chunk_tokens"))
+        # a model with recurrent layers: the rows whose states took the update
+        # and the prompt tokens the chunk rule walked, under its kind's names
+        kind = self._recurrent_kind
+        if kind:
+            self._inc(f"{kind}_decode_rows_total", held("recurrent_decode_rows"))
+            self._inc(f"{kind}_chunk_tokens_total", held("recurrent_chunk_tokens"))
         # the cache as the step found it, by kind, summed a step; and what a
         # window layer's decode walks visit (beside paged_live_blocks_total)
         self._inc("kv_global_blocks_used_total", held("kv_global_blocks"))
@@ -460,7 +458,7 @@ class EngineCore:
         else:
             drafts = self._build_drafts()
         if not any(drafts.values()):
-            return False  # nothing to verify: fused decode round is cheaper
+            return False  # nothing to verify: the split step is cheaper
         t0 = tr.now() if tr.enabled else 0.0
         round_res = self.engine.spec_round(self.spec_k, drafts=drafts)
         if not round_res:
@@ -481,15 +479,15 @@ class EngineCore:
         for uid, (drafted, accepted) in per_uid.items():
             self.spec_ctl.update(uid, drafted, accepted)
         # apply_spec_round already advanced the scheduler: deliver without
-        # feedback, exactly like fused decode rounds
+        # feedback
         self._deliver_results(sink, sched, round_res, feedback=False)
         return True
 
     def _deliver_results(self, sink, sched, results, feedback: bool) -> bool:
-        """Hand a step's or round's tokens ({uid: tokens in order}) to the
+        """Hand a step's tokens ({uid: tokens in order}) to the
         sink, then finish the sequences the engine capped: the
-        ``step.deliver`` span. ``feedback=False`` for rounds whose engine
-        call already advanced the scheduler. Returns True if any token
+        ``step.deliver`` span. ``feedback=False`` for a verify step, whose
+        engine call already advanced the scheduler. Returns True if any token
         reached a live request."""
         progress = False
         with get_tracer().span("step.deliver", track=self.name):
@@ -508,7 +506,7 @@ class EngineCore:
         return progress
 
     def step_once(self, sink) -> bool:
-        """One engine step (or fused decode / speculative verify round).
+        """One engine step (or speculative verify step).
         Returns True if any token landed, any prompt advanced by a chunk or
         a step was launched (progress). Caller holds ``step_lock``.
 
@@ -544,16 +542,15 @@ class EngineCore:
 
     def _runs_ahead(self) -> bool:
         """Whether this core launches a step before it collects the one
-        before, from what it is: not with a speculative controller or fused
-        rounds (their programs take a row's token from the host), not in a
+        before, from what it is: not with a speculative controller (the
+        verify program takes a row's token from the host), not in a
         role that hands K/V off after a step (the export reads what the
         step's tokens completed), not over an engine that has only
         ``step_tokens`` (a compute-free fake)."""
         return (
             self.spec_ctl is None
-            and self.decode_steps <= 1
             and self.role != "prefill"
-            and hasattr(self.engine, "launch_step")
+            and hasattr(self.engine, "launch_ahead")
         )
 
     def _collect_flight(self, sched, flight, reqs) -> Dict[int, int]:
@@ -576,12 +573,12 @@ class EngineCore:
         return results
 
     def _split_step(self, sched, launch: bool):
-        """The split step through the engine's two primitives, their order
-        chosen a step at a time: with a step in flight, hand its rows out
-        again with their tokens where they are (on the device), launch, THEN
-        collect it. Returns ({uid: token} of what was collected, whether
-        anything was scheduled or stays in flight)."""
-        if not hasattr(self.engine, "launch_step"):
+        """The split step through the engine's ``launch_ahead`` (with a step
+        in flight, hand its rows on with their tokens where they are, on the
+        device, and launch) and THEN ``collect_step`` of the step in flight.
+        Returns ({uid: token} of what was collected, whether anything was
+        scheduled or stays in flight)."""
+        if not hasattr(self.engine, "launch_ahead"):
             # no stamps from such an engine: the bracket of the call is the step
             tr = get_tracer()
             t0 = tr.now() if tr.enabled else 0.0
@@ -593,17 +590,18 @@ class EngineCore:
             return results, bool(getattr(stats, "scheduled_tokens", 0))
         prev, self._flight = self._flight, None
         collect = [prev] if prev is not None else []
-        if prev is not None and launch:
-            flight, reqs = prev
-            for uid, slot in flight.rows.items():
-                req = self.requests.get(uid)
+        flight = None
+        if launch:
+            before, reqs = prev or (None, {})
+
+            def wants(uid):
                 # not a row whose request is gone, nor one whose token in
-                # flight is its last by length: no row-step is wasted there
-                if req is not None and req is reqs[uid] and req.remaining_tokens > 1:
-                    sched.expect(uid, slot)
-        if launch and sched.has_work():
-            flight = self.engine.launch_step()
-            flight.stats.ahead = prev is not None and bool(flight.waited)
+                # flight is its last by length
+                req = self.requests.get(uid)
+                return req is not None and req is reqs[uid] and req.remaining_tokens > 1
+
+            flight = self.engine.launch_ahead(before, wants)
+        if flight is not None:
             cur = (flight, {uid: self.requests.get(uid) for uid in flight.rows})
             if flight.waited and self._runs_ahead():
                 self._flight = cur
@@ -625,14 +623,6 @@ class EngineCore:
             and not sched.has_pending()
             and bool(sched.running_uids())
         )
-        use_round = (
-            launch
-            and self.decode_steps > 1
-            and hasattr(self.engine, "decode_round")
-            and not sched.has_pending()
-            and bool(sched.running_uids())
-        )
-        tr = get_tracer()
         try:
             faults = get_fault_injector()
             if faults.enabled:
@@ -644,19 +634,6 @@ class EngineCore:
                 faults.check("engine.step", replica=self.name)
             if use_spec and self._spec_step(sink, sched):
                 return True
-            if use_round:
-                t0 = tr.now() if tr.enabled else 0.0
-                round_res = self.engine.decode_round(self.decode_steps)
-                if round_res:
-                    self._count_step()
-                    if tr.enabled:
-                        self._trace_round(tr, "round.fused", t0, tr.now(),
-                                          round_res, {
-                            "rows": len(round_res),
-                            "steps": self.decode_steps,
-                            "tokens": sum(len(t) for t in round_res.values()),
-                        })
-                    return self._deliver_results(sink, sched, round_res, feedback=False)
             results, scheduled = self._split_step(sched, launch)
         except Exception as e:
             # engine-level failure: per-request state is unknowable, so the

@@ -15,7 +15,7 @@ labels all work unchanged against a replica the router cannot call into:
     late rejection (recovered by replay), never pool corruption.
   * **tokens** arrive as TOKEN frames on the events channel; the pump
     thread feeds them into ``Router.deliver(feedback=False)`` — feedback
-    already happened agent-side, exactly like fused/spec rounds.
+    already happened agent-side, exactly like a verify step's.
   * **KV handoffs** ride the existing remote transport: ``adopt`` ships
     only the META descriptor; the agent fetches the staged payload
     straight from the prefill worker's ``KVEndpoint`` (data never
@@ -102,7 +102,6 @@ class RemoteEngineHandle:
         self._probe_timeout_s = float(probe_timeout_s)
 
         self._meta = dict(meta)
-        self.decode_steps = int(meta.get("decode_steps", 1) or 1)
         self.kv_headroom = float(meta.get("kv_headroom", 0.0) or 0.0)
         self.kv_total = int(self._kv_cfg("num_blocks", 0))
         self.kv_info = dict(meta.get("kv_info") or {})

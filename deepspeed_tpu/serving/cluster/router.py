@@ -3,7 +3,7 @@
 One ``Router`` owns N engines wrapped in :class:`EngineCore`: the first
 ``P`` are **prefill workers** (chunked prefill only — their split-step
 produces the request's first token, then the sequence's KV blocks hand
-off), the rest are **decode replicas** (fused decode rounds, spec decode).
+off), the rest are **decode replicas** (decode steps, spec decode).
 With ``P == 0`` the decode replicas are colocated engines — each request
 runs prefill AND decode on the replica the placement policy picked, with
 no handoff — which is the pure scale-out mode (and what the single-engine
@@ -82,7 +82,6 @@ class Router:
         max_queue: int = 128,
         kv_headroom: float = 0.0,
         default_timeout_s: Optional[float] = None,
-        decode_steps: int = 1,
         poll_interval_s: float = 0.02,
         monitor=None,
         spec_k: Optional[int] = None,
@@ -135,14 +134,13 @@ class Router:
 
         colocated = not prefill_engines
         self.prefill = [
-            EngineCore(e, name=f"p{i}", role="prefill", decode_steps=1,
-                       kv_headroom=kv_headroom, spec_k=0, metrics=self.metrics)
+            EngineCore(e, name=f"p{i}", role="prefill", kv_headroom=kv_headroom,
+                       spec_k=0, metrics=self.metrics)
             for i, e in enumerate(prefill_engines)
         ]
         self.decode = [
             EngineCore(e, name=f"d{i}", role="both" if colocated else "decode",
-                       decode_steps=decode_steps, kv_headroom=kv_headroom,
-                       spec_k=spec_k, spec_ngram=spec_ngram, proposer=proposer,
+                       kv_headroom=kv_headroom, spec_k=spec_k, spec_ngram=spec_ngram, proposer=proposer,
                        metrics=self.metrics)
             for i, e in enumerate(decode_engines)
         ]
@@ -1406,7 +1404,11 @@ class Router:
                     # late inadmissibility (e.g. raced config change): isolate
                     err = str(e)
             with self._cond:
-                if err is None:
+                if err is None and req.is_terminal:
+                    # the core's worker stepped it between admit() and here,
+                    # and the step failed: the terminal state stands
+                    pass
+                elif err is None:
                     req.state = RequestState.PREFILL
                     req.t_admitted = time.monotonic()
                     if req.trace is not None:
@@ -1677,8 +1679,7 @@ class Router:
             name = f"d{self._decode_seq}"
             self._decode_seq += 1
         core = EngineCore(
-            engine, name=name, role=tmpl.role,
-            decode_steps=tmpl.decode_steps, kv_headroom=tmpl.kv_headroom,
+            engine, name=name, role=tmpl.role, kv_headroom=tmpl.kv_headroom,
             spec_k=tmpl.spec_k, metrics=self.metrics,
         )
         core._warm_baseline = baseline

@@ -1,9 +1,8 @@
 """Continuous-batching serving driver.
 
 The long-lived loop MII/FastGen runs around the engine, rebuilt for the v2
-TPU engine: a background thread pumps ``engine.step_tokens()`` /
-``engine.decode_round()`` while callers submit ``Request``s from any
-thread and stream tokens out.
+TPU engine: a background thread pumps the engine's served step while
+callers submit ``Request``s from any thread and stream tokens out.
 
 Responsibilities (and how each maps to the loop):
 
@@ -26,7 +25,7 @@ The driver needs only a small engine protocol — ``scheduler`` (the
 ``RaggedScheduler`` API), ``state_manager`` (``free_blocks``), and
 ``step_tokens()`` returning ``{uid: next-token int}`` — so tests drive it
 with a compute-free fake over the REAL scheduler/allocator stack. Over an
-engine that also has ``launch_step()`` / ``collect_step()`` the core runs
+engine that also has ``launch_ahead()`` / ``collect_step()`` the core runs
 one step in flight: it launches step n+1 before it collects step n
 (``EngineCore.step_once``), so the loop keeps stepping while
 ``core.has_work()``, which is true until the last step launched is collected.
@@ -81,7 +80,6 @@ class ServingDriver:
         max_queue: int = 128,
         kv_headroom: float = 0.0,
         default_timeout_s: Optional[float] = None,
-        decode_steps: int = 1,
         poll_interval_s: float = 0.02,
         monitor=None,
         spec_k: Optional[int] = None,
@@ -93,7 +91,6 @@ class ServingDriver:
         self.max_queue = int(max_queue)
         self.kv_headroom = float(kv_headroom)
         self.default_timeout_s = default_timeout_s
-        self.decode_steps = int(decode_steps)
         self.poll_interval_s = float(poll_interval_s)
         self.monitor = monitor
         self.metrics = ServingMetrics()
@@ -107,7 +104,6 @@ class ServingDriver:
             engine,
             name="replica0",
             role="both",
-            decode_steps=self.decode_steps,
             kv_headroom=self.kv_headroom,
             spec_k=spec_k,
             spec_ngram=spec_ngram,
@@ -414,7 +410,7 @@ class ServingDriver:
     # token delivery ----------------------------------------------------
     def _deliver(self, req: Request, token: int, feedback: bool = True) -> None:
         """One generated token for an active request: record, stream, stop.
-        ``feedback=False`` for fused-round tokens — ``apply_decode_round``
+        ``feedback=False`` for a verify step's tokens — ``apply_spec_round``
         already advanced the scheduler, a second feedback would double-append.
         ``stop_fn`` exceptions propagate (caller isolates the request)."""
         now = time.monotonic()
@@ -463,7 +459,7 @@ class ServingDriver:
                             scheduler_done=True)
 
     def _step_once(self) -> bool:
-        """One engine step (or fused decode / speculative verify round).
+        """One engine step (or speculative verify step).
         Returns True if any token landed / request advanced (progress)."""
         with self.core.step_lock:
             return self.core.step_once(self)

@@ -10,7 +10,7 @@ changing fleet — the serving-side activation of the reference project's
   * ``controller`` — the ControlLoop thread: samples per-replica queue
                      depth / deadline-slack trends from ``replica_stats``
                      and scales decode replicas between min/max
-  * ``spares``     — warm standby engines whose split/fused/verify step
+  * ``spares``     — warm standby engines whose split/verify step
                      programs are pre-traced at spawn, so scale-up cost is
                      admission-time, not compile-time (pinned by a
                      recompile-counter assertion)

@@ -1,6 +1,6 @@
 """Warm spare engines: pay compilation at spawn, not at scale-up.
 
-A cold engine admitted into the fleet would trace its split/fused/verify
+A cold engine admitted into the fleet would trace its split/verify
 step programs on the first real request — seconds of compile latency
 exactly when the control loop scaled up because latency was already bad.
 A warm spare runs ``engine.warm_trace()`` at spawn (a throwaway prompt
@@ -47,8 +47,8 @@ class WarmSparePool:
     every engine entering the pool (spawned or released back by a
     scale-down) is warmed before it becomes acquirable.
 
-    ``warm_kw`` forwards the serving loop's step-program shape knobs
-    (``decode_steps``, ``spec_k``) to ``warm_trace`` so the spare traces
+    ``warm_kw`` forwards the serving loop's step-program shape knob
+    (``spec_k``) to ``warm_trace`` so the spare traces
     EXACTLY the programs the router's cores will run."""
 
     def __init__(

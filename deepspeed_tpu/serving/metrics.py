@@ -99,6 +99,8 @@ class ServingMetrics:
     PREFIX = "dstpu_serving"
 
     def __init__(self, buckets: Tuple[float, ...] = DEFAULT_BUCKETS):
+        from deepspeed_tpu.models.transformer import RECURRENT
+
         self._lock = threading.Lock()
         self.ttft = Histogram(buckets)
         self.tpot = Histogram(buckets)
@@ -148,17 +150,12 @@ class ServingMetrics:
             "moe_layer_calls_total": 0,
             # ... and the experts that had a row, summed over layer calls
             "moe_experts_hit_total": 0,
-            # Gated DeltaNet layers (EngineCore._count_step): rows whose
-            # recurrent state took the one-token update, of one such layer
-            # a step, and the prompt tokens one such layer's chunk rule walked
-            "gdn_decode_rows_total": 0,
-            "gdn_chunk_tokens_total": 0,
-            # Mamba layers: the same pair (the chunked scan's tokens)
-            "mamba_decode_rows_total": 0,
-            "mamba_chunk_tokens_total": 0,
-            # Kimi Delta Attention layers: the same pair
-            "kda_decode_rows_total": 0,
-            "kda_chunk_tokens_total": 0,
+            # recurrent layers, a pair a kind (gdn_*, mamba_*, kda_*:
+            # EngineCore._count_step): rows whose recurrent state took the
+            # one-token update, of one such layer a step, and the prompt
+            # tokens one such layer's chunk rule walked
+            **{f"{kind}_{what}_total": 0 for kind in RECURRENT
+               for what in ("decode_rows", "chunk_tokens")},
             # a state slot's bytes (update_kv_pool_info: set once, no total)
             "state_slot_bytes": 0,
             # the cache by kind (EngineCore._count_step), summed a step: blocks
@@ -194,7 +191,7 @@ class ServingMetrics:
             "ahead_rows_dropped_total": 0,
             # the device's step timed where it is collected
             # (EngineCore._count_step), by kind: seconds on the device of the
-            # steps with no prompt chunk (a fused or verify round among them)
+            # steps with no prompt chunk (a verify step among them)
             # and of those that carried one, each beside the count of the
             # steps its seconds hold (a step that launched nothing, a
             # compute-free fake's and a remote core's have no stamp); and

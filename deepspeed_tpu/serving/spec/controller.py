@@ -7,7 +7,7 @@ acceptance-rate EMA and:
 
   * serves the full draft length while the EMA stays healthy;
   * drops the request to ``k=0`` (plain decode riding the same verify
-    program, or the fused decode round when NO request drafts) once the
+    program, or the split step when NO request drafts) once the
     EMA falls below ``min_accept``;
   * re-probes with a full draft every ``probe_interval`` rounds, so a
     request that enters a predictable stretch (a quoted span, a

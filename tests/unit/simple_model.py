@@ -40,3 +40,18 @@ def random_dataset(n=64, in_dim=16, out_dim=16, seed=0):
 
 def batch_of(dataset, start, size):
     return {k: v[start : start + size] for k, v in dataset.items()}
+
+
+def served_tokens(engine, prompts, n_new):
+    """``prompts`` through a ``ServingDriver`` over ``engine``, ``n_new`` tokens
+    each: every request's generated tokens. A request's uid is its prompt's
+    index, as ``engine.generate()`` numbers its rows, so a sampled stream's
+    keys (uid, position) are the same on both."""
+    from deepspeed_tpu.serving import SamplingParams, ServingDriver
+
+    with ServingDriver(engine) as driver:
+        reqs = [driver.submit(p, params=SamplingParams(max_new_tokens=n_new, ignore_eos=True))
+                for p in prompts]
+        assert [r.uid for r in reqs] == list(range(len(prompts)))
+        assert all(r.wait(300) for r in reqs)
+    return [list(r.generated) for r in reqs]

@@ -151,6 +151,21 @@ def test_engine_equals_the_reference_through_the_interpreted_kernels():
     assert _gap(params, HF, prompts, got, toks) < TOL
 
 
+@pytest.mark.parametrize("sampling", [{}, {"greedy": False, "temperature": 0.9, "seed": 7}],
+                         ids=["greedy", "sampled"])
+def test_generate_equals_the_driven_core_on_the_latent_plane(sampling):
+    """``generate()`` is the served step: the same prompts through the serving
+    driver give the same tokens, greedy and sampled."""
+    from tests.unit.simple_model import served_tokens
+
+    cfg, params = _model()
+    prompts = _prompts((5, 70, 100, 33))
+    outs = _engine(cfg, params, **sampling).generate(prompts, max_new_tokens=20)
+    driven = served_tokens(_engine(cfg, params, **sampling), prompts, 20)
+    for p, out, got in zip(prompts, outs, driven):
+        assert [int(t) for t in out[len(p):]] == got
+
+
 def test_a_prefix_hit_shares_latent_blocks():
     """The prefix cache stays on for a latent pool: a hit shares blocks by
     table, whatever a block holds, and the second request's logits are the
@@ -450,8 +465,7 @@ def test_config_from_hf_refuses_what_it_cannot_compute(change, match):
     ({"kv_cache": {"kv_cache_dtype": "int8"}}, "int8 pool's scale planes"),
     ({"kv_cache": {"host_tier_bytes": 1 << 20, "prefix_cache": True}}, "host block tier"),
     ({"spec_k": 2}, "speculative"),
-    ({"decode_steps": 4}, "decode_steps > 1"),
-], ids=["int8_pool", "host_tier", "speculative", "fused_round"])
+], ids=["int8_pool", "host_tier", "speculative"])
 def test_what_cannot_carry_one_plane_refuses_at_build(extra, match):
     cfg, params = _model()
     with pytest.raises(NotImplementedError, match=match):

@@ -16,16 +16,16 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.analysis import verify as dv
 
-PROGRAMS = ["split_step", "decode_only_step", "one_row_step", "multistep_decode", "verify_step"]
+PROGRAMS = ["split_step", "decode_only_step", "one_row_step", "verify_step"]
 
 
 @functools.lru_cache(maxsize=None)
 def _programs(kind):
     """(engine, {name: (jitted, args)}) after two same-shape generate()
     passes: pass 1 traces, pass 2 must hit the caches. ``split_step`` holds
-    the passes' two prompts as two chunk rows; ``decode_only_step`` is the
-    split step's shape for a batch with no chunk row, ``one_row_step`` for a
-    batch with one."""
+    the passes' two prompts as two chunk rows and ``decode_only_step`` is the
+    split step's shape for a batch with no chunk row, both as ``generate()``
+    ran them; ``one_row_step`` is staged for a batch with one chunk row."""
     if kind in ("gdn", "window"):
         return dv._engine_v2_programs("bf16", model=kind)
     return dv._engine_v2_programs(kind)
@@ -55,9 +55,16 @@ def test_step_programs_alias_every_pool_leaf(program, kv_dtype):
     assert got == sorted((tuple(p.shape), str(p.dtype)) for p in pools)
 
 
-def test_split_step_traces_once():
-    _, programs = _programs("bf16")
-    res = dv.check_recompile("split_step", programs["split_step"][0])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "gdn", "window"])
+@pytest.mark.parametrize("program", ["split_step", "decode_only_step"])
+def test_the_steps_generate_ran_trace_once(program, kv_dtype):
+    """The two shapes ``generate()`` drives, one step in flight: the second
+    pass hit the first's programs, whatever ``last_tokens`` was (zeros, then
+    the step before's output)."""
+    _, programs = _programs(kv_dtype)
+    fn = programs[program][0]
+    assert fn._cache_size() == 1
+    res = dv.check_recompile(program, fn)
     assert res.ok, res.detail
 
 

@@ -561,7 +561,7 @@ def _elastic_real_roundtrip(tiny_model, kv_dtype, sampling):
 
     pool = WarmSparePool(
         factory=lambda: _real_engine(tiny_model, kv_dtype, sampling),
-        count=1, warm_kw={"decode_steps": 1, "spec_k": 0})
+        count=1, warm_kw={"spec_k": 0})
     cfg = ElasticServingConfig(min_decode_replicas=1, max_decode_replicas=2,
                                control_interval_s=30.0)
     router = Router(engines=[_real_engine(tiny_model, kv_dtype, sampling)],

@@ -364,15 +364,14 @@ def test_tune_serving_cpu_smoke():
                 n_kv_heads=2, max_seq_len=256, dtype="float32")
     common = dict(shape=tiny, concurrency=4, max_new=8, repeats=1,
                   block_size=16, num_blocks=64, max_blocks_per_seq=8,
-                  token_budget=128, prompt_chunk=64, max_prompt_chunks=2,
-                  prompt_min=8, prompt_max=32)
+                  token_budget=128, prompt_min=8, prompt_max=32)
     space = [
-        {"decode_steps": 4, **common},
-        {"decode_steps": 8, **common},
+        {"prompt_chunk": 64, "max_prompt_chunks": 2, **common},
+        {"prompt_chunk": 32, "max_prompt_chunks": 4, **common},
     ]
     best, val, records = tune_serving(
         max_experiments=2, timeout_s=600, platform="cpu", space=space,
     )
     assert len(records) == 2
     assert best is not None and val is not None and val > 0
-    assert best["decode_steps"] in (4, 8)
+    assert best["prompt_chunk"] in (64, 32)

@@ -517,8 +517,7 @@ class TestInferenceV2:
         engine._blocks_a_program = 0  # a latent pool: another kernel's walks
         assert engine._count_paged(tokens)["paged_programs"] == 0
 
-    @pytest.mark.parametrize("entry", ["step_tokens", "step_tokens_experts", "decode_round",
-                                       "spec_round"])
+    @pytest.mark.parametrize("entry", ["step_tokens", "step_tokens_experts", "spec_round"])
     def test_step_stats_filled_by(self, tiny_model, entry):
         """Every entry point leaves ONE fresh record of its step in
         ``last_step``: what the grid was sized to, what it carried, and what
@@ -558,12 +557,6 @@ class TestInferenceV2:
                 4, 1, 0, 2, 4 * 8, moe, kv_global_blocks=2, kv_context_tokens=21,
                 paged_programs=1,  # (blocks this small: four to a program)
                 ahead=False, ahead_rows_dropped=0)
-        elif entry == "decode_round":
-            assert len(engine.decode_round(3)[0]) == 3
-            # a round's 3 kernel calls a layer walk the round-start window
-            assert engine.last_step == StepStats(
-                4 * 3, 3, 0, 3 * 2, 3 * 4 * 8, kv_global_blocks=2, kv_context_tokens=23,
-                paged_programs=3)
         else:
             assert 1 <= len(engine.spec_round(2, drafts={0: [5]})[0]) <= 2
             # the pending token and one draft on a grid of R x (k + 1)
@@ -575,60 +568,10 @@ class TestInferenceV2:
         engine.scheduler.finish(0)
         assert engine.step_tokens() == {} and engine.last_step == StepStats()
 
-    @pytest.mark.parametrize("ds", [4, 8])
-    def test_fused_multistep_decode_matches_per_step(self, tiny_model, ds):
-        """decode_steps > 1 fuses ds greedy iterations into one device
-        program (argmax fed back in-device) — token-exact vs per-step greedy,
-        including a round count that doesn't divide max_new_tokens."""
-        cfg, params = tiny_model
-
-        def engine(ds_):
-            rc = RaggedInferenceEngineConfig.from_dict(
-                {
-                    "dtype": "float32",
-                    "decode_steps": ds_,
-                    "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 8},
-                    "state_manager": {"max_ragged_batch_size": 64, "max_ragged_sequence_count": 4},
-                }
-            )
-            return InferenceEngineV2(cfg, params, rc)
-
-        prompts = [np.arange(1, 9), np.arange(21, 33), np.arange(5, 10)]
-        refs = engine(1).generate(prompts, max_new_tokens=13)
-        outs = engine(ds).generate(prompts, max_new_tokens=13)
-        for o, r in zip(outs, refs):
-            np.testing.assert_array_equal(o, r)
-
-    def test_fused_decode_eos_truncation(self, tiny_model):
-        """A sequence hitting EOS mid-round is truncated and finished; the
-        others keep generating — outputs match the per-step EOS path."""
-        cfg, params = tiny_model
-
-        def engine(ds_):
-            rc = RaggedInferenceEngineConfig.from_dict(
-                {
-                    "dtype": "float32",
-                    "decode_steps": ds_,
-                    "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 8},
-                    "state_manager": {"max_ragged_batch_size": 64, "max_ragged_sequence_count": 4},
-                }
-            )
-            return InferenceEngineV2(cfg, params, rc)
-
-        prompts = [np.arange(1, 9), np.arange(21, 33)]
-        base = engine(1).generate(prompts, max_new_tokens=9)
-        # choose the 3rd generated token of seq 0 as the EOS id
-        eos = int(base[0][len(prompts[0]) + 2])
-        refs = engine(1).generate(prompts, max_new_tokens=9, eos_token_id=eos)
-        outs = engine(4).generate(prompts, max_new_tokens=9, eos_token_id=eos)
-        for o, r in zip(outs, refs):
-            np.testing.assert_array_equal(o, r)
-
-    @pytest.mark.parametrize("ds", [1, 4])
-    def test_windowed_model_serves_v2(self, tiny_model, ds):
+    def test_windowed_model_serves_v2(self, tiny_model):
         """A uniform sliding-window model (mistral-v0.1/starcoder2 class)
         serves through v2: paged attention applies the band, greedy output
-        matches the dense forward at both per-step and fused decode."""
+        matches the dense forward."""
         import dataclasses
 
         cfg, params = tiny_model
@@ -642,7 +585,6 @@ class TestInferenceV2:
         rc = RaggedInferenceEngineConfig.from_dict(
             {
                 "dtype": "float32",
-                "decode_steps": ds,
                 "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 8},
                 "state_manager": {"max_ragged_batch_size": 64, "max_ragged_sequence_count": 4},
             }
@@ -650,13 +592,6 @@ class TestInferenceV2:
         engine = InferenceEngineV2(wcfg, params, rc)
         out = engine.generate([prompt], max_new_tokens=6)[0]
         np.testing.assert_array_equal(np.asarray(out), np.asarray(toks))
-
-    def test_fused_decode_requires_prefill_done(self, tiny_model):
-        cfg, params = tiny_model
-        engine = self._engine(cfg, params)
-        engine.scheduler.submit(0, np.arange(1, 9))
-        with pytest.raises(RuntimeError, match="prompt chunks are still pending"):
-            engine.decode_round(4)
 
     def test_prompt_splitting_across_steps(self, tiny_model):
         """Prompt longer than the per-step token budget is split (SplitFuse)."""
@@ -769,3 +704,26 @@ def test_v1_fused_decode_overshoot_preserves_cache():
         params,
     ).generate(prompt, max_new_tokens=8)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_the_v2_engine_has_two_step_programs_and_no_decode_steps():
+    """One way to hide the host between two decode steps (one step in flight):
+    the step programs are the split step and the verify step, and nothing of
+    the engine, its config or the serving entry point takes ``decode_steps``."""
+    import inspect
+
+    from deepspeed_tpu.inference import cli
+    from deepspeed_tpu.inference.v2 import engine_v2
+    from deepspeed_tpu.serving import ServingDriver
+    from deepspeed_tpu.serving.cluster.core import EngineCore
+
+    assert sorted(engine_v2._BUILDERS) == ["split", "verify"]
+    assert all(hasattr(InferenceEngineV2, b) for b in engine_v2._BUILDERS.values())
+    assert list(inspect.signature(InferenceEngineV2.warm_trace).parameters) == [
+        "self", "spec_k", "uid"]
+    for owner in (ServingDriver, EngineCore):
+        assert "decode_steps" not in inspect.signature(owner.__init__).parameters
+    assert "decode_steps" not in RaggedInferenceEngineConfig().to_dict()
+    with pytest.raises(SystemExit):  # argparse: an unknown flag
+        cli.serve_parse_args(["--model", "", "--decode-steps", "4"])
+    assert not hasattr(cli.serve_parse_args(["--model", ""]), "decode_steps")

@@ -57,9 +57,9 @@ def _model(hf=HF, dtype="float32", seed=0):
     return cfg, jax.tree_util.tree_map_with_path(move, params)
 
 
-def _engine(cfg, params, dtype="float32", decode_steps=1, **extra):
+def _engine(cfg, params, dtype="float32", **extra):
     rc = {
-        "dtype": dtype, "decode_steps": decode_steps, "prompt_chunk": 160, "max_prompt_chunks": 2,
+        "dtype": dtype, "prompt_chunk": 160, "max_prompt_chunks": 2,
         "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 32},
         "state_manager": {"max_tracked_sequences": 6, "max_ragged_batch_size": 512,
                           "max_ragged_sequence_count": 4, "max_context": 512},
@@ -123,7 +123,7 @@ def test_engine_equals_the_reference_on_logits_float32(impl):
             want = _reference_logits(params, HF, p, served[uid])
             np.testing.assert_allclose(served[uid], want, atol=ATOL, rtol=0)
     assert eng.state_manager.state_slot_accounting() == {"total": 6, "free": 6, "live": 0}
-    assert eng.last_step.mamba_decode_rows >= 0 and eng.last_step.gdn_decode_rows == 0
+    assert eng.last_step.recurrent_decode_rows >= 0
 
 
 def test_a_long_run_of_mamba_layers_is_one_looped_body():
@@ -160,20 +160,23 @@ def test_slots_handed_on_serve_later_sequences_from_zero():
     assert eng.state_manager.state_slot_accounting()["live"] == 0
 
 
-def test_fused_round_and_generate_carry_the_state():
-    """``generate()`` with fused decode rounds (the state pools ride the
-    round's scan) gives the tokens of step-by-step decoding, and both agree
-    with the reference's greedy choice at every position."""
+@pytest.mark.parametrize("sampling", [{}, {"greedy": False, "temperature": 0.9, "seed": 7}],
+                         ids=["greedy", "sampled"])
+def test_generate_equals_the_driven_core_and_carries_the_state(sampling):
+    """``generate()`` is the served step (the state pools ride its carry from
+    one step to the next): the same prompts through the serving driver give
+    the same tokens, greedy and sampled, and the greedy ones agree with the
+    reference's choice at every position."""
+    from tests.unit.simple_model import served_tokens
+
     cfg, params = _model()
     rng = np.random.default_rng(2)
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in (5, 70, 200)]
-    with jax.default_matmul_precision("highest"):
-        by_step = _engine(cfg, params).generate(prompts, max_new_tokens=7)
-        fused_eng = _engine(cfg, params, decode_steps=3)
-        fused = fused_eng.generate(prompts, max_new_tokens=7)
-        assert ("round", 3) in fused_eng._programs
-        for a, b, p in zip(by_step, fused, prompts):
-            np.testing.assert_array_equal(a, b)
+    outs = _engine(cfg, params, **sampling).generate(prompts, max_new_tokens=7)
+    driven = served_tokens(_engine(cfg, params, **sampling), prompts, 7)
+    for a, got, p in zip(outs, driven, prompts):
+        assert [int(t) for t in a[len(p):]] == got
+        if not sampling:
             lg = np.asarray(ref.logits(params, a[:-1], HF))[len(p) - 1:]
             chosen = lg[np.arange(len(lg)), a[len(p):]]
             np.testing.assert_allclose(chosen, lg.max(-1), atol=ATOL)

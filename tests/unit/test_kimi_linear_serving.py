@@ -66,7 +66,7 @@ def model():
 
 def _engine(cfg, params, dtype="float32", **extra):
     rc = {
-        "dtype": dtype, "decode_steps": 1, "prompt_chunk": 160, "max_prompt_chunks": 2,
+        "dtype": dtype, "prompt_chunk": 160, "max_prompt_chunks": 2,
         "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 32},
         "state_manager": {"max_tracked_sequences": 6, "max_ragged_batch_size": 512,
                           "max_ragged_sequence_count": 4, "max_context": 512},
@@ -125,6 +125,24 @@ def _worst(eng, params, lens=(70,), seed=0, n_new=3):
                    for uid, p in enumerate(prompts))
 
 
+@pytest.mark.parametrize("sampling", [{}, {"greedy": False, "temperature": 0.9, "seed": 7}],
+                         ids=["greedy", "sampled"])
+def test_generate_equals_the_driven_core(model, engine, sampling):
+    """``generate()`` is the served step (KDA states beside the latent plane):
+    the same prompts through the serving driver, on the same engine once
+    ``generate()`` has left it idle, give the same tokens, greedy and sampled."""
+    from tests.unit.simple_model import served_tokens
+
+    eng = _engine(*model, **sampling) if sampling else engine
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in (5, 70)]
+    outs = eng.generate(prompts, max_new_tokens=6)
+    driven = served_tokens(eng, prompts, 6)
+    for p, out, got in zip(prompts, outs, driven):
+        assert [int(t) for t in out[len(p):]] == got
+    assert eng.state_manager.state_slot_accounting()["live"] == 0
+
+
 def test_forward_equals_the_reference(model):
     """``models.forward`` (every layer unrolled out of its own sub-stacks, the
     chunked rule, the expanded latent attention) against the reference's scan."""
@@ -153,8 +171,7 @@ def test_engine_equals_the_reference_on_logits_float32(model, engine):
     assert {k for k in engine._programs if k[0] == "split"} == {
         ("split", shape) for shape in [(0, 0), (1, 128), (1, 160), (2, 160)]}
     assert engine.state_manager.state_slot_accounting() == {"total": 6, "free": 6, "live": 0}
-    last = engine.last_step
-    assert last.kda_decode_rows >= 0 and last.gdn_decode_rows == last.mamba_decode_rows == 0
+    assert engine.last_step.recurrent_decode_rows >= 0
 
 
 def test_the_interpreted_kernels_serve_the_same(model, monkeypatch):
@@ -164,7 +181,7 @@ def test_the_interpreted_kernels_serve_the_same(model, monkeypatch):
     / the pool write at 4 heads with unrotated shared dims. The second prompt
     arrives after the first step, so the second step's TWO chunk rows are the
     first prompt's tail, continued from its slot's state, and a fresh row (one
-    chunk program for both steps); a step's ``kda_chunk_tokens`` are the live
+    chunk program for both steps); a step's ``recurrent_chunk_tokens`` are the live
     prompt tokens its chunk rows carried."""
     from deepspeed_tpu.ops.linear_attention import delta_chunk
 
@@ -188,8 +205,7 @@ def test_the_interpreted_kernels_serve_the_same(model, monkeypatch):
     assert steps[1].grid_slots == 4 + 2 * 160
     assert traced == [(2, 160)] * cfg.kind_count("kda")              # (chunk rows, tq) a KDA layer
     for st in steps:
-        assert st.kda_chunk_tokens == st.prefill_tokens
-        assert st.gdn_chunk_tokens == st.mamba_chunk_tokens == 0
+        assert st.recurrent_chunk_tokens == st.prefill_tokens
 
 
 def test_a_reused_slot_poisoned_with_nan_starts_from_zero(model, engine):
@@ -265,8 +281,6 @@ def test_what_a_recurrent_and_what_a_latent_model_are_refused_this_one_is_too(mo
                   {"kv_cache": {"host_tier_bytes": 1 << 20}}):
         with pytest.raises(NotImplementedError, match="Kimi Delta Attention layers keep a recurrent"):
             _engine(cfg, params, **extra)
-    with pytest.raises(NotImplementedError, match="latent-attention model .* decode_steps > 1"):
-        _engine(cfg, params, decode_steps=3)
     eng = _engine(cfg, params, kv_cache={"prefix_cache": True})
     assert eng.state_manager.prefix_cache is None   # switched off, with its log line
     with pytest.raises(NotImplementedError, match="Kimi Delta Attention layers keep"):
@@ -276,18 +290,20 @@ def test_what_a_recurrent_and_what_a_latent_model_are_refused_this_one_is_too(mo
 
 
 def test_the_engine_has_no_branch_on_the_kinds_name():
-    """``"kda"`` is an entry of ``RECURRENT``; outside ``_count_recurrent`` the
-    engine's source does not name it (nor its two wide projections' keys but in
-    the list of stacks read in place)."""
+    """``"kda"`` is an entry of ``RECURRENT``; neither the engine's source nor
+    the serving core's and the metrics' (a kind's counters are named from the
+    table) names it (nor its two wide projections' keys but in the list of
+    stacks read in place)."""
     import inspect
 
     from deepspeed_tpu.inference.v2 import engine_v2
+    from deepspeed_tpu.serving import metrics
+    from deepspeed_tpu.serving.cluster import core
 
     assert "kda" in T.RECURRENT
-    src = inspect.getsource(engine_v2)
-    count = inspect.getsource(engine_v2.InferenceEngineV2._count_recurrent)
-    rest = src.replace(count, "")
-    assert '"kda"' not in rest and "'kda'" not in rest
+    for module in (engine_v2, core, metrics):
+        src = inspect.getsource(module)
+        assert '"kda"' not in src and "'kda'" not in src and "kda_decode_rows" not in src
 
 
 # --- one share test: the shares add up to the uncut layer ---------------------

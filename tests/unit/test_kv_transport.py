@@ -173,14 +173,14 @@ class TestDeviceWire:
 
         src = _real_engine(tiny_model, "bf16")
         tgt = _real_engine(tiny_model, "bf16")
-        base_src = src.warm_trace(decode_steps=2)
-        base_tgt = tgt.warm_trace(decode_steps=2)
+        base_src = src.warm_trace()
+        base_tgt = tgt.warm_trace()
         tok = _prefill_one(src, 13, np.arange(1, 25, dtype=np.int32))
         ho = export_sequence(src, 13, tok, transport="device")
         src.scheduler.finish(13)
         import_sequence(tgt, ho)
-        for _ in range(2):
-            tgt.decode_round(2)
+        for _ in range(4):
+            tgt.scheduler.feedback(13, tgt.step_tokens()[13])
         assert_no_new_traces(src, base_src, label="device-wire exporter")
         assert_no_new_traces(tgt, base_tgt, label="device-wire importer")
         tgt.scheduler.finish(13)

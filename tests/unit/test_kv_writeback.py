@@ -205,11 +205,12 @@ def test_pool_holds_dense_kv_and_only_scheduled_slots_change(case, devices8):
             np.testing.assert_allclose(rows[u][1], ref_v, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("program", ["round", "verify"])
-def test_round_and_verify_write_dense_kv(program, devices8):
-    """The fused decode round and the speculative verify step write through
-    the same write-back: after one call the live slots of every sequence
-    hold the dense forward's K/V, and the tokens are generate()'s."""
+@pytest.mark.parametrize("program", ["ahead", "verify"])
+def test_steps_ahead_and_verify_write_dense_kv(program, devices8):
+    """Decode steps launched one ahead (a row's token read on the device,
+    never through the host) and the speculative verify step write through the
+    same write-back: the live slots of every sequence hold the dense forward's
+    K/V, and the tokens are generate()'s."""
     cfg = get_config("tiny", n_layers=2, dtype="float32", max_seq_len=512)
     params = init_params(cfg, jax.random.key(0))
     prompts = _prompts(cfg.vocab_size)
@@ -223,8 +224,16 @@ def test_round_and_verify_write_dense_kv(program, devices8):
         for u, tok in eng.step_tokens().items():
             streams[u].append(int(tok))
             eng.scheduler.feedback(u, int(tok))
-    if program == "round":
-        res = eng.decode_round(3)
+    if program == "ahead":
+        res = {u: [] for u in prompts}
+        flights = [eng.launch_ahead(None, None)]
+        for more in (True, True, False):
+            if more:
+                flights.append(eng.launch_ahead(flights[-1], lambda u: True))
+            for u, tok in eng.collect_step(flights.pop(0)).items():
+                res[u].append(tok)
+                eng.scheduler.feedback(u, tok)
+        assert all(len(toks) == 3 for toks in res.values())
     else:
         # the right next tokens for row 0, wrong ones for row 1, none for row 2
         n0 = len(streams[0])

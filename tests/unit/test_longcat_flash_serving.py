@@ -558,10 +558,9 @@ def test_config_from_hf_refuses_what_it_cannot_compute(change, match):
     ({"kv_cache": {"kv_cache_dtype": "int8"}}, "int8 pool's scale planes"),
     ({"kv_cache": {"host_tier_bytes": 1 << 20, "prefix_cache": True}}, "host block tier"),
     ({"spec_k": 2}, "speculative"),
-    ({"decode_steps": 4}, "decode_steps > 1"),
     ({"quant": {"enabled": True, "bits": 8}}, "quantized weights"),
     ({"tp_size": 2}, "tp_size=2"),
-], ids=["int8_pool", "host_tier", "speculative", "fused_round", "quantized_weights", "tp"])
+], ids=["int8_pool", "host_tier", "speculative", "quantized_weights", "tp"])
 def test_what_cannot_carry_the_planes_refuses_at_build(extra, match):
     cfg, params = _model()
     with pytest.raises(NotImplementedError, match=match):

@@ -163,14 +163,18 @@ def test_engine_equals_the_reference_through_the_interpreted_kernels():
     assert _gap(params, hf, prompts, got, toks) < 5 * TOL  # longer sums
 
 
-def test_fused_rounds_and_generate_go_through_both_pools():
-    """``generate()`` with ``decode_steps`` 4: fused rounds read the pools as
-    the round found them and write their tokens' ring blocks after it, each
-    pool's side buffers at its geometry."""
+def test_generate_equals_the_driven_core_through_both_pools():
+    """``generate()`` is the served step: the same prompts through the serving
+    driver give the same tokens, and over 50 tokens (a ring wraps, each pool's
+    side buffers at its geometry) each is the reference's best."""
+    from tests.unit.simple_model import served_tokens
+
     cfg, params = _model()
     prompts = _prompts((5, 70, 100))
-    outs = _engine(cfg, params, decode_steps=4).generate(prompts, max_new_tokens=50)
-    for p, out in zip(prompts, outs):
+    outs = _engine(cfg, params).generate(prompts, max_new_tokens=50)
+    driven = served_tokens(_engine(cfg, params), prompts, 50)
+    for p, out, got in zip(prompts, outs, driven):
+        assert [int(t) for t in out[len(p):]] == got
         want = np.asarray(ref.logits(params, out, HF))
         served = out[len(p):]
         best = want[len(p) - 1: -1]

@@ -97,7 +97,7 @@ class _RecordingOwner:
 
 def _meta(**over):
     meta = {
-        "tp_shards": 1, "decode_steps": 1, "kv_headroom": 0.0,
+        "tp_shards": 1, "kv_headroom": 0.0,
         "kv": {"num_blocks": 16, "block_size": 4, "max_blocks_per_seq": 8},
         "sm": {"max_tracked_sequences": 4, "max_context": 128},
         "kv_info": {}, "free_blocks": 16, "prefix": [], "stats": {},

@@ -620,7 +620,7 @@ class TestServingConservation:
 # ---------------------------------------------------------------------------
 # output parity: cache on vs off must be bit-identical (acceptance bar)
 # ---------------------------------------------------------------------------
-def _tiny_engine(prefix_cache, greedy, seed=7, decode_steps=1):
+def _tiny_engine(prefix_cache, greedy, seed=7):
     import jax
 
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
@@ -632,7 +632,6 @@ def _tiny_engine(prefix_cache, greedy, seed=7, decode_steps=1):
     rc = RaggedInferenceEngineConfig.from_dict({
         "dtype": "float32",
         "greedy": greedy, "temperature": 0.9, "seed": seed,
-        "decode_steps": decode_steps,
         "kv_cache": {"block_size": 4, "num_blocks": 128,
                      "max_blocks_per_seq": 32, "prefix_cache": prefix_cache},
         "state_manager": {"max_tracked_sequences": 16,
@@ -682,13 +681,17 @@ class TestOutputParity:
         for a, b in zip(off, on):
             np.testing.assert_array_equal(a, b)
 
-    def test_sampled_parity_across_decode_steps(self):
-        """The fused decode round and the per-step path sample identical
-        streams, cache on or off (decode_steps must not change outputs)."""
+    def test_sampled_parity_with_the_serving_driver(self):
+        """``generate()`` and the serving driver sample identical streams
+        over a warm cache: one step in flight on both, a hit on both."""
+        from tests.unit.simple_model import served_tokens
+
         prompts = _parity_prompts()
-        ref = _two_wave_generate(_tiny_engine(True, greedy=False, decode_steps=1),
-                                 prompts)
-        fused = _two_wave_generate(_tiny_engine(True, greedy=False, decode_steps=4),
-                                   prompts)
-        for a, b in zip(ref, fused):
-            np.testing.assert_array_equal(a, b)
+        engines = [_tiny_engine(True, greedy=False) for _ in range(2)]
+        for eng in engines:  # warm: the shared system prompt is cached
+            eng.generate([list(prompts[0])], max_new_tokens=10)
+        outs = engines[0].generate([list(p) for p in prompts[1:]], max_new_tokens=10)
+        driven = served_tokens(engines[1], [np.asarray(p, np.int32) for p in prompts[1:]], 10)
+        assert all(eng.prefix_cache.stats()["hits"] >= 1 for eng in engines)
+        for p, out, got in zip(prompts[1:], outs, driven):
+            assert [int(t) for t in out[len(p):]] == got

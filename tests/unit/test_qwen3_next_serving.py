@@ -56,9 +56,9 @@ def _model(hf=HF, dtype="float32", seed=0):
     return cfg, jax.tree_util.tree_map_with_path(move, params)
 
 
-def _engine(cfg, params, dtype="float32", decode_steps=1, **extra):
+def _engine(cfg, params, dtype="float32", **extra):
     rc = {
-        "dtype": dtype, "decode_steps": decode_steps, "prompt_chunk": 160, "max_prompt_chunks": 2,
+        "dtype": dtype, "prompt_chunk": 160, "max_prompt_chunks": 2,
         "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 32},
         "state_manager": {"max_tracked_sequences": 6, "max_ragged_batch_size": 512,
                           "max_ragged_sequence_count": 4, "max_context": 512},
@@ -111,7 +111,7 @@ def test_engine_equals_the_reference_on_logits_float32(gdn_impl, monkeypatch):
     Pallas kernels ``dstpu_gdn_decode`` and ``dstpu_gdn_chunk`` themselves on
     the state pool. Then two more prompts, the second arriving after the first
     step: that step's TWO chunk rows are the first prompt's tail, continued from
-    its slot's state, and a fresh row; a step's ``gdn_chunk_tokens`` are the
+    its slot's state, and a fresh row; a step's ``recurrent_chunk_tokens`` are the
     live prompt tokens its chunk rows carried."""
     from deepspeed_tpu.ops.linear_attention import delta_chunk
 
@@ -134,8 +134,7 @@ def test_engine_equals_the_reference_on_logits_float32(gdn_impl, monkeypatch):
         # (chunk rows, tq) of the programs that went through the chunk kernel
         assert traced == ({(1, 128), (1, 160), (2, 160)} if gdn_impl == "interpret" else set())
         for st in steps:
-            assert st.gdn_chunk_tokens == st.prefill_tokens
-            assert st.kda_chunk_tokens == st.mamba_chunk_tokens == 0
+            assert st.recurrent_chunk_tokens == st.prefill_tokens
         for uid, p in enumerate(more):
             np.testing.assert_allclose(
                 later[uid], _reference_logits(params, HF, p, later[uid]), atol=5e-5, rtol=0)
@@ -234,20 +233,23 @@ def test_a_state_lost_between_two_steps_fails_the_comparison():
     assert np.abs(second - want[1]).max() > 0.5
 
 
-def test_fused_round_and_generate_carry_the_state():
-    """``generate()`` with fused decode rounds (the state pools ride the
-    round's scan) gives the tokens of step-by-step decoding, and both agree
-    with the reference's greedy choice at every position."""
+@pytest.mark.parametrize("sampling", [{}, {"greedy": False, "temperature": 0.9, "seed": 7}],
+                         ids=["greedy", "sampled"])
+def test_generate_equals_the_driven_core_and_carries_the_state(sampling):
+    """``generate()`` is the served step (the state pools ride its carry from
+    one step to the next): the same prompts through the serving driver give
+    the same tokens, greedy and sampled, and the greedy ones agree with the
+    reference's choice at every position."""
+    from tests.unit.simple_model import served_tokens
+
     cfg, params = _model()
     rng = np.random.default_rng(2)
     prompts = [rng.integers(1, 256, size=n).astype(np.int32) for n in (5, 70, 200)]
-    with jax.default_matmul_precision("highest"):
-        by_step = _engine(cfg, params).generate(prompts, max_new_tokens=7)
-        fused_eng = _engine(cfg, params, decode_steps=3)
-        fused = fused_eng.generate(prompts, max_new_tokens=7)
-        assert ("round", 3) in fused_eng._programs
-        for a, b, p in zip(by_step, fused, prompts):
-            np.testing.assert_array_equal(a, b)
+    outs = _engine(cfg, params, **sampling).generate(prompts, max_new_tokens=7)
+    driven = served_tokens(_engine(cfg, params, **sampling), prompts, 7)
+    for a, got, p in zip(outs, driven, prompts):
+        assert [int(t) for t in a[len(p):]] == got
+        if not sampling:
             lg = np.asarray(ref.logits(params, a[:-1], HF))[len(p) - 1:]
             chosen = lg[np.arange(len(lg)), a[len(p):]]
             np.testing.assert_allclose(chosen, lg.max(-1), atol=5e-5)

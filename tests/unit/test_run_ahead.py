@@ -270,11 +270,11 @@ def test_an_engine_failure_with_a_step_in_flight_fails_the_active_set_once(toy, 
     assert c["requests_failed_total"] == 2
 
 
-@pytest.mark.parametrize("kind", ["spec_k", "decode_steps", "prefill_role"])
+@pytest.mark.parametrize("kind", ["spec_k", "prefill_role"])
 def test_a_core_that_cannot_run_ahead_never_does(toy, kind):
-    """(f): a speculative controller or fused rounds take a row's token from
-    the host, and a prefill worker hands K/V off after its step: such a core
-    collects where it launches, through the same two primitives."""
+    """(f): a speculative controller takes a row's token from the host, and
+    a prefill worker hands K/V off after its step: such a core collects where
+    it launches, through the same two primitives."""
     name, make = toy
     work = _work([(0, 5, 6), (0, 70, 9), (2, 33, 5)])
     want = _sync_reference(make(), work)
@@ -282,16 +282,12 @@ def test_a_core_that_cannot_run_ahead_never_does(toy, kind):
         core = EngineCore(make(), role="prefill")
         assert not core._runs_ahead() and EngineCore(make(), role="decode")._runs_ahead()
         return
-    if kind == "spec_k":
-        if name != "dense":
-            # spec_round is refused for a second kind of cache: the core that
-            # would drive it still collects where it launches
-            assert not EngineCore(make(), spec_k=2)._runs_ahead()
-            return
-        engine, kw = make(spec_k=2), {"spec_k": 2}
-    else:
-        engine, kw = make(decode_steps=3), {"decode_steps": 3}
-    driver = ServingDriver(engine, **kw)
+    if name != "dense":
+        # spec_round is refused for a second kind of cache: the core that
+        # would drive it still collects where it launches
+        assert not EngineCore(make(), spec_k=2)._runs_ahead()
+        return
+    driver = ServingDriver(make(spec_k=2), spec_k=2)
     reqs = _serve(driver, work)
     for uid, req in enumerate(reqs):
         assert req.generated == want[uid], f"uid {uid}"

@@ -3,7 +3,7 @@
 Reference semantics: v1 guard-railed generate (reference
 inference/engine.py:585) + FastGen/MII sampled decoding on top of v2
 logits. Covers the shared sampler's filters and distribution, v1/v2
-agreement, per-sequence EOS under fused rounds, and logprobs."""
+agreement, per-sequence EOS with a step in flight, and logprobs."""
 
 import numpy as np
 import pytest
@@ -82,7 +82,7 @@ class TestSampleTokens:
             np.testing.assert_allclose(got[r], want[r, int(toks[r])], rtol=1e-5)
 
 
-def _make_v2(greedy=True, temperature=1.0, top_k=0, top_p=0.0, seed=0, decode_steps=4):
+def _make_v2(greedy=True, temperature=1.0, top_k=0, top_p=0.0, seed=0):
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
     from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models import TransformerConfig, init_params
@@ -93,7 +93,7 @@ def _make_v2(greedy=True, temperature=1.0, top_k=0, top_p=0.0, seed=0, decode_st
     )
     params = init_params(mc, jax.random.key(11))
     rc = RaggedInferenceEngineConfig.from_dict({
-        "dtype": "float32", "decode_steps": decode_steps,
+        "dtype": "float32",
         "greedy": greedy, "temperature": temperature, "top_k": top_k,
         "top_p": top_p, "seed": seed,
         "kv_cache": {"block_size": 16, "num_blocks": 64, "max_blocks_per_seq": 8},
@@ -120,20 +120,33 @@ class TestV2Sampling:
         b = _make_v2(greedy=True, seed=9).generate([p.copy() for p in prompts], max_new_tokens=6)
         np.testing.assert_array_equal(a[0], b[0])  # greedy ignores the seed
 
-    def test_round_logprobs_exposed(self):
-        eng = _make_v2(greedy=False, temperature=0.9, decode_steps=4)
+    def test_verify_step_logprobs_exposed(self):
+        """The verify step returns its emitted tokens' log-probabilities
+        (``last_logprobs``); the streams it continues are ``generate()``'s."""
         prompts = [np.arange(1, 9, dtype=np.int32), np.arange(30, 38, dtype=np.int32)]
-        eng.generate([p.copy() for p in prompts], max_new_tokens=8)
-        assert eng.last_logprobs and all(
-            lp.shape == (4,) and np.isfinite(lp).all()
-            for lp in eng.last_logprobs.values()
-        )
+        want = _make_v2(greedy=False, temperature=0.9).generate(
+            [p.copy() for p in prompts], max_new_tokens=8)
+        eng = _make_v2(greedy=False, temperature=0.9)
+        for uid, p in enumerate(prompts):
+            eng.scheduler.submit(uid, p)
+        at = {0: 8, 1: 8}  # the position of a row's next token
+        while eng.scheduler.has_pending():
+            for uid, tok in eng.step_tokens().items():
+                assert tok == want[uid][at[uid]]
+                at[uid] += 1
+                eng.scheduler.feedback(uid, tok)
+        # row 0 drafts its own next two tokens, row 1 nothing
+        res = eng.spec_round(3, drafts={0: [int(t) for t in want[0][at[0]:at[0] + 2]]})
+        assert [int(t) for t in res[0]] == [int(t) for t in want[0][at[0]:at[0] + 3]]
+        assert [int(t) for t in res[1]] == [int(want[1][at[1]])]
+        assert {u: lp.shape for u, lp in eng.last_logprobs.items()} == {0: (3,), 1: (1,)}
+        assert all(np.isfinite(lp).all() and (lp <= 0).all() for lp in eng.last_logprobs.values())
 
     def test_mixed_eos_lengths(self):
-        """Per-sequence EOS under fused rounds: rows stop at their own
+        """Per-sequence EOS with a step in flight: rows stop at their own
         lengths. Probe the greedy streams first, then pick an eos id that
         one row emits early and the other never emits."""
-        probe = _make_v2(greedy=True, decode_steps=4)
+        probe = _make_v2(greedy=True)
         prompts = [np.arange(1, 9, dtype=np.int32), np.arange(40, 48, dtype=np.int32)]
         outs = probe.generate([p.copy() for p in prompts], max_new_tokens=8)
         gen0 = list(outs[0][8:])
@@ -142,7 +155,7 @@ class TestV2Sampling:
         eos = next((t for t in gen0[:3] if t not in gen1), None)
         if eos is None:
             pytest.skip("probe streams overlap; cannot construct a clean eos")
-        eng = _make_v2(greedy=True, decode_steps=4)
+        eng = _make_v2(greedy=True)
         outs2 = eng.generate([p.copy() for p in prompts], max_new_tokens=8,
                              eos_token_id=int(eos))
         g0, g1 = list(outs2[0][8:]), list(outs2[1][8:])

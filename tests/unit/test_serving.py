@@ -19,6 +19,7 @@ import pytest
 from deepspeed_tpu.inference.config import KVCacheConfig, StateManagerConfig
 from deepspeed_tpu.inference.v2.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.scheduler import RaggedScheduler
+from deepspeed_tpu.models.transformer import RECURRENT
 from deepspeed_tpu.serving.driver import RequestRejected, ServingDriver
 from deepspeed_tpu.serving.metrics import Histogram, ServingMetrics
 from deepspeed_tpu.serving.request import Request, RequestState, SamplingParams
@@ -75,6 +76,31 @@ class FakeEngine:
             if not chunked:  # decode row or final prompt chunk: token ready
                 out[uid] = (int(toks[-1]) + 1) % self.vocab
         return out
+
+
+@pytest.mark.parametrize("kind", sorted(RECURRENT))
+def test_a_recurrent_kinds_counters_are_named_for_it(kind):
+    """``T.RECURRENT`` is the one description of a recurrent kind, its
+    counters included: every kind's pair is exported at zero, and a step's
+    ``recurrent_*`` pair goes to the pair of the engine's kind alone."""
+    from deepspeed_tpu.inference.v2.engine_v2 import StepStats
+    from deepspeed_tpu.serving.cluster.core import EngineCore
+
+    metrics = ServingMetrics()
+    names = {k: (f"{k}_decode_rows_total", f"{k}_chunk_tokens_total") for k in RECURRENT}
+    assert all(metrics.counters[n] == 0 for pair in names.values() for n in pair)
+    eng = FakeEngine()
+    eng._mc = SimpleNamespace(recurrent_kind=kind)
+    core = EngineCore(eng, metrics=metrics)
+    core._count_step(StepStats(recurrent_decode_rows=3, recurrent_chunk_tokens=20))
+    core._count_step(StepStats(recurrent_decode_rows=4))
+    for k, pair in names.items():
+        assert [metrics.counters[n] for n in pair] == ([7, 20] if k == kind else [0, 0])
+    assert f"{kind}_decode_rows_total 7" in metrics.prometheus_text()
+    # a model without recurrent layers moves none of them
+    plain = ServingMetrics()
+    EngineCore(FakeEngine(), metrics=plain)._count_step(StepStats(scheduled_tokens=5))
+    assert all(plain.counters[n] == 0 for pair in names.values() for n in pair)
 
 
 def _expected_tokens(prompt, n):

@@ -43,7 +43,7 @@ def tiny_model():
     return cfg, init_params(cfg, jax.random.key(0))
 
 
-def _engine(tiny_model, decode_steps=1):
+def _engine(tiny_model):
     """R = 4 decode slots and one prompt chunk of at most 512 tokens a step:
     the split step's grid is 4 + 128 or 4 + 512 slots."""
     from deepspeed_tpu.inference.config import RaggedInferenceEngineConfig
@@ -52,7 +52,6 @@ def _engine(tiny_model, decode_steps=1):
     cfg, params = tiny_model
     rc = RaggedInferenceEngineConfig.from_dict({
         "dtype": "float32",
-        "decode_steps": decode_steps,
         "kv_cache": {"block_size": 16, "num_blocks": 96, "max_blocks_per_seq": 32},
         "state_manager": {"max_tracked_sequences": 8, "max_ragged_batch_size": 512,
                           "max_ragged_sequence_count": 4, "max_context": 512},
@@ -60,11 +59,11 @@ def _engine(tiny_model, decode_steps=1):
     return InferenceEngineV2(cfg, params, rc)
 
 
-def _serve(engine, prompts_and_new, **driver_kw):
+def _serve(engine, prompts_and_new):
     """Queue every request BEFORE the loop starts, so that the loop admits
     them all in its first pass and the steps that follow are the same in
     every run. Returns the driver (stopped) and the finished requests."""
-    driver = ServingDriver(engine, **driver_kw)
+    driver = ServingDriver(engine)
     reqs = [driver.submit(np.arange(1, n + 1, dtype=np.int32) + 7 * i,
                           params=SamplingParams(max_new_tokens=new, ignore_eos=True))
             for i, (n, new) in enumerate(prompts_and_new)]
@@ -152,22 +151,6 @@ class TestServingSpans:
         assert tracer.stats()["dropped_spans"] == 0
         assert tracer.stats()["ring_evicted_spans"] == 0
 
-    def test_fused_round_has_the_same_phases(self, tiny_model):
-        tracer = set_tracer(SpanTracer())
-        _serve(_engine(tiny_model, decode_steps=4), [(20, 9)], decode_steps=4)
-        ring = tracer.ring_spans()
-        rounds = [sp for sp in ring if sp.name == "round.fused"]
-        assert rounds
-        for name in ("engine.stage", "engine.launch", "engine.dispatch",
-                     "engine.device_wait", "engine.materialize"):
-            assert any(_inside(sp, r) for r in rounds for sp in ring if sp.name == name), name
-        # the prompt's step, then the rounds: each is delivered once
-        assert [sp.name for sp in sorted(ring, key=lambda s: s.t0)
-                if sp.name in ("round.fused",) + DEVICE_SPANS] == (
-                    ["step.chunk"] + ["round.fused"] * len(rounds))
-        assert sum(sp.name == "step.deliver" for sp in ring) == 1 + len(rounds)
-        _assert_overlap_only_by_nesting([sp for sp in ring if sp.name not in DEVICE_SPANS])
-
     def test_ring_overflow_is_counted(self):
         tracer = SpanTracer(max_events=256)
         for _ in range(260):
@@ -196,35 +179,16 @@ class TestGridCounters:
         text = driver.metrics.prometheus_text()
         assert "grid_slots_total 652" in text and "steps_with_prefill_total 2" in text
 
-    def test_fused_round_counts_rows_times_steps(self, tiny_model):
-        driver, _ = _serve(_engine(tiny_model, decode_steps=4), [(20, 9)], decode_steps=4)
-        c = driver.metrics.counters
-        # one prefill step (first token), then two fused rounds of 4 tokens
-        # for the one running row on a grid of R x steps = 16 slots each
-        assert c["engine_steps_total"] == 3
-        assert c["grid_slots_total"] == (4 + 128) + 16 + 16
-        assert c["scheduled_tokens_total"] == 20 + 4 + 4
-        assert c["steps_with_prefill_total"] == 1
-
-
-    @pytest.mark.parametrize("decode_steps,work,live,slots,programs", [
+    def test_paged_counters_are_live_blocks_over_table_slots(self, tiny_model):
+        """``ceil(pool tokens / block size)`` summed over the decode rows
+        against ``R x B``, for one layer's decode attention calls, and the
+        kernel's programs that read those blocks."""
         # the three steps of the first test. Decode rows: none; one whose pool
         # holds 200 tokens = 13 blocks of 16; that row at 201 (13) and one at
         # 20 (2). Every step's tables have R x B = 4 x 32 slots. Blocks this
         # small go four to a program: 13 blocks are 4 programs, 2 are one
-        (1, [(200, 3), (20, 2)], 0 + 13 + (13 + 2), 3 * 128, 0 + 4 + (4 + 1)),
-        # a prefill step, then two fused rounds of 4 steps: the row's pool
-        # window stays at the round's start (20, then 24 tokens: 2 blocks),
-        # and each of a round's 4 kernel calls a layer walks it again
-        (4, [(20, 9)], 0 + 4 * 2 + 4 * 2, 128 + 2 * 4 * 128, 0 + 4 * 1 + 4 * 1),
-    ])
-    def test_paged_counters_are_live_blocks_over_table_slots(
-            self, tiny_model, decode_steps, work, live, slots, programs):
-        """``ceil(pool tokens / block size)`` summed over the decode rows
-        against ``R x B``, for one layer's decode attention calls, and the
-        kernel's programs that read those blocks."""
-        driver, _ = _serve(_engine(tiny_model, decode_steps=decode_steps), work,
-                           **({"decode_steps": decode_steps} if decode_steps > 1 else {}))
+        live, slots, programs = 0 + 13 + (13 + 2), 3 * 128, 0 + 4 + (4 + 1)
+        driver, _ = _serve(_engine(tiny_model), [(200, 3), (20, 2)])
         c = driver.metrics.counters
         assert c["paged_live_blocks_total"] == live
         assert c["paged_table_slots_total"] == slots

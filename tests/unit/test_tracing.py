@@ -111,13 +111,13 @@ class TestSpanTracer:
 
     def test_ring_and_instant_and_ctx_manager(self):
         tr = SpanTracer()
-        with tr.span("round.fused", track="d0", args={"rows": 3}) as sp:
+        with tr.span("round.verify", track="d0", args={"rows": 3}) as sp:
             pass
         assert sp.t1 is not None
         mark = tr.instant("host_tier.spill", track="d0", args={"block": 5})
         assert mark.t0 == mark.t1
         ring = tr.ring_spans()
-        assert [s.name for s in ring] == ["round.fused", "host_tier.spill"]
+        assert [s.name for s in ring] == ["round.verify", "host_tier.spill"]
         assert all(s.track == "d0" for s in ring)
 
     def test_ring_bounded_and_min_clamp(self):
@@ -239,7 +239,7 @@ class TestChromeExport:
         tr.end(tr.start(3, "queued", t0=1.0), t1=1.1)
         tr.end(root, t1=2.0)
         tr.end_trace(3)
-        tr.complete("round.fused", 1.2, 1.3, track="d0", args={"rows": 2})
+        tr.complete("round.verify", 1.2, 1.3, track="d0", args={"rows": 2})
         return tr
 
     def test_export_layout_and_validation(self):
