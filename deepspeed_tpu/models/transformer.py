@@ -45,6 +45,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.accelerator.device import on_tpu
 from deepspeed_tpu.ops.attention import attention as attention_op
+from deepspeed_tpu.ops.attention import head_major, heads_flat, heads_view, token_major, tokens_view
 from deepspeed_tpu.parallel.topology import (
     BATCH_AXES,
     CONTEXT_AXIS,
@@ -1599,8 +1600,16 @@ def _rope(
     c: "TransformerConfig",
     seq_len: Optional[Any] = None,
     theta: Optional[float] = None,
+    head_axis: int = 1,
 ) -> jax.Array:
-    """Rotary embedding on [b, h, s, d] given positions [b, s] or [s].
+    """Rotary embedding on [b, h, s, d] given positions [b, s] or [s]
+    (head-major: ``head_axis`` 1, what the serving steps and the v1 decode
+    pass), or on x with its tokens laid as ``positions`` lays them and the
+    heads at ``head_axis``: [b, s, h, d] with [b, s], or the tiles' view
+    [b, s / 8, h, 8, d] with [b, s / 8, 8] (``ops.attention.heads_view``,
+    ``head_axis`` 2: where the projections wrote it). cos and sin are a
+    position's, broadcast over the head axis where it is: the same float32
+    arithmetic on the same numbers whichever.
     ``theta``: the base where the layer's kind has its own (``rope_theta_of``).
 
     rope_frac < 1 (phi partial rotary, HF partial_rotary_factor): only the
@@ -1621,8 +1630,9 @@ def _rope(
     angles = positions[..., None].astype(jnp.float32) * freqs  # [b, s, rot/2]
     # attn_factor scales cos/sin directly (HF convention: yarn/longrope
     # "attention_scaling" multiplies the embedding, hence scores by factor²)
-    cos = jnp.cos(angles)[:, None] * attn_factor  # [b, 1, s, rot/2]
-    sin = jnp.sin(angles)[:, None] * attn_factor
+    over_heads = (slice(None),) * head_axis + (None,)
+    cos = jnp.cos(angles)[over_heads] * attn_factor  # [b, 1, s, rot/2] head-major
+    sin = jnp.sin(angles)[over_heads] * attn_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     out = out.astype(tail.dtype if tail is not None else x.dtype)
@@ -1877,25 +1887,40 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
     if qk_norm_full(c):
         q = qk_norm_apply(c, q, lp["q_norm"], head_axis=-1)
         k = qk_norm_apply(c, k, lp["k_norm"], head_axis=-1)
-    q = q.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
-    k = k.reshape(b, s, nkv, d).transpose(0, 2, 1, 3)
-    v = v.reshape(b, s, nkv, d).transpose(0, 2, 1, 3)
+    # q and k a head at a time WHERE THE PROJECTIONS WROTE THEM (heads_view:
+    # [b, s / 8, heads, 8, d], the same bytes as [b, s, heads * d]): the norm
+    # and rope are a token's and a head's own, and the flash kernels index a
+    # head's lanes out of [b, s, heads * d] in place (attention_op, rank 3).
+    # A path that wants [b, heads, s, d] transposes where it is chosen, below.
+    # Pinned as the products wrote them: unpinned, the chip's compiler folds the
+    # norm's and rope's gradients into the operands of BOTH backward products
+    # of wq and wk and runs them twice at the matrix unit's pace (17,570-17,617
+    # tokens/s against 17,864-17,869 pinned: my chip runs, PR 59).
+    q, k = as_written((q, k))
+    q, k = heads_view(q, nh), heads_view(k, nkv)
     if c.qk_norm and not qk_norm_full(c):
         # qwen3 rmsnorm / phi affine layernorm / stablelm-2 per-head, pre-rope
-        q = qk_norm_apply(c, q, lp["q_norm"], head_axis=1, b=lp.get("q_norm_b"))
-        k = qk_norm_apply(c, k, lp["k_norm"], head_axis=1, b=lp.get("k_norm_b"))
+        q = qk_norm_apply(c, q, lp["q_norm"], head_axis=2, b=lp.get("q_norm_b"))
+        k = qk_norm_apply(c, k, lp["k_norm"], head_axis=2, b=lp.get("k_norm_b"))
     if c.position == "rope":
         # seq len: the LIVE sequence length (HF's max(position_ids)+1) — in
         # decode that is cache fill + this block, traced; else the static s
         seq_len = kv_cache[2] + s if kv_cache is not None else s
-        q_r, k_r = _rope(q, positions, c, seq_len), _rope(k, positions, c, seq_len)
+        at = tokens_view(positions if positions.ndim > 1 else positions[None], s)
+        q_r = _rope(q, at, c, seq_len, head_axis=2)
+        k_r = _rope(k, at, c, seq_len, head_axis=2)
         if c.rope_window_only:  # a global layer (flag 0) attends with no position term
             q_r, k_r = jnp.where(local_flag > 0, q_r, q), jnp.where(local_flag > 0, k_r, k)
         q, k = q_r, k_r
 
+    def heads_major(*xs):  # the paths that take [b, heads, s, d]
+        return tuple(head_major(x, d) for x in xs)
+
+    q, k = heads_flat(q), heads_flat(k)
     new_cache = None
     if kv_cache is not None:
         # decode: append to cache along seq
+        q, k, v = heads_major(q, k, v)
         ck, cv, clen = kv_cache  # [b, nkv, S, d], [b, nkv, S, d], scalar
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k, clen, axis=2)
         cv = jax.lax.dynamic_update_slice_in_dim(cv, v, clen, axis=2)
@@ -1912,7 +1937,7 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
         bias = mask_bias[None, None]
         if c.position == "alibi":
             bias = bias + _alibi_bias(c, kpos)
-        out = attention_op(q, k, v, causal=False, bias=bias, scale=c.attn_scale)
+        out = token_major(attention_op(q, k, v, causal=False, bias=bias, scale=c.attn_scale))
     else:
         topo = get_topology()
         impl = c.attention_impl
@@ -1938,12 +1963,12 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
                     "sliding_window under ring context parallelism is not "
                     "supported (band masks are global-position)"
                 )
-            out = attention_op(
-                q, k, v, causal=True, segment_ids=segment_ids,
+            out = token_major(attention_op(
+                *heads_major(q, k, v), causal=True, segment_ids=segment_ids,
                 scale=c.attn_scale, impl="flash_ring",
                 alibi_slopes=(jnp.asarray(alibi_slopes(nh))
                               if c.position == "alibi" else None),
-            )
+            ))
         elif topo.sequence_parallel_size > 1:
             if c.position == "alibi":
                 raise NotImplementedError(
@@ -1959,26 +1984,26 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
                 from deepspeed_tpu.parallel.sequence import ring_attention
 
                 # window masks over GLOBAL positions inside the ring loop
-                out = ring_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids,
+                out = token_major(ring_attention(
+                    *heads_major(q, k, v), causal=True, segment_ids=segment_ids,
                     scale=c.attn_scale, window=c.sliding_window,
                     window_flag=local_flag,
-                )
+                ))
             else:
                 from deepspeed_tpu.parallel.sequence import ulysses_attention
 
-                out = ulysses_attention(
-                    q, k, v, causal=True, segment_ids=segment_ids,
+                out = token_major(ulysses_attention(
+                    *heads_major(q, k, v), causal=True, segment_ids=segment_ids,
                     scale=c.attn_scale, window=c.sliding_window,
                     window_flag=local_flag,
-                )
+                ))
         elif c.position == "alibi":
             # rank-1 form rides the flash kernel (slope * key_position added
             # in-kernel) — the dense [s, s] bias never materializes
             out = attention_op(
                 q, k, v, causal=True, segment_ids=segment_ids,
                 alibi_slopes=jnp.asarray(alibi_slopes(nh)),
-                alibi_positions=positions, impl=impl,
+                alibi_positions=positions, impl=impl, head_dim=d,
             )
         else:
             # sliding windows ride the flash kernel (in-kernel band mask;
@@ -2001,8 +2026,8 @@ def _attention_block(c: TransformerConfig, lp, x, positions, segment_ids, kv_cac
                 q, k, v, causal=c.attn_causal, segment_ids=segment_ids,
                 scale=c.attn_scale, window=c.sliding_window,
                 window_flag=local_flag, impl=impl, schedule=schedule,
+                head_dim=d,
             )
-    out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * d)
     if c.attn_out_gate:
         out = attn_gate(out, _proj(c, x, lp["wq_gate"]))
     out = _proj(c, out, lp["wo"])

@@ -9,6 +9,13 @@ tests/unit/ops/test_attention.py.
 """
 
 from deepspeed_tpu.ops.attention.core import attention, mha_reference
+from deepspeed_tpu.ops.attention.flash_pallas import (
+    head_major,
+    heads_flat,
+    heads_view,
+    token_major,
+    tokens_view,
+)
 from deepspeed_tpu.ops.attention.sharded import (
     head_sharded_flash,
     ring_flash_attention,
@@ -16,7 +23,12 @@ from deepspeed_tpu.ops.attention.sharded import (
 
 __all__ = [
     "attention",
+    "head_major",
     "head_sharded_flash",
+    "heads_flat",
+    "heads_view",
     "mha_reference",
     "ring_flash_attention",
+    "token_major",
+    "tokens_view",
 ]

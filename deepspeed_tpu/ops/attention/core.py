@@ -5,9 +5,13 @@
     allow tiling onto the MXU (head_dim and block sizes aligned),
   * otherwise: a numerically-stable jnp implementation that XLA fuses well.
 
-Shapes follow the TPU-friendly layout [batch, num_heads, seq, head_dim]
+Shapes follow the head-major layout [batch, num_heads, seq, head_dim]
 (q) / [batch, num_kv_heads, seq, head_dim] (k, v); grouped-query attention
 (num_heads a multiple of num_kv_heads) is handled in all backends.
+``attention`` also takes the projections' own [batch, seq, heads * head_dim]
+(rank 3, with ``head_dim``): the flash kernels read it in place where a head
+is whole lanes, every other backend gets head-major operands by a transpose
+here, where the backend is chosen.
 
 Reference parity: the fused softmax/attention CUDA ops of
 csrc/transformer/inference/csrc/pt_binding.cpp (softmax_context etc.) and the
@@ -185,7 +189,8 @@ def _splash_dispatch(q, k, v, causal, segment_ids, bias, scale, window,
 
 
 def _flash_sharded(q, k, v, causal, segment_ids, scale, alibi_slopes=None,
-                   alibi_positions=None, window=0, window_flag=None):
+                   alibi_positions=None, window=0, window_flag=None,
+                   head_dim=None):
     """Run the Pallas flash kernel under a multi-device mesh (batch/head
     sharding — ops.attention.sharded.head_sharded_flash). Returns None when
     the shapes don't divide; the caller falls back to the reference einsum
@@ -196,7 +201,7 @@ def _flash_sharded(q, k, v, causal, segment_ids, scale, alibi_slopes=None,
     out = head_sharded_flash(
         q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
         alibi_slopes=alibi_slopes, alibi_positions=alibi_positions,
-        window=window, window_flag=window_flag,
+        window=window, window_flag=window_flag, head_dim=head_dim,
     )
     if out is None and alibi_slopes is not None:
         global _warned_alibi_fallback
@@ -212,7 +217,7 @@ def _flash_sharded(q, k, v, causal, segment_ids, scale, alibi_slopes=None,
     return out
 
 
-def _ring_eligible(q, k, bias, causal, window):
+def _ring_eligible(b, h, h_kv, s, sk, d, bias, causal, window):
     """Whether 'auto' dispatch may take the ring context-parallel path: the
     topology's ``context`` axis is >1 (explicit opt-in via mesh config) and
     the schedule/shapes fit the ring's contract."""
@@ -222,13 +227,40 @@ def _ring_eligible(q, k, bias, causal, window):
     n = topo.context_parallel_size
     if n <= 1 or bias is not None or not causal or window:
         return False
-    b, h, s, d = q.shape
-    h_kv, sk = k.shape[1], k.shape[2]
     if s != sk or d not in (64, 128, 256) or s % n or (s // n) % 128:
         return False
     from deepspeed_tpu.ops.attention.sharded import _divisible
 
     return _divisible(topo, b, h, h_kv, s=s)
+
+
+def _auto_flash(impl, bias, d, sq, sk):
+    """Whether the dense dispatch chain ends at the flash kernel."""
+    return impl == "flash" or (
+        impl in (None, "auto")
+        and on_tpu()
+        and bias is None
+        and d in (64, 128, 256)
+        and sq % 128 == 0
+        and sk % 128 == 0
+        and sq == sk  # self-attention training path; decode uses reference
+    )
+
+
+def _flash_reads_in_place(impl, bias, schedule, ring, b, h, h_kv, sq, sk, d):
+    """Whether a token-major call ([b, s, heads * d] operands) stays so: the
+    dispatch ends at the flash kernel (not at splash, the ring or the
+    reference), which takes a head of whole lanes where the projections wrote
+    it, and the mesh divides, so nothing falls back behind it."""
+    if d % 128 or schedule is not None or ring:
+        return False
+    if not (_auto_flash(impl, bias, d, sq, sk) or (impl == "flash_head_sharded" and bias is None)):
+        return False
+    from deepspeed_tpu.ops.attention.sharded import _divisible
+    from deepspeed_tpu.parallel.topology import get_topology
+
+    topo = get_topology()
+    return topo.world_size == 1 or _divisible(topo, b, h, h_kv)
 
 
 def attention(
@@ -245,8 +277,18 @@ def attention(
     window: int = 0,
     window_flag: Optional[jax.Array] = None,
     schedule=None,
+    head_dim: Optional[int] = None,
 ) -> jax.Array:
     """Dispatching attention entry point.
+
+    The layout is the operands' rank. Rank 4, head-major: q [b, h, sq, d]; k, v
+    [b, h_kv, sk, d] → [b, h, sq, d]. Rank 3, token-major, with ``head_dim``:
+    q [b, sq, h * head_dim]; k, v [b, sk, h_kv * head_dim] → [b, sq,
+    h * head_dim]: what a layer's projections write and its output projection
+    reads. A rank-3 call that resolves to the flash kernel at a head of whole
+    lanes (``head_dim % 128 == 0``) hands them over as they are; any other
+    backend (splash, the ring, the reference, a head of 64) takes head-major
+    operands, transposed here.
 
     ``impl`` selects the backend:
       * None / 'auto' — splash when a block ``schedule`` (or sparse mask)
@@ -267,8 +309,24 @@ def attention(
     ALiBi and sliding windows ride the flash path (in-kernel masking; a
     static window additionally prunes out-of-band kv blocks from the grid);
     a dense ``bias`` forces the reference path."""
-    d = q.shape[-1]
-    sq, sk = q.shape[2], k.shape[2]
+    token_major = q.ndim == 3
+    if token_major:
+        if not head_dim:
+            raise ValueError("attention: [b, s, heads * d] operands need head_dim")
+        (b, sq, _), sk, d = q.shape, k.shape[1], head_dim
+        h, h_kv = q.shape[2] // d, k.shape[2] // d
+    else:
+        (b, h, sq, d), h_kv, sk = q.shape, k.shape[1], k.shape[2]
+    ring = impl in (None, "auto") and _ring_eligible(b, h, h_kv, sq, sk, d, bias, causal, window)
+    if token_major and not _flash_reads_in_place(impl, bias, schedule, ring, b, h, h_kv, sq, sk, d):
+        from deepspeed_tpu.ops.attention import flash_pallas
+
+        q, k, v = (flash_pallas.head_major(x, d) for x in (q, k, v))
+        return flash_pallas.token_major(attention(
+            q, k, v, causal=causal, segment_ids=segment_ids, bias=bias, scale=scale,
+            impl=impl, alibi_slopes=alibi_slopes, alibi_positions=alibi_positions,
+            window=window, window_flag=window_flag, schedule=schedule,
+        ))
     if alibi_slopes is not None and (impl == "splash" or schedule is not None):
         raise ValueError("attention: ALiBi is not supported on the splash "
                          "scheduled path")
@@ -315,7 +373,7 @@ def attention(
             q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
             alibi_slopes=alibi_slopes, alibi_positions=alibi_positions,
             window=window, window_flag=window_flag,
-            interpret=not on_tpu(),
+            interpret=not on_tpu(), head_dim=head_dim,
         )
         if out is None:
             raise ValueError(
@@ -323,27 +381,20 @@ def attention(
                 f"{q.shape} do not divide the mesh"
             )
         return out
-    if impl in (None, "auto") and _ring_eligible(q, k, bias, causal, window):
+    if ring:
         from deepspeed_tpu.ops.attention import sharded
 
         return sharded.ring_flash_attention(
             q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
             alibi_slopes=alibi_slopes, interpret=not on_tpu(),
         )
-    use_flash = impl == "flash" or (
-        impl in (None, "auto")
-        and on_tpu()
-        and bias is None
-        and d in (64, 128, 256)
-        and sq % 128 == 0
-        and sk % 128 == 0
-        and sq == sk  # self-attention training path; decode uses reference
-    )
-    if use_flash:
+    if _auto_flash(impl, bias, d, sq, sk):
         out = _flash_sharded(q, k, v, causal, segment_ids, scale, alibi_slopes,
-                             alibi_positions, window, window_flag)
+                             alibi_positions, window, window_flag, head_dim)
         if out is not None:
             return out
+    if token_major:  # _flash_reads_in_place said the kernel takes it, above
+        raise RuntimeError("attention: a token-major call fell through the flash dispatch")
     if window and sq == sk and sq >= 4096:
         global _warned_window_fallback
         if not _warned_window_fallback:

@@ -7,7 +7,22 @@ online-softmax tiling so the [s, s] score matrix never materializes in HBM.
 
 Design (round 3: kv-pipelined — nothing sequence-length-sized is ever VMEM
 resident, lifting the former ~8k dense cap):
-  * Layout [b, h, s, d]. Forward grid (b, h, nq, nk) with the kv block index
+  * Two operand layouts, one set of kernels, told apart by the operands'
+    rank. HEAD-MAJOR [b, h, s, d]: a head's block is (1, 1, rows, d) at
+    (batch, head, row block, 0). TOKEN-MAJOR [b, s, h * d], which is where the
+    q/k/v projections wrote them and where the output projection reads: a
+    head is a column of (rows, 128) tiles, its block (1, rows, d) at (batch,
+    row block, head), so nothing is re-laid between the projections and the
+    kernels: q, k, v, the output, ``do`` and the three gradients all stay so.
+    A head must be whole lanes for its column to be a block (``d % 128 ==
+    0``); a head of 64 is run head-major. Only the BlockSpecs, the output
+    shapes and the squeeze of a block's unit dims differ (``_head_spec`` /
+    ``_head_ref``): the kernel bodies see the same [rows, d] refs either way.
+    LSE stays [b, h, s, LANES]. What is computed a head at a time AROUND the
+    kernels (a per-head norm, rope, the GQA group's sum) goes through
+    ``heads_view``, the same bytes a head at a time: a plain [b, s, h, d]
+    split is tiled differently in HBM and costs a copy each way.
+  * Forward grid (b, h, nq, nk) with the kv block index
     minor: each program sees one [bq, d] q block and one [bk, d] k/v block;
     Pallas double-buffers the next kv block's HBM→VMEM copy behind the
     current block's MXU work. Softmax state (m, l) and the output
@@ -441,10 +456,15 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref,
 # dq accumulator ([s, d] f32) in VMEM. It runs where that fits this many bytes
 # (s <= 4096 at d = 128, 8192 at 64); a longer sequence takes the dq kernel and
 # the dk/dv kernel without it, which hold one block each.
-# At that budget the kernel fits the 16 MiB scoped-VMEM default with every mask
-# operand present (the described v5e compiles it inside 12 MiB at s = 4096,
-# d = 128, blocks of 1024), so the call raises no limit.
+# At that budget (s = 4096, d = 128, blocks of 1024) the kernel's blocks, double
+# buffers, accumulators and temporaries come to 16.1 MiB token-major and 17.1
+# head-major with no mask operand, on a described v5e with every operand in HBM:
+# at the edge of the 16 MiB scoped-VMEM default, under it only where XLA happens
+# to keep some operands in VMEM itself (13.6 MiB in the parent's train step). So
+# the fused call states its own limit, with room for the mask operands (segment
+# ids are 1 MiB more); a v5e has 128 MiB of VMEM.
 DQ_RESIDENT_BYTES = 2 * 1024 * 1024
+FUSED_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def _pick_block(s, target=None):
@@ -482,8 +502,17 @@ def flash_attention(
     alibi_positions=None,
     window: int = 0,
     window_flag=None,
+    head_dim: Optional[int] = None,
 ) -> jax.Array:
-    """Flash attention. q: [b, h, s, d]; k, v: [b, h_kv, s, d] → [b, h, s, d].
+    """Flash attention, in the layout its operands have. Rank 4 is HEAD-MAJOR:
+    q [b, h, s, d]; k, v [b, h_kv, s, d] → [b, h, s, d]. Rank 3 is TOKEN-MAJOR,
+    what a layer's projections write: q [b, s, h * head_dim]; k, v
+    [b, s, h_kv * head_dim] → [b, s, h * head_dim] (``head_dim`` says where a
+    head ends), and the gradients come back so. Where a head is whole lanes
+    (``head_dim % 128 == 0``) the kernels index a head's lanes out of those
+    arrays in place; a head that is not (64) cannot be a block's column and is
+    re-laid to the head-major form here, as its caller used to. Same sums in
+    the same order either way: outputs and gradients are bit-equal.
 
     ``segment_ids``: optional [b, s] int32 — packed-sequence masking happens
     IN the kernel (tokens attend only within their own segment), so packed
@@ -504,23 +533,114 @@ def flash_attention(
     in-kernel masking (full causal grid, flash memory). Requires causal."""
     if window and not causal:
         raise ValueError("flash_attention: window > 0 requires causal=True")
+    if q.ndim == 3 and not head_dim:
+        raise ValueError("flash_attention: [b, s, heads * d] operands need head_dim")
+    # which form the kernels take is read off the head: whole lanes or not
+    head_lanes = head_dim if q.ndim == 3 and head_dim % LANES == 0 else 0
+    relaid = q.ndim == 3 and not head_lanes
+    if relaid:
+        q, k, v = (head_major(x, head_dim) for x in (q, k, v))
+    b, _, _, s, _ = _dims(q, k, head_lanes)
     alibi = None
     if alibi_slopes is not None:
-        b, _, s, _ = q.shape
         alibi = build_alibi_operand(alibi_slopes, alibi_positions, b, s)
     wflag = None
     if window and window_flag is not None:
         wflag = jnp.broadcast_to(
             jnp.asarray(window_flag, jnp.int32).reshape(1, 1), (1, LANES)
         )
-    return _flash_core(q, k, v, segment_ids, alibi, wflag, causal, scale,
-                       int(window), interpret)
+    out = _flash_core(q, k, v, segment_ids, alibi, wflag, causal, scale,
+                      int(window), interpret, head_lanes)
+    return token_major(out) if relaid else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
-def _flash_core(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret):
-    out, _ = _flash_fwd(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _flash_core(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret,
+                head_lanes):
+    """``head_lanes`` 0: head-major operands, q [b, h, s, d]. Else token-major,
+    q [b, s, h * head_lanes], k / v [b, s, h_kv * head_lanes], and the output,
+    the residuals and the three gradients in that form too."""
+    out, _ = _flash_fwd(q, k, v, segment_ids, alibi, wflag, causal, scale, window,
+                        interpret, head_lanes)
     return out
+
+
+def _dims(q, k, head_lanes):
+    """(b, h, h_kv, s, d) of a call's q and k in either layout."""
+    if head_lanes:
+        b, s, lanes = q.shape
+        return b, lanes // head_lanes, k.shape[2] // head_lanes, s, head_lanes
+    b, h, s, d = q.shape
+    return b, h, k.shape[1], s, d
+
+
+SUBLANES = 8
+
+
+def heads_view(x, heads):
+    """x [b, s, heads * d] a head at a time WITHOUT leaving where it lies:
+    [b, s / 8, heads, 8, d]. In HBM a [.., s, heads * d] array is (8, 128)
+    tiles, eight tokens of one head's lanes; its default 4-D split [b, s,
+    heads, d] tiles (heads, d) instead and is a copy away, in both directions,
+    which is what a per-head norm, rope and their gradients around a
+    token-major kernel would pay. This view's minor dims are the tile: the
+    same bytes, so the chip's compiler reads and writes it in place. The head
+    axis is 2; a position-wise operand broadcasts from ``tokens_view``. A
+    sequence that is not whole tiles takes the plain [b, s, heads, d]: head
+    axis 2 as well. :func:`heads_flat` is the way back."""
+    b, s, lanes = x.shape
+    if s % SUBLANES:
+        return x.reshape(b, s, heads, lanes // heads)
+    return x.reshape(b, s // SUBLANES, SUBLANES, heads, lanes // heads).transpose(0, 1, 3, 2, 4)
+
+
+def tokens_view(positions, s):
+    """[.., s] per-token values, shaped as :func:`heads_view` lays the tokens
+    of a sequence of ``s``: [.., s / 8, 8], or as they are."""
+    if s % SUBLANES:
+        return positions
+    return positions.reshape(positions.shape[:-1] + (s // SUBLANES, SUBLANES))
+
+
+def heads_flat(x):
+    """A :func:`heads_view` back as [b, s, heads * d]."""
+    if x.ndim == 5:
+        b, t, heads, r, d = x.shape
+        return x.transpose(0, 1, 3, 2, 4).reshape(b, t * r, heads * d)
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def head_major(x, head_dim):
+    """[b, s, heads * head_dim] re-laid as [b, heads, s, head_dim]: what every
+    backend but the token-major kernels takes. A copy."""
+    return x.reshape(x.shape[:2] + (-1, head_dim)).transpose(0, 2, 1, 3)
+
+
+def token_major(x):
+    """[b, heads, s, d] re-laid as [b, s, heads * d]: :func:`head_major` undone."""
+    return x.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[2], -1)
+
+
+def _head_spec(head_lanes, rows, d, row_map, head_map=lambda h_: h_):
+    """BlockSpec of one head's [rows, d] block of a per-head operand, at row
+    block ``row_map(i, j)`` of head ``head_map(h_)``. Head-major: (1, 1, rows,
+    d) of [b, heads, s, d]. Token-major: the head's d lanes out of [b, s,
+    heads * d], (1, rows, d) at column block ``head``: a column of (rows, 128)
+    tiles, so the block copy stays tile-granular."""
+    if head_lanes:
+        return pl.BlockSpec((1, rows, d),
+                            lambda b_, h_, i, j: (b_, row_map(i, j), head_map(h_)))
+    return pl.BlockSpec((1, 1, rows, d),
+                        lambda b_, h_, i, j: (b_, head_map(h_), row_map(i, j), 0))
+
+
+def _head_ref(head_lanes, ref):
+    """A :func:`_head_spec` block with its unit dims squeezed: [rows, d]."""
+    return ref.at[0] if head_lanes else ref.at[0, 0]
+
+
+def _layout_name(head_lanes):
+    return "token_major" if head_lanes else "head_major"
 
 
 def _kv_clamp(causal, bq, bk, window=0, static_window=False):
@@ -617,15 +737,23 @@ def _pop_mask_refs(rest, seg_ops, alibi_ops, wf_ops=()):
     return kw, rest
 
 
-def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret):
-    b, h, s, d = q.shape
-    h_kv = k.shape[1]
+def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret,
+                head_lanes=0):
+    b, h, h_kv, s, d = _dims(q, k, head_lanes)
     group = h // h_kv
     scale = scale if scale is not None else d ** -0.5
     bq = _pick_block(s)
     bk = _pick_block(s)
     nq, nk = s // bq, s // bk
     jc = _kv_clamp(causal, bq, bk, window, static_window=wflag is None)
+    # once a traced forward: which form its operands took
+    from deepspeed_tpu.observability.tracing import get_tracer
+
+    get_tracer().instant("flash.layout", track="trace", args={
+        "kernel": FLASH_FWD, "s": s, "block": bq, "layout": _layout_name(head_lanes)})
+    head = functools.partial(_head_ref, head_lanes)
+    q_spec = _head_spec(head_lanes, bq, d, lambda i, j: i)
+    kv_spec = _head_spec(head_lanes, bk, d, jc, lambda h_: h_ // group)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk, window=window
@@ -638,22 +766,16 @@ def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, inter
     def entry(qr, kr, vr, *rest):
         kw, (orf, lr, mref, lref, aref) = _pop_mask_refs(
             rest, seg_ops, alibi_ops, wf_ops)
-        kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
+        kernel(head(qr), head(kr), head(vr), head(orf),
                lr.at[0, 0], mref, lref, aref, **kw)
 
     out, lse = pl.pallas_call(
-        # refs arrive with the leading (1, 1) block dims squeezed via .at
+        # refs arrive with the block's leading unit dims squeezed via .at
         entry,
         grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // group, jc(i, j), 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, i, j: (b_, h_ // group, jc(i, j), 0)),
-        ] + seg_specs + alibi_specs + wf_specs,
+        in_specs=[q_spec, kv_spec, kv_spec] + seg_specs + alibi_specs + wf_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            q_spec,
             pl.BlockSpec((1, 1, bq, LANES), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
@@ -671,8 +793,10 @@ def _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, inter
     return out, lse
 
 
-def _flash_fwd(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret):
-    out, lse = _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret)
+def _flash_fwd(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interpret,
+               head_lanes=0):
+    out, lse = _flash_call(q, k, v, segment_ids, alibi, wflag, causal, scale, window,
+                           interpret, head_lanes)
     # Residual LSE is narrowed to one lane (it is lane-broadcast) so saving it
     # costs b·h·s·4 bytes, not ×LANES; the backward re-broadcasts. The names
     # feed the remat policies (models.transformer.remat_policy: every one but
@@ -690,15 +814,14 @@ def _flash_fwd(q, k, v, segment_ids, alibi, wflag, causal, scale, window, interp
     return out, (q, k, v, segment_ids, alibi, wflag, out, lse1)
 
 
-def _flash_bwd(causal, scale, window, interpret, res, g):
+def _flash_bwd(causal, scale, window, interpret, head_lanes, res, g):
     q, k, v, segment_ids, alibi, wflag, out, lse = res
     lse = jnp.broadcast_to(lse, lse.shape[:-1] + (LANES,))
-    b, h, s, d = q.shape
-    h_kv = k.shape[1]
+    b, h, h_kv, s, d = _dims(q, k, head_lanes)
     group = h // h_kv
     scale_v = scale if scale is not None else d ** -0.5
     args = (q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale_v,
-            window, interpret)
+            window, interpret, head_lanes)
     # Which kernels is read off the shape: one pass where a head's dq
     # accumulator may stay in VMEM, the dq and dk/dv kernels above that.
     # Same sums in the same order either way.
@@ -709,7 +832,15 @@ def _flash_bwd(causal, scale, window, interpret, res, g):
         dk_h, dv_h = _bwd_dkv_call(*args, with_dq=False)
     # dk/dv come per q-head and are reduced over the GQA group here, outside
     # the kernel: the ring's hand-over (flash_dkv_finalize) sums the same way.
-    if group > 1:
+    # Token-major the group is split off the head axis of the tiles' view: the
+    # same terms in the same order, and no re-laying around the sum.
+    if group > 1 and head_lanes:
+        def fold(x):
+            x = heads_view(x, h)
+            x = x.reshape(x.shape[:2] + (h_kv, group) + x.shape[3:]).astype(jnp.float32)
+            return heads_flat(jnp.sum(x, axis=3).astype(k.dtype))
+        dk, dv = fold(dk_h), fold(dv_h)
+    elif group > 1:
         dk = jnp.sum(dk_h.reshape(b, h_kv, group, s, d).astype(jnp.float32), axis=2).astype(k.dtype)
         dv = jnp.sum(dv_h.reshape(b, h_kv, group, s, d).astype(jnp.float32), axis=2).astype(v.dtype)
     else:
@@ -718,10 +849,10 @@ def _flash_bwd(causal, scale, window, interpret, res, g):
 
 
 def _bwd_dq_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
-                 window, interpret):
+                 window, interpret, head_lanes=0):
     """dq from the dq kernel: q-major, kv blocks streamed."""
-    b, h, s, d = q.shape
-    group = h // k.shape[1]
+    b, h, h_kv, s, d = _dims(q, k, head_lanes)
+    group = h // h_kv
     bq = _pick_block(s)
     bk = _pick_block(s)
     nq, nk = s // bq, s // bk
@@ -735,14 +866,15 @@ def _bwd_dq_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, jc)
     wf_ops, wf_specs = _wflag_specs(wflag)
 
+    head = functools.partial(_head_ref, head_lanes)
+
     def entry(qr, kr, vr, orf, dor, lr, *rest):
         kw, (dqr, dref, aref) = _pop_mask_refs(rest, seg_ops, alibi_ops, wf_ops)
-        kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
-               dor.at[0, 0], lr.at[0, 0], dqr.at[0, 0], dref, aref, **kw)
+        kernel(head(qr), head(kr), head(vr), head(orf),
+               head(dor), lr.at[0, 0], head(dqr), dref, aref, **kw)
 
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d),
-                           lambda b_, h_, i, j: (b_, h_ // group, jc(i, j), 0))
+    q_spec = _head_spec(head_lanes, bq, d, lambda i, j: i)
+    kv_spec = _head_spec(head_lanes, bk, d, jc, lambda h_: h_ // group)
     return pl.pallas_call(
         entry,
         grid=(b, h, nq, nk),
@@ -762,13 +894,13 @@ def _bwd_dq_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
 
 
 def _bwd_dkv_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale,
-                  window, interpret, with_dq):
+                  window, interpret, head_lanes=0, *, with_dq):
     """(per-q-head dk, per-q-head dv) from the dk/dv kernel: kv-major, with
     the q/do/o/lse stream minor so one [bk, d] kv block stays resident.
     ``with_dq``: the fused backward, (dk, dv, dq) from that one pass, the
     head's whole dq accumulated in VMEM beside them."""
-    b, h, s, d = q.shape
-    group = h // k.shape[1]
+    b, h, h_kv, s, d = _dims(q, k, head_lanes)
+    group = h // h_kv
     bq = _pick_block(s)
     bk = _pick_block(s)
     nq, nk = s // bq, s // bk
@@ -781,7 +913,8 @@ def _bwd_dkv_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale
         classes = causal_pair_classes(s, bq, bk)
         get_tracer().instant("flash.causal_pairs", track="trace", args={
             "s": s, "block": bq, **classes._asdict(),
-            "pairs_of_products": classes.pairs_of_products})
+            "pairs_of_products": classes.pairs_of_products,
+            "layout": _layout_name(head_lanes)})
 
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nq=nq,
@@ -792,23 +925,24 @@ def _bwd_dkv_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale
     alibi_ops, alibi_specs = _alibi_specs(alibi, bk, lambda i, j: i)
     wf_ops, wf_specs = _wflag_specs(wflag)
 
+    head = functools.partial(_head_ref, head_lanes)
+
     def entry(qr, kr, vr, orf, dor, lr, *rest):
         kw, rest = _pop_mask_refs(rest, seg_ops, alibi_ops, wf_ops)
         if with_dq:
             dkr, dvr, dqr, dka, dva, dqa = rest
-            kw.update(dq_ref=dqr.at[0, 0], dq_acc_ref=dqa)
+            kw.update(dq_ref=head(dqr), dq_acc_ref=dqa)
         else:
             dkr, dvr, dka, dva = rest
-        kernel(qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], orf.at[0, 0],
-               dor.at[0, 0], lr.at[0, 0], dkr.at[0, 0], dvr.at[0, 0],
+        kernel(head(qr), head(kr), head(vr), head(orf),
+               head(dor), lr.at[0, 0], head(dkr), head(dvr),
                dka, dva, **kw)
 
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, qc(i, j), 0))
-    kv_in_spec = pl.BlockSpec((1, 1, bk, d),
-                              lambda b_, h_, i, j: (b_, h_ // group, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+    q_spec = _head_spec(head_lanes, bq, d, qc)
+    kv_in_spec = _head_spec(head_lanes, bk, d, lambda i, j: i, lambda h_: h_ // group)
+    kv_spec = _head_spec(head_lanes, bk, d, lambda i, j: i)
     # the head's whole dq: written back once, when the head changes
-    dq_spec = pl.BlockSpec((1, 1, s, d), lambda b_, h_, i, j: (b_, h_, 0, 0))
+    dq_spec = _head_spec(head_lanes, s, d, lambda i, j: 0)
     return pl.pallas_call(
         entry,
         grid=(b, h, nk, nq),
@@ -818,12 +952,14 @@ def _bwd_dkv_call(q, k, v, out, g, lse, segment_ids, alibi, wflag, causal, scale
                          lambda b_, h_, i, j: (b_, h_, qc(i, j), 0)),
         ] + seg_specs + alibi_specs + wf_specs,
         out_specs=[kv_spec, kv_spec] + [dq_spec] * with_dq,
-        out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype)] * (2 + with_dq),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * (2 + with_dq),
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),  # dk accumulator
             pltpu.VMEM((bk, d), jnp.float32),  # dv accumulator
         ] + [pltpu.VMEM((nq, bq, d), jnp.float32)] * with_dq,  # the head's dq
         interpret=interpret,
+        **({"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES)}
+           if with_dq else {}),
         name=FLASH_BWD_FUSED if with_dq else FLASH_BWD_DKV,
     )(q, k, v, out, g, lse, *seg_ops, *alibi_ops, *wf_ops)
 

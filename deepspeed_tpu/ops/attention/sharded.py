@@ -85,7 +85,7 @@ def _divisible(topo, b, h, h_kv, s=None):
 
 def head_sharded_flash(q, k, v, causal=True, segment_ids=None, scale=None,
                        alibi_slopes=None, alibi_positions=None, window=0,
-                       window_flag=None, interpret=False):
+                       window_flag=None, interpret=False, head_dim=None):
     """Flash attention with batch/head sharding under ``shard_map``.
 
     Pins the canonical layout (batch over data/expert, heads over
@@ -93,6 +93,12 @@ def head_sharded_flash(q, k, v, causal=True, segment_ids=None, scale=None,
     manually per device. ALiBi slopes ride along SHARDED over the head axes,
     so each device's kernel sees exactly its local heads' slopes. Returns
     ``None`` when the shapes don't divide over the mesh (caller falls back).
+
+    Takes both of ``flash_attention``'s layouts, by the operands' rank:
+    head-major q [b, h, s, d], k / v [b, h_kv, s, d], or token-major, the
+    projections' own q [b, s, h * head_dim], k / v [b, s, h_kv * head_dim].
+    The heads are pinned where they are (axis 1, or the lanes: a shard is a
+    run of whole heads) and the output comes back in q's layout.
     """
     topo = get_topology()
     if topo.world_size == 1:
@@ -100,13 +106,16 @@ def head_sharded_flash(q, k, v, causal=True, segment_ids=None, scale=None,
             q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
             alibi_slopes=alibi_slopes, alibi_positions=alibi_positions,
             window=window, window_flag=window_flag, interpret=interpret,
+            head_dim=head_dim,
         )
-    b, h, s, d = q.shape
-    h_kv = k.shape[1]
-    if not _divisible(topo, b, h, h_kv):
+    if q.ndim == 3:
+        h, h_kv = q.shape[2] // head_dim, k.shape[2] // head_dim
+        spec = P(BATCH_AXES, None, HEAD_AXES)
+    else:
+        h, h_kv = q.shape[1], k.shape[1]
+        spec = P(BATCH_AXES, HEAD_AXES, None, None)
+    if not _divisible(topo, q.shape[0], h, h_kv):
         return None
-
-    spec = P(BATCH_AXES, HEAD_AXES, None, None)
     sharding = NamedSharding(topo.mesh, spec)
     q, k, v = (jax.lax.with_sharding_constraint(x, sharding) for x in (q, k, v))
 
@@ -145,7 +154,8 @@ def head_sharded_flash(q, k, v, causal=True, segment_ids=None, scale=None,
         return flash_attention(q_, k_, v_, causal=causal, segment_ids=seg,
                                scale=scale, alibi_slopes=sl,
                                alibi_positions=pos, window=window,
-                               window_flag=wf, interpret=interpret)
+                               window_flag=wf, interpret=interpret,
+                               head_dim=head_dim)
 
     fn = manual_over(body, topo.mesh, (spec, spec, spec, *extra_specs), spec)
     return fn(q, k, v, *extra_ops)

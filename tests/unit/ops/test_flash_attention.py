@@ -625,28 +625,213 @@ def test_the_backward_kernels_share_the_strips_bitwise(monkeypatch, variant):
     assert all(np.abs(np.asarray(a)).max() > 0 for a in fused)
 
 
-def test_a_traced_backward_records_its_pair_classes(monkeypatch):
+@pytest.mark.parametrize("layout, shape, kw", [
+    ("head_major", (1, 2, 768, 64), {}),
+    ("head_major", (1, 2, 768, 128), {}),
+    ("token_major", (1, 768, 2 * 128), {"head_dim": 128}),
+    ("head_major", (1, 768, 2 * 64), {"head_dim": 64}),   # a head of 64 is re-laid
+], ids=["bhsd_64", "bhsd_128", "bs_hd_128", "bs_hd_64"])
+def test_a_traced_backward_records_its_pair_classes(monkeypatch, layout, shape, kw):
     """Once a traced causal backward the tracer gets ``flash.causal_pairs`` with
-    the call's count; a forward alone classes nothing, and a windowed call,
-    whose pairs all run whole, records none."""
+    the call's count and the ``layout`` its kernels took; a forward alone
+    classes nothing and says its layout in ``flash.layout``, and a windowed
+    call, whose pairs all run whole, records no classes."""
     from deepspeed_tpu.observability import tracing
 
     monkeypatch.setenv("DSTPU_FLASH_BLOCK", "256")
     old = tracing.get_tracer()
     tracer = tracing.set_tracer(tracing.SpanTracer())
     try:
-        q = jax.ShapeDtypeStruct((1, 2, 768, 64), jnp.float32)
+        q = jax.ShapeDtypeStruct(shape, jnp.float32)
 
-        def loss(q, k, v, **kw):
-            return flash_attention(q, k, v, causal=True, interpret=True, **kw).sum()
+        def loss(q, k, v, **more):
+            return flash_attention(q, k, v, causal=True, interpret=True, **kw, **more).sum()
 
         jax.eval_shape(loss, q, q, q)
         jax.eval_shape(jax.grad(functools.partial(loss, window=200)), q, q, q)
-        assert not tracer.ring_spans()
+        assert [(sp.name, sp.args) for sp in tracer.ring_spans()] == [("flash.layout", {
+            "kernel": flash_pallas.FLASH_FWD, "s": 768, "block": 256, "layout": layout})] * 2
         jax.eval_shape(jax.grad(loss), q, q, q)
         spans = [sp for sp in tracer.ring_spans() if sp.name == "flash.causal_pairs"]
     finally:
         tracing.set_tracer(old)
     assert [sp.args for sp in spans] == [{
         "s": 768, "block": 256, "pruned": 3, "under": 3, "diagonal": 3, "strip": 128,
-        "live_tiles": 9, "tiles": 12, "pairs_of_products": 5.25}]
+        "live_tiles": 9, "tiles": 12, "pairs_of_products": 5.25, "layout": layout}]
+
+
+# -- the two operand layouts (PR 59) --------------------------------------------------------------
+# Rank 4 is head-major [b, h, s, d]; rank 3 is token-major [b, s, h * d], what the
+# projections write, and a head of whole lanes (d % 128 == 0) is indexed out of
+# it in place. One set of kernels: the cases below run both forms of each.
+
+_token_major = flash_pallas.token_major
+
+
+def _head_major(x, d):
+    return flash_pallas.head_major(x, d)
+
+
+_LAYOUT_VARIANTS = {
+    "gqa_16_8": {"h": 16, "h_kv": 8},
+    "mha_8_8": {"h": 8, "h_kv": 8},
+    "segments": {"segments": True},
+    "window": {"window": 160},            # static: straddles the 128 blocks, prunes and masks
+    "window_flag0": {"window": 160, "window_flag": 0},
+    "window_flag1": {"window": 160, "window_flag": 1},
+    "alibi": {"alibi": True},
+    "above_dq_budget": {"budget": 0},     # dq and dk/dv kernels, as a long sequence takes
+}
+
+
+def _layout_case(variant, d=128, s=256):
+    from deepspeed_tpu.models.transformer import alibi_slopes
+
+    spec = dict(_LAYOUT_VARIANTS[variant])
+    h = spec.pop("h", 4)
+    q, k, v = _qkv(b=2, h=h, h_kv=spec.pop("h_kv", 2), s=s, d=d, seed=9)
+    kw, budget = {}, spec.pop("budget", None)
+    if spec.pop("segments", False):
+        kw["segment_ids"] = _packed_segments(2, s, n_seg=3)
+    if spec.pop("alibi", False):
+        kw["alibi_slopes"] = jnp.asarray(alibi_slopes(h))
+    if "window_flag" in spec:
+        spec["window_flag"] = jnp.int32(spec["window_flag"])
+    kw.update(spec)
+    return q, k, v, kw, budget
+
+
+def _run_layout(layout, q, k, v, g, d, **kw):
+    """(out, dq, dk, dv) of one call in ``layout``, handed back head-major."""
+    if layout == "head_major":
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, interpret=True, **kw), q, k, v)
+        return (out,) + tuple(vjp(g))
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, interpret=True, head_dim=d, **kw),
+                       *map(_token_major, (q, k, v)))
+    assert out.shape == (q.shape[0], q.shape[2], q.shape[1] * d)
+    return tuple(_head_major(x, d) for x in (out,) + tuple(vjp(_token_major(g))))
+
+
+@pytest.mark.parametrize("variant", list(_LAYOUT_VARIANTS))
+@pytest.mark.parametrize("layout", ["head_major", "token_major"])
+def test_parity_with_the_reference_in_both_layouts(monkeypatch, layout, variant):
+    """Forward and dq / dk / dv against ``mha_reference`` at a head of 128, the
+    operands head-major or where the projections wrote them: GQA 16:8 and 8:8,
+    packed segments, a static and a flagged window, ALiBi, and the backward on
+    each side of ``DQ_RESIDENT_BYTES``."""
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", "128")
+    q, k, v, kw, budget = _layout_case(variant)
+    if budget is not None:
+        monkeypatch.setattr(flash_pallas, "DQ_RESIDENT_BYTES", budget)
+    g = jax.random.normal(jax.random.key(12), q.shape, q.dtype)
+    got = _run_layout(layout, q, k, v, g, 128, causal=True, **kw)
+    ref_kw = dict(kw)
+    if "window_flag" in ref_kw and int(ref_kw.pop("window_flag")) == 0:
+        ref_kw["window"] = 0
+    ref, vjp = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal=True, **ref_kw), q, k, v)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref), rtol=2e-4, atol=2e-4)
+    for a, b_, name in zip(got[1:], vjp(g), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-3, atol=1e-3,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("variant", ["gqa_16_8", "segments", "window_flag1", "alibi",
+                                     "above_dq_budget"])
+def test_the_two_layouts_are_bit_equal(monkeypatch, variant):
+    """The same blocks through the same kernel bodies, and the group's sum over
+    the same terms in the same order: outputs and all three gradients of the
+    token-major call equal the head-major call's to the last bit (bf16
+    operands, so that a different order of a sum would show). Blocks of 256 at
+    s = 768: pairs of all three classes, the diagonal ones cut."""
+    monkeypatch.setenv("DSTPU_FLASH_BLOCK", "256")
+    q, k, v, kw, budget = _layout_case(variant, s=768)
+    if budget is not None:
+        monkeypatch.setattr(flash_pallas, "DQ_RESIDENT_BYTES", budget)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    g = jax.random.normal(jax.random.key(12), q.shape, q.dtype)
+    head = _run_layout("head_major", q, k, v, g, 128, causal=True, **kw)
+    token = _run_layout("token_major", q, k, v, g, 128, causal=True, **kw)
+    for a, b_, name in zip(head, token, ("out", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b_, np.float32),
+                                      err_msg=name)
+        assert float(jnp.abs(a.astype(jnp.float32)).max()) > 0
+
+
+def test_a_head_of_64_keeps_the_head_major_kernels():
+    """A head that is not whole lanes cannot be a column of tiles: rank-3
+    operands at d = 64 are re-laid to [b, h, s, d] in front of the same
+    head-major kernels (every per-head operand of the traced calls is rank 4)
+    and the results are the head-major call's, bit for bit."""
+    q, k, v = _qkv(b=1, h=4, h_kv=2, s=256, d=64)
+    g = jax.random.normal(jax.random.key(12), q.shape, q.dtype)
+    head = _run_layout("head_major", q, k, v, g, 64, causal=True)
+    token = _run_layout("token_major", q, k, v, g, 64, causal=True)
+    for a, b_ in zip(head, token):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b_))
+
+    def calls(d):
+        x = _token_major(_qkv(b=1, h=4, h_kv=2, s=256, d=d)[0])
+        kv = x[..., : 2 * d]
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True, head_dim=d).sum(), argnums=(0, 1, 2)))(x, kv, kv)
+        return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+    assert {e.invars[0].aval.shape for e in calls(64)} == {(1, 4, 256, 64)}
+    assert {e.invars[0].aval.shape for e in calls(128)} == {(1, 256, 4 * 128)}
+    with pytest.raises(ValueError, match="head_dim"):
+        flash_attention(*(_token_major(x) for x in (q, k, v)), causal=True, interpret=True)
+
+
+def test_heads_view_is_the_same_numbers_a_head_at_a_time():
+    """``heads_view`` / ``heads_flat`` / ``tokens_view``: [b, s, heads * d] as
+    [b, s / 8, heads, 8, d] and back, a token's value beside its rows; a
+    sequence that is not whole tiles takes [b, s, heads, d]."""
+    x = jnp.arange(2 * 24 * 3 * 4, dtype=jnp.float32).reshape(2, 24, 12)
+    view = flash_pallas.heads_view(x, 3)
+    assert view.shape == (2, 3, 3, 8, 4)
+    pos = flash_pallas.tokens_view(jnp.arange(24)[None], 24)
+    assert pos.shape == (1, 3, 8)
+    for t in (0, 7, 8, 23):
+        assert int(pos[0, t // 8, t % 8]) == t
+        np.testing.assert_array_equal(np.asarray(view[1, t // 8, 2, t % 8]), np.asarray(x[1, t, 8:]))
+    np.testing.assert_array_equal(np.asarray(flash_pallas.heads_flat(view)), np.asarray(x))
+    odd = x[:, :21]
+    assert flash_pallas.heads_view(odd, 3).shape == (2, 21, 3, 4)
+    assert flash_pallas.tokens_view(jnp.arange(21)[None], 21).shape == (1, 21)
+    np.testing.assert_array_equal(np.asarray(flash_pallas.heads_flat(flash_pallas.heads_view(odd, 3))),
+                                  np.asarray(odd))
+
+
+@pytest.mark.parametrize("layout", ["head_major", "token_major"])
+def test_head_sharded_flash_takes_both_layouts(devices8, layout):
+    """``head_sharded_flash`` over data = 2 x model = 2 (the batch over the one,
+    runs of whole heads over the other, a GQA group inside a shard): rank-4
+    operands pinned on the head axis, rank-3 ones on the lanes, the same
+    kernels inside the ``shard_map``; forward and gradients against the
+    reference."""
+    from deepspeed_tpu.ops.attention.sharded import head_sharded_flash
+    from deepspeed_tpu.parallel.topology import Topology, reset_topology, set_topology
+
+    reset_topology()
+    set_topology(Topology(data=2, model=2, devices=jax.devices()[:4]))
+    try:
+        q, k, v = _qkv(b=2, h=4, h_kv=2, s=256, d=128)
+        seg = _packed_segments(2, 256, n_seg=2)
+        g = jax.random.normal(jax.random.key(12), q.shape, q.dtype)
+        lay, back = ((lambda x: x), (lambda x: x)) if layout == "head_major" else (
+            _token_major, functools.partial(_head_major, d=128))
+        kw = {} if layout == "head_major" else {"head_dim": 128}
+
+        @jax.jit
+        def run(q, k, v, g):
+            out, vjp = jax.vjp(lambda q, k, v: head_sharded_flash(
+                q, k, v, causal=True, segment_ids=seg, interpret=True, **kw), q, k, v)
+            return (out,) + tuple(vjp(g))
+
+        got = [back(x) for x in run(*map(lay, (q, k, v, g)))]
+    finally:
+        reset_topology()
+    ref, vjp = jax.vjp(lambda q, k, v: mha_reference(q, k, v, causal=True, segment_ids=seg), q, k, v)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(ref), rtol=2e-4, atol=2e-4)
+    for a, b_, name in zip(got[1:], vjp(g), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-3, atol=1e-3, err_msg=f"d{name}")

@@ -253,6 +253,52 @@ def test_the_flash_kernels_compile_for_a_v5e_with_every_mask_operand(one_chip, o
     assert sum(FP.FLASH_FWD in ln for ln in calls) == 1 and sum(FP.FLASH_BWD_FUSED in ln for ln in calls) == 1
 
 
+def test_an_attention_layer_compiles_for_a_v5e_with_nothing_re_laid_around_the_kernels(
+        one_chip, on_the_chip, monkeypatch):
+    """``_attention_block`` forward and backward as both train cells run it (16
+    query heads over 8 KV heads of 128, per-head RMS norm, rope, 4,096 tokens,
+    bf16): the kernels take q, k, v, ``do`` and hand back the output and the
+    three gradients as [1, 4096, heads * 128], where the projections write and
+    read, and the per-head norm and rope run on the tiles' view of the same
+    bytes (``heads_view``). So the compiled layer holds no ``copy``,
+    ``transpose`` or ``reshape`` of a [1, 4096, 16 | 8, 128] (or [1, 16 | 8,
+    4096, 128]) array: the parent's program had four here, and eleven a layer
+    in the whole step (PERF.md, PR 59)."""
+    import re
+
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.ops.attention import core
+    from deepspeed_tpu.ops.attention import flash_pallas as FP
+    from deepspeed_tpu.parallel.topology import Topology, reset_topology, set_topology
+
+    monkeypatch.setattr(core, "on_tpu", lambda: True)
+    c = T.get_config("tiny", hidden_size=1024, n_heads=16, n_kv_heads=8, head_dim_override=128,
+                     qk_norm=True, dtype="bfloat16", max_seq_len=4096)
+    shapes = jax.eval_shape(lambda k: T.init_params(c, k), jax.random.key(0))["layers"]
+    lp = {k: jax.ShapeDtypeStruct(v.shape[1:], jnp.bfloat16, sharding=one_chip)
+          for k, v in shapes.items() if k in T.ATTENTION_KEYS}
+    x = jax.ShapeDtypeStruct((1, 4096, c.hidden_size), jnp.bfloat16, sharding=one_chip)
+
+    def layer(lp, x, g):
+        pos = jnp.arange(4096, dtype=jnp.int32)
+        out, vjp = jax.vjp(lambda lp, x: T._attention_block(c, lp, x, pos, None)[0], lp, x)
+        return (out,) + vjp(g)
+
+    reset_topology()
+    set_topology(Topology(devices=[one_chip._device]))
+    try:
+        text = jax.jit(layer).lower(lp, x, x).compile().as_text()
+    finally:
+        reset_topology()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert sum(FP.FLASH_FWD in ln for ln in calls) == 1 and sum(FP.FLASH_BWD_FUSED in ln for ln in calls) == 1
+    assert all("bf16[1,4096,2048]" in ln and "[1,16,4096,128]{3,2,1,0:T(8,128)(2,1)" not in ln for ln in calls)
+    heads = re.compile(r"\[1,4096,(16|8),128\]|\[1,(16|8),4096,128\]")
+    relaid = [ln.split(", metadata")[0] for ln in text.splitlines()
+              if re.search(r" (copy|transpose|reshape)\(", ln) and heads.search(ln)]
+    assert not relaid, relaid[:3]
+
+
 @pytest.mark.parametrize("Rc,tq", [(0, 0), (1, 128), (2, 128)],
                          ids=["decode_only", "one_chunk_row", "two_chunk_rows"])
 def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chip, on_the_chip,

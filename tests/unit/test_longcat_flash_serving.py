@@ -431,7 +431,10 @@ PARENT_JAXPRS = {
     "axk1_route": "052705b9bca383cd",
     # (forward()'s activation constraints are the suite's: tests/conftest.py's eight devices)
     "axk1_forward": "e597939813b15ed9",
-    "olmoe_forward": "37d87416336029e8",
+    # PR 59: forward()'s softmax attention (``_attention_block``) keeps q, k, v where the
+    # projections wrote them (``heads_view``, rank-3 ``attention_op``); the served steps above
+    # did not move. Latent attention (axk1_forward) makes its own transposes and did not either.
+    "olmoe_forward": "5e8c02ae07e46f57",
 }
 OLMOE = dict(model_type="olmoe", vocab_size=256, hidden_size=64, num_hidden_layers=2,
              num_attention_heads=4, num_key_value_heads=4, intermediate_size=32, num_experts=8,

@@ -175,3 +175,80 @@ def test_a_layer_keeps_the_kernels_output_and_one_float_a_row(interpreted, polic
     assert sorted(a.size for a in kept.values()) == [b * nh * SEQ, b * nh * SEQ * d]
     lse = kept["named 'flash_lse'"]
     assert (lse.size, lse.dtype) == (b * nh * SEQ, jnp.float32)
+
+
+def _tiny_128(**kw):
+    # a head of whole lanes: _attention_block hands the kernel q, k, v where the
+    # projections wrote them, [b, s, heads * 128] (PR 59)
+    return _tiny(head_dim_override=128, **kw)
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_token_major_operands_keep_their_tags(interpreted, policy):
+    # every policy but "nothing" keeps the kernel's output and one float a row
+    # of its log-sum-exp, "flash_qkv" q, k and v besides, all token-major: the
+    # same arrays at the same sizes as head-major, [b, s, heads * d]
+    cfg = _tiny_128(remat_policy=policy)
+    b, nh, nkv, d = 2, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    assert d == 128
+    kept = [(why.split(" from ")[0], aval)
+            for aval, why in _kept_by_one_layer(cfg, T.remat_policy(policy), rows=b)
+            if "flash_pallas.py" in why]
+    shapes = sorted(a.shape for _, a in kept)
+    out, lse, q, kv = (b, SEQ, nh * d), (b, nh, SEQ, 1), (b, SEQ, nh * d), (b, SEQ, nkv * d)
+    if policy == "nothing":
+        assert shapes == []
+    elif policy == "flash_qkv":
+        assert shapes == sorted([out, lse, q, kv, kv])
+        assert sum(name == "named 'flash_qkv'" for name, _ in kept) == 3
+    elif policy == "everything":
+        assert out in shapes and lse in shapes
+    else:
+        assert shapes == sorted([out, lse])
+    if policy != "nothing":
+        assert dict(kept)["named 'flash_lse'"].dtype == jnp.float32
+
+
+def test_the_model_hands_the_kernel_token_major_operands(one_device):
+    # the traced layer calls the kernels on rank-3 operands at a head of 128
+    # and on head-major ones at the tiny preset's head of 32
+    def operands(cfg):
+        params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.key(0))
+        jaxpr = jax.make_jaxpr(jax.grad(make_loss_fn(cfg)))(params, _batch(cfg))
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield eqn.params["name"], eqn.invars[0].aval.shape
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (tuple, list)) else (v,):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from walk(sub)
+        return set(walk(jaxpr.jaxpr))
+
+    cfg = _tiny_128(remat_policy="dots_with_no_batch_dims")
+    assert operands(cfg) == {(flash_pallas.FLASH_FWD, (1, SEQ, cfg.n_heads * 128)),
+                             (flash_pallas.FLASH_BWD_FUSED, (1, SEQ, cfg.n_heads * 128))}
+    cfg = _tiny()
+    assert operands(cfg) == {(flash_pallas.FLASH_FWD, (1, cfg.n_heads, SEQ, cfg.head_dim)),
+                             (flash_pallas.FLASH_BWD_FUSED, (1, cfg.n_heads, SEQ, cfg.head_dim))}
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["one_segment", "packed"])
+def test_token_major_gradients_are_those_of_no_remat(interpreted, segments):
+    cfg = _tiny_128()
+    params = init_params(cfg, jax.random.key(0))
+    batch = _batch(cfg, rows=2, segments=segments)
+    grad = lambda c: jax.jit(jax.value_and_grad(make_loss_fn(c)))(params, batch)  # noqa: E731
+    loss, grads = grad(cfg)
+    loss0, grads0 = grad(dataclasses.replace(cfg, remat=False))
+    # ... and of the plain reference attention, closely
+    loss_ref, grads_ref = grad(dataclasses.replace(cfg, attention_impl="reference"))
+    assert float(loss) == float(loss0) and np.isfinite(float(loss))
+    assert abs(float(loss) - float(loss_ref)) < 1e-4
+    for (path, g), g0, gr in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(grads0),
+                                 jax.tree.leaves(grads_ref)):
+        assert float(jnp.abs(g).max()) > 0, path
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(g0), err_msg=str(path))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(gr), rtol=2e-3, atol=2e-4, err_msg=str(path))
