@@ -468,16 +468,23 @@ def kernel_cases(size):
     for r, n in enumerate((-(-(int(cstart[0]) + ltq) // lbs), ltq // lbs)):
         ctab[r, :n] = [free.pop() for _ in range(n)]   # (no block is two rows': the write below)
 
+    # (a chunk row of 512 slots attends EXPANDED, a head's keys and values made
+    # of the cached latents inside the kernel; the tiny size's 32 slots absorbed)
+    ldr = lD - lrank
+    ldn = ldv = 8 if TINY else 128
+
     def latent_chk(impl):
-        def run(q, pool, tb, qpos, new, limit):
-            return LP.latent_chunk(q, pool, tb, qpos, lP, new, limit, rank=lrank, scale=lD ** -0.5,
+        def run(q, qr, w, pool, tb, qpos, new, limit):
+            return LP.latent_chunk(q, qr, w, pool, tb, qpos, lP, new, limit, scale=lD ** -0.5,
                                    impl=impl, interpret=interp)
         return run
 
     cases.append((
-        "latent chunk, absorbed, the pool below the chunk and its own vectors",
+        "latent chunk, the pool below the chunk and its own vectors",
         latent_chk("kernel"),
-        (rnd((2, ltq, lnh, lD)), lpool, jnp.asarray(ctab), ccpos, rnd((2, ltq, lD)), cstart),
+        (rnd((2, ltq, lnh * (ldn + ldr))), rnd((2, ltq, lnh, ldr)),
+         rnd((lrank, lnh * (ldn + ldv))) * lrank ** -0.5, lpool, jnp.asarray(ctab), ccpos,
+         rnd((2, ltq, lD)), cstart),
         latent_chk("dense"), 2e-2,
     ))
     # the write: lR decode rows' blocks and a chunk's, two layers of the pool

@@ -120,7 +120,8 @@ class StepStats:
     chunk_live_blocks_total
     / chunk_table_slots_total, moe_*_total, the recurrent kind's <kind>_*_total, kv_*_total,
     paged_window_live_blocks_total, latent_decode_rows_total /
-    latent_decode_blocks_total / latent_live_blocks_total,
+    latent_decode_blocks_total / latent_live_blocks_total, latent_chunk_rows_total /
+    latent_chunk_expanded_rows_total,
     moe_group_hit_tokens_total, steps_ahead_total,
     ahead_rows_dropped_total, and the step's time on the device by KIND:
     decode_step_seconds_total / chunk_step_seconds_total with the counts of the
@@ -169,6 +170,11 @@ class StepStats:
     # latent attention; a step walks kv_layers planes); 0 elsewhere
     latent_decode_rows: int = 0
     latent_decode_blocks: int = 0
+    # ... the chunk rows of the step (one plane's again) and those of them that
+    # attended in the EXPANDED form: all or none, by the step's bucket
+    # (latent_pallas.chunk_expands)
+    latent_chunk_rows: int = 0
+    latent_chunk_expanded_rows: int = 0
     # ... and the pool blocks the tracked sequences' tables hold (kv_global_blocks
     # also counts what the prefix cache retains until a request needs the room)
     latent_live_blocks: int = 0
@@ -1754,16 +1760,20 @@ class InferenceEngineV2:
             return jax.lax.fori_loop(
                 0, n, lambda pi, st: run_period(pi, *st, traced), (x, carry))
 
-        def layer_params(take):
-            return {**jax.tree.map(take, sliced), **whole}
+        def at_layer(stacks, i):
+            # a looped latent layer's ``wkv_b`` goes on unsliced, as _static_layer
+            # hands an unrolled one's: the chunk kernel indexes the layer itself
+            lp = jax.tree.map(lambda a: traced(a, i), stacks)
+            if "wkv_b" in stacks and self._mesh is None:
+                lp["wkv_b"] = Stacked(stacks["wkv_b"], i)
+            return lp
 
         if not isinstance(windows, list):
             def body(li, st):
                 x, carry = st
-                lp = layer_params(lambda a: traced(a, li))
+                lp = {**at_layer(sliced, li), **whole}
                 if subs is not None:
-                    lp["sub"] = tuple(jax.tree.map(lambda a: traced(a, 2 * li + i), subs)
-                                      for i in range(2))
+                    lp["sub"] = tuple(at_layer(subs, 2 * li + i) for i in range(2))
                 return layer_fn(lp, x, li, carry, window=windows)
 
             x, carry = jax.lax.fori_loop(0, L, body, (x, carry))
@@ -1794,12 +1804,17 @@ class InferenceEngineV2:
         GSPMD take arrays), and a layer whose products are ``stack_dot``'s
         (``_layer_qkv`` / ``_layer_tail`` / ``T.kind_qkv``, a KDA layer's two wide
         projections through ``T._proj``; ``_latent_layer`` multiplies its ``wo``
-        itself, and its ``wq`` stays sliced beside it)."""
+        itself, and its ``wq`` stays sliced beside it). A latent layer's
+        ``wkv_b`` stays in its stack too: the expanded chunk kernel indexes the
+        layer's columns out of it (``latent_pallas.latent_chunk``), and
+        ``T.latent_up`` slices it for the decode rows as it always did."""
         in_place = self._mesh is None
+        stay = _READ_IN_PLACE
+        if self._latent:
+            stay = tuple(k for k in stay if k not in ("wq", "wo")) + ("wkv_b",)
 
         def take(k, a):
-            if (in_place and k in _READ_IN_PLACE and not (self._latent and k in ("wq", "wo"))
-                    and isinstance(a, jax.Array) and a.shape[0] > 1):
+            if in_place and k in stay and isinstance(a, jax.Array) and a.shape[0] > 1:
                 return Stacked(a, i)
             return jax.tree.map(lambda b: b[i], a)
 
@@ -2062,17 +2077,24 @@ class InferenceEngineV2:
         return x, self._record_kv(carry, li, k, v, moe)
 
     def _latent_attention(self, lp, x, plane, meta):
-        """Latent attention of the split step in the ABSORBED form, on the
-        cache's plane ``plane`` (as ``_kv_source`` takes a layer: the plane
-        itself, or in a stack with recurrent layers the LAYER, whose ordinal
-        among the latent ones is its plane): ``W_UK`` moved to the query (``q = [q_nope W_UK
-        | q_rope]``, every head against the one cached vector a token) and
-        ``W_UV`` behind the output. Decode rows through ``latent_decode`` (the
-        pool below their position and their own new vector as the extra
-        column), chunk rows through ``latent_chunk`` (the pool below the chunk's
-        start, then the chunk's own vectors, causal); on the chip the kernels
-        ``dstpu_mla_decode`` / ``dstpu_mla_chunk``, elsewhere the dense forms.
-        Returns (the block's output [1, t, h], the new vectors [t, latent_dim])."""
+        """Latent attention of the split step on the cache's plane ``plane`` (as
+        ``_kv_source`` takes a layer: the plane itself, or in a stack with
+        recurrent layers the LAYER, whose ordinal among the latent ones is its
+        plane), each kind of row in the form its queries a key pay for
+        (ops/attention/latent_pallas.py). Decode rows ABSORBED, one query a
+        key: ``W_UK`` moved to the query (``q = [q_nope W_UK | q_rope]``, every
+        head against the one cached vector a token), ``W_UV`` behind the
+        output, through ``latent_decode`` (the pool below their position and
+        their own new vector as the extra column). Chunk rows through
+        ``latent_chunk`` (the pool below the chunk's start, then the chunk's
+        own vectors, causal), which takes the query projection AS WRITTEN
+        (``latent_q``: the dims without rotary are read where they lie),
+        ``q_rope`` as ``latent_qkv`` rotated it and ``wkv_b`` as the checkpoint
+        stores it: a row of ``prompt_chunk`` slots attends EXPANDED, a head's
+        keys and values made of the cached latents inside the kernel; the
+        128-slot bucket absorbed. On the chip the kernels ``dstpu_mla_decode``
+        / ``dstpu_mla_chunk``, elsewhere the dense forms. Returns (the block's
+        output [1, t, h], the new vectors [t, latent_dim])."""
         from deepspeed_tpu.ops.attention.latent_pallas import latent_chunk, latent_decode
 
         c = self._mc
@@ -2080,23 +2102,24 @@ class InferenceEngineV2:
         nh, rank, D = c.n_heads, c.kv_lora_rank, c.latent_dim
         scale = c.attn_scale if c.attn_scale is not None else c.head_dim ** -0.5
         a = T._norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c.norm, c.norm_eps)
-        q_nope, q_rope, ckv = T.latent_qkv(c, lp, a[0], meta["positions"], meta["live"])
+        q = T.latent_q(c, lp, a[0])
+        q_nope, q_rope, ckv = T.latent_qkv(c, lp, a[0], meta["positions"], meta["live"], q=q)
         w_uk, w_uv = T.latent_up(c, lp)
-        q = jnp.concatenate([jnp.einsum("thd,chd->thc", q_nope, w_uk), q_rope], axis=-1)
+        qa = jnp.concatenate([jnp.einsum("thd,chd->thc", q_nope[:R], w_uk), q_rope[:R]], axis=-1)
         pool, _, tables_l, trash_l = self._kv_source(meta, plane, "dec_tables")
         out = latent_decode(
-            q[:R], pool, tables_l, meta["dec_pos"], trash_l, rank=rank, scale=scale,
+            qa, pool, tables_l, meta["dec_pos"], trash_l, rank=rank, scale=scale,
             extra=(ckv[:R, None], meta["dec_pos"][:, None]), pool_limit=meta["dec_pos"],
             impl=self._attn_impl)
+        out = jnp.einsum("thc,chd->thd", out, w_uv).reshape(R, nh * c.v_head_dim)
         if tq:
             _, _, tables_l, trash_l = self._kv_source(meta, plane, "chk_tables")
             out_c = latent_chunk(
-                q[R:].reshape(Rc, tq, nh, D), pool, tables_l, meta["chk_pos"], trash_l,
-                ckv[R:].reshape(Rc, tq, D), meta["chk_start"], rank=rank, scale=scale,
-                impl=self._attn_impl)
-            out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh, rank)], axis=0)
-        heads = jnp.einsum("thc,chd->thd", out, w_uv).reshape(x.shape[1], nh * c.v_head_dim)
-        return (heads @ lp["wo"])[None], ckv
+                q[R:].reshape(Rc, tq, -1), q_rope[R:].reshape(Rc, tq, nh, -1), lp["wkv_b"],
+                pool, tables_l, meta["chk_pos"], trash_l, ckv[R:].reshape(Rc, tq, D),
+                meta["chk_start"], scale=scale, impl=self._attn_impl)
+            out = jnp.concatenate([out, out_c.reshape(Rc * tq, nh * c.v_head_dim)], axis=0)
+        return (out @ lp["wo"])[None], ckv
 
     def _latent_layer(self, lp, x, li, meta, carry):
         """One latent-attention layer of the split step: ``_latent_attention``
@@ -2452,6 +2475,10 @@ class InferenceEngineV2:
             self.last_step.latent_decode_rows = len(dec_rows)
             self.last_step.latent_decode_blocks = self.last_step.paged_live_blocks
             self.last_step.latent_live_blocks = self.state_manager.live_blocks
+            from deepspeed_tpu.ops.attention.latent_pallas import chunk_expands
+
+            self.last_step.latent_chunk_rows = len(chk_rows)
+            self.last_step.latent_chunk_expanded_rows = len(chk_rows) if chunk_expands(tq) else 0
         inputs = {
             "tokens": tokens, "positions": positions, "blk": blk, "row": row,
             "dec_tables": dec_tables, "dec_pos": dec_pos, "dec_uids": dec_uids,

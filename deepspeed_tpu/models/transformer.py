@@ -1745,28 +1745,42 @@ def _rope_pairs(c: TransformerConfig, x, positions, seq_len=None):
     return _rope(x.transpose(1, 0, 2)[None], positions[None], c, seq_len)[0].transpose(1, 0, 2)
 
 
-def latent_qkv(c: TransformerConfig, lp, a, positions, seq_len=None):
+def latent_q(c: TransformerConfig, lp, a):
+    """The query projection of one latent-attention layer on normed activations
+    ``a`` [t, h], AS WRITTEN: [t, n_heads x head_dim], a head's ``[nope | rope]``
+    dims on adjacent lanes, the rope dims not yet rotated (``latent_qkv`` splits
+    and rotates them; the serving step's expanded chunk kernel reads the nope
+    dims where they lie)."""
+    from deepspeed_tpu.ops.normalization.fused_norm import rms_norm_reference
+
+    if "wq" in lp:  # q_lora_rank 0: one projection
+        q = as_written(_proj(c, a, lp["wq"]))
+    else:
+        eps = c.norm_eps if c.latent_norm_eps is None else c.latent_norm_eps
+        cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], eps)
+        q = as_written(_proj(c, cq, lp["wq_b"]))
+    if c.latent_q_scale != 1.0:  # longcat_flash: behind wq_b, nope and rope dims alike
+        q = (q.astype(jnp.float32) * c.latent_q_scale).astype(q.dtype)
+    return q
+
+
+def latent_qkv(c: TransformerConfig, lp, a, positions, seq_len=None, q=None):
     """The projections of one latent-attention layer on normed activations
     ``a`` [t, h] at ``positions`` [t]: (q_nope [t, nh, qk_nope_dim], q_rope
     [t, nh, qk_rope_dim] rotated, ckv [t, latent_dim]: the normed latent and
     the rotated key dims every head shares, which is what a cache holds of a
-    token; under ``position="none"`` neither is rotated). Shared by the model's
-    forward (expanded form) and the serving steps (absorbed form)."""
+    token; under ``position="none"`` neither is rotated). ``q``: ``latent_q``'s
+    result where the caller holds it already. Shared by the model's forward
+    (expanded form) and the serving steps."""
     from deepspeed_tpu.ops.normalization.fused_norm import rms_norm_reference
 
     t = a.shape[0]
     nh, rank, dn = c.n_heads, c.kv_lora_rank, c.qk_nope_dim
     eps = c.norm_eps if c.latent_norm_eps is None else c.latent_norm_eps
-    if "wq" in lp:  # q_lora_rank 0: one projection
-        q = as_written(_proj(c, a, lp["wq"])).reshape(t, nh, c.head_dim)
-    else:
-        cq = rms_norm_reference(_proj(c, a, lp["wq_a"]), lp["q_a_norm"], eps)
-        q = as_written(_proj(c, cq, lp["wq_b"])).reshape(t, nh, c.head_dim)
+    q = (latent_q(c, lp, a) if q is None else q).reshape(t, nh, c.head_dim)
     kv = _proj(c, a, lp["wkv_a"])
     latent = rms_norm_reference(kv[:, :rank], lp["kv_a_norm"], eps)
-    if c.latent_q_scale != 1.0:  # longcat_flash: behind wq_b, nope and rope dims alike
-        q = (q.astype(jnp.float32) * c.latent_q_scale).astype(q.dtype)
-    if c.latent_kv_scale != 1.0:  # ... and the normed latent; the rotary key dims are not
+    if c.latent_kv_scale != 1.0:  # longcat_flash: the normed latent; the rotary key dims are not
         latent = (latent.astype(jnp.float32) * c.latent_kv_scale).astype(latent.dtype)
     if c.position == "rope":
         q_rope = _rope_pairs(c, q[..., dn:], positions, seq_len)
@@ -1780,7 +1794,12 @@ def latent_up(c: TransformerConfig, lp):
     """``wkv_b`` split by head: (W_UK [rank, nh, qk_nope_dim], W_UV [rank, nh,
     v_head_dim]): a head's keys and values of the latent (expanded form), or
     moved to the query and the output side (absorbed form)."""
-    w = lp["wkv_b"].reshape(c.kv_lora_rank, c.n_heads, c.qk_nope_dim + c.v_head_dim)
+    from deepspeed_tpu.ops.stack_matmul import Stacked
+
+    w = lp["wkv_b"]
+    if isinstance(w, Stacked):  # the serving step keeps the stack for its chunk kernel
+        w = w.stack[w.index]
+    w = w.reshape(c.kv_lora_rank, c.n_heads, c.qk_nope_dim + c.v_head_dim)
     return w[..., : c.qk_nope_dim], w[..., c.qk_nope_dim:]
 
 

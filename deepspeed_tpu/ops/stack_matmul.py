@@ -36,7 +36,10 @@ _VMEM_LIMIT_BYTES = 48 << 20
 
 class Stacked(NamedTuple):
     """Layer ``index`` (static) of parameters stacked ``[layers, k, n]``, not
-    yet sliced: what ``stack_dot`` multiplies with in place."""
+    yet sliced: what ``stack_dot`` multiplies with in place. (A latent layer's
+    ``wkv_b`` rides the same way to ``latent_pallas.latent_chunk``, whose
+    kernel takes the index as a scalar: there a looped stack's traced layer
+    will do.)"""
     stack: jax.Array
     index: int
 

@@ -264,6 +264,9 @@ class EngineCore:
         self._inc("latent_decode_rows_total", held("latent_decode_rows"))
         self._inc("latent_decode_blocks_total", held("latent_decode_blocks"))
         self._inc("latent_live_blocks_total", held("latent_live_blocks"))
+        # ... its chunk rows, and those that attended expanded
+        self._inc("latent_chunk_rows_total", held("latent_chunk_rows"))
+        self._inc("latent_chunk_expanded_rows_total", held("latent_chunk_expanded_rows"))
 
     # -- admission accounting --------------------------------------------
     def blocks_needed(self, req: Request, prefill_only: bool = False) -> int:

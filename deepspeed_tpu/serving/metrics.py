@@ -168,12 +168,17 @@ class ServingMetrics:
             "paged_window_live_blocks_total": 0,
             # a latent pool (EngineCore._count_step): decode rows, and the
             # pool blocks ONE layer's absorbed decode walks for them, and the
-            # blocks the tracked sequences' tables hold, summed a step; a
+            # blocks the tracked sequences' tables hold, summed a step; the
+            # chunk rows of its steps and those that attended in the expanded
+            # form (a row of prompt_chunk slots does, a lone tail of at most
+            # 128 tokens stays absorbed); a
             # grouped router: (token, expert-layer call) pairs routed, and
             # those whose kept groups include one this chip holds
             "latent_decode_rows_total": 0,
             "latent_decode_blocks_total": 0,
             "latent_live_blocks_total": 0,
+            "latent_chunk_rows_total": 0,
+            "latent_chunk_expanded_rows_total": 0,
             "moe_group_tokens_total": 0,
             "moe_group_hit_tokens_total": 0,
             # a router with identity experts (moe_zero_experts; 0 for every

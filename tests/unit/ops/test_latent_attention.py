@@ -3,6 +3,7 @@ dense forms, interpreted on the CPU at a small size, and compiled for a
 described v5e at A.X-K1's widths (64 heads against blocks of [576, 128])."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,24 +68,153 @@ def test_decode_keeps_what_a_block_holds_outside_the_context_out_of_the_sum():
         assert np.isfinite(np.asarray(out)).all(), impl
 
 
+def _chunk_rows(rng, starts, ns, tq, width=B + 3):
+    """Chunk rows at ``starts`` with ``ns`` live queries of ``tq``: (q_pos [Rc,
+    tq], tables [Rc, width] over distinct blocks)."""
+    q_pos = np.full((len(starts), tq), -1, np.int32)
+    for r in range(len(starts)):
+        q_pos[r, : ns[r]] = starts[r] + np.arange(ns[r])
+    return q_pos, _tables(rng, np.asarray(starts) + np.asarray(ns), width=width)
+
+
 @pytest.mark.parametrize("tile", [8, 16, 32])
 def test_chunk_kernel_equals_the_dense_form(tile):
-    """A chunk that starts inside a block with a padded tail, and a first chunk
-    (nothing below it), at tiles under, at and over the block size."""
+    """The ABSORBED form: a chunk that starts inside a block with a padded
+    tail, and a first chunk (nothing below it), at tiles under, at and over the
+    block size."""
     rng = np.random.default_rng(2)
     Rc, tq = 2, 64
     starts, ns = np.array([21, 0]), np.array([50, 64])
-    q_pos = np.full((Rc, tq), -1, np.int32)
-    for r in range(Rc):
-        q_pos[r, : ns[r]] = starts[r] + np.arange(ns[r])
-    tables = _tables(rng, starts + ns, width=B + 3)
+    q_pos, tables = _chunk_rows(rng, starts, ns, tq)
     q = jnp.asarray(rng.normal(size=(Rc, tq, NH, D)), jnp.float32)
     new = jnp.asarray(rng.normal(size=(Rc, tq, D)), jnp.float32)
     args = (q, _pool(rng), jnp.asarray(tables), jnp.asarray(q_pos), TRASH, new, jnp.asarray(starts))
-    dense = LP.latent_chunk(*args, rank=RANK, scale=0.3, impl="dense")
-    kernel = LP.latent_chunk(*args, rank=RANK, scale=0.3, impl="kernel", tile=tile)
+    dense = LP.latent_chunk_absorbed(*args, rank=RANK, scale=0.3, impl="dense")
+    kernel = LP.latent_chunk_absorbed(*args, rank=RANK, scale=0.3, impl="kernel", tile=tile)
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense), atol=3e-6)
     assert not np.asarray(dense[0, 50:]).any()   # the padded tail
+
+
+DN, DV = 8, 8   # a head's key dims without rotary and its value dims, in these tests
+
+# what a row of the EXPANDED kernel may be: (starts, live queries) a row of tq = 64
+# over blocks of 16 (a visit is 64 keys)
+CHUNK_ROWS = {
+    "one_row_on_a_visits_edge": ([64], [64]),
+    "two_rows_a_tail_and_a_first_chunk": ([21, 0], [50, 64]),    # the second: no pool below it
+    "context_ends_inside_a_visit": ([37], [64]),
+    "a_tail_of_one_query": ([70], [1]),
+    "a_padded_row_beside_a_live_one": ([16, 0], [64, 0]),
+}
+
+
+def _expanded_inputs(rng, nh, starts, ns, tq=64, dtype=jnp.float32):
+    Rc = len(starts)
+    q_pos, tables = _chunk_rows(rng, starts, ns, tq)
+    rnd = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)  # noqa: E731
+    w = (rnd(RANK, nh * (DN + DV)) * 0.3).astype(dtype)
+    # (the query projection as written: a head's [nope | rope] dims on adjacent lanes)
+    return (rnd(Rc, tq, nh * (DN + ROPE)), rnd(Rc, tq, nh, ROPE), w, _pool(rng).astype(dtype),
+            jnp.asarray(tables), jnp.asarray(q_pos), TRASH, rnd(Rc, tq, D), jnp.asarray(starts))
+
+
+_WIDE = ("two_rows_a_tail_and_a_first_chunk", "context_ends_inside_a_visit")   # the shapes that differ by row
+
+
+@pytest.mark.parametrize("rows,nh,heads", (
+    [(rows, 4, 2) for rows in CHUNK_ROWS] + [(rows, 8, 8) for rows in CHUNK_ROWS]
+    + [(rows, nh, LP.EXPANDED_HEADS) for nh in (64, 32) for rows in _WIDE]))
+def test_expanded_chunk_kernel_equals_the_dense_expanded_form(rows, nh, heads):
+    """A head's keys and values made of the cached latents inside the kernel
+    equal ``kv_b_proj`` of the gathered context in plain jax.numpy, at both
+    cells' head counts and at a head-group width under, at and over the one
+    the sweep kept."""
+    starts, ns = CHUNK_ROWS[rows]
+    args = _expanded_inputs(np.random.default_rng(5), nh, starts, ns)
+    dense = LP.latent_chunk_expanded(*args, scale=0.3, impl="dense")
+    kernel = LP.latent_chunk_expanded(*args, scale=0.3, impl="kernel", heads=heads)
+    assert kernel.shape == (len(starts), 64, nh * DV)
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense), atol=5e-6)
+    for r, n in enumerate(ns):
+        assert not np.asarray(dense[r, n:]).any()   # padded queries emit 0
+    assert float(jnp.abs(dense).max()) > 0.1
+
+
+def test_expanded_chunk_stops_at_the_trash_block_and_keeps_nan_out_of_the_sum():
+    """A table that names the trash block below the limit it is given (the
+    walk's last visit finds it) and a NaN behind the row's context: neither
+    form lets either into a sum."""
+    rng = np.random.default_rng(6)
+    args = list(_expanded_inputs(rng, NH, [60], [64]))
+    tables = np.array(args[4])
+    tables[0, 4:] = TRASH                       # the row holds 64 tokens
+    clean = np.array(args[3])
+    clean[TRASH] = np.nan
+    args[4] = jnp.asarray(tables)
+    for limit in (58, 100):                     # inside the last held block; past what the table holds
+        pool = clean.copy()
+        pool[tables[0, 3], :, limit - 48:] = np.nan   # positions from the limit on, of the last held block
+        args[3], args[8] = jnp.asarray(pool), jnp.asarray([limit])
+        dense = LP.latent_chunk_expanded(*args, scale=0.3, impl="dense")
+        kernel = LP.latent_chunk_expanded(*args, scale=0.3, impl="kernel", heads=2)
+        assert np.isfinite(np.asarray(dense)).all() and np.isfinite(np.asarray(kernel)).all(), limit
+        np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense), atol=5e-6)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 3e-2)], ids=["float32", "bfloat16"])
+def test_the_expanded_and_the_absorbed_dense_forms_agree(dtype, tol):
+    """The same attention: ``(q_nope W_UK) . c`` is ``q_nope . (W_UK^T c)``.
+    In bf16 the two round at different places (the absorbed query against the
+    expanded key), which is all that separates them."""
+    starts, ns = CHUNK_ROWS["two_rows_a_tail_and_a_first_chunk"]
+    q, qr, w, *rest = _expanded_inputs(np.random.default_rng(7), NH, starts, ns, dtype=dtype)
+    expanded = LP.latent_chunk_expanded(q, qr, w, *rest, scale=0.3, impl="dense")
+    wr = w.reshape(RANK, NH, DN + DV)
+    qa = jnp.concatenate([jnp.einsum("rthd,chd->rthc", LP._nope(q, NH, ROPE), wr[..., :DN]), qr], axis=-1)
+    absorbed = jnp.einsum("rthc,chd->rthd", LP.latent_chunk_absorbed(
+        qa, *rest, rank=RANK, scale=0.3, impl="dense"), wr[..., DN:]).reshape(expanded.shape)
+    np.testing.assert_allclose(np.asarray(expanded, np.float32), np.asarray(absorbed, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("tq,form", [(128, "absorbed"), (256, "expanded"), (512, "expanded")])
+@pytest.mark.parametrize("impl", ["dense", "kernel"])
+def test_latent_chunk_picks_the_form_by_the_rows_slots_alone(monkeypatch, impl, tq, form):
+    """128 slots a row (one row of at most 128 tokens) absorbed, ``prompt_chunk``
+    expanded, on the chip and off it: nothing but ``tq`` is asked."""
+    rng = np.random.default_rng(8)
+    took = []
+    for name in ("absorbed", "expanded"):
+        fn = getattr(LP, f"latent_chunk_{name}")
+        monkeypatch.setattr(LP, f"latent_chunk_{name}", lambda *a, _f=fn, _n=name, **k: (
+            took.append(_n), _f(*a, **{**k, "impl": "dense"}))[1])
+    args = _expanded_inputs(rng, NH, [16], [3], tq=tq)
+    out = LP.latent_chunk(*args, scale=0.3, impl=impl)
+    assert took == [form] and LP.chunk_expands(tq) == (form == "expanded")
+    assert out.shape == (1, tq, NH * DV)
+    want = LP.latent_chunk_expanded(*args, scale=0.3, impl="dense")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+
+
+def test_the_sweep_tool_counts_what_the_module_header_says():
+    """``tools/mla_kernels.py`` prices a score at 2,176 operations absorbed and
+    at 640 expanded plus 262,144 a (key, head) shared by a row's queries: 1.9x
+    fewer at 512 queries, even at ~171, more at 128."""
+    import importlib.util
+
+    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    spec = importlib.util.spec_from_file_location("mla_kernels", os.path.join(here, "tools", "mla_kernels.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    ratio = {}
+    for tq in (128, 512):
+        absorbed, pool_bytes = tool.needs("absorbed", 1, tq, 64, 8192, products=False)
+        expanded, walked = tool.needs("expanded", 1, tq, 64, 8192)
+        scores = tq * 64 * (8192 + tq)
+        assert absorbed == scores * 2176 and expanded == scores * 640 + 64 * (8192 + tq) * 262144
+        ratio[tq] = absorbed / expanded
+        # one walk of the context a query tile absorbed, one a HEAD expanded (a group's heads share it)
+        assert walked == 64 * (8192 + tq) * 576 * 2 and pool_bytes == walked * (tq // LP.chunk_tile(tq, 64)) // 64
+    assert ratio[512] == pytest.approx(2176 / 1152) and ratio[128] < 1 < 2176 / (640 + 262144 / 172)
 
 
 def test_write_kernel_equals_the_scatter():
@@ -124,8 +254,9 @@ def test_an_unknown_impl_raises(impl):
             LP.latent_decode(z((1, NH, D)), _pool(rng), z((1, B), jnp.int32), z(1, jnp.int32), TRASH,
                              rank=RANK, scale=1.0, impl="auto")
         elif impl == "chunk":
-            LP.latent_chunk(z((1, BS, NH, D)), _pool(rng), z((1, B), jnp.int32), z((1, BS), jnp.int32),
-                            TRASH, z((1, BS, D)), z(1, jnp.int32), rank=RANK, scale=1.0, impl="auto")
+            LP.latent_chunk(z((1, BS, NH * (DN + ROPE))), z((1, BS, NH, ROPE)), z((RANK, NH * (DN + DV))), _pool(rng),
+                            z((1, B), jnp.int32), z((1, BS), jnp.int32), TRASH, z((1, BS, D)),
+                            z(1, jnp.int32), scale=1.0, impl="auto")
         else:
             LP.latent_write(z((1, 2, D, BS)), z((1, 4, D)), z(4, jnp.int32), z(4, jnp.int32), impl="auto")
 
@@ -161,12 +292,23 @@ def on_the_chip(monkeypatch):
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("what", ["decode", "chunk_128", "chunk_512", "write"])
+# the expanded chunk kernel at the three latent cells' widths: (heads, table
+# slots, cache planes, blocks a plane: the cell's pool bytes over 576 x 128 x 2 a block)
+_CHUNK_512 = {"chunk_512": (64, 112, 5, 2712), "chunk_512_longcat": (64, 112, 8, 2543),
+              "chunk_512_kimi": (32, 272, 3, 7912)}
+
+
+@pytest.mark.parametrize("what", ["decode", "chunk_128", *_CHUNK_512, "write"])
 def test_the_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, on_the_chip, what):
     """Mosaic takes each kernel at 64 heads, blocks of [576, 128] and the
     cell's pool, reads the pool in place (no pool-sized copy in front of the
-    call) and, for the write, updates it in place."""
-    L, NBp, Dm, bs, rank, nh, Bt, R, Rc = 5, 2712, 576, 128, 512, 64, 112, 32, 2
+    call) and, for the write, updates it in place. A chunk row of 128 slots
+    compiles the ABSORBED body, one of 512 the EXPANDED one, at A.X-K1's,
+    LongCat's and Kimi Linear's heads, table slots and planes: ``wkv_b`` read as
+    the checkpoint stores it and the query projection as it was written (no
+    copy of either), nothing the size of a row's context times its heads."""
+    Dm, bs, rank, R, Rc, dn, dr, dv = 576, 128, 512, 32, 2, 128, 64, 128
+    nh, Bt, L, NBp = _CHUNK_512.get(what, _CHUNK_512["chunk_512"])
 
     def S(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -182,11 +324,12 @@ def test_the_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, on_the_
     elif what.startswith("chunk"):
         tq = int(what.split("_")[1])
 
-        def f(q, pool, tab, pos, new, start):
-            return LP.latent_chunk(q, pool.reshape(L * NBp, Dm, bs), tab, pos, NBp - 1, new, start,
-                                   rank=rank, scale=0.1, impl="kernel")
-        comp = jax.jit(f).lower(S((Rc, tq, nh, Dm)), pool, S((Rc, Bt), i32), S((Rc, tq), i32),
-                                S((Rc, tq, Dm)), S((Rc,), i32)).compile()
+        def f(q, qr, w, pool, tab, pos, new, start):
+            return LP.latent_chunk(q, qr, w, pool.reshape(L * NBp, Dm, bs), tab, pos, NBp - 1, new,
+                                   start, scale=0.1, impl="kernel")
+        comp = jax.jit(f).lower(S((Rc, tq, nh * (dn + dr))), S((Rc, tq, nh, dr)), S((rank, nh * (dn + dv))),
+                                pool, S((Rc, Bt), i32), S((Rc, tq), i32), S((Rc, tq, Dm)),
+                                S((Rc,), i32)).compile()
     else:
         n, G = R + Rc * 512, 64
 
@@ -198,9 +341,20 @@ def test_the_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, on_the_
         assert comp.memory_analysis().temp_size_in_bytes < 64 << 20   # no second pool
     text = comp.as_text()
     assert text.count("tpu_custom_call") == 1
-    assert not [ln for ln in text.splitlines()
-                if " copy(" in ln and (f"[{L * NBp}," in ln.split(" copy(")[0]
-                                       or f"[{L},{NBp}," in ln.split(" copy(")[0])]
+
+    def written(ln):   # elements a copy, transpose or non-bitcast reshape writes
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([0-9,]+)\]\S* (?:copy|transpose|reshape)\(", ln)
+        return int(np.prod([int(x) for x in m[1].split(",")])) if m else 0
+
+    sizes = [written(ln) for ln in text.splitlines()]
+    assert max(sizes) < L * NBp * Dm * bs // 2        # nothing the size of the pool
+    if what in _CHUNK_512:
+        # the weight and the projection go to the kernel as they lie (the rotated
+        # rope dims, [.., nh, 64], are the one operand XLA re-lays: half a lane tile
+        # a head as the rotary fusion writes them), and the program holds nothing
+        # context-sized: a row's keys and values a head would be Bt x 128 x nh x 256
+        assert rank * nh * (dn + dv) not in sizes and Rc * 512 * nh * (dn + dr) not in sizes
+        assert comp.memory_analysis().temp_size_in_bytes < 24 << 20 < Bt * bs * nh * (dn + dv) * 2
 
 
 def test_the_flash_backward_compiles_for_a_v5e_at_the_train_cells_shape(one_chip, on_the_chip):
@@ -299,8 +453,46 @@ def test_an_attention_layer_compiles_for_a_v5e_with_nothing_re_laid_around_the_k
     assert not relaid, relaid[:3]
 
 
-@pytest.mark.parametrize("Rc,tq", [(0, 0), (1, 128), (2, 128)],
-                         ids=["decode_only", "one_chunk_row", "two_chunk_rows"])
+def _expanded_chunk_side(text):
+    """What the expanded chunk kernel's calls may not have around them in a
+    compiled step. Each call's query projection and ``wkv_b`` stack are traced
+    back through what moves no byte into another order (bitcasts, tuple
+    elements, the compiler's asynchronous slices and prefetches) to the
+    operation that wrote them: the projection's own product and the program's
+    parameter, never a copy, a transpose or a re-laying reshape (a slice of the
+    layer in front of the call, ``latent_up``'s split by heads); and nothing
+    re-lays the call's output on its way to the output projection. The rotated
+    rope dims alone are re-laid ([.., nh, 64] as the rotary fusion writes them:
+    half a lane tile a head)."""
+    lines = text.splitlines()
+    made = {}
+    for ln in lines:
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (?:\(.*?\)|\S+) ([\w\-]+)\((.*)", ln)
+        if m:   # (the compiler's prefetch of a small stack into fast memory, a slice a layer, joined)
+            op = "prefetch" if 'custom_call_target="ConcatBitcast"' in ln else m[2]
+            made[m[1]] = (op, re.findall(r"%[\w.\-]+", m[3].split("), ")[0]))
+    moves_nothing = {"bitcast", "get-tuple-element", "slice-start", "slice-done", "copy-start", "copy-done",
+                     "opt-barrier", "tuple", "prefetch"}
+
+    def origin(name):
+        op, operands = made[name]
+        return origin(operands[0]) if op in moves_nothing and operands else op
+
+    calls = [n for n, (op, operands) in made.items() if op == "custom-call" and n.startswith("%" + LP.MLA_CHUNK)]
+    assert calls
+    for call in calls:
+        operands = made[call][1]
+        # behind the grid's size and the ten scalar operands; q_rope between them
+        q, wkv_b = operands[11], operands[13]
+        relaid = {"copy", "transpose", "reshape"}
+        assert origin(q) not in relaid, (call, "queries", origin(q))
+        assert origin(wkv_b) == "parameter", (call, "wkv_b", origin(wkv_b))
+        readers = [op for op, operands in made.values() if call in operands]
+        assert readers and not relaid & set(readers), (call, readers)
+
+
+@pytest.mark.parametrize("Rc,tq", [(0, 0), (1, 128), (2, 128), (2, 512)],
+                         ids=["decode_only", "one_chunk_row", "two_chunk_rows", "two_expanded_chunk_rows"])
 def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chip, on_the_chip,
                                                                          monkeypatch, Rc, tq):
     """The whole served step of ``a.x-k1.serve-doc-long-closed64`` at its
@@ -308,10 +500,10 @@ def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chi
     to the output, the three latent kernels and the grouped expert matmul in
     it, and no copy the size of the pool (``dstpu lint --verify``'s question,
     asked of the chip's compiler: off the chip the pool is written by XLA's
-    scatter, which transposes it)."""
+    scatter, which transposes it). Rows of 128 slots attend absorbed, rows of
+    512 EXPANDED (``_expanded_chunk_side``)."""
     import dataclasses
     import json
-    import re
     import sys
 
     import deepspeed_tpu.accelerator.device as device
@@ -365,6 +557,8 @@ def test_the_cells_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(one_chi
         int(np.prod([int(x) for x in dims.split(",")])) >= pool_elems // 2
         for dims in re.findall(r"\[([0-9,]+)\]", ln.split(" copy(")[0])[:1])]
     assert not big, big[:2]
+    if LP.chunk_expands(tq):
+        _expanded_chunk_side(text)
 
 
 # -- the paged kernels at MiMo-V2-Flash's geometry, compiled for the same described v5e ---------
@@ -477,6 +671,13 @@ LONGCAT = ("longcat-flash-chat", "longcat-flash-chat.serve-tool-agent-closed64",
            "exact")
 
 
+# Kimi Linear: 32 heads, 272 table slots, the latent layers' 3 planes beside the
+# KDA layers' state slots, an UNROLLED stack; its chunk rows attend expanded
+KIMI = ("kimi-linear-48b-a3b", "kimi-linear-48b-a3b.serve-doc-xlong-closed64",
+        {"dstpu_mla_decode", "dstpu_mla_write", "dstpu_moe_gmm", "dstpu_kda_decode"},
+        lambda layers: (layers["full"]["wq"],), "exact")
+
+
 # Jamba2-3B, whole: the block pool at ONE KV head (read as the row it is:
 # paged_pallas.one_head; a row a head, the compiler copied both planes, 173 M
 # elements each, in front of every call), the state pool [26 x 33, 16, 40, 128]
@@ -525,13 +726,14 @@ def _k_exaones_pools(nb):
     # LongCat-Flash's sub-block stacks are looped too, indexed at 2 li + i
     (LONGCAT, 0, 0, None, 140_000_000),
     (LONGCAT, 2, 512, None, 1_200_000_000),
+    (KIMI, 2, 512, None, 400_000_000),
     # Jamba2-3B: 17.6 / 51.1 MB (a chunk step's are the scan's operands in
     # float32, [1024, 40, 128] each, laid out for the kernel: PR 53)
     (JAMBA, 0, 0, _jambas_pools, 30_000_000),
     (JAMBA, 2, 512, _jambas_pools, 80_000_000),
 ], ids=["decode_only", "two_chunk_rows", "k_exaone_decode_only", "k_exaone_one_chunk_row",
         "qwen3_decode_only", "qwen3_one_chunk_row", "qwen3_next_decode_only", "a_x_k1_decode_only",
-        "longcat_decode_only", "longcat_two_chunk_rows", "jamba_decode_only",
+        "longcat_decode_only", "longcat_two_chunk_rows", "kimi_two_chunk_rows", "jamba_decode_only",
         "jamba_two_chunk_rows"])
 def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
         one_chip, on_the_chip, monkeypatch, model, Rc, tq, pool_shapes, temp_limit):
@@ -600,6 +802,8 @@ def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
     want = kernels | ({"dstpu_mla_chunk" if "lat_vblk" in inputs else "dstpu_paged_chunk"} if tq else set())
     if tq and "dstpu_mamba_decode" in kernels:
         want = want | {"dstpu_mamba_scan"}
+    if tq and "dstpu_kda_decode" in kernels:
+        want = want | {"dstpu_kda_chunk"}
     assert want <= set(re.findall(r"dstpu_[a-z_]+", text))
     # nor one of a layer's projection: the stacks are read in place
     # (ops/stack_matmul.py; sliced, every wq, wk, wv and wo was written out of
@@ -624,10 +828,13 @@ def test_mimo_v2_flashs_split_step_compiles_for_a_v5e_with_no_pool_sized_copy(
     if exact:
         sizes = {int(np.prod(w.shape[n:])) for w in split_by_heads(shapes["layers"]) for n in (0, 1)}
         sizes |= {int(np.prod(p.shape[n:])) for p in pools for n in (0, 1)}   # nor a pool, nor a plane
-        big = [ln for ln in text.splitlines() if written(ln) in sizes]
+        # (from a megabyte on: Kimi's [32, 512, 32] slice of W_UK is a state slot's size)
+        big = [ln for ln in text.splitlines() if written(ln) in sizes and written(ln) >= 1 << 20]
     assert not big, big[:2]
     print("temporaries", ma.temp_size_in_bytes)
     assert ma.temp_size_in_bytes < temp_limit
+    if "lat_vblk" in inputs and LP.chunk_expands(tq):
+        _expanded_chunk_side(text)
 
 
 # -- ops/stack_matmul.py, compiled for the same described v5e (one file a topology) ---------------

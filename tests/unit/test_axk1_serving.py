@@ -151,6 +151,57 @@ def test_engine_equals_the_reference_through_the_interpreted_kernels():
     assert _gap(params, HF, prompts, got, toks) < TOL
 
 
+def _long_prompt_engine(cfg, params, impl):
+    """``prompt_chunk`` 512 as the cells have it, at the toy widths: a prompt's
+    whole chunks attend EXPANDED, a lone tail of at most 128 tokens absorbed."""
+    return _engine(cfg, params, prompt_chunk=512, max_prompt_chunks=1, paged_attention_impl=impl,
+                   kv_cache={"num_blocks": 48, "max_blocks_per_seq": 44},
+                   state_manager={"max_ragged_batch_size": 520, "max_context": 704})
+
+
+@pytest.mark.parametrize("impl", ["dense", "kernel"], ids=["dense_forms", "interpreted_kernels"])
+def test_a_long_prompts_chunk_attends_expanded_and_its_tail_absorbed(impl):
+    """A prompt of 600 tokens is a 512-slot chunk row and then a tail of 88 in
+    the 128-slot bucket: ``StepStats`` counts one expanded row and one absorbed,
+    the tail attends to what the expanded chunk's step cached, and the logits
+    are the one-pass reference's; then decode rows alone count no chunk row."""
+    cfg, params = _model()
+    eng = _long_prompt_engine(cfg, params, impl)
+    prompts = _prompts((600,))
+    rows = []
+    step = eng.step
+
+    def counted():
+        out = step()
+        rows.append((eng.last_step.prefill_tokens, eng.last_step.latent_chunk_rows,
+                     eng.last_step.latent_chunk_expanded_rows))
+        return out
+
+    eng.step = counted
+    got, toks = _serve_logits(eng, prompts, [6])
+    assert rows[:2] == [(512, 1, 1), (88, 1, 0)] and set(rows[2:]) == {(0, 0, 0)}
+    assert _gap(params, HF, prompts, got, toks) < TOL
+
+
+def test_the_serving_core_folds_the_chunk_rows_by_form():
+    """``latent_chunk_rows_total`` / ``latent_chunk_expanded_rows_total`` beside
+    the decode rows' counters, tracing off: two prompts of 600 and 1,100 tokens,
+    one chunk row a step, are three whole chunks and two tails."""
+    from deepspeed_tpu.serving import SamplingParams, ServingDriver
+
+    cfg, params = _model()
+    eng = _engine(cfg, params, prompt_chunk=512, max_prompt_chunks=1,
+                  kv_cache={"num_blocks": 120, "max_blocks_per_seq": 72},
+                  state_manager={"max_ragged_batch_size": 520, "max_context": 1152})
+    with ServingDriver(eng) as driver:
+        reqs = [driver.submit(p, params=SamplingParams(max_new_tokens=3, ignore_eos=True))
+                for p in _prompts((600, 1100))]
+        assert all(r.wait(300) for r in reqs)
+        c = dict(driver.metrics.counters)
+    assert (c["latent_chunk_rows_total"], c["latent_chunk_expanded_rows_total"]) == (5, 3)
+    assert c["latent_decode_rows_total"] > 0
+
+
 @pytest.mark.parametrize("sampling", [{}, {"greedy": False, "temperature": 0.9, "seed": 7}],
                          ids=["greedy", "sampled"])
 def test_generate_equals_the_driven_core_on_the_latent_plane(sampling):
@@ -236,8 +287,8 @@ def _control_gap(monkeypatch, patch, lens=(100,)):
 def _patched_latent_qkv(change):
     plain = T.latent_qkv
 
-    def patched(c, lp, a, positions, seq_len=None):
-        return change(c, lp, a, positions, *plain(c, lp, a, positions, seq_len))
+    def patched(c, lp, a, positions, seq_len=None, **kw):
+        return change(c, lp, a, positions, *plain(c, lp, a, positions, seq_len, **kw))
     return patched
 
 

@@ -370,9 +370,9 @@ CONTROLS = {
         lambda plain: lambda x, lp, **kw: plain(x, lp, **{**kw, "q_scale": 1.0}))(ref.attention)),
     # the rotary key dims scaled with the latent
     "k_rope_scaled_too": lambda mp: mp.setattr(T, "latent_qkv", (
-        lambda plain: lambda c, lp, a, pos, n=None: (lambda q, r, ckv: (q, r, jnp.concatenate(
+        lambda plain: lambda c, lp, a, pos, n=None, **kw: (lambda q, r, ckv: (q, r, jnp.concatenate(
             [ckv[:, : c.kv_lora_rank], ckv[:, c.kv_lora_rank:] * c.latent_kv_scale], -1)))(
-                *plain(c, lp, a, pos, n)))(T.latent_qkv)),
+                *plain(c, lp, a, pos, n, **kw)))(T.latent_qkv)),
     # the selection bias used as weight
     "bias_used_as_weight": lambda mp: mp.setattr(ref, "routing_weights", (
         lambda x, router, bias, *, top_k, scale: (lambda p: (lambda top: jnp.sum(
@@ -423,8 +423,13 @@ def test_each_sub_block_writes_its_own_plane():
 # scale. A change that moves one of these on purpose moves its hash with it and
 # says here what changed.
 PARENT_JAXPRS = {
-    "axk1_step_decode_only": "7e9c2c60a09dfefb",
-    "axk1_step_two_chunk_rows": "d68a7b78c68652a8",
+    # PR 62, on purpose: ``_latent_attention`` absorbs the DECODE rows' queries alone and
+    # applies ``W_UV`` to their outputs alone; the chunk rows go to ``latent_chunk`` with the
+    # query projection as written (``latent_q``) and ``wkv_b``, which picks their form by
+    # their slots (32 here: absorbed, the same products on the chunk rows' share of the grid);
+    # an unrolled layer's ``wkv_b`` rides unsliced (``Stacked``) and is sliced where it is used
+    "axk1_step_decode_only": "69ce9f57577aff81",
+    "axk1_step_two_chunk_rows": "8174959756bea42a",
     "olmoe_step_decode_only": "04622bed5d4837d2",
     "olmoe_step_two_chunk_rows": "ec2f0070e4739179",
     "olmoe_route": "eff34a66bbfca8bb",
