@@ -42,6 +42,17 @@ tiles aligned to group boundaries through scalar prefetch, a tile that straddles
 a boundary visited once a group and stored under a row mask); elsewhere, and as
 its oracle, ``jax.lax.ragged_dot``. Its backward is the plain ``ragged_dot``
 forms: no train cell measures it yet.
+
+What a visit reads (``_col_tile``, from ``(k, n, itemsize)`` alone): its expert's
+whole ``[k, n]`` matrix where that is within 4 MiB, else column tiles ``[k,
+tn]`` of the widest kind within 4 MiB, and never narrower than 512 columns of
+bf16 (a kilobyte a row), whatever ``k``: at a hidden size of 6,144 or 7,168 that
+floor decides (blocks of 6 and 7 MiB, four passes over the visits where the
+budget alone gave ``[hidden, 256]`` and eight). The kernel is bound by its
+weights' bytes, and a grid step costs it ~0.65 us that the DMA does not hide,
+so fewer, larger blocks read faster; how far that was taken, and why not to the
+whole matrix, is in PERF.md section 6 (PR 63; ``tools/moe_kernels.py`` is the
+sweep).
 """
 
 import functools
@@ -58,8 +69,10 @@ from deepspeed_tpu.accelerator.device import on_tpu
 # The kernel's name in a device trace (``pallas_call(name=...)`` names the Mosaic
 # custom call), beside the other ``dstpu_*`` names.
 MOE_GMM = "dstpu_moe_gmm"
-# one expert's weight block held in VMEM (twice: the pipeline's two buffers)
+# one expert's weight block held in VMEM (twice: the pipeline's two buffers),
+# unless the floor on a block's rows asks for more (``_col_tile``)
 _WEIGHT_BLOCK_BYTES = 4 << 20
+_MIN_ROW_BYTES = 1024
 _VMEM_LIMIT_BYTES = 48 << 20
 
 
@@ -77,11 +90,27 @@ def row_tile(m_rows: int, itemsize: int) -> int:
 
 
 def _col_tile(k: int, n: int, itemsize: int) -> int:
-    """Columns of one weight block: the whole of ``n`` where ``[k, n]`` fits the
-    block budget, else the widest multiple of 128 that divides ``n`` and does."""
+    """Columns of the weight block ``[k, tn]`` a visit reads, from the shape
+    alone: the whole of ``n`` where ``[k, n]`` fits the block budget, else the
+    widest multiple of 128 that divides ``n`` and does, and never under
+    ``_MIN_ROW_BYTES`` a row (512 columns of bf16) whatever ``k``, where ``n`` has
+    such a divisor.
+
+    The kernel alone on the v5e (``tools/moe_kernels.py``; PERF.md section 6, PR
+    63) takes ``steps x ~0.65 us + bytes / ~760 GB/s``: the pipeline issues the
+    next block's DMA from inside a grid step, so a step's own cost is never
+    hidden, and a column tile multiplies the steps (``n // tn`` passes over the
+    visits, skipped ones included). At 16 experts of ``[6144, 2048]`` the budget
+    alone gave 256 columns: 623 us a call on a decode step's groups and 753 with
+    a 512-token chunk, against 585 and 685 at 512 (K-EXAONE ``gen_tok_s`` +0.75
+    to +2.7%). Wider still read a little faster alone (whole: 587 and 659) but
+    the whole-matrix block under a 96 MiB VMEM limit cost MiMo-V2-Flash 12% of
+    its ``gen_tok_s`` in the cell, so the floor stops at the narrowest block the
+    cells already ran: every shape it does not reach keeps the block it had."""
     if n % 128 or k * n * itemsize <= _WEIGHT_BLOCK_BYTES:
         return n
-    tn = max(128, (_WEIGHT_BLOCK_BYTES // (k * itemsize)) // 128 * 128)
+    floor = min(n, _MIN_ROW_BYTES // itemsize)
+    tn = max(floor, (_WEIGHT_BLOCK_BYTES // (k * itemsize)) // 128 * 128)
     while n % tn:
         tn -= 128
     return tn
@@ -118,10 +147,19 @@ def _gmm_kernel(offsets, group_of, tile_of, n_visits, layer, x_ref, w_ref, o_ref
         o_ref[...] = jnp.where(mine, acc, o_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _gmm_pallas(lhs, rhs, group_sizes, layer, tm: int, interpret: bool):
+def _gmm_pallas(lhs, rhs, group_sizes, layer, tm: int, interpret: bool, tn: Optional[int] = None):
+    """``tn``: the weight block's columns; ``_col_tile``'s for every caller but
+    a test or ``tools/moe_kernels.py``."""
     m, k = lhs.shape
     _, E, _, n = rhs.shape
-    tn = _col_tile(k, n, rhs.dtype.itemsize)
+    itemsize = rhs.dtype.itemsize
+    tn = tn or _col_tile(k, n, itemsize)
+    # once a traced call, into the set-up record: which block its visits read
+    from deepspeed_tpu.observability.setup_record import get_setup_record
+
+    record = get_setup_record()
+    now = record.now()
+    record.add("moe_gmm.block", now, now, k=k, n=n, tn=tn, run_bytes=(k * n if tn == n else tn) * itemsize)
     V = m // tm + E - 1  # every tile once, and once more for each boundary inside one
     first, visits = tile_visits(group_sizes.astype(jnp.int32), tm)
     vend = jnp.cumsum(visits)

@@ -1,10 +1,14 @@
 """The drop-free expert layer (parallel/moe/grouped.py): rows sorted by expert
 and grouped matmuls, against the obvious oracle (every expert on every token,
 masked by the top-k) in float32; its gradients; the Pallas kernel interpreted
-against ``jax.lax.ragged_dot``; and the counters a serving step feeds from the
+against ``jax.lax.ragged_dot``, at a wide hidden size in the rule's column tiles
+and in one block too; the rule of the weight block over the shapes of the
+benchmark's seven expert configurations; and the counters a serving step feeds from the
 [L, E] routed rows, against a count by hand with the grid's padding excluded."""
 
 import dataclasses
+import json
+import os
 
 import numpy as np
 import pytest
@@ -158,6 +162,59 @@ def test_the_kernel_interpreted_equals_ragged_dot(rows, tm, dtype):
                                    atol=0.5 if dtype == jnp.bfloat16 else 1e-3, rtol=2e-2)
 
 
+# group sizes over four experts and 48 rows in tiles of 16 (bf16) or 8: groups
+# that straddle a row tile beside an empty one, with rows of no group and
+# skipped visits behind them; one group that holds every row; a full grid; a
+# traced layer of the stack. ``tn``: None for the rule's block, which at ``k`` =
+# 4,224 is the floor's (a kilobyte a row: two column tiles of 512 in bf16, four
+# of 256 in float32, as at K-EXAONE's 6,144 and A.X-K1's 7,168); 1,024 for the
+# whole [4224, 1024] matrix as one block.
+WIDE_CASES = {
+    "straddling_and_empty": ([5, 0, 19, 11], False, None),
+    "straddling_and_empty_in_one_block": ([5, 0, 19, 11], False, 1024),
+    "one_group_holds_every_row": ([0, 0, 48, 0], False, None),
+    "every_row_routed_in_one_block": ([9, 23, 1, 15], False, 1024),
+    "a_traced_layer_of_the_stack": ([14, 3, 0, 20], True, None),
+    "a_traced_layer_in_one_block": ([14, 3, 0, 20], True, 1024),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(WIDE_CASES))
+def test_the_kernel_interpreted_at_a_wide_hidden_size(case, dtype):
+    sizes, stacked, tn = WIDE_CASES[case]
+    E, k, n, rows = 4, 4224, 1024, 48
+    tm = grouped.row_tile(rows, jnp.dtype(dtype).itemsize)
+    assert grouped._col_tile(k, n, jnp.dtype(dtype).itemsize) * jnp.dtype(dtype).itemsize == grouped._MIN_ROW_BYTES
+    rng = np.random.default_rng(len(case))
+    lhs = jnp.asarray(rng.standard_normal((rows, k)), dtype)
+    rhs = jnp.asarray(rng.standard_normal((2 if stacked else 1, E, k, n)) * k ** -0.5, dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    layer = len(rhs) - 1
+    got = jax.jit(lambda li: grouped._gmm_pallas(lhs, rhs, sizes, li, tm, True, tn=tn))(jnp.int32(layer))
+    want = grouped.grouped_matmul(lhs, rhs[layer], sizes, tm, "ragged")
+    total = int(sizes.sum())
+    # sums of 4,224 terms in two orders, rounded to the dtype: one unit in the
+    # last place of bf16 (2 ** -7 of the value) where the two straddle a rounding
+    tol = dict(atol=1e-2, rtol=2 ** -7) if dtype == jnp.bfloat16 else dict(atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[:total], np.float32), np.asarray(want[:total], np.float32), **tol)
+
+
+def test_a_traced_call_leaves_its_block_in_the_setup_record():
+    from deepspeed_tpu.observability import SetupRecord, get_setup_record, set_setup_record
+
+    old = get_setup_record()
+    fresh = set_setup_record(SetupRecord())
+    try:
+        lhs, rhs = jnp.ones((16, 256), jnp.bfloat16), jnp.ones((3, 256, 384), jnp.bfloat16)
+        grouped.grouped_matmul(lhs, rhs, jnp.asarray([4, 0, 9], jnp.int32), 16, "interpret")
+        said = [sp.args for sp in fresh.spans() if sp.name == "moe_gmm.block"]
+    finally:
+        set_setup_record(old)
+    # the whole [256, 384] matrix is one block and one run of HBM
+    assert said == [{"k": 256, "n": 384, "tn": 384, "run_bytes": 256 * 384 * 2}]
+
+
 @pytest.mark.parametrize("impl", ["interpret", "ragged"])
 def test_a_layer_of_the_whole_stack_is_read_in_place(impl):
     """``layer=``: the weights are the stack [L, E, k, n] and the kernel's index
@@ -184,7 +241,55 @@ def test_the_tiling_rule_and_the_rows_it_covers():
     np.testing.assert_array_equal(visits, [1, 0, 3, 2, 0, 2])
     np.testing.assert_array_equal(first[[0, 2, 3, 5]], [0, 0, 2, 3])
     assert grouped.computed_rows(np.array([[5, 0, 17, 3, 0, 9], [0, 0, 0, 0, 0, 0]]), 8) == 64
-    assert grouped._col_tile(2048, 1024, 2) == 1024 and grouped._col_tile(8192, 4096, 2) == 256
+    assert grouped._col_tile(2048, 1024, 2) == 1024
+
+
+# The benchmark's seven expert configurations, and the columns of the block a
+# visit read of each one's bf16 ``w_up`` / ``w_gate`` and ``w_down`` under the 4
+# MiB budget alone, as it stood until PR 63. The floor on a block's rows moves
+# the three wide hidden sizes' up / gate blocks and no other (PERF.md section 6,
+# PR 63: the kernel-alone sweep of tools/moe_kernels.py, and what a wider block
+# cost MiMo-V2-Flash in its cell).
+COLUMNS_BEFORE = {
+    "olmoe-1b-7b": (1024, 2048),
+    "qwen3-next-80b-a3b": (512, 2048),
+    "mimo-v2-flash": (512, 1024),
+    "k-exaone-236b-a23b": (256, 1024),
+    "a.x-k1": (256, 1024),
+    "longcat-flash-chat": (256, 1024),
+    "kimi-linear-48b-a3b": (512, 1152),
+}
+
+
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("name", sorted(COLUMNS_BEFORE))
+def test_the_weight_block_of_the_benchmarks_expert_shapes(name, product):
+    from deepspeed_tpu.models.hf import config_from_hf
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmarks", "configs", name + ".json")) as f:
+        cfg = config_from_hf(json.load(f))
+    k, n = (cfg.hidden_size, cfg.expert_dim) if product == "up" else (cfg.expert_dim, cfg.hidden_size)
+    tn = grouped._col_tile(k, n, 2)
+    before = COLUMNS_BEFORE[name][product == "down"]
+    assert n % tn == 0 and tn % 128 == 0
+    if before * 2 < grouped._MIN_ROW_BYTES:
+        # a wide hidden size's up / gate block: rows of a kilobyte, half the passes
+        assert cfg.hidden_size >= 6144 and product == "up" and tn * 2 == grouped._MIN_ROW_BYTES
+    else:
+        assert tn == before
+    # both buffers of the block, of a 128-row tile and of the output block, and
+    # the product's float32 result, inside the stated limit
+    vmem = 2 * (k * tn + 128 * k + 128 * tn) * 2 + 128 * tn * 4
+    assert vmem <= grouped._VMEM_LIMIT_BYTES
+
+
+def test_a_matrix_over_the_budget_is_read_in_column_tiles():
+    assert grouped._col_tile(8192, 4096, 2) == 512       # the floor: 8 MiB a block
+    assert grouped._col_tile(1024, 4096, 2) == 2048      # the budget: 4 MiB a block
+    assert grouped._col_tile(6144, 2048, 4) == 256       # float32: a kilobyte a row is 256 columns
+    assert grouped._col_tile(1024, 2304, 2) == 1152      # the widest multiple of 128 that divides n
+    assert grouped._col_tile(64, 100, 2) == 100          # an n that is no multiple of 128 is not cut
 
 
 def test_serving_counters_equal_a_count_by_hand():
